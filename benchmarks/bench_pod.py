@@ -277,8 +277,7 @@ def _free_port() -> int:
 def run_pod(nproc: int, n_batches: int, outdir: str, commit_every: int) -> dict:
     _validate(nproc, n_batches, commit_every)
     port = _free_port()
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env = dict(os.environ)  # workers force the CPU backend themselves
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
     procs = []

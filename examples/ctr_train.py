@@ -32,6 +32,7 @@ from torchkafka_tpu.models.recsys import (
     make_dlrm_train_step,
     make_chunk_processor,
 )
+from torchkafka_tpu.utils.devices import enable_compile_cache
 
 N_PARTS = 8
 
@@ -57,6 +58,7 @@ def make_broker(cfg: DLRMConfig, n_records: int) -> tk.InMemoryBroker:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=40)
     ap.add_argument("--batch", type=int, default=1024)

@@ -68,9 +68,13 @@ def make_consumer(tk, jax):
 def train(args) -> None:
     import jax
 
-    if args.coordinator:  # self-spawned worker: join the local pod
-        from torchkafka_tpu.utils.devices import force_cpu_devices
+    from torchkafka_tpu.utils.devices import (
+        enable_compile_cache,
+        force_cpu_devices,
+    )
 
+    enable_compile_cache()
+    if args.coordinator:  # self-spawned worker: join the local pod
         force_cpu_devices(2)
         jax.distributed.initialize(
             coordinator_address=args.coordinator,
@@ -152,8 +156,7 @@ def spawn(args) -> int:
     with socket.socket() as s:
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)  # workers pick the CPU backend themselves
+    env = dict(os.environ)  # workers pick the CPU backend themselves
     procs = []
     for pid in range(args.spawn):
         procs.append(

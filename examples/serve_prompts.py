@@ -30,6 +30,7 @@ import torchkafka_tpu as tk
 from torchkafka_tpu.models import TransformerConfig
 from torchkafka_tpu.models.transformer import init_params
 from torchkafka_tpu.serve import StreamingGenerator
+from torchkafka_tpu.utils.devices import enable_compile_cache, force_cpu_devices
 
 TOPIC = "prompts"
 PROMPT_LEN = 32
@@ -37,6 +38,7 @@ VOCAB = 2048
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--prompts", type=int, default=24)
     ap.add_argument("--slots", type=int, default=4)
@@ -48,17 +50,11 @@ def main() -> int:
                     "of this size, remaining devices on data (slots). "
                     "Try --cpu-devices 8 --tp 2 anywhere.")
     ap.add_argument("--cpu-devices", type=int, default=None,
-                    help="force a virtual CPU mesh of this many devices "
-                    "(env vars are too late where jax is pre-imported; "
-                    "this uses jax.config before first device use)")
+                    help="force the CPU backend with this many virtual "
+                    "devices (a mesh to shard over without chips)")
     args = ap.parse_args()
     if args.cpu_devices:
-        try:
-            from torchkafka_tpu.utils.devices import force_cpu_devices
-
-            force_cpu_devices(args.cpu_devices)
-        except RuntimeError:
-            pass  # backend already live; use whatever devices exist
+        force_cpu_devices(args.cpu_devices)
 
     broker = tk.InMemoryBroker()
     broker.create_topic(TOPIC, partitions=2)

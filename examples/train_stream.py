@@ -26,6 +26,7 @@ import optax
 
 import torchkafka_tpu as tk
 from torchkafka_tpu.models import TransformerConfig, make_train_step
+from torchkafka_tpu.utils.devices import enable_compile_cache
 
 TOPIC = "tokens"
 N_PARTS = 8
@@ -59,6 +60,7 @@ def make_consumer(broker: tk.InMemoryBroker) -> tk.MemoryConsumer:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=32)

@@ -156,8 +156,7 @@ TIER1 = ("pre_commit", "checkpoint_mid_write")
 
 
 def _spawn(mode: str, port: int, workdir: str, point: str, at: int):
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)  # the child configures CPU itself
+    env = dict(os.environ)  # the child configures CPU itself
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
     marker = os.path.join(workdir, "marker")

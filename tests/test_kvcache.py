@@ -708,7 +708,7 @@ class TestStaleTailInvariant:
 
 def _mesh(axes):
     """A host-device mesh over exactly prod(axes) of the 8 forced CPU
-    devices (conftest sets --xla_force_host_platform_device_count)."""
+    devices (conftest sets jax_num_cpu_devices)."""
     from torchkafka_tpu.parallel import make_mesh
 
     n = int(np.prod(list(axes.values())))
@@ -954,6 +954,31 @@ class TestBackendCapabilityErrors:
         assert bk.paged and bk.int8 and bk.kernel and bk.sharded
         d = bk.describe()
         assert d["layout"] == "paged" and d["data"] == 2 and d["tp"] == 2
+
+    def test_paged_kernel_gate_is_lane_alignment_on_tpu(self):
+        """What compiled Mosaic accepted on the v5e (PR 21): the block
+        size is the LANE dim of the [NB, K, bs] scale tiles, so 256 and
+        384 compile and 264 (% 8, the old gate) is refused. Host-only:
+        the resolver decides from the backend string."""
+        from torchkafka_tpu.kvcache import resolve_kv_backend
+        from torchkafka_tpu.models import TransformerConfig
+
+        cfg = TransformerConfig(d_model=256, n_heads=2, n_kv_heads=2)
+        assert cfg.head_dim == 128
+
+        def resolve(bs, kv_kernel):
+            return resolve_kv_backend(
+                cfg, kv_dtype="int8", kv_kernel=kv_kernel,
+                kv_pages=PagedKVConfig(block_size=bs, num_blocks=16),
+                max_len=4 * bs, slots=2, backend="tpu",
+            )
+
+        for bs in (256, 384):
+            assert resolve(bs, True).kernel and resolve(bs, "auto").kernel
+        with pytest.raises(ValueError, match=r"block_size=264 % 128"):
+            resolve(264, True)
+        auto = resolve(264, "auto")
+        assert not auto.kernel and "264" in auto.kernel_disabled_reason
 
 
 class TestFleetChaosDifferential:

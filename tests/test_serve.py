@@ -448,7 +448,11 @@ class TestStreamingGenerator:
             consumer, params, cfg, slots=2, prompt_len=P, max_new=MAX_NEW,
         )
         server.warmup()
-        r = server.decode_roofline(iters=2, windows=2)
+        # Off-chip the peak is the caller's to give: the device-kind
+        # lookup knows chips only, and this device is the CPU.
+        with pytest.raises(ValueError, match="no published peaks"):
+            server.decode_roofline(iters=2, windows=2)
+        r = server.decode_roofline(iters=2, windows=2, peak_hbm_gbs=819.0)
         # The slope between the two windows can be ~0/negative for a toy
         # model on CPU (both windows are dispatch noise); a degenerate
         # slope must be FLAGGED (numeric fields None), never published as
@@ -506,7 +510,7 @@ class TestStreamingGenerator:
         )
         server.warmup()
         before = np.asarray(server._pos).copy()
-        server.decode_roofline(iters=1, windows=1)
+        server.decode_roofline(iters=1, windows=1, peak_hbm_gbs=819.0)
         np.testing.assert_array_equal(np.asarray(server._pos), before)
         # And still serves correctly afterwards.
         assert len(list(server.run(max_records=4))) == 4

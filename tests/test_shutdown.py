@@ -114,7 +114,6 @@ class TestGracefulDrain:
         repo_root = str(pathlib.Path(__file__).resolve().parent.parent)
         env = dict(os.environ)
         env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-        env.pop("JAX_PLATFORMS", None)
         proc = subprocess.Popen(
             [sys.executable, str(script), str(out), str(ready)],
             env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,

@@ -140,8 +140,8 @@ class CommitToken:
     def commit_async(self, wait_for: Any = None) -> "Future[bool]":
         """Pipelined ``commit``: same barrier-then-commit, on the stream's
         single commit thread, so the training loop never stalls on the
-        step-retirement wait (which can be ~100 ms of pure latency on
-        remote/tunneled device transports). FIFO thread ⇒ commit order is
+        step-retirement wait (the step's whole device time, plus a scalar
+        fetch). FIFO thread ⇒ commit order is
         preserved; semantics are unchanged — offsets still only commit
         after THIS batch's step provably retired. The returned Future
         resolves to commit()'s bool (or raises BarrierError); the stream's

@@ -6,9 +6,11 @@ import argparse
 import json
 
 from torchkafka_tpu.harness.scenarios import SCENARIOS, run_scenario
+from torchkafka_tpu.utils.devices import enable_compile_cache, require_tpu
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="torchkafka_tpu benchmark harness")
     ap.add_argument("--scenario", type=int, choices=sorted(SCENARIOS), default=None,
                     help="which BASELINE scenario; default: all")
@@ -68,6 +70,10 @@ def main() -> None:
                     "(default: one block) — smaller bounds per-tick "
                     "prefill work, the decode-latency lever")
     args = ap.parse_args()
+    if args.model_scale:
+        # The zoo scales exist to be measured on the chip (rooflines, MFU);
+        # on anything else they would run for hours and report nothing.
+        require_tpu()
     if args.scenario:
         nums = [args.scenario]
     elif args.model_scale:

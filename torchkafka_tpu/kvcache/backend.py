@@ -131,7 +131,7 @@ def _kernel_probe_paged(cfg, block_size: int, on_tpu: bool) -> str | None:
     ):
         return (
             f"tiling: head_dim={cfg.head_dim} % 128, block_size="
-            f"{block_size} % 8, and block_size >= 256 required on TPU"
+            f"{block_size} % 128, and block_size >= 256 required on TPU"
         )
     return None
 

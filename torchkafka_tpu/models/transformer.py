@@ -42,7 +42,12 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from torchkafka_tpu.models.quant import QTensor, embed_rows, load_weight
-from torchkafka_tpu.ops.attention import mha, ring_attention, ulysses_attention
+from torchkafka_tpu.ops.attention import (
+    axis_is_manual,
+    mha,
+    ring_attention,
+    ulysses_attention,
+)
 from torchkafka_tpu.ops.xent import dense_softmax_xent, fused_softmax_xent
 
 
@@ -530,8 +535,6 @@ class Transformer:
         """Global RoPE positions. Inside a manual region over 'sp' (a
         pipeline stage) the layer sees only its sequence shard, so offset by
         the shard index; in the auto-sharded path jit sees the global view."""
-        from torchkafka_tpu.ops._compat import axis_is_manual
-
         if axis_is_manual("sp"):
             return lax.axis_index("sp") * local_len + jnp.arange(local_len)
         return jnp.arange(local_len)

@@ -115,8 +115,7 @@ def random_serving_params(
     }
 
     # ONE jitted program for the whole tree: per-leaf jits cost a separate
-    # compile each, and on remote-compile transports that is minutes of
-    # wall clock for what is seconds of device work.
+    # compile each for what is seconds of device work.
     def build(rng_key):
         keys = jax.random.split(rng_key, len(shapes) + 2)
         layers: dict = {

@@ -21,6 +21,8 @@ Public surface:
 
 from __future__ import annotations
 
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -32,7 +34,20 @@ logger = logging.getLogger(__name__)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "_decode.cpp")
-_SO = os.path.join(_HERE, "_tk_native" + (sysconfig.get_config_var("EXT_SUFFIX") or ".so"))
+_EXT = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
+
+
+def _so_path() -> str:
+    """The extension's path carries a digest of ``_decode.cpp``: a binary
+    is current iff its name matches the source beside it. Modification
+    times mean nothing after a copy or a checkout, and a stale binary
+    would otherwise be loaded in place of the source a commit ships."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_HERE, f"_tk_native_{digest}{_EXT}")
+
+
+_SO = _so_path()
 
 _native = None
 
@@ -46,6 +61,9 @@ def _build() -> bool:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(_SO + ".tmp", _SO)  # atomic: concurrent imports see whole file
+        for old in glob.glob(os.path.join(_HERE, f"_tk_native*{_EXT}")):
+            if old != _SO:  # binaries of earlier sources
+                os.remove(old)
         return True
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired, OSError) as e:
         detail = getattr(e, "stderr", b"")
@@ -58,9 +76,8 @@ def _build() -> bool:
 
 def _load() -> None:
     global _native
-    if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-        if not _build():
-            return
+    if not os.path.exists(_SO) and not _build():
+        return
     try:
         import importlib.util
 

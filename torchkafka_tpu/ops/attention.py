@@ -34,9 +34,14 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchkafka_tpu.ops._compat import shard_map  # noqa: E402
-
 _NEG_INF = -1e30  # finite sentinel: avoids -inf - -inf = nan in the recurrence
+
+
+def axis_is_manual(name: str) -> bool:
+    """True when tracing inside a shard_map manual region over ``name`` —
+    the guard the ring/ulysses wrappers and RoPE positioning use to avoid
+    nesting a second shard_map on a bound axis."""
+    return name in jax.sharding.get_abstract_mesh().manual_axes
 
 
 def mha(
@@ -351,8 +356,6 @@ def ulysses_attention(
             f"{k.shape[2]} must both be divisible by it — use "
             "ring_attention for indivisible head counts"
         )
-    from torchkafka_tpu.ops._compat import axis_is_manual
-
     body = functools.partial(
         _ulysses_local, axis_name=axis_name, axis_size=axis_size,
         causal=causal, use_flash=use_flash,
@@ -360,7 +363,7 @@ def ulysses_attention(
     if axis_is_manual(axis_name):
         return body(q, k, v)
     spec = P(None, axis_name, None, None)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -405,8 +408,6 @@ def ring_attention(
     axis_size = mesh.shape[axis_name]
     if axis_size == 1:
         return mha(q, k, v, causal=causal)
-    from torchkafka_tpu.ops._compat import axis_is_manual
-
     if axis_is_manual(axis_name):
         # Already inside a manual region over axis_name (e.g. a pipeline
         # stage that bound 'sp' alongside 'pp'): q/k/v are local shards and
@@ -424,7 +425,7 @@ def ring_attention(
         _ring_attention_local, axis_name=axis_name, axis_size=axis_size,
         causal=causal, use_flash=use_flash,
     )
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -41,14 +41,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU backend only; tests on CPU run the kernel in interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from torchkafka_tpu.ops.attention import mha
 
@@ -119,14 +112,11 @@ def _flash_kernel(
 
 
 def _scratch(shapes):
-    if pltpu is not None:
-        return [pltpu.VMEM(sh, jnp.float32) for sh in shapes]
-    return [jax.ShapeDtypeStruct(sh, jnp.float32) for sh in shapes]
+    return [pltpu.VMEM(sh, jnp.float32) for sh in shapes]
 
 
 def _smem_spec():
-    kw = {} if pltpu is None else {"memory_space": pltpu.SMEM}
-    return pl.BlockSpec((1,), lambda b, i, j: (0,), **kw)
+    return pl.BlockSpec((1,), lambda b, i, j: (0,), memory_space=pltpu.SMEM)
 
 
 def _offsets(q_offset, k_offset):
@@ -169,7 +159,7 @@ def _flash_fwd_bhsd(
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k
     )
-    vmem = {} if _VMEM is None else {"memory_space": _VMEM}
+    vmem = {"memory_space": pltpu.VMEM}
     qoff, koff = _offsets(q_offset, k_offset)
     kv = _kv_index(n_q_heads, n_kv_heads)
     return pl.pallas_call(
@@ -322,7 +312,7 @@ def _flash_bwd_bhsd(
         do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
     )  # [BH, Sq, 1]
 
-    vmem = {} if _VMEM is None else {"memory_space": _VMEM}
+    vmem = {"memory_space": pltpu.VMEM}
     qoff, koff = _offsets(q_offset, k_offset)
     kv = _kv_index(n_q_heads, n_kv_heads)
 
@@ -412,19 +402,11 @@ def _default_interpret() -> bool:
 
 def tpu_compiler_params(dimension_semantics: tuple) -> dict:
     """``{"compiler_params": ...}`` kwargs for a compiled-Mosaic
-    pallas_call, or ``{}`` when the TPU module is unavailable. One home
-    for the CompilerParams/TPUCompilerParams rename fallback (the class
-    was named TPUCompilerParams before jax 0.7) — shared by the flash,
-    qmatmul, and kvattn kernels."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:  # pragma: no cover
-        return {}
-    params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
+    pallas_call — shared by the qmatmul and kvattn kernels."""
     return {
-        "compiler_params": params_cls(dimension_semantics=dimension_semantics)
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=dimension_semantics
+        )
     }
 
 
@@ -568,27 +550,24 @@ def flash_attention_sharded(
     Requirements (the caller gates on these — Transformer falls back to
     the dense path otherwise): B divisible by data·fsdp, H and K by tp.
     Per-shard sequences that don't tile fall back to dense INSIDE the
-    shard, same math. Mesh axes not named here (sp/pp/ep) see the inputs
-    replicated, matching what GSPMD would do.
+    shard, same math. The region is manual over EVERY mesh axis: compiled
+    Mosaic refuses a kernel under a partially manual region ("cannot be
+    automatically partitioned"). Axes the spec does not name (sp/pp/ep)
+    see the inputs replicated, and each of their shards runs the same
+    attention.
     """
-    from torchkafka_tpu.ops._compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     batch_axes = tuple(a for a in ("data", "fsdp") if a in mesh.shape)
     tp = "tp" if "tp" in mesh.shape else None
     spec = P(batch_axes if batch_axes else None, None, tp, None)
-    manual = frozenset(batch_axes) | (frozenset({tp}) if tp else frozenset())
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             flash_attention, causal=causal, interpret=interpret
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        # Manual over ONLY the batch/head axes; any other mesh axes
-        # (sp/pp/ep) stay auto-sharded for GSPMD to manage around the
-        # kernel, matching the ring/ulysses wrappers' style.
-        axis_names=manual,
         check_vma=False,
     )
     return fn(q, k, v)

@@ -81,18 +81,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from torchkafka_tpu.ops.flash import _default_interpret, tpu_compiler_params
 
 _NEG_INF = -1e30
-# pl.ANY replaced pltpu.ANY (DeprecationWarning; the alias is slated for
-# removal) — fall back for older jax.
-_ANY = getattr(pl, "ANY", None) or (pltpu and pltpu.ANY)
 
 
 def _kvattn_kernel(
@@ -130,10 +123,13 @@ def _kvattn_kernel(
 
 
 def kernel_applicable(head_dim: int, max_len: int) -> bool:
-    """Compiled-Mosaic tiling constraints: lane-aligned head_dim and
-    sublane-aligned pool length (the (M, K)-trailing scale blocks need
-    M % 8; Dh is the lane dim of the payload blocks). Interpret mode
-    accepts anything; tests force it."""
+    """Shape gate of the dense-pool kernels on every backend:
+    lane-aligned head_dim (Dh is the lane dim of the payload blocks) and
+    a pool length that tiles by 8. Compiled Mosaic needs more for the
+    dynamic-length read — its [K, mb] scale slices put the M-block on the
+    LANE dim, so mb must be a multiple of 128 — which the serving probe
+    (kvcache/backend.py) enforces on TPU by requiring ``dynlen_block`` >=
+    256. Interpret mode accepts anything; tests force it."""
     return head_dim % 128 == 0 and max_len % 8 == 0
 
 
@@ -601,10 +597,10 @@ def int8_decode_attention_dynlen(
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos: (i, 0, 0, 0)),
         scratch_shapes=[
@@ -629,17 +625,13 @@ def int8_decode_attention_dynlen(
     return out.reshape(b, 1, h, dh)
 
 
-def _serving_shard_specs(mesh):
-    """(batch_axes, tp, manual) for the sharded decode-kernel wrappers:
-    slots over ``data``, kv/q heads over ``tp`` — exactly the dense slot
-    pool's ``kv_sharding`` axes, so the wrapped kernel reads the pool in
-    the layout serving already stores it in. ``fsdp``/``ep``/``pp`` axes
-    stay out of the manual region (weight-only axes; the kernel's
-    operands are replicated across them)."""
-    batch_axes = tuple(a for a in ("data",) if a in mesh.shape)
-    tp = "tp" if "tp" in mesh.shape else None
-    manual = frozenset(batch_axes) | (frozenset({tp}) if tp else frozenset())
-    return (batch_axes if batch_axes else None), tp, manual
+# The two sharded wrappers below are manual over EVERY mesh axis: compiled
+# Mosaic refuses a kernel under a partially manual shard_map region ("Mosaic
+# kernels cannot be automatically partitioned"; met on a 2x2 v5e mesh, PR
+# 21). Their specs name only ``data`` (slots) and ``tp`` (kv/q heads) — the
+# dense slot pool's ``kv_sharding`` axes — and simply do not mention
+# ``fsdp``/``ep``/``pp``: weight-only axes across which the kernel's
+# operands are replicated, so each of their shards runs the same read.
 
 
 def int8_decode_attention_dynlen_sharded(
@@ -663,21 +655,20 @@ def int8_decode_attention_dynlen_sharded(
     XLA read's layouts: q/pos/caches batch over ``data``, kv heads over
     ``tp``. Requirements (the capability probe gates on these): B
     divisible by data, H and K by tp."""
-    from torchkafka_tpu.ops._compat import shard_map
     from jax.sharding import PartitionSpec as P
 
-    bspec, tp, manual = _serving_shard_specs(mesh)
+    bspec = "data" if "data" in mesh.shape else None
+    tp = "tp" if "tp" in mesh.shape else None
     qspec = P(bspec, None, tp, None)   # [B, 1, H, Dh]
     cspec = P(bspec, tp, None, None)   # [B, K, M, Dh] K-major payloads
     sspec = P(bspec, tp, None)         # [B, K, M] scales
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             int8_decode_attention_dynlen, block=block, interpret=interpret
         ),
         mesh=mesh,
         in_specs=(qspec, cspec, sspec, cspec, sspec, P(bspec)),
         out_specs=qspec,
-        axis_names=manual,
         check_vma=False,
     )
     return fn(q, ck_q, ck_s, cv_q, cv_s, pos)
@@ -705,14 +696,14 @@ def int8_paged_decode_attention_sharded(
     paged path entirely — block pools are shared storage with no slot
     axis to split, and re-introducing data sharding at this kernel's
     boundary re-triggers the jax-0.4.x partitioned-concat miscompile
-    the rest of the program avoids. Each tp shard DMAs only live
-    blocks for its own heads; no collectives."""
-    from torchkafka_tpu.ops._compat import shard_map
+    the rest of the program avoids. The region is still manual over
+    every mesh axis (note above): the specs name only ``tp``, so each
+    data shard runs the same read on its replica. Each tp shard DMAs
+    only live blocks for its own heads; no collectives."""
     from jax.sharding import PartitionSpec as P
 
-    _bspec, tp, _manual = _serving_shard_specs(mesh)
-    manual = frozenset({tp}) if tp else frozenset()
-    if not manual:
+    tp = "tp" if "tp" in mesh.shape else None
+    if tp is None:
         # No tp axis: nothing to split — the plain kernel call inside
         # the (data-replicated) paged program is already correct.
         return int8_paged_decode_attention(
@@ -722,13 +713,12 @@ def int8_paged_decode_attention_sharded(
     qspec = P(None, None, tp, None)    # [B, 1, H, Dh]
     pspec = P(None, tp, None, None)    # [NB, K, bs, Dh] payload pools
     sspec = P(None, tp, None)          # [NB, K, bs] scale pools
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(int8_paged_decode_attention, interpret=interpret),
         mesh=mesh,
         in_specs=(qspec, pspec, sspec, pspec, sspec, P(None, None),
                   P(None)),
         out_specs=qspec,
-        axis_names=manual,
         check_vma=False,
     )
     return fn(q, pool_kq, pool_ks, pool_vq, pool_vs, table, pos)
@@ -838,13 +828,16 @@ def _kvattn_paged_kernel(
 
 def paged_kernel_applicable(head_dim: int, block_size: int) -> bool:
     """Compiled-Mosaic tiling constraints for the block-table read:
-    lane-aligned head_dim and sublane-aligned block size (the [K, bs]
-    scale tiles need bs % 8; Dh is the lane dim of the payload tiles).
+    lane-aligned head_dim (Dh is the lane dim of the payload tiles) AND
+    lane-aligned block size — the scale pools are [NB, K, bs], so bs is
+    the lane dim of every scale tile the kernel DMAs. Read off the v5e
+    (PR 21): bs = 256 and 384 compile; 264, 272, 288 and 320 are refused
+    ("Slice shape along dimension 2 must be aligned to tiling (128)").
     Interpret mode accepts anything; tests force it. Callers should
     additionally require a reasonable block size (>= 256) on TPU —
     skipping works at block granularity, but tiny blocks drown in
     per-block DMA/recurrence overhead (the dynlen_block lesson)."""
-    return head_dim % 128 == 0 and block_size % 8 == 0
+    return head_dim % 128 == 0 and block_size % 128 == 0
 
 
 def int8_paged_decode_attention(
@@ -886,10 +879,10 @@ def int8_paged_decode_attention(
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos, tbl: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
             (1, n_kv, rep, dh), lambda i, pos, tbl: (i, 0, 0, 0)

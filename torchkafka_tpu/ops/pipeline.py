@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from torchkafka_tpu.ops._compat import shard_map  # noqa: E402
-
 
 def gpipe(
     layer_fn: Callable[[jax.Array, Any], Any],
@@ -171,7 +169,7 @@ def gpipe(
         x_spec = P(None, None, *tuple(act_spec)[1:])
     else:
         x_spec = P()
-    result = shard_map(
+    result = jax.shard_map(
         stage_body,
         mesh=mesh,
         in_specs=(layer_specs, x_spec),
