@@ -165,6 +165,78 @@ class TestTracerDerivations:
         tr.tokens(r, 2)
         assert tr.slo.hist("itl").count == 2  # ITL still measured
 
+    def test_dispatched_admission_closes_ttft_at_the_first_sync(self):
+        """An admission that was only dispatched has no token the host
+        can see: TTFT closes at the first ``tokens`` event, seconds later
+        on a real chip, not at ``slot_active``."""
+        mc = ManualClock()
+        tr = RecordTracer(ObsConfig(clock=mc.now))
+        r = _rec()
+        tr.polled(r, replica=3)
+        mc.advance(0.050)
+        tr.slot_active(r, replica=3, dispatched=True)
+        assert tr.slo.hist("ttft").count == 0  # nothing surfaced yet
+        mc.advance(2.0)  # the admit program and the first tick block
+        tr.tokens(r, 5, replica=3)
+        assert tr.slo.hist("ttft").count == 1
+        assert tr.slo.hist("ttft").percentile(50) == pytest.approx(2.050)
+        assert tr.slo.hist("ttft", "replica", "3").count == 1
+        mc.advance(1.0)
+        tr.tokens(r, 4, replica=3)  # a later sync closes nothing more
+        tr.finished(r, 9, replica=3)
+        assert tr.slo.hist("ttft").count == 1
+        # The per-record view reads events alone and stops at the stamp.
+        assert tr.record_trace("t", 0, 0).ttft_s == pytest.approx(0.050)
+
+    def test_dispatched_flag_leaves_the_event_stream_alone(self):
+        def lifecycle(**kw):
+            mc = ManualClock()
+            tr = RecordTracer(ObsConfig(clock=mc.now))
+            r = _rec()
+            tr.polled(r)
+            mc.advance(0.050)
+            tr.slot_active(r, **kw)
+            mc.advance(2.0)
+            tr.tokens(r, 5)
+            tr.finished(r, 5)
+            tr.note_commit({("t", 0): 1})
+            return tr
+
+        plain, flagged = lifecycle(), lifecycle(dispatched=True)
+        assert list(plain.events) == list(flagged.events)  # times too
+        assert plain.slo.hist("ttft").percentile(50) == pytest.approx(0.050)
+        assert flagged.slo.hist("ttft").percentile(50) == pytest.approx(2.050)
+
+    def test_finished_closes_a_dispatched_ttft_without_token_events(self):
+        """A journal-served or tokenless finish still closes the
+        interval, and the burn monitor gets the same TTFT the histogram
+        did."""
+        seen = []
+
+        class Monitor:
+            def note_completed(self, lane, tenant, *, ttft_s, **_kw):
+                seen.append(ttft_s)
+
+        mc = ManualClock()
+        tr = RecordTracer(ObsConfig(clock=mc.now))
+        tr.attach_monitor(Monitor())
+        r = _rec()
+        tr.polled(r)
+        tr.slot_active(r, dispatched=True)
+        mc.advance(0.700)
+        tr.finished(r, 1)
+        tr.note_commit({("t", 0): 1})
+        assert tr.slo.hist("ttft").percentile(50) == pytest.approx(0.700)
+        assert seen == [pytest.approx(0.700)]
+
+    def test_warm_dispatched_admission_still_skips_ttft(self):
+        tr = RecordTracer(ObsConfig(clock=ManualClock().now))
+        r = _rec()
+        tr.polled(r)
+        tr.slot_active(r, warm=True, dispatched=True)
+        tr.tokens(r, 2)
+        assert tr.slo.hist("ttft").count == 0
+
     def test_ring_bound_and_drop_counter(self):
         tr = RecordTracer(ObsConfig(capacity=8, clock=ManualClock().now))
         for i in range(20):
@@ -449,6 +521,68 @@ def _prompts(n, seed=7):
     prompts = rng.integers(0, VOCAB, (n, P), dtype=np.int32)
     prompts[:, :5] = np.arange(5, dtype=np.int32)  # shared radix prefix
     return prompts
+
+
+class TestServedTTFT:
+    """The SLO's TTFT on a real server whose clock moves between the
+    admission's dispatch and the sync that surfaces its token."""
+
+    ADMIT_S, BLOCK_S = 0.25, 1.0
+
+    def _serve(self, model, **kw):
+        cfg, params = model
+        mc = ManualClock()
+        tr = RecordTracer(ObsConfig(clock=mc.now))
+        broker = tk.InMemoryBroker()
+        _topic(broker, _prompts(4))
+        consumer = tk.MemoryConsumer(broker, "p", group_id="g")
+        server = StreamingGenerator(
+            consumer, params, cfg, slots=4, prompt_len=P, max_new=MAX_NEW,
+            ticks_per_sync=4, tracer=tr, **kw,
+        )
+
+        def slow(fn, seconds):
+            def call(*a):
+                mc.advance(seconds)  # the program's device time
+                return fn(*a)
+            return call
+
+        if server._admit_fn is not None:
+            server._admit_fn = slow(server._admit_fn, self.ADMIT_S)
+        server._tick_fn = slow(server._tick_fn, self.BLOCK_S)
+        if getattr(server, "_tick_chunk_fn", None) is not None:
+            server._tick_chunk_fn = slow(server._tick_chunk_fn, self.BLOCK_S)
+        assert sum(1 for _ in server.run(max_records=4)) == 4
+        consumer.close()
+        return tr
+
+    def test_dense_ttft_spans_the_admission_and_the_first_block(self, model):
+        tr = self._serve(model)
+        ttft = tr.slo.hist("ttft")
+        assert ttft.count == 4
+        # Polled at 0; the admit program takes 0.25 s and the first block
+        # of ticks 1 s before the host sees a token. The stamp of
+        # ``slot_active`` is the dispatch's, 0.25 s in.
+        assert ttft.percentile(50) == pytest.approx(1.25)
+        assert ttft.percentile(99) == pytest.approx(1.25)
+        assert tr.record_trace("p", 0, 0).ttft_s == pytest.approx(0.25)
+
+    def test_chunked_ttft_still_closes_at_slot_active(self, model):
+        """The chunked path stamps ``slot_active`` after the sync of the
+        tick that sampled token 0: the histogram and the stamp agree."""
+        tr = self._serve(model, kv_pages=PAGES)
+        ttft = tr.slo.hist("ttft")
+        assert ttft.count == 4
+        for off in (0, 1):
+            view = tr.record_trace("p", 0, off)
+            assert view.ttft_s >= self.BLOCK_S
+            assert ttft.percentile(99) >= view.ttft_s
+        stamps = sorted(
+            tr.record_trace("p", part, off).ttft_s
+            for part in (0, 1) for off in (0, 1)
+        )
+        assert ttft.percentile(99) == pytest.approx(stamps[-1])
+        assert ttft.percentile(1) == pytest.approx(stamps[0])
 
 
 class TestTracedServingExactness:

@@ -25,6 +25,7 @@ from typing import Any
 import jax
 
 from torchkafka_tpu.errors import BarrierError
+from torchkafka_tpu.utils import tracing as xprof
 
 logger = logging.getLogger(__name__)
 
@@ -43,8 +44,7 @@ class CommitBarrier:
         self._calls = 0
         self._strict = strict
 
-    @staticmethod
-    def _retire(wait_for: Any) -> None:
+    def _retire(self, wait_for: Any) -> None:
         """Prove the step's device work is complete.
 
         ``block_until_ready`` plus — in strict mode — a one-scalar host
@@ -55,13 +55,17 @@ class CommitBarrier:
         that had not retired would break the at-least-once contract.
         Cost: one scalar D2H per batch.
         """
-        jax.block_until_ready(wait_for)
-        leaves = [
-            leaf for leaf in jax.tree_util.tree_leaves(wait_for)
-            if isinstance(leaf, jax.Array) and leaf.size > 0
-        ]
-        if leaves:
-            jax.device_get(leaves[0].ravel()[0])
+        with xprof.span(xprof.SPAN_COMMIT_WAIT):
+            jax.block_until_ready(wait_for)
+        if not self._strict:
+            return
+        with xprof.span(xprof.SPAN_COMMIT_FETCH):
+            leaves = [
+                leaf for leaf in jax.tree_util.tree_leaves(wait_for)
+                if isinstance(leaf, jax.Array) and leaf.size > 0
+            ]
+            if leaves:
+                jax.device_get(leaves[0].ravel()[0])
 
     def __call__(self, wait_for: Any = None) -> None:
         try:
@@ -70,17 +74,17 @@ class CommitBarrier:
                 # batch's results exist before its offsets become committable
                 # (the reference's yield-then-commit ordering,
                 # /root/reference/src/auto_commit.py:55-58, made device-aware).
-                if self._strict:
-                    self._retire(wait_for)
-                else:
-                    jax.block_until_ready(wait_for)
+                self._retire(wait_for)
             self._calls += 1
             # Executed for real in tests/test_pod.py (spawned jax.distributed
             # processes) — the cross-process commit coordination path.
             if jax.process_count() > 1:
                 from jax.experimental import multihost_utils
 
-                multihost_utils.sync_global_devices(f"{self._name}:{self._calls}")
+                with xprof.span(xprof.SPAN_COMMIT_SYNC):
+                    multihost_utils.sync_global_devices(
+                        f"{self._name}:{self._calls}"
+                    )
         except BarrierError:
             raise
         except Exception as e:
@@ -93,7 +97,4 @@ class CommitBarrier:
 class LocalBarrier(CommitBarrier):
     def __call__(self, wait_for: Any = None) -> None:
         if wait_for is not None:
-            if self._strict:
-                self._retire(wait_for)
-            else:
-                jax.block_until_ready(wait_for)
+            self._retire(wait_for)

@@ -28,6 +28,7 @@ from torchkafka_tpu.commit.barrier import CommitBarrier
 from torchkafka_tpu.errors import CommitFailedError
 from torchkafka_tpu.source.consumer import Consumer
 from torchkafka_tpu.source.records import TopicPartition
+from torchkafka_tpu.utils import tracing as xprof
 
 logger = logging.getLogger(__name__)
 
@@ -122,7 +123,8 @@ class CommitToken:
             return True
         t0 = time.perf_counter()
         try:
-            self._consumer.commit(self._offsets)
+            with xprof.span(xprof.SPAN_COMMIT_OFFSETS):
+                self._consumer.commit(self._offsets)
         except CommitFailedError as e:
             # Non-fatal by contract: the group rebalanced; records will be
             # re-delivered to the new partition owners.

@@ -12,7 +12,9 @@ the serving code's own phase transitions:
     prefill_queued   chunked admission reserved a slot + enqueued suffix
     chunk_scheduled  its first suffix tokens rode a fused chunk tick
     warm_resumed     a journal hint restored emitted tokens at admit
-    slot_active      first token exists (admit/prefill/activation done)
+    slot_active      admission dispatched (dense / legacy paged: the first
+                     token surfaces at the next host sync) or activated
+                     with its first token in hand (chunked, adopted, warm)
     tokens           a tick block produced n new tokens for its slot
     finished         generation retired (EOS or max_new), output emitted
     journal_served   finished entry re-served from a dead replica journal
@@ -245,7 +247,12 @@ class RecordTrace:
 
     @property
     def ttft_s(self) -> float | None:
-        """poll → first token (admission + queue + prefill, inclusive)."""
+        """poll → ``slot_active`` (admission + queue, and the prefill
+        where the stamp follows it). Events alone cannot tell an
+        admission that was only dispatched from one whose token is in
+        hand, so on the dense and legacy-paged paths this reads SHORT of
+        the SLO histogram's TTFT, which closes at the host sync that
+        surfaced the token."""
         t0, t1 = self._t(POLLED), self._t(SLOT_ACTIVE)
         return None if t0 is None or t1 is None else max(0.0, t1 - t0)
 
@@ -273,15 +280,17 @@ class RecordTrace:
 class _Lifecycle:
     """Open per-record state between POLLED and a terminal stage."""
 
-    __slots__ = ("lane", "tenant", "replica", "polled_t", "active_t",
-                 "last_tok_t", "finished", "tokens", "warm", "queue_wait")
+    __slots__ = ("lane", "tenant", "replica", "polled_t", "first_tok_t",
+                 "ttft_open", "last_tok_t", "finished", "tokens", "warm",
+                 "queue_wait")
 
     def __init__(self, lane: str, tenant: str, replica, t: float) -> None:
         self.lane = lane
         self.tenant = tenant
         self.replica = replica
         self.polled_t = t
-        self.active_t: float | None = None
+        self.first_tok_t: float | None = None  # a first token in hand
+        self.ttft_open = False  # admission dispatched, token not surfaced
         self.last_tok_t: float | None = None
         self.finished = False
         self.tokens = 0
@@ -420,28 +429,41 @@ class RecordTracer:
                 ("replica", replica), ("tokens_restored", tokens_restored),
             ))
 
-    def slot_active(self, rec: Record, replica=None, warm: bool = False) -> None:
-        """First token exists for this record: admit dispatch returned
-        (dense / legacy-paged) or the activation chunk tick landed
-        (chunked). Closes the TTFT interval."""
+    def slot_active(self, rec: Record, replica=None, warm: bool = False,
+                    dispatched: bool = False) -> None:
+        """Admission dispatched for this record. ``dispatched=True`` (the
+        dense and legacy-paged paths) says the admit program was only
+        DISPATCHED: its token exists for the host at the next sync, and
+        the TTFT interval closes at the first ``tokens`` or ``finished``
+        event. Otherwise the first token is in hand (the chunked path
+        stamps after the sync of its activating tick; an adoption brings
+        its token) and TTFT closes here. The event is the same either
+        way."""
         with self._lock:
             life = self._life(rec, replica)
             life.replica = replica if replica is not None else life.replica
             t = self._emit(SLOT_ACTIVE, rec.topic, rec.partition, rec.offset, (
                 ("replica", replica), ("warm", warm),
             ))
-            life.active_t = t
             life.last_tok_t = t
             life.tokens = max(life.tokens, 1)
             life.warm = warm
-            if not warm:
-                # A warm resume's "first token" was decoded by the dead
-                # replica pre-kill; timing it from THIS poll would report
-                # a fabricated (and negative-looking) TTFT.
-                self.slo.observe(
-                    "ttft", max(0.0, t - life.polled_t), lane=life.lane,
-                    tenant=life.tenant, replica=life.replica,
-                )
+            # A warm resume's "first token" was decoded by the dead
+            # replica pre-kill; timing it from THIS poll would report
+            # a fabricated (and negative-looking) TTFT.
+            life.ttft_open = dispatched and not warm
+            if not warm and not dispatched:
+                self._first_token(life, t)
+
+    def _first_token(self, life: _Lifecycle, t: float) -> None:
+        """The record's first token reached the host at ``t``: close TTFT
+        (lock held)."""
+        life.ttft_open = False
+        life.first_tok_t = t
+        self.slo.observe(
+            "ttft", max(0.0, t - life.polled_t), lane=life.lane,
+            tenant=life.tenant, replica=life.replica,
+        )
 
     def tokens(self, rec: Record, n_new: int, replica=None) -> None:
         """A tick block surfaced ``n_new`` new tokens for this record
@@ -451,10 +473,13 @@ class RecordTracer:
             return
         with self._lock:
             life = self._life(rec, replica)
+            t = None
             if self.config.token_events:
-                self._emit(TOKENS, rec.topic, rec.partition, rec.offset, (
+                t = self._emit(TOKENS, rec.topic, rec.partition, rec.offset, (
                     ("n", n_new), ("replica", replica),
                 ))
+            if life.ttft_open:
+                self._first_token(life, self._clock() if t is None else t)
             if life.last_tok_t is not None:
                 per_tok = max(0.0, self._clock() - life.last_tok_t) / n_new
                 self.slo.observe_many(
@@ -468,9 +493,11 @@ class RecordTracer:
         with self._lock:
             life = self._life(rec, replica)
             life.finished = True
-            self._emit(FINISHED, rec.topic, rec.partition, rec.offset, (
+            t = self._emit(FINISHED, rec.topic, rec.partition, rec.offset, (
                 ("replica", replica), ("tokens", n_tokens),
             ))
+            if life.ttft_open:
+                self._first_token(life, t)
 
     def journal_served(self, rec: Record, n_tokens: int, replica=None) -> None:
         with self._lock:
@@ -528,8 +555,8 @@ class RecordTracer:
                 if self._monitor is not None:
                     ttft = (
                         None
-                        if life.warm or life.active_t is None
-                        else max(0.0, life.active_t - life.polled_t)
+                        if life.warm or life.first_tok_t is None
+                        else max(0.0, life.first_tok_t - life.polled_t)
                     )
                     self._monitor.note_completed(
                         life.lane, life.tenant, ttft_s=ttft, e2e_s=e2e,

@@ -182,6 +182,7 @@ def _flash_fwd_bhsd(
         ],
         scratch_shapes=_scratch([(block_q, d), (block_q, 128), (block_q, 128)]),
         interpret=interpret,
+        name="tk_flash_fwd",
     )(q, k, v, qoff, koff)
 
 
@@ -344,6 +345,7 @@ def _flash_bwd_bhsd(
         out_specs=qd(lambda b, i, j: (b, i, 0)),
         scratch_shapes=_scratch([(block_q, d)]),
         interpret=interpret,
+        name="tk_flash_bwd_dq",
     )(q, k, v, do, lse, delta, qoff, koff)
 
     # dk/dv are written PER Q-HEAD (grid rows would race on a shared kv row
@@ -373,6 +375,7 @@ def _flash_bwd_bhsd(
         ],
         scratch_shapes=_scratch([(block_k, d), (block_k, d)]),
         interpret=interpret,
+        name="tk_flash_bwd_dkv",
     )(q, k, v, do, lse, delta, qoff, koff)
     return dq, dk, dv
 

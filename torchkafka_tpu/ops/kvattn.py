@@ -244,6 +244,7 @@ def int8_decode_attention_kmajor(
         out_specs=pl.BlockSpec((bb, n_kv, rep, dh), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
         interpret=interpret,
+        name="tk_kvattn_kmajor",
         **kw,
     )(qg, ck_q, ck_s.astype(jnp.float32), cv_q, cv_s.astype(jnp.float32),
       mask3)
@@ -293,6 +294,7 @@ def int8_decode_attention(
         out_specs=pl.BlockSpec((1, n_kv, rep, dh), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
         interpret=interpret,
+        name="tk_kvattn",
         **kw,
     )(qg, ck_q, ck_s.astype(jnp.float32), cv_q, cv_s.astype(jnp.float32),
       mask3)
@@ -619,6 +621,7 @@ def int8_decode_attention_dynlen(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
         interpret=interpret,
+        name="tk_kvattn_dynlen",
         **kw,
     )(pos.astype(jnp.int32), qg, ck_q, ck_s.astype(jnp.float32), cv_q,
       cv_s.astype(jnp.float32))
@@ -903,6 +906,7 @@ def int8_paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
         interpret=interpret,
+        name="tk_kvattn_paged",
         **kw,
     )(pos.astype(jnp.int32), table.astype(jnp.int32), qg, pool_kq,
       pool_ks.astype(jnp.float32), pool_vq, pool_vs.astype(jnp.float32))

@@ -146,6 +146,7 @@ def quantized_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=_scratch([(bm, bn)]),
         interpret=interpret,
+        name="tk_qmatmul",
         **kw,
     )(x2, q, scale.astype(jnp.float32))
     return out2.reshape(*lead, n)

@@ -44,6 +44,7 @@ from torchkafka_tpu.source.consumer import Consumer
 from torchkafka_tpu.transform.batcher import Batch, Batcher
 from torchkafka_tpu.transform.processor import Processor
 from torchkafka_tpu.utils.metrics import StreamMetrics
+from torchkafka_tpu.utils import tracing as xprof
 from torchkafka_tpu.utils.tracing import ingest_lag_ms
 
 _logger = logging.getLogger(__name__)
@@ -261,10 +262,13 @@ class KafkaStream:
     def _to_dev(self, batch: Batch) -> Batch:
         """Move a host batch toward the device (async dispatch)."""
         if self._to_device:
-            if self._mesh is not None:
-                data = global_batch(batch.data, self._mesh, self._data_axis)
-            else:
-                data = jax.tree_util.tree_map(jax.device_put, batch.data)
+            with xprof.span(xprof.SPAN_STREAM_TO_DEVICE):
+                if self._mesh is not None:
+                    data = global_batch(
+                        batch.data, self._mesh, self._data_axis
+                    )
+                else:
+                    data = jax.tree_util.tree_map(jax.device_put, batch.data)
             batch = Batch(data=data, valid_count=batch.valid_count, offsets=batch.offsets)
         self.metrics.batches.add(1)
         return batch
@@ -383,9 +387,11 @@ class KafkaStream:
         try:
             while not self._stop.is_set():
                 try:
-                    records = self._consumer.poll(
-                        max_records=self._max_poll, timeout_ms=self._poll_timeout_ms
-                    )
+                    with xprof.span(xprof.SPAN_STREAM_POLL):
+                        records = self._consumer.poll(
+                            max_records=self._max_poll,
+                            timeout_ms=self._poll_timeout_ms,
+                        )
                 except ConsumerClosedError:
                     break  # clean end: consumer closed under us
                 if not records:
@@ -396,7 +402,9 @@ class KafkaStream:
                         break
                     continue
                 last_data = monotonic()
-                for out in self._process_chunk(records):
+                with xprof.span(xprof.SPAN_STREAM_TRANSFORM):
+                    outs = self._process_chunk(records)
+                for out in outs:
                     self._ship(out)
             for tail in self._batcher.flush_tails():
                 self._ship(tail)
@@ -416,16 +424,19 @@ class KafkaStream:
             if self._stop.is_set():
                 raise StopIteration
             try:
-                records = self._consumer.poll(
-                    max_records=self._max_poll, timeout_ms=self._poll_timeout_ms
-                )
+                with xprof.span(xprof.SPAN_STREAM_POLL):
+                    records = self._consumer.poll(
+                        max_records=self._max_poll,
+                        timeout_ms=self._poll_timeout_ms,
+                    )
             except ConsumerClosedError:
                 records = []
                 self._stop.set()
             if records:
                 self._idle_since = None
                 try:
-                    self._ready.extend(self._process_chunk(records))
+                    with xprof.span(xprof.SPAN_STREAM_TRANSFORM):
+                        self._ready.extend(self._process_chunk(records))
                 except BaseException as e:  # noqa: BLE001 - sticky, then re-raised
                     # Same sticky-death contract as the threaded path: a
                     # processor error ENDS the stream. Without this, a
@@ -464,17 +475,18 @@ class KafkaStream:
         if not self._started:
             self._started = True
             self._thread.start()
-        while True:
-            try:
-                item = self._queue.get(timeout=0.5)
-                break
-            except queue.Empty:
-                if self._error is not None:
-                    self._exhausted = True
-                    raise self._error
-                if self._stop.is_set():
-                    self._exhausted = True
-                    raise StopIteration
+        with xprof.span(xprof.SPAN_STREAM_NEXT):
+            while True:
+                try:
+                    item = self._queue.get(timeout=0.5)
+                    break
+                except queue.Empty:
+                    if self._error is not None:
+                        self._exhausted = True
+                        raise self._error
+                    if self._stop.is_set():
+                        self._exhausted = True
+                        raise StopIteration
         if item is _END:
             self._exhausted = True
             if self._error is not None:

@@ -268,13 +268,29 @@ class ServeMetrics:
         # window the moment the watermark passes them
         self.commit_latency = LatencyHistogram()  # full commit path: output
         # flush + durability waits + offset commit (see _commit docstring)
-        self.slot_occupancy = Gauge()  # active slots / pool size, last tick
+        self.slot_occupancy = Gauge()  # active slots / pool size at the
+        # LAST sync only (a held slot counts, past its budget or not); the
+        # share over a window is slot_ticks_served / slot_ticks_run
         # Per-tick serving step time (host-observed: chunk pack + device
         # dispatch + sync) and tokens surfaced per tick block — the
         # device-side "where did the tick go" companion to the obs
         # layer's host-side record spans.
         self.tick_time = LatencyHistogram()
-        self.tokens_per_tick = Gauge()
+        self.tokens_per_tick = Gauge()  # tokens the LAST tick block surfaced
+        # Where the scheduler wastes work — cumulative (never reset), so a
+        # reader takes the difference over its own window.
+        self.slot_ticks_run = RateMeter()  # slots x ticks of every tick
+        # block dispatched: what the device was asked to decode
+        self.slot_ticks_served = RateMeter()  # tokens those ticks surfaced,
+        # after the budget clamp (a slot's first token is its admission's,
+        # not a tick's): idle slots and slots past their budget tick for
+        # nothing
+        self.admit_calls = RateMeter()  # admit_records calls that prefilled
+        self.admit_rows = RateMeter()  # rows admitted by a prefill
+        self.admit_rows_prefilled = RateMeter()  # rows the prefill programs
+        # ran: every slot on the dense path (the admit program prefills
+        # the whole [slots, prompt] batch whatever its mask), the admitted
+        # rows alone on the paths that prefill row by row
         self.output_capped = RateMeter()  # slots force-finished by a
         # per-record output budget (max_new_of) at sync granularity
         # Paged prefix cache (kv_pages=, torchkafka_tpu/kvcache): all zero
@@ -442,6 +458,13 @@ class ServeMetrics:
             "ticks": self.tick_time.count,
             "step_time": self.tick_time.summary(),
             "tokens_per_tick": round(self.tokens_per_tick.value, 2),
+            "scheduler": {
+                "slot_ticks_run": self.slot_ticks_run.count,
+                "slot_ticks_served": self.slot_ticks_served.count,
+                "admit_calls": self.admit_calls.count,
+                "admit_rows": self.admit_rows.count,
+                "admit_rows_prefilled": self.admit_rows_prefilled.count,
+            },
             "output_capped": self.output_capped.count,
             "prefix_cache": self.cache_summary(),
             "tenant_cache": self.tenant_cache_summary(),
@@ -571,6 +594,10 @@ class ServeMetrics:
                 ('percentile="p99"', s["step_time"]["p99_ms"]),
             ]),
             ("tokens_per_tick", "gauge", s["tokens_per_tick"]),
+            *(
+                (f"{name}_total", "counter", value)
+                for name, value in s["scheduler"].items()
+            ),
             ("output_capped_total", "counter", s["output_capped"]),
             ("tenant_prefix_cache_hits_total", "counter", [
                 (format_labels(tenant=t), v["hits"])
@@ -2676,6 +2703,13 @@ class StreamingGenerator:
         self._sync_tier_metrics()
         admitted = int(admit_mask.sum())
         filled = admitted + len(resumed) + len(adopted) + reserved
+        # Both paged modes prefill row by row: no row is prefilled that
+        # was not admitted (an adoption prefills nothing here).
+        prefilled = filled - len(adopted)
+        if prefilled:
+            self.metrics.admit_calls.add(1)
+            self.metrics.admit_rows.add(prefilled)
+            self.metrics.admit_rows_prefilled.add(prefilled)
         if filled:
             if in_flight > 0:
                 self.metrics.readmissions.add(filled)
@@ -2704,7 +2738,8 @@ class StreamingGenerator:
             if self._tracer is not None:
                 for i in slot_ids:
                     self._tracer.slot_active(
-                        self._slot_rec[i], replica=self._trace_replica
+                        self._slot_rec[i], replica=self._trace_replica,
+                        dispatched=True,
                     )
         if resumed or adopted:
             res_mask = np.zeros((B,), bool)
@@ -3337,87 +3372,102 @@ class StreamingGenerator:
         paged mode, where pool pressure can also DEFER records — call
         with an empty list to re-offer the deferred backlog)."""
         if self._kv_pages is not None:
-            return self._admit_records_paged(records)
-        free = [i for i in range(self._slots) if not self._active[i]]
-        if len(records) > len(free):
-            raise ValueError(
-                f"offered {len(records)} records with {len(free)} free slots"
-            )
-        in_flight = self._slots - len(free)
-        B, W = self._slots, self._key_width
-        prompts = np.zeros((B, self._prompt_len), np.int32)
-        admit_mask = np.zeros((B,), bool)
-        keys_np = np.zeros((B, W), np.uint32)
-        key_mask = np.zeros((B,), bool)
-        queue = list(records)
-        slot_iter = iter(free)
-        resumed = 0
-        journal_dirty = False
-        while True:
-            nxt = self._next_decodable(queue)
-            if nxt is None:
-                break
-            rec, toks = nxt
-            kd = self._record_key_data(rec)
-            hint = self._take_hint(rec)
-            if hint is not None and hint.finished:
-                # The dead replica finished this completion but never
-                # committed it: re-serve the journaled tokens verbatim at
-                # the next step — zero re-decode, byte-identical output.
-                out = np.asarray(hint.tokens, np.int32)
-                self._journal_ready.append((rec, out))
-                self.metrics.journal_served.add(1)
-                if self._tracer is not None:
-                    self._tracer.journal_served(
-                        rec, len(out), replica=self._trace_replica
-                    )
+            if not self._chunked:
+                # Legacy paged admission dispatches a prefill per record,
+                # each under its own tk_serve:admit.
+                return self._admit_records_paged(records)
+            # Chunked admission dispatches no prefill (the fused tick
+            # carries it): the whole call is preparation.
+            with xprof.span(xprof.SPAN_ADMIT_PREP):
+                return self._admit_records_paged(records)
+        with xprof.span(xprof.SPAN_ADMIT_PREP):
+            free = [i for i in range(self._slots) if not self._active[i]]
+            if len(records) > len(free):
+                raise ValueError(
+                    f"offered {len(records)} records with {len(free)} "
+                    "free slots"
+                )
+            in_flight = self._slots - len(free)
+            B, W = self._slots, self._key_width
+            prompts = np.zeros((B, self._prompt_len), np.int32)
+            admit_mask = np.zeros((B,), bool)
+            keys_np = np.zeros((B, W), np.uint32)
+            key_mask = np.zeros((B,), bool)
+            queue = list(records)
+            slot_iter = iter(free)
+            resumed = 0
+            journal_dirty = False
+            while True:
+                nxt = self._next_decodable(queue)
+                if nxt is None:
+                    break
+                rec, toks = nxt
+                kd = self._record_key_data(rec)
+                hint = self._take_hint(rec)
+                if hint is not None and hint.finished:
+                    # The dead replica finished this completion but never
+                    # committed it: re-serve the journaled tokens verbatim at
+                    # the next step — zero re-decode, byte-identical output.
+                    out = np.asarray(hint.tokens, np.int32)
+                    self._journal_ready.append((rec, out))
+                    self.metrics.journal_served.add(1)
+                    if self._tracer is not None:
+                        self._tracer.journal_served(
+                            rec, len(out), replica=self._trace_replica
+                        )
+                    if self._journal is not None:
+                        self._journal_record(
+                            rec, hint.key_data or kd, out, True
+                        )
+                        journal_dirty = True
+                    continue
+                i = next(slot_iter, None)
+                if i is None:
+                    # Unreachable under the caller contract (records <= free
+                    # slots; finished hints consume none).
+                    raise RuntimeError("admission ran out of free slots")
+                key_np = (
+                    np.asarray(hint.key_data, np.uint32)
+                    if hint is not None and hint.key_data is not None else kd
+                )
+                keys_np[i] = key_np
+                key_mask[i] = True
+                if hint is not None:
+                    self._resume_into_slot(i, rec, toks, hint, key_np)
+                    resumed += 1
+                    journal_dirty = journal_dirty or self._journal is not None
+                    continue
+                prompts[i] = toks
+                self._slot_rec[i] = rec
+                admit_mask[i] = True
+                self._active[i] = True
+                self._slot_emitted[i] = 0
+                self._slot_journaled[i] = 0
                 if self._journal is not None:
-                    self._journal_record(rec, hint.key_data or kd, out, True)
+                    self._journal_record(rec, kd, (), False)
                     journal_dirty = True
-                continue
-            i = next(slot_iter, None)
-            if i is None:
-                # Unreachable under the caller contract (records <= free
-                # slots; finished hints consume none).
-                raise RuntimeError("admission ran out of free slots")
-            key_np = (
-                np.asarray(hint.key_data, np.uint32)
-                if hint is not None and hint.key_data is not None else kd
-            )
-            keys_np[i] = key_np
-            key_mask[i] = True
-            if hint is not None:
-                self._resume_into_slot(i, rec, toks, hint, key_np)
-                resumed += 1
-                journal_dirty = journal_dirty or self._journal is not None
-                continue
-            prompts[i] = toks
-            self._slot_rec[i] = rec
-            admit_mask[i] = True
-            self._active[i] = True
-            self._slot_emitted[i] = 0
-            self._slot_journaled[i] = 0
-            if self._journal is not None:
-                self._journal_record(rec, kd, (), False)
-                journal_dirty = True
-        admitted = int(admit_mask.sum())
-        filled = admitted + resumed
-        if filled:
-            if in_flight > 0:
-                # Slots refilled while other generations were mid-flight:
-                # the observable that distinguishes continuous batching
-                # from lockstep waves.
-                self.metrics.readmissions.add(filled)
-            self._slot_keys = jnp.where(
-                jnp.asarray(key_mask)[:, None], jnp.asarray(keys_np),
-                self._slot_keys,
-            )
+            admitted = int(admit_mask.sum())
+            filled = admitted + resumed
+            if filled:
+                if in_flight > 0:
+                    # Slots refilled while other generations were mid-flight:
+                    # the observable that distinguishes continuous batching
+                    # from lockstep waves.
+                    self.metrics.readmissions.add(filled)
+                self._slot_keys = jnp.where(
+                    jnp.asarray(key_mask)[:, None], jnp.asarray(keys_np),
+                    self._slot_keys,
+                )
+            if admitted:
+                operands = (
+                    jnp.asarray(prompts), jnp.asarray(admit_mask),
+                    jnp.asarray(keys_np),
+                )
         if admitted:
             with xprof.span(xprof.SPAN_ADMIT):
                 out = self._admit_fn(
                     self._caches, self._last_tok, self._pos, self._gen,
-                    jnp.asarray(prompts), jnp.asarray(admit_mask),
-                    jnp.asarray(keys_np),
+                    *operands,
                 )
             # Rebind self state after every dispatch: admit/tick DONATE
             # the pool, so the old self._caches handles are dead buffers —
@@ -3428,8 +3478,18 @@ class StreamingGenerator:
             if self._tracer is not None:
                 for i in np.nonzero(admit_mask)[0]:
                     self._tracer.slot_active(
-                        self._slot_rec[i], replica=self._trace_replica
+                        self._slot_rec[i], replica=self._trace_replica,
+                        dispatched=True,
                     )
+        if filled:
+            # The dense program prefills every row of its [slots, prompt]
+            # batch, whatever the mask; a warm resume prefills its one row
+            # in a dispatch of its own.
+            self.metrics.admit_calls.add(1)
+            self.metrics.admit_rows.add(filled)
+            self.metrics.admit_rows_prefilled.add(
+                (B if admitted else 0) + resumed
+            )
         if journal_dirty:
             self._journal.flush()
         return filled
@@ -3562,8 +3622,9 @@ class StreamingGenerator:
         completions: list[tuple[Record, np.ndarray]] = []
         if self._journal_ready:
             ready, self._journal_ready = self._journal_ready, []
-            for rec, out in ready:
-                self._retire_completion(rec, out, completions)
+            with xprof.span(xprof.SPAN_RETIRE):
+                for rec, out in ready:
+                    self._retire_completion(rec, out, completions)
         run_chunk = self._chunked and bool(self._prefill_queue)
         if self._active.any() or run_chunk:
             self._tick_counter += 1
@@ -3611,104 +3672,11 @@ class StreamingGenerator:
                 )
             self.metrics.tick_time.observe(time.perf_counter() - tick_t0)
             crash_hook("mid_tick")
-            self.metrics.slot_occupancy.set(float(self._active.mean()))
-            if self._max_new_of is not None:
-                # device_get may hand back non-writable views; the budget
-                # clamp below mutates the done/count mirrors.
-                done_h = np.array(done_h)
-                n_out_h = np.array(n_out_h)
-            # Per-slot emitted-token mirrors: decoded-token accounting
-            # (the cold-vs-warm replay differential) and the journal's
-            # token cadence both read them. Counted BEFORE retirement so
-            # a completing slot's final tokens are journaled while its
-            # record is still attached.
-            journal_dirty = False
-            decoded = 0
-            for i in np.nonzero(self._active)[0]:
-                cnt = int(
-                    n_out_h[i] if done_h[i]
-                    else pos_h[i] - self._prompt_len + 1
+            with xprof.span(xprof.SPAN_RETIRE):
+                self._retire_block(
+                    done_h, n_out_h, gen_h, pos_h, completions, finishers,
+                    run_chunk,
                 )
-                if self._max_new_of is not None:
-                    budget = self._max_new_of(self._slot_rec[i])
-                    if budget is not None:
-                        budget = max(1, min(int(budget), self._max_new))
-                        if cnt >= budget:
-                            # Budget reached (tick blocks may overshoot
-                            # by up to ticks_per_sync - 1 tokens; the
-                            # overshoot is truncated): force-finish this
-                            # slot exactly like a device done.
-                            cnt = budget
-                            if not done_h[i]:
-                                self.metrics.output_capped.add(1)
-                            done_h[i] = True
-                            n_out_h[i] = budget
-                new_toks = cnt - int(self._slot_emitted[i])
-                decoded += new_toks
-                if self._tracer is not None and new_toks > 0:
-                    self._tracer.tokens(
-                        self._slot_rec[i], new_toks,
-                        replica=self._trace_replica,
-                    )
-                self._slot_emitted[i] = cnt
-                if self._journal is not None:
-                    rec = self._slot_rec[i]
-                    if done_h[i]:
-                        self._journal.finish(rec, gen_h[i, :cnt])
-                        journal_dirty = True
-                    elif (
-                        cnt - int(self._slot_journaled[i])
-                        >= self._journal.cadence
-                    ):
-                        self._journal.progress(rec, gen_h[i, :cnt])
-                        self._slot_journaled[i] = cnt
-                        journal_dirty = True
-            if decoded > 0:
-                self.metrics.decoded_tokens.add(decoded)
-            self.metrics.tokens_per_tick.set(float(decoded))
-            if journal_dirty:
-                # Synchronous at the cadence point: the whole point is
-                # that a SIGKILL one instruction later finds these tokens
-                # on disk.
-                self._journal.flush()
-            if done_h.any():
-                for i in np.nonzero(done_h)[0]:
-                    rec = self._slot_rec[i]
-                    assert rec is not None
-                    self._active[i] = False
-                    self._slot_rec[i] = None
-                    self._slot_emitted[i] = 0
-                    self._slot_journaled[i] = 0
-                    if self._kv_pages is not None:
-                        # Unpin the slot's blocks: uncached ones return to
-                        # the free list; cached prefix blocks stay alive on
-                        # the radix tree's own reference. The row falls back
-                        # to the sink so this slot's frozen-position tick
-                        # writes can never touch a re-allocated block.
-                        self._release_slot_blocks(i)
-                    out = gen_h[i, : n_out_h[i]].copy()
-                    self._retire_completion(rec, out, completions)
-                if self._kv_pages is not None:
-                    self._caches = self._paged_set_table(
-                        self._caches, self._device_table()
-                    )
-                    self.metrics.cache_pool_occupancy.set(
-                        self._kv_alloc.occupancy()
-                    )
-            if finishers:
-                # AFTER the done bookkeeping above (which must see the
-                # pre-activation active set and its fetched state):
-                # completed prefills activate for the NEXT tick.
-                self._activate_chunk_finishers(finishers)
-                if self._prefill_role:
-                    # Disaggregated prefill: nothing decodes here — cut
-                    # the freshly activated slots into handoffs and free
-                    # them before any decode tick could run.
-                    self._harvest_prefilled(finishers)
-            if run_chunk:
-                self.metrics.admission_queue_tokens.set(float(sum(
-                    len(e.seq) - e.off for e in self._prefill_queue
-                )))
         if (
             completions
             and self._uncommitted >= self._commit_every
@@ -3716,6 +3684,115 @@ class StreamingGenerator:
         ):
             self._uncommitted = 0
         return completions
+
+    def _retire_block(self, done_h, n_out_h, gen_h, pos_h, completions,
+                      finishers, run_chunk: bool) -> None:
+        """What the host does with a tick block's fetched state, from the
+        sync's return on: the per-record budget clamp, token accounting
+        (metrics, tracer, journal), retirement of finished slots into
+        ``completions`` and activation of completed chunk prefills."""
+        self.metrics.slot_occupancy.set(float(self._active.mean()))
+        if self._max_new_of is not None:
+            # device_get may hand back non-writable views; the budget
+            # clamp below mutates the done/count mirrors.
+            done_h = np.array(done_h)
+            n_out_h = np.array(n_out_h)
+        # Per-slot emitted-token mirrors: decoded-token accounting
+        # (the cold-vs-warm replay differential) and the journal's
+        # token cadence both read them. Counted BEFORE retirement so
+        # a completing slot's final tokens are journaled while its
+        # record is still attached.
+        journal_dirty = False
+        decoded = 0
+        first_tokens = 0  # slots surfacing their admission's own token
+        for i in np.nonzero(self._active)[0]:
+            cnt = int(
+                n_out_h[i] if done_h[i]
+                else pos_h[i] - self._prompt_len + 1
+            )
+            if self._max_new_of is not None:
+                budget = self._max_new_of(self._slot_rec[i])
+                if budget is not None:
+                    budget = max(1, min(int(budget), self._max_new))
+                    if cnt >= budget:
+                        # Budget reached (tick blocks may overshoot
+                        # by up to ticks_per_sync - 1 tokens; the
+                        # overshoot is truncated): force-finish this
+                        # slot exactly like a device done.
+                        cnt = budget
+                        if not done_h[i]:
+                            self.metrics.output_capped.add(1)
+                        done_h[i] = True
+                        n_out_h[i] = budget
+            new_toks = cnt - int(self._slot_emitted[i])
+            decoded += new_toks
+            first_tokens += int(self._slot_emitted[i] == 0)
+            if self._tracer is not None and new_toks > 0:
+                self._tracer.tokens(
+                    self._slot_rec[i], new_toks,
+                    replica=self._trace_replica,
+                )
+            self._slot_emitted[i] = cnt
+            if self._journal is not None:
+                rec = self._slot_rec[i]
+                if done_h[i]:
+                    self._journal.finish(rec, gen_h[i, :cnt])
+                    journal_dirty = True
+                elif (
+                    cnt - int(self._slot_journaled[i])
+                    >= self._journal.cadence
+                ):
+                    self._journal.progress(rec, gen_h[i, :cnt])
+                    self._slot_journaled[i] = cnt
+                    journal_dirty = True
+        if decoded > 0:
+            self.metrics.decoded_tokens.add(decoded)
+        self.metrics.tokens_per_tick.set(float(decoded))
+        self.metrics.slot_ticks_run.add(self._slots * self._ticks_per_sync)
+        self.metrics.slot_ticks_served.add(decoded - first_tokens)
+        if journal_dirty:
+            # Synchronous at the cadence point: the whole point is
+            # that a SIGKILL one instruction later finds these tokens
+            # on disk.
+            self._journal.flush()
+        if done_h.any():
+            for i in np.nonzero(done_h)[0]:
+                rec = self._slot_rec[i]
+                assert rec is not None
+                self._active[i] = False
+                self._slot_rec[i] = None
+                self._slot_emitted[i] = 0
+                self._slot_journaled[i] = 0
+                if self._kv_pages is not None:
+                    # Unpin the slot's blocks: uncached ones return to
+                    # the free list; cached prefix blocks stay alive on
+                    # the radix tree's own reference. The row falls back
+                    # to the sink so this slot's frozen-position tick
+                    # writes can never touch a re-allocated block.
+                    self._release_slot_blocks(i)
+                out = gen_h[i, : n_out_h[i]].copy()
+                self._retire_completion(rec, out, completions)
+            if self._kv_pages is not None:
+                self._caches = self._paged_set_table(
+                    self._caches, self._device_table()
+                )
+                self.metrics.cache_pool_occupancy.set(
+                    self._kv_alloc.occupancy()
+                )
+        if finishers:
+            # AFTER the done bookkeeping above (which must see the
+            # pre-activation active set and its fetched state):
+            # completed prefills activate for the NEXT tick.
+            self._activate_chunk_finishers(finishers)
+            if self._prefill_role:
+                # Disaggregated prefill: nothing decodes here — cut
+                # the freshly activated slots into handoffs and free
+                # them before any decode tick could run.
+                self._harvest_prefilled(finishers)
+        if run_chunk:
+            self.metrics.admission_queue_tokens.set(float(sum(
+                len(e.seq) - e.off for e in self._prefill_queue
+            )))
 
     def flush_commits(self) -> bool:
         """Commit anything emitted since the last commit (cadence-pending
@@ -3782,12 +3859,14 @@ class StreamingGenerator:
             if take_cap and len(pending) < take_cap:
                 # Never let an empty topic stall in-flight decode ticks:
                 # poll without blocking while anything is generating.
-                records = self._consumer.poll(
-                    max_records=self._max_poll,
-                    timeout_ms=0 if in_flight else 50,
-                )
+                with xprof.span(xprof.SPAN_POLL):
+                    records = self._consumer.poll(
+                        max_records=self._max_poll,
+                        timeout_ms=0 if in_flight else 50,
+                    )
+                    if records:
+                        self.note_fetched(records)
                 if records:
-                    self.note_fetched(records)
                     pending.extend(records)
                     exhausted_at = None
             if (take_cap and pending) or (free and deferred and budget):
@@ -3840,26 +3919,27 @@ class StreamingGenerator:
         if self._txn_mode:
             return self._commit_txn(t0)
         if self._output_producer is not None:
-            try:
-                self._output_producer.flush()
-            except Exception:  # noqa: BLE001 - any flush failure fails closed
-                self.metrics.output_flush_failures.add(1)
-                _logger.exception(
-                    "output flush failed; SKIPPING offset commit so the "
-                    "affected prompts re-deliver and regenerate"
-                )
-                return False
-            pending, self._pending_outputs = self._pending_outputs, []
-            for handle in pending:
+            with xprof.span(xprof.SPAN_OUTPUT_FLUSH):
                 try:
-                    handle.get(30.0)
-                except Exception as exc:
+                    self._output_producer.flush()
+                except Exception:  # noqa: BLE001 - a flush failure fails closed
                     self.metrics.output_flush_failures.add(1)
-                    raise OutputDeliveryError(
-                        "an output record terminally failed delivery; "
-                        "refusing to commit source offsets past lost "
-                        "output (restart re-delivers and regenerates)"
-                    ) from exc
+                    _logger.exception(
+                        "output flush failed; SKIPPING offset commit so the "
+                        "affected prompts re-deliver and regenerate"
+                    )
+                    return False
+                pending, self._pending_outputs = self._pending_outputs, []
+                for handle in pending:
+                    try:
+                        handle.get(30.0)
+                    except Exception as exc:
+                        self.metrics.output_flush_failures.add(1)
+                        raise OutputDeliveryError(
+                            "an output record terminally failed delivery; "
+                            "refusing to commit source offsets past lost "
+                            "output (restart re-delivers and regenerates)"
+                        ) from exc
         snapshot = self._ledger.snapshot()
         # Commit only partitions we still OWN: an eager rebalance (a
         # member joined/left — fleet.scale on the process fleet) can take
