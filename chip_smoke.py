@@ -235,9 +235,13 @@ def check_kernels(sz: Sizes) -> dict:
     paged = jax.jit(int8_paged_decode_attention)(
         q, pool_kq, pool_ks, pool_vq, pool_vs, table, pos
     )
-    dyn = jax.jit(int8_decode_attention_dynlen)(
-        q, dense_view(pool_kq), dense_view(pool_ks), dense_view(pool_vq),
-        dense_view(pool_vs), pos,
+    dense = [dense_view(p) for p in (pool_kq, pool_ks, pool_vq, pool_vs)]
+    dyn = jax.jit(int8_decode_attention_dynlen)(q, *dense, pos)
+    # The same read out of a STACKED pool taken whole, as the serving tick
+    # makes it: the slab as layer 1, behind a layer of other bytes.
+    dyn_layer = jax.jit(int8_decode_attention_dynlen)(
+        q, *(jnp.stack([jnp.roll(v, 1, axis=0), v]) for v in dense), pos,
+        layer=jnp.int32(1),
     )
     err = {}
 
@@ -251,6 +255,10 @@ def check_kernels(sz: Sizes) -> dict:
 
     close("block_table_read", paged, ref)
     close("dynlen_read", dyn, ref)
+    _require(
+        np.array_equal(np.asarray(dyn_layer), np.asarray(dyn)),
+        "dynlen_read: layer 1 of the stacked pool differs from its slab",
+    )
 
     # Flash forward and backward (GQA, causal) against the dense XLA body.
     seq = sz.train_seq

@@ -189,3 +189,46 @@ class TestFuzzDifferential:
         ]
         fast, slow = _both("gather_rows", vals, 6, np.int32, -1)
         np.testing.assert_array_equal(fast, slow)
+
+
+@needs_native
+def test_concurrent_builds_all_succeed(tmp_path):
+    """Two processes that build at once into an empty directory (test
+    workers on a fresh checkout) both end with the binary in place: each
+    compiles to a temporary name of its own. With one shared name the
+    first ``os.replace`` took the file from under the other, whose build
+    then reported failure and whose ``needs_native`` tests skipped."""
+    import os
+    import subprocess
+    import sys
+    import time
+
+    child = (
+        "import os, sys, time\n"
+        "import torchkafka_tpu.native as n\n"
+        "d = sys.argv[1]\n"
+        "n._HERE, n._SO = d, os.path.join(d, os.path.basename(n._SO))\n"
+        "open(os.path.join(d, f'ready.{os.getpid()}'), 'w').close()\n"
+        "while not os.path.exists(os.path.join(d, 'go')):\n"
+        "    time.sleep(0.01)\n"
+        "sys.exit(0 if n._build() and os.path.exists(n._SO) else 1)\n"
+    )
+    procs = [
+        subprocess.Popen([sys.executable, "-c", child, str(tmp_path)])
+        for _ in range(2)
+    ]
+    try:
+        deadline = time.monotonic() + 120
+        while len(list(tmp_path.glob("ready.*"))) < 2:
+            assert time.monotonic() < deadline, "the builders did not start"
+            assert all(p.poll() is None for p in procs)
+            time.sleep(0.05)
+        (tmp_path / "go").touch()
+        assert [p.wait(timeout=240) for p in procs] == [0, 0]
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+                p.wait()
+    built = [f for f in os.listdir(tmp_path) if f.startswith("_tk_native")]
+    assert built == [os.path.basename(native._SO)]  # and no temporary left

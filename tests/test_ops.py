@@ -440,6 +440,90 @@ class TestInt8DecodeAttentionKernel:
                 err_msg=f"block={mb}",
             )
 
+    @staticmethod
+    def _stacked_pool(n_layers=3, B=4, M=32, K=2, rep=2, Dh=16, seed=3):
+        """A K-major int8 pool [L, B, K, M, Dh] with scales [L, B, K, M],
+        a query, and ragged watermarks: one-block, mid-block and
+        several-block slots, so that the kernel's buffer parity and its
+        prefetch of the NEXT slot's first block (which crosses from one
+        slot's rows into the next's) both matter."""
+        from torchkafka_tpu.serve import _quant_kv
+
+        rng = np.random.default_rng(seed)
+        q = jnp.asarray(rng.normal(size=(B, 1, K * rep, Dh)), jnp.float32)
+        kv = jnp.asarray(
+            rng.normal(size=(2, n_layers, B, K, M, Dh)) * 2, jnp.float32
+        )
+        (kq, ks), (vq, vs) = _quant_kv(kv[0]), _quant_kv(kv[1])
+        pos = jnp.asarray([17, 3, 31, 8])
+        return q, (kq, ks, vq, vs), pos
+
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    def test_dynlen_layer_indexed_matches_slab_read(self, layer):
+        """The read of layer ``l`` out of the stacked pool, taken whole,
+        is bit for bit the 4-D read of that layer's slab: same arithmetic,
+        only the DMAs' source row moves. As a Python int and traced."""
+        from torchkafka_tpu.ops.kvattn import int8_decode_attention_dynlen
+
+        q, pool, pos = self._stacked_pool()
+        ref = int8_decode_attention_dynlen(
+            q, *(c[layer] for c in pool), pos, block=8, interpret=True
+        )
+        out = int8_decode_attention_dynlen(
+            q, *pool, pos, layer=layer, block=8, interpret=True
+        )
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        traced = jax.jit(
+            lambda l: int8_decode_attention_dynlen(
+                q, *pool, pos, layer=l, block=8, interpret=True
+            )
+        )(jnp.int32(layer))
+        np.testing.assert_array_equal(np.asarray(traced), np.asarray(ref))
+
+    def test_dynlen_layer_needs_the_stacked_pool(self):
+        from torchkafka_tpu.ops.kvattn import int8_decode_attention_dynlen
+
+        q, pool, pos = self._stacked_pool()
+        with pytest.raises(ValueError, match="stacked pool"):
+            int8_decode_attention_dynlen(
+                q, *(c[0] for c in pool), pos, layer=0, interpret=True
+            )
+
+    @pytest.mark.parametrize(
+        "axes", [{"data": 2, "tp": 2, "fsdp": 2}, {"data": 4, "tp": 2}]
+    )
+    def test_dynlen_sharded_layer_indexed(self, axes):
+        """Under a mesh the stacked pool enters the shard_map 5-D and each
+        (data, tp) shard merges L with ITS slots: equal to the unsharded
+        slab read, which is (slot, head)-parallel."""
+        from torchkafka_tpu.models.generate import (
+            kv_kmajor_scale_sharding, kv_kmajor_sharding,
+        )
+        from torchkafka_tpu.ops.kvattn import (
+            int8_decode_attention_dynlen,
+            int8_decode_attention_dynlen_sharded,
+        )
+
+        mesh = make_mesh(axes)
+        q, pool, pos = self._stacked_pool()
+        placed = tuple(
+            jax.device_put(
+                c, kv_kmajor_sharding(mesh) if c.ndim == 5
+                else kv_kmajor_scale_sharding(mesh)
+            )
+            for c in pool
+        )
+        for layer in (0, 2):
+            ref = int8_decode_attention_dynlen(
+                q, *(c[layer] for c in pool), pos, block=8, interpret=True
+            )
+            out = jax.jit(
+                lambda l: int8_decode_attention_dynlen_sharded(
+                    q, *placed, pos, mesh, layer=l, block=8, interpret=True
+                )
+            )(jnp.int32(layer))
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
     def test_paged_kernel_matches_gathered_read(self):
         """v4 (block-table read: the v3 watermark-DMA structure through
         per-slot block tables) against the XLA gathered scale-folded

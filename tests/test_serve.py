@@ -890,3 +890,235 @@ class TestExpertParallelServing:
             np.testing.assert_array_equal(
                 sharded[idx], base[idx], err_msg=f"prompt {idx}"
             )
+
+
+# ----------------------------------------------------------------------
+# The decode tick leaves the KV pool where it lies (PR 25): the pool is the
+# layer scan's CARRY. Below, the formulation it replaced — the pool as the
+# layer scan's xs and ys, one layer's slab a step — kept as the reference
+# the carried tick must equal bit for bit; it lives in this file only.
+
+
+def _ref_layer_step(x, layer, ck, cv, pos_b, cfg):
+    from torchkafka_tpu.models.generate import _attend_cached, _project_qkv
+    from torchkafka_tpu.models.transformer import _rope
+
+    q, k, v = _project_qkv(x, layer, cfg)
+    q = _rope(q, pos_b[:, None], cfg.rope_theta)
+    k = _rope(k, pos_b[:, None], cfg.rope_theta)
+    rows = jnp.arange(ck.shape[0])
+    ck = ck.at[rows, pos_b].set(k[:, 0].astype(ck.dtype))
+    cv = cv.at[rows, pos_b].set(v[:, 0].astype(cv.dtype))
+    valid = jnp.arange(ck.shape[1])[None, :] <= pos_b[:, None]
+    return _attend_cached(x, q, ck, cv, valid, layer, cfg), (ck, cv)
+
+
+def _ref_layer_step_q(x, layer, ckq, cks, cvq, cvs, pos_b, cfg, kernel, mesh):
+    from torchkafka_tpu.models.generate import (
+        _attend_cached, _attn_tail, _project_qkv,
+    )
+    from torchkafka_tpu.models.transformer import _rope
+    from torchkafka_tpu.ops.kvattn import int8_decode_attention_dynlen
+    from torchkafka_tpu.serve import _quant_kv
+
+    q, k, v = _project_qkv(x, layer, cfg)
+    q = _rope(q, pos_b[:, None], cfg.rope_theta)
+    k = _rope(k, pos_b[:, None], cfg.rope_theta)
+    kq, ks = _quant_kv(k[:, 0])
+    vq, vs = _quant_kv(v[:, 0])
+    rows = jnp.arange(ckq.shape[0])
+    if kernel:  # slab [B, K, M, Dh] / [B, K, M]
+        kidx = jnp.arange(ckq.shape[1])[None, :]
+
+        def upd(c, row):
+            return c.at[rows[:, None], kidx, pos_b[:, None]].set(row)
+    else:  # slab [B, M, K, Dh] / [B, M, K]
+        def upd(c, row):
+            return c.at[rows, pos_b].set(row)
+    slab = (upd(ckq, kq), upd(cks, ks), upd(cvq, vq), upd(cvs, vs))
+    if kernel:
+        read = int8_decode_attention_dynlen  # one layer's slab, 4-D
+        if mesh is not None:
+            from jax.sharding import PartitionSpec as PS
+
+            qs, cs = PS("data", None, "tp", None), PS("data", "tp", None, None)
+            ss = PS("data", "tp", None)
+            read = jax.shard_map(
+                read, mesh=mesh, in_specs=(qs, cs, ss, cs, ss, PS("data")),
+                out_specs=qs, check_vma=False,
+            )
+        return _attn_tail(x, read(q, *slab, pos_b), layer, cfg), slab
+    valid = jnp.arange(ckq.shape[1])[None, :] <= pos_b[:, None]
+    x = _attend_cached(
+        x, q, slab[0], slab[2], valid, layer, cfg,
+        k_scale=slab[1], v_scale=slab[3],
+    )
+    return x, slab
+
+
+def _ref_tick_block(srv, params, caches, last_tok, pos, gen, active_in):
+    """``serve.py::_build::tick_block`` as it stood before PR 25, greedy,
+    no EOS: the layer scan takes the pool as xs and returns it as ys."""
+    from jax import lax
+
+    from torchkafka_tpu.models.quant import embed_rows, load_weight
+    from torchkafka_tpu.models.transformer import _rms_norm
+
+    cfg, P_, max_new = srv._cfg, srv._prompt_len, srv._max_new
+
+    def one(carry, _):
+        caches, last_tok, pos, gen, done_latch, n_out = carry
+        act = active_in & ~done_latch
+        x = embed_rows(params["embed"], last_tok, cfg.dtype)[:, None, :]
+
+        def body(x, inputs):
+            layer, *slab = inputs
+            if srv._kv_int8:
+                return _ref_layer_step_q(
+                    x, layer, *slab, pos, cfg, srv._kv_kernel, srv._mesh
+                )
+            return _ref_layer_step(x, layer, *slab, pos, cfg)
+
+        x, caches = lax.scan(body, x, (params["layers"], *caches))
+        x = _rms_norm(x, params["ln_f"])
+        logits = jnp.einsum(
+            "bd,dv->bv", x[:, 0], load_weight(params["lm_head"], cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        t = pos - P_
+        idx = jnp.minimum(t + 1, max_new - 1)
+        onehot = jnp.arange(max_new)[None, :] == idx[:, None]
+        gen = jnp.where(onehot & act[:, None], tok[:, None], gen)
+        done_now = act & (t + 2 >= max_new)
+        pos = jnp.where(act & ~done_now, pos + 1, pos)
+        last_tok = jnp.where(act, tok, last_tok)
+        n_out = jnp.where(done_now, jnp.minimum(t + 2, max_new), n_out)
+        return (
+            tuple(caches), last_tok, pos, gen, done_latch | done_now, n_out
+        ), None
+
+    B = last_tok.shape[0]
+    init = (caches, last_tok, pos, gen, jnp.zeros((B,), bool),
+            jnp.zeros((B,), jnp.int32))
+    return lax.scan(one, init, None, length=srv._ticks_per_sync)[0]
+
+
+_TICK_VARIANTS = {
+    # name: (kv_dtype, kv_kernel, mesh axes)
+    "bf16": (None, "auto", None),
+    "int8": ("int8", False, None),
+    "int8-kernel": ("int8", True, None),
+    "int8-mesh": ("int8", False, {"data": 2, "tp": 2, "fsdp": 2}),
+    "int8-kernel-mesh": ("int8", True, {"data": 2, "tp": 2, "fsdp": 2}),
+}
+
+
+def _tick_server(variant, ticks=3):
+    """A 2-layer toy server of the variant (heads of 128: the kernel's lane
+    width; 4 slots and 3 ticks a sync, numbers no model dimension has)."""
+    from torchkafka_tpu.parallel import make_mesh
+
+    kv_dtype, kv_kernel, axes = _TICK_VARIANTS[variant]
+    cfg = TransformerConfig(
+        vocab_size=VOCAB, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        d_ff=64, max_seq_len=P + MAX_NEW, dtype=jnp.float32,
+    )
+    params = init_params(jax.random.key(0), cfg)
+    broker = tk.InMemoryBroker()
+    broker.create_topic("p", partitions=1)
+    consumer = tk.MemoryConsumer(broker, "p", group_id=f"g-{variant}")
+    srv = StreamingGenerator(
+        consumer, params, cfg, slots=4, prompt_len=P, max_new=MAX_NEW,
+        ticks_per_sync=ticks, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
+        mesh=make_mesh(axes) if axes else None,
+    )
+    assert srv._kv_kernel is (kv_kernel is True)
+    return srv, consumer
+
+
+def _scans(jaxpr):
+    """Every scan equation under ``jaxpr``, however deeply it is nested."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _scans(inner)
+
+
+@pytest.mark.parametrize("variant", ["bf16", "int8", "int8-kernel"])
+def test_layer_scan_carries_the_pool(variant):
+    """Structure: in the tick's jaxpr every pool-shaped value of the layer
+    scan is a carry. As an input (xs) the pool is sliced a layer at a
+    time, as an output (ys) written back a layer at a time into a second
+    buffer, and copied whole at the tick's end (PERF.md, PR 25)."""
+    srv, consumer = _tick_server(variant)
+    L, B = srv._cfg.n_layers, 4
+    jaxpr = jax.make_jaxpr(srv._tick_block_raw)(
+        srv._params, srv._caches, srv._last_tok, srv._pos, srv._gen,
+        jnp.ones((B,), bool), srv._slot_keys,
+    )
+    pool_shapes = {c.shape for c in srv._caches}
+    slab_shapes = {s[1:] for s in pool_shapes}
+    layer_scans = [
+        e for e in _scans(jaxpr.jaxpr)
+        if e.params["length"] == L
+        and any(v.aval.shape in pool_shapes for v in e.invars)
+    ]
+    assert len(layer_scans) == 1, [e.params["length"] for e in layer_scans]
+    (scan,) = layer_scans
+    nc, nk = scan.params["num_consts"], scan.params["num_carry"]
+    carry_in = [v.aval.shape for v in scan.invars[nc:nc + nk]]
+    carry_out = [v.aval.shape for v in scan.outvars[:nk]]
+    xs = [v.aval.shape for v in scan.invars[nc + nk:]]
+    ys = [v.aval.shape for v in scan.outvars[nk:]]
+    for shape in pool_shapes:
+        n = sum(1 for c in srv._caches if c.shape == shape)
+        assert carry_in.count(shape) == n and carry_out.count(shape) == n
+    moved = pool_shapes | slab_shapes
+    assert not [s for s in xs + ys if s in moved], (xs, ys)
+    assert not [
+        v.aval.shape for v in scan.invars[:nc] if v.aval.shape in moved
+    ]
+    srv.close()
+    consumer.close()
+
+
+@pytest.mark.parametrize("variant", list(_TICK_VARIANTS))
+def test_carried_tick_equals_xs_ys_reference(variant):
+    """Identity: one block of ticks from a ragged state (slots at different
+    positions, one of them idle) gives bit-identical gen, pos, done, counts
+    and pool to the xs/ys formulation above."""
+    srv, consumer = _tick_server(variant)
+    B = 4
+    rng = np.random.default_rng(11)
+    prompts = jnp.asarray(rng.integers(0, VOCAB, (B, P)), jnp.int32)
+    state = srv._admit_fn(
+        srv._caches, srv._last_tok, srv._pos, srv._gen, prompts,
+        jnp.ones((B,), bool), srv._slot_keys,
+    )
+    # A first block with two slots idle leaves the watermarks ragged.
+    tick = jax.jit(srv._tick_block_raw)
+    state = tick(
+        srv._params, *state, jnp.asarray([True, False, True, False]),
+        srv._slot_keys,
+    )[:4]
+    active = jnp.asarray([True, True, False, True])
+    got = tick(srv._params, *state, active, srv._slot_keys)
+    ref = jax.jit(
+        lambda *a: _ref_tick_block(srv, *a)
+    )(srv._params, *state, active)
+    assert sorted(np.asarray(state[2]).tolist()) != [P] * B  # ragged indeed
+    for name, a, b in zip(
+        ("caches", "last_tok", "pos", "gen", "done", "n_out"), got, ref
+    ):
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(
+                np.asarray(x), np.asarray(y), err_msg=f"{variant}: {name}"
+            )
+    assert int(np.asarray(got[2]).max()) > P  # the block did decode
+    srv.close()
+    consumer.close()

@@ -21,6 +21,7 @@ Public surface:
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import hashlib
 import logging
@@ -54,16 +55,21 @@ _native = None
 
 def _build() -> bool:
     include = sysconfig.get_paths()["include"]
+    # A temporary name of this process's own: several processes may build
+    # at once (test workers on a fresh checkout), and with one shared name
+    # the first os.replace takes the file from under the others.
+    tmp = _SO + f".{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-        f"-I{include}", _SRC, "-o", _SO + ".tmp", "-lz",
+        f"-I{include}", _SRC, "-o", tmp, "-lz",
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_SO + ".tmp", _SO)  # atomic: concurrent imports see whole file
+        os.replace(tmp, _SO)  # atomic: concurrent imports see whole file
         for old in glob.glob(os.path.join(_HERE, f"_tk_native*{_EXT}")):
             if old != _SO:  # binaries of earlier sources
-                os.remove(old)
+                with contextlib.suppress(FileNotFoundError):  # a concurrent build's
+                    os.remove(old)
         return True
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired, OSError) as e:
         detail = getattr(e, "stderr", b"")

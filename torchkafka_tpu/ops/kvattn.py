@@ -454,11 +454,14 @@ def block_table_attention(
 
 
 def _kvattn_dynlen_kernel(
-    pos_ref, q_ref, kq_hbm, ks_hbm, vq_hbm, vs_hbm, o_ref,
+    pos_ref, base_ref, q_ref, kq_hbm, ks_hbm, vq_hbm, vs_hbm, o_ref,
     kt, st, vt, wt, sems, *, mb: int, inv_sqrt_dh: float,
 ):
     b = pl.program_id(0)
     nb = pl.num_programs(0)
+    # Slot b's rows lie at ``base + b`` of the pool operands: 0 for one
+    # layer's slab, ``layer * B`` for the stacked pool taken whole.
+    base = base_ref[0]
     pos = pos_ref[b]
     n_blocks = (pos + mb) // mb  # ceil((pos + 1) / mb), pos >= 0
     q = q_ref[0]  # [K, rep, Dh] compute dtype
@@ -479,7 +482,8 @@ def _kvattn_dynlen_kernel(
         0, b, lambda t, acc: acc + blocks_of(t), jnp.int32(0)
     ) % 2
 
-    def dmas(slot, row, j):
+    def dmas(slot, i, j):  # block j of slot i into buffer ``slot``
+        row = base + i
         return (
             pltpu.make_async_copy(
                 kq_hbm.at[row, :, pl.ds(j * mb, mb), :], kt.at[slot],
@@ -566,6 +570,7 @@ def int8_decode_attention_dynlen(
     cv_s: jax.Array,
     pos: jax.Array,
     *,
+    layer: jax.Array | int | None = None,
     block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -575,6 +580,13 @@ def int8_decode_attention_dynlen(
     [B, 1, H, Dh]. HBM traffic scales with the actual fill, not the
     pool size — inexpressible in XLA, where every read is pool-shaped.
 
+    With ``layer`` (a scalar, traced or not) the caches are the STACKED
+    pool, [L, B, K, M, Dh] and [L, B, K, M], and the read is layer
+    ``layer``'s. The pool stays where it lies: a Pallas operand is opaque
+    to XLA, so a ``pool[layer]`` outside the call would materialise the
+    slab. The kernel sees the pool with L and B merged (a bitcast) and
+    DMAs from row ``layer * B + b``.
+
     Exact w.r.t. the scale-folded read restricted to valid positions
     (flash-style online softmax; differential-tested against v2 with
     ``valid = arange(M) <= pos[:, None]``).
@@ -582,6 +594,18 @@ def int8_decode_attention_dynlen(
     b, s, h, dh = q.shape
     if s != 1:
         raise ValueError(f"decode attention is one token per slot, got S={s}")
+    if layer is None:
+        base = jnp.zeros((1,), jnp.int32)
+    else:
+        if ck_q.ndim != 5 or ck_q.shape[1] != b:
+            raise ValueError(
+                f"layer= takes the stacked pool [L, {b}, K, M, Dh], got "
+                f"{ck_q.shape}"
+            )
+        base = (jnp.asarray(layer, jnp.int32) * b).reshape(1)
+        ck_q, ck_s, cv_q, cv_s = (
+            c.reshape(-1, *c.shape[2:]) for c in (ck_q, ck_s, cv_q, cv_s)
+        )
     n_kv, m = ck_q.shape[1], ck_q.shape[2]
     rep = h // n_kv
     mb = block or dynlen_block(m)
@@ -595,16 +619,18 @@ def int8_decode_attention_dynlen(
     # the order must be the textual one.
     kw = {} if interpret else tpu_compiler_params(("arbitrary",))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos: (i, 0, 0, 0)),
+            pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos, base: (i, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (1, n_kv, rep, dh), lambda i, pos, base: (i, 0, 0, 0)
+        ),
         scratch_shapes=[
             pltpu.VMEM((2, n_kv, mb, dh), jnp.int8),   # k tiles
             pltpu.VMEM((2, n_kv, mb), jnp.float32),    # k scales
@@ -623,7 +649,7 @@ def int8_decode_attention_dynlen(
         interpret=interpret,
         name="tk_kvattn_dynlen",
         **kw,
-    )(pos.astype(jnp.int32), qg, ck_q, ck_s.astype(jnp.float32), cv_q,
+    )(pos.astype(jnp.int32), base, qg, ck_q, ck_s.astype(jnp.float32), cv_q,
       cv_s.astype(jnp.float32))
     return out.reshape(b, 1, h, dh)
 
@@ -646,10 +672,12 @@ def int8_decode_attention_dynlen_sharded(
     pos: jax.Array,
     mesh,
     *,
+    layer: jax.Array | int,
     block: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """``int8_decode_attention_dynlen`` under a serving mesh.
+    """``int8_decode_attention_dynlen`` of layer ``layer`` of the STACKED
+    pool ([L, B, K, M, Dh] / [L, B, K, M]) under a serving mesh.
 
     A Pallas call is opaque to GSPMD (the ``flash_attention_sharded``
     lesson), but the decode read is (slot, head)-parallel with no
@@ -657,24 +685,31 @@ def int8_decode_attention_dynlen_sharded(
     over its own kv heads — so ``shard_map`` splits it exactly like the
     XLA read's layouts: q/pos/caches batch over ``data``, kv heads over
     ``tp``. Requirements (the capability probe gates on these): B
-    divisible by data, H and K by tp."""
+    divisible by data, H and K by tp. The pool enters the region 5-D and
+    L merges with the SHARD's slots inside it: an unsharded L cannot
+    merge with a ``data``-sharded B outside."""
     from jax.sharding import PartitionSpec as P
 
     bspec = "data" if "data" in mesh.shape else None
     tp = "tp" if "tp" in mesh.shape else None
-    qspec = P(bspec, None, tp, None)   # [B, 1, H, Dh]
-    cspec = P(bspec, tp, None, None)   # [B, K, M, Dh] K-major payloads
-    sspec = P(bspec, tp, None)         # [B, K, M] scales
+    qspec = P(bspec, None, tp, None)         # [B, 1, H, Dh]
+    cspec = P(None, bspec, tp, None, None)   # [L, B, K, M, Dh] payloads
+    sspec = P(None, bspec, tp, None)         # [L, B, K, M] scales
+
+    def read(q, ck_q, ck_s, cv_q, cv_s, pos, layer):
+        return int8_decode_attention_dynlen(
+            q, ck_q, ck_s, cv_q, cv_s, pos, layer=layer, block=block,
+            interpret=interpret,
+        )
+
     fn = jax.shard_map(
-        functools.partial(
-            int8_decode_attention_dynlen, block=block, interpret=interpret
-        ),
+        read,
         mesh=mesh,
-        in_specs=(qspec, cspec, sspec, cspec, sspec, P(bspec)),
+        in_specs=(qspec, cspec, sspec, cspec, sspec, P(bspec), P()),
         out_specs=qspec,
         check_vma=False,
     )
-    return fn(q, ck_q, ck_s, cv_q, cv_s, pos)
+    return fn(q, ck_q, ck_s, cv_q, cv_s, pos, jnp.asarray(layer, jnp.int32))
 
 
 def int8_paged_decode_attention_sharded(

@@ -157,11 +157,12 @@ def _quant_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 def _slot_layer_step_q(
-    x, layer, ck_q, ck_s, cv_q, cv_s, pos_b, cfg, use_kernel=False,
+    x, layer, ck_q, ck_s, cv_q, cv_s, l, pos_b, cfg, use_kernel=False,
     mesh=None,
 ):
-    """int8-KV variant of ``_slot_layer_step``: the pool stores int8
-    payloads + per-(position, head) f32 absmax scales over Dh —
+    """int8-KV variant of ``_slot_layer_step``, over the same STACKED pool
+    at layer index ``l`` (written in place, read at ``l``): the pool stores
+    int8 payloads + per-(position, head) f32 absmax scales over Dh —
     (Dh+4)/(2·Dh) ≈ 52% of bf16 pool bytes at Dh=128 — read through
     ``_attend_cached``'s scale-folded mode (scales land on the small
     score/prob tensors; the big operands carry only a cast). A capacity
@@ -177,25 +178,21 @@ def _slot_layer_step_q(
     k = _rope(k, pos_b[:, None], cfg.rope_theta)
     kq, ks = _quant_kv(k[:, 0])  # [B, K, Dh] int8, [B, K]
     vq, vs = _quant_kv(v[:, 0])
-    rows = jnp.arange(ck_q.shape[0])
+    rows = jnp.arange(ck_q.shape[1])
     if use_kernel:
-        # K-MAJOR pool ([B, K, M, Dh] / [B, K, M] per layer): each head's
+        # K-MAJOR pool ([L, B, K, M, Dh] / [L, B, K, M]): each head's
         # [M, Dh] tile is a contiguous slice, which is what lets the
         # kernel batch its dots over (slot, head) with no relayout — the
         # v1 postmortem's fix (ops/kvattn.py docstring). Writes are
         # scatters like the bf16 path (see _slot_layer_step's note):
-        # per-(row, head) at [b, :, pos_b[b]].
-        kidx = jnp.arange(ck_q.shape[1])[None, :]
+        # per-(row, head) at [l, b, :, pos_b[b]].
+        kidx = jnp.arange(ck_q.shape[2])[None, :]
 
-        def upd(c, row):  # payload [B, K, M, Dh] and scale [B, K, M] alike
-            return c.at[rows[:, None], kidx, pos_b[:, None]].set(row)
-
-        pool_len = ck_q.shape[2]
+        def upd(c, row):  # payload [L, B, K, M, Dh] and scale [L, B, K, M] alike
+            return c.at[l, rows[:, None], kidx, pos_b[:, None]].set(row)
     else:
-        def upd(c, row):  # payload [B, M, K, Dh] and scale [B, M, K] alike
-            return c.at[rows, pos_b].set(row)
-
-        pool_len = ck_q.shape[1]
+        def upd(c, row):  # payload [L, B, M, K, Dh] and scale [L, B, M, K] alike
+            return c.at[l, rows, pos_b].set(row)
     ck_q = upd(ck_q, kq)
     ck_s = upd(ck_s, ks)
     cv_q = upd(cv_q, vq)
@@ -206,7 +203,11 @@ def _slot_layer_step_q(
         # manually DMAs M-blocks with cross-program double buffering, so
         # HBM traffic scales with each slot's ACTUAL fill instead of the
         # pool size — inexpressible in XLA, where every read is
-        # pool-shaped. Net tick win at long pools (the regime "auto"
+        # pool-shaped. It takes the pool WHOLE with the layer's index (a
+        # second scalar-prefetch argument that offsets the DMA's source
+        # row): a Pallas operand is opaque to XLA, so handing it
+        # ``pool[l]`` would materialise the layer's slab every layer.
+        # Net tick win at long pools (the regime "auto"
         # selects; measured matrix in _build/PERF.md); fills < ~90%
         # (the continuous-batching norm) widen it. Caller gates on
         # tiling shapes (a Pallas call is opaque to GSPMD, the
@@ -221,17 +222,18 @@ def _slot_layer_step_q(
 
         if mesh is not None:
             attn = int8_decode_attention_dynlen_sharded(
-                q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh
+                q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh, layer=l
             )
         else:
             attn = int8_decode_attention_dynlen(
-                q, ck_q, ck_s, cv_q, cv_s, pos_b
+                q, ck_q, ck_s, cv_q, cv_s, pos_b, layer=l
             )
         x = _attn_tail(x, attn, layer, cfg)
     else:
-        valid = jnp.arange(pool_len)[None, :] <= pos_b[:, None]  # [B, M]
+        valid = jnp.arange(ck_q.shape[2])[None, :] <= pos_b[:, None]  # [B, M]
         x = _attend_cached(
-            x, q, ck_q, cv_q, valid, layer, cfg, k_scale=ck_s, v_scale=cv_s
+            x, q, _layer_of(ck_q, l), _layer_of(cv_q, l), valid, layer, cfg,
+            k_scale=_layer_of(ck_s, l), v_scale=_layer_of(cv_s, l),
         )
     return x, ck_q, ck_s, cv_q, cv_s
 
@@ -637,9 +639,17 @@ class ServeMetrics:
         ])
 
 
-def _slot_layer_step(x, layer, cache_k, cache_v, pos_b, cfg):
-    """One decode token through one layer with a DIFFERENT position per
-    slot. x: [B, 1, D]; caches [B, M, K, Dh]; pos_b: [B]. Only the rope and
+def _layer_of(pool, l):
+    """Layer ``l``'s slab of a stacked pool, for a read XLA can see into:
+    the dynamic slice fuses into the read's first operation."""
+    return lax.dynamic_index_in_dim(pool, l, keepdims=False)
+
+
+def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg):
+    """One decode token through layer ``l`` with a DIFFERENT position per
+    slot. x: [B, 1, D]; caches: the STACKED pool [L, B, M, K, Dh], which
+    the caller carries through its layer loop — written in place here, one
+    row per slot, and read at index ``l``; pos_b: [B]. Only the rope and
     the cache write differ from the lockstep ``generate._layer_step``; the
     attention/MLP tail is the shared ``_attend_cached``. (Sibling:
     spec_decode._multi_step generalizes this to S queries per row —
@@ -647,19 +657,24 @@ def _slot_layer_step(x, layer, cache_k, cache_v, pos_b, cfg):
     q, k, v = _project_qkv(x, layer, cfg)
     q = _rope(q, pos_b[:, None], cfg.rope_theta)
     k = _rope(k, pos_b[:, None], cfg.rope_theta)
-    # Per-row cache write as a SCATTER (.at[rows, pos].set). History: r4
+    # Per-row cache write as a SCATTER (.at[l, rows, pos].set). History: r4
     # shipped a vmapped dynamic_update_slice here, with a measurement
     # note claiming the masked-select lowering beat scatter ~10x. r5
     # re-measured both isolated (fori-chained slope: scatter 3.2 µs vs
     # select 41 µs per [16, 192, 8, 256] update) and end-to-end (1B
     # serve tick 6.66 → 4.73 ms, +41% tok/s) — the select rewrites the
     # whole pool every layer while the scatter writes one row per slot;
-    # the r4 note did not reproduce and is retracted in PERF.md.
-    rows = jnp.arange(cache_k.shape[0])
-    cache_k = cache_k.at[rows, pos_b].set(k[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[rows, pos_b].set(v[:, 0].astype(cache_v.dtype))
-    valid = jnp.arange(cache_k.shape[1])[None, :] <= pos_b[:, None]  # [B, M]
-    x = _attend_cached(x, q, cache_k, cache_v, valid, layer, cfg)
+    # the r4 note did not reproduce and is retracted in PERF.md. The
+    # scatter goes into the stacked pool and not into a layer's slab: a
+    # pool that is a scan's input and output is sliced, written back and
+    # copied whole every tick (PERF.md, PR 25); a carry is written in place.
+    rows = jnp.arange(cache_k.shape[1])
+    cache_k = cache_k.at[l, rows, pos_b].set(k[:, 0].astype(cache_k.dtype))
+    cache_v = cache_v.at[l, rows, pos_b].set(v[:, 0].astype(cache_v.dtype))
+    valid = jnp.arange(cache_k.shape[2])[None, :] <= pos_b[:, None]  # [B, M]
+    x = _attend_cached(
+        x, q, _layer_of(cache_k, l), _layer_of(cache_v, l), valid, layer, cfg
+    )
     return x, cache_k, cache_v
 
 
@@ -1469,24 +1484,30 @@ class StreamingGenerator:
                 act = active_in & ~done_latch
                 x = embed_rows(params["embed"], last_tok, cfg.dtype)[:, None, :]
 
-                if kv_int8:
-                    def body(x, inputs):
-                        layer, ckq, cks, cvq, cvs = inputs
-                        x, ckq, cks, cvq, cvs = _slot_layer_step_q(
-                            x, layer, ckq, cks, cvq, cvs, pos, cfg,
+                # The pool rides the layer loop as its CARRY, as it rides
+                # the tick loop: each layer scatters its rows into the
+                # stacked pool in place and reads at its own index. A
+                # scan's xs and ys are two buffers: as those, every
+                # layer's slab is sliced out of one and written back into
+                # the other, and the pool is copied whole every tick to
+                # become the tick loop's carry again (PERF.md, PR 25).
+                def body(carry, inputs):
+                    x, caches = carry
+                    layer, l = inputs
+                    if kv_int8:
+                        x, *caches = _slot_layer_step_q(
+                            x, layer, *caches, l, pos, cfg,
                             use_kernel=kv_kernel, mesh=mesh,
                         )
-                        return x, (ckq, cks, cvq, cvs)
-                else:
-                    def body(x, inputs):
-                        layer, ck, cv = inputs
-                        x, ck, cv = _slot_layer_step(x, layer, ck, cv, pos, cfg)
-                        return x, (ck, cv)
+                    else:
+                        x, *caches = _slot_layer_step(
+                            x, layer, *caches, l, pos, cfg
+                        )
+                    return (x, tuple(caches)), None
 
-                x, new_caches = lax.scan(
-                    body, x, (params["layers"], *caches)
+                (x, caches), _ = lax.scan(
+                    body, (x, caches), (params["layers"], jnp.arange(nl))
                 )
-                caches = new_caches
                 x = _rms_norm(x, params["ln_f"])
                 logits = jnp.einsum(
                     "bd,dv->bv", x[:, 0], load_weight(params["lm_head"], cfg.dtype),
@@ -1497,7 +1518,9 @@ class StreamingGenerator:
                 # safe: re-admission overwrites [0, P) via prefill and every
                 # later position is rewritten by the tick that reaches it
                 # BEFORE the attention that could read it. Freezing the
-                # caches with a jnp.where would copy the pool every token.
+                # caches with a jnp.where would copy the pool every token,
+                # which nothing in this program does: every write is a
+                # scatter into the carried pool.
                 t = pos - P  # decode ticks completed before this one
                 idx = jnp.minimum(t + 1, self._max_new - 1)
                 # One-hot select over the tiny [B, max_new] buffer.
@@ -1557,9 +1580,11 @@ class StreamingGenerator:
             gen = lax.dynamic_update_slice(gen, emitted_row[None, :], (slot, 0))
             return caches, last_tok, pos, gen
 
-        # Donate the cache pool: admit/tick rebuild it every call, and
-        # without donation each dispatch copies the full [L, B, M, K, Dh]
-        # pair. The run loop rebinds the returned buffers immediately.
+        # Donate the cache pool: the tick writes it in place (the pool is
+        # the carry of its tick and layer loops) and admit rebuilds it, so
+        # the output can be the caller's own buffer; without donation each
+        # dispatch first copies the full [L, B, M, K, Dh] pair. The run
+        # loop rebinds the returned buffers immediately.
         # Params travel as an ARGUMENT, not a closure: a closed-over param
         # tree lowers as jaxpr constants, and at zoo scale (2.5-8 GB) that
         # bloats lowering/compile memory and ships the weights inside the
