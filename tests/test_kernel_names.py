@@ -79,6 +79,13 @@ def qmm():
     )
 
 
+def flash_unequal():
+    x = jnp.zeros((1, 128, H, 192), F32)
+    return lambda: flash.flash_forward(
+        x, x, x[..., :128], scale=1.0, interpret=True
+    )
+
+
 def pallas_names(jaxpr) -> list[str]:
     """The names of the ``pallas_call``s anywhere in a jaxpr."""
     names = []
@@ -98,6 +105,7 @@ def pallas_names(jaxpr) -> list[str]:
     (flash_fwd, ["tk_flash_fwd"]),
     (flash_bwd, ["tk_flash_bwd_dkv", "tk_flash_bwd_dq", "tk_flash_fwd"]),
     (qmm, ["tk_qmatmul"]),
+    (flash_unequal, ["tk_flash_fwd"]),
 ], ids=lambda p: p.__name__ if callable(p) else None)
 def test_the_wrapper_calls_its_kernel_by_its_fixed_name(wrapper, names):
     jaxpr = jax.make_jaxpr(wrapper())().jaxpr

@@ -259,3 +259,201 @@ class TestCapacityDispatch:
             np.asarray(stats_c), np.asarray(stats_d), rtol=1e-6
         )
         assert float(stats_c[0].sum()) == 13 * MOE_CFG.expert_top_k
+
+
+# ------------------------------------------------- the routed expert layer
+# (ops/moe.py: sigmoid scores, a bias that moves the selection alone,
+# normalised weights times routed_scaling, shared experts, no drops.)
+# float32 on the CPU against NumPy loops; 1e-5 absolute on outputs of
+# order one: the grouped form sums a token's k products in another order
+# than the loop, and nothing else differs.
+
+ROUTED_CFG = TransformerConfig(
+    vocab_size=64, d_model=32, n_layers=3, n_heads=2, n_kv_heads=2, d_ff=48,
+    max_seq_len=16, dtype=jnp.float32, kv_lora_rank=16, qk_nope_dim=8,
+    qk_rope_dim=4, v_head_dim=8, rope_interleave=True, first_dense_layers=1,
+    n_experts=8, expert_top_k=3, expert_d_ff=12, n_shared_experts=2,
+    router_score="sigmoid", routed_scaling=2.448,
+)
+
+
+def _routed_layer(rng, bias_scale=0.0):
+    e, d, f = 8, 32, 12
+    n = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    return {
+        "router": n(d, e), "router_bias": bias_scale * n(e),
+        "w_gate": 0.2 * n(e, d, f), "w_up": 0.2 * n(e, d, f),
+        "w_down": 0.2 * n(e, f, d), "ws_gate": 0.2 * n(d, 2 * f),
+        "ws_up": 0.2 * n(d, 2 * f), "ws_down": 0.2 * n(2 * f, d),
+    }
+
+
+def _np_swiglu(x, gate, up, down):
+    g = x @ np.asarray(gate)
+    return ((g / (1 + np.exp(-g))) * (x @ np.asarray(up))) @ np.asarray(down)
+
+
+def _np_routed(h, layer, cfg, shared=True):
+    """A per-token loop over the chosen experts."""
+    x = np.asarray(h, np.float64).reshape(-1, h.shape[-1])
+    s = 1 / (1 + np.exp(-(x @ np.asarray(layer["router"], np.float64))))
+    sel = s + np.asarray(layer["router_bias"], np.float64)
+    out, chosen = np.zeros_like(x), []
+    for t in range(len(x)):
+        idx = np.argsort(-sel[t], kind="stable")[: cfg.expert_top_k]
+        w = s[t, idx] / s[t, idx].sum() * cfg.routed_scaling
+        chosen.append(idx)
+        for wi, e in zip(w, idx):
+            out[t] += wi * _np_swiglu(
+                x[t], layer["w_gate"][e], layer["w_up"][e], layer["w_down"][e]
+            )
+        if shared:
+            out[t] += _np_swiglu(
+                x[t], layer["ws_gate"], layer["ws_up"], layer["ws_down"]
+            )
+    return out.reshape(h.shape), np.asarray(chosen)
+
+
+class TestRoutedExpertLayer:
+    @pytest.mark.parametrize("form", ["grouped", "all_experts"])
+    def test_each_form_equals_the_per_token_loop(self, rng, form, monkeypatch):
+        """Sigmoid scores, the selection bias, the normalisation times
+        2.448 and the shared experts, in both forms of the one sum."""
+        from torchkafka_tpu.ops import moe
+
+        monkeypatch.setattr(
+            moe, "_GROUPED_MIN_PAIRS_PER_EXPERT", 0 if form == "grouped" else 10**9
+        )
+        h = jnp.asarray(rng.normal(size=(2, 9, 32)), jnp.float32)
+        layer = _routed_layer(rng, bias_scale=0.3)
+        out, idx = moe.routed_moe_mlp(h, layer, ROUTED_CFG)
+        ref, chosen = _np_routed(h, layer, ROUTED_CFG)
+        np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5)
+        assert (np.sort(np.asarray(idx).reshape(-1, 3)) == np.sort(chosen)).all()
+
+    def test_the_form_follows_the_static_row_count(self, rng, monkeypatch):
+        from torchkafka_tpu.ops import moe
+
+        called = []
+        for name in ("grouped_experts", "all_experts"):
+            honest = getattr(moe, name)
+            monkeypatch.setattr(
+                moe, name,
+                lambda *a, _h=honest, _n=name: called.append(_n) or _h(*a),
+            )
+        layer = _routed_layer(rng)
+        for rows in (4, 64):  # 12 pairs over 8 experts; 192 pairs
+            moe.routed_moe_mlp(
+                jnp.zeros((1, rows, 32), jnp.float32), layer, ROUTED_CFG
+            )
+        assert called == ["all_experts", "grouped_experts"]
+
+    def test_the_bias_moves_the_selection_and_not_the_weights(self, rng):
+        from torchkafka_tpu.ops.moe import route
+
+        h = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
+        layer = _routed_layer(rng)
+        kw = dict(top_k=3, scaling=2.448)
+        idx0, w0 = route(h, layer["router"], jnp.zeros((8,)), **kw)
+        # A large bias on expert 5 puts it into every selection...
+        bias = jnp.zeros((8,)).at[5].set(10.0)
+        idx1, w1 = route(h, layer["router"], bias, **kw)
+        assert (np.asarray(idx1) == 5).any(axis=1).all()
+        assert not (np.asarray(idx0) == 5).any(axis=1).all()
+        # ...and its weight is still its sigmoid score's share, not 10 more.
+        s = 1 / (1 + np.exp(-(np.asarray(h) @ np.asarray(layer["router"]))))
+        picked = np.take_along_axis(s, np.asarray(idx1), axis=1)
+        np.testing.assert_allclose(
+            np.asarray(w1), picked / picked.sum(1, keepdims=True) * 2.448,
+            rtol=1e-5,
+        )
+        np.testing.assert_allclose(np.asarray(w1).sum(1), 2.448, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(w0).sum(1), 2.448, rtol=1e-5)
+
+    def test_every_token_to_one_expert_and_nothing_dropped(self, rng):
+        """The worst load: all pairs of all tokens on the same experts.
+        A capacity dispatch would drop most of them; here every token
+        gets its full sum."""
+        from torchkafka_tpu.ops import moe
+
+        layer = _routed_layer(rng)
+        layer["router_bias"] = jnp.zeros((8,)).at[jnp.asarray([1, 4, 6])].set(50.0)
+        h = jnp.asarray(rng.normal(size=(1, 40, 32)), jnp.float32)
+        out, idx = moe.routed_moe_mlp(h, layer, ROUTED_CFG)
+        assert (np.sort(np.asarray(idx), axis=-1) == [1, 4, 6]).all()
+        ref, _ = _np_routed(h, layer, ROUTED_CFG)
+        np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5)
+        for form in (moe.grouped_experts, moe.all_experts):
+            x = h.reshape(-1, 32)
+            i, w = moe.route(
+                x, layer["router"], layer["router_bias"], top_k=3, scaling=2.448
+            )
+            got = form(x, i, w, layer["w_gate"], layer["w_up"], layer["w_down"])
+            want, _ = _np_routed(h, layer, ROUTED_CFG, shared=False)
+            np.testing.assert_allclose(np.asarray(got), want[0], atol=1e-5)
+
+    def test_the_shared_experts_see_every_token(self, rng):
+        from torchkafka_tpu.ops import moe
+
+        layer = _routed_layer(rng)
+        h = jnp.asarray(rng.normal(size=(1, 6, 32)), jnp.float32)
+        with_shared, _ = moe.routed_moe_mlp(h, layer, ROUTED_CFG)
+        without, _ = moe.routed_moe_mlp(
+            h, layer, dataclasses.replace(ROUTED_CFG, n_shared_experts=0)
+        )
+        want = _np_swiglu(
+            np.asarray(h[0]), layer["ws_gate"], layer["ws_up"], layer["ws_down"]
+        )
+        np.testing.assert_allclose(
+            np.asarray(with_shared - without)[0], want, atol=1e-5
+        )
+
+    def test_the_leading_dense_layer(self):
+        """``first_dense_layers`` 1: a stacked group of its own, run
+        first, with the dense FFN's width and no router; the forward is
+        the groups' layers applied in order."""
+        from torchkafka_tpu.models.transformer import init_params
+
+        cfg = ROUTED_CFG
+        params = init_params(jax.random.key(0), cfg)
+        assert params["dense_layers"]["w_gate"].shape == (1, 32, 48)
+        assert "router" not in params["dense_layers"]
+        assert params["layers"]["w_gate"].shape == (2, 8, 32, 12)
+        assert params["layers"]["ws_down"].shape == (2, 24, 32)
+        tokens = jnp.arange(10, dtype=jnp.int32).reshape(2, 5)
+        model = Transformer(cfg)
+        x = params["embed"][tokens]
+        for key, n in (("dense_layers", 1), ("layers", 2)):
+            for i in range(n):
+                x, _ = model._layer(x, jax.tree.map(lambda a: a[i], params[key]))
+        from torchkafka_tpu.models.transformer import _rms_norm
+
+        want = _rms_norm(x, params["ln_f"]) @ params["lm_head"]
+        np.testing.assert_allclose(
+            np.asarray(model(params, tokens)), np.asarray(want), atol=1e-5
+        )
+        # Without it every layer is an expert layer, in one group.
+        flat = dataclasses.replace(cfg, first_dense_layers=0)
+        assert "dense_layers" not in init_params(jax.random.key(0), flat)
+
+    def test_the_softmax_family_is_what_it_was(self, rng):
+        """A config without the new fields builds the layer it built:
+        ``_moe_mlp`` through ``Transformer._layer`` and ``_attn_tail``."""
+        from torchkafka_tpu.models.generate import _attn_tail
+        from torchkafka_tpu.models.transformer import init_params
+
+        params = init_params(jax.random.key(0), MOE_CFG)
+        layer = jax.tree.map(lambda a: a[0], params["layers"])
+        assert set(layer) == {
+            "ln1", "ln2", "wq", "wk", "wv", "wo", "router", "w_gate", "w_up",
+            "w_down",
+        }
+        x = jnp.asarray(rng.normal(size=(1, 3, 32)), jnp.float32)
+        attn = jnp.zeros((1, 3, 4, 8), jnp.float32)
+        from torchkafka_tpu.models.transformer import _rms_norm
+
+        want = x + _moe_mlp(_rms_norm(x, layer["ln2"]), layer, MOE_CFG)[0]
+        np.testing.assert_allclose(
+            np.asarray(_attn_tail(x, attn, layer, MOE_CFG)), np.asarray(want),
+            atol=1e-6,
+        )

@@ -1014,13 +1014,36 @@ _TICK_VARIANTS = {
 }
 
 
+# The latent (MLA) pool with routed experts: its tick has no xs/ys twin to
+# be identical to (it was written carried), so it joins the structure test
+# and the served-token tests below, not ``_TICK_VARIANTS``.
+_LATENT_VARIANTS = {
+    "latent": (None, False, None),
+    "latent-auto": (None, "auto", None),
+}
+
+
+def _latent_cfg(**over):
+    """A leading dense layer and two expert layers over latent attention,
+    every width a number of its own."""
+    base = dict(
+        vocab_size=VOCAB, d_model=32, n_layers=3, n_heads=2, n_kv_heads=2,
+        d_ff=48, max_seq_len=P + MAX_NEW, dtype=jnp.float32, kv_lora_rank=16,
+        qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8, rope_interleave=True,
+        first_dense_layers=1, n_experts=8, expert_top_k=2, expert_d_ff=12,
+        n_shared_experts=2, router_score="sigmoid", routed_scaling=2.448,
+    )
+    base.update(over)
+    return TransformerConfig(**base)
+
+
 def _tick_server(variant, ticks=3):
     """A 2-layer toy server of the variant (heads of 128: the kernel's lane
     width; 4 slots and 3 ticks a sync, numbers no model dimension has)."""
     from torchkafka_tpu.parallel import make_mesh
 
-    kv_dtype, kv_kernel, axes = _TICK_VARIANTS[variant]
-    cfg = TransformerConfig(
+    kv_dtype, kv_kernel, axes = {**_TICK_VARIANTS, **_LATENT_VARIANTS}[variant]
+    cfg = _latent_cfg() if variant in _LATENT_VARIANTS else TransformerConfig(
         vocab_size=VOCAB, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
         d_ff=64, max_seq_len=P + MAX_NEW, dtype=jnp.float32,
     )
@@ -1049,14 +1072,21 @@ def _scans(jaxpr):
                     yield from _scans(inner)
 
 
-@pytest.mark.parametrize("variant", ["bf16", "int8", "int8-kernel"])
+@pytest.mark.parametrize(
+    "variant", ["bf16", "int8", "int8-kernel", *_LATENT_VARIANTS]
+)
 def test_layer_scan_carries_the_pool(variant):
     """Structure: in the tick's jaxpr every pool-shaped value of the layer
     scan is a carry. As an input (xs) the pool is sliced a layer at a
     time, as an output (ys) written back a layer at a time into a second
     buffer, and copied whole at the tick's end (PERF.md, PR 25)."""
+    from torchkafka_tpu.models.transformer import _layer_groups
+
     srv, consumer = _tick_server(variant)
-    L, B = srv._cfg.n_layers, 4
+    B = 4
+    # One layer scan a stacked group: one, or the leading dense layers'
+    # and the expert layers' (the pool's layer index runs over both).
+    depths = [nl for _key, nl, _e in _layer_groups(srv._cfg)]
     jaxpr = jax.make_jaxpr(srv._tick_block_raw)(
         srv._params, srv._caches, srv._last_tok, srv._pos, srv._gen,
         jnp.ones((B,), bool), srv._slot_keys,
@@ -1065,24 +1095,25 @@ def test_layer_scan_carries_the_pool(variant):
     slab_shapes = {s[1:] for s in pool_shapes}
     layer_scans = [
         e for e in _scans(jaxpr.jaxpr)
-        if e.params["length"] == L
+        if e.params["length"] in depths
+        and e.params["length"] != srv._ticks_per_sync
         and any(v.aval.shape in pool_shapes for v in e.invars)
     ]
-    assert len(layer_scans) == 1, [e.params["length"] for e in layer_scans]
-    (scan,) = layer_scans
-    nc, nk = scan.params["num_consts"], scan.params["num_carry"]
-    carry_in = [v.aval.shape for v in scan.invars[nc:nc + nk]]
-    carry_out = [v.aval.shape for v in scan.outvars[:nk]]
-    xs = [v.aval.shape for v in scan.invars[nc + nk:]]
-    ys = [v.aval.shape for v in scan.outvars[nk:]]
-    for shape in pool_shapes:
-        n = sum(1 for c in srv._caches if c.shape == shape)
-        assert carry_in.count(shape) == n and carry_out.count(shape) == n
-    moved = pool_shapes | slab_shapes
-    assert not [s for s in xs + ys if s in moved], (xs, ys)
-    assert not [
-        v.aval.shape for v in scan.invars[:nc] if v.aval.shape in moved
-    ]
+    assert sorted(e.params["length"] for e in layer_scans) == sorted(depths)
+    for scan in layer_scans:
+        nc, nk = scan.params["num_consts"], scan.params["num_carry"]
+        carry_in = [v.aval.shape for v in scan.invars[nc:nc + nk]]
+        carry_out = [v.aval.shape for v in scan.outvars[:nk]]
+        xs = [v.aval.shape for v in scan.invars[nc + nk:]]
+        ys = [v.aval.shape for v in scan.outvars[nk:]]
+        for shape in pool_shapes:
+            n = sum(1 for c in srv._caches if c.shape == shape)
+            assert carry_in.count(shape) == n and carry_out.count(shape) == n
+        moved = pool_shapes | slab_shapes
+        assert not [s for s in xs + ys if s in moved], (xs, ys)
+        assert not [
+            v.aval.shape for v in scan.invars[:nc] if v.aval.shape in moved
+        ]
     srv.close()
     consumer.close()
 
@@ -1122,3 +1153,89 @@ def test_carried_tick_equals_xs_ys_reference(variant):
     assert int(np.asarray(got[2]).max()) > P  # the block did decode
     srv.close()
     consumer.close()
+
+
+# ------------------------------------------------ the latent pool, served
+
+
+def _greedy_by_full_forward(cfg, params, prompts, n):
+    """What a served request must read: each next token the argmax of the
+    model's own full forward over the prompt and the tokens so far
+    (``generate``'s lockstep decode is not taught latent attention)."""
+    from torchkafka_tpu.models.transformer import Transformer
+
+    model = jax.jit(Transformer(cfg).__call__)
+    seqs = np.asarray(prompts)
+    for _ in range(n):
+        nxt = np.asarray(jnp.argmax(model(params, jnp.asarray(seqs))[:, -1], -1))
+        seqs = np.concatenate([seqs, nxt[:, None].astype(np.int32)], axis=1)
+    return seqs[:, prompts.shape[1]:]
+
+
+@pytest.mark.parametrize("variant", list(_LATENT_VARIANTS))
+def test_latent_model_served_tokens_and_commit_watermark(variant):
+    """The tiny MLA + expert model through ``run()`` as any other: every
+    served token is the full forward's greedy choice (float32: exact),
+    every completion is committed by the last flush, and the expert and
+    pool counters count what was served."""
+    _dt, kv_kernel, _axes = _LATENT_VARIANTS[variant]
+    cfg = _latent_cfg()
+    params = init_params(jax.random.key(0), cfg)
+    broker = tk.InMemoryBroker()
+    prompts = _topic(broker, 10)
+    consumer = tk.MemoryConsumer(broker, "p", group_id="g")
+    server = StreamingGenerator(
+        consumer, params, cfg, slots=4, prompt_len=P, max_new=MAX_NEW,
+        commit_every=4, ticks_per_sync=3, kv_kernel=kv_kernel,
+    )
+    assert server.metrics.summary()["kv_backend"]["layout"] == "latent"
+    expected = _greedy_by_full_forward(cfg, params, prompts, MAX_NEW)
+    got = {}
+    for rec, toks in server.run(max_records=10):
+        got[(rec.partition, rec.offset)] = toks
+    assert len(got) == 10
+    for (part, off), toks in got.items():
+        np.testing.assert_array_equal(toks, expected[2 * off + part])
+    for p in (0, 1):
+        assert broker.committed("g", tk.TopicPartition("p", p)) == 5
+    s = server.metrics.summary()
+    served_ticks = s["scheduler"]["slot_ticks_served"]
+    assert served_ticks == 10 * (MAX_NEW - 1)
+    # Two expert layers, two choices a token a layer.
+    assert s["expert_layer"]["moe_assignments"] == served_ticks * 2 * 2
+    load = s["expert_layer"]["moe_expert_load"]
+    assert len(load) == 8 and sum(load) >= s["expert_layer"]["moe_assignments"]
+    assert 0 < s["expert_layer"]["moe_experts_touched"] <= sum(load)
+    # The tick of token j reads P + j rows, in each of three layers.
+    need = 10 * 3 * sum(P + j for j in range(1, MAX_NEW))
+    assert s["latent_pool"]["latent_positions_valid"] == need
+    assert s["latent_pool"]["latent_positions_read"] >= need
+    consumer.close()
+
+
+def test_latent_model_crash_before_commit_redelivers_unfinished():
+    cfg = _latent_cfg()
+    params = init_params(jax.random.key(0), cfg)
+    broker = tk.InMemoryBroker()
+    _topic(broker, 8)
+    consumer = tk.MemoryConsumer(broker, "p", group_id="g3")
+    server = StreamingGenerator(
+        consumer, params, cfg, slots=2, prompt_len=P, max_new=MAX_NEW,
+        commit_every=2,
+    )
+    finished = []
+    for rec, _toks in server.run(max_records=8):
+        finished.append((rec.partition, rec.offset))
+        if len(finished) == 4:
+            break  # crash: nothing flushes what finished since the commit
+    consumer.close()
+    committed = {
+        p: broker.committed("g3", tk.TopicPartition("p", p)) or 0 for p in (0, 1)
+    }
+    assert 2 <= sum(committed.values()) <= 4
+    again = tk.MemoryConsumer(broker, "p", group_id="g3")
+    seen = {(r.partition, r.offset) for r in again.poll(max_records=8)}
+    assert seen == {
+        (p, o) for p in (0, 1) for o in range(committed[p], 4)
+    }
+    again.close()

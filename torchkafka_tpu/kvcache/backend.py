@@ -63,8 +63,10 @@ KV_KERNEL_AUTO_MIN_POOL = 1024
 class KVBackend:
     """The resolved serving-cache configuration — what actually serves.
 
-    ``layout``: "dense" (per-slot pool) or "paged" (block pool + per-
-    slot tables). ``int8``: quantized payloads + group-wise scales.
+    ``layout``: "dense" (per-slot pool), "paged" (block pool + per-
+    slot tables) or "latent" (a latent-attention config's per-slot pool:
+    ONE tensor [L, B, M, rank + rope] in the compute dtype, read
+    absorbed). ``int8``: quantized payloads + group-wise scales.
     ``kernel``: the Pallas fill-bounded read engages on decode ticks.
     ``kernel_disabled_reason``: why it does NOT engage (None when it
     does, or when int8 was never requested — there is no kernel
@@ -136,6 +138,40 @@ def _kernel_probe_paged(cfg, block_size: int, on_tpu: bool) -> str | None:
     return None
 
 
+def _resolve_latent(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
+    """A latent-attention config's pool: what is built, and a reasoned
+    refusal of every combination that is not."""
+    what = "the latent (MLA) slot pool"
+    if kv_dtype == "int8":
+        raise ValueError(
+            f"{what} is compute-dtype only: kv_dtype='int8' quantises "
+            "(position, head) groups of K and V, and the latent row has "
+            "neither heads nor a scale scheme yet"
+        )
+    if kv_pages is not None:
+        raise ValueError(
+            f"{what} is a dense per-slot pool: kv_pages (block tables, "
+            "the radix prefix cache, its host tier and the prefill "
+            "hand-off cut from them) address K/V blocks of kv heads, "
+            "which a latent row does not have"
+        )
+    if mesh is not None and mesh.size > 1:
+        raise ValueError(
+            f"{what} serves on one device: the pool has no head axis to "
+            "shard over tp, and the routed expert layer holds every "
+            "expert (no exchange across chips is built)"
+        )
+    if kv_kernel is True:
+        raise ValueError(
+            f"{what} is read by XLA: no Pallas read is built for it, and "
+            "kv_kernel=True never falls back silently"
+        )
+    return KVBackend(
+        layout="latent", int8=False, kernel=False,
+        kernel_disabled_reason=None, chunked=False, data=1, tp=1,
+    )
+
+
 def _mesh_kernel_reason(cfg, mesh, slots: int) -> str | None:
     """None = the shard_map wrapping works on this mesh; else why not.
 
@@ -183,6 +219,11 @@ def resolve_kv_backend(
     if not (kv_kernel is True or kv_kernel is False or kv_kernel == "auto"):
         raise ValueError(
             f"kv_kernel must be True, False or 'auto', got {kv_kernel!r}"
+        )
+    if getattr(cfg, "is_mla", False):
+        return _resolve_latent(
+            cfg, mesh=mesh, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
+            kv_pages=kv_pages,
         )
     int8 = kv_dtype == "int8"
     if kv_kernel is True and not int8:

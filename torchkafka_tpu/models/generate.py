@@ -38,6 +38,9 @@ from torchkafka_tpu.models.quant import QTensor, embed_rows, load_weight, quanti
 from torchkafka_tpu.models.transformer import (
     Transformer,
     TransformerConfig,
+    _arch_refusal,
+    _dense_mlp,
+    _layer_groups,
     _moe_mlp,
     _rms_norm,
     _rope,
@@ -313,21 +316,34 @@ def _attend_cached(
 
 def _attn_tail(x, attn, layer, cfg):
     """Post-attention residual: output projection + the MLP block. Shared
-    by the bf16 cache read (``_attend_cached``) and the int8-KV read
-    (serve._attend_cached_q8), so the layer math has one definition."""
+    by the bf16 cache read (``_attend_cached``), the int8-KV read
+    (serve._attend_cached_q8) and the latent read
+    (serve._slot_layer_step_latent), so the layer math has one
+    definition."""
+    return _attn_tail_routing(x, attn, layer, cfg)[0]
+
+
+def _attn_tail_routing(x, attn, layer, cfg):
+    """``_attn_tail`` and the expert choices it made: (x, routing [B, S,
+    top_k] for a sigmoid-routed expert layer, else None)."""
     x = x + jnp.einsum("bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype))
     h = _rms_norm(x, layer["ln2"])
-    if cfg.is_moe:
-        # Decode always routes EXACTLY (dense dispatch) regardless of
-        # cfg.moe_dispatch: capacity drops are a training
-        # throughput/regularization tradeoff; at inference every token
-        # gets its routed experts (standard MoE serving semantics — see
-        # the moe_dispatch config comment).
-        mlp_out, _stats = _moe_mlp(h, layer, cfg)
-        return x + mlp_out
-    gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, load_weight(layer["w_gate"], cfg.dtype)))
-    up = jnp.einsum("bsd,df->bsf", h, load_weight(layer["w_up"], cfg.dtype))
-    return x + jnp.einsum("bsf,fd->bsd", gate * up, load_weight(layer["w_down"], cfg.dtype))
+    if "router" not in layer:
+        return x + _dense_mlp(h, layer, cfg), None
+    if cfg.routed_moe:
+        # The one routed expert layer, prefill's too (ops/moe.py): its
+        # form follows the static row count, and no token is dropped.
+        from torchkafka_tpu.ops.moe import routed_moe_mlp
+
+        mlp_out, routing = routed_moe_mlp(h, layer, cfg)
+        return x + mlp_out, routing
+    # Decode always routes EXACTLY (dense dispatch) regardless of
+    # cfg.moe_dispatch: capacity drops are a training
+    # throughput/regularization tradeoff; at inference every token
+    # gets its routed experts (standard MoE serving semantics — see
+    # the moe_dispatch config comment).
+    mlp_out, _stats = _moe_mlp(h, layer, cfg)
+    return x + mlp_out, None
 
 
 def _project_qkv(x, layer, cfg):
@@ -382,6 +398,9 @@ def prefill(
         model = Transformer(dataclasses.replace(cfg, attn_impl="auto"), mesh)
     else:
         model = Transformer(cfg, mesh)
+    if cfg.is_mla:
+        logits, latents, _routing = latent_forward(params, model, tokens)
+        return logits, latents
     if mesh is not None:
         tokens = lax.with_sharding_constraint(
             tokens, slot_sharding(mesh, tokens.ndim)
@@ -411,6 +430,36 @@ def prefill(
     cache_k = lax.dynamic_update_slice(cache_k, ks.astype(cfg.dtype), (0, 0, 0, 0, 0))
     cache_v = lax.dynamic_update_slice(cache_v, vs.astype(cfg.dtype), (0, 0, 0, 0, 0))
     return logits, _constrain_cache(KVCache(cache_k, cache_v), mesh)
+
+
+def latent_forward(params, model: Transformer, tokens: jax.Array):
+    """A latent-attention config's forward over ``tokens`` [B, S], with
+    what serving and the benchmark keep of it: (last-position logits [B,
+    V]; the rows the cache holds, [L, B, S, rank + rope], L over the
+    leading dense layers and then the expert layers; the expert layers'
+    routing [L_moe, B, S, top_k], or None for a config without experts).
+    Unlike ``prefill``'s ``KVCache`` the rows are S long, not a pool: the
+    caller writes them where its pool keeps them."""
+    cfg = model.cfg
+    x = embed_rows(params["embed"], tokens, cfg.dtype)
+
+    def capture(x, layer):
+        x, _stats, cached = model._layer_capture(x, layer)
+        return x, cached
+
+    latents, routing = [], None
+    for key, _nl, expert_mlp in _layer_groups(cfg):
+        x, (lat, rt) = lax.scan(capture, x, params[key])
+        latents.append(lat)
+        if expert_mlp:
+            routing = rt
+    x = _rms_norm(x, params["ln_f"])
+    logits = jnp.einsum(
+        "bd,dv->bv", x[:, -1], load_weight(params["lm_head"], cfg.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    latents = latents[0] if len(latents) == 1 else jnp.concatenate(latents)
+    return logits, latents.astype(cfg.dtype), routing
 
 
 def _decode_one(
@@ -457,6 +506,9 @@ def generate(
     ``serving_shardings`` layouts (kv heads shard over tp, batch over
     data); token-exact vs the mesh-less path (differential-tested)."""
     check_sampling_params(top_k, top_p)
+    why = _arch_refusal(cfg, "generate()'s lockstep decode")
+    if why:
+        raise ValueError(why)
     batch, seq = prompt.shape
     if mesh is not None:
         check_serving_mesh(cfg, mesh, batch=batch)

@@ -114,6 +114,38 @@ class TransformerConfig:
     # stacks (compile time independent of depth — the reason scan is the
     # default structure). 1 = never unroll.
     scan_unroll: int | None = None
+    # ---- What a checkpoint's architecture states (none is a tuning knob,
+    # and a config that leaves them at their defaults builds what it built
+    # before they existed).
+    # Latent attention (MLA, DeepSeek-V2/V3): ``kv_lora_rank`` > 0 caches
+    # ONE tensor a layer, the normed ``kv_lora_rank``-wide latent beside
+    # the roped ``qk_rope_dim``-wide key all heads share; a head's q/k
+    # width is ``qk_nope_dim + qk_rope_dim``, its v width ``v_head_dim``
+    # (models/mla.py). No q compression (``q_lora_rank``) is built.
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # RoPE over interleaved pairs (2i, 2i+1) instead of the split halves
+    # (i, i + D/2): how the checkpoint's q/k columns are ordered.
+    rope_interleave: bool = False
+    # The first ``first_dense_layers`` layers keep a dense SwiGLU of
+    # ``d_ff`` (``params["dense_layers"]``, run first); the expert layers
+    # (``params["layers"]``) follow. The cache's layer index runs over both.
+    first_dense_layers: int = 0
+    # An expert's FFN width (0 = ``d_ff``) and the shared experts every
+    # token passes through beside its routed ones (one SwiGLU of
+    # ``n_shared_experts * expert_d_ff``).
+    expert_d_ff: int = 0
+    n_shared_experts: int = 0
+    # 'softmax': the gates are the renormalised top-k of a softmax (the
+    # ``moe_dispatch`` family above). 'sigmoid' (DeepSeek-V3's
+    # ``noaux_tc``): scores are sigmoids, a per-expert bias moves the
+    # SELECTION alone, the selected scores are divided by their sum and
+    # multiplied by ``routed_scaling``; served by the routed expert layer
+    # (ops/moe.py), which drops no token at any load.
+    router_score: str = "softmax"
+    routed_scaling: float = 1.0
 
     @property
     def head_dim(self) -> int:
@@ -122,6 +154,28 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def routed_moe(self) -> bool:
+        """Expert layers of the sigmoid-scored kind (ops/moe.py)."""
+        return self.n_experts > 0 and self.router_score == "sigmoid"
+
+    @property
+    def latent_dim(self) -> int:
+        """What MLA caches a position a layer: latent beside roped key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def moe_d_ff(self) -> int:
+        return self.expert_d_ff or self.d_ff
 
     def __post_init__(self) -> None:
         if self.d_model % self.n_heads:
@@ -139,6 +193,46 @@ class TransformerConfig:
             raise ValueError("capacity_factor must be positive")
         if self.moe_group_size < 1:
             raise ValueError("moe_group_size must be >= 1")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router_score must be 'softmax' or 'sigmoid', got "
+                f"{self.router_score!r}"
+            )
+        if self.is_mla and not (
+            self.qk_nope_dim > 0 and self.v_head_dim > 0
+            and self.qk_rope_dim > 0 and self.qk_rope_dim % 2 == 0
+        ):
+            raise ValueError(
+                "kv_lora_rank > 0 (latent attention) needs qk_nope_dim, "
+                "v_head_dim and an even qk_rope_dim"
+            )
+        if self.is_moe and self.routed_moe != self.is_mla:
+            raise ValueError(
+                "router_score='sigmoid' (the routed expert layer, a "
+                "leading dense group) and latent attention (kv_lora_rank "
+                "> 0) are built together only: the grouped-query decode "
+                'loops scan params["layers"] alone, and the latent '
+                "layers' experts are the routed ones"
+            )
+        if self.rope_interleave and not self.is_mla:
+            raise ValueError(
+                "rope_interleave is read by latent attention alone "
+                "(kv_lora_rank > 0): the grouped-query paths rotate split "
+                "halves"
+            )
+        if not self.routed_moe and (
+            self.first_dense_layers or self.n_shared_experts
+            or self.expert_d_ff or self.routed_scaling != 1.0
+        ):
+            raise ValueError(
+                "first_dense_layers, n_shared_experts, expert_d_ff and "
+                "routed_scaling describe the sigmoid-routed expert layer: "
+                "set n_experts > 0 and router_score='sigmoid'"
+            )
+        if not 0 <= self.first_dense_layers < max(self.n_layers, 1):
+            raise ValueError(
+                "first_dense_layers must leave at least one expert layer"
+            )
 
 
 # --------------------------------------------------------------------- params
@@ -154,6 +248,9 @@ def param_specs(cfg: TransformerConfig) -> dict:
     sharded over ``ep``. Mesh axes absent from the actual Mesh are stripped
     by ``shardings_for_mesh``.
     """
+    why = _arch_refusal(cfg, "param_specs (a sharded layout)")
+    if why:
+        raise ValueError(why)
     if cfg.is_moe:
         mlp = {
             "router": P(None, "fsdp", None),  # [L, D, E] — replicated over ep
@@ -191,6 +288,75 @@ def param_specs(cfg: TransformerConfig) -> dict:
     }
 
 
+def _layer_groups(cfg: TransformerConfig) -> tuple[tuple[str, int, bool], ...]:
+    """The stacked groups of a parameter tree in the order they run:
+    ``(key, layers, expert_mlp)``. One group, ``"layers"``, unless the
+    architecture leads with dense layers (``first_dense_layers``)."""
+    lead = cfg.first_dense_layers
+    groups = (("dense_layers", lead, False),) if lead else ()
+    return groups + (("layers", cfg.n_layers - lead, cfg.is_moe),)
+
+
+def _arch_shapes(cfg: TransformerConfig, expert_mlp: bool) -> dict:
+    """One layer's tensors of a latent-attention config: name -> (shape,
+    fan_in or None for a norm's scale or the selection bias)."""
+    dm, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    shapes = {
+        "ln1": ((dm,), None), "ln2": ((dm,), None),
+        "wq": ((dm, h, cfg.qk_head_dim), dm),
+        # One projection gives the latent and the shared roped key.
+        "wkva": ((dm, cfg.latent_dim), dm),
+        "kv_norm": ((r,), None),
+        # Up-projection of the latent: a head's k_nope beside its v.
+        "wkvb": ((r, h, cfg.qk_nope_dim + cfg.v_head_dim), r),
+        "wo": ((h, cfg.v_head_dim, dm), h * cfg.v_head_dim),
+    }
+    lead, f = (), cfg.d_ff
+    if expert_mlp:
+        e, f = cfg.n_experts, cfg.moe_d_ff
+        lead, fs = (e,), cfg.n_shared_experts * f
+        shapes.update(router=((dm, e), dm), router_bias=((e,), None))
+        if fs:  # the shared experts, one SwiGLU
+            shapes.update(
+                ws_gate=((dm, fs), dm), ws_up=((dm, fs), dm),
+                ws_down=((fs, dm), fs),
+            )
+    shapes.update(
+        w_gate=(lead + (dm, f), dm), w_up=(lead + (dm, f), dm),
+        w_down=(lead + (f, dm), f),
+    )
+    return shapes
+
+
+def _arch_init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
+    """``init_params`` for the configs ``_arch_shapes`` describes: scaled
+    normals, norms at one, the selection bias at zero; every tensor of
+    every group from a key of its own."""
+    pd, dm, v = cfg.param_dtype, cfg.d_model, cfg.vocab_size
+
+    def draw(key, shape, fan_in):
+        if fan_in is None:  # a norm's scale, or the selection bias
+            return jnp.ones(shape, pd)
+        return (jax.random.normal(key, shape, pd) / math.sqrt(fan_in)).astype(pd)
+
+    k_embed, k_head, k_groups = jax.random.split(rng, 3)
+    out = {
+        "embed": draw(k_embed, (v, dm), dm),
+        "ln_f": jnp.ones((dm,), pd),
+        "lm_head": draw(k_head, (dm, v), dm),
+    }
+    for g, (key, nl, expert_mlp) in enumerate(_layer_groups(cfg)):
+        shapes = _arch_shapes(cfg, expert_mlp)
+        keys = jax.random.split(jax.random.fold_in(k_groups, g), len(shapes))
+        out[key] = {
+            name: draw(k, (nl, *shape), fan_in)
+            for k, (name, (shape, fan_in)) in zip(keys, shapes.items())
+        }
+        if "router_bias" in out[key]:
+            out[key]["router_bias"] = jnp.zeros((nl, cfg.n_experts), pd)
+    return out
+
+
 def shardings_for_mesh(mesh: Mesh, specs: Any) -> Any:
     """Convert specs → NamedShardings, dropping axis names the mesh lacks."""
 
@@ -213,6 +379,8 @@ def shardings_for_mesh(mesh: Mesh, specs: Any) -> Any:
 
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     """Scaled-normal init, stacked [L, ...] per layer tensor."""
+    if cfg.is_mla:
+        return _arch_init_params(rng, cfg)
     keys = jax.random.split(rng, 10)
     dm, dff, nl = cfg.d_model, cfg.d_ff, cfg.n_layers
     h, k, dh, v = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.vocab_size
@@ -421,10 +589,15 @@ def _moe_mlp_capacity(
     return out, stats
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def _rope(
+    x: jax.Array, positions: jax.Array, theta: float, interleave: bool = False
+) -> jax.Array:
     """Rotary embedding. x: [B, S, H, D]; positions: [S] global positions
     shared across the batch, or [B, S] per-row positions (the continuous-
-    batching server's slots sit at different depths)."""
+    batching server's slots sit at different depths). Pair i rotates by
+    ``position * theta**(-2i/D)``; its two members are columns (i, i +
+    D/2), or (2i, 2i + 1) with ``interleave`` (``TransformerConfig.
+    rope_interleave``: how a checkpoint orders its columns)."""
     dim = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [(B,) S, D/2]
@@ -432,9 +605,35 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
         angles = angles[None]  # broadcast over batch
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if interleave:
+        xf = x.astype(jnp.float32).reshape(*x.shape[:-1], dim // 2, 2)
+        x1, x2 = xf[..., 0], xf[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def _dense_mlp(h: jax.Array, layer: Mapping[str, jax.Array], cfg) -> jax.Array:
+    """SwiGLU on normed activations h [B, S, D]."""
+    gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, load_weight(layer["w_gate"], cfg.dtype)))
+    up = jnp.einsum("bsd,df->bsf", h, load_weight(layer["w_up"], cfg.dtype))
+    return jnp.einsum("bsf,fd->bsd", gate * up, load_weight(layer["w_down"], cfg.dtype))
+
+
+def _arch_refusal(cfg: "TransformerConfig", what: str) -> str | None:
+    """Why ``what`` does not take a latent-attention config (None: it
+    does, the config is not one)."""
+    if not cfg.is_mla:
+        return None
+    return (
+        f"{what} is not built for latent attention (kv_lora_rank > 0) and "
+        "its routed expert layer: these configs serve on one device "
+        "through StreamingGenerator's dense slot pool (compute-dtype "
+        "cache) and run Transformer's forward; nothing else has been "
+        "taught their layouts"
+    )
 
 
 class Transformer:
@@ -443,6 +642,10 @@ class Transformer:
     def __init__(self, cfg: TransformerConfig, mesh: Mesh | None = None):
         self.cfg = cfg
         self.mesh = mesh
+        if mesh is not None and mesh.size > 1:
+            why = _arch_refusal(cfg, "a mesh of more than one device")
+            if why:
+                raise ValueError(why)
         sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
         if cfg.attn_impl in ("ring", "ulysses") and sp_size <= 1:
             # An *explicitly* requested sequence-parallel impl that cannot
@@ -544,9 +747,45 @@ class Transformer:
     ) -> tuple[jax.Array, jax.Array]:
         """One decoder layer. Returns (activation, router stats [2, E] for
         MoE configs / [2, 1] zeros otherwise — see ``router_aux``)."""
+        x, stats, _cached = self._layer_capture(x, layer)
+        return x, stats
+
+    def _layer_capture(self, x: jax.Array, layer: Mapping[str, jax.Array]):
+        """``_layer`` with what serving keeps of it: (activation, router
+        stats, capture). For a latent-attention config the capture is
+        ``(latent [B, S, rank + rope], routing [B, S, top_k] | None)``,
+        the layer's cache rows and its expert choices; otherwise None
+        (``generate.prefill`` computes k and v beside the layer)."""
         cfg = self.cfg
         positions = self._seq_positions(x.shape[1])
         h = _rms_norm(x, layer["ln1"])
+        latent = None
+        if cfg.is_mla:
+            from torchkafka_tpu.models import mla
+
+            q_nope, q_rope, latent = mla.project(h, layer, cfg, positions)
+            attn = mla.attend_full(
+                q_nope, q_rope, latent, layer, cfg, use_flash=self._use_flash
+            )
+        else:
+            attn = self._gqa(h, layer, positions)
+        x = x + jnp.einsum("bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype))
+        h = _rms_norm(x, layer["ln2"])
+        stats, routing = jnp.zeros((2, 1), jnp.float32), None
+        if "router" not in layer:  # a dense layer (all of a dense config's)
+            x = x + _dense_mlp(h, layer, cfg)
+        elif cfg.routed_moe:
+            from torchkafka_tpu.ops.moe import routed_moe_mlp
+
+            mlp_out, routing = routed_moe_mlp(h, layer, cfg)
+            x = x + mlp_out
+        else:
+            mlp_out, stats = self._moe_mlp(h, layer)
+            x = x + mlp_out
+        return x, stats, (latent, routing) if cfg.is_mla else None
+
+    def _gqa(self, h, layer, positions):
+        cfg = self.cfg
         q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
         k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
         v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
@@ -562,16 +801,7 @@ class Transformer:
             rep = cfg.n_heads // cfg.n_kv_heads
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        attn = self._attention(q, k, v)
-        x = x + jnp.einsum("bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype))
-        h = _rms_norm(x, layer["ln2"])
-        if cfg.is_moe:
-            mlp_out, stats = self._moe_mlp(h, layer)
-            return x + mlp_out, stats
-        gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, load_weight(layer["w_gate"], cfg.dtype)))
-        up = jnp.einsum("bsd,df->bsf", h, load_weight(layer["w_up"], cfg.dtype))
-        x = x + jnp.einsum("bsf,fd->bsd", gate * up, load_weight(layer["w_down"], cfg.dtype))
-        return x, jnp.zeros((2, 1), jnp.float32)
+        return self._attention(q, k, v)
 
     def trunk(
         self, params: dict, tokens: jax.Array
@@ -585,6 +815,7 @@ class Transformer:
         n_tokens = tokens.shape[0] * tokens.shape[1]
 
         if self.mesh is not None and self.mesh.shape.get("pp", 1) > 1:
+            # (never a latent or sigmoid-routed config: __init__ refused)
             # GPipe over the stacked layers; embed/head/norm stay outside the
             # pipeline (replicated across pp). Router stats accumulate
             # through the schedule (valid-tick masked) and psum across
@@ -636,7 +867,13 @@ class Transformer:
                     cfg.n_layers if cfg.n_layers <= 8 and not weight_sharded
                     else 1
                 )
-            x, stats = lax.scan(body, x, params["layers"], unroll=unroll)
+            # One stacked group, or the leading dense layers and then the
+            # expert layers (``first_dense_layers``).
+            stats = []
+            for key, nl, _expert_mlp in _layer_groups(cfg):
+                x, st = lax.scan(body, x, params[key], unroll=min(unroll, nl))
+                stats.append(st)
+            stats = stats[0] if len(stats) == 1 else jnp.concatenate(stats)
         # stats: [L, 2, E] token-summed routing statistics; per-layer aux,
         # averaged over layers (identical math in both branches).
         aux = jnp.mean(jax.vmap(lambda s: router_aux(s, n_tokens))(stats))
@@ -754,6 +991,11 @@ def make_train_step(
     step_fn(params, opt_state, tokens, mask) → (params, opt_state, loss);
     donates params/opt_state, so the caller rebinds them every step.
     """
+    why = _arch_refusal(cfg, "make_train_step")
+    if why:
+        # No test holds their loss and gradients to a reference yet, and
+        # the routed expert layer carries no load-balance term.
+        raise ValueError(why)
     model = Transformer(cfg, mesh)
     p_shardings = shardings_for_mesh(mesh, param_specs(cfg))
     tok_sharding = NamedSharding(mesh, batch_spec(mesh))

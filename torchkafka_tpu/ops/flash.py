@@ -143,8 +143,12 @@ def _kv_index(n_q_heads: int, n_kv_heads: int):
 def _flash_fwd_bhsd(
     q, k, v, *, causal: bool, block_q: int, block_k: int, interpret: bool,
     q_offset=0, k_offset=0, n_q_heads: int = 1, n_kv_heads: int = 1,
+    scale: float | None = None,
 ):
-    """q: [B·H, Sq, D]; k,v: [B·K, Sk, D] → ([B·H, Sq, D], lse f32).
+    """q: [B·H, Sq, D]; k: [B·K, Sk, D]; v: [B·K, Sk, Dv] → ([B·H, Sq,
+    Dv], lse f32). Dv is D everywhere but in ``flash_forward`` (latent
+    attention's heads are wider in q/k than in v); ``scale`` defaults to
+    ``1/sqrt(D)``.
 
     ``q_offset``/``k_offset`` are the global positions of row 0 (traced i32
     scalars, SMEM) — this is what lets the same kernel serve the single-chip
@@ -153,8 +157,9 @@ def _flash_fwd_bhsd(
     served by the kv index map, not by materialising repeated heads.
     """
     bh, sq, d = q.shape
-    sk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    sk, dv = k.shape[1], v.shape[2]
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     grid = (bh, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k))
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k
@@ -165,22 +170,22 @@ def _flash_fwd_bhsd(
     return pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **vmem),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0), **vmem),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0), **vmem),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (kv(b), j, 0), **vmem),
             _smem_spec(),
             _smem_spec(),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **vmem),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0), **vmem),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0), **vmem),
         ],
-        scratch_shapes=_scratch([(block_q, d), (block_q, 128), (block_q, 128)]),
+        scratch_shapes=_scratch([(block_q, dv), (block_q, 128), (block_q, 128)]),
         interpret=interpret,
         name="tk_flash_fwd",
     )(q, k, v, qoff, koff)
@@ -439,6 +444,32 @@ def flash_attention(
     candidate block divides S, e.g. S < 128 or odd sizes).
     """
     return _flash_impl(q, k, v, causal, block_q, block_k, interpret)
+
+
+def flash_forward(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
+    causal: bool = True, interpret: bool | None = None,
+) -> jax.Array | None:
+    """Forward-only flash for heads wider in q/k than in v (latent
+    attention's prefill: q/k 192, v 128). q, k: [B, S, H, D]; v: [B, S, H,
+    Dv] → [B, S, H, Dv], or None where S does not tile (the caller has
+    its dense form). q and k are zero-padded to the lanes' multiple of
+    128, which leaves every score as it was; ``scale`` is the caller's,
+    since it follows the unpadded width. The kernel and its arithmetic
+    are ``flash_attention``'s."""
+    b, s, h, d = q.shape
+    block_q, block_k, interpret = _resolve(s, None, None, interpret)
+    if not _supported(s, block_q, block_k):
+        return None
+    pad = -d % 128
+    if pad:
+        q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),)) for a in (q, k))
+    out, _ = _flash_fwd_bhsd(
+        _to_bhsd(q), _to_bhsd(k), _to_bhsd(v),
+        causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
+        n_q_heads=h, n_kv_heads=k.shape[2], scale=scale,
+    )
+    return _from_bhsd(out, b, h)
 
 
 def _to_bhsd(x):

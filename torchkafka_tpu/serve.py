@@ -67,6 +67,7 @@ from torchkafka_tpu.resilience.crashpoint import crash_hook
 from torchkafka_tpu.models.generate import (
     _attend_cached,
     _attn_tail,
+    _attn_tail_routing,
     _project_qkv,
     check_sampling_params,
     check_serving_mesh,
@@ -83,7 +84,13 @@ from torchkafka_tpu.models.generate import (
     slot_sharding,
 )
 from torchkafka_tpu.models.quant import embed_rows, load_weight
-from torchkafka_tpu.models.transformer import TransformerConfig, _rms_norm, _rope
+from torchkafka_tpu.models.transformer import (
+    TransformerConfig,
+    _arch_refusal,
+    _layer_groups,
+    _rms_norm,
+    _rope,
+)
 from torchkafka_tpu.source.records import Record, TopicPartition
 from torchkafka_tpu.utils import tracing as xprof
 from torchkafka_tpu.utils.metrics import Gauge, LatencyHistogram, RateMeter
@@ -293,6 +300,20 @@ class ServeMetrics:
         # ran: every slot on the dense path (the admit program prefills
         # the whole [slots, prompt] batch whatever its mask), the admitted
         # rows alone on the paths that prefill row by row
+        # The routed expert layer and the latent pool (a latent-attention
+        # config; all zero otherwise). Cumulative like the scheduler's.
+        self.moe_assignments = RateMeter()  # (token, choice) pairs of the
+        # served slot-ticks: served ticks x top_k x expert layers
+        self.moe_experts_touched = RateMeter()  # sum over expert layers and
+        # ticks of the experts that got at least one pair from a slot the
+        # device held active (what a tick has to stream, counted on the
+        # device and fetched with the sync's other arrays)
+        self.moe_expert_load: np.ndarray = np.zeros((0,), np.int64)  # those
+        # pairs by expert, summed over layers and ticks
+        self.latent_positions_valid = RateMeter()  # cached rows the served
+        # ticks needed, summed over layers: a tick at position p needs p
+        self.latent_positions_read = RateMeter()  # rows the read fetched for
+        # every slot of every tick run, needed or not
         self.output_capped = RateMeter()  # slots force-finished by a
         # per-record output budget (max_new_of) at sync granularity
         # Paged prefix cache (kv_pages=, torchkafka_tpu/kvcache): all zero
@@ -467,6 +488,15 @@ class ServeMetrics:
                 "admit_rows": self.admit_rows.count,
                 "admit_rows_prefilled": self.admit_rows_prefilled.count,
             },
+            "expert_layer": {
+                "moe_assignments": self.moe_assignments.count,
+                "moe_experts_touched": self.moe_experts_touched.count,
+                "moe_expert_load": self.moe_expert_load.tolist(),
+            },
+            "latent_pool": {
+                "latent_positions_valid": self.latent_positions_valid.count,
+                "latent_positions_read": self.latent_positions_read.count,
+            },
             "output_capped": self.output_capped.count,
             "prefix_cache": self.cache_summary(),
             "tenant_cache": self.tenant_cache_summary(),
@@ -600,6 +630,16 @@ class ServeMetrics:
                 (f"{name}_total", "counter", value)
                 for name, value in s["scheduler"].items()
             ),
+            *(
+                (f"{name}_total", "counter", value)
+                for section in ("expert_layer", "latent_pool")
+                for name, value in s[section].items()
+                if name != "moe_expert_load"
+            ),
+            ("moe_expert_load_total", "counter", [
+                (format_labels(expert=str(e)), v)
+                for e, v in enumerate(s["expert_layer"]["moe_expert_load"])
+            ] or 0),
             ("output_capped_total", "counter", s["output_capped"]),
             ("tenant_prefix_cache_hits_total", "counter", [
                 (format_labels(tenant=t), v["hits"])
@@ -676,6 +716,35 @@ def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg):
         x, q, _layer_of(cache_k, l), _layer_of(cache_v, l), valid, layer, cfg
     )
     return x, cache_k, cache_v
+
+
+def _count_routing(stats, routing, act):
+    """(experts touched, pairs by expert) with one expert layer's routing
+    [B, 1, top_k] of a tick added: a pair counts where the device holds
+    the slot active, an expert is touched where it got at least one."""
+    touched, load = stats
+    pairs = jnp.zeros_like(load).at[routing.reshape(-1)].add(
+        jnp.repeat(act, routing.shape[-1]).astype(load.dtype)
+    )
+    return touched + jnp.sum(pairs > 0), load + pairs
+
+
+def _slot_layer_step_latent(x, layer, pool, l, pos_b, cfg):
+    """``_slot_layer_step`` for a latent-attention config: the stacked pool
+    is ONE tensor [L, B, M, rank + rope]. The slot's row (the normed
+    latent beside the roped shared key, models/mla.py) is scattered into
+    layer ``l`` in place, and the read is ABSORBED: the heads' queries meet
+    the cached rows themselves, nothing is up-projected for the pool's
+    positions. Returns (x, pool, routing [B, 1, top_k] | None)."""
+    from torchkafka_tpu.models import mla
+
+    h = _rms_norm(x, layer["ln1"])
+    q_nope, q_rope, latent = mla.project(h, layer, cfg, pos_b[:, None])
+    rows = jnp.arange(pool.shape[1])
+    pool = pool.at[l, rows, pos_b].set(latent[:, 0].astype(pool.dtype))
+    attn = mla.attend_absorbed(q_nope, q_rope, pool, l, pos_b, layer, cfg)
+    x, routing = _attn_tail_routing(x, attn, layer, cfg)
+    return x, pool, routing
 
 
 class _PendingPrefill:
@@ -1113,6 +1182,15 @@ class StreamingGenerator:
         producer."""
         if prompt_len + max_new > cfg.max_seq_len:
             raise ValueError("prompt_len + max_new exceeds cfg.max_seq_len")
+        for what, asked in (
+            ("kv_tier (the radix cache's host tier)", kv_tier is not None),
+            ("prefill_role (the disaggregated prefill hand-off)", prefill_role),
+            ("a mesh of more than one device",
+             mesh is not None and mesh.size > 1),
+        ):
+            why = asked and _arch_refusal(cfg, what)
+            if why:
+                raise ValueError(why)
         if max_new < 2:
             raise ValueError("max_new must be >= 2 (prefill emits token 0)")
         if ticks_per_sync < 1:
@@ -1350,6 +1428,9 @@ class StreamingGenerator:
         # resolved KVBackend this server actually serves with — a paged
         # pool too small for one slot re-resolves as dense here.
         self._kv_backend: KVBackend | None = None
+        # What the last tick block's routed expert layers counted on the
+        # device (_build); None for a config without one.
+        self._tick_stats = None
         self._build()
         if self._kv_backend is not None:
             self.metrics.note_backend(self._kv_backend)
@@ -1364,6 +1445,11 @@ class StreamingGenerator:
                 "kv_pages to hold at least one slot"
             )
         cfg = self._cfg
+        # A latent-attention config (models/mla.py) runs the same slot
+        # machinery over ONE cache tensor, [L, B, M, rank + rope] in the
+        # compute dtype; kvcache.resolve_kv_backend refuses every other
+        # combination with its reason.
+        latent = cfg.is_mla
         B, P, M = self._slots, self._prompt_len, self._max_len
         nl, kh, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
         temp = self._temperature
@@ -1433,7 +1519,15 @@ class StreamingGenerator:
             caches, last_tok, pos, gen = pin_state(caches, last_tok, pos, gen)
             logits, fresh = prefill(params, cfg, prompts, M, mesh)
             sel = admit_mask[None, :, None, None, None]  # over [L, B, M, K, Dh]
-            if kv_int8:
+            if latent:
+                # The prompt window's rows [L, B, P, C] of the admitted
+                # slots, written in place: the rest of the pool is not
+                # touched.
+                (pool,) = caches
+                kept = lax.slice_in_dim(pool, 0, P, axis=2)
+                rows = jnp.where(admit_mask[None, :, None, None], fresh, kept)
+                caches = (lax.dynamic_update_slice(pool, rows, (0, 0, 0, 0)),)
+            elif kv_int8:
                 fkq, fks = _quant_kv(fresh.k)
                 fvq, fvs = _quant_kv(fresh.v)
                 if kv_kernel:
@@ -1480,7 +1574,7 @@ class StreamingGenerator:
             caches, last_tok, pos, gen = pin_state(caches, last_tok, pos, gen)
 
             def one(carry, _):
-                caches, last_tok, pos, gen, done_latch, n_out = carry
+                caches, last_tok, pos, gen, done_latch, n_out, stats = carry
                 act = active_in & ~done_latch
                 x = embed_rows(params["embed"], last_tok, cfg.dtype)[:, None, :]
 
@@ -1492,9 +1586,16 @@ class StreamingGenerator:
                 # the other, and the pool is copied whole every tick to
                 # become the tick loop's carry again (PERF.md, PR 25).
                 def body(carry, inputs):
-                    x, caches = carry
+                    x, caches, stats = carry
                     layer, l = inputs
-                    if kv_int8:
+                    if latent:
+                        x, pool, routing = _slot_layer_step_latent(
+                            x, layer, *caches, l, pos, cfg
+                        )
+                        caches = (pool,)
+                        if routing is not None:
+                            stats = _count_routing(stats, routing, act)
+                    elif kv_int8:
                         x, *caches = _slot_layer_step_q(
                             x, layer, *caches, l, pos, cfg,
                             use_kernel=kv_kernel, mesh=mesh,
@@ -1503,11 +1604,17 @@ class StreamingGenerator:
                         x, *caches = _slot_layer_step(
                             x, layer, *caches, l, pos, cfg
                         )
-                    return (x, tuple(caches)), None
+                    return (x, tuple(caches), stats), None
 
-                (x, caches), _ = lax.scan(
-                    body, (x, caches), (params["layers"], jnp.arange(nl))
-                )
+                # The layer index runs over the leading dense layers and
+                # then the expert layers (one group for every other config).
+                first = 0
+                for key, n, _expert_mlp in _layer_groups(cfg):
+                    (x, caches, stats), _ = lax.scan(
+                        body, (x, caches, stats),
+                        (params[key], jnp.arange(first, first + n)),
+                    )
+                    first += n
                 x = _rms_norm(x, params["ln_f"])
                 logits = jnp.einsum(
                     "bd,dv->bv", x[:, 0], load_weight(params["lm_head"], cfg.dtype),
@@ -1544,14 +1651,25 @@ class StreamingGenerator:
                     done_now, jnp.minimum(t + 2, self._max_new), n_out
                 )
                 done_latch = done_latch | done_now
-                return (caches, last_tok, pos, gen, done_latch, n_out), None
+                return (caches, last_tok, pos, gen, done_latch, n_out, stats), None
 
             done0 = jnp.zeros((B,), bool)
             n0 = jnp.zeros((B,), jnp.int32)
-            (caches, last_tok, pos, gen, done, n_out), _ = lax.scan(
-                one, (caches, last_tok, pos, gen, done0, n0), None, length=K
+            # What the routed expert layer did (ServeMetrics.moe_*): the
+            # experts touched and the pairs by expert, counted on the
+            # device and fetched with the sync's other arrays. Nothing for
+            # a config without one.
+            stats0 = (
+                jnp.zeros((), jnp.int32),
+                jnp.zeros((cfg.n_experts,), jnp.int32),
+            ) if cfg.routed_moe else ()
+            (caches, last_tok, pos, gen, done, n_out, stats), _ = lax.scan(
+                one, (caches, last_tok, pos, gen, done0, n0, stats0), None,
+                length=K,
             )
-            return caches, last_tok, pos, gen, done, n_out
+            return (caches, last_tok, pos, gen, done, n_out) + (
+                (stats,) if stats else ()
+            )
 
         def resume_admit(params, caches, last_tok, pos, gen, seq, slot,
                          emitted_row, g):
@@ -1594,16 +1712,29 @@ class StreamingGenerator:
         # Raw (un-jitted) body for decode_roofline's fori-chained windows.
         self._tick_block_raw = tick_block
         self._admit_fn = lambda *a: _admit(self._params, *a)
-        self._tick_fn = lambda *a: _tick(self._params, *a)
-        if kv_int8:
+
+        def tick_fn(*a):
+            out = _tick(self._params, *a)
+            self._tick_stats = out[6] if len(out) > 6 else None
+            return out[:6]
+
+        self._tick_fn = tick_fn
+        if kv_int8 or latent:
             # int8 pools deliberately give up token-exactness, the one
             # contract warm resume exists to keep; hints are filtered out
-            # in _take_hint, so no resume program is built.
+            # in _take_hint, so no resume program is built. The latent
+            # pool has no spelling of the K/V resume prefill yet
+            # (_resume_supported): hints fall back to cold replay.
             self._resume_exec = None
         else:
             _resume = jax.jit(resume_admit, donate_argnums=(1,))
             self._resume_exec = lambda *a: _resume(self._params, *a)
-        if kv_int8 and kv_kernel:
+        if latent:
+            self._caches = (jnp.zeros((nl, B, M, cfg.latent_dim), cfg.dtype),)
+            self.metrics.moe_expert_load = np.zeros(
+                (cfg.n_experts if cfg.routed_moe else 0,), np.int64
+            )
+        elif kv_int8 and kv_kernel:
             # K-major pool for the Pallas read (see _slot_layer_step_q).
             self._caches = (
                 jnp.zeros((nl, B, kh, M, dh), jnp.int8),
@@ -2855,6 +2986,9 @@ class StreamingGenerator:
         # same either way, within noise).
         if fill not in ("mid", "live"):
             raise ValueError(f"fill must be 'mid' or 'live', got {fill!r}")
+        why = _arch_refusal(self._cfg, "decode_roofline (it counts K/V pool bytes)")
+        if why:
+            raise ValueError(why)
         # The probe ticks advance (and 'mid' first overwrites) self._pos;
         # without restoring it, a probe taken mid-serving would leave every
         # in-flight slot at a fabricated position and corrupt its remaining
@@ -3341,7 +3475,7 @@ class StreamingGenerator:
         data axis (its [1, S] resume prefill has no batch to shard —
         tp/fsdp-only meshes are unaffected). Everything else falls back
         to cold replay, which is still correct."""
-        if self._kv_int8:
+        if self._kv_int8 or self._cfg.is_mla:
             return False
         if self._mesh is None:
             return True
@@ -3692,9 +3826,13 @@ class StreamingGenerator:
             # together (separate np.asarray calls are separate round
             # trips).
             with xprof.span(xprof.SPAN_SYNC):
-                done_h, n_out_h, gen_h, pos_h = jax.device_get(
-                    (done, n_out, gen, pos)
+                done_h, n_out_h, gen_h, pos_h, stats_h = jax.device_get(
+                    (done, n_out, gen, pos, self._tick_stats)
                 )
+            if stats_h is not None:
+                touched, load = stats_h
+                self.metrics.moe_experts_touched.add(int(touched))
+                self.metrics.moe_expert_load += load
             self.metrics.tick_time.observe(time.perf_counter() - tick_t0)
             crash_hook("mid_tick")
             with xprof.span(xprof.SPAN_RETIRE):
@@ -3730,6 +3868,7 @@ class StreamingGenerator:
         journal_dirty = False
         decoded = 0
         first_tokens = 0  # slots surfacing their admission's own token
+        rows_needed = 0  # cached rows the served ticks read, a layer
         for i in np.nonzero(self._active)[0]:
             cnt = int(
                 n_out_h[i] if done_h[i]
@@ -3752,6 +3891,11 @@ class StreamingGenerator:
             new_toks = cnt - int(self._slot_emitted[i])
             decoded += new_toks
             first_tokens += int(self._slot_emitted[i] == 0)
+            # The tick that produced token j >= 1 read prompt_len + j rows.
+            j0 = max(int(self._slot_emitted[i]), 1)
+            rows_needed += (cnt - j0) * self._prompt_len + (
+                (cnt - j0) * (j0 + cnt - 1) // 2 if cnt > j0 else 0
+            )
             if self._tracer is not None and new_toks > 0:
                 self._tracer.tokens(
                     self._slot_rec[i], new_toks,
@@ -3775,6 +3919,18 @@ class StreamingGenerator:
         self.metrics.tokens_per_tick.set(float(decoded))
         self.metrics.slot_ticks_run.add(self._slots * self._ticks_per_sync)
         self.metrics.slot_ticks_served.add(decoded - first_tokens)
+        if self._cfg.is_mla:
+            n_layers = self._cfg.n_layers
+            self.metrics.latent_positions_valid.add(rows_needed * n_layers)
+            # The XLA read fetches the whole slab of every slot, every tick.
+            self.metrics.latent_positions_read.add(
+                n_layers * self._slots * self._ticks_per_sync * self._max_len
+            )
+            if self._cfg.routed_moe:
+                self.metrics.moe_assignments.add(
+                    (decoded - first_tokens) * self._cfg.expert_top_k
+                    * (n_layers - self._cfg.first_dense_layers)
+                )
         if journal_dirty:
             # Synchronous at the cadence point: the whole point is
             # that a SIGKILL one instruction later finds these tokens
@@ -3844,6 +4000,14 @@ class StreamingGenerator:
                 return True
             return False
         return True
+
+    @property
+    def cache_tensors(self) -> tuple:
+        """The slot pool's tensors as they stand (device arrays; the next
+        admit or tick donates them, so read or copy before stepping): for
+        tests and for a benchmark's comparison of what is cached with a
+        reference. Rows a retired slot wrote stay until its next admission."""
+        return self._caches
 
     @property
     def pending_commit(self) -> int:

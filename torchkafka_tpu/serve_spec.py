@@ -102,6 +102,11 @@ class SpecStreamingGenerator(StreamingGenerator):
         k: int = 4,
         **kwargs,
     ) -> None:
+        from torchkafka_tpu.models.transformer import _arch_refusal
+
+        why = _arch_refusal(cfg, "speculative serving")
+        if why:
+            raise ValueError(why)
         if kwargs.get("temperature", 0.0) != 0.0:
             raise ValueError(
                 "speculative serving is greedy-only: the accept rule "
