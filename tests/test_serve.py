@@ -1023,6 +1023,11 @@ _LATENT_VARIANTS = {
 }
 
 
+# The bf16 pool again, sampling: the admission draws token 0 from each
+# record's own key, which the chunked admission gathers with its rows.
+_SAMPLED_VARIANTS = {"bf16-sampled": (None, "auto", None)}
+
+
 def _latent_cfg(**over):
     """A leading dense layer and two expert layers over latent attention,
     every width a number of its own."""
@@ -1037,16 +1042,21 @@ def _latent_cfg(**over):
     return TransformerConfig(**base)
 
 
-def _tick_server(variant, ticks=3):
+def _tick_server(variant, ticks=3, latent_over=None, **server_kw):
     """A 2-layer toy server of the variant (heads of 128: the kernel's lane
     width; 4 slots and 3 ticks a sync, numbers no model dimension has)."""
     from torchkafka_tpu.parallel import make_mesh
 
-    kv_dtype, kv_kernel, axes = {**_TICK_VARIANTS, **_LATENT_VARIANTS}[variant]
-    cfg = _latent_cfg() if variant in _LATENT_VARIANTS else TransformerConfig(
-        vocab_size=VOCAB, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
-        d_ff=64, max_seq_len=P + MAX_NEW, dtype=jnp.float32,
-    )
+    kv_dtype, kv_kernel, axes = {
+        **_TICK_VARIANTS, **_LATENT_VARIANTS, **_SAMPLED_VARIANTS,
+    }[variant]
+    if variant in _LATENT_VARIANTS:
+        cfg = _latent_cfg(**(latent_over or {}))
+    else:
+        cfg = TransformerConfig(
+            vocab_size=VOCAB, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
+            d_ff=64, max_seq_len=P + MAX_NEW, dtype=jnp.float32,
+        )
     params = init_params(jax.random.key(0), cfg)
     broker = tk.InMemoryBroker()
     broker.create_topic("p", partitions=1)
@@ -1054,22 +1064,25 @@ def _tick_server(variant, ticks=3):
     srv = StreamingGenerator(
         consumer, params, cfg, slots=4, prompt_len=P, max_new=MAX_NEW,
         ticks_per_sync=ticks, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
-        mesh=make_mesh(axes) if axes else None,
+        mesh=make_mesh(axes) if axes else None, **server_kw,
     )
     assert srv._kv_kernel is (kv_kernel is True)
     return srv, consumer
 
 
-def _scans(jaxpr):
-    """Every scan equation under ``jaxpr``, however deeply it is nested."""
+def _eqns(jaxpr):
+    """Every equation under ``jaxpr``, however deeply it is nested."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
+        yield eqn
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from _scans(inner)
+                    yield from _eqns(inner)
+
+
+def _scans(jaxpr):
+    return (e for e in _eqns(jaxpr) if e.primitive.name == "scan")
 
 
 @pytest.mark.parametrize(
@@ -1153,6 +1166,207 @@ def test_carried_tick_equals_xs_ys_reference(variant):
     assert int(np.asarray(got[2]).max()) > P  # the block did decode
     srv.close()
     consumer.close()
+
+
+# ----------------------------------------------------------------------
+# The dense admission prefills only the rows it admits (PR 28): the admitted
+# slots in chunks of R rows, each chunk's prompt rows written into the pool
+# in place. Below, the formulation it replaced — every slot prefilled, the
+# admitted ones merged in by a select over the whole pool — kept as the
+# reference the chunked admission must equal bit for bit; it lives in this
+# file only.
+
+
+def _ref_admit(srv, params, caches, last_tok, pos, gen, prompts, admit_mask,
+               keys):
+    """``serve.py::_build::admit`` as it stood before PR 28."""
+    from jax import lax
+
+    from torchkafka_tpu.models.generate import prefill
+    from torchkafka_tpu.serve import _pick_slots, _quant_kv
+
+    cfg, P_, B = srv._cfg, srv._prompt_len, srv._slots
+    logits, fresh = prefill(params, cfg, prompts, srv._max_len, srv._mesh)
+    sel = admit_mask[None, :, None, None, None]
+    sel4 = admit_mask[None, :, None, None]
+    if cfg.is_mla:
+        (pool,) = caches
+        rows = jnp.where(sel4, fresh, lax.slice_in_dim(pool, 0, P_, axis=2))
+        caches = (lax.dynamic_update_slice(pool, rows, (0, 0, 0, 0)),)
+    elif srv._kv_int8:
+        fkq, fks = _quant_kv(fresh.k)
+        fvq, fvs = _quant_kv(fresh.v)
+        if srv._kv_kernel:
+            fkq, fvq = (jnp.swapaxes(a, 2, 3) for a in (fkq, fvq))
+            fks, fvs = (jnp.swapaxes(a, 2, 3) for a in (fks, fvs))
+        caches = (
+            jnp.where(sel, fkq, caches[0]), jnp.where(sel4, fks, caches[1]),
+            jnp.where(sel, fvq, caches[2]), jnp.where(sel4, fvs, caches[3]),
+        )
+    else:
+        caches = (
+            jnp.where(sel, fresh.k, caches[0]),
+            jnp.where(sel, fresh.v, caches[1]),
+        )
+    tok0 = _pick_slots(
+        logits, keys, jnp.zeros((B,), jnp.int32),
+        temperature=srv._temperature, top_k=srv._top_k, top_p=srv._top_p,
+    )
+    last_tok = jnp.where(admit_mask, tok0, last_tok)
+    pos = jnp.where(admit_mask, P_, pos)
+    gen = jnp.where(admit_mask[:, None], 0, gen)
+    gen = gen.at[:, 0].set(jnp.where(admit_mask, tok0, gen[:, 0]))
+    return caches, last_tok, pos, gen
+
+
+_ADMIT_VARIANTS = [
+    "bf16", "bf16-sampled", "int8", "int8-kernel", "latent", "int8-mesh",
+]
+_ADMIT_MASKS = {
+    # admitted rows: the mask (R = 2 of 4 slots: 3 rows pad the last chunk)
+    0: [False, False, False, False],
+    1: [False, False, True, False],
+    3: [True, False, True, True],
+    4: [True, True, True, True],
+}
+
+
+@pytest.fixture(scope="module", params=_ADMIT_VARIANTS)
+def admit_case(request):
+    """A toy server of the variant whose admission walks chunks of 2 rows
+    (the chunk constant patched while it is built: toy shapes give it every
+    slot otherwise), its pool mid-generation in every slot at ragged
+    positions, and the masked merge compiled beside it."""
+    from torchkafka_tpu import serve
+
+    variant = request.param
+    kw = {"temperature": 0.8, "top_k": 8} if variant in _SAMPLED_VARIANTS else {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(serve, "_ADMIT_CHUNK_TOKENS", 2 * P)
+        # Four experts: the merge's 4 rows and a chunk's 2 both average 8
+        # pairs an expert or more, so both take the routed layer's grouped
+        # form (ops/moe.py), as both do at the sizes that are served.
+        srv, consumer = _tick_server(variant, latent_over={"n_experts": 4}, **kw)
+    assert srv._admit_chunk_rows == 2
+    B = 4
+    rng = np.random.default_rng(5)
+    keys = jnp.asarray(
+        rng.integers(0, 2**32, srv._slot_keys.shape, dtype=np.uint32)
+    )
+    state = srv._admit_fn(
+        srv._caches, srv._last_tok, srv._pos, srv._gen,
+        jnp.asarray(rng.integers(0, VOCAB, (B, P)), jnp.int32),
+        jnp.ones((B,), bool), keys,
+    )
+    state = jax.jit(srv._tick_block_raw)(
+        srv._params, *state, jnp.asarray([True, False, True, True]), keys,
+    )[:4]
+    shardings = jax.tree.map(lambda a: a.sharding, state)
+    before = jax.tree.map(np.asarray, state)
+    ref = jax.jit(lambda *a: _ref_admit(srv, *a))
+    yield srv, keys, before, shardings, ref
+    srv.close()
+    consumer.close()
+
+
+def _assert_admission(srv, got, want, before, mask, exact=True):
+    """``got`` against the masked merge's ``want`` from the state ``before``:
+    the slot vectors whole, an admitted slot's pool rows [0, P); every
+    other row of the pool against what it was."""
+    for name, a, b in zip(("last_tok", "pos", "gen"), got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    assert np.asarray(got[2])[mask].tolist() == [P] * int(mask.sum())
+
+    def by_position(a):  # [L, B, M, ...] whatever the pool's layout
+        return np.swapaxes(np.asarray(a), 2, 3) if srv._kv_kernel else np.asarray(a)
+
+    for new, old, merged in zip(got[0], before[0], want[0]):
+        new, old, merged = by_position(new), by_position(old), by_position(merged)
+        if exact:
+            np.testing.assert_array_equal(new[:, mask, :P], merged[:, mask, :P])
+        else:
+            np.testing.assert_allclose(
+                new[:, mask, :P], merged[:, mask, :P], rtol=1e-5, atol=1e-6
+            )
+        np.testing.assert_array_equal(new[:, mask, P:], old[:, mask, P:])
+        np.testing.assert_array_equal(new[:, ~mask], old[:, ~mask])
+        if mask.any():  # the admission did write rows that differ
+            assert (new[:, mask, :P] != old[:, mask, :P]).any()
+
+
+@pytest.mark.parametrize("admitted", list(_ADMIT_MASKS))
+def test_chunked_admission_equals_masked_merge(admit_case, admitted):
+    """Identity: ``last_tok``, ``pos``, ``gen`` and every admitted slot's
+    pool rows [0, P) are bit-identical to the masked merge's; every other
+    slot's rows, and an admitted slot's rows past its prompt window, are
+    what they were.
+
+    The latent pool's compiled rows are held to a rounding, not to the bit:
+    XLA's CPU backend contracts the softmax's scale and subtraction in
+    ``mla.attend_full`` differently inside a while body than outside one
+    (the last bit of a row's attention, whatever the chunk holds). That the
+    two admissions are the same arithmetic is held op by op instead, with
+    the compiler out of the way, on the mask whose last chunk is padded."""
+    srv, keys, before, shardings, ref = admit_case
+    mask = np.asarray(_ADMIT_MASKS[admitted])
+    prompts = jnp.asarray(
+        np.random.default_rng(admitted).integers(0, VOCAB, (4, P)), jnp.int32
+    )
+    assert len(set(before[2].tolist())) > 1  # mid-generation, ragged
+    args = (prompts, jnp.asarray(mask), keys)
+    want = ref(srv._params, *jax.device_put(before, shardings), *args)
+    got = srv._admit_fn(*jax.device_put(before, shardings), *args)
+    latent = srv._cfg.is_mla
+    _assert_admission(srv, got, want, before, mask, exact=not latent)
+    if latent and admitted == 3:
+        with jax.disable_jit():
+            state = jax.tree.map(jnp.asarray, before)
+            want = _ref_admit(srv, srv._params, *state, *args)
+            got = srv._admit_fn(*state, *args)
+        _assert_admission(srv, got, want, before, mask)
+
+
+def test_admission_carries_the_pool_and_selects_nothing_of_its_shape(admit_case):
+    """Structure: in the admission's jaxpr the pool is the carry of the
+    chunk loop, whose trip count is a value of the mask, and of nothing
+    else at the top level; no select anywhere takes an operand of the
+    pool's shape (the masked merge copied the pool whole, five times)."""
+    srv, keys, before, _shardings, ref = admit_case
+    args = (
+        srv._params, *before, jnp.zeros((4, P), jnp.int32),
+        jnp.ones((4,), bool), keys,
+    )
+    pool_shapes = {c.shape for c in before[0]}
+
+    def pool_selects(fn):
+        return [
+            e for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "select_n"
+            and any(v.aval.shape in pool_shapes for v in e.invars)
+        ]
+
+    admit = next(  # the jitted program itself
+        c.cell_contents for c in srv._admit_fn.__closure__
+        if hasattr(c.cell_contents, "lower")
+    )
+    jaxpr = jax.make_jaxpr(admit)(*args).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+    top = call.params["jaxpr"].jaxpr
+    users = [
+        e for e in top.eqns
+        if any(getattr(v, "aval", None) is not None
+               and v.aval.shape in pool_shapes for v in e.invars)
+        and e.primitive.name != "sharding_constraint"
+    ]
+    # A while, not a scan: the trip count is a value of the mask.
+    assert [e.primitive.name for e in users] == ["while"]
+    (loop,) = users
+    n_pool = len(before[0])
+    assert sum(v.aval.shape in pool_shapes for v in loop.invars) == n_pool
+    assert sum(v.aval.shape in pool_shapes for v in loop.outvars) == n_pool
+    assert not pool_selects(admit)
+    if not srv._cfg.is_mla:  # (the latent merge selected over the window)
+        assert pool_selects(ref)  # the reference is what it says
 
 
 # ------------------------------------------------ the latent pool, served

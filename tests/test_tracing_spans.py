@@ -195,9 +195,10 @@ def test_stream_and_commit_spans_on_their_threads(tmp_path, prefetch):
 
 def test_scheduler_counters_match_a_hand_counted_run(model):
     """Ten records of 8 tokens through 4 slots, 4 ticks a sync. Three
-    admissions (4, 4 and 2 rows), each prefilling all 4 slots; six tick
-    blocks of 4 slots x 4 ticks; 80 tokens, 10 of them the admissions'
-    own, so 70 of the 96 slot-ticks served a token."""
+    admissions (4, 4 and 2 rows), each one chunk of 4 rows (an 8-token
+    window: a chunk holds every slot); six tick blocks of 4 slots x 4
+    ticks; 80 tokens, 10 of them the admissions' own, so 70 of the 96
+    slot-ticks served a token."""
     broker = prompt_topic(10)
     server = toy_server(broker, model)
     served = sum(1 for _ in server.run(max_records=10))
@@ -205,7 +206,7 @@ def test_scheduler_counters_match_a_hand_counted_run(model):
     assert served == 10
     assert got == {
         "slot_ticks_run": 96, "slot_ticks_served": 70, "admit_calls": 3,
-        "admit_rows": 10, "admit_rows_prefilled": 12,
+        "admit_rows": 10, "admit_rows_prefilled": 12, "admit_chunks": 3,
     }
     # Cumulative: a second run() resets the rate clocks, not these.
     for i in range(2):
@@ -218,6 +219,32 @@ def test_scheduler_counters_match_a_hand_counted_run(model):
     text = server.metrics.render_prometheus()
     for name, value in again.items():
         assert f"torchkafka_serve_{name}_total {value}\n" in text
+    server.close()
+
+
+@pytest.mark.parametrize("records,chunks,prefilled", [
+    (10, 5, 10),  # admissions of 4, 4 and 2 rows: 2, 2 and 1 chunks, no pad
+    (3, 2, 4),  # one admission of 3 rows: the second chunk pads a row
+    (1, 1, 2),  # one row prefills a chunk
+])
+def test_a_chunked_admission_prefills_what_its_chunks_hold(
+    model, monkeypatch, records, chunks, prefilled
+):
+    """The chunk constant patched so that a chunk is 2 of the 4 slots: an
+    admission prefills its rows rounded up to whole chunks, not the pool."""
+    from torchkafka_tpu import serve
+
+    monkeypatch.setattr(serve, "_ADMIT_CHUNK_TOKENS", 2 * P)
+    server = toy_server(prompt_topic(records), model)
+    assert sum(1 for _ in server.run(max_records=records)) == records
+    got = server.metrics.summary()["scheduler"]
+    assert got["admit_rows"] == records
+    assert got["admit_chunks"] == chunks
+    assert got["admit_rows_prefilled"] == prefilled == 2 * chunks
+    assert got["slot_ticks_served"] == records * (MAX_NEW - 1)
+    assert f"torchkafka_serve_admit_chunks_total {chunks}\n" in (
+        server.metrics.render_prometheus()
+    )
     server.close()
 
 

@@ -102,6 +102,15 @@ _logger = logging.getLogger(__name__)
 # ``resolve_kv_backend`` — the capability probe _build/_build_paged
 # consume (and ServeMetrics surfaces as kv_backend info).
 
+# Prompt tokens one trip of the dense admission prefills (``_build::admit``
+# walks the admitted slots in chunks of ``_ADMIT_CHUNK_TOKENS // window``
+# rows). A trip costs a pass over the weights and its rows: small chunks
+# pad the last trip less, large ones stream the weights less often (a
+# dense 7B gains down to 4 rows of 512, a routed-expert model wants 8: the
+# v5e's readings of 2,048, 3,072, 4,096 and 8,192 are in PERF.md, PR 28).
+# The program's shapes decide, never an option.
+_ADMIT_CHUNK_TOKENS = 3072
+
 
 def decode_tick_bytes(params, cfg: TransformerConfig, batch: int,
                       max_len: int, kv_int8: bool = False) -> tuple[int, int]:
@@ -296,10 +305,12 @@ class ServeMetrics:
         # nothing
         self.admit_calls = RateMeter()  # admit_records calls that prefilled
         self.admit_rows = RateMeter()  # rows admitted by a prefill
+        self.admit_chunks = RateMeter()  # trips of the dense admit program's
+        # chunk loop: ceil(admitted rows / rows a chunk) a call
         self.admit_rows_prefilled = RateMeter()  # rows the prefill programs
-        # ran: every slot on the dense path (the admit program prefills
-        # the whole [slots, prompt] batch whatever its mask), the admitted
-        # rows alone on the paths that prefill row by row
+        # ran: chunks x rows a chunk on the dense path (the last chunk of
+        # a call is padded to its static rows), the admitted rows alone on
+        # the paths that prefill row by row
         # The routed expert layer and the latent pool (a latent-attention
         # config; all zero otherwise). Cumulative like the scheduler's.
         self.moe_assignments = RateMeter()  # (token, choice) pairs of the
@@ -487,6 +498,7 @@ class ServeMetrics:
                 "admit_calls": self.admit_calls.count,
                 "admit_rows": self.admit_rows.count,
                 "admit_rows_prefilled": self.admit_rows_prefilled.count,
+                "admit_chunks": self.admit_chunks.count,
             },
             "expert_layer": {
                 "moe_assignments": self.moe_assignments.count,
@@ -1424,6 +1436,11 @@ class StreamingGenerator:
         # every tick's sampling — deliberately outside the donated state
         # tuple so state-poking tests/tools see the same tuple shapes.
         self._slot_keys = jnp.zeros((slots, self._key_width), jnp.uint32)
+        # Rows one trip of the admit program prefills. A program that
+        # prefills the whole [slots, prompt] batch whatever its mask (the
+        # speculative server's) makes one trip of every slot; the dense
+        # build walks the admitted slots in smaller chunks (_build).
+        self._admit_chunk_rows = slots
         # Set by _build/_build_paged (the spec subclass's too): the
         # resolved KVBackend this server actually serves with — a paged
         # pool too small for one slot re-resolves as dense here.
@@ -1511,53 +1528,75 @@ class StreamingGenerator:
             top_p=self._top_p,
         )
 
+        # Rows a chunk of the admission prefills: what static shapes give
+        # (_ADMIT_CHUNK_TOKENS over the prompt window, the pool's slots at
+        # most). The prefill constrains its batch over ``data``, so under a
+        # mesh a chunk is a whole number of rows a data shard.
+        data = 1 if mesh is None else mesh.shape.get("data", 1)
+        R = min(B, -(-max(1, _ADMIT_CHUNK_TOKENS // P) // data) * data)
+        self._admit_chunk_rows = R
+
         def admit(params, caches, last_tok, pos, gen, prompts, admit_mask,
                   keys):
-            """Prefill the full [B, P] prompt batch; merge admitted rows in.
-            prompts: [B, P] int32; admit_mask: [B] bool; keys: [B, W]
-            uint32 per-record key data (token 0 draws at index 0)."""
+            """Prefill the admitted rows of the [B, P] prompt batch, R rows
+            a trip, and write each trip's rows into the pool where they
+            belong. prompts: [B, P] int32; admit_mask: [B] bool; keys:
+            [B, W] uint32 per-record key data (token 0 draws at index 0).
+
+            The admitted slots come first in ``order`` and the loop makes
+            ceil(admitted / R) trips, a value of the mask: a slot that is
+            mid-generation costs nothing. The pool is the loop's CARRY and
+            every write a dynamic-update-slice of one slot's prompt window
+            [0, P) (a later position is rewritten by the tick that reaches
+            it before the attention that could read it, see ``tick_block``);
+            nothing here selects over the pool. The last trip's pad rows
+            repeat its last real row: the same slot gets the same values
+            twice."""
             caches, last_tok, pos, gen = pin_state(caches, last_tok, pos, gen)
-            logits, fresh = prefill(params, cfg, prompts, M, mesh)
-            sel = admit_mask[None, :, None, None, None]  # over [L, B, M, K, Dh]
-            if latent:
-                # The prompt window's rows [L, B, P, C] of the admitted
-                # slots, written in place: the rest of the pool is not
-                # touched.
-                (pool,) = caches
-                kept = lax.slice_in_dim(pool, 0, P, axis=2)
-                rows = jnp.where(admit_mask[None, :, None, None], fresh, kept)
-                caches = (lax.dynamic_update_slice(pool, rows, (0, 0, 0, 0)),)
-            elif kv_int8:
-                fkq, fks = _quant_kv(fresh.k)
-                fvq, fvs = _quant_kv(fresh.v)
-                if kv_kernel:
-                    # Kernel mode stores the pool K-major: transpose the
-                    # freshly-quantized [L, B, M, K, ·] prefill capture
-                    # once per admit (bytes ∝ one pool sweep; the per-tick
-                    # read path this layout accelerates runs max_new times
-                    # per admit).
-                    fkq, fvq = (jnp.swapaxes(a, 2, 3) for a in (fkq, fvq))
-                    fks, fvs = (jnp.swapaxes(a, 2, 3) for a in (fks, fvs))
-                    sel4 = admit_mask[None, :, None, None]  # [L, B, K, M]
+            order = jnp.argsort(~admit_mask, stable=True)
+            count = admit_mask.sum(dtype=jnp.int32)
+
+            def put(pool, rows, slots):
+                # rows [L, R, ...] over the window, in the pool's layout.
+                tail = (0,) * (pool.ndim - 2)
+                for r in range(R):
+                    pool = lax.dynamic_update_slice(
+                        pool, rows[:, r:r + 1].astype(pool.dtype),
+                        (0, slots[r], *tail),
+                    )
+                return pool
+
+            def chunk(i, state):
+                caches, last_tok, pos, gen = state
+                slots = order[jnp.minimum(i * R + jnp.arange(R), count - 1)]
+                logits, fresh = prefill(params, cfg, prompts[slots], P, mesh)
+                if latent:
+                    rows = (fresh,)  # [L, R, P, C]
+                elif kv_int8:
+                    rows = (*_quant_kv(fresh.k), *_quant_kv(fresh.v))
+                    if kv_kernel:
+                        # Kernel mode stores the pool K-major: transpose
+                        # the chunk's freshly-quantized [L, R, P, K, ·]
+                        # rows (the per-tick read this layout accelerates
+                        # runs max_new times an admission).
+                        rows = tuple(jnp.swapaxes(a, 2, 3) for a in rows)
                 else:
-                    sel4 = admit_mask[None, :, None, None]  # [L, B, M, K]
-                caches = (
-                    jnp.where(sel, fkq, caches[0]),
-                    jnp.where(sel4, fks, caches[1]),
-                    jnp.where(sel, fvq, caches[2]),
-                    jnp.where(sel4, fvs, caches[3]),
+                    rows = (fresh.k, fresh.v)
+                caches = tuple(put(c, a, slots) for c, a in zip(caches, rows))
+                tok0 = pick_rows(
+                    logits, keys[slots], jnp.zeros((R,), jnp.int32)
                 )
-            else:
-                caches = (
-                    jnp.where(sel, fresh.k, caches[0]),
-                    jnp.where(sel, fresh.v, caches[1]),
+                first = jnp.zeros((R, self._max_new), gen.dtype)
+                return (
+                    caches,
+                    last_tok.at[slots].set(tok0),
+                    pos.at[slots].set(P),
+                    gen.at[slots].set(first.at[:, 0].set(tok0)),
                 )
-            tok0 = pick_rows(logits, keys, jnp.zeros((B,), jnp.int32))  # [B]
-            last_tok = jnp.where(admit_mask, tok0, last_tok)
-            pos = jnp.where(admit_mask, P, pos)
-            gen = jnp.where(admit_mask[:, None], 0, gen)
-            gen = gen.at[:, 0].set(jnp.where(admit_mask, tok0, gen[:, 0]))
-            return caches, last_tok, pos, gen
+
+            return lax.fori_loop(
+                0, (count + R - 1) // R, chunk, (caches, last_tok, pos, gen)
+            )
 
         K = self._ticks_per_sync
 
@@ -1699,10 +1738,11 @@ class StreamingGenerator:
             return caches, last_tok, pos, gen
 
         # Donate the cache pool: the tick writes it in place (the pool is
-        # the carry of its tick and layer loops) and admit rebuilds it, so
-        # the output can be the caller's own buffer; without donation each
-        # dispatch first copies the full [L, B, M, K, Dh] pair. The run
-        # loop rebinds the returned buffers immediately.
+        # the carry of its tick and layer loops) and so does admit (the
+        # carry of its chunk loop), so the output can be the caller's own
+        # buffer; without donation each dispatch first copies the full
+        # [L, B, M, K, Dh] pair. The run loop rebinds the returned buffers
+        # immediately.
         # Params travel as an ARGUMENT, not a closure: a closed-over param
         # tree lowers as jaxpr constants, and at zoo scale (2.5-8 GB) that
         # bloats lowering/compile memory and ships the weights inside the
@@ -3641,13 +3681,15 @@ class StreamingGenerator:
                         dispatched=True,
                     )
         if filled:
-            # The dense program prefills every row of its [slots, prompt]
-            # batch, whatever the mask; a warm resume prefills its one row
-            # in a dispatch of its own.
+            # The admit program prefills its admitted rows a chunk at a
+            # time, the last chunk padded to its static rows; a warm resume
+            # prefills its one row in a dispatch of its own.
+            chunks = -(-admitted // self._admit_chunk_rows)
             self.metrics.admit_calls.add(1)
             self.metrics.admit_rows.add(filled)
+            self.metrics.admit_chunks.add(chunks)
             self.metrics.admit_rows_prefilled.add(
-                (B if admitted else 0) + resumed
+                chunks * self._admit_chunk_rows + resumed
             )
         if journal_dirty:
             self._journal.flush()
