@@ -25,21 +25,6 @@ def _kmajor_cache():
     return pay, scale, pay, scale
 
 
-def kmajor():
-    valid = jnp.ones((B, M), bool)
-    return lambda: kvattn.int8_decode_attention_kmajor(
-        _q(), *_kmajor_cache(), valid, interpret=True
-    )
-
-
-def mmajor():
-    pay, scale = jnp.zeros((B, M, K, DH), I8), jnp.ones((B, M, K), F32)
-    valid = jnp.ones((B, M), bool)
-    return lambda: kvattn.int8_decode_attention(
-        _q(), pay, scale, pay, scale, valid, interpret=True
-    )
-
-
 def dynlen():
     pos = jnp.full((B,), 5, jnp.int32)
     return lambda: kvattn.int8_decode_attention_dynlen(
@@ -98,8 +83,6 @@ def pallas_names(jaxpr) -> list[str]:
 
 
 @pytest.mark.parametrize("wrapper,names", [
-    (kmajor, ["tk_kvattn_kmajor"]),
-    (mmajor, ["tk_kvattn"]),
     (dynlen, ["tk_kvattn_dynlen"]),
     (paged, ["tk_kvattn_paged"]),
     (flash_fwd, ["tk_flash_fwd"]),

@@ -369,15 +369,13 @@ class TestChunkedPrefill:
     enqueues uncached suffixes; every tick carries a bounded chunk of
     them alongside all decode slots in ONE static jitted program. Pins:
 
-    - token-exactness + commit-ledger identity vs the DENSE server AND
-      vs the PR-4 per-record paged path (``prefill_chunk=0``), across
+    - token-exactness + commit-ledger identity vs the DENSE server, across
       chunk widths {1 token, half a prompt, auto} and greedy / seeded
       sampling / speculative serving — each chunk query attends exactly
       [0, position] of its slot's view, so the math is bitwise identical
       at any width;
-    - the jit-zoo fix: admission compiles O(1) programs across 50
-      mixed-suffix-length admissions (the legacy path's per-(suffix,
-      start) cache is the contrast);
+    - admission compiles O(1) programs across 50 mixed-suffix-length
+      admissions;
     - the prompt-storm latency bound: 4x-oversubscribed admissions never
       add a single tick to any in-flight slot's inter-token gap, and the
       queue drains FIFO with no deferral starvation."""
@@ -387,28 +385,29 @@ class TestChunkedPrefill:
         cfg, params = model
         prompts = _prompts(10)
         dense = _serve(cfg, params, prompts)
-        legacy = _serve(cfg, params, prompts, kv_pages=_chunk_pages(0))
-        return prompts, dense, legacy
+        # The radix work every chunk width must repeat: a fourth width
+        # (one prompt a tick), served once.
+        _, _, whole = _serve(cfg, params, prompts, kv_pages=_chunk_pages(P))
+        return prompts, dense, whole.metrics.cache_summary()
 
     @pytest.mark.parametrize(
         "chunk", [1, P // 2, None], ids=["1tok", "half", "auto"]
     )
-    def test_token_exact_vs_dense_and_pr4_paged(self, model, runs, chunk):
+    def test_token_exact_vs_dense(self, model, runs, chunk):
         cfg, params = model
-        prompts, (base, cb, _), (legacy, cl, sl) = runs
+        prompts, (base, cb, _), ws = runs
         got, cg, sg = _serve(
             cfg, params, prompts, kv_pages=_chunk_pages(chunk)
         )
         assert set(got) == set(base)
         for k in base:
             np.testing.assert_array_equal(got[k], base[k], err_msg=str(k))
-            np.testing.assert_array_equal(got[k], legacy[k], err_msg=str(k))
-        assert cg == cb == cl
-        # Same radix work and the same total prefilled tokens as the
-        # per-record path — only the dispatch structure changed.
-        cs, ls = sg.metrics.cache_summary(), sl.metrics.cache_summary()
-        assert cs["prefill_tokens"] == ls["prefill_tokens"]
-        assert cs["hits"] == ls["hits"]
+        assert cg == cb
+        # Same radix work and the same total prefilled tokens at every
+        # chunk width — only the dispatch structure changes.
+        cs = sg.metrics.cache_summary()
+        assert cs["prefill_tokens"] == ws["prefill_tokens"]
+        assert cs["hits"] == ws["hits"] > 0
         assert sg.metrics.chunk_ticks.count > 0
         assert sg.pending_admissions == 0
         assert not sg._prefill_queue  # chunk queue fully drained
@@ -430,7 +429,7 @@ class TestChunkedPrefill:
     def test_spec_rides_the_chunked_program(self, model):
         """Spec chunked serving: token-exact vs the plain DENSE server
         (the spec contract composed with chunking), admission compiled
-        into the tick program (no suffix-prefill jit zoo)."""
+        into the tick program."""
         cfg, params = model
         prompts = _prompts(8)
         base, cb, _ = _serve(cfg, params, prompts)
@@ -443,14 +442,13 @@ class TestChunkedPrefill:
         assert cs == cb
         assert ss.spec_stats()["proposed"] > 0
         assert ss.metrics.chunk_ticks.count > 0
-        assert len(ss._paged_prefill_jits) == 0
         assert ss._tick_chunk_jit._cache_size() == 1
 
     def test_admission_compiles_o1_programs(self, model):
         """50 admissions with MIXED suffix lengths (varying radix match
-        depths): the chunked tick set stays at one program per role —
-        the fused chunk tick, the decode-only tick, the sampling merge —
-        while the legacy path specialises per (suffix, start) pair."""
+        depths): the tick set stays at one program per role — the fused
+        chunk tick and the decode-only tick — and the server holds no
+        other jitted program that an admission could reach."""
         cfg, params = model
         rng = np.random.default_rng(3)
         fams = _prompts(4, shared_prefix_len=0, seed=13)
@@ -466,13 +464,7 @@ class TestChunkedPrefill:
         )
         assert s._tick_chunk_jit._cache_size() == 1
         assert s._tick_jit._cache_size() <= 1
-        assert len(s._paged_prefill_jits) == 0
-        # The legacy contrast: one specialisation per distinct
-        # (suffix, start) — the zoo this PR deletes from the hot path.
-        _, _, sl = _serve(
-            cfg, params, prompts, kv_pages=_chunk_pages(0, 160)
-        )
-        assert len(sl._paged_prefill_jits) > 1
+        assert s._admit_fn is None and not s._adopt_upload_jits
 
     def test_prompt_storm_decode_latency_bounded_and_fifo(self, model):
         """4x oversubscription with in-flight decode: a 1-block chunk
@@ -595,14 +587,6 @@ class TestInt8Paged:
         for k in dense:
             np.testing.assert_array_equal(kern[k], dense[k], err_msg=str(k))
         assert ck == cd
-
-    def test_legacy_admission_rejects_int8(self, model):
-        cfg, params = model
-        with pytest.raises(ValueError, match="prefill_chunk"):
-            _serve(
-                cfg, params, _prompts(2), kv_dtype="int8",
-                kv_pages=_chunk_pages(0),
-            )
 
 
 class TestStaleTailInvariant:
@@ -898,13 +882,27 @@ class TestBackendCapabilityErrors:
     """The capability probe's genuine exclusions: each raises a precise,
     regression-pinned error — everything else composes."""
 
-    def test_legacy_per_record_admission_rejects_mesh(self, mesh_model):
+    @pytest.mark.parametrize(
+        "how", ["config", "dict", "dict-int8", "dict-mesh"]
+    )
+    def test_per_record_admission_is_gone(self, mesh_model, how):
+        """``prefill_chunk=0`` is refused with one sentence wherever it
+        enters, whatever the backend it is asked of."""
         cfg, params = mesh_model
-        with pytest.raises(ValueError, match="prefill_chunk=0.*mesh"):
-            _serve(
-                cfg, params, _prompts(2), mesh=_mesh({"data": 2}),
-                kv_pages=_chunk_pages(0),
-            )
+        kw = {}
+        if how == "dict-int8":
+            kw["kv_dtype"] = "int8"
+        elif how == "dict-mesh":
+            kw["mesh"] = _mesh({"data": 2})
+        with pytest.raises(
+            ValueError, match="per-record admission was removed in PR 29"
+        ):
+            if how == "config":
+                PagedKVConfig(block_size=BS, num_blocks=40, prefill_chunk=0)
+            else:
+                _serve(
+                    cfg, params, _prompts(2), kv_pages=_chunk_pages(0), **kw
+                )
 
     def test_moe_rejects_pages(self):
         cfg = TransformerConfig(

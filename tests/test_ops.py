@@ -269,93 +269,8 @@ class TestUlysses:
 
 
 class TestInt8DecodeAttentionKernel:
-    """ops/kvattn.py (EXPERIMENTAL, off by default — measured slower than
-    the XLA scale-folded read on v5e, see its docstring): correctness is
-    still pinned so a redesigned successor starts from a tested scaffold."""
-
-    def test_matches_scale_folded_xla_read(self):
-        import jax.numpy as jnp
-
-        from torchkafka_tpu.ops.kvattn import int8_decode_attention
-        from torchkafka_tpu.serve import _quant_kv
-
-        rng = np.random.default_rng(0)
-        B, M, K, rep, Dh = 3, 24, 2, 2, 16
-        H = K * rep
-        q = jnp.asarray(rng.normal(size=(B, 1, H, Dh)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, M, K, Dh)) * 2, jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, M, K, Dh)) * 2, jnp.float32)
-        kq, ks = _quant_kv(k)
-        vq, vs = _quant_kv(v)
-        pos = jnp.asarray([5, 12, 23])
-        valid = jnp.arange(M)[None, :] <= pos[:, None]
-        # Reference: the scale-folded XLA read (the shipped int8-KV path).
-        qg = q[:, 0].reshape(B, K, rep, Dh)
-        scores = jnp.einsum("bkre,bmke->bkrm", qg, kq.astype(jnp.float32))
-        scores = scores * ks.transpose(0, 2, 1)[:, :, None, :] / np.sqrt(Dh)
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1)
-        pw = p * vs.transpose(0, 2, 1)[:, :, None, :]
-        ref = jnp.einsum(
-            "bkrm,bmke->bkre", pw, vq.astype(jnp.float32)
-        ).reshape(B, 1, H, Dh)
-        out = int8_decode_attention(q, kq, ks, vq, vs, valid, interpret=True)
-        np.testing.assert_allclose(
-            np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5
-        )
-
-    def test_kmajor_matches_scale_folded_xla_read(self):
-        """v2 (K-major pool, K-batched dots) — the shipped kernel — at
-        every slot_block, against the same scale-folded reference."""
-        import jax.numpy as jnp
-
-        from torchkafka_tpu.ops.kvattn import int8_decode_attention_kmajor
-        from torchkafka_tpu.serve import _quant_kv
-
-        rng = np.random.default_rng(1)
-        B, M, K, rep, Dh = 4, 24, 2, 2, 16
-        H = K * rep
-        q = jnp.asarray(rng.normal(size=(B, 1, H, Dh)), jnp.float32)
-        k = jnp.asarray(rng.normal(size=(B, M, K, Dh)) * 2, jnp.float32)
-        v = jnp.asarray(rng.normal(size=(B, M, K, Dh)) * 2, jnp.float32)
-        kq, ks = _quant_kv(k)
-        vq, vs = _quant_kv(v)
-        pos = jnp.asarray([5, 12, 23, 0])
-        valid = jnp.arange(M)[None, :] <= pos[:, None]
-        qg = q[:, 0].reshape(B, K, rep, Dh)
-        scores = jnp.einsum("bkre,bmke->bkrm", qg, kq.astype(jnp.float32))
-        scores = scores * ks.transpose(0, 2, 1)[:, :, None, :] / np.sqrt(Dh)
-        scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1)
-        pw = p * vs.transpose(0, 2, 1)[:, :, None, :]
-        ref = jnp.einsum(
-            "bkrm,bmke->bkre", pw, vq.astype(jnp.float32)
-        ).reshape(B, 1, H, Dh)
-        kqT, vqT = (jnp.swapaxes(a, 1, 2) for a in (kq, vq))
-        ksT, vsT = (jnp.swapaxes(a, 1, 2) for a in (ks, vs))
-        for bb in (1, 2, 4):
-            out = int8_decode_attention_kmajor(
-                q, kqT, ksT, vqT, vsT, valid, slot_block=bb, interpret=True
-            )
-            np.testing.assert_allclose(
-                np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5,
-                err_msg=f"slot_block={bb}",
-            )
-
-    def test_kmajor_slot_block_must_divide(self):
-        import jax.numpy as jnp
-
-        from torchkafka_tpu.ops.kvattn import int8_decode_attention_kmajor
-
-        B, M, K, Dh = 3, 8, 2, 16
-        q = jnp.zeros((B, 1, 4, Dh))
-        c = jnp.zeros((B, K, M, Dh), jnp.int8)
-        s = jnp.zeros((B, K, M))
-        valid = jnp.ones((B, M), bool)
-        with pytest.raises(ValueError, match="must divide"):
-            int8_decode_attention_kmajor(
-                q, c, s, c, s, valid, slot_block=2, interpret=True
-            )
+    """ops/kvattn.py: the dense pool's dynamic-length read and the paged
+    pool's block-table read, each held to the scale-folded XLA read."""
 
     def test_kernel_serving_end_to_end(self):
         """kv_kernel=True serves over the K-major pool (interpret mode on
@@ -405,38 +320,50 @@ class TestInt8DecodeAttentionKernel:
         for off in got_x:
             np.testing.assert_array_equal(got_k[off], got_x[off])
 
-    def test_dynlen_matches_kmajor_read(self):
-        """v3 (dynamic-length, online softmax over M-blocks) against the
-        v2 full read restricted to each slot's watermark, at several
-        block sizes including watermarks mid-block and at pool edges."""
-        import jax.numpy as jnp
+    @pytest.mark.parametrize("rep", [1, 4])
+    @pytest.mark.parametrize("pos", [
+        pytest.param([31, 31, 31, 31], id="full"),
+        pytest.param([15, 15, 15, 15], id="half"),
+        pytest.param([0, 7, 20, 31], id="mixed-one-at-0"),
+        pytest.param([16, 5, 31, 8], id="one-block-past-a-boundary"),
+    ])
+    def test_dynlen_matches_scale_folded_xla_read(self, monkeypatch, pos, rep):
+        """The dynamic-length read (online softmax over M-blocks, only
+        [0, pos] fetched) against ``_attend_cached``'s scale-folded read
+        of the whole pool masked to each slot's watermark: the fills a
+        pool meets, two GQA group sizes, several block sizes."""
+        from types import SimpleNamespace
 
-        from torchkafka_tpu.ops.kvattn import (
-            int8_decode_attention_dynlen, int8_decode_attention_kmajor,
-        )
+        from torchkafka_tpu.models import generate
+        from torchkafka_tpu.ops.kvattn import int8_decode_attention_dynlen
         from torchkafka_tpu.serve import _quant_kv
 
         rng = np.random.default_rng(2)
-        B, M, K, rep, Dh = 4, 32, 2, 2, 16
+        B, M, K, Dh = 4, 32, 2, 16
         H = K * rep
         q = jnp.asarray(rng.normal(size=(B, 1, H, Dh)), jnp.float32)
         k = jnp.asarray(rng.normal(size=(B, M, K, Dh)) * 2, jnp.float32)
         v = jnp.asarray(rng.normal(size=(B, M, K, Dh)) * 2, jnp.float32)
         kq, ks = _quant_kv(k)
         vq, vs = _quant_kv(v)
+        pos = jnp.asarray(pos)
+        valid = jnp.arange(M)[None, :] <= pos[:, None]
+        # The read alone: the shared tail (wo, MLP) needs layer weights.
+        monkeypatch.setattr(
+            generate, "_attn_tail", lambda x, attn, layer, cfg: attn
+        )
+        cfg = SimpleNamespace(dtype=jnp.float32, head_dim=Dh)
+        ref = generate._attend_cached(
+            None, q, kq, vq, valid, None, cfg, k_scale=ks, v_scale=vs
+        )
         kqT, vqT = (jnp.swapaxes(a, 1, 2) for a in (kq, vq))
         ksT, vsT = (jnp.swapaxes(a, 1, 2) for a in (ks, vs))
-        pos = jnp.asarray([0, 7, 15, 31])  # empty-ish, block edges, full
-        valid = jnp.arange(M)[None, :] <= pos[:, None]
-        ref = int8_decode_attention_kmajor(
-            q, kqT, ksT, vqT, vsT, valid, interpret=True
-        )
         for mb in (8, 16, 32):
             out = int8_decode_attention_dynlen(
                 q, kqT, ksT, vqT, vsT, pos, block=mb, interpret=True
             )
             np.testing.assert_allclose(
-                np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5,
+                np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5,
                 err_msg=f"block={mb}",
             )
 
@@ -525,11 +452,11 @@ class TestInt8DecodeAttentionKernel:
             np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     def test_paged_kernel_matches_gathered_read(self):
-        """v4 (block-table read: the v3 watermark-DMA structure through
-        per-slot block tables) against the XLA gathered scale-folded
-        read, with slots sharing physical prefix blocks, watermarks at
-        block edges and mid-block, and free/garbage blocks the tables
-        never reference (the kernel must not touch them)."""
+        """The block-table read (the dyn-len kernel's watermark-DMA
+        structure through per-slot block tables) against the XLA gathered
+        scale-folded read, with slots sharing physical prefix blocks,
+        watermarks at block edges and mid-block, and free/garbage blocks
+        the tables never reference (the kernel must not touch them)."""
         import jax.numpy as jnp
 
         from torchkafka_tpu.models.generate import _attend_cached
@@ -588,29 +515,24 @@ class TestInt8DecodeAttentionKernel:
         )
 
     def test_kernel_gates(self):
-        """v3's scratch is block-sized, so LONG pools are supported (the
-        v2 VMEM bound is gone from serving); pools that only tile at
-        tiny blocks are refused on TPU but accepted off-TPU (interpret
-        correctness path). kernel_feasible stays as the v2 record."""
+        """The dyn-len kernel's scratch is block-sized, so LONG pools are
+        supported; pools that only tile at tiny blocks are refused on TPU
+        but accepted off-TPU (interpret correctness path)."""
         import jax.numpy as jnp
 
         import torchkafka_tpu as tk
         from torchkafka_tpu.models.transformer import (
             TransformerConfig, init_params,
         )
-        from torchkafka_tpu.ops.kvattn import (
-            dynlen_block, kernel_feasible,
-        )
+        from torchkafka_tpu.ops.kvattn import dynlen_block
         from torchkafka_tpu.serve import StreamingGenerator
 
         assert dynlen_block(2048) == 512
         assert dynlen_block(4096) == 512
         assert dynlen_block(1032) == 8     # tiles, but tiny → TPU-gated
         assert dynlen_block(1030) == 0     # does not tile at all
-        assert kernel_feasible(8, 2048, 128)      # v2's measured-good
-        assert not kernel_feasible(8, 4096, 128)  # v2's measured-fail
-        # M=4096 now ACCEPTED with the explicit kernel (v3; off-TPU it
-        # honors via interpret — ctor only, no decode executed here).
+        # M=4096 is accepted with the explicit kernel (off-TPU it honors
+        # via interpret — ctor only, no decode executed here).
         cfg = TransformerConfig(
             vocab_size=64, d_model=1024, n_layers=1, n_heads=8,
             n_kv_heads=8, d_ff=64, max_seq_len=4096, dtype=jnp.float32,

@@ -284,7 +284,7 @@ def model():
 def _thrash_prompts(tenants=8, rounds=3, seed=3):
     """More distinct tenant prefixes than a tiny pool can hold, revisited
     round-robin — the workload where an HBM-only tree evicts every
-    prefix before its next hit (the TRAFFIC_BENCH hit-by-rank cliff)."""
+    prefix before its next hit (the hit-by-rank cliff)."""
     rng = np.random.default_rng(seed)
     t = rng.integers(0, VOCAB, (tenants, P), dtype=np.int32)
     return np.stack([t[i % tenants] for i in range(tenants * rounds)])

@@ -88,8 +88,7 @@ def _paired_host_ratio(
     n_slice: int, slices: int = 2,
 ) -> dict:
     """Alternating ours/reference-pattern slices over the SAME broker
-    records — bench.py's pairing discipline brought to the harness
-    (VERDICT r3 item 6): host-bound absolute numbers swing up to 15× with
+    records (VERDICT r3 item 6): host-bound absolute numbers swing up to 15× with
     box contention across rounds, but adjacent slices sample the same
     conditions, so the per-pair ratio is the stable signal. Reports the
     median of per-pair ratios plus both sides' rates.
@@ -906,8 +905,7 @@ def scenario_10(size: str = "tiny", replicas: int = 2) -> dict:
     one token-bucket rate-limited — and both priority lanes), finished by
     a mid-run graceful drain plus a restarted fleet serving the remainder
     with zero replayed completions. The tier-1 smoke for the fleet's
-    admission + drain paths: tiny model, seconds on CPU; the throughput
-    story lives in benchmarks/bench_fleet.py."""
+    admission + drain paths: tiny model, seconds on CPU."""
     import time as _time
 
     import torchkafka_tpu as tk
@@ -1126,8 +1124,7 @@ def scenario_12(size: str = "tiny", replicas: int = 2) -> dict:
     full prefill; every later one links the cached system-prompt blocks
     and prefills the suffix. The tier-1 guard for the cache-on fleet
     path: coverage + commit exactness (token-exactness vs cache-off is
-    tests/test_kvcache.py's differential; the throughput/memory story is
-    benchmarks/bench_kvcache.py)."""
+    tests/test_kvcache.py's differential)."""
     import time as _time
 
     import torchkafka_tpu as tk
@@ -1218,8 +1215,7 @@ def scenario_13(size: str = "tiny", replicas: int = 2) -> dict:
     commits complete, completions BYTE-IDENTICAL record-for-record
     (duplicates allowed, divergence not), and the journal provably used
     (warm resumes + journal-served > 0). The full cadence/mode
-    differential is tests/test_journal.py; the re-decoded-token savings
-    story is benchmarks/bench_fleet.py --failover."""
+    differential is tests/test_journal.py."""
     import tempfile
     import time as _time
 
@@ -1319,8 +1315,7 @@ def scenario_14(size: str = "tiny", prefill_chunk: int | None = None) -> dict:
     exactness and the chunk counters live. ``prefill_chunk`` defaults
     to one block per tick — small enough that the storm provably queues
     (admission_stall_ticks > 0). The exactness differential across
-    chunk widths is tests/test_kvcache.py; the wall-clock story is
-    benchmarks/bench_kvcache.py --chunk."""
+    chunk widths is tests/test_kvcache.py."""
     import time as _time
 
     import torchkafka_tpu as tk
@@ -1433,8 +1428,7 @@ def scenario_15(size: str = "tiny", replicas: int = 2) -> dict:
     endpoint smoke: a ``MetricsExporter`` on an ephemeral port scraped
     over real HTTP, every metrics class (fleet + per-replica serve +
     SLO tracer) riding the one /metrics exposition. The tier-1 guard
-    for the obs stack; trace determinism lives in tests/test_obs.py and
-    the overhead numbers in benchmarks/bench_obs.py."""
+    for the obs stack; trace determinism lives in tests/test_obs.py."""
     import time as _time
     import urllib.request
 
@@ -1596,8 +1590,7 @@ def scenario_16(size: str = "tiny", replicas: int = 2) -> dict:
     ``max_new`` header. Prints the per-tenant goodput / burn-rate report
     production watches; the tier-1 guard asserts non-degenerate
     per-tenant SLOs, trace balance, and zero lost records. The same-seed
-    byte-identity differential lives in tests/test_workload.py and the
-    overload sweep in benchmarks/bench_traffic.py."""
+    byte-identity differential lives in tests/test_workload.py."""
     import time as _time
 
     import torchkafka_tpu as tk
@@ -2381,8 +2374,7 @@ def scenario_20(size: str = "tiny", replicas: int = 2) -> dict:
     radix tree does real work while sharded. The tier-1 guard for the
     composed path: coverage + commit exactness and a non-degenerate
     cache hit rate (token-exactness vs single-device serving is
-    tests/test_kvcache.py's sharded differential; the wall-clock story
-    is benchmarks/bench_kvcache.py --mesh)."""
+    tests/test_kvcache.py's sharded differential)."""
     import time as _time
 
     import jax
@@ -3069,7 +3061,7 @@ def scenario_9(size: str = "tiny") -> dict:
 
     # Warmup pass (untimed-in-the-ratio; first-contact compiles land here),
     # then bucketed and pad-to-max back-to-back — both sides sample the
-    # same minutes of box weather, bench.py's pairing discipline.
+    # same minutes of box weather.
     run_pass("warm", bucketed=True)
     rows, elapsed, losses, rows_by_width, batches_by_width, stream = run_pass(
         "bucketed", bucketed=True

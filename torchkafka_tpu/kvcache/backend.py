@@ -24,12 +24,6 @@ Genuine exclusions (each raises):
 - ``kv_pages`` + MoE: the paged suffix prefill routes experts densely
   (decode's rule) while the dense prefill uses the training dispatch —
   serving both would break the cache-on/off exactness contract.
-- legacy per-record paged admission (``prefill_chunk=0``) + int8: the
-  PR-4 baseline is compute-dtype only (unchanged).
-- legacy per-record paged admission + mesh: the per-record suffix
-  prefill is a ``[1, S]`` dispatch whose singleton batch cannot shard
-  over ``data``; the chunked tick (``prefill_chunk`` None or >= 1) is
-  the sharded spelling.
 - ``kv_kernel=True`` that cannot be honored (tiling shapes, block
   size, or a mesh the slots/heads don't divide): require-or-raise, so
   a benchmark never misattributes the XLA read's numbers to the
@@ -54,8 +48,8 @@ __all__ = ["KVBackend", "resolve_kv_backend"]
 
 # Pool length at/above which kv_kernel="auto" engages the Pallas reads:
 # the kernels' advantage grows with pool bytes while their fixed
-# in-tick cost does not — measured win at 1024/2048, measured loss at
-# 192 (serve.py's full matrix; PERF.md).
+# in-tick cost does not. The threshold dates from a machine that is
+# gone and has no benchmark cell on its short side (ROADMAP C5).
 KV_KERNEL_AUTO_MIN_POOL = 1024
 
 
@@ -229,23 +223,7 @@ def resolve_kv_backend(
     if kv_kernel is True and not int8:
         raise ValueError("kv_kernel requires kv_dtype='int8'")
     paged = kv_pages is not None
-    chunked = paged and kv_pages.prefill_chunk != 0
     if paged:
-        if kv_pages.prefill_chunk == 0 and int8:
-            raise ValueError(
-                "legacy per-record paged admission (prefill_chunk=0) "
-                "is the PR-4 compute-dtype baseline; the int8 paged "
-                "pool requires the chunked tick (prefill_chunk None "
-                "or >= 1)"
-            )
-        if kv_pages.prefill_chunk == 0 and mesh is not None:
-            raise ValueError(
-                "legacy per-record paged admission (prefill_chunk=0) "
-                "cannot serve under a mesh: its per-record suffix "
-                "prefill is a [1, S] dispatch whose singleton batch "
-                "has no data shard — use the chunked tick "
-                "(prefill_chunk None or >= 1) or mesh=None"
-            )
         if cfg.is_moe:
             raise ValueError(
                 "kv_pages does not serve MoE configs: the paged suffix "
@@ -275,7 +253,7 @@ def resolve_kv_backend(
                     "numbers to the kernel"
                 )
             kernel = True
-        else:  # "auto": engage only in the measured-win regime
+        else:  # "auto": engage only on TPU at or above the threshold
             if reason is None:
                 if not on_tpu:
                     reason = f"auto: backend={backend!r} is not tpu"
@@ -294,7 +272,7 @@ def resolve_kv_backend(
         int8=int8,
         kernel=kernel,
         kernel_disabled_reason=None if kernel else reason,
-        chunked=chunked,
+        chunked=paged,
         data=data,
         tp=tp,
     )

@@ -51,23 +51,19 @@ class PagedKVConfig:
     the server then falls back to the dense cache-off path
     (gracefully, with a warning) rather than deadlocking admission.
 
-    ``prefill_chunk``: admission mode. ``None`` (default) = CHUNKED
-    prefill fused into the decode tick — admission enqueues each
+    ``prefill_chunk``: the chunk width of the paged admission, which is
+    CHUNKED prefill fused into the decode tick — admission enqueues each
     prompt's uncached suffix host-side and every tick processes a
     bounded, statically-shaped chunk of those tokens ALONGSIDE all
     decode slots in ONE jitted program (Sarathi-style: prefill rides
-    the weight stream decode already pays for), with the chunk width
-    auto-sized to ``slots * prompt_len`` (every admission a single
-    serving quantum can offer completes in one tick, preserving the
-    per-record completion timing of the per-record path shifted by
-    exactly one tick). An explicit int >= 1 fixes the chunk width —
+    the weight stream decode already pays for). ``None`` (default)
+    auto-sizes the width to ``slots * prompt_len`` (every admission a
+    single serving quantum can offer completes in one tick). An
+    explicit int >= 1 fixes the chunk width —
     smaller widths bound how much prefill work any one tick carries
     (the decode-latency lever under prompt storms; a prompt storm
     then drains FIFO at ``prefill_chunk`` tokens per tick while
     in-flight decode keeps emitting one token per slot per tick).
-    ``0`` = the LEGACY per-record admission (one suffix-prefill
-    dispatch per record, a jit specialisation per suffix length) —
-    kept as the measured PR-4 baseline and differential reference.
     """
 
     block_size: int
@@ -82,10 +78,16 @@ class PagedKVConfig:
                 f"num_blocks must be >= 2 (block 0 is the sink), "
                 f"got {self.num_blocks}"
             )
+        if self.prefill_chunk == 0:
+            raise ValueError(
+                "prefill_chunk=0: per-record admission was removed in PR "
+                "29; chunked admission is the paged admission (None for "
+                "the auto width, or >= 1)"
+            )
         if self.prefill_chunk is not None and self.prefill_chunk < 0:
             raise ValueError(
-                f"prefill_chunk must be None (auto), 0 (legacy per-record "
-                f"admission) or >= 1, got {self.prefill_chunk}"
+                f"prefill_chunk must be None (auto) or >= 1, got "
+                f"{self.prefill_chunk}"
             )
 
     def blocks_per_slot(self, max_len: int) -> int:

@@ -4,8 +4,8 @@ The HBM block pool holds the HOT prefix state; this module is where cold
 prefixes go to survive eviction. Without it, ``radix.evict`` FREES an
 unreferenced leaf — the prefix re-prefills from scratch on its next hit,
 and at production tenant counts (far more distinct prefixes than pool
-blocks) the tree thrashes: TRAFFIC_BENCH.json's hit-by-Zipf-rank cliff
-(0.89 → 0.60) is the small-scale preview. With a tier, eviction DEMOTES
+blocks) the tree thrashes (PERF_CPU.md's hit-rate-by-Zipf-rank table is
+the small-scale preview). With a tier, eviction DEMOTES
 the block's KV payload to a bounded pinned-host-RAM store instead
 (SGLang's RadixAttention hierarchy shape), and a radix match that walks
 off the in-HBM tree PROMOTES matching tier entries back into fresh pool

@@ -20,8 +20,8 @@ tokens per read for the same stream (the extra k query positions ride
 the same weight tiles through the MXU), plus k+1 draft reads at
 draft/target cost ratio. Expected speedup = E[accepted + 1] /
 ((k+1)·c + 1 + v) with c = draft/target tick ratio and v the multi-query
-overhead — both measured in benchmarks/bench_spec.py rather than
-assumed. Everything is static-shape: the per-round emission count is
+overhead — both are to be measured (harness scenario 7 ``--spec``
+reports the acceptance), not assumed. Everything is static-shape: the per-round emission count is
 dynamic but lives in POSITION BOOKKEEPING (per-row emitted counters and
 a one-hot scatter into a padded buffer), not in array shapes, so the
 whole loop jits as one ``lax.while_loop`` (guaranteed ≥1 token per

@@ -12,7 +12,7 @@ the serving code's own phase transitions:
     prefill_queued   chunked admission reserved a slot + enqueued suffix
     chunk_scheduled  its first suffix tokens rode a fused chunk tick
     warm_resumed     a journal hint restored emitted tokens at admit
-    slot_active      admission dispatched (dense / legacy paged: the first
+    slot_active      admission dispatched (dense: the first
                      token surfaces at the next host sync) or activated
                      with its first token in hand (chunked, adopted, warm)
     tokens           a tick block produced n new tokens for its slot
@@ -30,8 +30,7 @@ timestamps — byte-identical traces). ``TraceEvent.signature`` is the
 timestamp-free tuple the differential tests compare.
 
 Cost discipline: a server built with ``tracer=None`` pays only the
-``is not None`` guards at each call site (measured in
-benchmarks/bench_obs.py, budgeted ≤ 50 ns/record); an enabled tracer
+``is not None`` guards at each call site; an enabled tracer
 appends to a bounded ring (``deque(maxlen=...)``) and optionally streams
 JSONL. Derived SLO histograms (obs/slo.py) update inline on the events
 that close a latency interval.
@@ -250,7 +249,7 @@ class RecordTrace:
         """poll → ``slot_active`` (admission + queue, and the prefill
         where the stamp follows it). Events alone cannot tell an
         admission that was only dispatched from one whose token is in
-        hand, so on the dense and legacy-paged paths this reads SHORT of
+        hand, so on the dense path this reads SHORT of
         the SLO histogram's TTFT, which closes at the host sync that
         surfaced the token."""
         t0, t1 = self._t(POLLED), self._t(SLOT_ACTIVE)
@@ -432,7 +431,7 @@ class RecordTracer:
     def slot_active(self, rec: Record, replica=None, warm: bool = False,
                     dispatched: bool = False) -> None:
         """Admission dispatched for this record. ``dispatched=True`` (the
-        dense and legacy-paged paths) says the admit program was only
+        dense path) says the admit program was only
         DISPATCHED: its token exists for the host at the next sync, and
         the TTFT interval closes at the first ``tokens`` or ``finished``
         event. Otherwise the first token is in hand (the chunked path

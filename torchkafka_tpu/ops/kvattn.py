@@ -1,76 +1,45 @@
-"""Pallas int8-KV decode attention kernels.
+"""Pallas decode-attention reads of the int8 KV pools, and the XLA
+block-table reads of the paged pool.
 
-Two generations live here, both correctness-pinned by differential
-tests against the scale-folded XLA read (exact to f32 reduction order):
+Two kernels, one for each pool layout a ``StreamingGenerator`` builds:
 
-**v1 ``int8_decode_attention`` (M-major cache [B, M, K, Dh]) — measured
-SLOWER, kept as the recorded negative result.** The hypothesis it
-tested (PERF.md, int8-KV section): the XLA spelling of the int8-KV
-attention read materialises an int8→bf16 converted copy of the cache
-instead of fusing the convert into the dot's HBM read, costing ~20%
-equal-slot throughput vs a bf16 cache — so a kernel that streams int8
-tiles HBM→VMEM directly (the in-VMEM convert is on-core work) should
-win the bytes back. MEASURED (8B int8 weights, 96 slots, 192-token
-budget): 85.1 ms/tick vs the XLA read's 46.8 — 1.8× SLOWER. Diagnosis:
-the M-major layout puts the kv-head axis in the middle, so every
-per-head slice ``cache[:, k, :]`` is strided and Mosaic's batched-dot
-positional rule forces a per-head static loop of tiny [rep≤4, Dh]
-dots over relaid-out operands — serialized on-core work that swamps
-the saved HBM bytes.
+- ``int8_decode_attention_dynlen`` (``tk_kvattn_dynlen``) reads the DENSE
+  slot pool, payloads [B, K, M, Dh] int8 with scales [B, K, M] f32 (or the
+  stacked pool [L, B, ...] with ``layer=``), up to each slot's watermark.
+- ``int8_paged_decode_attention`` (``tk_kvattn_paged``) reads the PAGED
+  pool, blocks [NB, K, bs, Dh] with scales [NB, K, bs], through per-slot
+  block tables, up to each slot's watermark.
 
-**v2 ``int8_decode_attention_kmajor`` (K-major cache [B, K, M, Dh]) —
-the redesign v1's postmortem called for.** Storing the pool K-major
-makes every head's [M, Dh] tile a contiguous leading-axis slice, and
-both dots collapse into ONE K-batched ``dot_general`` whose batch dims
-sit at position 0 on each operand (Mosaic's requirement), so there is
-no per-head loop and no in-VMEM relayout. A ``slot_block`` parameter
-processes several slots per grid step — their (slot, head) axes merge
-into the batch dim by a layout-free leading reshape — so each grid
-step issues one large DMA (bb·K·M·Dh bytes) instead of v1's
-one-small-DMA-per-slot structure, and Pallas double-buffers it across
-the (B/bb,)-parallel grid.
+Both are held by differential tests to the scale-folded XLA read
+(``models.generate._attend_cached`` with ``k_scale``/``v_scale``), exact
+up to f32 reduction order.
 
-MEASURED (v5e, 8B shapes). Isolated pool read, fori-chained slope over
-alternating cache pairs: the kernel beats the XLA scale-folded read at
-every shape tried — 59.4 µs vs 66.0 (1.11×, 655 GB/s) at B=96/M=192,
-92.4 vs 120.8 µs (1.31×, 749 GB/s = 91% of peak) at B=16/M=2048. Full
-serving tick (the number that matters): the win survives only at LONG
-pools — M=2048 31.6→30.7 ms and M=1024 36.1→35.6 ms (exactly the
-isolated delta), but M=192 REGRESSES 16.7→17.3 (B=16) and 46.7→49.2 ms
-(B=96): the K-major update path plus the fusion break around a Pallas
-call cost ~2.5 ms/tick regardless of pool length. Hence serve.py's
-``kv_kernel="auto"`` engages the kernel only at pool length ≥ 1024;
-v1's "XLA materialises a converted copy" diagnosis also did not
-reproduce in-tick on this XLA version (the in-tick XLA read streams at
-the isolated rate), so the remaining known upside is a dynamic-length
-read (skip DMA beyond each slot's position — inexpressible in XLA).
+Why the layout and the structure:
 
-**v3 ``int8_decode_attention_dynlen`` (K-major + per-slot watermarks) —
-the SHIPPED serving kernel.** Same K-major layout and batched dots as
-v2, but the pool stays in HBM (``memory_space=ANY``), the per-slot
-watermarks arrive by scalar prefetch, and the kernel manually DMAs
-M-blocks with double buffering and a flash-style online-softmax
-recurrence — the per-slot block loop runs ``ceil((pos+1)/mb)`` times,
-so positions beyond a slot's fill are NEVER FETCHED. HBM traffic then
-scales with the actual fill instead of the pool size, which no XLA
-spelling can do (static shapes make every read pool-shaped). Two
-non-obvious pieces: (a) buffer parity is GLOBAL across the whole grid
-(each program derives its starting parity from the prefetched
-watermark prefix-sum) so that (b) each program's first block is DMA'd
-by its PREDECESSOR during the predecessor's last-block compute
-(sequential "arbitrary" grid; scratch persists across programs) —
-without the cross-program prefetch, every slot began with a DMA stall
-(measured +24% at full fill). MEASURED (v5e, 8B shapes, M=2048, B=16,
-paired interleaved slopes): v2 full read 98.0 µs; v3 103.8 µs at
-exactly-full (the online-softmax recurrence's cost), 51.0 µs at half
-fill (1.92× v2), 62.5 µs at mixed fills (1.57×) — and continuous
-batching lives at partial fills. Full tick (8B int8, 16 slots,
-pool 2048, fill pinned to the 75% steady-state midpoint): XLA read
-33.93 ms → v3 27.85 ms (+22% tok/s). serve.py ships v3 as the
-``kv_kernel="auto"`` kernel at pools ≥ 1024; v2 remains the
-fixed-shape record (and the differential-test reference).
+- **K-major.** With the kv-head axis ahead of the position axis every
+  head's [M, Dh] tile is a contiguous leading-axis slice, and both dots
+  are ONE K-batched ``dot_general`` whose batch dims sit at position 0 on
+  each operand (Mosaic's batched-dot rule). A position-major pool
+  [B, M, K, Dh] makes every per-head slice strided and forces a static
+  per-head loop of tiny [rep, Dh] dots over relaid-out operands.
+- **Watermarks by scalar prefetch.** The pool stays in HBM
+  (``memory_space=ANY``); the per-slot watermarks (and, paged, the block
+  tables) arrive as scalar-prefetch arguments, and the kernel DMAs
+  M-blocks itself, double-buffered, through a flash-style online-softmax
+  recurrence. The block loop runs ``ceil((pos+1)/mb)`` times, so
+  positions past a slot's fill are never fetched: HBM traffic follows the
+  fill, which no XLA spelling can do (static shapes make every read
+  pool-shaped).
+- **Global buffer parity.** Which of the two VMEM buffers block (slot, j)
+  uses is ``(blocks of all earlier slots + j) % 2``, derived by each
+  program from the prefetched watermarks' prefix sum, not restarted per
+  program, so that
+- **the predecessor prefetches.** The grid is sequential ("arbitrary")
+  and scratch persists across programs, so program i starts the DMA of
+  program i+1's first block during its own last block's compute; without
+  it every slot opens with a DMA stall.
 
-Net-new vs the reference (no kernels in its tree, SURVEY.md §2).
+Numbers (time a call, roofline share): PERF.md §5.
 """
 
 from __future__ import annotations
@@ -88,40 +57,6 @@ from torchkafka_tpu.ops.flash import _default_interpret, tpu_compiler_params
 _NEG_INF = -1e30
 
 
-def _kvattn_kernel(
-    q_ref, kq_ref, ks_ref, vq_ref, vs_ref, mask_ref, o_ref, *,
-    inv_sqrt_dh: float,
-):
-    q = q_ref[0]  # [K, rep, Dh] compute dtype
-    # int8 tiles were DMA'd into VMEM at 1 byte/element — the convert
-    # below is on-core work, not HBM traffic (the thing the kernel
-    # exists to halve).
-    kq = kq_ref[0].astype(q.dtype)  # [M, K, Dh]
-    vq = vq_ref[0].astype(q.dtype)
-    ks = ks_ref[0]  # [M, K] f32
-    vs = vs_ref[0]
-    mask = mask_ref[0, 0][None, :]  # [1, M]
-    # STATIC loop over kv heads (K is small — 8 at the 8B shapes):
-    # Mosaic's batched dot requires equal batch-dim positions, which the
-    # [M, K, Dh] cache layout doesn't give; per-head 2-D dots sidestep it
-    # and unroll fully at trace time.
-    outs = []
-    for k in range(q.shape[0]):
-        s = jax.lax.dot_general(
-            q[k], kq[:, k, :], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rep, M]
-        s = s * ks[:, k][None, :] * inv_sqrt_dh
-        s = jnp.where(mask, s, _NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        pw = (p * vs[:, k][None, :]).astype(q.dtype)
-        outs.append(jax.lax.dot_general(
-            pw, vq[:, k, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ))  # [rep, Dh]
-    o_ref[0] = jnp.stack(outs).astype(o_ref.dtype)
-
-
 def kernel_applicable(head_dim: int, max_len: int) -> bool:
     """Shape gate of the dense-pool kernels on every backend:
     lane-aligned head_dim (Dh is the lane dim of the payload blocks) and
@@ -131,174 +66,6 @@ def kernel_applicable(head_dim: int, max_len: int) -> bool:
     (kvcache/backend.py) enforces on TPU by requiring ``dynlen_block`` >=
     256. Interpret mode accepts anything; tests force it."""
     return head_dim % 128 == 0 and max_len % 8 == 0
-
-
-# Per-grid-step int8 in-block byte budget. Measured on v5e (Mosaic
-# compile + run): 4.2 MB of int8 in-blocks per step compiles and runs at
-# full rate (M=2048 bb=1, M=1024 bb=2); 8.4 MB fails to compile. Set just
-# above the known-good point.
-_SLOT_BLOCK_BUDGET = 4_718_592
-
-
-def kernel_feasible(n_kv: int, max_len: int, head_dim: int) -> bool:
-    """True iff SOME slot block fits the VMEM budget — bb=1 is the floor,
-    so feasibility is one slot's k+v int8 bytes within budget. Callers
-    gate on this before engaging the kernel: past it, every slot_block
-    choice (including 1) produces the in-block size that fails Mosaic
-    compilation (see _SLOT_BLOCK_BUDGET)."""
-    return 2 * n_kv * max_len * head_dim <= _SLOT_BLOCK_BUDGET
-
-
-def _pick_slot_block(batch: int, n_kv: int, max_len: int, head_dim: int) -> int:
-    """Largest slot block (≤8, dividing B) whose per-step working set —
-    two int8 payload blocks, their bf16 converts, and double-buffered
-    input windows — fits the measured VMEM budget. Larger bb is FASTER
-    where it fits (M=192: bb=8 59 µs vs bb=1 80 µs — fewer grid steps
-    amortize the per-step DMA issue cost)."""
-    per_slot = 2 * n_kv * max_len * head_dim  # k+v int8 bytes
-    for bb in (8, 4, 2, 1):
-        if batch % bb == 0 and bb * per_slot <= _SLOT_BLOCK_BUDGET:
-            return bb
-    return 1
-
-
-def _kvattn_kmajor_kernel(
-    q_ref, kq_ref, ks_ref, vq_ref, vs_ref, mask_ref, o_ref, *,
-    inv_sqrt_dh: float,
-):
-    bb, n_kv, rep, dh = q_ref.shape
-    m = kq_ref.shape[2]
-    g = bb * n_kv
-    # Leading-axis merges are layout-free (the trailing sublane/lane pair
-    # is untouched): (bb, K, ·, ·) → (bb·K, ·, ·) costs nothing.
-    q = q_ref[...].reshape(g, rep, dh)
-    kq = kq_ref[...].reshape(g, m, dh).astype(q.dtype)
-    # ONE batched dot over all (slot, head) pairs — batch dims at
-    # position 0 on both operands, Mosaic's batched-dot rule.
-    s = jax.lax.dot_general(
-        q, kq, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # [G, rep, M]
-    s = s.reshape(bb, n_kv, rep, m)
-    ks = ks_ref[...]  # [bb, K, M] f32
-    s = s * ks[:, :, None, :] * inv_sqrt_dh
-    mask = mask_ref[...]  # [bb, 1, M]
-    s = jnp.where(mask[:, :, None, :], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    vs = vs_ref[...]
-    pw = (p * vs[:, :, None, :]).astype(q.dtype).reshape(g, rep, m)
-    vq = vq_ref[...].reshape(g, m, dh).astype(q.dtype)
-    o = jax.lax.dot_general(
-        pw, vq, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # [G, rep, Dh]
-    o_ref[...] = o.reshape(bb, n_kv, rep, dh).astype(o_ref.dtype)
-
-
-def int8_decode_attention_kmajor(
-    q: jax.Array,
-    ck_q: jax.Array,
-    ck_s: jax.Array,
-    cv_q: jax.Array,
-    cv_s: jax.Array,
-    valid: jax.Array,
-    *,
-    slot_block: int | None = None,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """q [B, 1, H, Dh] (compute dtype) against a K-MAJOR int8 cache
-    ck_q/cv_q [B, K, M, Dh] with scales ck_s/cv_s [B, K, M] (f32) and a
-    readable-position mask valid [B, M] (bool) → attn [B, 1, H, Dh].
-
-    Exact w.r.t. the scale-folded XLA read (``_attend_cached`` with
-    k_scale/v_scale, modulo the cache transpose) up to f32 reduction
-    order — differential-tested. ``slot_block``: slots per grid step
-    (must divide B); default auto-picks for VMEM fit.
-    """
-    b, s, h, dh = q.shape
-    if s != 1:
-        raise ValueError(f"decode attention is one token per slot, got S={s}")
-    n_kv, m = ck_q.shape[1], ck_q.shape[2]
-    rep = h // n_kv
-    bb = slot_block or _pick_slot_block(b, n_kv, m, dh)
-    if b % bb:
-        raise ValueError(f"slot_block={bb} must divide batch={b}")
-    if interpret is None:
-        interpret = _default_interpret()
-    qg = q[:, 0].reshape(b, n_kv, rep, dh)  # k-major head grouping
-    mask3 = valid[:, None, :]  # [B, 1, M]
-    kw = {} if interpret else tpu_compiler_params(("parallel",))
-    out = pl.pallas_call(
-        functools.partial(
-            _kvattn_kmajor_kernel, inv_sqrt_dh=float(1.0 / np.sqrt(dh))
-        ),
-        grid=(b // bb,),
-        in_specs=[
-            pl.BlockSpec((bb, n_kv, rep, dh), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((bb, n_kv, m, dh), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((bb, n_kv, m), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bb, n_kv, m, dh), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((bb, n_kv, m), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bb, 1, m), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bb, n_kv, rep, dh), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
-        interpret=interpret,
-        name="tk_kvattn_kmajor",
-        **kw,
-    )(qg, ck_q, ck_s.astype(jnp.float32), cv_q, cv_s.astype(jnp.float32),
-      mask3)
-    return out.reshape(b, 1, h, dh)
-
-
-def int8_decode_attention(
-    q: jax.Array,
-    ck_q: jax.Array,
-    ck_s: jax.Array,
-    cv_q: jax.Array,
-    cv_s: jax.Array,
-    valid: jax.Array,
-    *,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """q [B, 1, H, Dh] (compute dtype) against an int8 cache
-    ck_q/cv_q [B, M, K, Dh] with scales ck_s/cv_s [B, M, K] (f32) and a
-    readable-position mask valid [B, M] (bool) → attn [B, 1, H, Dh].
-
-    Exact w.r.t. the scale-folded XLA read (``_attend_cached`` with
-    k_scale/v_scale) up to f32 reduction order — differential-tested.
-    """
-    b, s, h, dh = q.shape
-    if s != 1:
-        raise ValueError(f"decode attention is one token per slot, got S={s}")
-    m, n_kv = ck_q.shape[1], ck_q.shape[2]
-    rep = h // n_kv
-    if interpret is None:
-        interpret = _default_interpret()
-    qg = q[:, 0].reshape(b, n_kv, rep, dh)  # k-major head grouping
-    mask3 = valid[:, None, :]  # [B, 1, M] — (1, M) trailing block dims
-    kw = {} if interpret else tpu_compiler_params(("parallel",))
-    out = pl.pallas_call(
-        functools.partial(
-            _kvattn_kernel, inv_sqrt_dh=float(1.0 / np.sqrt(dh))
-        ),
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, n_kv, rep, dh), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, m, n_kv, dh), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, m, n_kv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, m, n_kv, dh), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, m, n_kv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1, m), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_kv, rep, dh), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
-        interpret=interpret,
-        name="tk_kvattn",
-        **kw,
-    )(qg, ck_q, ck_s.astype(jnp.float32), cv_q, cv_s.astype(jnp.float32),
-      mask3)
-    return out.reshape(b, 1, h, dh)
 
 
 # ------------------------------------------------------- paged (block-table)
@@ -352,7 +119,7 @@ def paged_gather_kmajor(pool: jax.Array, table: jax.Array) -> jax.Array:
     pool: [NB, K, bs, ...rest] (payload rest=(Dh,), scales rest=());
     table: [B, nblk] int32 → [B, nblk * bs, K, ...rest]. The int8 paged
     pool stores each block K-major so the Pallas block-table kernel's
-    per-block tiles are the v3 kernel's [K, bs, Dh] shape (one batched
+    per-block tiles are the dyn-len kernel's [K, bs, Dh] shape (one batched
     dot over (slot, head), no per-head relayout); the XLA read pays one
     transpose of the gathered view to recover the logical
     [B, M', K, ...] layout ``_attend_cached`` expects."""
@@ -441,16 +208,16 @@ def block_table_attention(
     return x, pool_k, pool_v
 
 
-# ------------------------------------------------------------------ v3
+# ------------------------------------------------ dense pool (dyn-len)
 # Dynamic-length read: the capability XLA's static shapes cannot express.
-# Every XLA spelling of decode attention (and kernels v1/v2) reads the
-# FULL pool and discards masked positions; per-slot fills vary in
-# continuous batching, so the discarded bytes are real HBM traffic. v3
-# takes the per-slot watermark as a SCALAR-PREFETCH argument, keeps the
-# pool in HBM (memory_space=ANY), and manually DMAs M-blocks with double
-# buffering, running the per-block online-softmax (flash) recurrence —
-# the fori_loop bound is ceil((pos+1)/mb), so blocks beyond a slot's
-# fill are never fetched.
+# Every XLA spelling of decode attention reads the FULL pool and discards
+# masked positions; per-slot fills vary in continuous batching, so the
+# discarded bytes are real HBM traffic. The kernel takes the per-slot
+# watermark as a SCALAR-PREFETCH argument, keeps the pool in HBM
+# (memory_space=ANY), and manually DMAs M-blocks with double buffering,
+# running the per-block online-softmax (flash) recurrence — the fori_loop
+# bound is ceil((pos+1)/mb), so blocks beyond a slot's fill are never
+# fetched.
 
 
 def _kvattn_dynlen_kernel(
@@ -471,7 +238,7 @@ def _kvattn_dynlen_kernel(
     # "arbitrary") and scratch persists across them, so each program's
     # FIRST block is DMA'd by its predecessor during that predecessor's
     # last-block compute — without this, every slot begins with a DMA
-    # stall (measured +24% at full fill vs v2's automatic pipeline).
+    # stall.
     # Buffer parity must therefore be GLOBAL over the whole run, not
     # per-program: block (slot, j) uses parity (prefix_blocks(slot) + j)
     # % 2, computable by any program from the prefetched watermarks.
@@ -588,8 +355,8 @@ def int8_decode_attention_dynlen(
     DMAs from row ``layer * B + b``.
 
     Exact w.r.t. the scale-folded read restricted to valid positions
-    (flash-style online softmax; differential-tested against v2 with
-    ``valid = arange(M) <= pos[:, None]``).
+    (flash-style online softmax; differential-tested against
+    ``_attend_cached`` with ``valid = arange(M) <= pos[:, None]``).
     """
     b, s, h, dh = q.shape
     if s != 1:
@@ -762,9 +529,9 @@ def int8_paged_decode_attention_sharded(
     return fn(q, pool_kq, pool_ks, pool_vq, pool_vs, table, pos)
 
 
-# ------------------------------------------------------------------ v4
-# Block-table read: the v3 watermark-DMA structure extended to read
-# THROUGH per-slot block tables (the int8 PAGED pool). Both the pool
+# ------------------------------------------ paged pool (block-table kernel)
+# Block-table read: the dyn-len kernel's watermark-DMA structure extended
+# to read THROUGH per-slot block tables (the int8 PAGED pool). Both the pool
 # watermarks (pos) and the block tables arrive by scalar prefetch; the
 # per-slot block loop DMAs exactly ceil((pos+1)/bs) physical blocks —
 # ``pool_kq.at[table[b, j]]`` — so HBM traffic scales with each slot's
@@ -772,12 +539,11 @@ def int8_paged_decode_attention_sharded(
 # which logical position) never materialises a gathered per-slot view
 # the way the XLA spelling must (paged_gather copies the view every
 # layer, every tick). The pool is K-MAJOR-PER-BLOCK ([NB, K, bs, Dh] /
-# [NB, K, bs]) so each block tile is exactly the v3 kernel's [K, mb,
-# Dh] shape: one batched dot over (slot, head), no per-head relayout
-# (the v1 postmortem's rule). Cross-program first-block prefetch and
-# global buffer parity are carried over from v3 verbatim — parity is
-# the prefix-sum of per-slot block counts, computable by any program
-# from the prefetched watermarks.
+# [NB, K, bs]) so each block tile is exactly the dyn-len kernel's [K, mb,
+# Dh] shape: one batched dot over (slot, head), no per-head relayout.
+# Cross-program first-block prefetch and global buffer parity are the
+# dyn-len kernel's — parity is the prefix-sum of per-slot block counts,
+# computable by any program from the prefetched watermarks.
 
 
 def _kvattn_paged_kernel(
@@ -910,7 +676,8 @@ def int8_paged_decode_attention(
     if interpret is None:
         interpret = _default_interpret()
     qg = q[:, 0].reshape(b, n_kv, rep, dh)
-    # SEQUENTIAL grid ("arbitrary"): cross-program prefetch, as v3.
+    # SEQUENTIAL grid ("arbitrary"): cross-program prefetch, as the
+    # dyn-len kernel.
     kw = {} if interpret else tpu_compiler_params(("arbitrary",))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # watermarks AND block tables

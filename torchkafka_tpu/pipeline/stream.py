@@ -166,8 +166,8 @@ class KafkaStream:
             )
         self._consumer = consumer
         self._processor = processor
-        self._chunked = bool(getattr(processor, "chunked", False))
-        if quarantine is not None and self._chunked:
+        self._processor_chunked = bool(getattr(processor, "chunked", False))
+        if quarantine is not None and self._processor_chunked:
             raise ValueError(
                 "on_processor_error='quarantine' needs a per-record "
                 "processor: a chunked processor's all-or-nothing call has "
@@ -205,7 +205,7 @@ class KafkaStream:
         self.metrics = StreamMetrics()
         self._ledger = OffsetLedger()
         if buckets is not None:
-            if self._chunked:
+            if self._processor_chunked:
                 raise ValueError(
                     "buckets= requires a per-record processor returning "
                     "variable-length 1-D rows; chunked processors emit "
@@ -338,7 +338,7 @@ class KafkaStream:
                 ingest_lag_ms(newest, clock=self._clock)
             )
         self._ledger.fetched_many(records)
-        if self._chunked:
+        if self._processor_chunked:
             # Vectorized path: one processor call per poll chunk, one
             # slice-copy per emitted batch — the throughput hot path.
             try:

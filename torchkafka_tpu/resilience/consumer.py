@@ -82,8 +82,7 @@ class ResilientConsumer(ConsumerIterMixin):
         self.metrics = metrics or ResilienceMetrics()
         # Last breaker state mirrored into metrics — plain attrs, so the
         # per-op happy path compares ints instead of taking RateMeter
-        # locks (this sync runs on EVERY poll/commit; measured in
-        # benchmarks/bench_pod.py --overhead).
+        # locks (this sync runs on EVERY poll/commit).
         self._seen_opens = 0
         self._seen_closes = 0
         self._seen_state = 0.0

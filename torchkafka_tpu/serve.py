@@ -182,13 +182,9 @@ def _slot_layer_step_q(
     (Dh+4)/(2·Dh) ≈ 52% of bf16 pool bytes at Dh=128 — read through
     ``_attend_cached``'s scale-folded mode (scales land on the small
     score/prob tensors; the big operands carry only a cast). A capacity
-    lever that, with scatter writes, also measures neutral-to-BETTER
-    equal-slot throughput than bf16 KV (+7% at 8B/96 slots — the
-    pre-scatter ~20% deficit was the select-rewrite of the pool's four
-    tensors, not the read; PERF.md) for ~2× the slot/context headroom.
-    Quantization error is bounded by absmax/127 per group; OPT-IN
-    because token-exactness vs the bf16 path is deliberately given
-    up."""
+    lever: ~2× the slot/context headroom. Quantization error is bounded by
+    absmax/127 per group; OPT-IN because token-exactness vs the bf16 path is
+    deliberately given up."""
     q, k, v = _project_qkv(x, layer, cfg)
     q = _rope(q, pos_b[:, None], cfg.rope_theta)
     k = _rope(k, pos_b[:, None], cfg.rope_theta)
@@ -198,10 +194,10 @@ def _slot_layer_step_q(
     if use_kernel:
         # K-MAJOR pool ([L, B, K, M, Dh] / [L, B, K, M]): each head's
         # [M, Dh] tile is a contiguous slice, which is what lets the
-        # kernel batch its dots over (slot, head) with no relayout — the
-        # v1 postmortem's fix (ops/kvattn.py docstring). Writes are
-        # scatters like the bf16 path (see _slot_layer_step's note):
-        # per-(row, head) at [l, b, :, pos_b[b]].
+        # kernel batch its dots over (slot, head) with no relayout
+        # (ops/kvattn.py docstring). Writes are scatters like the bf16
+        # path (see _slot_layer_step's note): per-(row, head) at
+        # [l, b, :, pos_b[b]].
         kidx = jnp.arange(ck_q.shape[2])[None, :]
 
         def upd(c, row):  # payload [L, B, K, M, Dh] and scale [L, B, K, M] alike
@@ -214,8 +210,8 @@ def _slot_layer_step_q(
     cv_q = upd(cv_q, vq)
     cv_s = upd(cv_s, vs)
     if use_kernel:
-        # Pallas DYNAMIC-LENGTH int8 decode attention (ops/kvattn.py
-        # v3): per-slot watermarks are scalar-prefetched and the kernel
+        # Pallas DYNAMIC-LENGTH int8 decode attention (ops/kvattn.py):
+        # per-slot watermarks are scalar-prefetched and the kernel
         # manually DMAs M-blocks with cross-program double buffering, so
         # HBM traffic scales with each slot's ACTUAL fill instead of the
         # pool size — inexpressible in XLA, where every read is
@@ -223,14 +219,11 @@ def _slot_layer_step_q(
         # second scalar-prefetch argument that offsets the DMA's source
         # row): a Pallas operand is opaque to XLA, so handing it
         # ``pool[l]`` would materialise the layer's slab every layer.
-        # Net tick win at long pools (the regime "auto"
-        # selects; measured matrix in _build/PERF.md); fills < ~90%
-        # (the continuous-batching norm) widen it. Caller gates on
-        # tiling shapes (a Pallas call is opaque to GSPMD, the
-        # flash_attention_sharded lesson — under a mesh the read runs
-        # per (data, tp) shard inside shard_map, each shard over its
-        # own slots and kv heads; the capability probe gated the
-        # divisibilities).
+        # Caller gates on tiling shapes (a Pallas call is opaque to
+        # GSPMD, the flash_attention_sharded lesson — under a mesh the
+        # read runs per (data, tp) shard inside shard_map, each shard
+        # over its own slots and kv heads; the capability probe gated
+        # the divisibilities).
         from torchkafka_tpu.ops.kvattn import (
             int8_decode_attention_dynlen,
             int8_decode_attention_dynlen_sharded,
@@ -370,9 +363,9 @@ class ServeMetrics:
         # proposing (0 = the built-in / construction-time draft)
         self._draft_refreshes: dict[str, RateMeter] = {}  # draft
         # hot-swaps by reason ("alpha_drop", "forced", ...)
-        # Chunked prefill (kv_pages with prefill_chunk != 0): admission
-        # enqueues uncached suffixes and every tick carries a bounded
-        # chunk of them alongside decode. All zero in legacy/dense modes.
+        # Chunked prefill (kv_pages): admission enqueues uncached
+        # suffixes and every tick carries a bounded chunk of them
+        # alongside decode. All zero with the dense pool.
         self.chunk_ticks = RateMeter()  # ticks that carried prefill chunk rows
         self.admission_stall_ticks = RateMeter()  # EXTRA ticks admissions
         # queued beyond the one-tick minimum (0 when every admission's
@@ -709,17 +702,12 @@ def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg):
     q, k, v = _project_qkv(x, layer, cfg)
     q = _rope(q, pos_b[:, None], cfg.rope_theta)
     k = _rope(k, pos_b[:, None], cfg.rope_theta)
-    # Per-row cache write as a SCATTER (.at[l, rows, pos].set). History: r4
-    # shipped a vmapped dynamic_update_slice here, with a measurement
-    # note claiming the masked-select lowering beat scatter ~10x. r5
-    # re-measured both isolated (fori-chained slope: scatter 3.2 µs vs
-    # select 41 µs per [16, 192, 8, 256] update) and end-to-end (1B
-    # serve tick 6.66 → 4.73 ms, +41% tok/s) — the select rewrites the
-    # whole pool every layer while the scatter writes one row per slot;
-    # the r4 note did not reproduce and is retracted in PERF.md. The
-    # scatter goes into the stacked pool and not into a layer's slab: a
-    # pool that is a scan's input and output is sliced, written back and
-    # copied whole every tick (PERF.md, PR 25); a carry is written in place.
+    # Per-row cache write as a SCATTER (.at[l, rows, pos].set), not a masked
+    # select: the select rewrites the whole pool every layer while the
+    # scatter writes one row per slot. The scatter goes into the stacked
+    # pool and not into a layer's slab: a pool that is a scan's input and
+    # output is sliced, written back and copied whole every tick (PERF.md,
+    # PR 25); a carry is written in place.
     rows = jnp.arange(cache_k.shape[1])
     cache_k = cache_k.at[l, rows, pos_b].set(k[:, 0].astype(cache_k.dtype))
     cache_v = cache_v.at[l, rows, pos_b].set(v[:, 0].astype(cache_v.dtype))
@@ -760,7 +748,7 @@ def _slot_layer_step_latent(x, layer, pool, l, pos_b, cfg):
 
 
 class _PendingPrefill:
-    """One admission's queued chunk-prefill work (paged chunked mode).
+    """One admission's queued chunk-prefill work (paged mode).
 
     The slot and its blocks are already reserved (table linked, radix
     inserted); ``seq`` is the UNCACHED suffix still to be written —
@@ -1029,39 +1017,34 @@ class StreamingGenerator:
         ``generate``); ``"int8"`` = quantized slot pool (int8 payload +
         per-(position, head) f32 absmax scale, ≈52% of bf16 pool bytes at
         head_dim 128) — the memory headroom that buys more concurrent
-        slots at the 8B-class scales (measured: 192 slots run where bf16
-        OOMs; with scatter writes equal-slot throughput is neutral-to-
-        BETTER than bf16 KV, +7% at 8B/96 slots — see PERF.md), at the
-        cost of bounded quantization error (opt-in precisely because
-        token-exactness is given up).
+        slots at the 8B-class scales, at the cost of bounded
+        quantization error (opt-in precisely because token-exactness is
+        given up).
 
         ``kv_kernel``: the Pallas DYNAMIC-LENGTH int8 decode-attention
         kernel (``ops.kvattn.int8_decode_attention_dynlen``) for the
         pool read: per-slot watermarks are scalar-prefetched and only
         positions [0, pos] are DMA'd per slot, so HBM traffic scales
         with each slot's actual fill instead of the pool size —
-        inexpressible in XLA, where every read is pool-shaped. Measured
-        at 8B shapes, M=2048 (paired, interleaved): 1.92× the XLA read
-        at half fill, 1.57× at mixed fills, 0.94× at exactly-full — and
-        continuous batching lives at partial fills. In-tick integration
-        still costs ~flat ms at short pools, so ``"auto"`` (default)
-        engages the kernel only at int8 pools ≥ 1024 tokens (TPU
-        backend, tiling shapes, pool tiling at a ≥ 256 block); else the
-        XLA read. Composes with ``mesh``: a Pallas call is opaque to
-        GSPMD, so the sharded read runs per (data, tp) shard inside
-        ``shard_map`` (``ops.kvattn.int8_decode_attention_dynlen_
-        sharded``, the ``flash_attention_sharded`` precedent — slots
-        over data, kv heads over tp, no collectives), gated by the
-        capability probe on the same divisibilities the XLA layouts
-        need. ``True``: REQUIRE the kernel at any pool length; raises
-        if shapes/mesh can't honor it (so a benchmark never
-        misattributes the XLA read's numbers to the kernel; the reason
-        is in the error and on ``metrics``); off-TPU it runs in Pallas
-        interpret mode — correct but slow, for tests. ``False``: always
-        the XLA read. In kernel mode the pool is stored K-major
-        ([L, B, K, M, Dh]) so every head's tile is a contiguous slice —
-        the layout lesson from the v1 kernel's negative result
-        (ops/kvattn.py docstring); note the tick time is then
+        inexpressible in XLA, where every read is pool-shaped, and
+        continuous batching lives at partial fills (time a call and
+        roofline share: PERF.md §5). ``"auto"`` (default) engages the
+        kernel only at int8 pools ≥ 1024 tokens (TPU backend, tiling
+        shapes, pool tiling at a ≥ 256 block); else the XLA read.
+        Composes with ``mesh``: a Pallas call is opaque to GSPMD, so the
+        sharded read runs per (data, tp) shard inside ``shard_map``
+        (``ops.kvattn.int8_decode_attention_dynlen_sharded``, the
+        ``flash_attention_sharded`` precedent — slots over data, kv
+        heads over tp, no collectives), gated by the capability probe on
+        the same divisibilities the XLA layouts need. ``True``: REQUIRE
+        the kernel at any pool length; raises if shapes/mesh can't honor
+        it (so a benchmark never misattributes the XLA read's numbers to
+        the kernel; the reason is in the error and on ``metrics``);
+        off-TPU it runs in Pallas interpret mode — correct but slow, for
+        tests. ``False``: always the XLA read. In kernel mode the pool
+        is stored K-major ([L, B, K, M, Dh]) so every head's tile is a
+        contiguous slice and the kernel's dots batch over heads with no
+        relayout (ops/kvattn.py docstring); note the tick time is then
         FILL-DEPENDENT (see ``decode_roofline``'s ``fill``).
 
         ``max_send_failure_streak``: a SYNCHRONOUS send failure leaves its
@@ -1089,50 +1072,46 @@ class StreamingGenerator:
         just re-prefills). Pool pressure defers admissions (FIFO
         re-offer once blocks free); a pool too small for even one slot
         falls back to dense cache-off serving with a warning
-        (``metrics.cache_fallbacks``). Composes with ``mesh`` in
-        chunked mode: the block pools shard kv heads over tp and
-        replicate over data (shared storage — any slot's table may
-        reference any block), per-slot state shards over data, tables
+        (``metrics.cache_fallbacks``). Composes with ``mesh``: the
+        block pools shard kv heads over tp and replicate over data
+        (shared storage — any slot's table may reference any block),
+        per-slot state shards over data, tables
         ride replicated, and the whole admission/radix/chunk machinery
         is mesh-blind host code — token-exact vs single-device serving
         (differential-tested across {data}, {tp}, {data, tp} meshes).
         Not MoE (the paged prefill routes experts densely — decode's
         rule — which would break exactness vs the training-dispatch
-        dense prefill), and the LEGACY per-record admission
-        (``prefill_chunk=0``) stays single-device (its [1, S] suffix
-        prefill has no data shard; both validated with precise
-        errors by ``kvcache.resolve_kv_backend``).
+        dense prefill; validated with a precise error by
+        ``kvcache.resolve_kv_backend``).
 
-        Admission is CHUNKED by default (``prefill_chunk`` on the
-        config): instead of one suffix-prefill dispatch per record (the
-        PR-4 path, kept at ``prefill_chunk=0``), admission reserves the
-        slot + blocks and enqueues the uncached suffix host-side; every
-        decode tick then carries a bounded, statically-shaped chunk of
-        queued suffix tokens ALONGSIDE all decode slots in the SAME
-        jitted program (Sarathi-style — prefill rides the weight stream
-        decode already pays for). Consequences: admission compiles O(1)
-        programs regardless of suffix-length mix (the per-(suffix,
-        start) jit zoo is gone), decode inter-token latency stays one
-        tick per token under prompt storms (the chunk bounds prefill
-        work per tick; the queue drains FIFO), and per-record outputs
-        stay bitwise identical to the dense and per-record paths (each
-        chunk query attends exactly [0, position] of its slot's logical
-        view — the same math at every chunk width).
+        Admission is CHUNKED (``prefill_chunk`` on the config is the
+        chunk width): admission reserves the slot + blocks and enqueues
+        the uncached suffix host-side; every decode tick then carries a
+        bounded, statically-shaped chunk of queued suffix tokens
+        ALONGSIDE all decode slots in the SAME jitted program
+        (Sarathi-style — prefill rides the weight stream decode already
+        pays for). Consequences: admission compiles O(1) programs
+        regardless of suffix-length mix, decode inter-token latency
+        stays one tick per token under prompt storms (the chunk bounds
+        prefill work per tick; the queue drains FIFO), and per-record
+        outputs stay bitwise identical to the dense path (each chunk
+        query attends exactly [0, position] of its slot's logical view —
+        the same math at every chunk width).
 
-        ``kv_dtype="int8"`` composes with ``kv_pages`` (chunked mode):
-        the block pools store int8 payloads + group-wise absmax scales
+        ``kv_dtype="int8"`` composes with ``kv_pages``: the block pools
+        store int8 payloads + group-wise absmax scales
         (``models.quant.quant_kv_groups`` — the same (position, head)
         groups as the dense int8 pool, so int8-paged is token-exact vs
         int8-DENSE serving), ~52% of the compute-dtype pool bytes.
         ``kv_kernel`` then selects the Pallas BLOCK-TABLE read for the
         decode ticks (``ops.kvattn.int8_paged_decode_attention`` — the
-        v3 watermark-DMA structure reading through per-slot block
-        tables, so HBM traffic scales with live tokens and no gathered
-        view is materialised); "auto" engages it on TPU at pools >=
-        1024 tokens with tiling shapes, True requires it (raises when
-        it cannot be honored), chunk-carrying ticks read via the XLA
-        gather either way (the multi-query chunk needs the gathered
-        view).
+        dyn-len kernel's watermark-DMA structure reading through
+        per-slot block tables, so HBM traffic scales with live tokens
+        and no gathered view is materialised); "auto" engages it on TPU
+        at pools >= 1024 tokens with tiling shapes, True requires it
+        (raises when it cannot be honored), chunk-carrying ticks read
+        via the XLA gather either way (the multi-query chunk needs the
+        gathered view).
 
         ``journal``: a ``journal.DecodeJournal`` — record, per in-flight
         slot, the minimal resumable state (record identity + payload CRC,
@@ -1149,7 +1128,7 @@ class StreamingGenerator:
         journaled FINISHED completion re-serves with zero re-decode.
         Warm resume of partial generations needs the compute-dtype pool
         (``kv_dtype=None``) and a resume-capable prefill: one device,
-        a data-free mesh, or the paged CHUNKED path under any mesh
+        a data-free mesh, or the paged path under any mesh
         (``_resume_supported``); hints are ignored (cold replay, still
         correct) otherwise.
 
@@ -1160,7 +1139,7 @@ class StreamingGenerator:
         the record's (topic, partition, offset) identity;
         ``trace_replica`` tags the events (the fleet sets it per
         replica). None (the default) costs only the per-site ``is not
-        None`` guards — measured in benchmarks/bench_obs.py.
+        None`` guards.
 
         ``quarantine``: a ``resilience.PoisonQuarantine``. Without it, an
         undecodable prompt is retired immediately as dropped (the
@@ -1346,11 +1325,10 @@ class StreamingGenerator:
         # plane; the DECODE group's exactly-once story is untouched —
         # it never depends on handoffs existing).
         if prefill_role:
-            if kv_pages is None or kv_pages.prefill_chunk == 0:
+            if kv_pages is None:
                 raise ValueError(
-                    "prefill_role requires kv_pages in chunked mode "
-                    "(the handoff is cut from the chunked-prefill "
-                    "machinery)"
+                    "prefill_role requires kv_pages (the handoff is cut "
+                    "from the chunked-prefill machinery)"
                 )
         self._prefill_role = prefill_role
         self._prefilled_ready: list[tuple[Record, PrefillHandoff]] = []
@@ -1361,13 +1339,12 @@ class StreamingGenerator:
         self._tier_seen = [0, 0, 0]  # demotions/promotions/hits mirrored
         # ONE capability probe for the whole (pages × dtype × kernel ×
         # mesh) space: validates the genuine exclusions eagerly (bad
-        # dtype/kernel values, MoE + pages, legacy per-record admission
-        # under int8 or a mesh, un-honorable kv_kernel=True) and raises
-        # precise errors. The composed axes — sharded paged pools,
-        # sharded kernels — are SUPPORTED now; _build/_build_paged
-        # re-resolve against the final pool length for the engagement
-        # decision and surface it on ``metrics`` (kv_backend info +
-        # kernel_engaged/kernel_disabled).
+        # dtype/kernel values, MoE + pages, un-honorable kv_kernel=True)
+        # and raises precise errors. The composed axes — sharded paged
+        # pools, sharded kernels — are SUPPORTED now;
+        # _build/_build_paged re-resolve against the final pool length
+        # for the engagement decision and surface it on ``metrics``
+        # (kv_backend info + kernel_engaged/kernel_disabled).
         resolve_kv_backend(
             cfg, mesh=mesh, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
             kv_pages=kv_pages, max_len=prompt_len + max_new, slots=slots,
@@ -1381,7 +1358,6 @@ class StreamingGenerator:
         # as while decoding.
         self._prefilling = np.zeros((slots,), bool)
         self._prefill_queue: list[_PendingPrefill] = []
-        self._chunked = False
         self._tick_counter = 0
         self._paged_table_idx = 2  # the table's slot in the state tuple
         self._kv_int8 = kv_dtype == "int8"
@@ -1473,15 +1449,12 @@ class StreamingGenerator:
         mesh = self._mesh
 
         kv_int8 = self._kv_int8
-        # The Pallas decode kernels (ops/kvattn.py). Full-tick pairs on
-        # v5e, 8B int8 weights, kernel off vs on: short pools LOSE
-        # (M=192/B=16 13.0→13.5 ms with scatter writes) — flat
-        # integration cost (K-major layout handling + the fusion break
-        # around a Pallas call) — while long pools WIN and the win
-        # grows with pool bytes (v2 K-major read: M=2048 33.95→27.24
-        # ms, +25% tok/s). The engagement decision (incl. the "auto"
-        # >= 1024-pool threshold and the per-mesh divisibilities the
-        # shard_map wrapping needs) is the capability probe's —
+        # The Pallas dyn-len read (ops/kvattn.py). The engagement
+        # decision (incl. the "auto" >= 1024-pool threshold — a Pallas
+        # call carries a flat cost, the K-major layout handling and a
+        # fusion break, that a short pool's read does not win back — and
+        # the per-mesh divisibilities the shard_map wrapping needs) is
+        # the capability probe's —
         # kvcache.resolve_kv_backend — so dense and paged builds, and
         # the metrics that surface the decision, share one rule. Under
         # a mesh the kernel runs per (data, tp) shard inside shard_map
@@ -1669,12 +1642,9 @@ class StreamingGenerator:
                 # scatter into the carried pool.
                 t = pos - P  # decode ticks completed before this one
                 idx = jnp.minimum(t + 1, self._max_new - 1)
-                # One-hot select over the tiny [B, max_new] buffer.
-                # (r4 claimed scatter cost ~2 ms here; r5 re-measured
-                # both spellings at parity within noise — 5.36 vs 5.34
-                # ms 1B tick — so this stays only because it is
-                # equivalent, unlike the POOL writes where scatter wins
-                # big, see _slot_layer_step.)
+                # One-hot select over the tiny [B, max_new] buffer
+                # (unlike the POOL writes, where only a scatter avoids
+                # rewriting the pool: _slot_layer_step).
                 onehot = jnp.arange(self._max_new)[None, :] == idx[:, None]
                 gen = jnp.where(onehot & act[:, None], tok[:, None], gen)
                 hit_eos = (
@@ -1853,9 +1823,7 @@ class StreamingGenerator:
         else:
             self._kv_radix = RadixCache(self._kv_alloc, pages.block_size)
         self._table_np = np.zeros((self._slots, nblk), np.int32)  # all sink
-        self._paged_prefill_jits: dict[tuple[int, int], Callable] = {}
-        # Chunked admission (the default; prefill_chunk=0 keeps the
-        # legacy per-record dispatch). The auto width covers every
+        # The chunk's auto width covers every
         # admission one serving quantum can offer (<= slots records,
         # <= prompt_len uncached tokens each) so default-config
         # admissions complete their prefill in the single next tick —
@@ -1863,7 +1831,6 @@ class StreamingGenerator:
         # gather dominates the tick (each chunk row materialises its
         # slot's whole logical view per layer), and a long-prompt storm
         # is exactly where bounded per-tick prefill work is the point.
-        self._chunked = pages.prefill_chunk != 0
         self._prefill_chunk = pages.prefill_chunk or min(
             self._slots * self._prompt_len, max(256, 2 * pages.block_size)
         )
@@ -1893,13 +1860,13 @@ class StreamingGenerator:
         kv_int8 = self._kv_int8
         self._paged_table_idx = 4 if kv_int8 else 2
 
-        # Pallas BLOCK-TABLE read (ops/kvattn.py v4): the v3 watermark-
-        # DMA kernel reading through per-slot block tables, int8 pools
-        # only. Decode-only ticks read through it; chunk-carrying ticks
-        # use the XLA gather (the multi-query chunk needs the gathered
-        # view, and a storm tick is prefill-dominated anyway). The
-        # engagement decision is the shared capability probe's ("auto"
-        # only in the measured-win regime — TPU, long pools; True =
+        # Pallas BLOCK-TABLE read (ops/kvattn.py): the dyn-len kernel's
+        # watermark-DMA structure reading through per-slot block tables,
+        # int8 pools only. Decode-only ticks read through it;
+        # chunk-carrying ticks use the XLA gather (the multi-query chunk
+        # needs the gathered view, and a storm tick is prefill-dominated
+        # anyway). The engagement decision is the shared capability
+        # probe's ("auto" only on TPU at long pools; True =
         # require-or-raise, validated at construction); under a mesh
         # the read runs per (data, tp) shard inside shard_map
         # (int8_paged_decode_attention_sharded) with the block pools
@@ -2024,59 +1991,6 @@ class StreamingGenerator:
                 preferred_element_type=jnp.float32,
             )
 
-        def suffix_prefill(params, pool_k, pool_v, table_row, toks, *, start):
-            """Chunked prefill of ONE slot's uncached prompt suffix.
-
-            toks: [1, S] (S = prompt_len - matched tokens); queries sit at
-            positions [start, start + S) and attend over the cached
-            prefix (gathered from the shared blocks the radix match
-            linked) plus themselves, causally — a miss (start=0) is a
-            plain full prefill. Per-S jit specialisations are cached
-            (at most prompt_len // block_size + 1 of them). Returns the
-            last position's logits (token 0 sampling) + updated pools."""
-            s = toks.shape[1]
-            x = embed_rows(params["embed"], toks, cfg.dtype)  # [1, S, D]
-            positions = (start + jnp.arange(s))[None, :]  # [1, S]
-
-            def body(x, inputs):
-                layer, pk, pv = inputs
-                q, k, v = _project_qkv(x, layer, cfg)
-                q = _rope(q, positions, cfg.rope_theta)
-                k = _rope(k, positions, cfg.rope_theta)
-                x, pk, pv = block_table_attention(
-                    x, q, k, v, pk, pv, table_row, positions, layer, cfg
-                )
-                return x, (pk, pv)
-
-            x, (pool_k, pool_v) = lax.scan(
-                body, x, (params["layers"], pool_k, pool_v)
-            )
-            x = _rms_norm(x, params["ln_f"])
-            logits = jnp.einsum(
-                "bd,dv->bv", x[:, -1],
-                load_weight(params["lm_head"], cfg.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            return logits, pool_k, pool_v
-
-        self._paged_suffix_fn = suffix_prefill
-
-        def admit_merge(last_tok, pos, gen, logits, admit_mask, keys):
-            """The dense admit's sampling/bookkeeping tail over host-
-            assembled per-slot logits rows: same [B, V] pick, same
-            per-record key discipline (index 0), so cache-on token 0
-            matches the dense server's bitwise."""
-            tok0 = pick_rows(
-                logits, keys, jnp.zeros((logits.shape[0],), jnp.int32)
-            )
-            last_tok = jnp.where(admit_mask, tok0, last_tok)
-            pos = jnp.where(admit_mask, P, pos)
-            gen = jnp.where(admit_mask[:, None], 0, gen)
-            gen = gen.at[:, 0].set(jnp.where(admit_mask, tok0, gen[:, 0]))
-            return last_tok, pos, gen
-
-        self._paged_merge = jax.jit(admit_merge)
-
         K = self._ticks_per_sync
         ti = self._paged_table_idx
 
@@ -2084,8 +1998,7 @@ class StreamingGenerator:
                             done_latch, n_out):
             """The decode tick's sampling/EOS/position bookkeeping over
             per-slot logits — identical to the dense tick body's tail
-            (see the dense ``tick_block`` for the measured rationale on
-            the one-hot gen write)."""
+            (see the dense ``tick_block`` on the one-hot gen write)."""
             tok = pick_rows(logits, skey, pos - P + 1)
             t = pos - P  # decode ticks completed before this one
             idx = jnp.minimum(t + 1, self._max_new - 1)
@@ -2237,14 +2150,11 @@ class StreamingGenerator:
         self._tick_jit = _tick
         self._tick_block_raw = tick_block
         self._tick_fn = lambda *a: _tick(self._params, *a)
-        if self._chunked:
-            _tick_chunk = jax.jit(tick_chunk_block, donate_argnums=(1,))
-            self._tick_chunk_jit = _tick_chunk
-            self._tick_chunk_fn = lambda *a: _tick_chunk(self._params, *a)
-        else:
-            self._tick_chunk_fn = None
+        _tick_chunk = jax.jit(tick_chunk_block, donate_argnums=(1,))
+        self._tick_chunk_jit = _tick_chunk
+        self._tick_chunk_fn = lambda *a: _tick_chunk(self._params, *a)
         self._admit_fn = None  # paged admission is host-orchestrated
-        self._resume_exec = None  # paged resume rides the chunk/suffix path
+        self._resume_exec = None  # paged resume rides the chunk path
         # _table_np.copy(): jnp.asarray may ZERO-COPY an aligned host
         # buffer on the CPU backend; admissions mutate _table_np in
         # place, which would rewrite this device table from under the
@@ -2292,29 +2202,6 @@ class StreamingGenerator:
             self._pos = jax.device_put(self._pos, rep)
             self._gen = jax.device_put(self._gen, rep)
 
-    def _paged_prefill_call(self, caches, table_row, toks, *,
-                            total_len: int | None = None):
-        """Dispatch the per-(suffix, start)-jitted suffix prefill; returns
-        (logits [1, V], caches with the pools rebound). ``total_len``: the
-        full sequence being prefilled (default prompt_len; a journal warm
-        resume prefills prompt + emitted tokens, so its queries start at
-        ``total_len - S``). Overridden by the spec server to prefill both
-        model pools."""
-        s = int(toks.shape[1])
-        start = (total_len or self._prompt_len) - s
-        fn = self._paged_prefill_jits.get((s, start))
-        if fn is None:
-            fn = jax.jit(
-                functools.partial(self._paged_suffix_fn, start=start),
-                donate_argnums=(1, 2),
-            )
-            self._paged_prefill_jits[(s, start)] = fn
-        with xprof.span(xprof.SPAN_ADMIT):
-            logits, pool_k, pool_v = fn(
-                self._params, caches[0], caches[1], table_row, toks
-            )
-        return logits, (pool_k, pool_v) + caches[2:]
-
     def _paged_set_table(self, caches, table_dev):
         """Rebind the device block table inside the state tuple (the
         table's slot in the tuple differs by pool mode — after the 2
@@ -2324,22 +2211,19 @@ class StreamingGenerator:
         return caches[:i] + (table_dev,) + caches[i + 1:]
 
     def _device_table(self) -> jax.Array:
-        """The block table the DEVICE state carries. In chunked mode the
+        """The block table the DEVICE state carries. The
         rows of reserved-but-still-prefilling slots are masked to the
         sink: an inactive decode row still writes its frozen position
         unconditionally, and that write must never land in the freshly
         linked blocks the chunk rows are filling (the chunk rows carry
-        their REAL rows separately, as the ctable operand)."""
-        if self._chunked:
-            t = np.where(
-                self._active[:, None], self._table_np, SINK_BLOCK
-            ).astype(np.int32)
-            return jnp.asarray(t)
-        # .copy(): jnp.asarray may ZERO-COPY an aligned host buffer on
-        # the CPU backend, and _table_np is mutated in place by later
-        # admissions/releases — the device table must be a snapshot,
-        # never a live view (alignment-dependent corruption otherwise).
-        return jnp.asarray(self._table_np.copy())
+        their REAL rows separately, as the ctable operand). A fresh
+        array, never a view of ``_table_np``: jnp.asarray may ZERO-COPY
+        an aligned host buffer on the CPU backend, and later
+        admissions/releases mutate ``_table_np`` in place."""
+        t = np.where(
+            self._active[:, None], self._table_np, SINK_BLOCK
+        ).astype(np.int32)
+        return jnp.asarray(t)
 
     def _release_slot_blocks(self, i: int) -> None:
         """Drop a retired slot's references; its table row falls back to
@@ -2494,7 +2378,7 @@ class StreamingGenerator:
     def _take_handoff(self, rec: Record) -> "PrefillHandoff | None":
         """Pop and validate ``rec``'s handoff; None = prefill locally
         (the at-least-once fallback every disaggregated path keeps)."""
-        if self._kv_pages is None or not self._chunked:
+        if self._kv_pages is None:
             return None
         hand = self._prefill_handoffs.pop(
             (rec.topic, rec.partition, rec.offset), None
@@ -2653,23 +2537,18 @@ class StreamingGenerator:
         duplicate prompt inside one batch hits its predecessor's freshly
         inserted prefix.
 
-        CHUNKED mode (the default): the slot is reserved and the suffix
-        ENQUEUED — the decode tick's fused program processes it a
-        bounded chunk at a time (step → _pack_chunk), and the slot
-        activates (token 0 sampled with the same per-record-key
-        discipline, or journal state restored) the tick its last suffix
-        token lands. Admission itself dispatches NOTHING and compiles
-        nothing: O(1) programs across any suffix-length mix. The radix
-        insert happens here, at reservation time — a later admission
-        matching these still-being-filled blocks is safe because the
-        chunk queue is strictly FIFO, so the matched positions are
-        always written in an earlier (or the same, write-before-attend)
-        dispatch than any query that attends over them.
-
-        LEGACY mode (``prefill_chunk=0``, the PR-4 baseline): one
-        suffix-prefill dispatch per record (a jit specialisation per
-        (suffix, start) pair), ending with the same [B, V] sampling
-        merge as the dense admit.
+        The slot is reserved and the suffix ENQUEUED — the decode tick's
+        fused program processes it a bounded chunk at a time (step →
+        _pack_chunk), and the slot activates (token 0 sampled with the
+        same per-record-key discipline, or journal state restored) the
+        tick its last suffix token lands. Admission itself dispatches
+        NOTHING and compiles nothing: O(1) programs across any
+        suffix-length mix. The radix insert happens here, at reservation
+        time — a later admission matching these still-being-filled
+        blocks is safe because the chunk queue is strictly FIFO, so the
+        matched positions are always written in an earlier (or the same,
+        write-before-attend) dispatch than any query that attends over
+        them.
 
         A record carrying a journal resume hint prefills
         ``prompt + emitted_tokens`` instead (the cached prompt prefix
@@ -2693,14 +2572,10 @@ class StreamingGenerator:
         bs = self._kv_pages.block_size
         nblk = self._blocks_per_slot
         B, W = self._slots, self._key_width
-        admit_mask = np.zeros((B,), bool)
         keys_np = np.zeros((B, W), np.uint32)
         key_mask = np.zeros((B,), bool)
-        slot_ids: list[int] = []
-        logits_rows: list = []
-        resumed: list[tuple[int, np.ndarray]] = []
         adopted: list[tuple[int, np.ndarray]] = []
-        reserved = 0  # chunked-mode reservations (prefill enqueued)
+        reserved = 0  # slots reserved, their prefill enqueued
         journal_dirty = False
         # NOTE: no local alias of self._caches here — tier demotions/
         # promotions inside radix.match/evict rebind self._caches
@@ -2817,9 +2692,9 @@ class StreamingGenerator:
             start = len(matched) * bs
             # Register the PROMPT's matchable whole blocks for reuse
             # (existing nodes are the ones we just matched; new nodes
-            # adopt this slot's freshly linked private blocks — in
-            # chunked mode still being FILLED, safe by chunk-queue FIFO:
-            # see the method docstring). Emitted-token blocks are never
+            # adopt this slot's freshly linked private blocks — still
+            # being FILLED, safe by chunk-queue FIFO: see the method
+            # docstring). Emitted-token blocks are never
             # cached: offsets are unique, so they could only ever match
             # their own redelivery.
             cacheable = RadixCache.matchable_blocks(len(toks), bs)
@@ -2857,36 +2732,19 @@ class StreamingGenerator:
                 if self._journal is not None:
                     self._journal_record(rec, key_np, emitted, False)
                     journal_dirty = True
-            if self._chunked:
-                # Reserve, enqueue, dispatch nothing: the tick's fused
-                # program prefills this suffix chunk by chunk and the
-                # slot activates the tick its last token lands.
-                self._prefilling[i] = True
-                self._prefill_queue.append(_PendingPrefill(
-                    i, rec, np.asarray(seq[start:], np.int32), start,
-                    key_np, emitted, self._tick_counter,
-                ))
-                if self._tracer is not None:
-                    self._tracer.prefill_queued(
-                        rec, len(seq) - start, replica=self._trace_replica
-                    )
-                reserved += 1
-                continue
-            # LEGACY: one suffix-prefill dispatch per record (a jit
-            # specialisation per suffix length) + the batched merge.
-            self.metrics.prefill_tokens.add(len(seq) - start)
-            self._active[i] = True
-            table_row = jnp.asarray(self._table_np[i][None, :].copy())
-            logits, self._caches = self._paged_prefill_call(
-                self._caches, table_row, jnp.asarray(seq[None, start:]),
-                total_len=len(seq),
-            )
-            if hint is None:
-                admit_mask[i] = True
-                slot_ids.append(i)
-                logits_rows.append(logits)
-            else:
-                resumed.append((i, emitted))
+            # Reserve, enqueue, dispatch nothing: the tick's fused
+            # program prefills this suffix chunk by chunk and the slot
+            # activates the tick its last token lands.
+            self._prefilling[i] = True
+            self._prefill_queue.append(_PendingPrefill(
+                i, rec, np.asarray(seq[start:], np.int32), start,
+                key_np, emitted, self._tick_counter,
+            ))
+            if self._tracer is not None:
+                self._tracer.prefill_queued(
+                    rec, len(seq) - start, replica=self._trace_replica
+                )
+            reserved += 1
         if queue:  # defensive: slots exhausted with records left
             self._paged_deferred.extend(queue)
         # Count records ENTERING the deferred state, not retry spins: the
@@ -2897,23 +2755,21 @@ class StreamingGenerator:
             self.metrics.admission_deferrals.add(newly_deferred)
         self.metrics.cache_pool_occupancy.set(self._kv_alloc.occupancy())
         self._sync_tier_metrics()
-        admitted = int(admit_mask.sum())
-        filled = admitted + len(resumed) + len(adopted) + reserved
-        # Both paged modes prefill row by row: no row is prefilled that
-        # was not admitted (an adoption prefills nothing here).
-        prefilled = filled - len(adopted)
-        if prefilled:
+        filled = len(adopted) + reserved
+        # The paged admission prefills row by row: no row is prefilled
+        # that was not admitted (an adoption prefills nothing here).
+        if reserved:
             self.metrics.admit_calls.add(1)
-            self.metrics.admit_rows.add(prefilled)
-            self.metrics.admit_rows_prefilled.add(prefilled)
+            self.metrics.admit_rows.add(reserved)
+            self.metrics.admit_rows_prefilled.add(reserved)
         if filled:
             if in_flight > 0:
                 self.metrics.readmissions.add(filled)
-            if not self._chunked or adopted:
-                # Chunked reservations push nothing: the device table
-                # keeps prefilling rows masked to the sink until
-                # activation (_device_table). Adopted slots activate NOW
-                # — their rows must unmask this push.
+            if adopted:
+                # Reservations push nothing: the device table keeps
+                # prefilling rows masked to the sink until activation
+                # (_device_table). Adopted slots activate NOW — their
+                # rows must unmask this push.
                 self._caches = self._paged_set_table(
                     self._caches, self._device_table()
                 )
@@ -2921,28 +2777,12 @@ class StreamingGenerator:
                 jnp.asarray(key_mask)[:, None], jnp.asarray(keys_np),
                 self._slot_keys,
             )
-        if admitted:
-            logits_b = jnp.zeros(
-                (self._slots, self._cfg.vocab_size), jnp.float32
-            ).at[jnp.asarray(slot_ids)].set(
-                jnp.concatenate(logits_rows, axis=0)
-            )
-            self._last_tok, self._pos, self._gen = self._paged_merge(
-                self._last_tok, self._pos, self._gen, logits_b,
-                jnp.asarray(admit_mask), jnp.asarray(keys_np),
-            )
-            if self._tracer is not None:
-                for i in slot_ids:
-                    self._tracer.slot_active(
-                        self._slot_rec[i], replica=self._trace_replica,
-                        dispatched=True,
-                    )
-        if resumed or adopted:
+        if adopted:
             res_mask = np.zeros((B,), bool)
             res_last = np.zeros((B,), np.int32)
             res_pos = np.zeros((B,), np.int32)
             res_gen = np.zeros((B, self._max_new), np.int32)
-            for i, emitted in resumed + adopted:
+            for i, emitted in adopted:
                 res_mask[i] = True
                 res_last[i] = emitted[-1]
                 # An adoption restores exactly one emitted token (the
@@ -2958,11 +2798,6 @@ class StreamingGenerator:
                 m[:, None], jnp.asarray(res_gen), self._gen
             )
             if self._tracer is not None:
-                for i, _emitted in resumed:
-                    self._tracer.slot_active(
-                        self._slot_rec[i], replica=self._trace_replica,
-                        warm=True,
-                    )
                 for i, _emitted in adopted:
                     # Adoption's first token genuinely exists now: TTFT
                     # closes here (not warm — nothing predates the poll).
@@ -3177,33 +3012,20 @@ class StreamingGenerator:
         key = self._slot_keys
         if self._kv_pages is not None:
             # Compile every program a paged serve can dispatch: the
-            # fused chunk tick (chunked; an all-padding chunk — writes
-            # land in the sink) OR the legacy miss-path suffix prefill,
-            # plus the sampling merge (all-False mask admits nothing)
-            # and the decode-only tick. Chunked admission compiles
+            # fused chunk tick (an all-padding chunk — writes land in
+            # the sink) and the decode-only tick. Admission compiles
             # NOTHING later — these are the whole program set, whatever
-            # suffix-length mix arrives (the jit-zoo fix).
-            if self._chunked:
-                C, nblk = self._prefill_chunk, self._blocks_per_slot
-                out = self._tick_chunk_fn(
-                    self._caches, self._last_tok, self._pos, self._gen,
-                    none, key, jnp.zeros((C,), jnp.int32),
-                    jnp.full((C, nblk), SINK_BLOCK, jnp.int32),
-                    jnp.zeros((C,), jnp.int32), none,
-                    jnp.zeros((B,), jnp.int32),
-                )
-                self._caches, self._last_tok, self._pos, self._gen = out[:4]
-                jax.device_get(out[4])
-            else:
-                table_row = jnp.zeros((1, self._blocks_per_slot), jnp.int32)
-                toks = jnp.zeros((1, self._prompt_len), jnp.int32)
-                _logits, self._caches = self._paged_prefill_call(
-                    self._caches, table_row, toks
-                )
-            logits_b = jnp.zeros((B, self._cfg.vocab_size), jnp.float32)
-            self._last_tok, self._pos, self._gen = self._paged_merge(
-                self._last_tok, self._pos, self._gen, logits_b, none, key
+            # suffix-length mix arrives.
+            C, nblk = self._prefill_chunk, self._blocks_per_slot
+            out = self._tick_chunk_fn(
+                self._caches, self._last_tok, self._pos, self._gen,
+                none, key, jnp.zeros((C,), jnp.int32),
+                jnp.full((C, nblk), SINK_BLOCK, jnp.int32),
+                jnp.zeros((C,), jnp.int32), none,
+                jnp.zeros((B,), jnp.int32),
             )
+            self._caches, self._last_tok, self._pos, self._gen = out[:4]
+            jax.device_get(out[4])
             out = self._tick_fn(
                 self._caches, self._last_tok, self._pos, self._gen, none, key
             )
@@ -3509,7 +3331,7 @@ class StreamingGenerator:
 
         int8 pools never (exactness was traded away — the one contract
         warm resume exists to keep). Compute-dtype pools: always on one
-        device; under a mesh, the paged CHUNKED path resumes fine (the
+        device; under a mesh, the paged path resumes fine (the
         prompt + emitted tokens ride the chunk queue and state restores
         host-side), and the dense path resumes when the mesh carries no
         data axis (its [1, S] resume prefill has no batch to shard —
@@ -3519,7 +3341,7 @@ class StreamingGenerator:
             return False
         if self._mesh is None:
             return True
-        if self._kv_pages is not None and self._chunked:
+        if self._kv_pages is not None:
             return True
         return self._mesh.shape.get("data", 1) == 1
 
@@ -3571,11 +3393,7 @@ class StreamingGenerator:
         paged mode, where pool pressure can also DEFER records — call
         with an empty list to re-offer the deferred backlog)."""
         if self._kv_pages is not None:
-            if not self._chunked:
-                # Legacy paged admission dispatches a prefill per record,
-                # each under its own tk_serve:admit.
-                return self._admit_records_paged(records)
-            # Chunked admission dispatches no prefill (the fused tick
+            # Paged admission dispatches no prefill (the fused tick
             # carries it): the whole call is preparation.
             with xprof.span(xprof.SPAN_ADMIT_PREP):
                 return self._admit_records_paged(records)
@@ -3826,7 +3644,7 @@ class StreamingGenerator:
             with xprof.span(xprof.SPAN_RETIRE):
                 for rec, out in ready:
                     self._retire_completion(rec, out, completions)
-        run_chunk = self._chunked and bool(self._prefill_queue)
+        run_chunk = bool(self._prefill_queue)
         if self._active.any() or run_chunk:
             self._tick_counter += 1
             tick_t0 = time.perf_counter()

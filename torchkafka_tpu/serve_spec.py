@@ -55,14 +55,11 @@ from the layouts alone — token-exact vs single-device spec serving
 
 Measured acceptance is a first-class output: the state tuple carries
 device-side (rounds, proposed, accepted) counters and ``spec_stats()``
-reports them, so harness scenario 7 ``--spec`` and
-``benchmarks/bench_spec.py --serve`` publish the MEASURED α of a real
-checkpoint, not a hypothetical point on the i.i.d. curve.
+reports them, so harness scenario 7 ``--spec`` publishes the MEASURED α
+of a real checkpoint, not a hypothetical point on the i.i.d. curve.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -74,7 +71,6 @@ from torchkafka_tpu.models.spec_decode import _multi_step, truncated_draft
 from torchkafka_tpu.models.transformer import _rms_norm, _rope
 from torchkafka_tpu.resilience.crashpoint import crash_hook
 from torchkafka_tpu.serve import StreamingGenerator
-from torchkafka_tpu.utils import tracing as xprof
 
 
 class SpecStreamingGenerator(StreamingGenerator):
@@ -412,9 +408,9 @@ class SpecStreamingGenerator(StreamingGenerator):
         multi-query verify, target-argmax accept — but both models' slot
         caches are block pools ``[L, NB, bs, K, Dh]`` addressed through
         ONE per-slot block table, and admission goes through the base
-        class's radix match → link → suffix-prefill path (both pools
-        prefilled per record; a prefix hit skips BOTH models' prompt
-        re-prefill). Verify/rollback respect block boundaries by
+        class's radix match → link → chunk-queue path (both pools
+        prefilled by the chunked tick; a prefix hit skips BOTH models'
+        prompt re-prefill). Verify/rollback respect block boundaries by
         construction: the verify's [pos, pos + k] writes scatter through
         the table (a span may straddle blocks — each position resolves
         its own (block, offset)), the slot's table covers the full
@@ -461,36 +457,6 @@ class SpecStreamingGenerator(StreamingGenerator):
                 preferred_element_type=jnp.float32,
             )
             return logits, pool_k, pool_v
-
-        def suffix_prefill(params_pair, t_k, t_v, d_k, d_v, table_row, toks,
-                           *, start):
-            """Chunked prompt-suffix prefill of BOTH pools for one slot
-            (the multi-query step at a fixed start IS a suffix prefill);
-            returns the target's last-position logits for token 0."""
-            tparams, dparams = params_pair
-            pos0 = jnp.full((1,), start, jnp.int32)
-            t_logits, t_k, t_v = multi_step_paged(
-                tparams, cfg, t_k, t_v, table_row, toks, pos0
-            )
-            _d, d_k, d_v = multi_step_paged(
-                dparams, dcfg, d_k, d_v, table_row, toks, pos0
-            )
-            return t_logits[:, -1], t_k, t_v, d_k, d_v
-
-        self._paged_suffix_fn = suffix_prefill
-
-        def admit_merge(last_tok, pos, gen, logits, admit_mask, key):
-            """Greedy token 0 from the target's logits — identical to the
-            dense spec admit's tail (speculative serving is greedy-only,
-            so the key goes unused past the shared signature)."""
-            tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            last_tok = jnp.where(admit_mask, tok0, last_tok)
-            pos = jnp.where(admit_mask, P, pos)
-            gen = jnp.where(admit_mask[:, None], 0, gen)
-            gen = gen.at[:, 0].set(jnp.where(admit_mask, tok0, gen[:, 0]))
-            return last_tok, pos, gen
-
-        self._paged_merge = jax.jit(admit_merge)
 
         K = self._ticks_per_sync
 
@@ -593,8 +559,7 @@ class SpecStreamingGenerator(StreamingGenerator):
             row — ``multi_step_paged`` with S=1 rows IS the chunk
             stage), then runs the K speculative rounds over the active
             slots. One dispatch per tick, O(1) compiled programs across
-            any suffix-length mix — the per-(suffix, start) jit zoo is
-            gone for spec serving too. Unlike the plain server's fused
+            any suffix-length mix. Unlike the plain server's fused
             pass the chunk stage is a separate layer sweep per model
             (the verify's multi-query structure doesn't concatenate with
             S=1 chunk rows); the dispatch-count win is identical, the
@@ -632,19 +597,16 @@ class SpecStreamingGenerator(StreamingGenerator):
         self._tick_fn = lambda *a: _tick(
             (self._params, self._draft_params), *a
         )
-        if self._chunked:
-            _tick_chunk = jax.jit(tick_chunk_block, donate_argnums=(1,))
-            self._tick_chunk_jit = _tick_chunk
-            self._tick_chunk_fn = lambda *a: _tick_chunk(
-                (self._params, self._draft_params), *a
-            )
-        else:
-            self._tick_chunk_fn = None
+        _tick_chunk = jax.jit(tick_chunk_block, donate_argnums=(1,))
+        self._tick_chunk_jit = _tick_chunk
+        self._tick_chunk_fn = lambda *a: _tick_chunk(
+            (self._params, self._draft_params), *a
+        )
         self._tick_block_raw = (
             lambda params, *a: tick_block((params, self._draft_params), *a)
         )
         self._admit_fn = None  # paged admission is host-orchestrated
-        self._resume_exec = None  # paged resume rides the chunk/suffix path
+        self._resume_exec = None  # paged resume rides the chunk path
         self._paged_table_idx = 4
 
         nl, kh, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
@@ -667,28 +629,6 @@ class SpecStreamingGenerator(StreamingGenerator):
         self._last_tok = jnp.zeros((B,), jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
         self._gen = jnp.zeros((B, max_new), jnp.int32)
-
-    def _paged_prefill_call(self, caches, table_row, toks, *,
-                            total_len: int | None = None):
-        """Both models' pools prefilled per record; counters/table pass
-        through untouched. ``total_len``: full sequence length — a
-        journal warm resume prefills prompt + emitted tokens (base-class
-        semantics)."""
-        s = int(toks.shape[1])
-        start = (total_len or self._prompt_len) - s
-        fn = self._paged_prefill_jits.get((s, start))
-        if fn is None:
-            fn = jax.jit(
-                functools.partial(self._paged_suffix_fn, start=start),
-                donate_argnums=(1, 2, 3, 4),
-            )
-            self._paged_prefill_jits[(s, start)] = fn
-        with xprof.span(xprof.SPAN_ADMIT):
-            logits, t_k, t_v, d_k, d_v = fn(
-                (self._params, self._draft_params), *caches[:4], table_row,
-                toks,
-            )
-        return logits, (t_k, t_v, d_k, d_v) + caches[4:]
 
     def swap_draft_params(self, draft_params, draft_cfg=None) -> None:
         """Hot-swap the DRAFT weights in place — the rollout plane's

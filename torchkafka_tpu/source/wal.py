@@ -6,7 +6,7 @@ transactions abort on epoch fences — but the broker those guarantees
 route through was fully volatile: kill the supervisor's
 ``InMemoryBroker`` and every topic, offset watermark, membership
 generation, and open transaction vanished, voiding the exactly-once
-contract FAILOVER_BENCH just asserted. This module is the durability
+contract the fleet's failover tests assert. This module is the durability
 substrate that closes that hole: an append-only event log the broker
 writes BEFORE acknowledging state changes and replays at construction
 (Kafka's own story — the log IS the broker; KIP-98 commit/abort markers

@@ -22,10 +22,10 @@ another):
     tk_serve:poll          run(): consumer.poll + note_fetched
     tk_serve:admit_prep    admit_records(): decode, journal hints, the
                            [slots, prompt] batch and its transfer, up to
-                           the dispatch (chunked paged admission
-                           dispatches nothing and is all preparation)
-    tk_serve:admit         admit_records() / _paged_prefill_call(): the
-                           prefill-admission dispatch (dense / legacy paged)
+                           the dispatch (paged admission dispatches
+                           nothing and is all preparation)
+    tk_serve:admit         admit_records(): the prefill-admission
+                           dispatch (dense)
     tk_serve:chunk_pack    step(): host packing of the fused tick's
                            prefill chunk
     tk_serve:tick          step(): the decode (or fused chunk) tick-block
@@ -61,10 +61,9 @@ called ``token.commit``):
     tk_commit:offsets      CommitToken.commit(): consumer.commit(offsets)
 
 The Pallas kernels carry fixed names too (``pl.pallas_call(name=...)`` in
-``ops/``): ``tk_kvattn_kmajor``, ``tk_kvattn``, ``tk_kvattn_dynlen``,
-``tk_kvattn_paged``, ``tk_flash_fwd``, ``tk_flash_bwd_dq``,
-``tk_flash_bwd_dkv``, ``tk_qmatmul`` — the device trace names each
-kernel's operation after them.
+``ops/``): ``tk_kvattn_dynlen``, ``tk_kvattn_paged``, ``tk_flash_fwd``,
+``tk_flash_bwd_dq``, ``tk_flash_bwd_dkv``, ``tk_qmatmul`` — the device
+trace names each kernel's operation after them.
 
 Record-level lifecycle tracing (who waited where, per record) is the
 separate ``torchkafka_tpu.obs`` subsystem; these annotations are the
