@@ -9,7 +9,8 @@ from a seed, and checks what comes out:
   gate     JAX's first device must be a TPU. No chip, no run — never a CPU
            (and Pallas-interpreter) run under a device's name.
   kernels  the compiled Pallas reads and flash attention against their XLA
-           references on a small input at the served head shapes.
+           references on a small input at the served head shapes; the
+           dense read's writing form against scatter-then-read, bit for bit.
   serve    prompt topic -> StreamingGenerator -> per-completion commits on
            the 8b zoo model (Llama-3-8B widths, all 32 layers, int8
            weights), then two short servers at a 1024-token pool so that
@@ -36,6 +37,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import importlib.metadata
 import json
@@ -259,6 +261,38 @@ def check_kernels(sz: Sizes) -> dict:
         np.array_equal(np.asarray(dyn_layer), np.asarray(dyn)),
         "dynlen_read: layer 1 of the stacked pool differs from its slab",
     )
+    # The WRITING form, as the serving tick calls it: this tick's rows go
+    # into layer 1 of the stacked pool inside the call. Held bit for bit
+    # to "scatter the rows, then read": the pools, and the attention too
+    # (the row is merged into the fetched tile, so the sums are the same).
+    stacked = [jnp.stack([jnp.roll(v, 1, axis=0), v]) for v in dense]
+    # A generator of their own: the flash check below keeps its draws.
+    row_rng = np.random.default_rng(1)
+    fresh = tuple(
+        a for _ in "kv" for a in quant_kv_groups(
+            jnp.asarray(row_rng.normal(size=(b, n_kv, dh)), jnp.float32)
+        )
+    )
+
+    @jax.jit
+    def scatter_then_read(q, pool, fresh, pos):
+        at = (1, jnp.arange(b)[:, None], jnp.arange(n_kv)[None, :], pos[:, None])
+        pool = [c.at[at].set(r) for c, r in zip(pool, fresh)]
+        return int8_decode_attention_dynlen(q, *pool, pos, layer=1), *pool
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def write_in_read(q, pool, fresh, pos):
+        return int8_decode_attention_dynlen(
+            q, *pool, pos, layer=jnp.int32(1), rows=fresh
+        )
+
+    want = scatter_then_read(q, stacked, fresh, pos)
+    got = write_in_read(q, [jnp.copy(c) for c in stacked], fresh, pos)
+    for name, g, w in zip(("attn", "kq", "ks", "vq", "vs"), got, want):
+        _require(
+            np.array_equal(np.asarray(g), np.asarray(w)),
+            f"dynlen_write: {name} differs from scatter-then-read",
+        )
 
     # Flash forward and backward (GQA, causal) against the dense XLA body.
     seq = sz.train_seq

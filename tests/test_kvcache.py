@@ -749,6 +749,7 @@ class TestShardedPagedServing:
         assert kb["layout"] == "paged" and kb["kv_dtype"] == "int8"
         assert kb["kernel_engaged"] == 1 and kb["kernel_disabled"] == {}
         assert kb["data"] == 2 and kb["tp"] == 2
+        assert kb["row_write"] == "scatter"  # the paged tick's own scatters
         assert sg.metrics.cache_summary()["hits"] > 0  # radix still works
 
     @pytest.mark.slow
@@ -935,8 +936,14 @@ class TestBackendCapabilityErrors:
         kb = s.metrics.summary()["kv_backend"]
         assert kb["kernel_engaged"] == 0
         assert any("auto" in r for r in kb["kernel_disabled"])
+        assert kb["row_write"] == "scatter"
         text = s.metrics.render_prometheus()
         assert "torchkafka_serve_kv_backend_info{" in text
+        info = next(
+            ln for ln in text.splitlines()
+            if ln.startswith("torchkafka_serve_kv_backend_info{")
+        )
+        assert 'row_write="scatter"' in info
         assert "torchkafka_serve_kv_kernel_engaged 0" in text
         assert 'torchkafka_serve_kv_kernel_disabled_total{reason="' in text
 
@@ -952,6 +959,27 @@ class TestBackendCapabilityErrors:
         assert bk.paged and bk.int8 and bk.kernel and bk.sharded
         d = bk.describe()
         assert d["layout"] == "paged" and d["data"] == 2 and d["tp"] == 2
+        assert d["row_write"] == "scatter"
+
+    @pytest.mark.parametrize("kv_dtype,kv_kernel,row_write", [
+        ("int8", True, "kernel"), ("int8", False, "scatter"),
+        (None, "auto", "scatter"),
+    ])
+    def test_row_write_follows_the_dense_kernel(self, kv_dtype, kv_kernel,
+                                                row_write):
+        """Who writes a tick's new rows: the dense int8 pool's Pallas read
+        where it engages (``serve._slot_layer_step_q``'s ``use_kernel``
+        branch is this same flag), XLA's scatters everywhere else."""
+        from torchkafka_tpu.kvcache import resolve_kv_backend
+        from torchkafka_tpu.models import TransformerConfig
+
+        cfg = TransformerConfig(d_model=256, n_heads=2, n_kv_heads=2)
+        bk = resolve_kv_backend(
+            cfg, kv_dtype=kv_dtype, kv_kernel=kv_kernel, kv_pages=None,
+            max_len=32, slots=2, backend="cpu",
+        )
+        assert bk.kernel is (row_write == "kernel")
+        assert bk.row_write == bk.describe()["row_write"] == row_write
 
     def test_paged_kernel_gate_is_lane_alignment_on_tpu(self):
         """What compiled Mosaic accepted on the v5e (PR 21): the block

@@ -451,6 +451,149 @@ class TestInt8DecodeAttentionKernel:
             )(jnp.int32(layer))
             np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
+    # The WRITING form (``rows=``): the tick's four cache scatters inside
+    # the read. Geometries (M, block): the row group the kernel sends home
+    # is 32 positions of payload and 128 lanes of scales, or the whole
+    # block where the block is smaller.
+    _WRITE_GEOMETRIES = {
+        "block-8": (32, 8),       # groups = the block
+        "block-64": (128, 64),    # 32-row payload groups, scales whole
+        "block-128": (256, 128),  # 32-row and 128-lane groups, as compiled
+    }
+    _WRITE_POSITIONS = {
+        # name: (M, mb) -> one watermark a slot
+        "zero": lambda M, mb: [0, 0, 0, 0],
+        "inside-block-0": lambda M, mb: [1, mb // 2, mb - 2, 3],
+        "block-edges": lambda M, mb: [mb - 1, mb, mb - 1, mb],
+        "multiples-of-32": lambda M, mb: [
+            min(32, M - 8), min(32, M - 8) - 1, min(96, M - 8), min(96, M - 8) - 1
+        ],
+        "last-position": lambda M, mb: [M - 1, M - 1, 0, M - 2],
+        "equal": lambda M, mb: [mb + 5] * 4,
+        "different": lambda M, mb: [3, mb + 6, M - 1, mb],
+    }
+
+    @staticmethod
+    def _fresh_rows(B=4, K=2, Dh=16, seed=9):
+        from torchkafka_tpu.serve import _quant_kv
+
+        rng = np.random.default_rng(seed)
+        k, v = jnp.asarray(rng.normal(size=(2, B, K, Dh)) * 3, jnp.float32)
+        return (*_quant_kv(k), *_quant_kv(v))
+
+    @staticmethod
+    def _scatter(pool, rows, pos, layer):
+        """The tick's four scatters as XLA ran them before PR 30."""
+        at = (
+            layer, jnp.arange(pos.shape[0])[:, None],
+            jnp.arange(pool[0].shape[2])[None, :], pos[:, None],
+        )
+        return tuple(c.at[at].set(r) for c, r in zip(pool, rows))
+
+    @pytest.mark.parametrize("case", list(_WRITE_POSITIONS))
+    @pytest.mark.parametrize("geometry", list(_WRITE_GEOMETRIES))
+    def test_dynlen_write_equals_scatter_then_read(self, geometry, case):
+        """``rows=`` against "scatter the rows, then the read-only form",
+        at a non-zero layer of a stacked pool: the four pools bit for bit
+        (so every other layer and position is untouched) and the attention
+        bit for bit too, since the row is merged into the fetched tile.
+        Watermarks inside block 0 are the stale-prefetch case: that block
+        was fetched by the slot before, ahead of the write."""
+        from torchkafka_tpu.ops.kvattn import int8_decode_attention_dynlen
+
+        M, mb = self._WRITE_GEOMETRIES[geometry]
+        q, pool, _ = self._stacked_pool(M=M)
+        rows = self._fresh_rows()
+        pos = jnp.asarray(self._WRITE_POSITIONS[case](M, mb), jnp.int32)
+        layer = 1
+        want_pool = self._scatter(pool, rows, pos, layer)
+        want = int8_decode_attention_dynlen(
+            q, *want_pool, pos, layer=layer, block=mb, interpret=True
+        )
+        got, *got_pool = jax.jit(
+            lambda l: int8_decode_attention_dynlen(
+                q, *pool, pos, layer=l, rows=rows, block=mb, interpret=True
+            )
+        )(jnp.int32(layer))
+        for name, g, w, old in zip(
+            ("kq", "ks", "vq", "vs"), got_pool, want_pool, pool
+        ):
+            np.testing.assert_array_equal(
+                np.asarray(g), np.asarray(w), err_msg=name
+            )
+            # Spelled out: B x K rows of layer 1 moved, and nothing else.
+            moved = np.asarray(g) != np.asarray(old)
+            assert not moved[[0, 2]].any(), name
+            at = np.zeros(moved.shape[1:4], bool)  # [B, K, M]
+            at[np.arange(4), :, np.asarray(pos)] = True
+            assert not moved[1][~at].any(), name
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_dynlen_write_into_a_slab_and_the_clamp(self):
+        """Without ``layer`` the write goes into one layer's 4-D slab; a
+        watermark past the pool is clamped to its last position (a DMA is
+        unchecked; the tick's latch never sends one)."""
+        from torchkafka_tpu.ops.kvattn import int8_decode_attention_dynlen
+
+        q, pool, _ = self._stacked_pool()
+        slab = tuple(c[2] for c in pool)
+        rows = self._fresh_rows()
+        M = slab[0].shape[2]
+        inside = jnp.asarray([M - 1, 4, M - 1, 9], jnp.int32)
+        past = jnp.asarray([M + 5, 4, M, 9], jnp.int32)
+        at = (jnp.arange(4)[:, None], jnp.arange(2)[None, :], inside[:, None])
+        want_pool = tuple(c.at[at].set(r) for c, r in zip(slab, rows))
+        want = int8_decode_attention_dynlen(
+            q, *want_pool, inside, block=8, interpret=True
+        )
+        for pos in (inside, past):
+            got, *got_pool = int8_decode_attention_dynlen(
+                q, *slab, pos, rows=rows, block=8, interpret=True
+            )
+            for g, w in zip(got_pool, want_pool):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize(
+        "axes", [{"data": 2, "tp": 2, "fsdp": 2}, {"data": 4, "tp": 2}]
+    )
+    def test_dynlen_sharded_write(self, axes):
+        """Under a mesh each (data, tp) shard writes its own slots' and
+        heads' rows: pools and attention equal to the unsharded write,
+        the pools back under the shardings they came in with."""
+        from torchkafka_tpu.models.generate import (
+            kv_kmajor_scale_sharding, kv_kmajor_sharding,
+        )
+        from torchkafka_tpu.ops.kvattn import (
+            int8_decode_attention_dynlen,
+            int8_decode_attention_dynlen_sharded,
+        )
+
+        mesh = make_mesh(axes)
+        q, pool, pos = self._stacked_pool()
+        rows = self._fresh_rows()
+        placed = tuple(
+            jax.device_put(
+                c, kv_kmajor_sharding(mesh) if c.ndim == 5
+                else kv_kmajor_scale_sharding(mesh)
+            )
+            for c in pool
+        )
+        want = int8_decode_attention_dynlen(
+            q, *self._scatter(pool, rows, pos, 2), pos, layer=2, block=8,
+            interpret=True,
+        )
+        got, *got_pool = jax.jit(
+            lambda l: int8_decode_attention_dynlen_sharded(
+                q, *placed, pos, mesh, layer=l, rows=rows, block=8,
+                interpret=True,
+            )
+        )(jnp.int32(2))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for g, w, p in zip(got_pool, self._scatter(pool, rows, pos, 2), placed):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            assert g.sharding.is_equivalent_to(p.sharding, g.ndim)
+
     def test_paged_kernel_matches_gathered_read(self):
         """The block-table read (the dyn-len kernel's watermark-DMA
         structure through per-slot block tables) against the XLA gathered

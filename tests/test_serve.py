@@ -1131,6 +1131,37 @@ def test_layer_scan_carries_the_pool(variant):
     consumer.close()
 
 
+@pytest.mark.parametrize("variant,scatters,kernel_outputs", [
+    ("int8", 4, None), ("int8-kernel", 0, 5), ("int8-kernel-mesh", 0, 5),
+])
+def test_kernel_tick_writes_its_rows_in_the_read(variant, scatters,
+                                                 kernel_outputs):
+    """Structure: the kernel-mode tick holds no ``scatter`` at all: its one
+    Pallas call returns the attention AND the four pool tensors, aliased
+    to the four it was given (PR 30). The XLA-mode tick keeps its four
+    scatters (one a pool tensor), and the metrics say which way serves."""
+    srv, consumer = _tick_server(variant)
+    jaxpr = jax.make_jaxpr(srv._tick_block_raw)(
+        srv._params, srv._caches, srv._last_tok, srv._pos, srv._gen,
+        jnp.ones((4,), bool), srv._slot_keys,
+    )
+    eqns = list(_eqns(jaxpr.jaxpr))
+    assert sum(e.primitive.name.startswith("scatter") for e in eqns) == scatters
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == (0 if kernel_outputs is None else 1)
+    for call in calls:
+        assert len(call.outvars) == kernel_outputs
+        aliases = dict(call.params["input_output_aliases"])
+        assert sorted(aliases.values()) == [1, 2, 3, 4]
+        for i, o in aliases.items():
+            assert call.invars[i].aval.shape == call.outvars[o].aval.shape
+            assert call.invars[i].aval.dtype == call.outvars[o].aval.dtype
+    row_write = srv.metrics.summary()["kv_backend"]["row_write"]
+    assert row_write == ("scatter" if kernel_outputs is None else "kernel")
+    srv.close()
+    consumer.close()
+
+
 @pytest.mark.parametrize("variant", list(_TICK_VARIANTS))
 def test_carried_tick_equals_xs_ys_reference(variant):
     """Identity: one block of ticks from a ragged state (slots at different

@@ -65,7 +65,11 @@ class KVBackend:
     ``kernel_disabled_reason``: why it does NOT engage (None when it
     does, or when int8 was never requested — there is no kernel
     without an int8 pool). ``data``/``tp``: mesh axis extents (1 =
-    unsharded axis; both 1 = single device)."""
+    unsharded axis; both 1 = single device). ``row_write`` (derived):
+    who writes a decode tick's new rows into the pool — "kernel" where
+    the dense int8 pool's Pallas read does it itself
+    (``ops.kvattn.int8_decode_attention_dynlen`` with ``rows=``),
+    "scatter" for XLA's scatters on every other path."""
 
     layout: str
     int8: bool
@@ -83,12 +87,19 @@ class KVBackend:
     def sharded(self) -> bool:
         return self.data > 1 or self.tp > 1
 
+    @property
+    def row_write(self) -> str:
+        # The flag serve.py's dense int8 tick branches on (``use_kernel``).
+        wrote_in_read = self.layout == "dense" and self.int8 and self.kernel
+        return "kernel" if wrote_in_read else "scatter"
+
     def describe(self) -> dict:
         """The ``ServeMetrics`` ``kv_backend`` info payload."""
         return {
             "layout": self.layout,
             "kv_dtype": "int8" if self.int8 else "compute",
             "kernel": self.kernel,
+            "row_write": self.row_write,
             "kernel_disabled_reason": self.kernel_disabled_reason,
             "chunked": self.chunked,
             "data": self.data,

@@ -6,6 +6,7 @@ Two kernels, one for each pool layout a ``StreamingGenerator`` builds:
 - ``int8_decode_attention_dynlen`` (``tk_kvattn_dynlen``) reads the DENSE
   slot pool, payloads [B, K, M, Dh] int8 with scales [B, K, M] f32 (or the
   stacked pool [L, B, ...] with ``layer=``), up to each slot's watermark.
+  With ``rows=`` it is the decode tick's WRITE too (below).
 - ``int8_paged_decode_attention`` (``tk_kvattn_paged``) reads the PAGED
   pool, blocks [NB, K, bs, Dh] with scales [NB, K, bs], through per-slot
   block tables, up to each slot's watermark.
@@ -38,6 +39,27 @@ Why the layout and the structure:
   and scratch persists across programs, so program i starts the DMA of
   program i+1's first block during its own last block's compute; without
   it every slot opens with a DMA stall.
+
+- **The dense kernel owns the tick's row write** (``rows=``; PR 30). A
+  decode tick puts one new position into each slot: 8 heads x 132 bytes.
+  As four XLA scatters into the K-major pool that is 384 separately
+  indexed updates each, run one after another, and cost more than this
+  read. The kernel takes the freshly quantised rows beside the query and
+  returns the four pool tensors ALIASED to the four it was given
+  (``input_output_aliases``; the operands stay in ``memory_space=ANY``, so
+  nothing is copied and the pool stays the carry of the tick's loops).
+  Inside, the row is MERGED INTO THE FETCHED TILE in VMEM before the dots:
+  column ``pos`` always lies in the slot's last block, and HBM cannot
+  serve it, since slot b's first block is fetched by program b - 1,
+  before program b writes anything. What goes back to HBM is the tile's
+  aligned group round the row — 32 positions of payload (int8 packs four
+  positions a word, (32, 128) a tile: one position is not a DMA's to
+  address) and 128 lanes of scales — from a staging scratch of its own,
+  waited one program later, so the tile's buffer is free for the next
+  prefetch at once. Every other byte of the group is what was fetched, so
+  the pool is bit for bit the scatters'. A DMA has no bounds check where
+  a scatter drops: ``pos <= M - 1`` is the caller's (the tick's latch
+  holds it) and the kernel clamps.
 
 Numbers (time a call, roofline share): PERF.md §5.
 """
@@ -221,15 +243,31 @@ def block_table_attention(
 
 
 def _kvattn_dynlen_kernel(
-    pos_ref, base_ref, q_ref, kq_hbm, ks_hbm, vq_hbm, vs_hbm, o_ref,
-    kt, st, vt, wt, sems, *, mb: int, inv_sqrt_dh: float,
+    pos_ref, base_ref, q_ref, *refs, mb: int, max_len: int,
+    inv_sqrt_dh: float, rg: int, lg: int,
 ):
+    # ``rg``/``lg`` > 0: the WRITING form, whose refs carry this tick's rows
+    # ([1, K, 1, Dh] int8, [1, K, 1] f32), the pool a second time as the
+    # aliased outputs, the staging scratch and its semaphores.
+    write = rg > 0
+    if write:
+        (nkq_ref, nks_ref, nvq_ref, nvs_ref, kq_hbm, ks_hbm, vq_hbm, vs_hbm,
+         o_ref, kq_out, ks_out, vq_out, vs_out, kt, st, vt, wt, sems,
+         gk, gks, gv, gvs, wsems) = refs
+    else:
+        kq_hbm, ks_hbm, vq_hbm, vs_hbm, o_ref, kt, st, vt, wt, sems = refs
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     # Slot b's rows lie at ``base + b`` of the pool operands: 0 for one
     # layer's slab, ``layer * B`` for the stacked pool taken whole.
     base = base_ref[0]
-    pos = pos_ref[b]
+
+    # A DMA is unchecked where XLA's gather and scatter clamp or drop: the
+    # watermark is held inside the pool here, for the read and the write.
+    def pos_of(t):
+        return jnp.minimum(pos_ref[t], max_len - 1)
+
+    pos = pos_of(b)
     n_blocks = (pos + mb) // mb  # ceil((pos + 1) / mb), pos >= 0
     q = q_ref[0]  # [K, rep, Dh] compute dtype
     n_kv, rep, dh = q.shape
@@ -243,7 +281,7 @@ def _kvattn_dynlen_kernel(
     # per-program: block (slot, j) uses parity (prefix_blocks(slot) + j)
     # % 2, computable by any program from the prefetched watermarks.
     def blocks_of(t):
-        return (pos_ref[t] + mb) // mb
+        return (pos_of(t) + mb) // mb
 
     parity0 = jax.lax.fori_loop(
         0, b, lambda t, acc: acc + blocks_of(t), jnp.int32(0)
@@ -270,6 +308,58 @@ def _kvattn_dynlen_kernel(
             ),
         )
 
+    def row_writes(r0, c0):  # the staged groups into slot b's pool row
+        row = base + b
+        return (
+            pltpu.make_async_copy(
+                gk, kq_out.at[row, :, pl.ds(r0, rg), :], wsems.at[0],
+            ),
+            pltpu.make_async_copy(
+                gks, ks_out.at[row, :, pl.ds(c0, lg)], wsems.at[1],
+            ),
+            pltpu.make_async_copy(
+                gv, vq_out.at[row, :, pl.ds(r0, rg), :], wsems.at[2],
+            ),
+            pltpu.make_async_copy(
+                gvs, vs_out.at[row, :, pl.ds(c0, lg)], wsems.at[3],
+            ),
+        )
+
+    def write_row(slot):
+        """Merge this tick's row into the fetched LAST tile (column
+        ``pos`` always lies there) and send its aligned group home.
+
+        The merge is in VMEM because HBM cannot serve it: slot b's block
+        0 was fetched by program b - 1, before anything this program
+        writes. The group (``rg`` payload rows: one packed int8 tile; ``lg``
+        scale lanes) goes out from a staging scratch of its own, so the
+        tile buffer is free for the next prefetch at once; the staging
+        scratch is waited where it is next filled, one program later (the
+        last program waits its own at the end)."""
+        off = pos - (n_blocks - 1) * mb
+        r0 = pl.multiple_of(off // rg * rg, rg)
+        c0 = pl.multiple_of(off // lg * lg, lg)
+
+        @pl.when(b > 0)
+        def _():
+            for d in row_writes(0, 0):
+                d.wait()
+
+        hit = jax.lax.broadcasted_iota(jnp.int32, (n_kv, rg, dh), 1) == off - r0
+        for tile, stage, new in ((kt, gk, nkq_ref), (vt, gv, nvq_ref)):
+            old = tile[slot, :, pl.ds(r0, rg), :]
+            grp = jnp.where(hit, new[0], old)  # new[0]: [K, 1, Dh]
+            tile[slot, :, pl.ds(r0, rg), :] = grp
+            stage[...] = grp
+        hit = jax.lax.broadcasted_iota(jnp.int32, (n_kv, lg), 1) == off - c0
+        for tile, stage, new in ((st, gks, nks_ref), (wt, gvs, nvs_ref)):
+            old = tile[slot, :, pl.ds(c0, lg)]
+            grp = jnp.where(hit, new[0], old)  # new[0]: [K, 1]
+            tile[slot, :, pl.ds(c0, lg)] = grp
+            stage[...] = grp
+        for d in row_writes((n_blocks - 1) * mb + r0, (n_blocks - 1) * mb + c0):
+            d.start()
+
     @pl.when(b == 0)
     def _():  # no predecessor: start our own first block
         for d in dmas(parity0 % 2, b, 0):
@@ -295,6 +385,8 @@ def _kvattn_dynlen_kernel(
 
         for d in dmas(slot, b, j):
             d.wait()
+        if write:
+            pl.when(j + 1 == n_blocks)(lambda: write_row(slot))
         kk = kt[slot].astype(q.dtype)  # [K, mb, Dh]
         s = jax.lax.dot_general(
             q, kk, (((2,), (2,)), ((0,), (0,))),
@@ -317,6 +409,11 @@ def _kvattn_dynlen_kernel(
 
     m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
     o_ref[0] = (acc / l[..., None]).astype(o_ref.dtype)
+    if write:
+        @pl.when(b + 1 == nb)
+        def _():
+            for d in row_writes(0, 0):
+                d.wait()
 
 
 def dynlen_block(max_len: int) -> int:
@@ -338,9 +435,10 @@ def int8_decode_attention_dynlen(
     pos: jax.Array,
     *,
     layer: jax.Array | int | None = None,
+    rows: tuple[jax.Array, jax.Array, jax.Array, jax.Array] | None = None,
     block: int | None = None,
     interpret: bool | None = None,
-) -> jax.Array:
+):
     """q [B, 1, H, Dh] against a K-MAJOR int8 cache ck_q/cv_q
     [B, K, M, Dh] with scales [B, K, M] (f32), reading ONLY positions
     [0, pos[b]] per slot (pos: [B] int32 watermarks) → attn
@@ -354,6 +452,16 @@ def int8_decode_attention_dynlen(
     slab. The kernel sees the pool with L and B merged (a bitcast) and
     DMAs from row ``layer * B + b``.
 
+    With ``rows`` = (kq, ks, vq, vs), this tick's quantised rows (kq/vq
+    [B, K, Dh] int8, ks/vs [B, K] f32), the call is the tick's WRITE as
+    well: it puts slot b's row at position ``pos[b]`` of the pool (of
+    layer ``layer``), attends over [0, pos[b]] with that row in place,
+    and returns (attn, ck_q, ck_s, cv_q, cv_s), the pools aliased to the
+    ones passed in — bit for bit what
+    ``c.at[layer, b, :, pos[b]].set(row)`` on each, then the read, gives.
+    ``pos`` must lie inside the pool (the kernel clamps it to M - 1: a
+    DMA has no bounds check, where a scatter drops what is out of range).
+
     Exact w.r.t. the scale-folded read restricted to valid positions
     (flash-style online softmax; differential-tested against
     ``_attend_cached`` with ``valid = arange(M) <= pos[:, None]``).
@@ -361,6 +469,8 @@ def int8_decode_attention_dynlen(
     b, s, h, dh = q.shape
     if s != 1:
         raise ValueError(f"decode attention is one token per slot, got S={s}")
+    pool = (ck_q, ck_s.astype(jnp.float32), cv_q, cv_s.astype(jnp.float32))
+    shapes = [c.shape for c in pool]
     if layer is None:
         base = jnp.zeros((1,), jnp.int32)
     else:
@@ -370,10 +480,8 @@ def int8_decode_attention_dynlen(
                 f"{ck_q.shape}"
             )
         base = (jnp.asarray(layer, jnp.int32) * b).reshape(1)
-        ck_q, ck_s, cv_q, cv_s = (
-            c.reshape(-1, *c.shape[2:]) for c in (ck_q, ck_s, cv_q, cv_s)
-        )
-    n_kv, m = ck_q.shape[1], ck_q.shape[2]
+        pool = tuple(c.reshape(-1, *c.shape[2:]) for c in pool)
+    n_kv, m = pool[0].shape[1:3]
     rep = h // n_kv
     mb = block or dynlen_block(m)
     if not mb or m % mb:
@@ -385,40 +493,73 @@ def int8_decode_attention_dynlen(
     # relies on program i+1's first block being DMA'd by program i, so
     # the order must be the textual one.
     kw = {} if interpret else tpu_compiler_params(("arbitrary",))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    q_spec = pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos, base: (i, 0, 0, 0))
+    in_specs = [q_spec]
+    operands = [qg]
+    out_specs = q_spec
+    out_shape = jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype)
+    scratch = [
+        pltpu.VMEM((2, n_kv, mb, dh), jnp.int8),   # k tiles
+        pltpu.VMEM((2, n_kv, mb), jnp.float32),    # k scales
+        pltpu.VMEM((2, n_kv, mb, dh), jnp.int8),   # v tiles
+        pltpu.VMEM((2, n_kv, mb), jnp.float32),    # v scales
+        pltpu.SemaphoreType.DMA((2, 4)),
+    ]
+    rg = lg = 0
+    if rows is not None:
+        kq, ks, vq, vs = rows
+        # What goes back to HBM is the aligned group round the row: 32
+        # positions of payload (one packed int8 tile: a single position is
+        # not a DMA's to address) and 128 lanes of scales, or the whole
+        # block where it is smaller than those (interpret-mode sizes).
+        rg = 32 if mb % 32 == 0 else mb
+        lg = 128 if mb % 128 == 0 else mb
+        in_specs += [
+            pl.BlockSpec((1, n_kv, 1, dh), lambda i, pos, base: (i, 0, 0, 0)),
+            pl.BlockSpec((1, n_kv, 1), lambda i, pos, base: (i, 0, 0)),
+        ] * 2
+        operands += [
+            kq.astype(jnp.int8)[:, :, None, :], ks.astype(jnp.float32)[..., None],
+            vq.astype(jnp.int8)[:, :, None, :], vs.astype(jnp.float32)[..., None],
+        ]
+        out_specs = [q_spec] + [any_spec] * 4
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct(c.shape, c.dtype) for c in pool
+        ]
+        scratch += [
+            pltpu.VMEM((n_kv, rg, dh), jnp.int8),      # staged k group
+            pltpu.VMEM((n_kv, lg), jnp.float32),       # staged k scales
+            pltpu.VMEM((n_kv, rg, dh), jnp.int8),      # staged v group
+            pltpu.VMEM((n_kv, lg), jnp.float32),       # staged v scales
+            pltpu.SemaphoreType.DMA((4,)),
+        ]
+        # Operand numbers count the two scalar-prefetch arguments: the
+        # pool is operands 7..10 and outputs 1..4.
+        kw["input_output_aliases"] = {7 + i: 1 + i for i in range(4)}
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos, base: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, n_kv, rep, dh), lambda i, pos, base: (i, 0, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((2, n_kv, mb, dh), jnp.int8),   # k tiles
-            pltpu.VMEM((2, n_kv, mb), jnp.float32),    # k scales
-            pltpu.VMEM((2, n_kv, mb, dh), jnp.int8),   # v tiles
-            pltpu.VMEM((2, n_kv, mb), jnp.float32),    # v scales
-            pltpu.SemaphoreType.DMA((2, 4)),
-        ],
+        in_specs=in_specs + [any_spec] * 4,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(
-            _kvattn_dynlen_kernel, mb=mb,
-            inv_sqrt_dh=float(1.0 / np.sqrt(dh)),
+            _kvattn_dynlen_kernel, mb=mb, max_len=m,
+            inv_sqrt_dh=float(1.0 / np.sqrt(dh)), rg=rg, lg=lg,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
         name="tk_kvattn_dynlen",
         **kw,
-    )(pos.astype(jnp.int32), base, qg, ck_q, ck_s.astype(jnp.float32), cv_q,
-      cv_s.astype(jnp.float32))
-    return out.reshape(b, 1, h, dh)
+    )(pos.astype(jnp.int32), base, *operands, *pool)
+    if rows is None:
+        return out.reshape(b, 1, h, dh)
+    attn, *pool = out
+    return (attn.reshape(b, 1, h, dh),
+            *(c.reshape(sh) for c, sh in zip(pool, shapes)))
 
 
 # The two sharded wrappers below are manual over EVERY mesh axis: compiled
@@ -440,9 +581,10 @@ def int8_decode_attention_dynlen_sharded(
     mesh,
     *,
     layer: jax.Array | int,
+    rows: tuple[jax.Array, jax.Array, jax.Array, jax.Array] | None = None,
     block: int | None = None,
     interpret: bool | None = None,
-) -> jax.Array:
+):
     """``int8_decode_attention_dynlen`` of layer ``layer`` of the STACKED
     pool ([L, B, K, M, Dh] / [L, B, K, M]) under a serving mesh.
 
@@ -454,7 +596,9 @@ def int8_decode_attention_dynlen_sharded(
     ``tp``. Requirements (the capability probe gates on these): B
     divisible by data, H and K by tp. The pool enters the region 5-D and
     L merges with the SHARD's slots inside it: an unsharded L cannot
-    merge with a ``data``-sharded B outside."""
+    merge with a ``data``-sharded B outside. With ``rows`` each shard
+    writes its own slots' and heads' rows, and the pools come back under
+    the specs they came in with."""
     from jax.sharding import PartitionSpec as P
 
     bspec = "data" if "data" in mesh.shape else None
@@ -462,21 +606,28 @@ def int8_decode_attention_dynlen_sharded(
     qspec = P(bspec, None, tp, None)         # [B, 1, H, Dh]
     cspec = P(None, bspec, tp, None, None)   # [L, B, K, M, Dh] payloads
     sspec = P(None, bspec, tp, None)         # [L, B, K, M] scales
+    pool_specs = (cspec, sspec, cspec, sspec)
+    in_specs = (qspec, *pool_specs, P(bspec), P())
+    args = (q, ck_q, ck_s, cv_q, cv_s, pos, jnp.asarray(layer, jnp.int32))
+    out_specs = qspec
+    if rows is not None:
+        rspec = P(bspec, tp, None)           # [B, K, Dh] fresh payloads
+        rsspec = P(bspec, tp)                # [B, K] fresh scales
+        in_specs += (rspec, rsspec, rspec, rsspec)
+        args += tuple(rows)
+        out_specs = (qspec, *pool_specs)
 
-    def read(q, ck_q, ck_s, cv_q, cv_s, pos, layer):
+    def read(q, ck_q, ck_s, cv_q, cv_s, pos, layer, *rows):
         return int8_decode_attention_dynlen(
-            q, ck_q, ck_s, cv_q, cv_s, pos, layer=layer, block=block,
-            interpret=interpret,
+            q, ck_q, ck_s, cv_q, cv_s, pos, layer=layer, rows=rows or None,
+            block=block, interpret=interpret,
         )
 
     fn = jax.shard_map(
-        read,
-        mesh=mesh,
-        in_specs=(qspec, cspec, sspec, cspec, sspec, P(bspec), P()),
-        out_specs=qspec,
+        read, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
-    return fn(q, ck_q, ck_s, cv_q, cv_s, pos, jnp.asarray(layer, jnp.int32))
+    return fn(*args)
 
 
 def int8_paged_decode_attention_sharded(

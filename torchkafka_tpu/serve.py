@@ -190,55 +190,50 @@ def _slot_layer_step_q(
     k = _rope(k, pos_b[:, None], cfg.rope_theta)
     kq, ks = _quant_kv(k[:, 0])  # [B, K, Dh] int8, [B, K]
     vq, vs = _quant_kv(v[:, 0])
-    rows = jnp.arange(ck_q.shape[1])
     if use_kernel:
-        # K-MAJOR pool ([L, B, K, M, Dh] / [L, B, K, M]): each head's
-        # [M, Dh] tile is a contiguous slice, which is what lets the
-        # kernel batch its dots over (slot, head) with no relayout
-        # (ops/kvattn.py docstring). Writes are scatters like the bf16
-        # path (see _slot_layer_step's note): per-(row, head) at
-        # [l, b, :, pos_b[b]].
-        kidx = jnp.arange(ck_q.shape[2])[None, :]
-
-        def upd(c, row):  # payload [L, B, K, M, Dh] and scale [L, B, K, M] alike
-            return c.at[l, rows[:, None], kidx, pos_b[:, None]].set(row)
-    else:
-        def upd(c, row):  # payload [L, B, M, K, Dh] and scale [L, B, M, K] alike
-            return c.at[l, rows, pos_b].set(row)
-    ck_q = upd(ck_q, kq)
-    ck_s = upd(ck_s, ks)
-    cv_q = upd(cv_q, vq)
-    cv_s = upd(cv_s, vs)
-    if use_kernel:
-        # Pallas DYNAMIC-LENGTH int8 decode attention (ops/kvattn.py):
-        # per-slot watermarks are scalar-prefetched and the kernel
-        # manually DMAs M-blocks with cross-program double buffering, so
-        # HBM traffic scales with each slot's ACTUAL fill instead of the
-        # pool size — inexpressible in XLA, where every read is
-        # pool-shaped. It takes the pool WHOLE with the layer's index (a
-        # second scalar-prefetch argument that offsets the DMA's source
-        # row): a Pallas operand is opaque to XLA, so handing it
-        # ``pool[l]`` would materialise the layer's slab every layer.
-        # Caller gates on tiling shapes (a Pallas call is opaque to
-        # GSPMD, the flash_attention_sharded lesson — under a mesh the
-        # read runs per (data, tp) shard inside shard_map, each shard
-        # over its own slots and kv heads; the capability probe gated
-        # the divisibilities).
+        # Pallas DYNAMIC-LENGTH int8 decode attention (ops/kvattn.py; its
+        # docstring has the why of each point), which is this layer's
+        # WRITE as well as its read. The pool is K-MAJOR ([L, B, K, M, Dh]
+        # / [L, B, K, M]) so that the kernel batches its dots over (slot,
+        # head); per-slot watermarks are scalar-prefetched and the kernel
+        # DMAs M-blocks itself, so HBM traffic follows each slot's ACTUAL
+        # fill. It takes the pool WHOLE with the layer's index (``pool[l]``
+        # outside an opaque call would materialise the slab every layer)
+        # and returns it ALIASED to what came in, with this tick's rows at
+        # [l, b, :, pos_b[b]]: merged into the tile the kernel fetched,
+        # whose aligned group it writes back. As four XLA scatters, one
+        # update after another, that write cost more than the read
+        # (PERF.md, PR 30). Every slot writes, active or not
+        # (``tick_block``'s note on stale rows). A DMA has no bounds check
+        # where a scatter drops: the tick's latch holds
+        # pos_b <= P + max_new - 2 < M, and the kernel clamps. Under a
+        # mesh the call runs per (data, tp) shard inside shard_map, each
+        # shard over its own slots and kv heads (the capability probe
+        # gated the divisibilities).
         from torchkafka_tpu.ops.kvattn import (
             int8_decode_attention_dynlen,
             int8_decode_attention_dynlen_sharded,
         )
 
+        fresh = (kq, ks, vq, vs)
         if mesh is not None:
-            attn = int8_decode_attention_dynlen_sharded(
-                q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh, layer=l
+            attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen_sharded(
+                q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh, layer=l, rows=fresh
             )
         else:
-            attn = int8_decode_attention_dynlen(
-                q, ck_q, ck_s, cv_q, cv_s, pos_b, layer=l
+            attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen(
+                q, ck_q, ck_s, cv_q, cv_s, pos_b, layer=l, rows=fresh
             )
         x = _attn_tail(x, attn, layer, cfg)
     else:
+        # The XLA read has nothing to write inside: scatters, like the
+        # bf16 path (see _slot_layer_step's note), into the position-major
+        # pool, payload [L, B, M, K, Dh] and scale [L, B, M, K] alike.
+        rows = jnp.arange(ck_q.shape[1])
+        ck_q, ck_s, cv_q, cv_s = (
+            c.at[l, rows, pos_b].set(row)
+            for c, row in ((ck_q, kq), (ck_s, ks), (cv_q, vq), (cv_s, vs))
+        )
         valid = jnp.arange(ck_q.shape[2])[None, :] <= pos_b[:, None]  # [B, M]
         x = _attend_cached(
             x, q, _layer_of(ck_q, l), _layer_of(cv_q, l), valid, layer, cfg,
@@ -588,6 +583,7 @@ class ServeMetrics:
                 (format_labels(
                     layout=str(kb.get("layout", "dense")),
                     kv_dtype=str(kb.get("kv_dtype", "compute")),
+                    row_write=str(kb.get("row_write", "scatter")),
                     sharding=f"data={kb.get('data', 1)},tp={kb.get('tp', 1)}",
                 ), 1),
             ]),
@@ -1591,8 +1587,9 @@ class StreamingGenerator:
                 x = embed_rows(params["embed"], last_tok, cfg.dtype)[:, None, :]
 
                 # The pool rides the layer loop as its CARRY, as it rides
-                # the tick loop: each layer scatters its rows into the
-                # stacked pool in place and reads at its own index. A
+                # the tick loop: each layer writes its rows into the
+                # stacked pool in place (a scatter, or the dense int8
+                # kernel's aliased write) and reads at its own index. A
                 # scan's xs and ys are two buffers: as those, every
                 # layer's slab is sliced out of one and written back into
                 # the other, and the pool is copied whole every tick to
@@ -1639,7 +1636,8 @@ class StreamingGenerator:
                 # BEFORE the attention that could read it. Freezing the
                 # caches with a jnp.where would copy the pool every token,
                 # which nothing in this program does: every write is a
-                # scatter into the carried pool.
+                # scatter, or the read kernel's own row write, into the
+                # carried pool.
                 t = pos - P  # decode ticks completed before this one
                 idx = jnp.minimum(t + 1, self._max_new - 1)
                 # One-hot select over the tiny [B, max_new] buffer
