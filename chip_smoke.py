@@ -320,7 +320,8 @@ def check_kernels(sz: Sizes) -> dict:
 
 def _serve_once(
     tk, params, cfg, mesh, *, group: str, slots: int, prompt_len: int,
-    max_new: int, records: int, expect_layout: str | None, cache, **kv,
+    max_new: int, records: int, expect_layout: str | None, cache,
+    with_summary: bool = False, **kv,
 ) -> dict:
     """One server over a fresh 2-partition prompt topic: warm up, serve
     ``records`` prompts to completion, check tokens, commits and (for the
@@ -392,6 +393,7 @@ def _serve_once(
              "kernel_disabled_reason", "data", "tp")
         },
         "peak_bytes_in_use": _peak_bytes(),
+        **({"summary": summary} if with_summary else {}),
     }
 
 
@@ -457,6 +459,53 @@ def run_serve(tk, sz: Sizes, mesh, cache) -> None:
         ),
         **common,
     ))
+
+
+def run_serve_share(tk, sz: Sizes, cache) -> None:
+    """The double-layer family on the normal path at toy size (the real
+    head widths, 192 and 128, so the chip compiles flash at them): one
+    device whatever the mesh, as such a config refuses one."""
+    import jax
+    import jax.numpy as jnp
+
+    from torchkafka_tpu.models import TransformerConfig
+    from torchkafka_tpu.models.transformer import init_params
+
+    dtype = jnp.bfloat16 if sz.serve_scale else jnp.float32
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        d_ff=512, max_seq_len=sz.prompt_len + sz.max_new, dtype=dtype,
+        param_dtype=dtype, kv_lora_rank=128, qk_nope_dim=128,
+        qk_rope_dim=64, v_head_dim=128, rope_interleave=True,
+        q_lora_rank=128, mla_scale_q_lora=True, mla_scale_kv_lora=True,
+        attn_blocks=2, n_experts=16, zero_experts=8, expert_top_k=4,
+        expert_d_ff=128, router_score="softmax", norm_topk=False,
+        routed_scaling=6.0, experts_held=(4, 4),
+    )
+    params = init_params(jax.random.key(2), cfg)
+    facts = _serve_once(
+        tk, params, cfg, None, group="smoke-double-layer-share",
+        slots=sz.slots, prompt_len=sz.prompt_len, max_new=sz.max_new,
+        records=sz.pool_records, expect_layout=None, cache=cache,
+        with_summary=True,
+    )
+    summary = facts.pop("summary")
+    experts, pool = summary["expert_layer"], summary["latent_pool"]
+    fates = [experts[f"moe_{f}_assignments"] for f in ("zero", "local", "absent")]
+    _require(
+        facts["kv_backend"]["layout"] == "latent" and pool["attn_blocks"] == 2
+        and experts["experts_held"] == [4, 4],
+        f"smoke-double-layer-share: {facts['kv_backend']}, {pool}, {experts}",
+    )
+    _require(
+        min(fates) > 0 and sum(fates) == experts["moe_assignments"],
+        f"smoke-double-layer-share: pairs by fate {fates} of "
+        f"{experts['moe_assignments']}",
+    )
+    _report("serve.double_layer_share", {
+        **facts, "pairs_zero_local_absent": fates,
+        "latent_positions_valid": pool["latent_positions_valid"],
+    })
 
 
 # -------------------------------------------------------------------- train
@@ -627,6 +676,8 @@ def main(argv: list[str] | None = None) -> int:
     t0 = time.perf_counter()
     _report("kernels", {**check_kernels(sz), **cache.take()})
     run_serve(tk, sz, serve_mesh, cache)
+    gc.collect()
+    run_serve_share(tk, sz, cache)
     gc.collect()
     run_train(tk, sz, train_mesh, cache)
     _report("done", {"total_s": round(time.perf_counter() - t0, 1)})

@@ -226,7 +226,7 @@ REFUSALS = {
         peak_hbm_gbs=819.0
     ),
     "kv_kernel=True": lambda c, p: _server(c, p, kv_kernel=True),
-    "q compression": lambda c, p: latent_cfg(qk_rope_dim=0),
+    "no roped key": lambda c, p: latent_cfg(qk_rope_dim=0),
     "interleave without latent attention": lambda c, p: TransformerConfig(
         rope_interleave=True
     ),
@@ -252,11 +252,12 @@ REASONS = {
     "make_train_step": "make_train_step is not built",
     "decode_roofline": "K/V pool bytes",
     "kv_kernel=True": "no Pallas read is built",
-    "q compression": "even qk_rope_dim",
+    "no roped key": "even qk_rope_dim",
     "interleave without latent attention": "latent attention alone",
     "shared experts without the routed layer": "router_score='sigmoid'",
     "routed experts without latent attention": "built together only",
-    "softmax experts under latent attention": "built together only",
+    # (unnormalised softmax scores ARE built since PR 31: tests/test_longcat_layer.py)
+    "softmax experts under latent attention": "norm_topk=False alone",
 }
 
 

@@ -39,6 +39,7 @@ from torchkafka_tpu.models.transformer import (
     Transformer,
     TransformerConfig,
     _arch_refusal,
+    _double_scan,
     _dense_mlp,
     _layer_groups,
     _moe_mlp,
@@ -436,7 +437,8 @@ def latent_forward(params, model: Transformer, tokens: jax.Array):
     """A latent-attention config's forward over ``tokens`` [B, S], with
     what serving and the benchmark keep of it: (last-position logits [B,
     V]; the rows the cache holds, [L, B, S, rank + rope], L over the
-    leading dense layers and then the expert layers; the expert layers'
+    leading dense layers and then the expert layers (over the blocks, 2 a
+    layer, of the double layer); the expert layers'
     routing [L_moe, B, S, top_k], or None for a config without experts).
     Unlike ``prefill``'s ``KVCache`` the rows are S long, not a pool: the
     caller writes them where its pool keeps them."""
@@ -449,7 +451,13 @@ def latent_forward(params, model: Transformer, tokens: jax.Array):
 
     latents, routing = [], None
     for key, _nl, expert_mlp in _layer_groups(cfg):
-        x, (lat, rt) = lax.scan(capture, x, params[key])
+        xs, step = params[key], capture
+        if cfg.attn_blocks == 2:
+            xs, layer_of = _double_scan(params[key])
+            step = lambda x, s, f=layer_of: capture(x, f(s))  # noqa: E731
+        x, (lat, rt) = lax.scan(step, x, xs)
+        if cfg.attn_blocks == 2:  # [L, 2, ...]: block i of layer l at 2l + i
+            lat = lat.reshape(-1, *lat.shape[2:])
         latents.append(lat)
         if expert_mlp:
             routing = rt

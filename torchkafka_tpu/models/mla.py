@@ -1,13 +1,17 @@
 """Latent attention (MLA, DeepSeek-V2/V3): the projections, the two
 attention paths, and what is cached.
 
-A layer's weights (``models/transformer.py::_arch_shapes``): ``wq`` [D, H,
-nope + rope] (no q compression), ``wkva`` [D, rank + rope] (the latent and
-the key all heads share), ``kv_norm`` [rank], ``wkvb`` [rank, H, nope + v]
-(a head's k_nope beside its v), ``wo`` [H, v, D].
+A block's weights (``models/transformer.py::_arch_shapes``): ``wq`` [D, H,
+nope + rope], or with query compression (``q_lora_rank`` > 0) ``wqa`` [D,
+q_rank], ``q_norm`` [q_rank] and ``wqb`` [q_rank, H, nope + rope]; ``wkva``
+[D, rank + rope] (the latent and the key all heads share), ``kv_norm``
+[rank], ``wkvb`` [rank, H, nope + v] (a head's k_nope beside its v), ``wo``
+[H, v, D].
 
-What is cached, one tensor a layer: ``concat(norm(c), rope(k_r))``,
-``rank + rope`` wide, in the compute dtype.
+What is cached, one tensor a block: ``concat(norm(c), rope(k_r))``,
+``rank + rope`` wide, in the compute dtype; with ``mla_scale_kv_lora`` the
+normed latent times ``sqrt(D / rank)``, the scaled latent being what the
+heads' keys and values are made from.
 
 Two paths, the same mathematics reordered:
 
@@ -38,11 +42,24 @@ def project(h, layer, cfg: TransformerConfig, positions):
     """Normed activations h [B, S, D] at ``positions`` ([S] or [B, S]) →
     (q_nope [B, S, H, nope], q_rope [B, S, H, rope] roped, latent [B, S,
     rank + rope]: what the cache holds)."""
-    q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
+    if cfg.q_lora_rank:
+        cq = _rms_norm(
+            jnp.einsum("bsd,dq->bsq", h, load_weight(layer["wqa"], cfg.dtype)),
+            layer["q_norm"],
+        )
+        if cfg.mla_scale_q_lora:
+            cq = cq * jnp.asarray(
+                math.sqrt(cfg.d_model / cfg.q_lora_rank), cq.dtype
+            )
+        q = jnp.einsum("bsq,qhe->bshe", cq, load_weight(layer["wqb"], cfg.dtype))
+    else:
+        q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
     kva = jnp.einsum("bsd,dc->bsc", h, load_weight(layer["wkva"], cfg.dtype))
     c, k_r = jnp.split(kva, [cfg.kv_lora_rank], axis=-1)
     c = _rms_norm(c, layer["kv_norm"])
+    if cfg.mla_scale_kv_lora:
+        c = c * jnp.asarray(math.sqrt(cfg.d_model / cfg.kv_lora_rank), c.dtype)
     q_rope = _rope(q_rope, positions, cfg.rope_theta, cfg.rope_interleave)
     k_r = _rope(
         k_r[:, :, None, :], positions, cfg.rope_theta, cfg.rope_interleave
@@ -84,8 +101,9 @@ def attend_full(q_nope, q_rope, latent, layer, cfg, *, use_flash: bool):
 
 
 def attend_absorbed(q_nope, q_rope, pool, l, pos_b, layer, cfg):
-    """One decode query a slot against layer ``l`` of the stacked latent
-    pool [L, B, M, rank + rope], rows 0..pos_b valid → [B, 1, H, v]."""
+    """One decode query a slot against row ``l`` (a layer's, or a block's
+    of the double layer) of the stacked latent pool [L, B, M, rank +
+    rope], positions 0..pos_b valid → [B, 1, H, v]."""
     r = cfg.kv_lora_rank
     w_uk, w_uv = jnp.split(
         load_weight(layer["wkvb"], cfg.dtype), [cfg.qk_nope_dim], axis=-1

@@ -121,11 +121,25 @@ class TransformerConfig:
     # ONE tensor a layer, the normed ``kv_lora_rank``-wide latent beside
     # the roped ``qk_rope_dim``-wide key all heads share; a head's q/k
     # width is ``qk_nope_dim + qk_rope_dim``, its v width ``v_head_dim``
-    # (models/mla.py). No q compression (``q_lora_rank``) is built.
+    # (models/mla.py). ``q_lora_rank`` > 0 compresses the queries too
+    # (``wqa`` -> RMSNorm -> ``wqb``); the two flags, as the source has
+    # them, multiply the normed compressed query by ``sqrt(d_model /
+    # q_lora_rank)`` and the normed latent by ``sqrt(d_model /
+    # kv_lora_rank)`` (the scaled latent is what is cached).
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    q_lora_rank: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    # 2: the shortcut-connected double layer. A layer holds two latent
+    # attention blocks and two dense SwiGLUs of ``d_ff`` in a row, and ONE
+    # expert branch that leaves the first block's normed output and
+    # rejoins after the second SwiGLU (``_double_layer``); the cache holds
+    # a row a BLOCK, ``2 * n_layers`` of them, block ``i`` of layer ``l``
+    # at ``2l + i``.
+    attn_blocks: int = 1
     # RoPE over interleaved pairs (2i, 2i+1) instead of the split halves
     # (i, i + D/2): how the checkpoint's q/k columns are ordered.
     rope_interleave: bool = False
@@ -138,14 +152,26 @@ class TransformerConfig:
     # ``n_shared_experts * expert_d_ff``).
     expert_d_ff: int = 0
     n_shared_experts: int = 0
-    # 'softmax': the gates are the renormalised top-k of a softmax (the
-    # ``moe_dispatch`` family above). 'sigmoid' (DeepSeek-V3's
-    # ``noaux_tc``): scores are sigmoids, a per-expert bias moves the
-    # SELECTION alone, the selected scores are divided by their sum and
-    # multiplied by ``routed_scaling``; served by the routed expert layer
-    # (ops/moe.py), which drops no token at any load.
+    # 'softmax': without latent attention, the gates are the renormalised
+    # top-k of a softmax (the ``moe_dispatch`` family above). 'sigmoid'
+    # (DeepSeek-V3's ``noaux_tc``): scores are sigmoids. With latent
+    # attention either score is served by the routed expert layer
+    # (ops/moe.py), which drops no token at any load: a per-expert bias
+    # moves the SELECTION alone, the selected scores are divided by their
+    # sum if ``norm_topk`` and multiplied by ``routed_scaling``.
     router_score: str = "softmax"
     routed_scaling: float = 1.0
+    norm_topk: bool = True
+    # Zero-compute experts: the router has ``n_experts + zero_experts``
+    # outputs, and a pair that chose an index >= ``n_experts`` adds its
+    # weight times the layer's input itself (the identity, no weights).
+    zero_experts: int = 0
+    # ``(first, count)``: this device holds experts ``[first, first +
+    # count)`` of each layer and no others (one chip's share of an
+    # expert-parallel deployment). The router keeps all its outputs and its
+    # top-k; a pair that chose an absent expert adds nothing HERE: the
+    # layer's output is this chip's part of the sum. None: every expert.
+    experts_held: tuple[int, int] | None = None
 
     @property
     def head_dim(self) -> int:
@@ -161,13 +187,38 @@ class TransformerConfig:
 
     @property
     def routed_moe(self) -> bool:
-        """Expert layers of the sigmoid-scored kind (ops/moe.py)."""
-        return self.n_experts > 0 and self.router_score == "sigmoid"
+        """Expert layers of the routed kind (ops/moe.py): sigmoid scores,
+        or any score beside latent attention."""
+        return self.n_experts > 0 and (
+            self.router_score == "sigmoid" or self.is_mla
+        )
 
     @property
     def latent_dim(self) -> int:
-        """What MLA caches a position a layer: latent beside roped key."""
+        """What MLA caches a position a block: latent beside roped key."""
         return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def cache_layers(self) -> int:
+        """Rows of the stacked cache: one an attention block."""
+        return self.n_layers * self.attn_blocks
+
+    @property
+    def router_width(self) -> int:
+        return self.n_experts + self.zero_experts
+
+    @property
+    def moe_partial(self) -> bool:
+        """The routed layer's pairs do not all meet an expert's weights
+        here: some choose the identity, or an expert on another chip."""
+        return self.routed_moe and (
+            self.experts_held is not None or self.zero_experts > 0
+        )
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """``(first, count)`` of the experts whose weights are here."""
+        return self.experts_held or (0, self.n_experts)
 
     @property
     def qk_head_dim(self) -> int:
@@ -182,8 +233,10 @@ class TransformerConfig:
             raise ValueError("d_model must divide by n_heads")
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must divide by n_kv_heads")
-        if self.n_experts and self.expert_top_k > self.n_experts:
-            raise ValueError("expert_top_k cannot exceed n_experts")
+        if self.n_experts and self.expert_top_k > self.router_width:
+            raise ValueError(
+                "expert_top_k cannot exceed n_experts (+ zero_experts)"
+            )
         if self.moe_dispatch not in ("dense", "capacity"):
             raise ValueError(
                 f"moe_dispatch must be 'dense' or 'capacity', got "
@@ -214,6 +267,72 @@ class TransformerConfig:
                 'loops scan params["layers"] alone, and the latent '
                 "layers' experts are the routed ones"
             )
+        if self.is_mla and self.is_moe and (
+            self.router_score == "softmax" and self.norm_topk
+        ):
+            raise ValueError(
+                "router_score='softmax' beside latent attention is built "
+                "with norm_topk=False alone (the selected probabilities "
+                "times routed_scaling, as the zero-compute-expert family "
+                "routes): the renormalised softmax top-k is the "
+                "moe_dispatch family's, which latent attention does not run"
+            )
+        if not self.is_mla and (
+            self.q_lora_rank or self.mla_scale_q_lora
+            or self.mla_scale_kv_lora or self.attn_blocks != 1
+        ):
+            raise ValueError(
+                "q_lora_rank, mla_scale_q_lora, mla_scale_kv_lora and "
+                "attn_blocks describe latent attention: set kv_lora_rank "
+                "> 0 (the grouped-query layer has one attention block and "
+                "no compressed query)"
+            )
+        if self.q_lora_rank < 0 or (
+            self.mla_scale_q_lora and not self.q_lora_rank
+        ):
+            raise ValueError(
+                "mla_scale_q_lora scales the COMPRESSED query: it needs "
+                "q_lora_rank > 0"
+            )
+        if self.attn_blocks not in (1, 2):
+            raise ValueError(
+                f"attn_blocks must be 1 or 2, got {self.attn_blocks}: the "
+                "layers built are the plain one and the shortcut-connected "
+                "double layer"
+            )
+        if self.attn_blocks == 2 and not (
+            self.routed_moe and not self.first_dense_layers
+            and not self.n_shared_experts
+        ):
+            raise ValueError(
+                "attn_blocks=2 is the shortcut-connected double layer as "
+                "built: two latent blocks, two dense SwiGLUs of d_ff and "
+                "ONE routed expert branch in EVERY layer (n_experts > 0, "
+                "no first_dense_layers, no shared experts: its dense "
+                "SwiGLUs take their place)"
+            )
+        if not self.routed_moe and (
+            self.zero_experts or self.experts_held is not None
+            or not self.norm_topk
+        ):
+            raise ValueError(
+                "zero_experts, experts_held and norm_topk=False describe "
+                "the routed expert layer (ops/moe.py): set n_experts > 0 "
+                "beside latent attention; the softmax family of "
+                "moe_dispatch computes every expert for every token and "
+                "renormalises its top-k"
+            )
+        if self.zero_experts < 0:
+            raise ValueError("zero_experts must be >= 0")
+        if self.experts_held is not None:
+            first, count = self.experts_held
+            if not (0 <= first and count >= 1
+                    and first + count <= self.n_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held} must name a "
+                    f"non-empty range (first, count) inside the "
+                    f"{self.n_experts} routed experts"
+                )
         if self.rope_interleave and not self.is_mla:
             raise ValueError(
                 "rope_interleave is read by latent attention alone "
@@ -226,7 +345,7 @@ class TransformerConfig:
         ):
             raise ValueError(
                 "first_dense_layers, n_shared_experts, expert_d_ff and "
-                "routed_scaling describe the sigmoid-routed expert layer: "
+                "routed_scaling describe the routed expert layer: "
                 "set n_experts > 0 and router_score='sigmoid'"
             )
         if not 0 <= self.first_dense_layers < max(self.n_layers, 1):
@@ -297,34 +416,68 @@ def _layer_groups(cfg: TransformerConfig) -> tuple[tuple[str, int, bool], ...]:
     return groups + (("layers", cfg.n_layers - lead, cfg.is_moe),)
 
 
+# What each attention block of the double layer has of its own, stacked on
+# a [2] axis after [L]; the rest of a layer (router, experts) is the one
+# expert branch's.
+_BLOCK_TENSORS = (
+    "ln1", "ln2", "wq", "wqa", "q_norm", "wqb", "wkva", "kv_norm", "wkvb",
+    "wo", "w_gate", "w_up", "w_down",
+)
+_EXPERT_TENSORS = ("we_gate", "we_up", "we_down")
+
+
 def _arch_shapes(cfg: TransformerConfig, expert_mlp: bool) -> dict:
     """One layer's tensors of a latent-attention config: name -> (shape,
-    fan_in or None for a norm's scale or the selection bias)."""
+    fan_in or None for a norm's scale or the selection bias). In the
+    double layer (``attn_blocks`` 2) the attention's and the dense
+    SwiGLU's tensors lead with the block axis, and the held experts have
+    names of their own (``we_*``)."""
     dm, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
-    shapes = {
-        "ln1": ((dm,), None), "ln2": ((dm,), None),
-        "wq": ((dm, h, cfg.qk_head_dim), dm),
+    shapes = {"ln1": ((dm,), None), "ln2": ((dm,), None)}
+    if cfg.q_lora_rank:
+        q = cfg.q_lora_rank
+        shapes.update(
+            wqa=((dm, q), dm), q_norm=((q,), None),
+            wqb=((q, h, cfg.qk_head_dim), q),
+        )
+    else:
+        shapes["wq"] = ((dm, h, cfg.qk_head_dim), dm)
+    shapes.update({
         # One projection gives the latent and the shared roped key.
         "wkva": ((dm, cfg.latent_dim), dm),
         "kv_norm": ((r,), None),
         # Up-projection of the latent: a head's k_nope beside its v.
         "wkvb": ((r, h, cfg.qk_nope_dim + cfg.v_head_dim), r),
         "wo": ((h, cfg.v_head_dim, dm), h * cfg.v_head_dim),
-    }
+    })
+    double = cfg.attn_blocks == 2
     lead, f = (), cfg.d_ff
     if expert_mlp:
-        e, f = cfg.n_experts, cfg.moe_d_ff
-        lead, fs = (e,), cfg.n_shared_experts * f
-        shapes.update(router=((dm, e), dm), router_bias=((e,), None))
+        e, w = cfg.held_experts[1], cfg.router_width
+        shapes.update(router=((dm, w), dm), router_bias=((w,), None))
+        fs = cfg.n_shared_experts * cfg.moe_d_ff
         if fs:  # the shared experts, one SwiGLU
             shapes.update(
                 ws_gate=((dm, fs), dm), ws_up=((dm, fs), dm),
                 ws_down=((fs, dm), fs),
             )
+        if double:  # the dense SwiGLUs keep ``w_*``
+            fe = cfg.moe_d_ff
+            shapes.update(
+                we_gate=((e, dm, fe), dm), we_up=((e, dm, fe), dm),
+                we_down=((e, fe, dm), fe),
+            )
+        else:
+            lead, f = (e,), cfg.moe_d_ff
     shapes.update(
         w_gate=(lead + (dm, f), dm), w_up=(lead + (dm, f), dm),
         w_down=(lead + (f, dm), f),
     )
+    if double:
+        shapes = {
+            n: (((2,) + shape) if n in _BLOCK_TENSORS else shape, fan)
+            for n, (shape, fan) in shapes.items()
+        }
     return shapes
 
 
@@ -353,7 +506,7 @@ def _arch_init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             for k, (name, (shape, fan_in)) in zip(keys, shapes.items())
         }
         if "router_bias" in out[key]:
-            out[key]["router_bias"] = jnp.zeros((nl, cfg.n_experts), pd)
+            out[key]["router_bias"] = jnp.zeros((nl, cfg.router_width), pd)
     return out
 
 
@@ -622,6 +775,73 @@ def _dense_mlp(h: jax.Array, layer: Mapping[str, jax.Array], cfg) -> jax.Array:
     return jnp.einsum("bsf,fd->bsd", gate * up, load_weight(layer["w_down"], cfg.dtype))
 
 
+def _double_scan(group: Mapping[str, jax.Array], first: int = 0):
+    """What a ``lax.scan`` over the double layers of a stacked group
+    runs on: ``(xs, layer_of)``. ``xs`` are the router and its bias
+    (``[L, ...]``) beside the layer index from ``first``; ``layer_of(x)``
+    makes ``_double_layer``'s ``(layer, stacks, l)`` of a step's slice.
+    The blocks' tensors ``[L, 2, ...]`` and the held experts' ``[L, E,
+    ...]`` do not ride the scan: they stay stacked, seen ``[2L, ...]`` and
+    ``[L * E, ...]``, and a block or an expert takes its own by ONE
+    dynamic index that fuses into the product reading it. As a scan's
+    slice ``[2, ...]`` indexed again by block, the compiler materialises
+    every layer's slice first: 14 of a 36 ms tick were copies of the
+    dense weights (PERF.md, PR 31)."""
+    stacks = {
+        n: w.reshape(-1, *w.shape[2:]) for n, w in group.items()
+        if n in _BLOCK_TENSORS + _EXPERT_TENSORS
+    }
+    rest = {n: w for n, w in group.items() if n not in stacks}
+    count = rest["router"].shape[0]
+
+    def layer_of(x):
+        rest_l, l = x
+        return rest_l, stacks, l - first
+
+    return (rest, jnp.arange(first, first + count)), layer_of
+
+
+def _double_layer(x, layer, cfg: "TransformerConfig", attend):
+    """The shortcut-connected double layer (``attn_blocks`` 2), shared by
+    the full forward, the admission's prefill and the decode tick:
+
+        a0 = x  + MLA_0(N_in0(x));   m = N_post0(a0)
+        s  = Experts(m)                          the shortcut branch
+        b0 = a0 + F_0(m)
+        a1 = b0 + MLA_1(N_in1(b0))
+        y  = a1 + F_1(N_post1(a1)) + s           the branch rejoins
+
+    ``layer``: one layer's tensors, its blocks' ``[2, ...]`` and its
+    experts' ``[E, ...]``; or, inside a scan, ``_double_scan``'s ``(the
+    router's slice, the stacks of every layer's blocks [2L, ...] and
+    experts [L * E, ...], the layer's index in them)``. ``attend(i, h,
+    block)`` is block ``i``'s attention on its normed input ``h`` → [B, S,
+    H, v]: the caller's, because what is cached and how it is read differ
+    between a whole sequence and a tick. Returns (y, the routing [B, S,
+    top_k])."""
+    from torchkafka_tpu.ops.moe import routed_moe_mlp
+
+    layer, stacks, l = layer if isinstance(layer, tuple) else (layer, layer, 0)
+
+    def block(i, x):
+        blk = {
+            n: lax.dynamic_index_in_dim(stacks[n], 2 * l + i, keepdims=False)
+            for n in _BLOCK_TENSORS if n in stacks
+        }
+        attn = attend(i, _rms_norm(x, blk["ln1"]), blk)
+        x = x + jnp.einsum(
+            "bshe,hed->bsd", attn, load_weight(blk["wo"], cfg.dtype)
+        )
+        return x, _rms_norm(x, blk["ln2"]), blk
+
+    a0, m, blk0 = block(0, x)
+    branch, routing = routed_moe_mlp(m, layer, cfg, experts=(
+        *(stacks[n] for n in _EXPERT_TENSORS), l * cfg.held_experts[1]
+    ))
+    a1, m1, blk1 = block(1, a0 + _dense_mlp(m, blk0, cfg))
+    return a1 + _dense_mlp(m1, blk1, cfg) + branch, routing
+
+
 def _arch_refusal(cfg: "TransformerConfig", what: str) -> str | None:
     """Why ``what`` does not take a latent-attention config (None: it
     does, the config is not one)."""
@@ -629,10 +849,13 @@ def _arch_refusal(cfg: "TransformerConfig", what: str) -> str | None:
         return None
     return (
         f"{what} is not built for latent attention (kv_lora_rank > 0) and "
-        "its routed expert layer: these configs serve on one device "
-        "through StreamingGenerator's dense slot pool (compute-dtype "
-        "cache) and run Transformer's forward; nothing else has been "
-        "taught their layouts"
+        "its routed expert layer: these configs (with or without "
+        "compressed queries, the double layer, zero-compute experts, a "
+        "held share of the experts) serve on one device through "
+        "StreamingGenerator's dense slot pool (compute-dtype cache, bf16 "
+        "or float32 weights) and run Transformer's forward; nothing else "
+        "has been taught their layouts, and no exchange across chips "
+        "stands behind a held share"
     )
 
 
@@ -754,10 +977,26 @@ class Transformer:
         """``_layer`` with what serving keeps of it: (activation, router
         stats, capture). For a latent-attention config the capture is
         ``(latent [B, S, rank + rope], routing [B, S, top_k] | None)``,
-        the layer's cache rows and its expert choices; otherwise None
+        the layer's cache rows (``[2, B, S, rank + rope]``, a row a block,
+        for the double layer) and its expert choices; otherwise None
         (``generate.prefill`` computes k and v beside the layer)."""
         cfg = self.cfg
         positions = self._seq_positions(x.shape[1])
+        if cfg.attn_blocks == 2:
+            from torchkafka_tpu.models import mla
+
+            latents = [None, None]
+
+            def attend(i, h, blk):
+                q_nope, q_rope, latents[i] = mla.project(h, blk, cfg, positions)
+                return mla.attend_full(
+                    q_nope, q_rope, latents[i], blk, cfg,
+                    use_flash=self._use_flash,
+                )
+
+            x, routing = _double_layer(x, layer, cfg, attend)
+            stats = jnp.zeros((2, 1), jnp.float32)
+            return x, stats, (jnp.stack(latents), routing)
         h = _rms_norm(x, layer["ln1"])
         latent = None
         if cfg.is_mla:
@@ -871,7 +1110,11 @@ class Transformer:
             # expert layers (``first_dense_layers``).
             stats = []
             for key, nl, _expert_mlp in _layer_groups(cfg):
-                x, st = lax.scan(body, x, params[key], unroll=min(unroll, nl))
+                xs, step = params[key], body
+                if cfg.attn_blocks == 2:
+                    xs, layer_of = _double_scan(params[key])
+                    step = lambda x, s, f=layer_of: body(x, f(s))  # noqa: E731
+                x, st = lax.scan(step, x, xs, unroll=min(unroll, nl))
                 stats.append(st)
             stats = stats[0] if len(stats) == 1 else jnp.concatenate(stats)
         # stats: [L, 2, E] token-summed routing statistics; per-layer aux,

@@ -1,26 +1,51 @@
-"""The routed expert layer: sigmoid-scored token-choice routing and one
+"""The routed expert layer: token-choice routing over sigmoid or softmax
+scores, zero-compute experts, one chip's share of the experts, and one
 expert matmul over the token-choice pairs, shared by prefill and decode.
 
 The softmax family in ``models/transformer.py`` (``_moe_mlp``: every
 expert for every token; ``_moe_mlp_capacity``: Switch dispatch with drops)
-stays as it is for its configs. This layer serves the DeepSeek-V3 kind
-(``TransformerConfig.router_score == "sigmoid"``):
+stays as it is for its configs. This layer serves the kinds that come
+with latent attention (``TransformerConfig.routed_moe``):
 
-    s   = sigmoid(h · W_r)                       float32, [N, E]
-    sel = top_k(s + b)                           b moves the SELECTION only
-    w   = s[sel] / sum(s[sel]) * routed_scaling  the bias is not in a weight
-    y   = sum_k w_k · E_sel_k(h) + S(h)          S: the shared experts
+    s   = sigmoid(h · W_r)  or  softmax(h · W_r)     float32, [N, E + Z]
+    sel = top_k(s + b)                       b moves the SELECTION only
+    w   = s[sel] * routed_scaling            divided by sum(s[sel]) first
+                                             if ``norm_topk``; the bias
+                                             is not in a weight
+    y   = sum_k w_k · E_sel_k(h) + S(h)      S: the shared experts, if any
 
-No token is dropped at any load: the pairs are sorted by expert and each
-expert multiplies exactly the rows routed to it (``lax.ragged_dot``, a
-grouped matmul), so the FLOPs are top-k's, not E's.
+**Zero-compute experts** (``zero_experts`` = Z > 0): the router has E + Z
+outputs and ``E_e(h) = h`` for ``e >= E``: such a pair adds ``w · h`` and
+touches no weight.
 
-Where the rows are few against the experts (a decode tick: 64 rows x 6
-choices over 128 experts touches 95% of the experts, so the weights are
-streamed whole either way) the all-experts einsum is the other form of
-the same sum. Which one runs is decided by the static shapes alone
-(``_GROUPED_MIN_PAIRS_PER_EXPERT``), never by an option; PERF.md holds
-the chip's readings of both.
+**A share of the experts** (``experts_held`` = (first, count)): the
+weights here are those of experts ``[first, first + count)`` alone, one
+chip's of an expert-parallel deployment. The router keeps all its outputs
+and its top-k. A pair that chose a held expert goes through it, a pair
+that chose a zero expert adds ``w · h`` (every chip computes those for its
+own tokens), a pair that chose an absent expert adds nothing: the output
+is THIS chip's part of the sum. Nothing stands in for the absent chips.
+
+No token is dropped at any load, in any form:
+
+- ``grouped_experts`` (every expert held): the pairs are sorted by expert
+  and each expert multiplies exactly the rows routed to it
+  (``lax.ragged_dot``), so the FLOPs are top-k's, not E's.
+- ``all_experts`` (every expert held): where the rows are few against the
+  experts (a decode tick) every expert multiplies every row and the
+  unrouted ones are weighted by zero: the weights are streamed whole
+  either way.
+- ``compacted_experts`` (a share held, or zero experts behind the real
+  ones; prefill and decode alike): of N·K pairs only ``count / (E + Z)``
+  meet a held expert, so the local pairs are sorted to the front and
+  multiplied in tiles of ``cap`` rows of one expert; the loop walks the
+  tiles there are, a value of the routing and not a bound on it: all N
+  rows to one expert are N / cap tiles, none is cut, and an expert no
+  pair chose is not read.
+
+Which one runs is decided by the configuration and the static shapes
+alone (``_GROUPED_MIN_PAIRS_PER_EXPERT`` against the pairs an expert can
+expect), never by an option; PERF.md holds the chip's readings.
 """
 
 from __future__ import annotations
@@ -37,7 +62,8 @@ from torchkafka_tpu.models.quant import load_weight
 _GROUPED_MIN_PAIRS_PER_EXPERT = 8
 
 
-def route(h, router, bias, *, top_k: int, scaling: float):
+def route(h, router, bias, *, top_k: int, scaling: float,
+          score: str = "sigmoid", norm_topk: bool = True):
     """h [N, D] → (idx [N, K] int32, weights [N, K] float32).
 
     Scores in float32 at the matmul's highest precision: a near-tie
@@ -47,10 +73,16 @@ def route(h, router, bias, *, top_k: int, scaling: float):
         "nd,de->ne", h.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
-    scores = jax.nn.sigmoid(logits)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
     _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
-    weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scaling
+    if norm_topk:
+        weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scaling
+    else:
+        weights = picked * scaling
     return idx.astype(jnp.int32), weights
 
 
@@ -82,6 +114,55 @@ def grouped_experts(h, idx, weights, w_gate, w_up, w_down):
     return jnp.einsum("nkd,nk->nd", out, weights).astype(h.dtype)
 
 
+def compacted_experts(h, idx, weights, w_gate, w_up, w_down, e: int,
+                      cap: int, base=0):
+    """Σ_k w_k · E_idx_k(h) over the pairs whose ``idx`` names one of the
+    ``e`` experts HERE (``0 <= idx < e``; any other value is a pair this
+    chip does not compute), in TILES of ``cap`` rows of one expert. Expert
+    ``i``'s matrices are row ``base + i`` of ``w_gate``, ``w_up`` [.., D,
+    F] and ``w_down`` [.., F, D]: the stacks may hold more than this
+    layer's experts (every layer's, ``base`` then the layer's first), and
+    ONE dynamic index reaches an expert and fuses into the product that
+    reads it.
+
+    The pairs are sorted with the local ones first, by expert; an expert
+    with ``n`` pairs has ``ceil(n / cap)`` tiles, and the loop walks the
+    tiles that exist, ``sum_e ceil(n_e / cap)`` of them: a value of
+    ``idx``, so no routing overflows it, and a routing that sends every
+    row to ONE expert costs that expert's tiles and not every expert's
+    (padding tokens all route alike: PERF.md, PR 31). A tile gathers its
+    rows ``[cap, D]`` (slots past the expert's run repeat a row and weigh
+    nothing), multiplies them with its expert's three matrices and adds
+    the weighted results to their tokens in float32."""
+    n, k = idx.shape
+    d = h.shape[-1]
+    flat = idx.reshape(-1)
+    key = jnp.where((flat >= 0) & (flat < e), flat, e)
+    order = jnp.argsort(key, stable=True)  # local pairs first, by expert
+    sizes = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+    starts = jnp.cumsum(sizes) - sizes
+    tiles_to = jnp.cumsum((sizes + cap - 1) // cap)  # tiles up to expert e
+    w_flat = weights.reshape(-1)
+
+    def tile(t, out):
+        ex = jnp.searchsorted(tiles_to, t, side="right").astype(jnp.int32)
+        j = t - (tiles_to[ex] - (sizes[ex] + cap - 1) // cap)
+        slot = j * cap + jnp.arange(cap)
+        pair = order[jnp.minimum(starts[ex] + slot, n * k - 1)]
+        tok = pair // k
+        y = _swiglu(h[tok], *(
+            lax.dynamic_index_in_dim(m, base + ex, keepdims=False)
+            for m in (w_gate, w_up, w_down)
+        )).astype(jnp.float32)  # [cap, D]
+        y = y * jnp.where(slot < sizes[ex], w_flat[pair], 0.0)[:, None]
+        return out.at[tok].add(y)
+
+    out = lax.fori_loop(
+        0, tiles_to[-1], tile, jnp.zeros((n, d), jnp.float32)
+    )
+    return out.astype(h.dtype)
+
+
 def all_experts(h, idx, weights, w_gate, w_up, w_down):
     """The same sum with every expert computed for every row and the
     unrouted ones weighted by zero."""
@@ -98,28 +179,53 @@ def all_experts(h, idx, weights, w_gate, w_up, w_down):
 
 
 def routed_experts(h, idx, weights, w_gate, w_up, w_down):
-    """The form the static shapes call for (module docstring)."""
+    """Every expert is here: the form the static shapes call for (module
+    docstring)."""
     n, k = idx.shape
     if n * k >= _GROUPED_MIN_PAIRS_PER_EXPERT * w_gate.shape[0]:
         return grouped_experts(h, idx, weights, w_gate, w_up, w_down)
     return all_experts(h, idx, weights, w_gate, w_up, w_down)
 
 
-def routed_moe_mlp(h, layer, cfg):
+def routed_moe_mlp(h, layer, cfg, experts=None):
     """One expert layer's MLP on normed activations h [B, S, D]:
-    (output [B, S, D], the routing idx [B, S, K])."""
+    (output [B, S, D], the routing idx [B, S, K] over ALL the router's
+    outputs). With ``cfg.experts_held`` the output is this chip's part of
+    the sum (module docstring). ``experts``: ``(w_gate, w_up, w_down,
+    base)``, stacks whose rows ``[base, base + E)`` are this layer's
+    experts, where the caller keeps them apart from the layer's other
+    tensors (the double layer); default the layer's own ``w_gate``,
+    ``w_up``, ``w_down``, from 0."""
     b, s, d = h.shape
     x = h.reshape(b * s, d)
     idx, weights = route(
         x, layer["router"], layer["router_bias"],
         top_k=cfg.expert_top_k, scaling=cfg.routed_scaling,
+        score=cfg.router_score, norm_topk=cfg.norm_topk,
     )
-    out = routed_experts(
-        x, idx, weights, *(
-            load_weight(layer[n], cfg.dtype)
-            for n in ("w_gate", "w_up", "w_down")
-        ),
+    *mats, base = experts or (
+        *(layer[n] for n in ("w_gate", "w_up", "w_down")), 0
     )
+    mats = [load_weight(m, cfg.dtype) for m in mats]
+    first, count = cfg.held_experts
+    if cfg.moe_partial:
+        # Twice the pairs a held expert can expect, in whole sublane groups.
+        cap = -(-2 * idx.size // cfg.router_width // 16) * 16
+        out = compacted_experts(
+            x, idx - first, weights, *mats, e=count, cap=cap, base=base
+        )
+    else:
+        if experts:
+            mats = [lax.dynamic_slice_in_dim(m, base, count) for m in mats]
+        out = routed_experts(x, idx, weights, *mats)
+    if cfg.zero_experts:
+        # The identity experts: w · h, no weights.
+        w_zero = jnp.sum(
+            jnp.where(idx >= cfg.n_experts, weights, 0.0), axis=-1
+        )
+        out = (
+            out.astype(jnp.float32) + w_zero[:, None] * x.astype(jnp.float32)
+        ).astype(x.dtype)
     if cfg.n_shared_experts:
         out = out + _swiglu(x, *(
             load_weight(layer[n], cfg.dtype)

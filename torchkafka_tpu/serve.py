@@ -87,6 +87,8 @@ from torchkafka_tpu.models.quant import embed_rows, load_weight
 from torchkafka_tpu.models.transformer import (
     TransformerConfig,
     _arch_refusal,
+    _double_layer,
+    _double_scan,
     _layer_groups,
     _rms_norm,
     _rope,
@@ -301,16 +303,26 @@ class ServeMetrics:
         # the paths that prefill row by row
         # The routed expert layer and the latent pool (a latent-attention
         # config; all zero otherwise). Cumulative like the scheduler's.
-        self.moe_assignments = RateMeter()  # (token, choice) pairs of the
-        # served slot-ticks: served ticks x top_k x expert layers
         self.moe_experts_touched = RateMeter()  # sum over expert layers and
         # ticks of the experts that got at least one pair from a slot the
         # device held active (what a tick has to stream, counted on the
         # device and fetched with the sync's other arrays)
         self.moe_expert_load: np.ndarray = np.zeros((0,), np.int64)  # those
-        # pairs by expert, summed over layers and ticks
+        # pairs by expert (by HELD expert, where a share is held), summed
+        # over layers and ticks
+        self.moe_assignments = RateMeter()  # the (token, choice) pairs of
+        # those slot-ticks, counted with the load: the sum of the three
+        # fates a pair can have. It chose an expert whose weights are here
+        # (every pair, where the layer holds every expert), the identity
+        # (``zero_experts``), or an expert on another chip
+        # (``experts_held``), which adds nothing here.
+        self.moe_zero_assignments = RateMeter()
+        self.moe_local_assignments = RateMeter()
+        self.moe_absent_assignments = RateMeter()
+        self.experts_held: list[int] | None = None  # [first, count]
+        self.attn_blocks = 1  # attention blocks (cache rows) a layer
         self.latent_positions_valid = RateMeter()  # cached rows the served
-        # ticks needed, summed over layers: a tick at position p needs p
+        # ticks needed, summed over blocks: a tick at position p needs p
         self.latent_positions_read = RateMeter()  # rows the read fetched for
         # every slot of every tick run, needed or not
         self.output_capped = RateMeter()  # slots force-finished by a
@@ -492,8 +504,13 @@ class ServeMetrics:
                 "moe_assignments": self.moe_assignments.count,
                 "moe_experts_touched": self.moe_experts_touched.count,
                 "moe_expert_load": self.moe_expert_load.tolist(),
+                "moe_zero_assignments": self.moe_zero_assignments.count,
+                "moe_local_assignments": self.moe_local_assignments.count,
+                "moe_absent_assignments": self.moe_absent_assignments.count,
+                "experts_held": self.experts_held,
             },
             "latent_pool": {
+                "attn_blocks": self.attn_blocks,
                 "latent_positions_valid": self.latent_positions_valid.count,
                 "latent_positions_read": self.latent_positions_read.count,
             },
@@ -635,7 +652,7 @@ class ServeMetrics:
                 (f"{name}_total", "counter", value)
                 for section in ("expert_layer", "latent_pool")
                 for name, value in s[section].items()
-                if name != "moe_expert_load"
+                if name not in ("moe_expert_load", "experts_held", "attn_blocks")
             ),
             ("moe_expert_load_total", "counter", [
                 (format_labels(expert=str(e)), v)
@@ -714,15 +731,30 @@ def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg):
     return x, cache_k, cache_v
 
 
-def _count_routing(stats, routing, act):
-    """(experts touched, pairs by expert) with one expert layer's routing
-    [B, 1, top_k] of a tick added: a pair counts where the device holds
-    the slot active, an expert is touched where it got at least one."""
-    touched, load = stats
-    pairs = jnp.zeros_like(load).at[routing.reshape(-1)].add(
-        jnp.repeat(act, routing.shape[-1]).astype(load.dtype)
-    )
-    return touched + jnp.sum(pairs > 0), load + pairs
+def _count_routing(stats, routing, act, cfg):
+    """(experts touched, pairs by expert[, pairs by fate]) with one expert
+    layer's routing [B, 1, top_k] of a tick added: a pair counts where
+    the device holds the slot active, an expert is touched where it got at
+    least one. Where the layer has zero-compute experts or holds a share
+    (``stats`` then has a third member), the load is over the HELD
+    experts and the fates are (zero, local, absent)."""
+    touched, load, *fates = stats
+    flat = routing.reshape(-1)
+    live = jnp.repeat(act, routing.shape[-1]).astype(load.dtype)
+    if not fates:
+        pairs = jnp.zeros_like(load).at[flat].add(live)
+        return touched + jnp.sum(pairs > 0), load + pairs
+    first, count = cfg.held_experts
+    zero = flat >= cfg.n_experts
+    local = (flat >= first) & (flat < first + count)
+    pairs = jnp.zeros_like(load).at[
+        jnp.where(local, flat - first, count)
+    ].add(live, mode="drop")
+    fate = jnp.stack([
+        jnp.sum(live * zero), jnp.sum(live * local),
+        jnp.sum(live * ~(zero | local)),
+    ])
+    return touched + jnp.sum(pairs > 0), load + pairs, fates[0] + fate
 
 
 def _slot_layer_step_latent(x, layer, pool, l, pos_b, cfg):
@@ -731,12 +763,28 @@ def _slot_layer_step_latent(x, layer, pool, l, pos_b, cfg):
     latent beside the roped shared key, models/mla.py) is scattered into
     layer ``l`` in place, and the read is ABSORBED: the heads' queries meet
     the cached rows themselves, nothing is up-projected for the pool's
-    positions. Returns (x, pool, routing [B, 1, top_k] | None)."""
+    positions. The double layer (``attn_blocks`` 2) does so twice, block
+    ``i`` against pool row ``2l + i``, the pool ``[2L, ...]``. Returns (x,
+    pool, routing [B, 1, top_k] | None)."""
     from torchkafka_tpu.models import mla
 
+    rows = jnp.arange(pool.shape[1])
+    if cfg.attn_blocks == 2:
+        held = [pool]
+
+        def attend(i, h, blk):
+            q_nope, q_rope, latent = mla.project(h, blk, cfg, pos_b[:, None])
+            held[0] = held[0].at[2 * l + i, rows, pos_b].set(
+                latent[:, 0].astype(pool.dtype)
+            )
+            return mla.attend_absorbed(
+                q_nope, q_rope, held[0], 2 * l + i, pos_b, blk, cfg
+            )
+
+        x, routing = _double_layer(x, layer, cfg, attend)
+        return x, held[0], routing
     h = _rms_norm(x, layer["ln1"])
     q_nope, q_rope, latent = mla.project(h, layer, cfg, pos_b[:, None])
-    rows = jnp.arange(pool.shape[1])
     pool = pool.at[l, rows, pos_b].set(latent[:, 0].astype(pool.dtype))
     attn = mla.attend_absorbed(q_nope, q_rope, pool, l, pos_b, layer, cfg)
     x, routing = _attn_tail_routing(x, attn, layer, cfg)
@@ -1603,7 +1651,7 @@ class StreamingGenerator:
                         )
                         caches = (pool,)
                         if routing is not None:
-                            stats = _count_routing(stats, routing, act)
+                            stats = _count_routing(stats, routing, act, cfg)
                     elif kv_int8:
                         x, *caches = _slot_layer_step_q(
                             x, layer, *caches, l, pos, cfg,
@@ -1619,9 +1667,13 @@ class StreamingGenerator:
                 # then the expert layers (one group for every other config).
                 first = 0
                 for key, n, _expert_mlp in _layer_groups(cfg):
+                    xs, step = (params[key], jnp.arange(first, first + n)), body
+                    if cfg.attn_blocks == 2:
+                        # The blocks' tensors stay stacked: _double_scan.
+                        xs, layer_of = _double_scan(params[key], first)
+                        step = lambda c, s, f=layer_of: body(c, (f(s), s[1]))  # noqa: E731
                     (x, caches, stats), _ = lax.scan(
-                        body, (x, caches, stats),
-                        (params[key], jnp.arange(first, first + n)),
+                        step, (x, caches, stats), xs
                     )
                     first += n
                 x = _rms_norm(x, params["ln_f"])
@@ -1668,8 +1720,11 @@ class StreamingGenerator:
             # a config without one.
             stats0 = (
                 jnp.zeros((), jnp.int32),
-                jnp.zeros((cfg.n_experts,), jnp.int32),
+                jnp.zeros((cfg.held_experts[1],), jnp.int32),
             ) if cfg.routed_moe else ()
+            if cfg.moe_partial:
+                # Pairs by fate: zero expert, held here, on another chip.
+                stats0 += (jnp.zeros((3,), jnp.int32),)
             (caches, last_tok, pos, gen, done, n_out, stats), _ = lax.scan(
                 one, (caches, last_tok, pos, gen, done0, n0, stats0), None,
                 length=K,
@@ -1738,10 +1793,16 @@ class StreamingGenerator:
             _resume = jax.jit(resume_admit, donate_argnums=(1,))
             self._resume_exec = lambda *a: _resume(self._params, *a)
         if latent:
-            self._caches = (jnp.zeros((nl, B, M, cfg.latent_dim), cfg.dtype),)
-            self.metrics.moe_expert_load = np.zeros(
-                (cfg.n_experts if cfg.routed_moe else 0,), np.int64
+            # A row an attention block: [2L, ...] for the double layer.
+            self._caches = (
+                jnp.zeros((cfg.cache_layers, B, M, cfg.latent_dim), cfg.dtype),
             )
+            self.metrics.moe_expert_load = np.zeros(
+                (cfg.held_experts[1] if cfg.routed_moe else 0,), np.int64
+            )
+            self.metrics.attn_blocks = cfg.attn_blocks
+            if cfg.routed_moe:
+                self.metrics.experts_held = list(cfg.held_experts)
         elif kv_int8 and kv_kernel:
             # K-major pool for the Pallas read (see _slot_layer_step_q).
             self._caches = (
@@ -3688,9 +3749,16 @@ class StreamingGenerator:
                     (done, n_out, gen, pos, self._tick_stats)
                 )
             if stats_h is not None:
-                touched, load = stats_h
+                touched, load, *fates = stats_h
                 self.metrics.moe_experts_touched.add(int(touched))
                 self.metrics.moe_expert_load += load
+                zero, local, absent = (
+                    int(n) for n in (fates[0] if fates else (0, load.sum(), 0))
+                )
+                self.metrics.moe_zero_assignments.add(zero)
+                self.metrics.moe_local_assignments.add(local)
+                self.metrics.moe_absent_assignments.add(absent)
+                self.metrics.moe_assignments.add(zero + local + absent)
             self.metrics.tick_time.observe(time.perf_counter() - tick_t0)
             crash_hook("mid_tick")
             with xprof.span(xprof.SPAN_RETIRE):
@@ -3778,17 +3846,12 @@ class StreamingGenerator:
         self.metrics.slot_ticks_run.add(self._slots * self._ticks_per_sync)
         self.metrics.slot_ticks_served.add(decoded - first_tokens)
         if self._cfg.is_mla:
-            n_layers = self._cfg.n_layers
-            self.metrics.latent_positions_valid.add(rows_needed * n_layers)
+            blocks = self._cfg.cache_layers
+            self.metrics.latent_positions_valid.add(rows_needed * blocks)
             # The XLA read fetches the whole slab of every slot, every tick.
             self.metrics.latent_positions_read.add(
-                n_layers * self._slots * self._ticks_per_sync * self._max_len
+                blocks * self._slots * self._ticks_per_sync * self._max_len
             )
-            if self._cfg.routed_moe:
-                self.metrics.moe_assignments.add(
-                    (decoded - first_tokens) * self._cfg.expert_top_k
-                    * (n_layers - self._cfg.first_dense_layers)
-                )
         if journal_dirty:
             # Synchronous at the cadence point: the whole point is
             # that a SIGKILL one instruction later finds these tokens
