@@ -1,0 +1,163 @@
+"""Rehearsal compiles for the described v5e of the two training steps the
+benchmark runs (``internlm2-1.8b`` on 2x2, its 20-layer cut on one chip),
+held to what ``cfg.remat`` keeps since PR 32: ONE flash forward kernel a
+layer (three Pallas calls in the step: forward, dq, dkv; the recompute's
+second forward is gone), the kept output and log-sum-exp stacked by an
+in-place write of one layer's slice, and a program that fits its chip.
+Nothing runs, so no number here is a measurement.
+
+A file of its own because ``tests/chipbench/`` belongs to the accepted
+benchmark and is not edited: the topology is described inside a fixture,
+never at import, and where this worker cannot load the TPU's library
+(another file's worker holds it and ``ALLOW_MULTIPLE_LIBTPU_LOAD`` is not
+set) the tests skip.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO))
+
+GIB = 2**30
+HBM_BYTES = 15.75 * GIB  # what the v5e compiler allows a program
+# configuration, rows a step, the kept stacks as one device holds them:
+# [L, B·H, S, D], the bf16 output's bits, and [L, B·H, S, 1] in f32.
+CELLS = {
+    "one_chip": ("internlm2-1.8b-1chip", 2, r"u16\[20,32,4096,128\]", r"f32\[20,32,4096,1\]"),
+    "2x2": ("internlm2-1.8b", 4, r"u16\[24,16,4096,128\]", r"f32\[24,16,4096,1\]"),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module", params=list(CELLS))
+def step(request, topo):
+    """(cell, the step program compiled for the described chips)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import torchkafka_tpu as tk
+    from chipbench.models import dense_decoder as model
+    from torchkafka_tpu.models import make_train_step
+    from torchkafka_tpu.models.transformer import (
+        batch_spec, init_params, opt_shardings_like, param_specs,
+        shardings_for_mesh,
+    )
+
+    name, rows, _, _ = CELLS[request.param]
+    conf = json.loads((REPO / "chipbench/configs" / f"{name}.json").read_text())
+    honest = jax.default_backend
+    jax.default_backend = lambda: "tpu"  # flash compiles, not interprets
+    try:
+        axes = conf["deployment"]["mesh"]
+        chips = int(np.prod(list(axes.values())))
+        mesh = tk.make_mesh(axes, devices=list(topo.devices)[:chips])
+        cfg = model.program_config(conf, 4096, remat=conf["deployment"]["remat"])
+        assert cfg.remat
+        opt = model.optimizer(conf)
+        _init_fn, step_fn = make_train_step(cfg, mesh, opt)
+        p_sh = shardings_for_mesh(mesh, param_specs(cfg))
+
+        def init(rng):
+            p = init_params(rng, cfg)
+            return p, opt.init(p)
+
+        p_shapes, o_shapes = jax.eval_shape(init, jax.random.key(0))
+        o_sh = opt_shardings_like(
+            o_shapes, p_shapes, p_sh, NamedSharding(mesh, P())
+        )
+
+        def sds(s, sh):
+            return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
+
+        tokens = jax.ShapeDtypeStruct(
+            (rows, 4096), jnp.int32,
+            sharding=NamedSharding(mesh, batch_spec(mesh)),
+        )
+        compiled = step_fn.lower(
+            jax.tree.map(sds, p_shapes, p_sh),
+            jax.tree.map(sds, o_shapes, o_sh), tokens, tokens,
+        ).compile()
+        return request.param, compiled
+    finally:
+        jax.default_backend = honest
+
+
+def opcodes_of(text: str, shape: str) -> set[str]:
+    """The opcodes of every operation whose result has ``shape``, inside
+    fused computations too."""
+    found = set()
+    for line in text.split("\n"):
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([a-z\-]+)\(", line)
+        if m and re.match(shape, m.group(1)):
+            found.add(m.group(2))
+    return found
+
+
+def test_one_forward_kernel_a_layer(step):
+    cell, compiled = step
+    text = compiled.as_text()
+    # tk_flash_fwd in the forward loop, tk_flash_bwd_dq and _dkv in the
+    # backward loop: the bare checkpoint held a fourth, the forward again.
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("tk_flash_fwd", "tk_flash_bwd_dq", "tk_flash_bwd_dkv"):
+        assert kernel in text
+    assert ("all-reduce(" in text) == (cell == "2x2")
+
+
+def test_the_kept_stacks_are_written_in_place(step):
+    cell, compiled = step
+    text = compiled.as_text()
+    for shape in CELLS[cell][2:]:
+        ops = opcodes_of(text, shape)
+        # One layer's slice goes into the stack by dynamic-update-slice and
+        # comes out by a dynamic-slice fused into its reader: no operation
+        # makes a second stack.
+        assert "dynamic-update-slice" in ops
+        assert ops <= {  # no copy, no transpose, no concatenate
+            "custom-call",  # AllocateBuffer, once, before the forward loop
+            "dynamic-update-slice", "fusion", "get-tuple-element",
+            "parameter", "bitcast",
+        }, ops
+
+
+def test_the_step_fits_its_chips(step, capsys):
+    cell, compiled = step
+    m = compiled.memory_analysis()
+    footprint = (
+        m.argument_size_in_bytes + m.temp_size_in_bytes
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    )
+    with capsys.disabled():
+        print(
+            f"\n{cell}: arguments {m.argument_size_in_bytes / GIB:.3f} GiB, "
+            f"temporaries {m.temp_size_in_bytes / GIB:.3f} GiB, "
+            f"footprint {footprint / GIB:.3f} GiB of {HBM_BYTES / GIB:.2f}; "
+            f"peak {m.peak_memory_in_bytes / GIB:.3f} GiB"
+        )
+    # The sum the benchmark's own compile test holds (it counts every
+    # stack the forward loop hands the backward loop twice, PERF.md §6),
+    # and the peak the compiler itself holds against the chip.
+    assert footprint < HBM_BYTES
+    assert m.peak_memory_in_bytes < footprint

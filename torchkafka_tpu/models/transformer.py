@@ -24,9 +24,16 @@ Design choices, all for the TPU/XLA compilation model:
   attention (torchkafka_tpu.ops.attention) so no device ever materialises
   the full sequence. RoPE/norms/MLP are elementwise-in-sequence and need no
   communication.
-- **Remat.** ``cfg.remat`` wraps the scanned layer body in
-  ``jax.checkpoint``, trading recompute for HBM — the standard long-context
-  lever.
+- **Remat.** ``cfg.remat`` wraps the scanned layer body (and gpipe's
+  ``layer_fn``) in ``jax.checkpoint``, trading recompute for HBM — the
+  standard long-context lever. The backward pass recomputes a layer from its
+  input, with one exception (``_remat_layer``): where the layer runs the
+  flash kernel, its ``[B·H, S, D]`` output and ``[B·H, S, 1]`` log-sum-exp
+  are KEPT (the two names ``ops/flash.py`` gives them), so the forward kernel
+  runs once a layer and not again in the recompute. That costs
+  ``2·B·S·H·D + 4·B·S·H`` bytes a layer in bf16, as much again as the layer
+  input remat already keeps where ``H·D = d_model``. A layer that runs no
+  flash kernel (dense fallback, ring/ulysses) names nothing: nothing is kept.
 """
 
 from __future__ import annotations
@@ -775,6 +782,16 @@ def _dense_mlp(h: jax.Array, layer: Mapping[str, jax.Array], cfg) -> jax.Array:
     return jnp.einsum("bsf,fd->bsd", gate * up, load_weight(layer["w_down"], cfg.dtype))
 
 
+def _remat_layer(fn: Callable) -> Callable:
+    """``cfg.remat``'s ``jax.checkpoint`` of one layer (module docstring,
+    "Remat."): everything is recomputed but what ``ops/flash.py`` names."""
+    from torchkafka_tpu.ops.flash import REMAT_SAVED
+
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED)
+    )
+
+
 def _double_scan(group: Mapping[str, jax.Array], first: int = 0):
     """What a ``lax.scan`` over the double layers of a stacked group
     runs on: ``(xs, layer_of)``. ``xs`` are the router and its bias
@@ -1074,7 +1091,7 @@ class Transformer:
                 )
             layer_fn = lambda a, layer: self._layer(a, layer)  # noqa: E731
             if cfg.remat:
-                layer_fn = jax.checkpoint(layer_fn)
+                layer_fn = _remat_layer(layer_fn)
             x, stats = gpipe(
                 layer_fn, params["layers"], x,
                 mesh=self.mesh, axis="pp", microbatches=cfg.pp_microbatches,
@@ -1088,7 +1105,7 @@ class Transformer:
                 return x, stats
 
             if cfg.remat:
-                body = jax.checkpoint(body)
+                body = _remat_layer(body)
             unroll = cfg.scan_unroll
             if unroll is None:
                 # Auto-unroll only when no mesh axis shards the WEIGHTS.

@@ -25,6 +25,17 @@ runs two Pallas kernels that recompute probabilities per tile from lse:
 
 so ``jax.grad`` through ``flash_attention`` allocates O(S·D), never O(S²).
 
+Under ``jax.checkpoint``: the forward rule names the two residuals that only
+the forward kernel can rebuild, the [B·H, S, D] output ``"tk_flash_out"`` and
+the log-sum-exp ``"tk_flash_lse"`` (``REMAT_SAVED``, ``checkpoint_name``). A
+policy that saves those names (``models/transformer.py::_remat_layer``) keeps
+them, 2·B·S·H·D + 4·B·S·H bytes a call in bf16, and the backward pass runs the
+dq and dkv kernels on them without a second forward kernel; q, k and v are
+recomputed from the layer's input as before. Without such a policy the names
+do nothing, and the primal functions (``flash_attention``'s own body,
+``flash_forward``) carry none: a program that is not differentiated is
+unchanged.
+
 Per-row vectors (lse, delta) are carried as [BH, S, 1] arrays with
 (1, block_q, 1) blocks: Mosaic accepts a minor block dim equal to the array
 dim, and the kernels get natural [block_q, 1] columns that broadcast against
@@ -40,12 +51,16 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from torchkafka_tpu.ops.attention import mha
 
 _NEG_INF = -1e30
+
+# The names ``_flash_fwd`` gives its output and its log-sum-exp (header).
+REMAT_SAVED = ("tk_flash_out", "tk_flash_lse")
 
 
 # ------------------------------------------------------------------ forward
@@ -512,6 +527,19 @@ def _flash_impl(q, k, v, causal, block_q, block_k, interpret):
     return _from_bhsd(out, b, h)
 
 
+def _name_bits(x, name):
+    """``checkpoint_name`` on an unsigned view of ``x``'s bits. A floating
+    residual that the forward pass goes on to use (the output is: the layer's
+    ``wo`` product reads it) is passed by ``jax.checkpoint`` through a
+    ``reduce_precision`` to its own precision: nothing to a kernel's bf16
+    output, but XLA keeps it as a pass over the tensor (4.9 ms of a 757 ms
+    step, PERF.md §6, PR 32). An integer residual is exempt, and the two
+    bitcasts fuse into their readers."""
+    bits = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    named = checkpoint_name(jax.lax.bitcast_convert_type(x, bits), name)
+    return jax.lax.bitcast_convert_type(named, x.dtype)
+
+
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
     _check_heads(q, k)
     b, s, h, d = q.shape
@@ -525,6 +553,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
         causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
         n_q_heads=h, n_kv_heads=k.shape[2],
     )
+    out = _name_bits(out, REMAT_SAVED[0])
+    lse = checkpoint_name(lse, REMAT_SAVED[1])
     return _from_bhsd(out, b, h), (q, k, v, out, lse)
 
 
