@@ -508,6 +508,85 @@ def run_serve_share(tk, sz: Sizes, cache) -> None:
     })
 
 
+def run_serve_kinds(tk, sz: Sizes, cache) -> None:
+    """Kinds of layer on the normal path at toy size (heads of 128, wider
+    than ``d_model / n_heads``, so the chip compiles the windowed flash
+    call at them): three sliding-window layers to one full YaRN layer over
+    the pool by kind, the routed expert layer beside grouped-query
+    attention, served until every ring has wrapped more than twice and
+    held token for token against the full forward's greedy choices
+    (float32 at the highest matmul precision, so a near-tie cannot flip)."""
+    import jax
+    import jax.numpy as jnp
+
+    from torchkafka_tpu.models import Transformer, TransformerConfig
+    from torchkafka_tpu.models.transformer import RopeKind, init_params
+    from torchkafka_tpu.serve import StreamingGenerator
+
+    window = 16 if sz.serve_scale else 4
+    prompt_len, max_new, records = sz.prompt_len, 3 * window + 4, sz.pool_records
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=256, n_layers=8, n_heads=4, n_kv_heads=2,
+        d_ff=512, max_seq_len=prompt_len + max_new, dtype=jnp.float32,
+        param_dtype=jnp.float32, rope_theta=500000.0, stated_head_dim=128,
+        sliding_window=window, window_pattern=(True, True, True, False),
+        rope_full=RopeKind(
+            500000.0, factor=16.0, original_len=64, attention_factor=1.2773,
+        ),
+        n_experts=8, expert_top_k=2, expert_d_ff=128,
+    )
+    group = "smoke-kinds"
+    with jax.default_matmul_precision("highest"):
+        params = init_params(jax.random.key(3), cfg)
+        broker = tk.InMemoryBroker()
+        broker.create_topic("prompts", partitions=2)
+        rng = np.random.default_rng(4)
+        prompts = rng.integers(1, cfg.vocab_size, (records, prompt_len), dtype=np.int32)
+        sent = {}
+        for i, prompt in enumerate(prompts):
+            r = broker.produce("prompts", prompt.tobytes(), partition=i % 2)
+            sent[(r.partition, r.offset)] = i
+        consumer = tk.MemoryConsumer(broker, "prompts", group_id=group)
+        server = StreamingGenerator(
+            consumer, params, cfg, slots=sz.pool_slots, prompt_len=prompt_len,
+            max_new=max_new, commit_every=16,
+        )
+        rows = np.zeros((records, prompt_len + max_new), np.int32)
+        rows[:, :prompt_len] = prompts
+        for rec, toks in server.run(max_records=records):
+            rows[sent[(rec.partition, rec.offset)], prompt_len:] = toks
+        summary = server.metrics.summary()
+        server.close()
+        consumer.close()
+        logits = jax.jit(Transformer(cfg).__call__)(params, jnp.asarray(rows))
+        greedy = np.asarray(jnp.argmax(logits[:, prompt_len - 1: -1], axis=-1))
+    agree = float((greedy == rows[:, prompt_len:]).mean())
+    pool, experts = summary["kv_pool"], summary["expert_layer"]
+    _require(
+        summary["kv_backend"]["layout"] == "by_kind"
+        and (pool["window"], pool["window_layers"], pool["full_layers"])
+        == (window, 6, 2),
+        f"{group}: {summary['kv_backend']}, {pool}",
+    )
+    _require(
+        agree == 1.0,
+        f"{group}: {agree:.4f} of the served tokens are the full forward's "
+        f"greedy choices after {max_new / window:.1f} wraps of the ring",
+    )
+    _require(
+        experts["moe_assignments"] == sum(experts["moe_expert_load"]) > 0,
+        f"{group}: {experts}",
+    )
+    _report("serve.layer_kinds", {
+        "completions": records, "tokens": records * max_new,
+        "ring_wraps": round(max_new / window, 1), "greedy_agreement": agree,
+        "window_positions_valid": pool["window_positions_valid"],
+        "full_positions_valid": pool["full_positions_valid"],
+        "moe_assignments": experts["moe_assignments"],
+        **cache.take(), "peak_bytes_in_use": _peak_bytes(),
+    })
+
+
 # -------------------------------------------------------------------- train
 
 
@@ -678,6 +757,8 @@ def main(argv: list[str] | None = None) -> int:
     run_serve(tk, sz, serve_mesh, cache)
     gc.collect()
     run_serve_share(tk, sz, cache)
+    gc.collect()
+    run_serve_kinds(tk, sz, cache)
     gc.collect()
     run_train(tk, sz, train_mesh, cache)
     _report("done", {"total_s": round(time.perf_counter() - t0, 1)})

@@ -342,7 +342,9 @@ class TestRoutedExpertLayer:
                 lambda *a, _h=honest, _n=name: called.append(_n) or _h(*a),
             )
         layer = _routed_layer(rng)
-        for rows in (4, 64):  # 12 pairs over 8 experts; 192 pairs
+        # 12 pairs over 8 experts; 384 pairs, 48 an expert (the grouped
+        # form takes 32 an expert and more)
+        for rows in (4, 128):
             moe.routed_moe_mlp(
                 jnp.zeros((1, rows, 32), jnp.float32), layer, ROUTED_CFG
             )

@@ -1269,29 +1269,34 @@ def admit_case(request):
     slot otherwise), its pool mid-generation in every slot at ragged
     positions, and the masked merge compiled beside it."""
     from torchkafka_tpu import serve
+    from torchkafka_tpu.ops import moe
 
     variant = request.param
     kw = {"temperature": 0.8, "top_k": 8} if variant in _SAMPLED_VARIANTS else {}
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(serve, "_ADMIT_CHUNK_TOKENS", 2 * P)
-        # Four experts: the merge's 4 rows and a chunk's 2 both average 8
-        # pairs an expert or more, so both take the routed layer's grouped
-        # form (ops/moe.py), as both do at the sizes that are served.
+        # The routed layer's grouped form for the merge's 4 rows and a
+        # chunk's 2 alike (ops/moe.py picks it by the pairs an expert can
+        # expect, which toy shapes never reach), as both take it at the
+        # sizes that are served: it multiplies row by row, so the two
+        # admissions can be held to the bit; the all-experts einsum rounds
+        # by its row count on the CPU.
+        mp.setattr(moe, "_GROUPED_MIN_PAIRS_PER_EXPERT", 0)
         srv, consumer = _tick_server(variant, latent_over={"n_experts": 4}, **kw)
-    assert srv._admit_chunk_rows == 2
-    B = 4
-    rng = np.random.default_rng(5)
-    keys = jnp.asarray(
-        rng.integers(0, 2**32, srv._slot_keys.shape, dtype=np.uint32)
-    )
-    state = srv._admit_fn(
-        srv._caches, srv._last_tok, srv._pos, srv._gen,
-        jnp.asarray(rng.integers(0, VOCAB, (B, P)), jnp.int32),
-        jnp.ones((B,), bool), keys,
-    )
-    state = jax.jit(srv._tick_block_raw)(
-        srv._params, *state, jnp.asarray([True, False, True, True]), keys,
-    )[:4]
+        assert srv._admit_chunk_rows == 2
+        B = 4
+        rng = np.random.default_rng(5)
+        keys = jnp.asarray(
+            rng.integers(0, 2**32, srv._slot_keys.shape, dtype=np.uint32)
+        )
+        state = srv._admit_fn(
+            srv._caches, srv._last_tok, srv._pos, srv._gen,
+            jnp.asarray(rng.integers(0, VOCAB, (B, P)), jnp.int32),
+            jnp.ones((B,), bool), keys,
+        )
+        state = jax.jit(srv._tick_block_raw)(
+            srv._params, *state, jnp.asarray([True, False, True, True]), keys,
+        )[:4]
     shardings = jax.tree.map(lambda a: a.sharding, state)
     before = jax.tree.map(np.asarray, state)
     ref = jax.jit(lambda *a: _ref_admit(srv, *a))
@@ -1326,7 +1331,7 @@ def _assert_admission(srv, got, want, before, mask, exact=True):
 
 
 @pytest.mark.parametrize("admitted", list(_ADMIT_MASKS))
-def test_chunked_admission_equals_masked_merge(admit_case, admitted):
+def test_chunked_admission_equals_masked_merge(admit_case, admitted, monkeypatch):
     """Identity: ``last_tok``, ``pos``, ``gen`` and every admitted slot's
     pool rows [0, P) are bit-identical to the masked merge's; every other
     slot's rows, and an admitted slot's rows past its prompt window, are
@@ -1338,6 +1343,10 @@ def test_chunked_admission_equals_masked_merge(admit_case, admitted):
     (the last bit of a row's attention, whatever the chunk holds). That the
     two admissions are the same arithmetic is held op by op instead, with
     the compiler out of the way, on the mask whose last chunk is padded."""
+    from torchkafka_tpu.ops import moe
+
+    # As ``admit_case`` built it: the merge and the op-by-op pass trace here.
+    monkeypatch.setattr(moe, "_GROUPED_MIN_PAIRS_PER_EXPERT", 0)
     srv, keys, before, shardings, ref = admit_case
     mask = np.asarray(_ADMIT_MASKS[admitted])
     prompts = jnp.asarray(

@@ -58,9 +58,14 @@ class KVBackend:
     """The resolved serving-cache configuration — what actually serves.
 
     ``layout``: "dense" (per-slot pool), "paged" (block pool + per-
-    slot tables) or "latent" (a latent-attention config's per-slot pool:
+    slot tables), "latent" (a latent-attention config's per-slot pool:
     ONE tensor [L, B, M, rank + rope] in the compute dtype, read
-    absorbed). ``int8``: quantized payloads + group-wise scales.
+    absorbed) or "by_kind" (a config with kinds of layer,
+    ``window_pattern``: per-slot pools allocated by kind in the compute
+    dtype, the full layers' K and V [Lf, B, M, K * Dh] and the window
+    layers' rings [Lw, B, sliding_window, K * Dh], a position's kv heads
+    side by side in one row). ``int8``: quantized payloads + group-wise
+    scales.
     ``kernel``: the Pallas fill-bounded read engages on decode ticks.
     ``kernel_disabled_reason``: why it does NOT engage (None when it
     does, or when int8 was never requested — there is no kernel
@@ -177,6 +182,42 @@ def _resolve_latent(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
     )
 
 
+def _resolve_by_kind(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
+    """The pool of a config with kinds of layer: what is built, and a
+    reasoned refusal of every combination that is not."""
+    what = "the slot pool by layer kind (window_pattern)"
+    if kv_dtype == "int8":
+        raise ValueError(
+            f"{what} is compute-dtype only: kv_dtype='int8' comes with "
+            "the dyn-len read, which bounds a slot's rows from above "
+            "(its fill) and has no lower bound or ring order to read a "
+            "window layer by"
+        )
+    if kv_pages is not None:
+        raise ValueError(
+            f"{what} is two dense per-slot pools: kv_pages (block tables, "
+            "the radix prefix cache, its host tier and the prefill "
+            "hand-off cut from them) address ONE pool in which every "
+            "layer holds every position, and a window layer holds a ring"
+        )
+    if mesh is not None and mesh.size > 1:
+        raise ValueError(
+            f"{what} serves on one device: no sharded layout has been "
+            "taught the two pools, and the routed expert layer holds "
+            "every expert (no exchange across chips is built)"
+        )
+    if kv_kernel is True:
+        raise ValueError(
+            f"{what} is read by XLA: the Pallas reads take an int8 pool "
+            "of whole contexts, and kv_kernel=True never falls back "
+            "silently"
+        )
+    return KVBackend(
+        layout="by_kind", int8=False, kernel=False,
+        kernel_disabled_reason=None, chunked=False, data=1, tp=1,
+    )
+
+
 def _mesh_kernel_reason(cfg, mesh, slots: int) -> str | None:
     """None = the shard_map wrapping works on this mesh; else why not.
 
@@ -227,6 +268,11 @@ def resolve_kv_backend(
         )
     if getattr(cfg, "is_mla", False):
         return _resolve_latent(
+            cfg, mesh=mesh, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
+            kv_pages=kv_pages,
+        )
+    if getattr(cfg, "window_pattern", ()):
+        return _resolve_by_kind(
             cfg, mesh=mesh, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
             kv_pages=kv_pages,
         )

@@ -46,6 +46,7 @@ from torchkafka_tpu.models.transformer import (
     _rms_norm,
     _rope,
     param_specs,
+    scan_periods,
     shardings_for_mesh,
 )
 
@@ -53,6 +54,34 @@ from torchkafka_tpu.models.transformer import (
 class KVCache(NamedTuple):
     k: jax.Array  # [L, B, max_len, K, Dh]
     v: jax.Array  # [L, B, max_len, K, Dh]
+
+
+class KindKVCache(NamedTuple):
+    """The cache of a config with kinds of layer (``window_pattern``),
+    allocated by kind: the full layers hold every position, a window layer
+    a RING of ``sliding_window`` rows, position p at row ``p mod window``
+    (keys are cached roped, so a ring's order does not matter to a read).
+    Layer ``j`` of period ``i`` is row ``i * count + rank`` of its kind's
+    tensors (``TransformerConfig.kind_rank``). A position's kv heads lie
+    side by side in ONE row of ``K * Dh`` columns, so the decode read is
+    two matrix products a layer against the slab in place
+    (``_attend_merged``)."""
+
+    k_full: jax.Array  # [Lf, B, max_len, K * Dh]
+    v_full: jax.Array
+    k_win: jax.Array  # [Lw, B, window, K * Dh]
+    v_win: jax.Array
+
+
+def ring_rows(rows: jax.Array, window: int) -> jax.Array:
+    """A window layer's rows over positions [0, S), [L, B, S, C], as its
+    ring holds them once S positions are written: the last ``window`` of
+    them, position p at row ``p mod window`` (S is static, so this is two
+    static slices); rows no position has reached yet are zero."""
+    s = rows.shape[2]
+    if s <= window:
+        return jnp.pad(rows, ((0, 0), (0, 0), (0, window - s), (0, 0)))
+    return jnp.roll(rows[:, :, s - window:], (s - window) % window, axis=2)
 
 
 # --------------------------------------------------------------- sampling
@@ -255,7 +284,7 @@ def _constrain_cache(cache: KVCache, mesh: Mesh | None) -> KVCache:
 
 def _attend_cached(
     x, q, cache_k, cache_v, valid, layer, cfg,
-    k_scale=None, v_scale=None,
+    k_scale=None, v_scale=None, routing=False,
 ):
     """Shared decode tail: grouped-query attention over the kv cache,
     masked softmax, output projection and the MLP residual. x: [B, S, D];
@@ -312,7 +341,44 @@ def _attend_cached(
         "bkrsm,bmke->bskre", probs.astype(cfg.dtype), vv,
         preferred_element_type=jnp.float32,
     ).astype(cfg.dtype).reshape(b, s, h, dh)
+    if routing:  # (x, the routed expert layer's choices or None)
+        return _attn_tail_routing(x, attn, layer, cfg)
     return _attn_tail(x, attn, layer, cfg)
+
+
+def _attend_merged(x, q, slab_k, slab_v, valid, layer, cfg):
+    """``_attend_cached`` for ONE query a slot against slabs whose rows
+    hold a position's kv heads side by side, [B, M, K * Dh] (a pool by
+    layer kind, ``KindKVCache``): the queries are laid block-diagonal,
+    head h in the columns of its kv head and zero elsewhere, so scores
+    and values are two plain matrix products a layer that read the slab
+    where it lies (``mla.attend_absorbed``'s shape). The products cost K
+    times the FLOPs of the grouped form and move the same bytes; the
+    grouped einsum over [B, M, K, Dh] has the compiler copy a layer's
+    slab out of the pool and re-tile it every tick (PERF.md, PR 34).
+    x: [B, 1, D]; q: [B, 1, H, Dh]; valid: [B, M]. Returns (x, the routed
+    expert layer's choices or None)."""
+    b, _s, h, dh = q.shape
+    n_kv = cfg.n_kv_heads
+    own = jnp.arange(h)[:, None] // (h // n_kv) == jnp.arange(n_kv)[None, :]
+    q_wide = jnp.where(
+        own[None, :, :, None], q[:, 0, :, None, :], 0
+    ).reshape(b, h, n_kv * dh)
+    scores = jnp.einsum(
+        "bhc,bmc->bhm", q_wide, slab_k.astype(cfg.dtype),
+        preferred_element_type=jnp.float32,
+    ) / jnp.sqrt(jnp.float32(dh))
+    probs = jax.nn.softmax(
+        jnp.where(valid[:, None, :], scores, -1e30), axis=-1
+    )
+    wide = jnp.einsum(
+        "bhm,bmc->bhc", probs.astype(cfg.dtype), slab_v.astype(cfg.dtype),
+        preferred_element_type=jnp.float32,
+    ).reshape(b, h, n_kv, dh)
+    attn = jnp.sum(
+        jnp.where(own[None, :, :, None], wide, 0.0), axis=2
+    ).astype(cfg.dtype)[:, None]
+    return _attn_tail_routing(x, attn, layer, cfg)
 
 
 def _attn_tail(x, attn, layer, cfg):
@@ -326,7 +392,7 @@ def _attn_tail(x, attn, layer, cfg):
 
 def _attn_tail_routing(x, attn, layer, cfg):
     """``_attn_tail`` and the expert choices it made: (x, routing [B, S,
-    top_k] for a sigmoid-routed expert layer, else None)."""
+    top_k] for a routed expert layer (ops/moe.py), else None)."""
     x = x + jnp.einsum("bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype))
     h = _rms_norm(x, layer["ln2"])
     if "router" not in layer:
@@ -402,6 +468,8 @@ def prefill(
     if cfg.is_mla:
         logits, latents, _routing = latent_forward(params, model, tokens)
         return logits, latents
+    if cfg.window_pattern:
+        return _prefill_kinds(params, model, tokens, max_len)
     if mesh is not None:
         tokens = lax.with_sharding_constraint(
             tokens, slot_sharding(mesh, tokens.ndim)
@@ -431,6 +499,49 @@ def prefill(
     cache_k = lax.dynamic_update_slice(cache_k, ks.astype(cfg.dtype), (0, 0, 0, 0, 0))
     cache_v = lax.dynamic_update_slice(cache_v, vs.astype(cfg.dtype), (0, 0, 0, 0, 0))
     return logits, _constrain_cache(KVCache(cache_k, cache_v), mesh)
+
+
+def _prefill_kinds(params, model: Transformer, tokens: jax.Array, max_len: int):
+    """``prefill`` for a config with kinds of layer: (last-position logits
+    [B, V], ``KindKVCache`` with the full layers' [0, S) filled and each
+    window layer's ring holding its last ``sliding_window`` positions)."""
+    cfg = model.cfg
+    batch, seq = tokens.shape
+    x = embed_rows(params["embed"], tokens, cfg.dtype)
+    positions = jnp.arange(seq)
+
+    def capture(x, layer, j, _i):
+        # Transformer._layer's k and v once more, beside it (as ``prefill``).
+        kind = cfg.layer_kind(j)
+        h = _rms_norm(x, layer["ln1"])
+        k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
+        v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
+        x, _stats = model._layer(x, layer, kind)
+        return x, (_rope(k, positions, kind[1]), v)
+
+    x, kv = scan_periods(cfg, params["layers"], x, capture)
+    x = _rms_norm(x, params["ln_f"])
+    logits = jnp.einsum(
+        "bd,dv->bv", x[:, -1], load_weight(params["lm_head"], cfg.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+    def pool(window: bool, t: int):
+        """One kind's k (t 0) or v (t 1): [periods, count, B, S, K, Dh]
+        as [L, B, S, K * Dh]."""
+        js = [j for j, w in enumerate(cfg.window_pattern) if w == window]
+        width = cfg.n_kv_heads * cfg.head_dim
+        if not js:
+            return jnp.zeros((0, batch, seq, width), cfg.dtype)
+        rows = jnp.stack([kv[j][t] for j in js], axis=1)
+        return rows.reshape(-1, batch, seq, width).astype(cfg.dtype)
+
+    grow = ((0, 0), (0, 0), (0, max_len - seq), (0, 0))
+    return logits, KindKVCache(
+        jnp.pad(pool(False, 0), grow), jnp.pad(pool(False, 1), grow),
+        ring_rows(pool(True, 0), cfg.sliding_window),
+        ring_rows(pool(True, 1), cfg.sliding_window),
+    )
 
 
 def latent_forward(params, model: Transformer, tokens: jax.Array):

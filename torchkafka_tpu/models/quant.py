@@ -95,12 +95,14 @@ _MOE_AXES = {
 def quantize_params(params: dict, cfg) -> dict:
     """Post-training int8 of every matmul/embedding weight; norms and the
     MoE router (tiny, routing-sensitive) stay in their original dtype."""
-    if getattr(cfg, "is_mla", False):
+    if hasattr(cfg, "is_mla"):
         from torchkafka_tpu.models.transformer import _arch_refusal
 
-        raise ValueError(_arch_refusal(
+        why = _arch_refusal(
             cfg, "quantize_params (int8 weights, the experts' among them)"
-        ))
+        )
+        if why:
+            raise ValueError(why)
     layer_axes = dict(_LAYER_AXES)
     if cfg.is_moe:
         layer_axes.update(_MOE_AXES)

@@ -59,6 +59,47 @@ from torchkafka_tpu.ops.xent import dense_softmax_xent, fused_softmax_xent
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeKind:
+    """How one KIND of layer rotates its queries and keys: ``theta`` alone
+    is the plain rotary embedding; ``factor`` > 1 is YaRN (the pairs that
+    turn fewer than ``beta_slow`` times in ``original_len`` positions are
+    slowed ``factor``-fold, those that turn more than ``beta_fast`` times
+    are left, a linear ramp between; cos and sin are both multiplied by
+    ``attention_factor``). What a checkpoint's config states, a kind."""
+
+    theta: float
+    factor: float = 1.0
+    original_len: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def correction_range(self, dim: int) -> tuple[int, int]:
+        """(low, high): the pair indices YaRN's ramp runs between."""
+        def pair_of(turns: float) -> float:
+            return dim * math.log(
+                self.original_len / (turns * 2 * math.pi)
+            ) / (2 * math.log(self.theta))
+
+        low = max(math.floor(pair_of(self.beta_fast)), 0)
+        high = min(math.ceil(pair_of(self.beta_slow)), dim - 1)
+        return low, high
+
+    def inv_freq(self, dim: int) -> np.ndarray:
+        """float32 [dim // 2]: the angle a pair turns a position."""
+        plain = self.theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        if self.factor == 1.0:
+            return plain.astype(np.float32)
+        low, high = self.correction_range(dim)
+        ramp = np.clip(
+            (np.arange(dim // 2) - low) / max(high - low, 0.001), 0.0, 1.0
+        )
+        return (plain * (1 - ramp) + plain / self.factor * ramp).astype(
+            np.float32
+        )
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32_000
     d_model: int = 512
@@ -179,10 +220,49 @@ class TransformerConfig:
     # top-k; a pair that chose an absent expert adds nothing HERE: the
     # layer's output is this chip's part of the sum. None: every expert.
     experts_held: tuple[int, int] | None = None
+    # A grouped-query head's width where the checkpoint states one that is
+    # not ``d_model // n_heads`` (``wq`` then maps d_model to ``n_heads *
+    # head_dim`` and ``wo`` back). 0: ``d_model // n_heads``.
+    stated_head_dim: int = 0
+    # Two KINDS of layer in one model: ``window_pattern`` says which layers
+    # of a period are sliding-window layers (a query at i sees the keys j
+    # with ``i - sliding_window < j <= i``), the others are full; the
+    # period is the pattern's length and divides ``n_layers``. ``(False,)``
+    # is a model of full layers alone. Each kind rotates by its own
+    # ``RopeKind`` (None: ``rope_theta``, plain). The serving pool is
+    # allocated by kind: a window layer holds a ring of ``sliding_window``
+    # rows a slot, a full layer the whole context (``kvcache/backend.py``,
+    # layout "by_kind").
+    sliding_window: int = 0
+    window_pattern: tuple[bool, ...] = ()
+    rope_window: RopeKind | None = None
+    rope_full: RopeKind | None = None
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.stated_head_dim or self.d_model // self.n_heads
+
+    def layer_kind(self, j: int) -> tuple[int | None, "RopeKind | float"]:
+        """``(window or None, rope)`` of the ``j``-th layer of a period:
+        what ``_rope`` and the attention of that layer are handed."""
+        if self.window_pattern[j]:
+            return self.sliding_window, self.rope_window or self.rope_theta
+        return None, self.rope_full or self.rope_theta
+
+    def kind_rank(self, j: int) -> tuple[int, int]:
+        """Of the ``j``-th layer of a period: ``(how many layers of its
+        kind come before it in the period, how many a period has)``. Layer
+        ``j`` of period ``i`` is row ``i * count + rank`` of its kind's
+        pool."""
+        same = [w == self.window_pattern[j] for w in self.window_pattern]
+        return sum(same[:j]), sum(same)
+
+    def kind_layers(self, window: bool) -> int:
+        """Layers of one kind in the whole model."""
+        if not self.window_pattern:
+            return 0 if window else self.n_layers
+        per = sum(w == window for w in self.window_pattern)
+        return self.n_layers // len(self.window_pattern) * per
 
     @property
     def is_moe(self) -> bool:
@@ -195,9 +275,11 @@ class TransformerConfig:
     @property
     def routed_moe(self) -> bool:
         """Expert layers of the routed kind (ops/moe.py): sigmoid scores,
-        or any score beside latent attention."""
+        any score beside latent attention, or experts of a stated width
+        of their own (``expert_d_ff``) beside grouped-query attention."""
         return self.n_experts > 0 and (
             self.router_score == "sigmoid" or self.is_mla
+            or self.expert_d_ff > 0
         )
 
     @property
@@ -266,13 +348,27 @@ class TransformerConfig:
                 "kv_lora_rank > 0 (latent attention) needs qk_nope_dim, "
                 "v_head_dim and an even qk_rope_dim"
             )
-        if self.is_moe and self.routed_moe != self.is_mla:
+        gqa_routed = (
+            # The routed layer beside grouped-query attention, as built:
+            # the renormalised softmax top-k, no selection bias, every
+            # layer an expert layer holding every expert.
+            self.routed_moe and not self.is_mla
+            and self.router_score == "softmax" and self.norm_topk
+            and not self.first_dense_layers and not self.n_shared_experts
+            and not self.zero_experts and self.experts_held is None
+            and self.routed_scaling == 1.0
+        )
+        if self.is_moe and self.routed_moe != self.is_mla and not gqa_routed:
             raise ValueError(
                 "router_score='sigmoid' (the routed expert layer, a "
                 "leading dense group) and latent attention (kv_lora_rank "
                 "> 0) are built together only: the grouped-query decode "
                 'loops scan params["layers"] alone, and the latent '
-                "layers' experts are the routed ones"
+                "layers' experts are the routed ones (beside grouped-query "
+                "attention the routed layer is built for experts of a "
+                "stated width, expert_d_ff, under the renormalised softmax "
+                "top-k alone: norm_topk=True, routed_scaling 1, no shared, "
+                "zero or absent experts, no leading dense layer)"
             )
         if self.is_mla and self.is_moe and (
             self.router_score == "softmax" and self.norm_topk
@@ -358,6 +454,40 @@ class TransformerConfig:
         if not 0 <= self.first_dense_layers < max(self.n_layers, 1):
             raise ValueError(
                 "first_dense_layers must leave at least one expert layer"
+            )
+        if self.stated_head_dim < 0 or (self.stated_head_dim and self.is_mla):
+            raise ValueError(
+                "stated_head_dim is a grouped-query head's width (>= 0); "
+                "latent attention states its own (qk_nope_dim, qk_rope_dim, "
+                "v_head_dim)"
+            )
+        pattern = self.window_pattern
+        if pattern and (
+            self.n_layers % len(pattern)
+            or any(pattern) != (self.sliding_window > 0)
+        ):
+            raise ValueError(
+                f"window_pattern={pattern} must divide n_layers="
+                f"{self.n_layers} into whole periods, and sliding_window="
+                f"{self.sliding_window} must be positive exactly when the "
+                "pattern has a window layer"
+            )
+        if not pattern and (
+            self.sliding_window or self.rope_window or self.rope_full
+        ):
+            raise ValueError(
+                "sliding_window, rope_window and rope_full describe the "
+                "kinds of layer of a window_pattern: set one ((False,) is "
+                "a model of full layers alone)"
+            )
+        if pattern and (
+            self.is_mla or self.first_dense_layers
+            or self.attn_impl in ("ring", "ulysses")
+        ):
+            raise ValueError(
+                "window_pattern is built for grouped-query layers in one "
+                "stacked group on one device: not beside latent attention, "
+                "first_dense_layers or a sequence-parallel attn_impl"
             )
 
 
@@ -550,12 +680,12 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
         return (jax.random.normal(key, shape, pd) / math.sqrt(fan_in)).astype(pd)
 
     if cfg.is_moe:
-        ne = cfg.n_experts
+        ne, eff = cfg.n_experts, cfg.moe_d_ff
         mlp = {
             "router": norm(keys[8], (nl, dm, ne), dm),
-            "w_gate": norm(keys[5], (nl, ne, dm, dff), dm),
-            "w_up": norm(keys[6], (nl, ne, dm, dff), dm),
-            "w_down": norm(keys[7], (nl, ne, dff, dm), dff),
+            "w_gate": norm(keys[5], (nl, ne, dm, eff), dm),
+            "w_up": norm(keys[6], (nl, ne, dm, eff), dm),
+            "w_down": norm(keys[7], (nl, ne, eff, dm), eff),
         }
     else:
         mlp = {
@@ -750,21 +880,30 @@ def _moe_mlp_capacity(
 
 
 def _rope(
-    x: jax.Array, positions: jax.Array, theta: float, interleave: bool = False
+    x: jax.Array, positions: jax.Array, theta: "float | RopeKind",
+    interleave: bool = False,
 ) -> jax.Array:
     """Rotary embedding. x: [B, S, H, D]; positions: [S] global positions
     shared across the batch, or [B, S] per-row positions (the continuous-
     batching server's slots sit at different depths). Pair i rotates by
-    ``position * theta**(-2i/D)``; its two members are columns (i, i +
-    D/2), or (2i, 2i + 1) with ``interleave`` (``TransformerConfig.
-    rope_interleave``: how a checkpoint orders its columns)."""
+    ``position * theta**(-2i/D)``, or by a layer kind's own table
+    (``theta`` a ``RopeKind``: YaRN's slowed pairs, cos and sin times its
+    attention factor); its two members are columns (i, i + D/2), or (2i,
+    2i + 1) with ``interleave`` (``TransformerConfig.rope_interleave``: how
+    a checkpoint orders its columns)."""
     dim = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    gain = 1.0
+    if isinstance(theta, RopeKind):
+        freqs, gain = jnp.asarray(theta.inv_freq(dim)), theta.attention_factor
+    else:
+        freqs = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [(B,) S, D/2]
     if angles.ndim == 2:
         angles = angles[None]  # broadcast over batch
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if gain != 1.0:
+        cos, sin = cos * jnp.float32(gain), sin * jnp.float32(gain)
     if interleave:
         xf = x.astype(jnp.float32).reshape(*x.shape[:-1], dim // 2, 2)
         x1, x2 = xf[..., 0], xf[..., 1]
@@ -859,9 +998,66 @@ def _double_layer(x, layer, cfg: "TransformerConfig", attend):
     return a1 + _dense_mlp(m1, blk1, cfg) + branch, routing
 
 
+def scan_periods(cfg: "TransformerConfig", stacks, carry, step, first=0):
+    """``lax.scan`` over the PERIODS of ``cfg.window_pattern``, shared by
+    the full forward, the admission's prefill and the decode tick. A
+    period's layers run in a row inside one scan step, so each knows its
+    kind statically (``cfg.layer_kind(j)``: a window or none, its rope);
+    ``stacks`` (a group's tensors, ``[L, ...]``) do not ride the scan: layer
+    ``j`` of period ``i`` takes its own by ONE dynamic index, which fuses
+    into the product reading it (``_double_scan`` has the lesson).
+    A routed expert layer's matrices are handed on as stacks of every
+    layer's experts, ``layer["experts_at"]`` = ``(w_gate, w_up, w_down
+    [L * E, ...], the layer's first row)``: ``ops/moe.py`` reaches an
+    expert there, and its grouped matmul (a custom call, into which no
+    slice fuses) takes the stack whole.
+    ``step(carry, layer, j, i) -> (carry, y)``. Returns (carry, a tuple
+    over ``j`` of the ``y`` stacked over the periods)."""
+    p = len(cfg.window_pattern)
+    experts = () if not (cfg.routed_moe and "router" in stacks) else tuple(
+        stacks[n].reshape(-1, *stacks[n].shape[2:])
+        for n in ("w_gate", "w_up", "w_down")
+    )
+    rest = {
+        n: w for n, w in stacks.items()
+        if not (experts and n in ("w_gate", "w_up", "w_down"))
+    }
+
+    def period(carry, i):
+        ys = []
+        for j in range(p):
+            at = first + i * p + j
+            layer = {
+                n: lax.dynamic_index_in_dim(w, at, keepdims=False)
+                for n, w in rest.items()
+            }
+            if experts:
+                layer["experts_at"] = (*experts, at * cfg.n_experts)
+            carry, y = step(carry, layer, j, i)
+            ys.append(y)
+        return carry, tuple(ys)
+
+    count = next(iter(stacks.values())).shape[0]
+    return lax.scan(period, carry, jnp.arange(count // p))
+
+
 def _arch_refusal(cfg: "TransformerConfig", what: str) -> str | None:
-    """Why ``what`` does not take a latent-attention config (None: it
-    does, the config is not one)."""
+    """Why ``what`` does not take a latent-attention config, or one with
+    kinds of layer or the routed layer beside grouped-query attention
+    (None: it does, the config is neither)."""
+    if cfg.window_pattern or (cfg.routed_moe and not cfg.is_mla):
+        return (
+            f"{what} is not built for a config with kinds of layer "
+            "(window_pattern: sliding-window and full layers, a rope a "
+            "kind) or the routed expert layer beside grouped-query "
+            "attention (expert_d_ff): these configs serve on one device "
+            "through StreamingGenerator's dense slot pools (compute-dtype "
+            "cache, by layer kind where there is a window; bf16 or float32 "
+            "weights) and run Transformer's forward. The backward flash "
+            "kernels, the int8 pool and its Pallas read take no window, "
+            "pages and the radix cache address one kind of pool, and no "
+            "sharded layout has been taught the two pools"
+        )
     if not cfg.is_mla:
         return None
     return (
@@ -934,7 +1130,21 @@ class Transformer:
     def init(self, rng: jax.Array) -> dict:
         return init_params(rng, self.cfg)
 
-    def _attention(self, q, k, v):
+    def _attention(self, q, k, v, window=None):
+        if window is not None:
+            # A sliding-window layer (never under a mesh or a sequence-
+            # parallel impl: the config and ``__init__`` refused). Forward
+            # only: the windowed kernel has no backward.
+            from torchkafka_tpu.ops.flash import _repeat_kv, flash_forward
+
+            if self._use_flash:
+                out = flash_forward(
+                    q, k, v, scale=1.0 / math.sqrt(q.shape[-1]), window=window
+                )
+                if out is not None:
+                    return out  # else S does not tile: the dense form
+            k, v = _repeat_kv(q, k, v)
+            return mha(q, k, v, causal=True, window=window)
         if self._use_ulysses:
             return ulysses_attention(
                 q, k, v, mesh=self.mesh, axis_name="sp", causal=True,
@@ -983,14 +1193,18 @@ class Transformer:
         return jnp.arange(local_len)
 
     def _layer(
-        self, x: jax.Array, layer: Mapping[str, jax.Array]
+        self, x: jax.Array, layer: Mapping[str, jax.Array], kind=None
     ) -> tuple[jax.Array, jax.Array]:
         """One decoder layer. Returns (activation, router stats [2, E] for
-        MoE configs / [2, 1] zeros otherwise — see ``router_aux``)."""
-        x, stats, _cached = self._layer_capture(x, layer)
+        MoE configs / [2, 1] zeros otherwise — see ``router_aux``).
+        ``kind``: ``cfg.layer_kind(j)`` where the config has kinds of
+        layer, ``(window or None, rope)``."""
+        x, stats, _cached = self._layer_capture(x, layer, kind)
         return x, stats
 
-    def _layer_capture(self, x: jax.Array, layer: Mapping[str, jax.Array]):
+    def _layer_capture(
+        self, x: jax.Array, layer: Mapping[str, jax.Array], kind=None
+    ):
         """``_layer`` with what serving keeps of it: (activation, router
         stats, capture). For a latent-attention config the capture is
         ``(latent [B, S, rank + rope], routing [B, S, top_k] | None)``,
@@ -1024,7 +1238,7 @@ class Transformer:
                 q_nope, q_rope, latent, layer, cfg, use_flash=self._use_flash
             )
         else:
-            attn = self._gqa(h, layer, positions)
+            attn = self._gqa(h, layer, positions, kind)
         x = x + jnp.einsum("bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype))
         h = _rms_norm(x, layer["ln2"])
         stats, routing = jnp.zeros((2, 1), jnp.float32), None
@@ -1040,13 +1254,16 @@ class Transformer:
             x = x + mlp_out
         return x, stats, (latent, routing) if cfg.is_mla else None
 
-    def _gqa(self, h, layer, positions):
+    def _gqa(self, h, layer, positions, kind=None):
         cfg = self.cfg
+        window, rope = kind or (None, cfg.rope_theta)
         q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
         k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
         v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q = _rope(q, positions, rope)
+        k = _rope(k, positions, rope)
+        if window is not None:
+            return self._attention(q, k, v, window)
         if cfg.n_kv_heads != cfg.n_heads and not (
             self._use_flash or self._use_ulysses
         ):
@@ -1127,6 +1344,19 @@ class Transformer:
             # expert layers (``first_dense_layers``).
             stats = []
             for key, nl, _expert_mlp in _layer_groups(cfg):
+                if cfg.window_pattern:
+                    # Kinds of layer: a scan over periods (forward only,
+                    # so no remat: ``make_train_step`` refuses them).
+                    x, st = scan_periods(
+                        cfg, params[key], x,
+                        lambda x, layer, j, _i: self._layer(
+                            x, layer, cfg.layer_kind(j)
+                        ),
+                    )
+                    stats.append(jnp.stack(st, axis=1).reshape(
+                        -1, *st[0].shape[1:]
+                    ))
+                    continue
                 xs, step = params[key], body
                 if cfg.attn_blocks == 2:
                     xs, layer_of = _double_scan(params[key])

@@ -52,6 +52,7 @@ def mha(
     causal: bool = True,
     q_offset: int | jax.Array = 0,
     k_offset: int | jax.Array = 0,
+    window: int | None = None,
 ) -> jax.Array:
     """Dense multi-head attention.
 
@@ -60,6 +61,8 @@ def mha(
     ``q_offset``/``k_offset`` are the global positions of the first row of
     each block — this is what lets the same kernel serve both the single-chip
     path (offsets 0) and one block step of ring attention (shard offsets).
+    ``window`` (with ``causal``): a sliding-window layer, a query at i sees
+    the keys j with ``i - window < j <= i``.
     """
     dim = q.shape[-1]
     scores = jnp.einsum(
@@ -69,6 +72,8 @@ def mha(
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = k_offset + jnp.arange(k.shape[1])
         mask = q_pos[:, None] >= k_pos[None, :]
+        if window is not None:
+            mask &= q_pos[:, None] - k_pos[None, :] < window
         scores = jnp.where(mask[None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum(

@@ -70,13 +70,20 @@ def _flash_kernel(
     q_ref, k_ref, v_ref, qoff_ref, koff_ref, o_ref, lse_ref,
     acc_ref, m_ref, l_ref,
     *, scale: float, causal: bool, block_q: int, block_k: int,
+    window: int | None = None,
 ):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)
+    if window is not None:
+        # A sliding window (offsets 0): the grid's last axis runs over the
+        # key blocks from the first one the q-block's window reaches
+        # (``_window_first``), not from 0; blocks wholly under the window
+        # are never fetched.
+        ki = _window_first(qi, block_q, block_k, window) + step
     qoff = qoff_ref[0]  # global position of q row 0 (ring shard offset)
     koff = koff_ref[0]
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -102,11 +109,18 @@ def _flash_kernel(
         if causal:
             q_pos = qoff + qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_pos = koff + ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            keep = q_pos >= k_pos
+            if window is not None:  # a straddling block is masked
+                keep &= q_pos - k_pos < window
+            s = jnp.where(keep, s, _NEG_INF)
         m_prev = m_ref[:, :1]  # [block_q, 1]
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if window is not None:
+            # A row's keys of a straddling block may all lie under its
+            # window while its maximum is still the mask's: exp(0) is 1.
+            p = jnp.where(keep, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[:, :1] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
@@ -116,7 +130,7 @@ def _flash_kernel(
         acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[:, :1] = m_new
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -124,6 +138,23 @@ def _flash_kernel(
         # the future): l == 0 → lse ≈ -1e30, o = 0; the partial-merge
         # weight exp(lse - lse_new) underflows to exactly 0.
         lse_ref[0] = m_ref[:, :1] + jnp.log(l)  # [block_q, 1]
+
+
+def _window_first(qi, block_q: int, block_k: int, window: int):
+    """The first key block a q-block's sliding window reaches: its first
+    row ``qi * block_q`` sees the keys from ``qi * block_q - window + 1``
+    on."""
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+
+
+def _window_blocks(sq: int, block_q: int, block_k: int, window: int) -> int:
+    """Key blocks a q-block visits under a sliding window, at most: from
+    ``_window_first`` to the block of its last row."""
+    return max(
+        (qi * block_q + block_q - 1) // block_k
+        - max(qi * block_q - window + 1, 0) // block_k + 1
+        for qi in range(-(-sq // block_q))
+    )
 
 
 def _scratch(shapes):
@@ -158,12 +189,15 @@ def _kv_index(n_q_heads: int, n_kv_heads: int):
 def _flash_fwd_bhsd(
     q, k, v, *, causal: bool, block_q: int, block_k: int, interpret: bool,
     q_offset=0, k_offset=0, n_q_heads: int = 1, n_kv_heads: int = 1,
-    scale: float | None = None,
+    scale: float | None = None, window: int | None = None,
 ):
     """q: [B·H, Sq, D]; k: [B·K, Sk, D]; v: [B·K, Sk, Dv] → ([B·H, Sq,
     Dv], lse f32). Dv is D everywhere but in ``flash_forward`` (latent
     attention's heads are wider in q/k than in v); ``scale`` defaults to
-    ``1/sqrt(D)``.
+    ``1/sqrt(D)``. ``window`` (static, causal, offsets 0, forward only): a
+    sliding window; the call is named ``tk_flash_fwd_win``, its grid
+    visits ``_window_blocks`` key blocks a q-block, and the causal call's
+    program is untouched.
 
     ``q_offset``/``k_offset`` are the global positions of row 0 (traced i32
     scalars, SMEM) — this is what lets the same kernel serve the single-chip
@@ -182,6 +216,11 @@ def _flash_fwd_bhsd(
     vmem = {"memory_space": pltpu.VMEM}
     qoff, koff = _offsets(q_offset, k_offset)
     kv = _kv_index(n_q_heads, n_kv_heads)
+    if window is not None:
+        return _flash_fwd_window(
+            q, k, v, qoff, koff, kv, scale=scale, block_q=block_q,
+            block_k=block_k, interpret=interpret, window=window,
+        )
     return pl.pallas_call(
         kernel,
         out_shape=[
@@ -203,6 +242,51 @@ def _flash_fwd_bhsd(
         scratch_shapes=_scratch([(block_q, dv), (block_q, 128), (block_q, 128)]),
         interpret=interpret,
         name="tk_flash_fwd",
+    )(q, k, v, qoff, koff)
+
+
+def _flash_fwd_window(q, k, v, qoff, koff, kv, *, scale, block_q, block_k,
+                      interpret, window):
+    """``_flash_fwd_bhsd`` under a sliding window: the same kernel, its
+    last grid axis over the key blocks the window reaches. A step past the
+    q-block's own diagonal names the last block again (no new fetch) and
+    is skipped by the causal test."""
+    bh, sq, d = q.shape
+    sk, dv = k.shape[1], v.shape[2]
+    last = pl.cdiv(sk, block_k) - 1
+    vmem = {"memory_space": pltpu.VMEM}
+
+    def key_block(b, i, j):
+        first = _window_first(i, block_q, block_k, window)
+        return (kv(b), jnp.minimum(first + j, last), 0)
+
+    return pl.pallas_call(
+        functools.partial(
+            _flash_kernel, scale=scale, causal=True, block_q=block_q,
+            block_k=block_k, window=window,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+        ],
+        grid=(
+            bh, pl.cdiv(sq, block_q),
+            _window_blocks(sq, block_q, block_k, window),
+        ),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0), **vmem),
+            pl.BlockSpec((1, block_k, d), key_block, **vmem),
+            pl.BlockSpec((1, block_k, dv), key_block, **vmem),
+            _smem_spec(),
+            _smem_spec(),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0), **vmem),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0), **vmem),
+        ],
+        scratch_shapes=_scratch([(block_q, dv), (block_q, 128), (block_q, 128)]),
+        interpret=interpret,
+        name="tk_flash_fwd_win",
     )(q, k, v, qoff, koff)
 
 
@@ -464,16 +548,20 @@ def flash_attention(
 def flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, *, scale: float,
     causal: bool = True, interpret: bool | None = None,
+    window: int | None = None, block_q: int | None = None,
+    block_k: int | None = None,
 ) -> jax.Array | None:
-    """Forward-only flash for heads wider in q/k than in v (latent
-    attention's prefill: q/k 192, v 128). q, k: [B, S, H, D]; v: [B, S, H,
-    Dv] → [B, S, H, Dv], or None where S does not tile (the caller has
-    its dense form). q and k are zero-padded to the lanes' multiple of
-    128, which leaves every score as it was; ``scale`` is the caller's,
-    since it follows the unpadded width. The kernel and its arithmetic
-    are ``flash_attention``'s."""
+    """Forward-only flash: for heads wider in q/k than in v (latent
+    attention's prefill: q/k 192, v 128), and for a sliding-window layer
+    (``window``: a query at i sees the keys j with ``i - window < j <= i``;
+    causal). q, k: [B, S, H, D]; v: [B, S, K, Dv] → [B, S, H, Dv], or
+    None where S does not tile (the caller has its dense form). q and k
+    are zero-padded to the lanes' multiple of 128, which leaves every
+    score as it was; ``scale`` is the caller's, since it follows the
+    unpadded width. The kernel and its arithmetic are
+    ``flash_attention``'s."""
     b, s, h, d = q.shape
-    block_q, block_k, interpret = _resolve(s, None, None, interpret)
+    block_q, block_k, interpret = _resolve(s, block_q, block_k, interpret)
     if not _supported(s, block_q, block_k):
         return None
     pad = -d % 128
@@ -482,7 +570,7 @@ def flash_forward(
     out, _ = _flash_fwd_bhsd(
         _to_bhsd(q), _to_bhsd(k), _to_bhsd(v),
         causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
-        n_q_heads=h, n_kv_heads=k.shape[2], scale=scale,
+        n_q_heads=h, n_kv_heads=k.shape[2], scale=scale, window=window,
     )
     return _from_bhsd(out, b, h)
 

@@ -5,7 +5,9 @@ expert matmul over the token-choice pairs, shared by prefill and decode.
 The softmax family in ``models/transformer.py`` (``_moe_mlp``: every
 expert for every token; ``_moe_mlp_capacity``: Switch dispatch with drops)
 stays as it is for its configs. This layer serves the kinds that come
-with latent attention (``TransformerConfig.routed_moe``):
+with latent attention, and beside grouped-query attention the experts of
+a stated width of their own, routed by the renormalised softmax top-k with
+no selection bias (``TransformerConfig.routed_moe``):
 
     s   = sigmoid(h · W_r)  or  softmax(h · W_r)     float32, [N, E + Z]
     sel = top_k(s + b)                       b moves the SELECTION only
@@ -58,13 +60,19 @@ from torchkafka_tpu.models.quant import load_weight
 
 # Token-choice pairs an expert must average before the sorted, grouped
 # form is taken: below it the all-experts einsum streams the same weights
-# and skips the sort (PERF.md, PR 27, has both readings on the v5e).
-_GROUPED_MIN_PAIRS_PER_EXPERT = 8
+# and skips the sort. The grouped matmul walks tiles of 512 rows of ONE
+# expert, so an expert's few rows cost it a whole tile's steps: read on the
+# v5e at 3 pairs an expert (PR 27) and at 16 (PR 34: a tick of 128 rows,
+# top-8 of 64), the all-experts form wins both; the admissions that take
+# the grouped form average 144 pairs and more (PERF.md has the readings;
+# nothing between 16 and 144 has been read).
+_GROUPED_MIN_PAIRS_PER_EXPERT = 32
 
 
 def route(h, router, bias, *, top_k: int, scaling: float,
           score: str = "sigmoid", norm_topk: bool = True):
-    """h [N, D] → (idx [N, K] int32, weights [N, K] float32).
+    """h [N, D] → (idx [N, K] int32, weights [N, K] float32). ``bias``
+    None: a router that states no selection bias.
 
     Scores in float32 at the matmul's highest precision: a near-tie
     between the k-th and the (k+1)-th expert should not flip on the
@@ -77,7 +85,8 @@ def route(h, router, bias, *, top_k: int, scaling: float,
         scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-    _, idx = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    biased = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
         weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * scaling
@@ -91,7 +100,7 @@ def _swiglu(x, w_gate, w_up, w_down):
     return jnp.einsum("nf,fd->nd", gate * jnp.einsum("nd,df->nf", x, w_up), w_down)
 
 
-def grouped_experts(h, idx, weights, w_gate, w_up, w_down):
+def grouped_experts(h, idx, weights, w_gate, w_up, w_down, base=None):
     """Σ_k w_k · E_idx_k(h) by one grouped matmul a projection.
 
     h [N, D]; idx, weights [N, K]; w_gate, w_up [E, D, F]; w_down
@@ -99,12 +108,19 @@ def grouped_experts(h, idx, weights, w_gate, w_up, w_down):
     (stable, so a token's rows keep their order inside a group), each
     expert multiplies its own run of rows, and the rows go back to
     their tokens by the inverse permutation, weighted and summed in
-    float32."""
+    float32. ``base``: the matrices are stacks of MORE than this layer's
+    experts (every layer's, ``[L * E, ...]``) and expert ``i`` is row
+    ``base + i``: the groups of the other rows are empty. The grouped
+    matmul is a custom call, into which no slice fuses: a layer's slice
+    of the stack would be copied out first, three times the experts'
+    bytes a layer (PERF.md, PR 34)."""
     n, k = idx.shape
     e = w_gate.shape[0]
     flat = idx.reshape(-1)
     order = jnp.argsort(flat, stable=True)  # sorted pair -> pair
-    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    sizes = jnp.zeros((e,), jnp.int32).at[
+        flat if base is None else base + flat
+    ].add(1)
     rows = h[order // k]  # [N·K, D]
     gate = jax.nn.silu(lax.ragged_dot(rows, w_gate, sizes))
     up = lax.ragged_dot(rows, w_up, sizes)
@@ -178,12 +194,27 @@ def all_experts(h, idx, weights, w_gate, w_up, w_down):
     ).astype(h.dtype)
 
 
-def routed_experts(h, idx, weights, w_gate, w_up, w_down):
+def routed_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
     """Every expert is here: the form the static shapes call for (module
-    docstring)."""
+    docstring). ``at`` = ``(base, count)``: the matrices are stacks and
+    this layer's experts their rows ``[base, base + count)``."""
     n, k = idx.shape
-    if n * k >= _GROUPED_MIN_PAIRS_PER_EXPERT * w_gate.shape[0]:
-        return grouped_experts(h, idx, weights, w_gate, w_up, w_down)
+    count = w_gate.shape[0] if at is None else at[1]
+    if n * k >= _GROUPED_MIN_PAIRS_PER_EXPERT * count:
+        return grouped_experts(
+            h, idx, weights, w_gate, w_up, w_down, at and at[0]
+        )
+    if at is not None:
+        # Few rows an expert, out of stacks: the compacted form reaches an
+        # expert by ONE dynamic index, which fuses into its products. The
+        # all-experts einsum over a slice of the stacks has the compiler
+        # re-lay the WHOLE stacks once a dispatch (PERF.md, PR 34: 4 GB
+        # of temporaries at 64 experts of 2304 x 896 in 8 layers).
+        cap = -(-2 * n * k // count // 16) * 16  # as a held share's
+        return compacted_experts(
+            h, idx, weights, w_gate, w_up, w_down, e=count, cap=cap,
+            base=at[0],
+        )
     return all_experts(h, idx, weights, w_gate, w_up, w_down)
 
 
@@ -194,15 +225,17 @@ def routed_moe_mlp(h, layer, cfg, experts=None):
     the sum (module docstring). ``experts``: ``(w_gate, w_up, w_down,
     base)``, stacks whose rows ``[base, base + E)`` are this layer's
     experts, where the caller keeps them apart from the layer's other
-    tensors (the double layer); default the layer's own ``w_gate``,
-    ``w_up``, ``w_down``, from 0."""
+    tensors (the double layer, or ``layer["experts_at"]`` of a scan over
+    periods); default the layer's own ``w_gate``, ``w_up``, ``w_down``,
+    from 0."""
     b, s, d = h.shape
     x = h.reshape(b * s, d)
     idx, weights = route(
-        x, layer["router"], layer["router_bias"],
+        x, layer["router"], layer.get("router_bias"),
         top_k=cfg.expert_top_k, scaling=cfg.routed_scaling,
         score=cfg.router_score, norm_topk=cfg.norm_topk,
     )
+    experts = experts or layer.get("experts_at")
     *mats, base = experts or (
         *(layer[n] for n in ("w_gate", "w_up", "w_down")), 0
     )
@@ -215,9 +248,9 @@ def routed_moe_mlp(h, layer, cfg, experts=None):
             x, idx - first, weights, *mats, e=count, cap=cap, base=base
         )
     else:
-        if experts:
-            mats = [lax.dynamic_slice_in_dim(m, base, count) for m in mats]
-        out = routed_experts(x, idx, weights, *mats)
+        out = routed_experts(
+            x, idx, weights, *mats, at=(base, count) if experts else None
+        )
     if cfg.zero_experts:
         # The identity experts: w · h, no weights.
         w_zero = jnp.sum(

@@ -66,6 +66,7 @@ from torchkafka_tpu.kvcache import (
 from torchkafka_tpu.resilience.crashpoint import crash_hook
 from torchkafka_tpu.models.generate import (
     _attend_cached,
+    _attend_merged,
     _attn_tail,
     _attn_tail_routing,
     _project_qkv,
@@ -92,6 +93,7 @@ from torchkafka_tpu.models.transformer import (
     _layer_groups,
     _rms_norm,
     _rope,
+    scan_periods,
 )
 from torchkafka_tpu.source.records import Record, TopicPartition
 from torchkafka_tpu.utils import tracing as xprof
@@ -325,6 +327,16 @@ class ServeMetrics:
         # ticks needed, summed over blocks: a tick at position p needs p
         self.latent_positions_read = RateMeter()  # rows the read fetched for
         # every slot of every tick run, needed or not
+        # The pool by layer kind (a config with ``window_pattern``; empty
+        # and zero otherwise): its shape, and the cached rows the served
+        # ticks needed against those every tick run fetched, by kind and
+        # summed over the kind's layers. A window layer's tick at position
+        # p needs min(p + 1, window) rows and its read fetches the ring.
+        self.kv_pool_static: dict = {}
+        self.window_positions_valid = RateMeter()
+        self.window_positions_read = RateMeter()
+        self.full_positions_valid = RateMeter()
+        self.full_positions_read = RateMeter()
         self.output_capped = RateMeter()  # slots force-finished by a
         # per-record output budget (max_new_of) at sync granularity
         # Paged prefix cache (kv_pages=, torchkafka_tpu/kvcache): all zero
@@ -514,6 +526,13 @@ class ServeMetrics:
                 "latent_positions_valid": self.latent_positions_valid.count,
                 "latent_positions_read": self.latent_positions_read.count,
             },
+            "kv_pool": {
+                **self.kv_pool_static,
+                "window_positions_valid": self.window_positions_valid.count,
+                "window_positions_read": self.window_positions_read.count,
+                "full_positions_valid": self.full_positions_valid.count,
+                "full_positions_read": self.full_positions_read.count,
+            },
             "output_capped": self.output_capped.count,
             "prefix_cache": self.cache_summary(),
             "tenant_cache": self.tenant_cache_summary(),
@@ -650,9 +669,12 @@ class ServeMetrics:
             ),
             *(
                 (f"{name}_total", "counter", value)
-                for section in ("expert_layer", "latent_pool")
+                for section in ("expert_layer", "latent_pool", "kv_pool")
                 for name, value in s[section].items()
-                if name not in ("moe_expert_load", "experts_held", "attn_blocks")
+                if name not in (
+                    "moe_expert_load", "experts_held", "attn_blocks",
+                    *self.kv_pool_static,
+                )
             ),
             ("moe_expert_load_total", "counter", [
                 (format_labels(expert=str(e)), v)
@@ -703,7 +725,7 @@ def _layer_of(pool, l):
     return lax.dynamic_index_in_dim(pool, l, keepdims=False)
 
 
-def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg):
+def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg, kind=None):
     """One decode token through layer ``l`` with a DIFFERENT position per
     slot. x: [B, 1, D]; caches: the STACKED pool [L, B, M, K, Dh], which
     the caller carries through its layer loop — written in place here, one
@@ -711,10 +733,21 @@ def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg):
     the cache write differ from the lockstep ``generate._layer_step``; the
     attention/MLP tail is the shared ``_attend_cached``. (Sibling:
     spec_decode._multi_step generalizes this to S queries per row —
-    update in step if the write/mask discipline changes.)"""
+    update in step if the write/mask discipline changes.)
+
+    ``kind`` (``cfg.layer_kind(j)``, a config with kinds of layer): the
+    layer's ``(window or None, rope)``, the caches its KIND's pool, a
+    position's kv heads in one row [L, B, M, K * Dh]
+    (``generate.KindKVCache``), and ``l`` its row there. A window layer's
+    pool is a ring [Lw, B, W, K * Dh]: the row goes to ``pos mod W`` and
+    the read takes the rows ``< min(pos + 1, W)``, which hold the last W
+    positions in some order (keys are cached roped, so the order does not
+    matter). Returns (x, cache_k, cache_v, the routed expert layer's
+    choices [B, 1, top_k] or None)."""
+    window, rope = kind or (None, cfg.rope_theta)
     q, k, v = _project_qkv(x, layer, cfg)
-    q = _rope(q, pos_b[:, None], cfg.rope_theta)
-    k = _rope(k, pos_b[:, None], cfg.rope_theta)
+    q = _rope(q, pos_b[:, None], rope)
+    k = _rope(k, pos_b[:, None], rope)
     # Per-row cache write as a SCATTER (.at[l, rows, pos].set), not a masked
     # select: the select rewrites the whole pool every layer while the
     # scatter writes one row per slot. The scatter goes into the stacked
@@ -722,13 +755,20 @@ def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg):
     # output is sliced, written back and copied whole every tick (PERF.md,
     # PR 25); a carry is written in place.
     rows = jnp.arange(cache_k.shape[1])
-    cache_k = cache_k.at[l, rows, pos_b].set(k[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[l, rows, pos_b].set(v[:, 0].astype(cache_v.dtype))
-    valid = jnp.arange(cache_k.shape[2])[None, :] <= pos_b[:, None]  # [B, M]
-    x = _attend_cached(
-        x, q, _layer_of(cache_k, l), _layer_of(cache_v, l), valid, layer, cfg
-    )
-    return x, cache_k, cache_v
+    at, last = pos_b, pos_b
+    if window is not None:
+        at, last = pos_b % window, jnp.minimum(pos_b, window - 1)
+    if kind is not None:  # a position's kv heads side by side in one row
+        k, v = (a.reshape(*a.shape[:2], -1) for a in (k, v))
+    cache_k = cache_k.at[l, rows, at].set(k[:, 0].astype(cache_k.dtype))
+    cache_v = cache_v.at[l, rows, at].set(v[:, 0].astype(cache_v.dtype))
+    valid = jnp.arange(cache_k.shape[2])[None, :] <= last[:, None]  # [B, M]
+    slabs = _layer_of(cache_k, l), _layer_of(cache_v, l)
+    if kind is None:
+        x, routing = _attend_cached(x, q, *slabs, valid, layer, cfg, routing=True)
+    else:
+        x, routing = _attend_merged(x, q, *slabs, valid, layer, cfg)
+    return x, cache_k, cache_v, routing
 
 
 def _count_routing(stats, routing, act, cfg):
@@ -1487,6 +1527,11 @@ class StreamingGenerator:
         # compute dtype; kvcache.resolve_kv_backend refuses every other
         # combination with its reason.
         latent = cfg.is_mla
+        # Kinds of layer (``window_pattern``): the same machinery over a
+        # pool allocated by kind, ``KindKVCache``'s four tensors: the full
+        # layers' K and V [Lf, B, M, K * Dh], the window layers' rings
+        # [Lw, B, W, K * Dh].
+        kinds = bool(cfg.window_pattern)
         B, P, M = self._slots, self._prompt_len, self._max_len
         nl, kh, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
         temp = self._temperature
@@ -1598,7 +1643,9 @@ class StreamingGenerator:
                         # runs max_new times an admission).
                         rows = tuple(jnp.swapaxes(a, 2, 3) for a in rows)
                 else:
-                    rows = (fresh.k, fresh.v)
+                    # (k, v); by kind the full layers' window [0, P) and
+                    # the rings as P positions leave them.
+                    rows = tuple(fresh)
                 caches = tuple(put(c, a, slots) for c, a in zip(caches, rows))
                 tok0 = pick_rows(
                     logits, keys[slots], jnp.zeros((R,), jnp.int32)
@@ -1658,15 +1705,37 @@ class StreamingGenerator:
                             use_kernel=kv_kernel, mesh=mesh,
                         )
                     else:
-                        x, *caches = _slot_layer_step(
+                        x, *caches, routing = _slot_layer_step(
                             x, layer, *caches, l, pos, cfg
                         )
+                        if routing is not None:
+                            stats = _count_routing(stats, routing, act, cfg)
                     return (x, tuple(caches), stats), None
+
+                def kind_body(carry, layer, j, i):
+                    # Layer j of period i: its kind's pool, its row there.
+                    x, caches, stats = carry
+                    rank, count = cfg.kind_rank(j)
+                    at = 2 if cfg.window_pattern[j] else 0
+                    x, ck, cv, routing = _slot_layer_step(
+                        x, layer, caches[at], caches[at + 1],
+                        i * count + rank, pos, cfg, cfg.layer_kind(j),
+                    )
+                    caches = caches[:at] + (ck, cv) + caches[at + 2:]
+                    if routing is not None:
+                        stats = _count_routing(stats, routing, act, cfg)
+                    return (x, caches, stats), None
 
                 # The layer index runs over the leading dense layers and
                 # then the expert layers (one group for every other config).
                 first = 0
                 for key, n, _expert_mlp in _layer_groups(cfg):
+                    if kinds:
+                        # Both pools are the period scan's carry.
+                        (x, caches, stats), _ = scan_periods(
+                            cfg, params[key], (x, caches, stats), kind_body
+                        )
+                        continue
                     xs, step = (params[key], jnp.arange(first, first + n)), body
                     if cfg.attn_blocks == 2:
                         # The blocks' tensors stay stacked: _double_scan.
@@ -1782,12 +1851,13 @@ class StreamingGenerator:
             return out[:6]
 
         self._tick_fn = tick_fn
-        if kv_int8 or latent:
+        if kv_int8 or latent or kinds:
             # int8 pools deliberately give up token-exactness, the one
             # contract warm resume exists to keep; hints are filtered out
             # in _take_hint, so no resume program is built. The latent
-            # pool has no spelling of the K/V resume prefill yet
-            # (_resume_supported): hints fall back to cold replay.
+            # pool and the pool by kind have no spelling of the K/V resume
+            # prefill yet (_resume_supported): hints fall back to cold
+            # replay.
             self._resume_exec = None
         else:
             _resume = jax.jit(resume_admit, donate_argnums=(1,))
@@ -1797,12 +1867,21 @@ class StreamingGenerator:
             self._caches = (
                 jnp.zeros((cfg.cache_layers, B, M, cfg.latent_dim), cfg.dtype),
             )
-            self.metrics.moe_expert_load = np.zeros(
-                (cfg.held_experts[1] if cfg.routed_moe else 0,), np.int64
-            )
             self.metrics.attn_blocks = cfg.attn_blocks
-            if cfg.routed_moe:
-                self.metrics.experts_held = list(cfg.held_experts)
+        elif kinds:
+            w = cfg.sliding_window
+            self._caches = tuple(
+                jnp.zeros(
+                    (cfg.kind_layers(window), B, rows, kh * dh), cfg.dtype
+                )
+                for window, rows in ((False, M), (False, M), (True, w), (True, w))
+            )
+            self.metrics.kv_pool_static = {
+                "window": w, "window_layers": cfg.kind_layers(True),
+                "full_layers": cfg.kind_layers(False),
+                "bytes_window": sum(c.nbytes for c in self._caches[2:]),
+                "bytes_full": sum(c.nbytes for c in self._caches[:2]),
+            }
         elif kv_int8 and kv_kernel:
             # K-major pool for the Pallas read (see _slot_layer_step_q).
             self._caches = (
@@ -1823,6 +1902,11 @@ class StreamingGenerator:
                 jnp.zeros((nl, B, M, kh, dh), cfg.dtype),
                 jnp.zeros((nl, B, M, kh, dh), cfg.dtype),
             )
+        if cfg.routed_moe:
+            self.metrics.moe_expert_load = np.zeros(
+                (cfg.held_experts[1],), np.int64
+            )
+            self.metrics.experts_held = list(cfg.held_experts)
         self._last_tok = jnp.zeros((B,), jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
         self._gen = jnp.zeros((B, self._max_new), jnp.int32)
@@ -3396,7 +3480,7 @@ class StreamingGenerator:
         data axis (its [1, S] resume prefill has no batch to shard —
         tp/fsdp-only meshes are unaffected). Everything else falls back
         to cold replay, which is still correct."""
-        if self._kv_int8 or self._cfg.is_mla:
+        if self._kv_int8 or self._cfg.is_mla or self._cfg.window_pattern:
             return False
         if self._mesh is None:
             return True
@@ -3795,6 +3879,8 @@ class StreamingGenerator:
         decoded = 0
         first_tokens = 0  # slots surfacing their admission's own token
         rows_needed = 0  # cached rows the served ticks read, a layer
+        ring_needed = 0  # and a window layer, of its ring
+        ring = self._cfg.sliding_window
         for i in np.nonzero(self._active)[0]:
             cnt = int(
                 n_out_h[i] if done_h[i]
@@ -3822,6 +3908,10 @@ class StreamingGenerator:
             rows_needed += (cnt - j0) * self._prompt_len + (
                 (cnt - j0) * (j0 + cnt - 1) // 2 if cnt > j0 else 0
             )
+            if ring:
+                ring_needed += int(np.minimum(
+                    self._prompt_len + np.arange(j0, cnt), ring
+                ).sum())
             if self._tracer is not None and new_toks > 0:
                 self._tracer.tokens(
                     self._slot_rec[i], new_toks,
@@ -3852,6 +3942,15 @@ class StreamingGenerator:
             self.metrics.latent_positions_read.add(
                 blocks * self._slots * self._ticks_per_sync * self._max_len
             )
+        if self._cfg.window_pattern:
+            m, ticks = self.metrics, self._slots * self._ticks_per_sync
+            n_win = self._cfg.kind_layers(True)
+            n_full = self._cfg.kind_layers(False)
+            m.window_positions_valid.add(ring_needed * n_win)
+            m.full_positions_valid.add(rows_needed * n_full)
+            # The XLA reads fetch every slot's ring and slab, every tick.
+            m.window_positions_read.add(n_win * ticks * ring)
+            m.full_positions_read.add(n_full * ticks * self._max_len)
         if journal_dirty:
             # Synchronous at the cadence point: the whole point is
             # that a SIGKILL one instruction later finds these tokens
