@@ -10,7 +10,9 @@ from a seed, and checks what comes out:
            (and Pallas-interpreter) run under a device's name.
   kernels  the compiled Pallas reads and flash attention against their XLA
            references on a small input at the served head shapes; the
-           dense read's writing form against scatter-then-read, bit for bit.
+           dense read's writing form against scatter-then-read, bit for bit;
+           the grouped expert matmul's two kernels against lax.ragged_dot
+           at both admissions' shapes, a row half padding.
   serve    prompt topic -> StreamingGenerator -> per-completion commits on
            the 8b zoo model (Llama-3-8B widths, all 32 layers, int8
            weights), then two short servers at a 1024-token pool so that
@@ -67,6 +69,8 @@ class Sizes:
     kv_kernel: bool | str
     train_seq: int
     train_batch: int
+    # The grouped expert matmul's shapes: (tokens, top-k, experts, D, F).
+    gmm_shapes: tuple
 
 
 CHIP = Sizes(
@@ -75,6 +79,8 @@ CHIP = Sizes(
     pool_prompt_len=768, pool_max_new=256, pool_slots=4, pool_records=6,
     block_size=256, kv_kernel="auto",
     train_seq=512, train_batch=8,
+    # An admission trip of mellum2-12b-a2.5b-8l and of kanana-2-30b-a3b-7l.
+    gmm_shapes=((4096, 8, 64, 2304, 896), (3072, 6, 128, 2048, 768)),
 )
 REHEARSAL = Sizes(
     serve_scale=None, train_scale=None,
@@ -82,6 +88,7 @@ REHEARSAL = Sizes(
     pool_prompt_len=24, pool_max_new=8, pool_slots=2, pool_records=3,
     block_size=8, kv_kernel=True,
     train_seq=128, train_batch=4,
+    gmm_shapes=((64, 2, 8, 32, 12),),
 )
 
 
@@ -293,6 +300,46 @@ def check_kernels(sz: Sizes) -> dict:
             np.array_equal(np.asarray(g), np.asarray(w)),
             f"dynlen_write: {name} differs from scatter-then-read",
         )
+
+    # The grouped expert matmul (ops/moe.py: the gated pair's kernel and
+    # the down projection's) against ``lax.ragged_dot`` over the same
+    # sorted rows, the layer's experts the SECOND half of their stacks. A
+    # row that is half padding routes as the cells' do: the padding's
+    # tokens all choose the same k experts, the rest k at random.
+    from torchkafka_tpu.ops import moe
+
+    for n, k, e, d, f in sz.gmm_shapes:
+        gmm_rng = np.random.default_rng(2)
+        idx = np.stack([gmm_rng.permutation(e)[:k] for _ in range(n)])
+        idx[: n // 2] = gmm_rng.permutation(e)[:k]
+        idx = jnp.asarray(idx, jnp.int32)
+        x = jnp.asarray(gmm_rng.normal(size=(n, d)), dt)
+        w = jnp.full((n, k), 1.0 / k, jnp.float32)
+        mats = [
+            jnp.asarray(gmm_rng.normal(size=(2 * e, *s)) / np.sqrt(s[0]), dt)
+            for s in ((d, f), (d, f), (f, d))
+        ]
+
+        @jax.jit
+        def by_ragged_dot(x, idx, w, w_gate, w_up, w_down, e=e, k=k, n=n):
+            flat = idx.reshape(-1)
+            order = jnp.argsort(flat, stable=True)
+            sizes = jnp.zeros((2 * e,), jnp.int32).at[e + flat].add(1)
+            rows = x[order // k]
+            gate = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
+            up = jax.lax.ragged_dot(rows, w_up, sizes)
+            out = jax.lax.ragged_dot(gate * up, w_down, sizes)
+            inverse = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+            out = out[inverse].reshape(n, k, -1).astype(jnp.float32)
+            return jnp.einsum("nkd,nk->nd", out, w).astype(x.dtype)
+
+        got = jax.jit(
+            lambda x, idx, w, *m, e=e: moe.grouped_experts(
+                x, idx, w, *m, (jnp.int32(e), e)
+            )
+        )(x, idx, w, *mats)
+        close(f"grouped_matmul_{d}x{f}", got, by_ragged_dot(x, idx, w, *mats))
+        del mats
 
     # Flash forward and backward (GQA, causal) against the dense XLA body.
     seq = sz.train_seq

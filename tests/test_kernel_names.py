@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from torchkafka_tpu.ops import flash, kvattn, qmatmul
+from torchkafka_tpu.ops import flash, kvattn, moe, qmatmul
 
 B, H, K, M, DH, BS = 2, 2, 1, 128, 128, 128
 F32, I8 = jnp.float32, jnp.int8
@@ -71,6 +71,14 @@ def flash_unequal():
     )
 
 
+def grouped_matmul():
+    x, idx = jnp.zeros((16, 32), F32), jnp.zeros((16, 2), jnp.int32)
+    gate, down = jnp.zeros((4, 32, 16), F32), jnp.zeros((4, 16, 32), F32)
+    return lambda: moe.grouped_experts(
+        x, idx, jnp.ones((16, 2), F32), gate, gate, down
+    )
+
+
 def pallas_names(jaxpr) -> list[str]:
     """The names of the ``pallas_call``s anywhere in a jaxpr."""
     names = []
@@ -89,6 +97,7 @@ def pallas_names(jaxpr) -> list[str]:
     (flash_bwd, ["tk_flash_bwd_dkv", "tk_flash_bwd_dq", "tk_flash_fwd"]),
     (qmm, ["tk_qmatmul"]),
     (flash_unequal, ["tk_flash_fwd"]),
+    (grouped_matmul, ["tk_gmm_down", "tk_gmm_gate_up"]),
 ], ids=lambda p: p.__name__ if callable(p) else None)
 def test_the_wrapper_calls_its_kernel_by_its_fixed_name(wrapper, names):
     jaxpr = jax.make_jaxpr(wrapper())().jaxpr

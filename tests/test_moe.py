@@ -459,3 +459,207 @@ class TestRoutedExpertLayer:
             np.asarray(_attn_tail(x, attn, layer, MOE_CFG)), np.asarray(want),
             atol=1e-6,
         )
+
+
+# --------------------------------------- the grouped matmul's own kernel
+# (ops/moe.py: ``tk_gmm_gate_up``, ``tk_gmm_down``; the Pallas interpreter
+# on the CPU.) Sorted rows and group sizes in, a plain loop over the
+# experts in float64 beside it: float32 operands, 1e-5 on outputs of order
+# one as above.
+
+# name: (group sizes of one layer's experts, rows a tile (the block a grid
+# step holds), rows a piece of it (what one product multiplies), D, F)
+_GMM_CASES = {
+    "uniform": ([32] * 8, 32, 32, 32, 12),
+    "every_pair_to_one_expert": ([0, 0, 0, 96, 0, 0], 32, 16, 32, 12),
+    # half of the pairs to k = 2 experts, the rest spread (a row that is
+    # half padding, which routes alike)
+    "half_to_k_experts_the_rest_spread": (
+        [70, 9, 11, 74, 8, 12, 10, 14, 7, 9], 64, 16, 32, 12
+    ),
+    "experts_with_no_pair": ([0, 40, 0, 0, 24, 0], 16, 16, 32, 12),
+    # runs end at 40, 91, 98, 101, 128: the last tile [96, 128) holds the
+    # ends of three experts' runs, and no size is a multiple of the tile
+    "sizes_off_the_tile_and_a_tile_of_three": (
+        [40, 51, 7, 3, 27], 32, 32, 32, 12
+    ),
+    # ... and in pieces of 16: the visit of the expert of 3 rows (98 to
+    # 101) multiplies one piece of the tile's two and skips the other
+    "a_tile_of_three_in_pieces": ([40, 51, 7, 3, 27], 32, 16, 32, 12),
+    "rows_not_a_multiple_of_the_tile": ([30, 45, 25], 32, 16, 32, 12),
+    "one_tile": ([3, 0, 5, 2], 16, 16, 32, 12),
+    "widths_18_to_7": ([20, 33, 11], 32, 16, 72, 28),  # 2304 : 896
+    "widths_8_to_3": ([20, 33, 11], 32, 16, 64, 24),  # 2048 : 768
+}
+
+
+def _gmm_swiglu(rows, mats, sizes, base, tm, ts):
+    """The two kernel calls over sorted rows, padded to whole tiles."""
+    from torchkafka_tpu.ops import moe
+
+    m = rows.shape[0]
+    tiles_m = -(-m // tm)
+    rows = jnp.pad(rows, ((0, tiles_m * tm - m), (0, 0)), mode="edge")
+    walk = moe._gmm_tiles(jnp.asarray(sizes, jnp.int32), tiles_m, tm)
+    mid = moe._gmm(rows, mats[:2], base, walk, tm, ts, "tk_gmm_gate_up")
+    out = moe._gmm(mid, mats[2:], base, walk, tm, ts, "tk_gmm_down")
+    return out[:m], walk
+
+
+class TestGroupedMatmulKernel:
+    @pytest.mark.parametrize("case", list(_GMM_CASES))
+    def test_the_kernel_equals_a_loop_over_the_experts(self, rng, case):
+        sizes, tm, ts, d, f = _GMM_CASES[case]
+        e, m = len(sizes), sum(sizes)
+        rows = rng.normal(size=(m, d))
+        mats = [
+            0.2 * rng.normal(size=s)
+            for s in ((e, d, f), (e, d, f), (e, f, d))
+        ]
+        got, walk = _gmm_swiglu(
+            jnp.asarray(rows, jnp.float32),
+            [jnp.asarray(w, jnp.float32) for w in mats], sizes, 0, tm, ts,
+        )
+        want, start = np.zeros((m, d)), 0
+        for i, n in enumerate(sizes):
+            want[start:start + n] = _np_swiglu(
+                rows[start:start + n], *(w[i] for w in mats)
+            )
+            start += n
+        np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
+        # The visits are the tiles each run touches: a value of the
+        # routing, with nothing for an expert no pair chose.
+        ends = np.cumsum(sizes)
+        visits = sum(
+            -(-hi // tm) - lo // tm
+            for lo, hi in zip(ends - sizes, ends) if hi > lo
+        )
+        assert int(walk[3]) == visits
+        assert set(np.asarray(walk[1])[:visits]) == {
+            i for i, n in enumerate(sizes) if n
+        }
+
+    @pytest.mark.parametrize("layer", [0, 1, 3])
+    def test_stacks_are_reached_at_base(self, rng, layer):
+        """Stacks of four layers' experts, taken whole: the layer's are
+        rows [base, base + E), every other layer's rows NaN."""
+        sizes, tm, ts, d, f, e = [20, 0, 33, 11], 32, 16, 32, 12, 4
+        rows = rng.normal(size=(sum(sizes), d))
+        own = [
+            0.2 * rng.normal(size=s)
+            for s in ((e, d, f), (e, d, f), (e, f, d))
+        ]
+        stacks = []
+        for w in own:
+            stack = np.full((4 * e, *w.shape[1:]), np.nan)
+            stack[layer * e:(layer + 1) * e] = w
+            stacks.append(jnp.asarray(stack, jnp.float32))
+        got, _walk = _gmm_swiglu(
+            jnp.asarray(rows, jnp.float32), stacks, sizes,
+            jnp.int32(layer * e), tm, ts,
+        )
+        want, start = np.zeros_like(rows), 0
+        for i, n in enumerate(sizes):
+            want[start:start + n] = _np_swiglu(
+                rows[start:start + n], *(w[i] for w in own)
+            )
+            start += n
+        np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
+
+    @pytest.mark.parametrize("stacked", [False, True])
+    def test_grouped_experts_equals_all_experts(self, rng, stacked):
+        """End to end (sort, kernels, inverse permutation, weighted sum)
+        at a row count that is no multiple of the tile, with an expert
+        that no pair chose."""
+        from torchkafka_tpu.ops import moe
+
+        layer = _routed_layer(rng)
+        layer["router_bias"] = jnp.zeros((8,)).at[2].set(-50.0)
+        x = jnp.asarray(rng.normal(size=(117, 32)), jnp.float32)
+        idx, w = moe.route(
+            x, layer["router"], layer["router_bias"], top_k=3, scaling=2.448
+        )
+        assert not (np.asarray(idx) == 2).any()
+        mats = [layer[n] for n in ("w_gate", "w_up", "w_down")]
+        want = moe.all_experts(x, idx, w, *mats)
+        at = None
+        if stacked:  # the middle layer of three, the others' rows NaN
+            mats = [
+                jnp.concatenate([jnp.full_like(m, jnp.nan), m,
+                                 jnp.full_like(m, jnp.nan)])
+                for m in mats
+            ]
+            at = (jnp.int32(8), 8)
+        got = moe.grouped_experts(x, idx, w, *mats, at)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+    def test_two_pallas_calls_take_the_stacks_as_they_came(self, rng):
+        """Structure: ``grouped_experts`` holds the gated pair's kernel and
+        the down projection's and no ``ragged_dot``; the stacks
+        ``[L * E, ...]`` are the calls' own operands, with nothing (a
+        slice, a dynamic slice, a copy, a gather) reading them first."""
+        from torchkafka_tpu.ops import moe
+
+        e, layers, d, f = 8, 3, 32, 12
+        stacks = [
+            jnp.zeros((layers * e, *s), jnp.float32)
+            for s in ((d, f), (d, f), (f, d))
+        ]
+        x = jnp.zeros((64, d), jnp.float32)
+        idx = jnp.zeros((64, 3), jnp.int32)
+        w = jnp.ones((64, 3), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda x, idx, w, a, b, c, base: moe.grouped_experts(
+                x, idx, w, a, b, c, (base, e)
+            )
+        )(x, idx, w, *stacks, jnp.int32(e)).jaxpr
+        names = [eqn.primitive.name for eqn in jaxpr.eqns]
+        assert "ragged_dot" not in names and "ragged_dot_general" not in names
+        calls = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+        assert [c.params["name"] for c in calls] == [
+            "tk_gmm_gate_up", "tk_gmm_down"
+        ]
+        gate, up, down = jaxpr.invars[3:6]
+        assert [v for v in calls[0].invars if v in (gate, up, down)] == [gate, up]
+        assert [v for v in calls[1].invars if v in (gate, up, down)] == [down]
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name != "pallas_call":
+                assert not {gate, up, down} & {
+                    v for v in eqn.invars if hasattr(v, "count")
+                }, eqn.primitive.name
+
+    def test_the_counts_are_the_pieces_multiplied(self, rng, monkeypatch):
+        """``grouped_counts``: the pairs, and the rows of every piece an
+        expert's run touches (pieces of 16 rows here, blocks of two)."""
+        from torchkafka_tpu.ops import moe
+
+        monkeypatch.setattr(moe, "_GMM_PIECE_ROWS", 16)
+        monkeypatch.setattr(moe, "_GMM_BLOCK_PIECES", 2)
+        assert moe._gmm_rows(120) == (32, 16) and moe._gmm_rows(9) == (16, 16)
+        idx = jnp.asarray(rng.integers(0, 8, size=(40, 3)), jnp.int32)
+        rows, tile_rows = np.asarray(moe.grouped_counts(idx[None], 8))
+        sizes = np.bincount(np.asarray(idx).reshape(-1), minlength=8)
+        ends = np.cumsum(sizes)
+        pieces = sum(
+            -(-hi // 16) - lo // 16
+            for lo, hi in zip(ends - sizes, ends) if hi > lo
+        )
+        assert rows == 120 and tile_rows == pieces * 16 > rows
+        # The kernels at those rows: the same sum as at the built ones.
+        layer = _routed_layer(rng)
+        x = jnp.asarray(rng.normal(size=(40, 32)), jnp.float32)
+        w = jnp.asarray(rng.uniform(size=(40, 3)), jnp.float32)
+        mats = [layer[n] for n in ("w_gate", "w_up", "w_down")]
+        np.testing.assert_allclose(
+            np.asarray(moe.grouped_experts(x, idx, w, *mats)),
+            np.asarray(moe.all_experts(x, idx, w, *mats)), atol=1e-5,
+        )
+
+    def test_the_built_rows(self):
+        """Blocks of four pieces of 128 rows (PERF.md §6's sweep), a block
+        no longer than the rows in whole pieces."""
+        from torchkafka_tpu.ops import moe
+
+        assert moe._gmm_rows(32768) == moe._gmm_rows(18432) == (512, 128)
+        assert moe._gmm_rows(1024) == (512, 128)
+        assert moe._gmm_rows(300) == (384, 128) and moe._gmm_rows(8) == (128, 128)

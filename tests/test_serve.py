@@ -1467,6 +1467,64 @@ def test_latent_model_served_tokens_and_commit_watermark(variant):
     consumer.close()
 
 
+def test_the_admissions_grouped_matmul_is_counted_by_hand():
+    """A window of 32 tokens: a trip of 4 rows is 256 pairs over 8 experts,
+    32 an expert, so the admission takes the grouped form (its kernels)
+    and a tick of 4 rows does not. Ten records through 4 slots: admissions
+    of 4, 4 and 2 rows, each one trip of 4; two expert layers, two choices
+    a token. The counts ride the next sync's fetch."""
+    from torchkafka_tpu.ops import moe
+
+    window = 32
+    cfg = _latent_cfg(max_seq_len=window + MAX_NEW)
+    params = init_params(jax.random.key(0), cfg)
+    broker = tk.InMemoryBroker()
+    broker.create_topic("p", partitions=2)
+    rng = np.random.default_rng(7)
+    for i in range(10):
+        broker.produce(
+            "p", rng.integers(0, VOCAB, (window,), dtype=np.int32).tobytes(),
+            partition=i % 2,
+        )
+    consumer = tk.MemoryConsumer(broker, "p", group_id="g")
+    server = StreamingGenerator(
+        consumer, params, cfg, slots=4, prompt_len=window, max_new=MAX_NEW,
+        commit_every=4, ticks_per_sync=3,
+    )
+    assert sum(1 for _ in server.run(max_records=10)) == 10
+    s = server.metrics.summary()
+    assert s["scheduler"]["admit_rows_prefilled"] == 12
+    experts = s["expert_layer"]
+    assert experts["grouped_matmul"] == "kernel"
+    assert experts["moe_grouped_rows"] == 12 * window * 2 * 2
+    # Pieces of 128 rows: a trip's 256 pairs a layer are 2 of them, and 8
+    # experts' runs touch 9 at the most.
+    _tm, ts = moe._gmm_rows(4 * window * 2)
+    assert ts == 128 and experts["moe_grouped_tile_rows"] % ts == 0
+    assert (
+        experts["moe_grouped_rows"] <= experts["moe_grouped_tile_rows"]
+        <= 3 * 2 * (2 + 8 - 1) * ts
+    )
+    assert server._admit_stats == []  # fetched with the last sync
+    text = server.metrics.render_prometheus()
+    assert f"moe_grouped_rows_total {experts['moe_grouped_rows']}\n" in text
+    consumer.close()
+    # A window of 8: 64 pairs a trip, 8 an expert. No grouped form in the
+    # admit program, no count, no further output.
+    small = StreamingGenerator(
+        tk.MemoryConsumer(broker, "p", group_id="g2"), params, cfg, slots=4,
+        prompt_len=P, max_new=MAX_NEW, ticks_per_sync=3,
+    )
+    out = small._admit_fn(
+        small._caches, small._last_tok, small._pos, small._gen,
+        jnp.zeros((4, P), jnp.int32), jnp.ones((4,), bool), small._slot_keys,
+    )
+    assert len(out) == 4 and small._admit_stats == []
+    got = small.metrics.summary()["expert_layer"]
+    assert got["grouped_matmul"] is None and got["moe_grouped_rows"] == 0
+    small.close()
+
+
 def test_latent_model_crash_before_commit_redelivers_unfinished():
     cfg = _latent_cfg()
     params = init_params(jax.random.key(0), cfg)

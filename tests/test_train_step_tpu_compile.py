@@ -6,6 +6,11 @@ second forward is gone), the kept output and log-sum-exp stacked by an
 in-place write of one layer's slice, and a program that fits its chip.
 Nothing runs, so no number here is a measurement.
 
+Beside them the grouped expert matmul's two kernels (``ops/moe.py``, PR
+36) at the widths of the two admissions that run them, about two seconds
+each: what Mosaic refuses (a block over the scoped VMEM, a contraction it
+does not take) shows here and not on the chip.
+
 A file of its own because ``tests/chipbench/`` belongs to the accepted
 benchmark and is not edited: the topology is described inside a fixture,
 never at import, and where this worker cannot load the TPU's library
@@ -161,3 +166,49 @@ def test_the_step_fits_its_chips(step, capsys):
     # and the peak the compiler itself holds against the chip.
     assert footprint < HBM_BYTES
     assert m.peak_memory_in_bytes < footprint
+
+
+# tokens a trip, top-k, experts a layer, layers stacked, D, F
+GROUPED = {
+    "mellum2-12b-a2.5b-8l": (4096, 8, 64, 8, 2304, 896),
+    "kanana-2-30b-a3b-7l": (3072, 6, 128, 1, 2048, 768),
+}
+
+
+@pytest.mark.parametrize("ambient", [None, "highest"])
+@pytest.mark.parametrize("name", list(GROUPED))
+def test_the_grouped_matmul_kernels_compile_at_the_admissions_widths(
+    topo, monkeypatch, name, ambient
+):
+    """``grouped_experts`` over a trip's pairs in bf16, the stacks taken
+    whole: two Mosaic calls under their names and no stack-shaped result
+    (a slice or a copy of a stack would be one). An ambient float32
+    matmul precision is float32 operands' alone and refuses nothing."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from torchkafka_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, k, e, layers, d, f = GROUPED[name]
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def fn(x, idx, w, gate, up, down, base):
+        return moe.grouped_experts(x, idx, w, gate, up, down, (base, e))
+
+    with jax.default_matmul_precision(ambient or "default"):
+        text = jax.jit(fn).lower(
+            sds((n, d)), sds((n, k), jnp.int32), sds((n, k), jnp.float32),
+            sds((layers * e, d, f)), sds((layers * e, d, f)),
+            sds((layers * e, f, d)), sds((), jnp.int32),
+        ).compile().as_text()
+    calls = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert "tk_gmm_gate_up" in calls[0] + calls[1]
+    assert "tk_gmm_down" in calls[0] + calls[1]
+    stack = rf"bf16\[{layers * e},({d},{f}|{f},{d})\]"
+    assert opcodes_of(text, stack) <= {"parameter"}

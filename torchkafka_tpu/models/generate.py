@@ -439,13 +439,15 @@ def _layer_step(x, layer, cache_k, cache_v, pos, cfg):
 
 def prefill(
     params, cfg: TransformerConfig, tokens: jax.Array, max_len: int,
-    mesh: Mesh | None = None,
+    mesh: Mesh | None = None, routing: bool = False,
 ):
     """Full forward over the prompt, capturing k/v into static caches.
 
     tokens: [B, S] → (last-position logits [B, V], KVCache with [0,S) filled).
     Uses Transformer.__call__ for the logits (single source of truth) and an
-    auxiliary scan to capture per-layer k/v.
+    auxiliary scan to capture per-layer k/v. ``routing``: a third result,
+    the routed expert layers' choices ``[L_moe, B, S, top_k]`` (None for a
+    config without such a layer), for the caller that counts them.
 
     With ``mesh``, the prompt batch is constrained over data and the cache
     over (data, tp) — weights are assumed committed to ``serving_shardings``
@@ -466,10 +468,11 @@ def prefill(
     else:
         model = Transformer(cfg, mesh)
     if cfg.is_mla:
-        logits, latents, _routing = latent_forward(params, model, tokens)
-        return logits, latents
+        out = latent_forward(params, model, tokens)
+        return out if routing else out[:2]
     if cfg.window_pattern:
-        return _prefill_kinds(params, model, tokens, max_len)
+        out = _prefill_kinds(params, model, tokens, max_len)
+        return out if routing else out[:2]
     if mesh is not None:
         tokens = lax.with_sharding_constraint(
             tokens, slot_sharding(mesh, tokens.ndim)
@@ -484,10 +487,10 @@ def prefill(
         k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
         v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
         k = _rope(k, positions, cfg.rope_theta)
-        x, _stats = model._layer(x, layer)
-        return x, (k, v)
+        x, _stats, (_latent, chosen) = model._layer_capture(x, layer)
+        return x, (k, v, chosen)
 
-    x, (ks, vs) = lax.scan(capture, x, params["layers"])
+    x, (ks, vs, chosen) = lax.scan(capture, x, params["layers"])
     x = _rms_norm(x, params["ln_f"])
     logits = jnp.einsum(
         "bd,dv->bv", x[:, -1], load_weight(params["lm_head"], cfg.dtype),
@@ -498,13 +501,15 @@ def prefill(
     cache_v = jnp.zeros((nl, batch, max_len, kh, dh), cfg.dtype)
     cache_k = lax.dynamic_update_slice(cache_k, ks.astype(cfg.dtype), (0, 0, 0, 0, 0))
     cache_v = lax.dynamic_update_slice(cache_v, vs.astype(cfg.dtype), (0, 0, 0, 0, 0))
-    return logits, _constrain_cache(KVCache(cache_k, cache_v), mesh)
+    cache = _constrain_cache(KVCache(cache_k, cache_v), mesh)
+    return (logits, cache, chosen) if routing else (logits, cache)
 
 
 def _prefill_kinds(params, model: Transformer, tokens: jax.Array, max_len: int):
     """``prefill`` for a config with kinds of layer: (last-position logits
     [B, V], ``KindKVCache`` with the full layers' [0, S) filled and each
-    window layer's ring holding its last ``sliding_window`` positions)."""
+    window layer's ring holding its last ``sliding_window`` positions, the
+    routed expert layers' choices [L, B, S, top_k] or None)."""
     cfg = model.cfg
     batch, seq = tokens.shape
     x = embed_rows(params["embed"], tokens, cfg.dtype)
@@ -516,8 +521,8 @@ def _prefill_kinds(params, model: Transformer, tokens: jax.Array, max_len: int):
         h = _rms_norm(x, layer["ln1"])
         k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
         v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
-        x, _stats = model._layer(x, layer, kind)
-        return x, (_rope(k, positions, kind[1]), v)
+        x, _stats, (_latent, chosen) = model._layer_capture(x, layer, kind)
+        return x, (_rope(k, positions, kind[1]), v, chosen)
 
     x, kv = scan_periods(cfg, params["layers"], x, capture)
     x = _rms_norm(x, params["ln_f"])
@@ -537,11 +542,15 @@ def _prefill_kinds(params, model: Transformer, tokens: jax.Array, max_len: int):
         return rows.reshape(-1, batch, seq, width).astype(cfg.dtype)
 
     grow = ((0, 0), (0, 0), (0, max_len - seq), (0, 0))
+    chosen = None
+    if kv[0][2] is not None:  # [periods, B, S, K] a layer of the period
+        chosen = jnp.stack([y[2] for y in kv], axis=1)
+        chosen = chosen.reshape(-1, *chosen.shape[2:])
     return logits, KindKVCache(
         jnp.pad(pool(False, 0), grow), jnp.pad(pool(False, 1), grow),
         ring_rows(pool(True, 0), cfg.sliding_window),
         ring_rows(pool(True, 1), cfg.sliding_window),
-    )
+    ), chosen
 
 
 def latent_forward(params, model: Transformer, tokens: jax.Array):

@@ -1009,7 +1009,7 @@ def scan_periods(cfg: "TransformerConfig", stacks, carry, step, first=0):
     A routed expert layer's matrices are handed on as stacks of every
     layer's experts, ``layer["experts_at"]`` = ``(w_gate, w_up, w_down
     [L * E, ...], the layer's first row)``: ``ops/moe.py`` reaches an
-    expert there, and its grouped matmul (a custom call, into which no
+    expert there, and its grouped matmul (Pallas calls, into which no
     slice fuses) takes the stack whole.
     ``step(carry, layer, j, i) -> (carry, y)``. Returns (carry, a tuple
     over ``j`` of the ``y`` stacked over the periods)."""
@@ -1209,8 +1209,9 @@ class Transformer:
         stats, capture). For a latent-attention config the capture is
         ``(latent [B, S, rank + rope], routing [B, S, top_k] | None)``,
         the layer's cache rows (``[2, B, S, rank + rope]``, a row a block,
-        for the double layer) and its expert choices; otherwise None
-        (``generate.prefill`` computes k and v beside the layer)."""
+        for the double layer) and its expert choices; otherwise ``(None,
+        routing | None)`` (``generate.prefill`` computes k and v beside
+        the layer)."""
         cfg = self.cfg
         positions = self._seq_positions(x.shape[1])
         if cfg.attn_blocks == 2:
@@ -1252,7 +1253,7 @@ class Transformer:
         else:
             mlp_out, stats = self._moe_mlp(h, layer)
             x = x + mlp_out
-        return x, stats, (latent, routing) if cfg.is_mla else None
+        return x, stats, (latent, routing)
 
     def _gqa(self, h, layer, positions, kind=None):
         cfg = self.cfg

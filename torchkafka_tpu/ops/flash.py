@@ -507,12 +507,17 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tpu_compiler_params(dimension_semantics: tuple) -> dict:
+def tpu_compiler_params(
+    dimension_semantics: tuple, vmem_limit_bytes: int | None = None
+) -> dict:
     """``{"compiler_params": ...}`` kwargs for a compiled-Mosaic
-    pallas_call — shared by the qmatmul and kvattn kernels."""
+    pallas_call — shared by the qmatmul, kvattn and grouped-matmul
+    kernels. ``vmem_limit_bytes``: the scoped VMEM a kernel whose blocks
+    outgrow the compiler's default asks for (None: the default)."""
     return {
         "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=dimension_semantics
+            dimension_semantics=dimension_semantics,
+            vmem_limit_bytes=vmem_limit_bytes,
         )
     }
 

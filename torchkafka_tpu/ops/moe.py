@@ -31,8 +31,15 @@ is THIS chip's part of the sum. Nothing stands in for the absent chips.
 No token is dropped at any load, in any form:
 
 - ``grouped_experts`` (every expert held): the pairs are sorted by expert
-  and each expert multiplies exactly the rows routed to it
-  (``lax.ragged_dot``), so the FLOPs are top-k's, not E's.
+  and each expert multiplies exactly the rows routed to it, in two Pallas
+  kernels of this file (``tk_gmm_gate_up``: ``silu(x · w_gate) * (x ·
+  w_up)`` formed in float32 and written once; ``tk_gmm_down``), so the
+  FLOPs are top-k's, not E's. The kernels walk the sorted rows in blocks
+  of pieces of 128 rows (``_gmm_rows``); a block that straddles experts
+  is visited once by each, which multiplies the pieces its run touches;
+  an expert's matrices stay in VMEM over its consecutive blocks, and the
+  stacks ``[L * E, ...]`` are taken whole (``_gmm``). Off the TPU the
+  Pallas interpreter runs them.
 - ``all_experts`` (every expert held): where the rows are few against the
   experts (a decode tick) every expert multiplies every row and the
   unrouted ones are weighted by zero: the weights are streamed whole
@@ -52,20 +59,24 @@ expect), never by an option; PERF.md holds the chip's readings.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from torchkafka_tpu.models.quant import load_weight
+from torchkafka_tpu.ops.flash import _default_interpret, tpu_compiler_params
 
 # Token-choice pairs an expert must average before the sorted, grouped
 # form is taken: below it the all-experts einsum streams the same weights
-# and skips the sort. The grouped matmul walks tiles of 512 rows of ONE
-# expert, so an expert's few rows cost it a whole tile's steps: read on the
-# v5e at 3 pairs an expert (PR 27) and at 16 (PR 34: a tick of 128 rows,
-# top-8 of 64), the all-experts form wins both; the admissions that take
-# the grouped form average 144 pairs and more (PERF.md has the readings;
-# nothing between 16 and 144 has been read).
+# and skips the sort, the gather of the sorted rows and the inverse
+# permutation. The admissions that take the grouped form average 144 pairs
+# an expert and more; a tick averages 3 and 16 (PERF.md §6 has the v5e's
+# readings of both forms in a tick; nothing between 16 and 144 has been
+# read).
 _GROUPED_MIN_PAIRS_PER_EXPERT = 32
 
 
@@ -100,31 +111,160 @@ def _swiglu(x, w_gate, w_up, w_down):
     return jnp.einsum("nf,fd->nd", gate * jnp.einsum("nd,df->nf", x, w_up), w_down)
 
 
-def grouped_experts(h, idx, weights, w_gate, w_up, w_down, base=None):
-    """Σ_k w_k · E_idx_k(h) by one grouped matmul a projection.
+# The grouped matmul's rows (PERF.md §6 has the v5e's sweep at both
+# admissions' shapes). A PIECE is what one product multiplies: 128 rows,
+# the MXU's own edge; Mosaic's product of a longer run of rows is slower a
+# row at every shape read (512 rows in one product take 1.4 times four of
+# 128), and what a skewed routing wastes is the unfilled part of a piece.
+# A BLOCK is what one grid step fetches and writes, 4 pieces: fewer steps
+# and longer copies, and a piece the visiting expert's run does not reach
+# is skipped, so a larger block multiplies no more.
+_GMM_PIECE_ROWS = 128
+_GMM_BLOCK_PIECES = 4
+
+
+def _gmm_rows(pairs: int) -> tuple[int, int]:
+    """(rows a block, rows a piece) for ``pairs`` sorted rows: the
+    constants above, the block no longer than the rows in whole pieces."""
+    pieces = min(_GMM_BLOCK_PIECES, -(-pairs // _GMM_PIECE_ROWS))
+    return pieces * _GMM_PIECE_ROWS, _GMM_PIECE_ROWS
+
+
+def _gmm_tiles(sizes, tiles_m: int, tm: int):
+    """The grouped matmul's walk over the sorted rows, in tiles (blocks)
+    of ``tm`` rows aligned to the rows (tile ``i`` is rows ``[i * tm, (i +
+    1) * tm)``): an expert with a run ``[start, end)`` visits every tile
+    its run touches, so a tile that straddles experts is visited once by
+    each and an expert with no pair visits none. Returns (offsets [E + 1],
+    the expert and the tile of visit ``t`` [tiles_m + E - 1] (the most
+    visits any routing makes; past ``visits`` the entries mean nothing),
+    visits: a value of the routing)."""
+    e = sizes.shape[0]
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    each = jnp.where(sizes > 0, (ends + tm - 1) // tm - first, 0)
+    upto = jnp.cumsum(each)
+    t = jnp.arange(tiles_m + e - 1, dtype=jnp.int32)
+    expert = jnp.minimum(jnp.searchsorted(upto, t, side="right"), e - 1)
+    tile = first[expert] + t - (upto[expert] - each[expert])
+    return (
+        jnp.concatenate([starts, ends[-1:]]).astype(jnp.int32),
+        expert.astype(jnp.int32),
+        jnp.clip(tile, 0, tiles_m - 1).astype(jnp.int32),
+        upto[-1].astype(jnp.int32),
+    )
+
+
+def _gmm_kernel(base_ref, offsets_ref, expert_ref, tile_ref, x_ref, *refs,
+                tm: int, ts: int):
+    """One visit: the tile's rows times the visiting expert's matrix (two
+    matrices: ``silu(x · w_gate) * (x · w_up)``, formed in float32), kept
+    for the rows of the expert's run; the tile's other rows keep what
+    their own experts' visits wrote. The tile (a block of ``tm`` rows) is
+    multiplied in pieces of ``ts`` rows, and a piece the run does not
+    reach is skipped."""
+    *w_refs, o_ref = refs
+    # The ambient matmul precision is float32 operands' alone: Mosaic
+    # refuses a float32 contraction of bf16 operands.
+    dot = functools.partial(
+        jnp.dot, preferred_element_type=jnp.float32,
+        precision=None if x_ref.dtype == jnp.float32 else lax.Precision.DEFAULT,
+    )
+    t = pl.program_id(0)
+    ex = expert_ref[t]
+    row0 = tile_ref[t] * tm
+    start, end = offsets_ref[ex], offsets_ref[ex + 1]
+
+    def piece(i, _):
+        at = pl.ds(pl.multiple_of(i * ts, ts), ts)
+        first = row0 + i * ts
+
+        @pl.when((start < first + ts) & (end > first))
+        def _multiply():
+            x = x_ref[at, :]
+            y = dot(x, w_refs[0][...])
+            if len(w_refs) == 2:
+                y = jax.nn.silu(y) * dot(x, w_refs[1][...])
+            row = first + lax.broadcasted_iota(jnp.int32, (ts, 1), 0)
+            own = (row >= start) & (row < end)
+            o_ref[at, :] = jnp.where(own, y.astype(o_ref.dtype), o_ref[at, :])
+
+    # A loop, not the pieces one after the other: the kernel's code is one
+    # piece's, and a process loads it with every program that holds it.
+    lax.fori_loop(0, tm // ts, piece, None)
+
+
+def _gmm(rows, mats, base, walk, tm: int, ts: int, name: str):
+    """rows [M, K] sorted by expert, M a multiple of the block ``tm``, a
+    multiple of the piece ``ts``; mats: one ``[.., K, N]`` stack (rows ·
+    W) or two (the gated pair); expert ``i`` is row ``base + i`` of a
+    stack. → [M, N] in ``rows``' dtype, accumulated in float32. The grid
+    is the walk's visits; a matrix block is an expert's whole ``[K, N]``
+    and its index does not change over the expert's consecutive visits,
+    so it is fetched once an expert."""
+    m, kdim = rows.shape
+    n = mats[0].shape[-1]
+    offsets, expert, tile, visits = walk
+    interpret = _default_interpret()
+    item = rows.dtype.itemsize
+    # Two buffers a block, a piece's float32 products, room for the rest.
+    vmem = 2 * item * (len(mats) * kdim * n + tm * (kdim + n))
+    vmem += 4 * ts * n * (len(mats) + 1) + (8 << 20)
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm, ts=ts),
+        out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(visits,),
+            in_specs=[
+                pl.BlockSpec((tm, kdim), lambda t, b, o, e, i: (i[t], 0)),
+                *(
+                    pl.BlockSpec(
+                        (None, kdim, n),
+                        lambda t, b, o, e, i: (b[0] + e[t], 0, 0),
+                    )
+                    for _ in mats
+                ),
+            ],
+            out_specs=pl.BlockSpec((tm, n), lambda t, b, o, e, i: (i[t], 0)),
+        ),
+        interpret=interpret,
+        name=name,
+        **({} if interpret else tpu_compiler_params(
+            ("arbitrary",), vmem_limit_bytes=vmem
+        )),
+    )(jnp.asarray(base, jnp.int32).reshape(1), offsets, expert, tile,
+      rows, *mats)
+
+
+def grouped_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
+    """Σ_k w_k · E_idx_k(h) by one grouped matmul kernel a projection pair.
 
     h [N, D]; idx, weights [N, K]; w_gate, w_up [E, D, F]; w_down
     [E, F, D]. The N·K (token, choice) pairs are sorted by expert
     (stable, so a token's rows keep their order inside a group), each
     expert multiplies its own run of rows, and the rows go back to
     their tokens by the inverse permutation, weighted and summed in
-    float32. ``base``: the matrices are stacks of MORE than this layer's
-    experts (every layer's, ``[L * E, ...]``) and expert ``i`` is row
-    ``base + i``: the groups of the other rows are empty. The grouped
-    matmul is a custom call, into which no slice fuses: a layer's slice
-    of the stack would be copied out first, three times the experts'
-    bytes a layer (PERF.md, PR 34)."""
+    float32. ``at`` = ``(base, count)``: the matrices are stacks of MORE
+    than this layer's experts (every layer's, ``[L * E, ...]``) and expert
+    ``i`` of ``count`` is row ``base + i``. The kernels take the stacks
+    whole and reach a row through their index maps: a layer's slice of a
+    stack would be copied out first, three times the experts' bytes a
+    layer (PERF.md, PR 34)."""
     n, k = idx.shape
-    e = w_gate.shape[0]
+    base, count = at or (0, w_gate.shape[0])
+    tm, ts = _gmm_rows(n * k)
+    tiles_m = -(-n * k // tm)
     flat = idx.reshape(-1)
     order = jnp.argsort(flat, stable=True)  # sorted pair -> pair
-    sizes = jnp.zeros((e,), jnp.int32).at[
-        flat if base is None else base + flat
-    ].add(1)
-    rows = h[order // k]  # [N·K, D]
-    gate = jax.nn.silu(lax.ragged_dot(rows, w_gate, sizes))
-    up = lax.ragged_dot(rows, w_up, sizes)
-    out = lax.ragged_dot(gate * up, w_down, sizes)  # [N·K, D], sorted
+    sizes = jnp.zeros((count,), jnp.int32).at[flat].add(1)
+    walk = _gmm_tiles(sizes, tiles_m, tm)
+    # Rows past N·K fill the last tile: no expert's, never read back.
+    padded = jnp.pad(order, (0, tiles_m * tm - n * k), mode="edge")
+    rows = h[padded // k]  # [tiles · tm, D]
+    mid = _gmm(rows, (w_gate, w_up), base, walk, tm, ts, "tk_gmm_gate_up")
+    out = _gmm(mid, (w_down,), base, walk, tm, ts, "tk_gmm_down")  # sorted
     inverse = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
     out = out[inverse].reshape(n, k, -1).astype(jnp.float32)
     return jnp.einsum("nkd,nk->nd", out, weights).astype(h.dtype)
@@ -194,16 +334,49 @@ def all_experts(h, idx, weights, w_gate, w_up, w_down):
     ).astype(h.dtype)
 
 
+def _takes_grouped(pairs: int, count: int) -> bool:
+    return pairs >= _GROUPED_MIN_PAIRS_PER_EXPERT * count
+
+
+def grouped_form(cfg, rows: int) -> bool:
+    """Whether ``routed_moe_mlp`` sums ``rows`` tokens' pairs by
+    ``grouped_experts``."""
+    return bool(
+        cfg.routed_moe and not cfg.moe_partial
+        and _takes_grouped(rows * cfg.expert_top_k, cfg.n_experts)
+    )
+
+
+def grouped_counts(routing, count: int):
+    """What ``grouped_experts`` multiplied for the routing [L, ..., K] of
+    L layers' calls over ``count`` experts each: int32 (the pairs, the
+    rows of the pieces its kernels multiplied: every piece an expert's
+    run touches, whole), summed over the layers. Their quotient is the
+    pieces' fill, what uneven routing costs the kernels
+    (``ServeMetrics.moe_grouped_rows``, ``_tile_rows``)."""
+    flat = routing.reshape(routing.shape[0], -1)
+    pairs = flat.shape[1]
+    _tm, ts = _gmm_rows(pairs)
+
+    def pieces(layer):
+        sizes = jnp.sum(
+            layer[:, None] == jnp.arange(count), axis=0, dtype=jnp.int32
+        )
+        return _gmm_tiles(sizes, -(-pairs // ts), ts)[3]
+
+    return jnp.stack([
+        jnp.int32(flat.size), jax.vmap(pieces)(flat).sum() * ts
+    ])
+
+
 def routed_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
     """Every expert is here: the form the static shapes call for (module
     docstring). ``at`` = ``(base, count)``: the matrices are stacks and
     this layer's experts their rows ``[base, base + count)``."""
     n, k = idx.shape
     count = w_gate.shape[0] if at is None else at[1]
-    if n * k >= _GROUPED_MIN_PAIRS_PER_EXPERT * count:
-        return grouped_experts(
-            h, idx, weights, w_gate, w_up, w_down, at and at[0]
-        )
+    if _takes_grouped(n * k, count):
+        return grouped_experts(h, idx, weights, w_gate, w_up, w_down, at)
     if at is not None:
         # Few rows an expert, out of stacks: the compacted form reaches an
         # expert by ONE dynamic index, which fuses into its products. The

@@ -95,6 +95,7 @@ from torchkafka_tpu.models.transformer import (
     _rope,
     scan_periods,
 )
+from torchkafka_tpu.ops import moe
 from torchkafka_tpu.source.records import Record, TopicPartition
 from torchkafka_tpu.utils import tracing as xprof
 from torchkafka_tpu.utils.metrics import Gauge, LatencyHistogram, RateMeter
@@ -321,6 +322,14 @@ class ServeMetrics:
         self.moe_zero_assignments = RateMeter()
         self.moe_local_assignments = RateMeter()
         self.moe_absent_assignments = RateMeter()
+        # The admission's grouped expert matmul (ops/moe.py; None and zero
+        # where the admit program does not hold it): what multiplies the
+        # sorted pairs, the pairs it multiplied and the rows of the pieces
+        # (128 rows) its kernels multiplied, every piece whole: rows /
+        # tile_rows is the pieces' fill.
+        self.grouped_matmul: str | None = None
+        self.moe_grouped_rows = RateMeter()
+        self.moe_grouped_tile_rows = RateMeter()
         self.experts_held: list[int] | None = None  # [first, count]
         self.attn_blocks = 1  # attention blocks (cache rows) a layer
         self.latent_positions_valid = RateMeter()  # cached rows the served
@@ -520,6 +529,9 @@ class ServeMetrics:
                 "moe_local_assignments": self.moe_local_assignments.count,
                 "moe_absent_assignments": self.moe_absent_assignments.count,
                 "experts_held": self.experts_held,
+                "grouped_matmul": self.grouped_matmul,
+                "moe_grouped_rows": self.moe_grouped_rows.count,
+                "moe_grouped_tile_rows": self.moe_grouped_tile_rows.count,
             },
             "latent_pool": {
                 "attn_blocks": self.attn_blocks,
@@ -673,6 +685,7 @@ class ServeMetrics:
                 for name, value in s[section].items()
                 if name not in (
                     "moe_expert_load", "experts_held", "attn_blocks",
+                    "grouped_matmul",
                     *self.kv_pool_static,
                 )
             ),
@@ -1508,6 +1521,9 @@ class StreamingGenerator:
         # What the last tick block's routed expert layers counted on the
         # device (_build); None for a config without one.
         self._tick_stats = None
+        # What the admissions since the last sync counted of their grouped
+        # expert matmul, on the device (_build); fetched with the sync.
+        self._admit_stats: list = []
         self._build()
         if self._kv_backend is not None:
             self.metrics.note_backend(self._kv_backend)
@@ -1597,6 +1613,12 @@ class StreamingGenerator:
         data = 1 if mesh is None else mesh.shape.get("data", 1)
         R = min(B, -(-max(1, _ADMIT_CHUNK_TOKENS // P) // data) * data)
         self._admit_chunk_rows = R
+        # A trip's R * P tokens go through the grouped expert matmul
+        # (ops/moe.py decides by the static shapes): the admit program
+        # then counts what its kernels multiplied, one more output.
+        grouped = moe.grouped_form(cfg, R * P)
+        if grouped:
+            self.metrics.grouped_matmul = "kernel"
 
         def admit(params, caches, last_tok, pos, gen, prompts, admit_mask,
                   keys):
@@ -1629,9 +1651,16 @@ class StreamingGenerator:
                 return pool
 
             def chunk(i, state):
-                caches, last_tok, pos, gen = state
+                caches, last_tok, pos, gen, *counts = state
                 slots = order[jnp.minimum(i * R + jnp.arange(R), count - 1)]
-                logits, fresh = prefill(params, cfg, prompts[slots], P, mesh)
+                logits, fresh, *chosen = prefill(
+                    params, cfg, prompts[slots], P, mesh, routing=grouped
+                )
+                if grouped:  # the routing [L_moe, R, P, top_k]
+                    counts = [
+                        counts[0]
+                        + moe.grouped_counts(chosen[0], cfg.n_experts)
+                    ]
                 if latent:
                     rows = (fresh,)  # [L, R, P, C]
                 elif kv_int8:
@@ -1656,10 +1685,13 @@ class StreamingGenerator:
                     last_tok.at[slots].set(tok0),
                     pos.at[slots].set(P),
                     gen.at[slots].set(first.at[:, 0].set(tok0)),
+                    *counts,
                 )
 
+            counts0 = (jnp.zeros((2,), jnp.int32),) if grouped else ()
             return lax.fori_loop(
-                0, (count + R - 1) // R, chunk, (caches, last_tok, pos, gen)
+                0, (count + R - 1) // R, chunk,
+                (caches, last_tok, pos, gen, *counts0),
             )
 
         K = self._ticks_per_sync
@@ -1843,7 +1875,13 @@ class StreamingGenerator:
         _tick = jax.jit(tick_block, donate_argnums=(1,))
         # Raw (un-jitted) body for decode_roofline's fori-chained windows.
         self._tick_block_raw = tick_block
-        self._admit_fn = lambda *a: _admit(self._params, *a)
+
+        def admit_fn(*a):
+            out = _admit(self._params, *a)
+            self._admit_stats.extend(out[4:])
+            return out[:4]
+
+        self._admit_fn = admit_fn
 
         def tick_fn(*a):
             out = _tick(self._params, *a)
@@ -3829,9 +3867,16 @@ class StreamingGenerator:
             # together (separate np.asarray calls are separate round
             # trips).
             with xprof.span(xprof.SPAN_SYNC):
-                done_h, n_out_h, gen_h, pos_h, stats_h = jax.device_get(
-                    (done, n_out, gen, pos, self._tick_stats)
+                done_h, n_out_h, gen_h, pos_h, stats_h, admits_h = (
+                    jax.device_get((
+                        done, n_out, gen, pos, self._tick_stats,
+                        self._admit_stats,
+                    ))
                 )
+            self._admit_stats = []
+            for rows, tile_rows in admits_h:
+                self.metrics.moe_grouped_rows.add(int(rows))
+                self.metrics.moe_grouped_tile_rows.add(int(tile_rows))
             if stats_h is not None:
                 touched, load, *fates = stats_h
                 self.metrics.moe_experts_touched.add(int(touched))

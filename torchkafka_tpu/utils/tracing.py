@@ -62,8 +62,9 @@ called ``token.commit``):
 
 The Pallas kernels carry fixed names too (``pl.pallas_call(name=...)`` in
 ``ops/``): ``tk_kvattn_dynlen``, ``tk_kvattn_paged``, ``tk_flash_fwd``,
-``tk_flash_bwd_dq``, ``tk_flash_bwd_dkv``, ``tk_qmatmul`` — the device
-trace names each kernel's operation after them.
+``tk_flash_fwd_win``, ``tk_flash_bwd_dq``, ``tk_flash_bwd_dkv``,
+``tk_qmatmul``, ``tk_gmm_gate_up``, ``tk_gmm_down`` — the device trace
+names each kernel's operation after them.
 
 Record-level lifecycle tracing (who waited where, per record) is the
 separate ``torchkafka_tpu.obs`` subsystem; these annotations are the
