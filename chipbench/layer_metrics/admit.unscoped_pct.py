@@ -1,0 +1,8 @@
+"""Share of the admit program's leaf-operation time that stands under no
+scope of the program's."""
+
+from chipbench.layer_metrics import _scopes
+
+
+def read(run):
+    return _scopes.unscoped_pct(run, r"admit")

@@ -206,7 +206,7 @@ def test_scheduler_counters_match_a_hand_counted_run(model):
     assert served == 10
     assert got == {
         "slot_ticks_run": 96, "slot_ticks_served": 70, "admit_calls": 3,
-        "admit_rows": 10, "admit_rows_prefilled": 12, "admit_chunks": 3,
+        "admit_rows": 10, "admit_rows_prefilled": 12,
     }
     # Cumulative: a second run() resets the rate clocks, not these.
     for i in range(2):
@@ -239,10 +239,9 @@ def test_a_chunked_admission_prefills_what_its_chunks_hold(
     assert sum(1 for _ in server.run(max_records=records)) == records
     got = server.metrics.summary()["scheduler"]
     assert got["admit_rows"] == records
-    assert got["admit_chunks"] == chunks
     assert got["admit_rows_prefilled"] == prefilled == 2 * chunks
     assert got["slot_ticks_served"] == records * (MAX_NEW - 1)
-    assert f"torchkafka_serve_admit_chunks_total {chunks}\n" in (
+    assert f"torchkafka_serve_admit_rows_prefilled_total {prefilled}\n" in (
         server.metrics.render_prometheus()
     )
     server.close()

@@ -49,6 +49,7 @@ from torchkafka_tpu.models.transformer import (
     scan_periods,
     shardings_for_mesh,
 )
+from torchkafka_tpu.utils import tracing
 
 
 class KVCache(NamedTuple):
@@ -73,6 +74,7 @@ class KindKVCache(NamedTuple):
     v_win: jax.Array
 
 
+@tracing.scope(tracing.SCOPE_KV_WRITE)
 def ring_rows(rows: jax.Array, window: int) -> jax.Array:
     """A window layer's rows over positions [0, S), [L, B, S, C], as its
     ring holds them once S positions are written: the last ``window`` of
@@ -120,6 +122,7 @@ def filter_logits(
     return logits
 
 
+@tracing.scope(tracing.SCOPE_HEAD)
 def sample_logits(
     logits: jax.Array,
     key: jax.Array,
@@ -314,6 +317,16 @@ def _attend_cached(
     dot's HBM read, so int8 KV trades ~20% equal-slot throughput for
     ~2× pool capacity; a Pallas decode kernel streaming int8 directly
     is the known fix."""
+    attn = _read_cached(q, cache_k, cache_v, valid, cfg, k_scale, v_scale)
+    if routing:  # (x, the routed expert layer's choices or None)
+        return _attn_tail_routing(x, attn, layer, cfg)
+    return _attn_tail(x, attn, layer, cfg)
+
+
+@tracing.scope(tracing.SCOPE_KV_READ)
+def _read_cached(q, cache_k, cache_v, valid, cfg, k_scale, v_scale):
+    """``_attend_cached``'s read: scores, masked softmax and values →
+    [B, S, H, Dh]."""
     b, s, h, dh = q.shape
     kk = cache_k.astype(cfg.dtype)
     vv = cache_v.astype(cfg.dtype)
@@ -337,16 +350,13 @@ def _attend_cached(
     probs = jax.nn.softmax(scores, axis=-1)
     if v_scale is not None:
         probs = probs * v_scale.transpose(0, 2, 1)[:, :, None, None, :]
-    attn = jnp.einsum(
+    return jnp.einsum(
         "bkrsm,bmke->bskre", probs.astype(cfg.dtype), vv,
         preferred_element_type=jnp.float32,
     ).astype(cfg.dtype).reshape(b, s, h, dh)
-    if routing:  # (x, the routed expert layer's choices or None)
-        return _attn_tail_routing(x, attn, layer, cfg)
-    return _attn_tail(x, attn, layer, cfg)
 
 
-def _attend_merged(x, q, slab_k, slab_v, valid, layer, cfg):
+def _attend_merged(x, q, slab_k, slab_v, valid, layer, cfg, scope):
     """``_attend_cached`` for ONE query a slot against slabs whose rows
     hold a position's kv heads side by side, [B, M, K * Dh] (a pool by
     layer kind, ``KindKVCache``): the queries are laid block-diagonal,
@@ -356,8 +366,16 @@ def _attend_merged(x, q, slab_k, slab_v, valid, layer, cfg):
     times the FLOPs of the grouped form and move the same bytes; the
     grouped einsum over [B, M, K, Dh] has the compiler copy a layer's
     slab out of the pool and re-tile it every tick (PERF.md, PR 34).
-    x: [B, 1, D]; q: [B, 1, H, Dh]; valid: [B, M]. Returns (x, the routed
-    expert layer's choices or None)."""
+    x: [B, 1, D]; q: [B, 1, H, Dh]; valid: [B, M]; ``scope``: the read's
+    name by the pool's kind (``tracing.SCOPE_KV_READ_WINDOW`` or
+    ``_FULL``). Returns (x, the routed expert layer's choices or None)."""
+    with tracing.scope(scope):
+        attn = _read_merged(q, slab_k, slab_v, valid, cfg)
+    return _attn_tail_routing(x, attn, layer, cfg)
+
+
+def _read_merged(q, slab_k, slab_v, valid, cfg):
+    """``_attend_merged``'s read → [B, 1, H, Dh]."""
     b, _s, h, dh = q.shape
     n_kv = cfg.n_kv_heads
     own = jnp.arange(h)[:, None] // (h // n_kv) == jnp.arange(n_kv)[None, :]
@@ -375,10 +393,9 @@ def _attend_merged(x, q, slab_k, slab_v, valid, layer, cfg):
         "bhm,bmc->bhc", probs.astype(cfg.dtype), slab_v.astype(cfg.dtype),
         preferred_element_type=jnp.float32,
     ).reshape(b, h, n_kv, dh)
-    attn = jnp.sum(
+    return jnp.sum(
         jnp.where(own[None, :, :, None], wide, 0.0), axis=2
     ).astype(cfg.dtype)[:, None]
-    return _attn_tail_routing(x, attn, layer, cfg)
 
 
 def _attn_tail(x, attn, layer, cfg):
@@ -393,8 +410,11 @@ def _attn_tail(x, attn, layer, cfg):
 def _attn_tail_routing(x, attn, layer, cfg):
     """``_attn_tail`` and the expert choices it made: (x, routing [B, S,
     top_k] for a routed expert layer (ops/moe.py), else None)."""
-    x = x + jnp.einsum("bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype))
-    h = _rms_norm(x, layer["ln2"])
+    with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+        x = x + jnp.einsum(
+            "bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype)
+        )
+        h = _rms_norm(x, layer["ln2"])
     if "router" not in layer:
         return x + _dense_mlp(h, layer, cfg), None
     if cfg.routed_moe:
@@ -413,6 +433,7 @@ def _attn_tail_routing(x, attn, layer, cfg):
     return x + mlp_out, None
 
 
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
 def _project_qkv(x, layer, cfg):
     """RMSNorm + q/k/v projections for decode queries. x: [B, S, D] —
     S=1 for a decode tick, S=k+1 for spec decode's multi-query verify."""
@@ -423,6 +444,17 @@ def _project_qkv(x, layer, cfg):
     return q, k, v
 
 
+@tracing.scope(tracing.SCOPE_HEAD)
+def head_logits(params, cfg, x, at: int):
+    """The final norm and the head's product at position ``at`` of x
+    [B, S, D] → float32 logits [B, V]."""
+    x = _rms_norm(x, params["ln_f"])
+    return jnp.einsum(
+        "bd,dv->bv", x[:, at], load_weight(params["lm_head"], cfg.dtype),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _layer_step(x, layer, cache_k, cache_v, pos, cfg):
     """One token through one layer. x: [B, 1, D]; caches [B, max_len, K, Dh];
     pos: scalar current position. Returns (x, new_cache_k, new_cache_v)."""
@@ -430,8 +462,9 @@ def _layer_step(x, layer, cache_k, cache_v, pos, cfg):
     positions = pos[None] if pos.ndim == 0 else pos
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
-    cache_k = lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
-    cache_v = lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
+    with tracing.scope(tracing.SCOPE_KV_WRITE):
+        cache_k = lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype), (0, pos, 0, 0))
+        cache_v = lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype), (0, pos, 0, 0))
     valid = jnp.arange(cache_k.shape[1]) <= pos  # attend to cache[0..pos]
     x = _attend_cached(x, q, cache_k, cache_v, valid, layer, cfg)
     return x, cache_k, cache_v
@@ -478,29 +511,28 @@ def prefill(
             tokens, slot_sharding(mesh, tokens.ndim)
         )
     batch, seq = tokens.shape
-    x = embed_rows(params["embed"], tokens, cfg.dtype)
+    with tracing.scope(tracing.SCOPE_EMBED):
+        x = embed_rows(params["embed"], tokens, cfg.dtype)
     positions = jnp.arange(seq)
 
     def capture(x, layer):
         # Same math as Transformer._layer, but returns k/v for the cache.
-        h = _rms_norm(x, layer["ln1"])
-        k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
-        v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
-        k = _rope(k, positions, cfg.rope_theta)
+        with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+            h = _rms_norm(x, layer["ln1"])
+            k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
+            v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
+            k = _rope(k, positions, cfg.rope_theta)
         x, _stats, (_latent, chosen) = model._layer_capture(x, layer)
         return x, (k, v, chosen)
 
     x, (ks, vs, chosen) = lax.scan(capture, x, params["layers"])
-    x = _rms_norm(x, params["ln_f"])
-    logits = jnp.einsum(
-        "bd,dv->bv", x[:, -1], load_weight(params["lm_head"], cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    logits = head_logits(params, cfg, x, -1)
     nl, kh, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-    cache_k = jnp.zeros((nl, batch, max_len, kh, dh), cfg.dtype)
-    cache_v = jnp.zeros((nl, batch, max_len, kh, dh), cfg.dtype)
-    cache_k = lax.dynamic_update_slice(cache_k, ks.astype(cfg.dtype), (0, 0, 0, 0, 0))
-    cache_v = lax.dynamic_update_slice(cache_v, vs.astype(cfg.dtype), (0, 0, 0, 0, 0))
+    with tracing.scope(tracing.SCOPE_KV_WRITE):
+        cache_k = jnp.zeros((nl, batch, max_len, kh, dh), cfg.dtype)
+        cache_v = jnp.zeros((nl, batch, max_len, kh, dh), cfg.dtype)
+        cache_k = lax.dynamic_update_slice(cache_k, ks.astype(cfg.dtype), (0, 0, 0, 0, 0))
+        cache_v = lax.dynamic_update_slice(cache_v, vs.astype(cfg.dtype), (0, 0, 0, 0, 0))
     cache = _constrain_cache(KVCache(cache_k, cache_v), mesh)
     return (logits, cache, chosen) if routing else (logits, cache)
 
@@ -512,24 +544,22 @@ def _prefill_kinds(params, model: Transformer, tokens: jax.Array, max_len: int):
     routed expert layers' choices [L, B, S, top_k] or None)."""
     cfg = model.cfg
     batch, seq = tokens.shape
-    x = embed_rows(params["embed"], tokens, cfg.dtype)
+    with tracing.scope(tracing.SCOPE_EMBED):
+        x = embed_rows(params["embed"], tokens, cfg.dtype)
     positions = jnp.arange(seq)
 
     def capture(x, layer, j, _i):
         # Transformer._layer's k and v once more, beside it (as ``prefill``).
         kind = cfg.layer_kind(j)
-        h = _rms_norm(x, layer["ln1"])
-        k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
-        v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
+        with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+            h = _rms_norm(x, layer["ln1"])
+            k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
+            v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
         x, _stats, (_latent, chosen) = model._layer_capture(x, layer, kind)
         return x, (_rope(k, positions, kind[1]), v, chosen)
 
     x, kv = scan_periods(cfg, params["layers"], x, capture)
-    x = _rms_norm(x, params["ln_f"])
-    logits = jnp.einsum(
-        "bd,dv->bv", x[:, -1], load_weight(params["lm_head"], cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    logits = head_logits(params, cfg, x, -1)
 
     def pool(window: bool, t: int):
         """One kind's k (t 0) or v (t 1): [periods, count, B, S, K, Dh]
@@ -546,11 +576,13 @@ def _prefill_kinds(params, model: Transformer, tokens: jax.Array, max_len: int):
     if kv[0][2] is not None:  # [periods, B, S, K] a layer of the period
         chosen = jnp.stack([y[2] for y in kv], axis=1)
         chosen = chosen.reshape(-1, *chosen.shape[2:])
-    return logits, KindKVCache(
-        jnp.pad(pool(False, 0), grow), jnp.pad(pool(False, 1), grow),
-        ring_rows(pool(True, 0), cfg.sliding_window),
-        ring_rows(pool(True, 1), cfg.sliding_window),
-    ), chosen
+    with tracing.scope(tracing.SCOPE_KV_WRITE):
+        cache = KindKVCache(
+            jnp.pad(pool(False, 0), grow), jnp.pad(pool(False, 1), grow),
+            ring_rows(pool(True, 0), cfg.sliding_window),
+            ring_rows(pool(True, 1), cfg.sliding_window),
+        )
+    return logits, cache, chosen
 
 
 def latent_forward(params, model: Transformer, tokens: jax.Array):
@@ -563,7 +595,8 @@ def latent_forward(params, model: Transformer, tokens: jax.Array):
     Unlike ``prefill``'s ``KVCache`` the rows are S long, not a pool: the
     caller writes them where its pool keeps them."""
     cfg = model.cfg
-    x = embed_rows(params["embed"], tokens, cfg.dtype)
+    with tracing.scope(tracing.SCOPE_EMBED):
+        x = embed_rows(params["embed"], tokens, cfg.dtype)
 
     def capture(x, layer):
         x, _stats, cached = model._layer_capture(x, layer)
@@ -581,11 +614,7 @@ def latent_forward(params, model: Transformer, tokens: jax.Array):
         latents.append(lat)
         if expert_mlp:
             routing = rt
-    x = _rms_norm(x, params["ln_f"])
-    logits = jnp.einsum(
-        "bd,dv->bv", x[:, -1], load_weight(params["lm_head"], cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    logits = head_logits(params, cfg, x, -1)
     latents = latents[0] if len(latents) == 1 else jnp.concatenate(latents)
     return logits, latents.astype(cfg.dtype), routing
 
@@ -595,7 +624,8 @@ def _decode_one(
     mesh: Mesh | None = None,
 ):
     """token: [B] → logits [B, V], updated cache. pos: scalar position."""
-    x = embed_rows(params["embed"], token, cfg.dtype)[:, None, :]  # [B,1,D]
+    with tracing.scope(tracing.SCOPE_EMBED):
+        x = embed_rows(params["embed"], token, cfg.dtype)[:, None, :]  # [B,1,D]
 
     def body(x, inputs):
         layer, ck, cv = inputs
@@ -603,11 +633,7 @@ def _decode_one(
         return x, (ck, cv)
 
     x, (ck, cv) = lax.scan(body, x, (params["layers"], cache.k, cache.v))
-    x = _rms_norm(x, params["ln_f"])
-    logits = jnp.einsum(
-        "bd,dv->bv", x[:, 0], load_weight(params["lm_head"], cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    logits = head_logits(params, cfg, x, 0)
     return logits, _constrain_cache(KVCache(ck, cv), mesh)
 
 

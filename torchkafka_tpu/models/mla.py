@@ -36,8 +36,10 @@ import jax.numpy as jnp
 
 from torchkafka_tpu.models.quant import load_weight
 from torchkafka_tpu.models.transformer import TransformerConfig, _rms_norm, _rope
+from torchkafka_tpu.utils import tracing
 
 
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
 def project(h, layer, cfg: TransformerConfig, positions):
     """Normed activations h [B, S, D] at ``positions`` ([S] or [B, S]) →
     (q_nope [B, S, H, nope], q_rope [B, S, H, rope] roped, latent [B, S,
@@ -75,14 +77,22 @@ def attend_full(q_nope, q_rope, latent, layer, cfg, *, use_flash: bool):
     """Causal self-attention over a whole sequence, un-absorbed →
     [B, S, H, v]."""
     b, s, h, _ = q_nope.shape
-    c, k_r = jnp.split(latent, [cfg.kv_lora_rank], axis=-1)
-    kv = jnp.einsum("bsr,rhe->bshe", c, load_weight(layer["wkvb"], cfg.dtype))
-    k_nope, v = jnp.split(kv, [cfg.qk_nope_dim], axis=-1)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_r[:, :, None, :], (b, s, h, k_r.shape[-1]))],
-        axis=-1,
-    )
+    with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+        c, k_r = jnp.split(latent, [cfg.kv_lora_rank], axis=-1)
+        kv = jnp.einsum("bsr,rhe->bshe", c, load_weight(layer["wkvb"], cfg.dtype))
+        k_nope, v = jnp.split(kv, [cfg.qk_nope_dim], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_r[:, :, None, :], (b, s, h, k_r.shape[-1]))],
+            axis=-1,
+        )
+    return _attend_whole(q, k, v, cfg, use_flash)
+
+
+@tracing.scope(tracing.SCOPE_ATTN_FLASH)
+def _attend_whole(q, k, v, cfg, use_flash: bool):
+    """``attend_full``'s attention proper, heads up-projected."""
+    s = q.shape[1]
     if use_flash:
         from torchkafka_tpu.ops.flash import flash_forward
 
@@ -105,11 +115,22 @@ def attend_absorbed(q_nope, q_rope, pool, l, pos_b, layer, cfg):
     of the double layer) of the stacked latent pool [L, B, M, rank +
     rope], positions 0..pos_b valid → [B, 1, H, v]."""
     r = cfg.kv_lora_rank
-    w_uk, w_uv = jnp.split(
-        load_weight(layer["wkvb"], cfg.dtype), [cfg.qk_nope_dim], axis=-1
-    )
-    q_lat = jnp.einsum("bshe,rhe->bshr", q_nope, w_uk)
-    q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)  # [B, 1, H, C]
+    with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+        w_uk, w_uv = jnp.split(
+            load_weight(layer["wkvb"], cfg.dtype), [cfg.qk_nope_dim], axis=-1
+        )
+        q_lat = jnp.einsum("bshe,rhe->bshr", q_nope, w_uk)
+        q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)  # [B, 1, H, C]
+    o_lat = _read_latent(q_cat, pool, l, pos_b, cfg)
+    with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+        return jnp.einsum("bshr,rhe->bshe", o_lat[..., :r], w_uv)
+
+
+@tracing.scope(tracing.SCOPE_KV_READ_LATENT)
+def _read_latent(q_cat, pool, l, pos_b, cfg):
+    """``attend_absorbed``'s read: the absorbed queries [B, 1, H, rank +
+    rope] against row ``l`` of the pool → [B, 1, H, rank + rope], the
+    roped key's columns still there."""
     slab = jax.lax.dynamic_index_in_dim(pool, l, keepdims=False)
     scores = jnp.einsum(
         "bshc,bmc->bhsm", q_cat, slab, preferred_element_type=jnp.float32
@@ -121,8 +142,7 @@ def attend_absorbed(q_nope, q_rope, pool, l, pos_b, layer, cfg):
     # Over the slab's full width, the roped key's columns dropped after: a
     # ``slab[..., :r]`` operand is a copy of the slab (0.3 GB a layer a
     # tick at the benchmark's pool), this reads it in place.
-    o_lat = jnp.einsum(
+    return jnp.einsum(
         "bhsm,bmc->bshc", probs.astype(cfg.dtype), slab,
         preferred_element_type=jnp.float32,
-    ).astype(cfg.dtype)[..., :r]
-    return jnp.einsum("bshr,rhe->bshe", o_lat, w_uv)
+    ).astype(cfg.dtype)

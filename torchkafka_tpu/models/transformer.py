@@ -56,6 +56,7 @@ from torchkafka_tpu.ops.attention import (
     ulysses_attention,
 )
 from torchkafka_tpu.ops.xent import dense_softmax_xent, fused_softmax_xent
+from torchkafka_tpu.utils import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -879,6 +880,7 @@ def _moe_mlp_capacity(
     return out, stats
 
 
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
 def _rope(
     x: jax.Array, positions: jax.Array, theta: "float | RopeKind",
     interleave: bool = False,
@@ -914,6 +916,7 @@ def _rope(
     return out.astype(x.dtype)
 
 
+@tracing.scope(tracing.SCOPE_FFN)
 def _dense_mlp(h: jax.Array, layer: Mapping[str, jax.Array], cfg) -> jax.Array:
     """SwiGLU on normed activations h [B, S, D]."""
     gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, load_weight(layer["w_gate"], cfg.dtype)))
@@ -984,11 +987,14 @@ def _double_layer(x, layer, cfg: "TransformerConfig", attend):
             n: lax.dynamic_index_in_dim(stacks[n], 2 * l + i, keepdims=False)
             for n in _BLOCK_TENSORS if n in stacks
         }
-        attn = attend(i, _rms_norm(x, blk["ln1"]), blk)
-        x = x + jnp.einsum(
-            "bshe,hed->bsd", attn, load_weight(blk["wo"], cfg.dtype)
-        )
-        return x, _rms_norm(x, blk["ln2"]), blk
+        with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+            h = _rms_norm(x, blk["ln1"])
+        attn = attend(i, h, blk)
+        with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+            x = x + jnp.einsum(
+                "bshe,hed->bsd", attn, load_weight(blk["wo"], cfg.dtype)
+            )
+            return x, _rms_norm(x, blk["ln2"]), blk
 
     a0, m, blk0 = block(0, x)
     branch, routing = routed_moe_mlp(m, layer, cfg, experts=(
@@ -1130,6 +1136,7 @@ class Transformer:
     def init(self, rng: jax.Array) -> dict:
         return init_params(rng, self.cfg)
 
+    @tracing.scope(tracing.SCOPE_ATTN_FLASH)
     def _attention(self, q, k, v, window=None):
         if window is not None:
             # A sliding-window layer (never under a mesh or a sequence-
@@ -1229,7 +1236,8 @@ class Transformer:
             x, routing = _double_layer(x, layer, cfg, attend)
             stats = jnp.zeros((2, 1), jnp.float32)
             return x, stats, (jnp.stack(latents), routing)
-        h = _rms_norm(x, layer["ln1"])
+        with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+            h = _rms_norm(x, layer["ln1"])
         latent = None
         if cfg.is_mla:
             from torchkafka_tpu.models import mla
@@ -1240,8 +1248,11 @@ class Transformer:
             )
         else:
             attn = self._gqa(h, layer, positions, kind)
-        x = x + jnp.einsum("bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype))
-        h = _rms_norm(x, layer["ln2"])
+        with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+            x = x + jnp.einsum(
+                "bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype)
+            )
+            h = _rms_norm(x, layer["ln2"])
         stats, routing = jnp.zeros((2, 1), jnp.float32), None
         if "router" not in layer:  # a dense layer (all of a dense config's)
             x = x + _dense_mlp(h, layer, cfg)
@@ -1258,11 +1269,12 @@ class Transformer:
     def _gqa(self, h, layer, positions, kind=None):
         cfg = self.cfg
         window, rope = kind or (None, cfg.rope_theta)
-        q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
-        k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
-        v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
-        q = _rope(q, positions, rope)
-        k = _rope(k, positions, rope)
+        with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+            q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
+            k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
+            v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
+            q = _rope(q, positions, rope)
+            k = _rope(k, positions, rope)
         if window is not None:
             return self._attention(q, k, v, window)
         if cfg.n_kv_heads != cfg.n_heads and not (
@@ -1285,7 +1297,8 @@ class Transformer:
         the lm_head projection — split out so ``loss`` can feed the fused
         blocked CE without ever materialising [B, S, V] logits."""
         cfg = self.cfg
-        x = embed_rows(params["embed"], tokens, cfg.dtype)
+        with tracing.scope(tracing.SCOPE_EMBED):
+            x = embed_rows(params["embed"], tokens, cfg.dtype)
         n_tokens = tokens.shape[0] * tokens.shape[1]
 
         if self.mesh is not None and self.mesh.shape.get("pp", 1) > 1:
@@ -1368,7 +1381,8 @@ class Transformer:
         # stats: [L, 2, E] token-summed routing statistics; per-layer aux,
         # averaged over layers (identical math in both branches).
         aux = jnp.mean(jax.vmap(lambda s: router_aux(s, n_tokens))(stats))
-        return _rms_norm(x, params["ln_f"]), aux
+        with tracing.scope(tracing.SCOPE_HEAD):
+            return _rms_norm(x, params["ln_f"]), aux
 
     def __call__(
         self, params: dict, tokens: jax.Array, *, return_aux: bool = False
@@ -1376,10 +1390,11 @@ class Transformer:
         """tokens [B, S] int32 → logits [B, S, V] float32 (and, with
         ``return_aux``, the mean per-layer router load-balance loss)."""
         x, aux = self.trunk(params, tokens)
-        logits = jnp.einsum(
-            "bsd,dv->bsv", x, load_weight(params["lm_head"], self.cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
+        with tracing.scope(tracing.SCOPE_HEAD):
+            logits = jnp.einsum(
+                "bsd,dv->bsv", x, load_weight(params["lm_head"], self.cfg.dtype),
+                preferred_element_type=jnp.float32,
+            )
         if return_aux:
             return logits, aux
         return logits
@@ -1411,22 +1426,24 @@ class Transformer:
         # Shift once for both CE paths: position i predicts token i+1; the
         # final position (and padded rows) carry mask 0. Keeping full length
         # S also keeps the batch divisible over an sp axis.
-        targets = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
-        m = jnp.ones(tokens.shape, jnp.float32) if mask is None else mask
-        m = jnp.pad(m[:, 1:].astype(jnp.float32), ((0, 0), (0, 1)))
-        if self._use_fused_ce(params):
-            ce = fused_softmax_xent(
-                x, params["lm_head"], targets, m,
-                cfg.ce_block_size, cfg.dtype,
-            )
-        else:
-            # Dense fallback shares the oracle implementation (ops/xent.py)
-            # — one CE definition, two materialisation strategies.
-            ce = dense_softmax_xent(
-                x, load_weight(params["lm_head"], cfg.dtype), targets, m,
-                cfg.dtype,
-            )
-        return ce + cfg.router_aux_coef * aux
+        with tracing.scope(tracing.SCOPE_LOSS):
+            targets = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+            m = jnp.ones(tokens.shape, jnp.float32) if mask is None else mask
+            m = jnp.pad(m[:, 1:].astype(jnp.float32), ((0, 0), (0, 1)))
+            if self._use_fused_ce(params):
+                ce = fused_softmax_xent(
+                    x, params["lm_head"], targets, m,
+                    cfg.ce_block_size, cfg.dtype,
+                )
+            else:
+                # Dense fallback shares the oracle implementation
+                # (ops/xent.py): one CE definition, two materialisation
+                # strategies.
+                ce = dense_softmax_xent(
+                    x, load_weight(params["lm_head"], cfg.dtype), targets, m,
+                    cfg.dtype,
+                )
+            return ce + cfg.router_aux_coef * aux
 
 
 # ----------------------------------------------------------------- train step
@@ -1530,8 +1547,9 @@ def make_train_step(
         # step. Constraining here lets the wanted layout propagate back
         # into the transpose instead.
         grads = jax.lax.with_sharding_constraint(grads, p_shardings)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
+        with tracing.scope(tracing.SCOPE_OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree_util.tree_map(lambda p, u: p + u, params, updates)
         params = jax.lax.with_sharding_constraint(params, p_shardings)
         return params, opt_state, loss
 

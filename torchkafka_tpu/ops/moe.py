@@ -69,6 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from torchkafka_tpu.models.quant import load_weight
 from torchkafka_tpu.ops.flash import _default_interpret, tpu_compiler_params
+from torchkafka_tpu.utils import tracing
 
 # Token-choice pairs an expert must average before the sorted, grouped
 # form is taken: below it the all-experts einsum streams the same weights
@@ -80,6 +81,7 @@ from torchkafka_tpu.ops.flash import _default_interpret, tpu_compiler_params
 _GROUPED_MIN_PAIRS_PER_EXPERT = 32
 
 
+@tracing.scope(tracing.SCOPE_MOE_ROUTE)
 def route(h, router, bias, *, top_k: int, scaling: float,
           score: str = "sigmoid", norm_topk: bool = True):
     """h [N, D] → (idx [N, K] int32, weights [N, K] float32). ``bias``
@@ -195,6 +197,7 @@ def _gmm_kernel(base_ref, offsets_ref, expert_ref, tile_ref, x_ref, *refs,
     lax.fori_loop(0, tm // ts, piece, None)
 
 
+@tracing.scope(tracing.SCOPE_MOE_EXPERTS)
 def _gmm(rows, mats, base, walk, tm: int, ts: int, name: str):
     """rows [M, K] sorted by expert, M a multiple of the block ``tm``, a
     multiple of the piece ``ts``; mats: one ``[.., K, N]`` stack (rows ·
@@ -256,18 +259,21 @@ def grouped_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
     base, count = at or (0, w_gate.shape[0])
     tm, ts = _gmm_rows(n * k)
     tiles_m = -(-n * k // tm)
-    flat = idx.reshape(-1)
-    order = jnp.argsort(flat, stable=True)  # sorted pair -> pair
-    sizes = jnp.zeros((count,), jnp.int32).at[flat].add(1)
-    walk = _gmm_tiles(sizes, tiles_m, tm)
-    # Rows past N·K fill the last tile: no expert's, never read back.
-    padded = jnp.pad(order, (0, tiles_m * tm - n * k), mode="edge")
-    rows = h[padded // k]  # [tiles · tm, D]
+    with tracing.scope(tracing.SCOPE_MOE_ROUTE):
+        flat = idx.reshape(-1)
+        order = jnp.argsort(flat, stable=True)  # sorted pair -> pair
+        sizes = jnp.zeros((count,), jnp.int32).at[flat].add(1)
+        walk = _gmm_tiles(sizes, tiles_m, tm)
+    with tracing.scope(tracing.SCOPE_MOE_DISPATCH):
+        # Rows past N·K fill the last tile: no expert's, never read back.
+        padded = jnp.pad(order, (0, tiles_m * tm - n * k), mode="edge")
+        rows = h[padded // k]  # [tiles · tm, D]
     mid = _gmm(rows, (w_gate, w_up), base, walk, tm, ts, "tk_gmm_gate_up")
     out = _gmm(mid, (w_down,), base, walk, tm, ts, "tk_gmm_down")  # sorted
-    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
-    out = out[inverse].reshape(n, k, -1).astype(jnp.float32)
-    return jnp.einsum("nkd,nk->nd", out, weights).astype(h.dtype)
+    with tracing.scope(tracing.SCOPE_MOE_DISPATCH):
+        inverse = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+        out = out[inverse].reshape(n, k, -1).astype(jnp.float32)
+        return jnp.einsum("nkd,nk->nd", out, weights).astype(h.dtype)
 
 
 def compacted_experts(h, idx, weights, w_gate, w_up, w_down, e: int,
@@ -292,13 +298,14 @@ def compacted_experts(h, idx, weights, w_gate, w_up, w_down, e: int,
     the weighted results to their tokens in float32."""
     n, k = idx.shape
     d = h.shape[-1]
-    flat = idx.reshape(-1)
-    key = jnp.where((flat >= 0) & (flat < e), flat, e)
-    order = jnp.argsort(key, stable=True)  # local pairs first, by expert
-    sizes = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
-    starts = jnp.cumsum(sizes) - sizes
-    tiles_to = jnp.cumsum((sizes + cap - 1) // cap)  # tiles up to expert e
-    w_flat = weights.reshape(-1)
+    with tracing.scope(tracing.SCOPE_MOE_ROUTE):
+        flat = idx.reshape(-1)
+        key = jnp.where((flat >= 0) & (flat < e), flat, e)
+        order = jnp.argsort(key, stable=True)  # local pairs first, by expert
+        sizes = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+        starts = jnp.cumsum(sizes) - sizes
+        tiles_to = jnp.cumsum((sizes + cap - 1) // cap)  # tiles up to expert e
+        w_flat = weights.reshape(-1)
 
     def tile(t, out):
         ex = jnp.searchsorted(tiles_to, t, side="right").astype(jnp.int32)
@@ -306,32 +313,39 @@ def compacted_experts(h, idx, weights, w_gate, w_up, w_down, e: int,
         slot = j * cap + jnp.arange(cap)
         pair = order[jnp.minimum(starts[ex] + slot, n * k - 1)]
         tok = pair // k
-        y = _swiglu(h[tok], *(
-            lax.dynamic_index_in_dim(m, base + ex, keepdims=False)
-            for m in (w_gate, w_up, w_down)
-        )).astype(jnp.float32)  # [cap, D]
+        rows = h[tok]
+        with tracing.scope(tracing.SCOPE_MOE_EXPERTS):
+            y = _swiglu(rows, *(
+                lax.dynamic_index_in_dim(m, base + ex, keepdims=False)
+                for m in (w_gate, w_up, w_down)
+            )).astype(jnp.float32)  # [cap, D]
         y = y * jnp.where(slot < sizes[ex], w_flat[pair], 0.0)[:, None]
         return out.at[tok].add(y)
 
-    out = lax.fori_loop(
-        0, tiles_to[-1], tile, jnp.zeros((n, d), jnp.float32)
-    )
-    return out.astype(h.dtype)
+    with tracing.scope(tracing.SCOPE_MOE_DISPATCH):
+        out = lax.fori_loop(
+            0, tiles_to[-1], tile, jnp.zeros((n, d), jnp.float32)
+        )
+        return out.astype(h.dtype)
 
 
 def all_experts(h, idx, weights, w_gate, w_up, w_down):
     """The same sum with every expert computed for every row and the
     unrouted ones weighted by zero."""
     e = w_gate.shape[0]
-    combine = jnp.sum(
-        jax.nn.one_hot(idx, e, dtype=jnp.float32) * weights[..., None], axis=1
-    )  # [N, E]
-    gate = jax.nn.silu(jnp.einsum("nd,edf->enf", h, w_gate))
-    up = jnp.einsum("nd,edf->enf", h, w_up)
-    out = jnp.einsum("enf,efd->end", gate * up, w_down)
-    return jnp.einsum(
-        "end,ne->nd", out.astype(jnp.float32), combine
-    ).astype(h.dtype)
+    with tracing.scope(tracing.SCOPE_MOE_DISPATCH):
+        combine = jnp.sum(
+            jax.nn.one_hot(idx, e, dtype=jnp.float32) * weights[..., None],
+            axis=1,
+        )  # [N, E]
+    with tracing.scope(tracing.SCOPE_MOE_EXPERTS):
+        gate = jax.nn.silu(jnp.einsum("nd,edf->enf", h, w_gate))
+        up = jnp.einsum("nd,edf->enf", h, w_up)
+        out = jnp.einsum("enf,efd->end", gate * up, w_down)
+    with tracing.scope(tracing.SCOPE_MOE_DISPATCH):
+        return jnp.einsum(
+            "end,ne->nd", out.astype(jnp.float32), combine
+        ).astype(h.dtype)
 
 
 def _takes_grouped(pairs: int, count: int) -> bool:
@@ -347,6 +361,7 @@ def grouped_form(cfg, rows: int) -> bool:
     )
 
 
+@tracing.scope(tracing.SCOPE_MOE_ROUTE)
 def grouped_counts(routing, count: int):
     """What ``grouped_experts`` multiplied for the routing [L, ..., K] of
     L layers' calls over ``count`` experts each: int32 (the pairs, the
@@ -426,15 +441,18 @@ def routed_moe_mlp(h, layer, cfg, experts=None):
         )
     if cfg.zero_experts:
         # The identity experts: w · h, no weights.
-        w_zero = jnp.sum(
-            jnp.where(idx >= cfg.n_experts, weights, 0.0), axis=-1
-        )
-        out = (
-            out.astype(jnp.float32) + w_zero[:, None] * x.astype(jnp.float32)
-        ).astype(x.dtype)
+        with tracing.scope(tracing.SCOPE_MOE_DISPATCH):
+            w_zero = jnp.sum(
+                jnp.where(idx >= cfg.n_experts, weights, 0.0), axis=-1
+            )
+            out = (
+                out.astype(jnp.float32)
+                + w_zero[:, None] * x.astype(jnp.float32)
+            ).astype(x.dtype)
     if cfg.n_shared_experts:
-        out = out + _swiglu(x, *(
-            load_weight(layer[n], cfg.dtype)
-            for n in ("ws_gate", "ws_up", "ws_down")
-        ))
+        with tracing.scope(tracing.SCOPE_FFN):
+            out = out + _swiglu(x, *(
+                load_weight(layer[n], cfg.dtype)
+                for n in ("ws_gate", "ws_up", "ws_down")
+            ))
     return out.reshape(b, s, d), idx.reshape(b, s, -1)

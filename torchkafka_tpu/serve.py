@@ -72,6 +72,7 @@ from torchkafka_tpu.models.generate import (
     _project_qkv,
     check_sampling_params,
     check_serving_mesh,
+    head_logits,
     kv_kmajor_scale_sharding,
     kv_kmajor_sharding,
     kv_scale_sharding,
@@ -142,6 +143,7 @@ def decode_tick_bytes(params, cfg: TransformerConfig, batch: int,
     return total - embed + embed_rows_read, kv
 
 
+@xprof.scope(xprof.SCOPE_HEAD)
 def _pick_slots(logits, key_data, idx, *, temperature, top_k, top_p):
     """Per-slot sampling with per-(record, token-index) keys.
 
@@ -166,6 +168,7 @@ def _pick_slots(logits, key_data, idx, *, temperature, top_k, top_p):
     )(logits, keys)
 
 
+@xprof.scope(xprof.SCOPE_KV_WRITE)
 def _quant_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Symmetric absmax int8 over the last (head_dim) axis:
     [..., Dh] → (int8 [..., Dh], f32 scale [...]). The shared
@@ -221,24 +224,26 @@ def _slot_layer_step_q(
         )
 
         fresh = (kq, ks, vq, vs)
-        if mesh is not None:
-            attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen_sharded(
-                q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh, layer=l, rows=fresh
-            )
-        else:
-            attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen(
-                q, ck_q, ck_s, cv_q, cv_s, pos_b, layer=l, rows=fresh
-            )
+        with xprof.scope(xprof.SCOPE_KV_READ):
+            if mesh is not None:
+                attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen_sharded(
+                    q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh, layer=l, rows=fresh
+                )
+            else:
+                attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen(
+                    q, ck_q, ck_s, cv_q, cv_s, pos_b, layer=l, rows=fresh
+                )
         x = _attn_tail(x, attn, layer, cfg)
     else:
         # The XLA read has nothing to write inside: scatters, like the
         # bf16 path (see _slot_layer_step's note), into the position-major
         # pool, payload [L, B, M, K, Dh] and scale [L, B, M, K] alike.
         rows = jnp.arange(ck_q.shape[1])
-        ck_q, ck_s, cv_q, cv_s = (
-            c.at[l, rows, pos_b].set(row)
-            for c, row in ((ck_q, kq), (ck_s, ks), (cv_q, vq), (cv_s, vs))
-        )
+        with xprof.scope(xprof.SCOPE_KV_WRITE):
+            ck_q, ck_s, cv_q, cv_s = (
+                c.at[l, rows, pos_b].set(row)
+                for c, row in ((ck_q, kq), (ck_s, ks), (cv_q, vq), (cv_s, vs))
+            )
         valid = jnp.arange(ck_q.shape[2])[None, :] <= pos_b[:, None]  # [B, M]
         x = _attend_cached(
             x, q, _layer_of(ck_q, l), _layer_of(cv_q, l), valid, layer, cfg,
@@ -298,8 +303,6 @@ class ServeMetrics:
         # nothing
         self.admit_calls = RateMeter()  # admit_records calls that prefilled
         self.admit_rows = RateMeter()  # rows admitted by a prefill
-        self.admit_chunks = RateMeter()  # trips of the dense admit program's
-        # chunk loop: ceil(admitted rows / rows a chunk) a call
         self.admit_rows_prefilled = RateMeter()  # rows the prefill programs
         # ran: chunks x rows a chunk on the dense path (the last chunk of
         # a call is padded to its static rows), the admitted rows alone on
@@ -519,7 +522,6 @@ class ServeMetrics:
                 "admit_calls": self.admit_calls.count,
                 "admit_rows": self.admit_rows.count,
                 "admit_rows_prefilled": self.admit_rows_prefilled.count,
-                "admit_chunks": self.admit_chunks.count,
             },
             "expert_layer": {
                 "moe_assignments": self.moe_assignments.count,
@@ -771,19 +773,25 @@ def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg, kind=None):
     at, last = pos_b, pos_b
     if window is not None:
         at, last = pos_b % window, jnp.minimum(pos_b, window - 1)
-    if kind is not None:  # a position's kv heads side by side in one row
-        k, v = (a.reshape(*a.shape[:2], -1) for a in (k, v))
-    cache_k = cache_k.at[l, rows, at].set(k[:, 0].astype(cache_k.dtype))
-    cache_v = cache_v.at[l, rows, at].set(v[:, 0].astype(cache_v.dtype))
+    with xprof.scope(xprof.SCOPE_KV_WRITE):
+        if kind is not None:  # a position's kv heads side by side in one row
+            k, v = (a.reshape(*a.shape[:2], -1) for a in (k, v))
+        cache_k = cache_k.at[l, rows, at].set(k[:, 0].astype(cache_k.dtype))
+        cache_v = cache_v.at[l, rows, at].set(v[:, 0].astype(cache_v.dtype))
     valid = jnp.arange(cache_k.shape[2])[None, :] <= last[:, None]  # [B, M]
     slabs = _layer_of(cache_k, l), _layer_of(cache_v, l)
     if kind is None:
         x, routing = _attend_cached(x, q, *slabs, valid, layer, cfg, routing=True)
     else:
-        x, routing = _attend_merged(x, q, *slabs, valid, layer, cfg)
+        x, routing = _attend_merged(
+            x, q, *slabs, valid, layer, cfg,
+            xprof.SCOPE_KV_READ_FULL if window is None
+            else xprof.SCOPE_KV_READ_WINDOW,
+        )
     return x, cache_k, cache_v, routing
 
 
+@xprof.scope(xprof.SCOPE_MOE_ROUTE)
 def _count_routing(stats, routing, act, cfg):
     """(experts touched, pairs by expert[, pairs by fate]) with one expert
     layer's routing [B, 1, top_k] of a tick added: a pair counts where
@@ -827,18 +835,21 @@ def _slot_layer_step_latent(x, layer, pool, l, pos_b, cfg):
 
         def attend(i, h, blk):
             q_nope, q_rope, latent = mla.project(h, blk, cfg, pos_b[:, None])
-            held[0] = held[0].at[2 * l + i, rows, pos_b].set(
-                latent[:, 0].astype(pool.dtype)
-            )
+            with xprof.scope(xprof.SCOPE_KV_WRITE):
+                held[0] = held[0].at[2 * l + i, rows, pos_b].set(
+                    latent[:, 0].astype(pool.dtype)
+                )
             return mla.attend_absorbed(
                 q_nope, q_rope, held[0], 2 * l + i, pos_b, blk, cfg
             )
 
         x, routing = _double_layer(x, layer, cfg, attend)
         return x, held[0], routing
-    h = _rms_norm(x, layer["ln1"])
+    with xprof.scope(xprof.SCOPE_ATTN_PROJ):
+        h = _rms_norm(x, layer["ln1"])
     q_nope, q_rope, latent = mla.project(h, layer, cfg, pos_b[:, None])
-    pool = pool.at[l, rows, pos_b].set(latent[:, 0].astype(pool.dtype))
+    with xprof.scope(xprof.SCOPE_KV_WRITE):
+        pool = pool.at[l, rows, pos_b].set(latent[:, 0].astype(pool.dtype))
     attn = mla.attend_absorbed(q_nope, q_rope, pool, l, pos_b, layer, cfg)
     x, routing = _attn_tail_routing(x, attn, layer, cfg)
     return x, pool, routing
@@ -1640,6 +1651,7 @@ class StreamingGenerator:
             order = jnp.argsort(~admit_mask, stable=True)
             count = admit_mask.sum(dtype=jnp.int32)
 
+            @xprof.scope(xprof.SCOPE_KV_WRITE)
             def put(pool, rows, slots):
                 # rows [L, R, ...] over the window, in the pool's layout.
                 tail = (0,) * (pool.ndim - 2)
@@ -1670,7 +1682,8 @@ class StreamingGenerator:
                         # the chunk's freshly-quantized [L, R, P, K, ·]
                         # rows (the per-tick read this layout accelerates
                         # runs max_new times an admission).
-                        rows = tuple(jnp.swapaxes(a, 2, 3) for a in rows)
+                        with xprof.scope(xprof.SCOPE_KV_WRITE):
+                            rows = tuple(jnp.swapaxes(a, 2, 3) for a in rows)
                 else:
                     # (k, v); by kind the full layers' window [0, P) and
                     # the rings as P positions leave them.
@@ -1711,7 +1724,8 @@ class StreamingGenerator:
             def one(carry, _):
                 caches, last_tok, pos, gen, done_latch, n_out, stats = carry
                 act = active_in & ~done_latch
-                x = embed_rows(params["embed"], last_tok, cfg.dtype)[:, None, :]
+                with xprof.scope(xprof.SCOPE_EMBED):
+                    x = embed_rows(params["embed"], last_tok, cfg.dtype)[:, None, :]
 
                 # The pool rides the layer loop as its CARRY, as it rides
                 # the tick loop: each layer writes its rows into the
@@ -1777,11 +1791,7 @@ class StreamingGenerator:
                         step, (x, caches, stats), xs
                     )
                     first += n
-                x = _rms_norm(x, params["ln_f"])
-                logits = jnp.einsum(
-                    "bd,dv->bv", x[:, 0], load_weight(params["lm_head"], cfg.dtype),
-                    preferred_element_type=jnp.float32,
-                )
+                logits = head_logits(params, cfg, x, 0)
                 tok = pick_rows(logits, skey, pos - P + 1)
                 # Inactive slots write stale kv at their frozen position —
                 # safe: re-admission overwrites [0, P) via prefill and every
@@ -3686,7 +3696,6 @@ class StreamingGenerator:
             chunks = -(-admitted // self._admit_chunk_rows)
             self.metrics.admit_calls.add(1)
             self.metrics.admit_rows.add(filled)
-            self.metrics.admit_chunks.add(chunks)
             self.metrics.admit_rows_prefilled.add(
                 chunks * self._admit_chunk_rows + resumed
             )

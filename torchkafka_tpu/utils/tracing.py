@@ -66,6 +66,84 @@ The Pallas kernels carry fixed names too (``pl.pallas_call(name=...)`` in
 ``tk_qmatmul``, ``tk_gmm_gate_up``, ``tk_gmm_down`` — the device trace
 names each kernel's operation after them.
 
+The device programs name their parts (``jit_admit``, ``jit_tick_block``,
+``jit__step`` and whatever else traces the shared model code): twelve
+``jax.named_scope`` names, the ``SCOPE_*`` constants below, opened through
+``scope`` where the work is written, once, in the shared building blocks.
+A scope costs a context manager while a program is traced and nothing in
+the compiled program: it becomes the ``metadata={op_name=...}`` of the HLO
+instructions traced inside it, and the optimised HLO is otherwise the
+same. The profiler's operation line carries no scope; its file does, in
+the ``/host:metadata`` plane, which holds the ``HloProto`` of every
+module that ran (``chipbench/layer_metrics/_scopes.py`` reads it back and
+joins it with the operation line by instruction name). Of nested scopes
+the innermost counts; a Pallas call's own name (below) sits inside the
+scope that holds it. The names, with what each holds and the functions
+that open it:
+
+    tk_embed           the token embedding: Transformer.trunk,
+                       generate.prefill / _prefill_kinds / latent_forward
+                       / _decode_one, serve.py's tick_block
+    tk_attn_proj       the norm before attention, q/k/v or latent
+                       projections, rope (YaRN), the output projection,
+                       its residual and the norm after it:
+                       transformer._rope, Transformer._gqa /
+                       _layer_capture, _double_layer, mla.project /
+                       attend_full (the latent's up-projection) /
+                       attend_absorbed (the absorbed products),
+                       generate._project_qkv / _attn_tail_routing /
+                       prefill's k and v, serve._slot_layer_step_latent
+    tk_kv_write        quantisation and the row or ring write:
+                       serve._quant_kv, _slot_layer_step*, admit's put,
+                       generate.ring_rows, prefill's pools
+    tk_kv_read         scores, softmax and values over CACHED positions,
+                       a pool of one kind: generate._read_cached
+                       (_attend_cached's read), serve._slot_layer_step_q
+                       (the Pallas call tk_kvattn_dynlen, the row write
+                       it holds too)
+    tk_kv_read_window  the same over a window layer's ring:
+                       serve._slot_layer_step(kind=) names it to
+                       generate._attend_merged
+    tk_kv_read_full    the same over a full layer's slab of a pool by
+                       kind: likewise
+    tk_kv_read_latent  the absorbed read of the latent pool:
+                       mla._read_latent (attend_absorbed's read)
+    tk_attn_flash      attention over a whole sequence, the flash kernels
+                       and XLA's form: Transformer._attention,
+                       mla._attend_whole (attend_full's attention)
+    tk_ffn             the dense FFN and the shared experts:
+                       transformer._dense_mlp, moe.routed_moe_mlp
+    tk_moe_route       router scores, bias, top-k, renormalisation, the
+                       sort, group sizes and the counts of what was
+                       routed: moe.route, grouped_experts,
+                       compacted_experts, grouped_counts,
+                       serve._count_routing
+    tk_moe_dispatch    rows gathered into expert order, a tile's gather,
+                       the inverse gather, the weighted sum or
+                       scatter-add: moe.grouped_experts,
+                       compacted_experts, all_experts, routed_moe_mlp
+                       (the zero experts' term)
+    tk_moe_experts     the expert products themselves (tk_gmm_*, a tile's
+                       three products, the all-experts einsum): moe._gmm,
+                       compacted_experts::tile, all_experts
+    tk_head            the final norm, the head's product, sampling:
+                       Transformer.trunk (the final norm) / __call__,
+                       generate.head_logits (prefill, _prefill_kinds,
+                       latent_forward, _decode_one and serve.py's
+                       tick_block call it) / sample_logits,
+                       serve._pick_slots
+    tk_loss            the blocked cross-entropy: Transformer.loss
+    tk_optimizer       the optimizer's update and its addition to the
+                       parameters: make_train_step::_step
+
+``tk_flash_out`` and ``tk_flash_lse`` are the remat policy's names
+(``ops/flash.py::REMAT_SAVED``), not tracing.
+
+Importing this module puts metadata into the persistent compilation
+cache's key (``jax_compilation_cache_include_metadata_in_key``; the note
+above the constants says why): an executable read from the cache carries
+the HLO it was compiled from, names and all.
+
 Record-level lifecycle tracing (who waited where, per record) is the
 separate ``torchkafka_tpu.obs`` subsystem; these annotations are the
 profiler-timeline complement.
@@ -74,10 +152,29 @@ profiler-timeline complement.
 from __future__ import annotations
 
 import contextlib
+import os
+import re
 import time
 from typing import Callable, Iterator
 
 import jax
+
+# The persistent compilation cache leaves metadata out of its key by
+# default, so a program whose HLO differs from an older tree's by its
+# names alone is handed the executable the older tree compiled, and with it
+# the older HLO: a profile then shows no scope (PR 38: a tick served from
+# its parent's cache entry read 100% unscoped on the chip). With metadata
+# in the key, a tree whose names or lines moved compiles its own entries.
+# Source locations are metadata too, so the checkout's own path is taken
+# out of them: a checkout that moves keeps its entries.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+if jax.config.jax_hlo_source_file_canonicalization_regex is None:
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        "^" + re.escape(os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)
+        ))) + os.sep),
+    )
 
 # Span names (one place, so the README recipe, the program and whatever
 # reads a trace back agree).
@@ -99,6 +196,23 @@ SPAN_COMMIT_FETCH = "tk_commit:fetch"
 SPAN_COMMIT_SYNC = "tk_commit:sync"
 SPAN_COMMIT_OFFSETS = "tk_commit:offsets"
 
+# Scope names of the device programs (the docstring lists who opens each).
+SCOPE_EMBED = "tk_embed"
+SCOPE_ATTN_PROJ = "tk_attn_proj"
+SCOPE_KV_WRITE = "tk_kv_write"
+SCOPE_KV_READ = "tk_kv_read"
+SCOPE_KV_READ_WINDOW = "tk_kv_read_window"
+SCOPE_KV_READ_FULL = "tk_kv_read_full"
+SCOPE_KV_READ_LATENT = "tk_kv_read_latent"
+SCOPE_ATTN_FLASH = "tk_attn_flash"
+SCOPE_FFN = "tk_ffn"
+SCOPE_MOE_ROUTE = "tk_moe_route"
+SCOPE_MOE_DISPATCH = "tk_moe_dispatch"
+SCOPE_MOE_EXPERTS = "tk_moe_experts"
+SCOPE_HEAD = "tk_head"
+SCOPE_LOSS = "tk_loss"
+SCOPE_OPTIMIZER = "tk_optimizer"
+
 
 @contextlib.contextmanager
 def trace_session(logdir: str) -> Iterator[None]:
@@ -113,6 +227,11 @@ def trace_session(logdir: str) -> Iterator[None]:
 def span(name: str):
     """Annotate a host-side region on the profiler's timeline."""
     return jax.profiler.TraceAnnotation(name)
+
+
+def scope(name: str):
+    """Name the device work traced inside: a ``jax.named_scope``."""
+    return jax.named_scope(name)
 
 
 def ingest_lag_ms(
