@@ -79,8 +79,12 @@ CHIP = Sizes(
     pool_prompt_len=768, pool_max_new=256, pool_slots=4, pool_records=6,
     block_size=256, kv_kernel="auto",
     train_seq=512, train_batch=8,
-    # An admission trip of mellum2-12b-a2.5b-8l and of kanana-2-30b-a3b-7l.
-    gmm_shapes=((4096, 8, 64, 2304, 896), (3072, 6, 128, 2048, 768)),
+    # An admission trip of mellum2-12b-a2.5b-8l and of kanana-2-30b-a3b-7l,
+    # and a decode tick of the former's 128 slots (16 pairs an expert).
+    gmm_shapes=(
+        (4096, 8, 64, 2304, 896), (3072, 6, 128, 2048, 768),
+        (128, 8, 64, 2304, 896),
+    ),
 )
 REHEARSAL = Sizes(
     serve_scale=None, train_scale=None,
@@ -338,7 +342,7 @@ def check_kernels(sz: Sizes) -> dict:
                 x, idx, w, *m, (jnp.int32(e), e)
             )
         )(x, idx, w, *mats)
-        close(f"grouped_matmul_{d}x{f}", got, by_ragged_dot(x, idx, w, *mats))
+        close(f"grouped_matmul_{n}x{d}x{f}", got, by_ragged_dot(x, idx, w, *mats))
         del mats
 
     # Flash forward and backward (GQA, causal) against the dense XLA body.

@@ -7,6 +7,8 @@ expert parallelism.
 """
 
 import dataclasses
+import functools
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -342,8 +344,8 @@ class TestRoutedExpertLayer:
                 lambda *a, _h=honest, _n=name: called.append(_n) or _h(*a),
             )
         layer = _routed_layer(rng)
-        # 12 pairs over 8 experts; 384 pairs, 48 an expert (the grouped
-        # form takes 32 an expert and more)
+        # 12 pairs over 8 experts, 1.5 an expert; 384 pairs, 48 an expert
+        # (the grouped form takes 16 an expert and more)
         for rows in (4, 128):
             moe.routed_moe_mlp(
                 jnp.zeros((1, rows, 32), jnp.float32), layer, ROUTED_CFG
@@ -663,3 +665,120 @@ class TestGroupedMatmulKernel:
         assert moe._gmm_rows(32768) == moe._gmm_rows(18432) == (512, 128)
         assert moe._gmm_rows(1024) == (512, 128)
         assert moe._gmm_rows(300) == (384, 128) and moe._gmm_rows(8) == (128, 128)
+
+
+# The benchmark's four serving configurations at their own sizes: (file
+# under chipbench/configs, program, the form its routed layer takes there).
+# Mellum2's tick averages 16 pairs an expert out of stacks, Kanana's 3 out
+# of the layer's own tensors; their admissions 512 and 144; LongCat holds a
+# share; Mistral has no routed layer.
+_CELL_FORMS = [
+    ("mellum2-12b-a2.5b-8l", "tick", "grouped"),
+    ("mellum2-12b-a2.5b-8l", "admit", "grouped"),
+    ("kanana-2-30b-a3b-7l", "tick", "all_experts"),
+    ("kanana-2-30b-a3b-7l", "admit", "grouped"),
+    ("longcat-flash-omni-4l-ep32", "tick", "compacted"),
+    ("longcat-flash-omni-4l-ep32", "admit", "compacted"),
+    ("mistral-7b-v0.3-w8", "tick", None),
+    ("mistral-7b-v0.3-w8", "admit", None),
+]
+
+
+@functools.cache
+def _cell_server(name, slots=None):
+    """The configuration's server at its deployment's sizes, built under
+    ``jax.eval_shape``: every static decision made, no byte allocated."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    import torchkafka_tpu as tk
+    from torchkafka_tpu.serve import StreamingGenerator
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    conf = json.loads((root / "chipbench/configs" / f"{name}.json").read_text())
+    model = importlib.import_module(conf["model"])
+    dep = conf["deployment"]
+    window, new = dep["prompt_window"], dep["max_new"]
+    cfg = model.program_config(conf, window + new)
+    broker = tk.InMemoryBroker()
+    broker.create_topic("p", partitions=1)
+    consumer = tk.MemoryConsumer(broker, "p", group_id="g")
+    held = []
+
+    def build():
+        params = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(lambda: model.serving_params(conf, 0)),
+        )
+        held.append(StreamingGenerator(
+            consumer, params, cfg, slots=slots or dep["slots"],
+            prompt_len=window, max_new=new,
+            ticks_per_sync=dep["ticks_per_sync"], kv_dtype=dep["kv_dtype"],
+            kv_kernel=dep["kv_kernel"],
+        ))
+        return 0
+
+    jax.eval_shape(build)
+    return held[0], cfg, window
+
+
+class TestTheFormACellTakes:
+    @pytest.mark.parametrize("name,program,form", _CELL_FORMS)
+    def test_each_cells_static_shapes(self, name, program, form):
+        """The rule of ``ops/moe.py`` at the sizes the benchmark serves:
+        what the server says of its tick, and what its admission's trip
+        takes by the same rule."""
+        from torchkafka_tpu.ops import moe
+
+        server, cfg, window = _cell_server(name)
+        experts = server.metrics.summary()["expert_layer"]
+        if program == "tick":
+            assert experts["tick_form"] == form
+            assert moe.expert_form(cfg, server._slots) == form
+        else:
+            trip = server._admit_chunk_rows * window
+            assert moe.expert_form(cfg, trip) == form
+            assert experts["grouped_matmul"] == (
+                "kernel" if form == "grouped" else None
+            )
+
+    def test_fewer_slots_than_the_thresholds_keep_the_loop(self):
+        """The rule is the static shapes', not the model's: Mellum2 with a
+        pair an expert fewer than the threshold asks keeps the compacted
+        loop out of its stacks."""
+        from torchkafka_tpu.ops import moe
+
+        _server, cfg, _window = _cell_server("mellum2-12b-a2.5b-8l")
+        slots = (
+            moe._GROUPED_MIN_PAIRS_PER_EXPERT * cfg.n_experts
+            // cfg.expert_top_k
+        )
+        assert moe.expert_form(cfg, slots) == "grouped"
+        few, _cfg, _w = _cell_server("mellum2-12b-a2.5b-8l", slots - 1)
+        assert few.metrics.summary()["expert_layer"]["tick_form"] == "compacted"
+
+    @pytest.mark.parametrize("rows,stacked,form", [
+        (1, False, "all_experts"), (1, True, "compacted"),
+        (64, False, "grouped"), (64, True, "grouped"),
+    ])
+    def test_the_form_named_is_the_form_traced(self, rng, rows, stacked, form):
+        """The rule's answer against the program ``routed_experts`` builds
+        for the same shapes (3 pairs over 8 experts, and 192): the
+        kernels' calls, the tile loop, or neither."""
+        from torchkafka_tpu.ops import moe
+
+        layer = _routed_layer(rng)
+        mats = [layer[n] for n in ("w_gate", "w_up", "w_down")]
+        if stacked:  # two layers' experts, this layer the second
+            mats = [jnp.concatenate([m, m]) for m in mats]
+        idx = jnp.zeros((rows, 3), jnp.int32)
+        jaxpr = jax.make_jaxpr(lambda x, w, *m: moe.routed_experts(
+            x, idx, w, *m, at=(8, 8) if stacked else None
+        ))(jnp.zeros((rows, 32)), jnp.zeros((rows, 3)), *mats)
+        names = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+        assert moe._form(rows * 3, 8, stacked) == form
+        assert ("pallas_call" in names) == (form == "grouped")
+        assert ("while" in names) == (form == "compacted")

@@ -1470,9 +1470,9 @@ def test_latent_model_served_tokens_and_commit_watermark(variant):
 def test_the_admissions_grouped_matmul_is_counted_by_hand():
     """A window of 32 tokens: a trip of 4 rows is 256 pairs over 8 experts,
     32 an expert, so the admission takes the grouped form (its kernels)
-    and a tick of 4 rows does not. Ten records through 4 slots: admissions
-    of 4, 4 and 2 rows, each one trip of 4; two expert layers, two choices
-    a token. The counts ride the next sync's fetch."""
+    and a tick of 4 rows, one pair an expert, does not. Ten records through
+    4 slots: admissions of 4, 4 and 2 rows, each one trip of 4; two expert
+    layers, two choices a token. The counts ride the next sync's fetch."""
     from torchkafka_tpu.ops import moe
 
     window = 32
@@ -1523,6 +1523,70 @@ def test_the_admissions_grouped_matmul_is_counted_by_hand():
     got = small.metrics.summary()["expert_layer"]
     assert got["grouped_matmul"] is None and got["moe_grouped_rows"] == 0
     small.close()
+
+
+def test_a_tick_grouped_by_its_own_shapes_serves_the_reference_tokens():
+    """Kinds of layer over stacks of every layer's experts, as Mellum2's:
+    slots enough that a tick's pairs reach the threshold an expert, so the
+    tick sums its experts by the grouped kernels by its own static shapes
+    (nothing patched where the server is built or run) and says so. In
+    float32 every served token is the greedy choice of the full forward,
+    which is traced with the threshold out of reach: its experts go
+    through the tile loop and no kernel."""
+    from torchkafka_tpu.models.transformer import RopeKind
+    from torchkafka_tpu.ops import moe
+
+    experts, top_k = 4, 2
+    slots = moe._GROUPED_MIN_PAIRS_PER_EXPERT * experts // top_k
+    cfg = TransformerConfig(
+        vocab_size=VOCAB, d_model=32, n_layers=4, n_heads=4, n_kv_heads=2,
+        d_ff=48, max_seq_len=P + MAX_NEW, dtype=jnp.float32,
+        stated_head_dim=16, sliding_window=4,
+        window_pattern=(True, True, True, False), rope_theta=500000.0,
+        rope_full=RopeKind(
+            500000.0, factor=16.0, original_len=64, attention_factor=1.2773,
+        ),
+        n_experts=experts, expert_top_k=top_k, expert_d_ff=24,
+        router_score="softmax", norm_topk=True,
+    )
+    params = init_params(jax.random.key(0), cfg)
+    broker = tk.InMemoryBroker()
+    prompts = _topic(broker, 10)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe, "_GROUPED_MIN_PAIRS_PER_EXPERT", 10**9)
+        expected = _greedy_by_full_forward(cfg, params, prompts, MAX_NEW)
+    consumer = tk.MemoryConsumer(broker, "p", group_id="g")
+    server = StreamingGenerator(
+        consumer, params, cfg, slots=slots, prompt_len=P, max_new=MAX_NEW,
+        commit_every=4, ticks_per_sync=3,
+    )
+    summary = server.metrics.summary()
+    assert summary["kv_backend"]["layout"] == "by_kind"
+    assert summary["expert_layer"]["tick_form"] == "grouped"
+    eqns = _eqns(jax.make_jaxpr(server._tick_block_raw)(
+        server._params, server._caches, server._last_tok, server._pos,
+        server._gen, jnp.ones((slots,), bool), server._slot_keys,
+    ).jaxpr)
+    calls = [e.params["name"] for e in eqns if e.primitive.name == "pallas_call"]
+    # One scan body holds a period's four layers.
+    assert calls == ["tk_gmm_gate_up", "tk_gmm_down"] * 4
+    got = dict(
+        ((rec.partition, rec.offset), toks)
+        for rec, toks in server.run(max_records=10)
+    )
+    assert len(got) == 10
+    for (part, off), toks in got.items():
+        np.testing.assert_array_equal(toks, expected[2 * off + part])
+    served = server.metrics.summary()["scheduler"]["slot_ticks_served"]
+    assert served == 10 * (MAX_NEW - 1)
+    consumer.close()
+    # One slot fewer: under the threshold, the tile loop, and it says so.
+    few = StreamingGenerator(
+        tk.MemoryConsumer(broker, "p", group_id="g2"), params, cfg,
+        slots=slots - 1, prompt_len=P, max_new=MAX_NEW, ticks_per_sync=3,
+    )
+    assert few.metrics.summary()["expert_layer"]["tick_form"] == "compacted"
+    few.close()
 
 
 def test_latent_model_crash_before_commit_redelivers_unfinished():

@@ -267,7 +267,11 @@ FAMILIES = {
     }),
     "MLA with experts": (latent_experts, {
         "tick": (DENSE | MOE | {"tk_kv_read_latent"}, 38),
-        "admit": (DENSE | MOE | {"tk_attn_flash"}, 42),
+        # 4 rows of 16 tokens, top-2 over 8 experts, are 16 pairs an expert:
+        # the grouped form since PR 39, as the cells' admissions take it. Its
+        # kernels the interpreter unrolls here into loops with no name (on
+        # the chip each is one call under ``tk_moe_experts``); so below.
+        "admit": (DENSE | MOE | {"tk_attn_flash"}, 62),
     }),
     "the held share's double layer": (double_layer_share, {
         "tick": (DENSE | MOE | {"tk_kv_read_latent"}, 56),
@@ -276,7 +280,7 @@ FAMILIES = {
     "window/full with experts": (window_full_experts, {
         "tick": ((DENSE | MOE | {"tk_kv_read_window", "tk_kv_read_full"})
                  - {"tk_ffn"}, 92),
-        "admit": ((DENSE | MOE | {"tk_attn_flash"}) - {"tk_ffn"}, 85),
+        "admit": ((DENSE | MOE | {"tk_attn_flash"}) - {"tk_ffn"}, 142),
     }),
     "the training step": (training_step, {
         "step": ({"tk_embed", "tk_attn_proj", "tk_attn_flash", "tk_ffn",

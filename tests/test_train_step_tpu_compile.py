@@ -7,9 +7,10 @@ in-place write of one layer's slice, and a program that fits its chip.
 Nothing runs, so no number here is a measurement.
 
 Beside them the grouped expert matmul's two kernels (``ops/moe.py``, PR
-36) at the widths of the two admissions that run them, about two seconds
-each: what Mosaic refuses (a block over the scoped VMEM, a contraction it
-does not take) shows here and not on the chip.
+36) at the widths of the two admissions that run them and of the one tick
+that does (Mellum2's, PR 39), about two seconds each: what Mosaic refuses
+(a block over the scoped VMEM, a contraction it does not take) shows here
+and not on the chip.
 
 A file of its own because ``tests/chipbench/`` belongs to the accepted
 benchmark and is not edited: the topology is described inside a fixture,
@@ -172,6 +173,9 @@ def test_the_step_fits_its_chips(step, capsys):
 GROUPED = {
     "mellum2-12b-a2.5b-8l": (4096, 8, 64, 8, 2304, 896),
     "kanana-2-30b-a3b-7l": (3072, 6, 128, 1, 2048, 768),
+    # PR 39: a decode tick of Mellum2's 128 slots, 1,024 sorted rows (16 an
+    # expert) out of the same [512, ...] stacks.
+    "mellum2-12b-a2.5b-8l.tick": (128, 8, 64, 8, 2304, 896),
 }
 
 

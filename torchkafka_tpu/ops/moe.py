@@ -30,31 +30,39 @@ is THIS chip's part of the sum. Nothing stands in for the absent chips.
 
 No token is dropped at any load, in any form:
 
-- ``grouped_experts`` (every expert held): the pairs are sorted by expert
+- ``grouped_experts`` (every expert held; an admission, a forward, and a
+  decode tick whose rows average ``_GROUPED_MIN_PAIRS_PER_EXPERT`` pairs
+  an expert: Mellum2's 128 slots, 16): the pairs are sorted by expert
   and each expert multiplies exactly the rows routed to it, in two Pallas
   kernels of this file (``tk_gmm_gate_up``: ``silu(x · w_gate) * (x ·
   w_up)`` formed in float32 and written once; ``tk_gmm_down``), so the
   FLOPs are top-k's, not E's. The kernels walk the sorted rows in blocks
   of pieces of 128 rows (``_gmm_rows``); a block that straddles experts
   is visited once by each, which multiplies the pieces its run touches;
-  an expert's matrices stay in VMEM over its consecutive blocks, and the
-  stacks ``[L * E, ...]`` are taken whole (``_gmm``). Off the TPU the
-  Pallas interpreter runs them.
-- ``all_experts`` (every expert held): where the rows are few against the
-  experts (a decode tick) every expert multiplies every row and the
-  unrouted ones are weighted by zero: the weights are streamed whole
-  either way.
+  an expert's matrices stay in VMEM over its consecutive blocks, the next
+  expert's are fetched while this one multiplies, and the stacks ``[L *
+  E, ...]`` are taken whole (``_gmm``). Off the TPU the Pallas
+  interpreter runs them.
+- ``all_experts`` (every expert held, the layer's own ``[E, ...]``
+  tensors): where the rows are fewer an expert than that (Kanana's decode
+  tick, 3) every expert multiplies every row and the unrouted ones are
+  weighted by zero: the weights are streamed whole either way.
 - ``compacted_experts`` (a share held, or zero experts behind the real
-  ones; prefill and decode alike): of N·K pairs only ``count / (E + Z)``
-  meet a held expert, so the local pairs are sorted to the front and
-  multiplied in tiles of ``cap`` rows of one expert; the loop walks the
-  tiles there are, a value of the routing and not a bound on it: all N
-  rows to one expert are N / cap tiles, none is cut, and an expert no
-  pair chose is not read.
+  ones: prefill and decode alike; and the few rows an expert of a layer
+  whose experts are rows of stacks, where the einsum would copy the
+  stacks): of N·K pairs only ``count / (E + Z)`` meet a held expert, so
+  the local pairs are sorted to the front and multiplied in tiles of
+  ``cap`` rows of one expert; the loop walks the tiles there are, a value
+  of the routing and not a bound on it: all N rows to one expert are N /
+  cap tiles, none is cut, and an expert no pair chose is not read. Its
+  body is serial: a tile's weights are not fetched while the one before
+  multiplies.
 
 Which one runs is decided by the configuration and the static shapes
-alone (``_GROUPED_MIN_PAIRS_PER_EXPERT`` against the pairs an expert can
-expect), never by an option; PERF.md holds the chip's readings.
+alone (``_form``: ``_GROUPED_MIN_PAIRS_PER_EXPERT`` against the pairs an
+expert can expect, and whether the experts are rows of stacks), never by
+an option; ``expert_form`` names the choice for a configuration and a
+row count, and PERF.md holds the chip's readings.
 """
 
 from __future__ import annotations
@@ -74,11 +82,19 @@ from torchkafka_tpu.utils import tracing
 # Token-choice pairs an expert must average before the sorted, grouped
 # form is taken: below it the all-experts einsum streams the same weights
 # and skips the sort, the gather of the sorted rows and the inverse
-# permutation. The admissions that take the grouped form average 144 pairs
-# an expert and more; a tick averages 3 and 16 (PERF.md §6 has the v5e's
-# readings of both forms in a tick; nothing between 16 and 144 has been
-# read).
-_GROUPED_MIN_PAIRS_PER_EXPERT = 32
+# permutation (out of stacks, the compacted loop walks its tiles). Read on
+# the v5e (PERF.md §6, PR 39; ms a decode tick, the fallback against the
+# grouped form): Mellum2, out of its stacks, at 16, 12, 8 and 4 pairs an
+# expert (128, 96, 64, 32 slots) 24.94 / 17.53, 23.28 / 15.60, 20.53 /
+# 13.38, 18.33 / 11.44: the grouped form wins at every point against the
+# loop; Kanana's tick, over the layer's own tensors, at 3: 18.09 / 40.10,
+# the einsum keeps it, and not by the pairs (the kernels are handed a copy
+# of the layer's experts out of the layer scan). The one constant governs
+# both fallbacks, so it stands at the highest point read at which a
+# serving cell sits, Mellum2's 16: the own-tensor kind has been read
+# nowhere between 3 and an admission's 144 (Kanana's; Mellum2's 512), and
+# what it would take to go to 4 is in PERF.md §7.
+_GROUPED_MIN_PAIRS_PER_EXPERT = 16
 
 
 @tracing.scope(tracing.SCOPE_MOE_ROUTE)
@@ -352,13 +368,34 @@ def _takes_grouped(pairs: int, count: int) -> bool:
     return pairs >= _GROUPED_MIN_PAIRS_PER_EXPERT * count
 
 
+def _form(pairs: int, count: int, stacked: bool) -> str:
+    """The form of the sum where every expert is held: ``pairs`` over
+    ``count`` experts, their matrices rows of stacks or the layer's own."""
+    if _takes_grouped(pairs, count):
+        return "grouped"
+    return "compacted" if stacked else "all_experts"
+
+
+def expert_form(cfg, rows: int) -> str | None:
+    """The form ``routed_moe_mlp`` sums ``rows`` tokens' pairs by:
+    ``"grouped"``, ``"compacted"``, ``"all_experts"``; None: the config
+    has no routed layer. The experts come out of stacks where the model
+    hands them on so (``scan_periods``' ``experts_at`` under a
+    ``window_pattern``, the double layer's)."""
+    if not cfg.routed_moe:
+        return None
+    if cfg.moe_partial:
+        return "compacted"
+    return _form(
+        rows * cfg.expert_top_k, cfg.n_experts,
+        stacked=bool(cfg.window_pattern) or cfg.attn_blocks == 2,
+    )
+
+
 def grouped_form(cfg, rows: int) -> bool:
     """Whether ``routed_moe_mlp`` sums ``rows`` tokens' pairs by
     ``grouped_experts``."""
-    return bool(
-        cfg.routed_moe and not cfg.moe_partial
-        and _takes_grouped(rows * cfg.expert_top_k, cfg.n_experts)
-    )
+    return expert_form(cfg, rows) == "grouped"
 
 
 @tracing.scope(tracing.SCOPE_MOE_ROUTE)
@@ -390,9 +427,10 @@ def routed_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
     this layer's experts their rows ``[base, base + count)``."""
     n, k = idx.shape
     count = w_gate.shape[0] if at is None else at[1]
-    if _takes_grouped(n * k, count):
+    form = _form(n * k, count, stacked=at is not None)
+    if form == "grouped":
         return grouped_experts(h, idx, weights, w_gate, w_up, w_down, at)
-    if at is not None:
+    if form == "compacted":
         # Few rows an expert, out of stacks: the compacted form reaches an
         # expert by ONE dynamic index, which fuses into its products. The
         # all-experts einsum over a slice of the stacks has the compiler

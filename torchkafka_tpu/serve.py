@@ -331,6 +331,11 @@ class ServeMetrics:
         # (128 rows) its kernels multiplied, every piece whole: rows /
         # tile_rows is the pieces' fill.
         self.grouped_matmul: str | None = None
+        # The form a decode tick sums its routed experts by, decided from
+        # the static shapes where the tick is built (ops/moe.py::
+        # expert_form): "grouped", "compacted", "all_experts"; None: no
+        # routed layer.
+        self.tick_form: str | None = None
         self.moe_grouped_rows = RateMeter()
         self.moe_grouped_tile_rows = RateMeter()
         self.experts_held: list[int] | None = None  # [first, count]
@@ -532,6 +537,7 @@ class ServeMetrics:
                 "moe_absent_assignments": self.moe_absent_assignments.count,
                 "experts_held": self.experts_held,
                 "grouped_matmul": self.grouped_matmul,
+                "tick_form": self.tick_form,
                 "moe_grouped_rows": self.moe_grouped_rows.count,
                 "moe_grouped_tile_rows": self.moe_grouped_tile_rows.count,
             },
@@ -687,7 +693,7 @@ class ServeMetrics:
                 for name, value in s[section].items()
                 if name not in (
                     "moe_expert_load", "experts_held", "attn_blocks",
-                    "grouped_matmul",
+                    "grouped_matmul", "tick_form",
                     *self.kv_pool_static,
                 )
             ),
@@ -1630,6 +1636,8 @@ class StreamingGenerator:
         grouped = moe.grouped_form(cfg, R * P)
         if grouped:
             self.metrics.grouped_matmul = "kernel"
+        # A tick's B tokens, by the same rule.
+        self.metrics.tick_form = moe.expert_form(cfg, B)
 
         def admit(params, caches, last_tok, pos, gen, prompts, admit_mask,
                   keys):
