@@ -64,8 +64,12 @@ class KVBackend:
     ``window_pattern``: per-slot pools allocated by kind in the compute
     dtype, the full layers' K and V [Lf, B, M, K * Dh] and the window
     layers' rings [Lw, B, sliding_window, K * Dh], a position's kv heads
-    side by side in one row). ``int8``: quantized payloads + group-wise
-    scales.
+    side by side in one row) or "state" (a config with linear-attention
+    layers, ``linear_pattern``: slot memory by kind, a linear layer's
+    recurrent state [L_lin, B, H, E, E] in float32 and its conv tail
+    [L_lin, B, taps - 1, 3 * H * E] in the compute dtype, which no
+    position indexes, beside the latent layers' pool [L_lat, B, M, rank +
+    rope]). ``int8``: quantized payloads + group-wise scales.
     ``kernel``: the Pallas fill-bounded read engages on decode ticks.
     ``kernel_disabled_reason``: why it does NOT engage (None when it
     does, or when int8 was never requested — there is no kernel
@@ -182,6 +186,43 @@ def _resolve_latent(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
     )
 
 
+def _resolve_state(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
+    """The slot memory of a config with linear-attention layers: what is
+    built, and a reasoned refusal of every combination that is not."""
+    what = "the slot memory of linear-attention layers (linear_pattern)"
+    if kv_dtype == "int8":
+        raise ValueError(
+            f"{what} keeps a float32 recurrent state: kv_dtype='int8' "
+            "quantises rows of K and V a position, and a state that every "
+            "token rewrites has no scale scheme that holds over a thousand "
+            "updates yet"
+        )
+    if kv_pages is not None:
+        raise ValueError(
+            f"{what} is a state a slot, not rows a position: kv_pages "
+            "(block tables, the radix prefix cache, its host tier and the "
+            "prefill hand-off cut from them) share and rebuild a cache by "
+            "BLOCKS OF POSITIONS, and the state after a prefix is no block "
+            "of anything (a snapshot a prefix is not built)"
+        )
+    if mesh is not None and mesh.size > 1:
+        raise ValueError(
+            f"{what} serves on one device: no sharded layout has been "
+            "taught the state, and the routed expert layer's held share "
+            "has no exchange across chips behind it"
+        )
+    if kv_kernel is True:
+        raise ValueError(
+            f"{what} is passed over by its own kernel (tk_kda_step) and "
+            "the latent pool is read by XLA: kv_kernel=True asks for the "
+            "int8 pool's Pallas read, and never falls back silently"
+        )
+    return KVBackend(
+        layout="state", int8=False, kernel=False,
+        kernel_disabled_reason=None, chunked=False, data=1, tp=1,
+    )
+
+
 def _resolve_by_kind(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
     """The pool of a config with kinds of layer: what is built, and a
     reasoned refusal of every combination that is not."""
@@ -265,6 +306,11 @@ def resolve_kv_backend(
     if not (kv_kernel is True or kv_kernel is False or kv_kernel == "auto"):
         raise ValueError(
             f"kv_kernel must be True, False or 'auto', got {kv_kernel!r}"
+        )
+    if getattr(cfg, "linear_pattern", ()):
+        return _resolve_state(
+            cfg, mesh=mesh, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
+            kv_pages=kv_pages,
         )
     if getattr(cfg, "is_mla", False):
         return _resolve_latent(

@@ -500,6 +500,16 @@ def prefill(
         model = Transformer(dataclasses.replace(cfg, attn_impl="auto"), mesh)
     else:
         model = Transformer(cfg, mesh)
+    if cfg.linear_pattern:
+        # Linear and latent layers: what a slot keeps is (states, conv
+        # tails, latent rows), each over its kind's layers.
+        from torchkafka_tpu.models.linear_attn import hybrid_forward
+
+        with tracing.scope(tracing.SCOPE_EMBED):
+            x = embed_rows(params["embed"], tokens, cfg.dtype)
+        x, kept, chosen = hybrid_forward(params, model, x)
+        out = head_logits(params, cfg, x, -1), kept, chosen
+        return out if routing else out[:2]
     if cfg.is_mla:
         out = latent_forward(params, model, tokens)
         return out if routing else out[:2]

@@ -238,6 +238,28 @@ class TransformerConfig:
     window_pattern: tuple[bool, ...] = ()
     rope_window: RopeKind | None = None
     rope_full: RopeKind | None = None
+    # Linear-attention (KDA, ops/kda.py) layers beside latent-attention
+    # layers (models/linear_attn.py): ``linear_pattern`` says which layers
+    # of a period are linear (True) and which latent (False); the period
+    # divides the layers after ``first_dense_layers``, which are linear
+    # too. A linear layer has ``n_heads`` heads of ``linear_head_dim``, a
+    # causal depthwise convolution over ``linear_conv`` tokens on q, k and
+    # v, and a decay a channel ``linear_lower_bound * sigmoid(..)``. What a
+    # slot keeps of it is a recurrent state and a conv tail, not rows by
+    # position (``kvcache/backend.py``, layout "state").
+    linear_pattern: tuple[bool, ...] = ()
+    linear_head_dim: int = 128
+    linear_conv: int = 4
+    linear_lower_bound: float = -5.0
+    # A head-wise sigmoid gate on the attention's output before ``wo``
+    # (one scalar a head, ``wg`` [D, H]); built beside ``linear_pattern``.
+    attn_gate: bool = False
+    # Group-limited selection (DeepSeek-V3's): the router's outputs fall
+    # into ``n_group`` groups of consecutive experts, a group scores the
+    # sum of its two best biased scores, the ``topk_group`` best groups
+    # stay and the top-k is taken among them. (1, 1): no groups.
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -290,8 +312,19 @@ class TransformerConfig:
 
     @property
     def cache_layers(self) -> int:
-        """Rows of the stacked cache: one an attention block."""
+        """Rows of the stacked cache: one an attention block (a hybrid
+        model's latent layers alone)."""
+        if self.linear_pattern:
+            return self.hybrid_layers(False)
         return self.n_layers * self.attn_blocks
+
+    def hybrid_layers(self, linear: bool) -> int:
+        """Layers of one kind of a ``linear_pattern`` model: the leading
+        dense layers are linear."""
+        pattern = self.linear_pattern
+        periods = (self.n_layers - self.first_dense_layers) // len(pattern)
+        per = sum(k == linear for k in pattern)
+        return periods * per + (self.first_dense_layers if linear else 0)
 
     @property
     def router_width(self) -> int:
@@ -481,6 +514,42 @@ class TransformerConfig:
                 "kinds of layer of a window_pattern: set one ((False,) is "
                 "a model of full layers alone)"
             )
+        hybrid = self.linear_pattern
+        if hybrid and not (
+            self.is_mla and not self.q_lora_rank and self.attn_blocks == 1
+            and not pattern and any(hybrid) and not all(hybrid)
+            and (self.n_layers - self.first_dense_layers) % len(hybrid) == 0
+            and self.linear_head_dim > 0 and self.linear_conv >= 2
+            and self.linear_lower_bound < 0
+        ):
+            raise ValueError(
+                f"linear_pattern={hybrid} is built beside latent attention "
+                "(kv_lora_rank > 0, no q_lora_rank, attn_blocks 1, no "
+                "window_pattern): a period of linear AND latent layers "
+                "that divides the layers after first_dense_layers, "
+                "linear_head_dim > 0, linear_conv >= 2 and a negative "
+                "linear_lower_bound"
+            )
+        if self.attn_gate and not hybrid:
+            raise ValueError(
+                "attn_gate (the head-wise output gate) is built for the "
+                "layers of a linear_pattern alone"
+            )
+        if (self.n_group, self.topk_group) != (1, 1) and not (
+            self.routed_moe and not self.zero_experts
+            and self.n_group >= 1 and self.n_experts % self.n_group == 0
+            and 1 <= self.topk_group <= self.n_group
+            and self.n_experts // self.n_group >= 2
+            and self.expert_top_k
+            <= self.topk_group * (self.n_experts // self.n_group)
+        ):
+            raise ValueError(
+                f"n_group={self.n_group}, topk_group={self.topk_group} "
+                "describe the routed expert layer's group-limited "
+                "selection: n_group divides n_experts into groups of at "
+                "least two, topk_group of them hold expert_top_k experts, "
+                "and there are no zero experts"
+            )
         if pattern and (
             self.is_mla or self.first_dense_layers
             or self.attn_impl in ("ring", "ulysses")
@@ -619,6 +688,96 @@ def _arch_shapes(cfg: TransformerConfig, expert_mlp: bool) -> dict:
     return shapes
 
 
+# A hybrid model's tensors by kind (``linear_pattern``): what a linear
+# layer has of its own, what a latent layer has; the rest (the norms, the
+# MLP's) every layer has.
+_LINEAR_TENSORS = (
+    "lqkv", "lconv", "lf", "l_alog", "l_dt", "lb", "lg", "lnorm", "lo",
+)
+_LATENT_TENSORS = ("wq", "wkva", "kv_norm", "wkvb", "wo", "wg")
+
+
+def _linear_shapes(cfg: TransformerConfig) -> dict:
+    """One linear layer's own tensors (``models/linear_attn.py``): name
+    -> (shape, fan_in or None)."""
+    dm, h, e = cfg.d_model, cfg.n_heads, cfg.linear_head_dim
+    return {
+        "lqkv": ((dm, 3 * h * e), dm),
+        "lconv": ((cfg.linear_conv, 3 * h * e), cfg.linear_conv),
+        "lf": ((dm, h, e), dm), "l_alog": ((h,), None),
+        "l_dt": ((h, e), None), "lb": ((dm, h), dm), "lg": ((dm, h), dm),
+        "lnorm": ((e,), None), "lo": ((h, e, dm), h * e),
+    }
+
+
+def _hybrid_shapes(cfg: TransformerConfig, nl: int, expert_mlp: bool) -> dict:
+    """A hybrid group of ``nl`` layers, stacked by kind: name -> (stacked
+    shape, fan_in or None). What every layer has leads with ``nl``, a
+    kind's own tensors with the group's layers of the kind (the leading
+    dense layers are all linear)."""
+    shapes = _arch_shapes(cfg, expert_mlp)
+    latent = {n: v for n, v in shapes.items() if n in _LATENT_TENSORS}
+    if cfg.attn_gate:
+        latent["wg"] = ((cfg.d_model, cfg.n_heads), cfg.d_model)
+    common = {n: v for n, v in shapes.items() if n not in latent}
+    lead = cfg.first_dense_layers and not expert_mlp
+    pattern = (True,) if lead else cfg.linear_pattern
+    n_lin = nl // len(pattern) * sum(pattern)
+    return {
+        n: ((count, *shape), fan)
+        for count, part in (
+            (nl, common), (n_lin, _linear_shapes(cfg)), (nl - n_lin, latent),
+        ) if count
+        for n, (shape, fan) in part.items()
+    }
+
+
+def hybrid_groups(cfg: TransformerConfig):
+    """The stacked groups of a ``linear_pattern`` model in the order they
+    run: ``(key, the period's pattern, the group's first row among the
+    linear layers, among the latent layers)``. The leading dense layers
+    are a group of periods of one linear layer."""
+    lead = cfg.first_dense_layers
+    groups = (("dense_layers", (True,), 0, 0),) if lead else ()
+    return groups + (("layers", tuple(cfg.linear_pattern), lead, 0),)
+
+
+def scan_hybrid(cfg, group, pattern, carry, step, lin0=0, lat0=0):
+    """``scan_periods`` for a group stacked BY KIND: the tensors every
+    layer has ``[L, ...]``, a linear layer's own over the group's linear
+    layers, a latent layer's over its latent layers; each taken by one
+    dynamic index at the layer's row in its stack. ``step(carry, layer,
+    linear, row) -> (carry, y)`` with ``row`` the layer's row among ALL
+    the model's layers of its kind (``lin0``, ``lat0``: the group's
+    first). Returns (carry, a tuple over the period's layers of the ``y``
+    stacked over the periods)."""
+    p = len(pattern)
+    per = {True: sum(pattern), False: p - sum(pattern)}
+    experts, rest = _expert_stacks(cfg, group)
+    own = {True: _LINEAR_TENSORS, False: _LATENT_TENSORS}
+
+    def period(carry, i):
+        ys = []
+        for j, linear in enumerate(pattern):
+            at = i * p + j
+            kind_at = i * per[linear] + sum(k == linear for k in pattern[:j])
+            layer = {
+                n: lax.dynamic_index_in_dim(
+                    w, kind_at if n in own[linear] else at, keepdims=False
+                )
+                for n, w in rest.items() if n not in own[not linear]
+            }
+            if experts:
+                layer["experts_at"] = (*experts, at * cfg.held_experts[1])
+            carry, y = step(
+                carry, layer, linear, (lin0 if linear else lat0) + kind_at
+            )
+            ys.append(y)
+        return carry, tuple(ys)
+
+    return lax.scan(period, carry, jnp.arange(group["ln1"].shape[0] // p))
+
+
 def _arch_init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     """``init_params`` for the configs ``_arch_shapes`` describes: scaled
     normals, norms at one, the selection bias at zero; every tensor of
@@ -637,12 +796,21 @@ def _arch_init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
         "lm_head": draw(k_head, (dm, v), dm),
     }
     for g, (key, nl, expert_mlp) in enumerate(_layer_groups(cfg)):
-        shapes = _arch_shapes(cfg, expert_mlp)
+        if cfg.linear_pattern:
+            shapes = _hybrid_shapes(cfg, nl, expert_mlp)
+        else:
+            shapes = {
+                n: ((nl, *shape), fan)
+                for n, (shape, fan) in _arch_shapes(cfg, expert_mlp).items()
+            }
         keys = jax.random.split(jax.random.fold_in(k_groups, g), len(shapes))
         out[key] = {
-            name: draw(k, (nl, *shape), fan_in)
+            name: draw(k, shape, fan_in)
             for k, (name, (shape, fan_in)) in zip(keys, shapes.items())
         }
+        for name in ("l_alog", "l_dt"):  # a rate of one, no shift
+            if name in out[key]:
+                out[key][name] = jnp.zeros_like(out[key][name])
         if "router_bias" in out[key]:
             out[key]["router_bias"] = jnp.zeros((nl, cfg.router_width), pd)
     return out
@@ -1004,6 +1172,19 @@ def _double_layer(x, layer, cfg: "TransformerConfig", attend):
     return a1 + _dense_mlp(m1, blk1, cfg) + branch, routing
 
 
+def _expert_stacks(cfg: "TransformerConfig", stacks):
+    """A group's tensors as a period scan hands them on: (a routed expert
+    layer's matrices as stacks of every layer's experts ``[L * E, ...]``,
+    or () where the group has none; the group's other tensors)."""
+    names = ("w_gate", "w_up", "w_down")
+    if not (cfg.routed_moe and "router" in stacks):
+        return (), dict(stacks)
+    experts = tuple(
+        stacks[n].reshape(-1, *stacks[n].shape[2:]) for n in names
+    )
+    return experts, {n: w for n, w in stacks.items() if n not in names}
+
+
 def scan_periods(cfg: "TransformerConfig", stacks, carry, step, first=0):
     """``lax.scan`` over the PERIODS of ``cfg.window_pattern``, shared by
     the full forward, the admission's prefill and the decode tick. A
@@ -1020,14 +1201,7 @@ def scan_periods(cfg: "TransformerConfig", stacks, carry, step, first=0):
     ``step(carry, layer, j, i) -> (carry, y)``. Returns (carry, a tuple
     over ``j`` of the ``y`` stacked over the periods)."""
     p = len(cfg.window_pattern)
-    experts = () if not (cfg.routed_moe and "router" in stacks) else tuple(
-        stacks[n].reshape(-1, *stacks[n].shape[2:])
-        for n in ("w_gate", "w_up", "w_down")
-    )
-    rest = {
-        n: w for n, w in stacks.items()
-        if not (experts and n in ("w_gate", "w_up", "w_down"))
-    }
+    experts, rest = _expert_stacks(cfg, stacks)
 
     def period(carry, i):
         ys = []
@@ -1051,6 +1225,18 @@ def _arch_refusal(cfg: "TransformerConfig", what: str) -> str | None:
     """Why ``what`` does not take a latent-attention config, or one with
     kinds of layer or the routed layer beside grouped-query attention
     (None: it does, the config is neither)."""
+    if cfg.linear_pattern:
+        return (
+            f"{what} is not built for a config with linear-attention "
+            "layers (linear_pattern): what a slot keeps of such a layer is "
+            "a recurrent state and a conv tail that no position indexes, "
+            "so nothing that rebuilds, shares, pages, quantises, shards "
+            "or differentiates a cache of rows by position can hold it. "
+            "These configs serve on one device through StreamingGenerator's "
+            "slot memory by kind (a float32 state and a conv tail a linear "
+            "layer, a compute-dtype latent pool a latent layer) and run "
+            "Transformer's forward"
+        )
     if cfg.window_pattern or (cfg.routed_moe and not cfg.is_mla):
         return (
             f"{what} is not built for a config with kinds of layer "
@@ -1357,6 +1543,14 @@ class Transformer:
             # One stacked group, or the leading dense layers and then the
             # expert layers (``first_dense_layers``).
             stats = []
+            if cfg.linear_pattern:
+                # Linear and latent layers (forward only, no remat:
+                # ``make_train_step`` refuses them).
+                from torchkafka_tpu.models.linear_attn import hybrid_forward
+
+                x, _kept, _routing = hybrid_forward(params, self, x)
+                with tracing.scope(tracing.SCOPE_HEAD):
+                    return _rms_norm(x, params["ln_f"]), jnp.float32(0.0)
             for key, nl, _expert_mlp in _layer_groups(cfg):
                 if cfg.window_pattern:
                     # Kinds of layer: a scan over periods (forward only,

@@ -99,9 +99,14 @@ _GROUPED_MIN_PAIRS_PER_EXPERT = 16
 
 @tracing.scope(tracing.SCOPE_MOE_ROUTE)
 def route(h, router, bias, *, top_k: int, scaling: float,
-          score: str = "sigmoid", norm_topk: bool = True):
+          score: str = "sigmoid", norm_topk: bool = True,
+          n_group: int = 1, topk_group: int = 1):
     """h [N, D] → (idx [N, K] int32, weights [N, K] float32). ``bias``
-    None: a router that states no selection bias.
+    None: a router that states no selection bias. ``n_group`` > 1: the
+    selection is limited by group (the outputs fall into ``n_group``
+    groups of consecutive experts, a group scores the sum of its two best
+    biased scores, the ``topk_group`` best groups stay, the top-k is taken
+    among their experts).
 
     Scores in float32 at the matmul's highest precision: a near-tie
     between the k-th and the (k+1)-th expert should not flip on the
@@ -115,6 +120,13 @@ def route(h, router, bias, *, top_k: int, scaling: float,
     else:
         scores = jax.nn.softmax(logits, axis=-1)
     biased = scores if bias is None else scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        by_group = biased.reshape(biased.shape[0], n_group, -1)
+        best = lax.top_k(lax.top_k(by_group, 2)[0].sum(-1), topk_group)[1]
+        stays = jnp.any(best[..., None] == jnp.arange(n_group), axis=1)
+        biased = jnp.where(stays[..., None], by_group, -jnp.inf).reshape(
+            biased.shape
+        )
     _, idx = lax.top_k(biased, top_k)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
@@ -388,7 +400,8 @@ def expert_form(cfg, rows: int) -> str | None:
         return "compacted"
     return _form(
         rows * cfg.expert_top_k, cfg.n_experts,
-        stacked=bool(cfg.window_pattern) or cfg.attn_blocks == 2,
+        stacked=bool(cfg.window_pattern or cfg.linear_pattern)
+        or cfg.attn_blocks == 2,
     )
 
 
@@ -460,6 +473,7 @@ def routed_moe_mlp(h, layer, cfg, experts=None):
         x, layer["router"], layer.get("router_bias"),
         top_k=cfg.expert_top_k, scaling=cfg.routed_scaling,
         score=cfg.router_score, norm_topk=cfg.norm_topk,
+        n_group=cfg.n_group, topk_group=cfg.topk_group,
     )
     experts = experts or layer.get("experts_at")
     *mats, base = experts or (

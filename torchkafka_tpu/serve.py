@@ -85,6 +85,7 @@ from torchkafka_tpu.models.generate import (
     serving_shardings,
     slot_sharding,
 )
+from torchkafka_tpu.models import linear_attn
 from torchkafka_tpu.models.quant import embed_rows, load_weight
 from torchkafka_tpu.models.transformer import (
     TransformerConfig,
@@ -94,6 +95,8 @@ from torchkafka_tpu.models.transformer import (
     _layer_groups,
     _rms_norm,
     _rope,
+    hybrid_groups,
+    scan_hybrid,
     scan_periods,
 )
 from torchkafka_tpu.ops import moe
@@ -149,7 +152,7 @@ def _pick_slots(logits, key_data, idx, *, temperature, top_k, top_p):
 
     ``logits``: [B, V]; ``key_data``: [B, W] uint32 — each row the raw
     key data of that slot's RECORD key (derived once at admit from the
-    record's identity, ``StreamingGenerator._record_key_data``); ``idx``:
+    record's identity, ``StreamingGenerator._records_key_data``); ``idx``:
     [B] int32 — the gen-buffer index of the token being sampled. Row b
     draws with ``fold_in(record_key_b, idx_b)``, so a record's token i is
     the same draw no matter which slot, tick, replica, or process decodes
@@ -339,6 +342,15 @@ class ServeMetrics:
         self.moe_grouped_rows = RateMeter()
         self.moe_grouped_tile_rows = RateMeter()
         self.experts_held: list[int] | None = None  # [first, count]
+        # Group-limited selection (``n_group``, ``topk_group``; 1 and 1
+        # where the router has no groups).
+        self.expert_groups: dict = {"n_group": 1, "topk_group": 1}
+        # The linear-attention layers' slot memory (a config with
+        # ``linear_pattern``; empty otherwise): the layers, the bytes of
+        # the recurrent state and of the conv tails, the state's dtype,
+        # what a tick passes over the state by ("kernel": tk_kda_step;
+        # "xla") and the admission's form ("chunked").
+        self.linear_state: dict = {}
         self.attn_blocks = 1  # attention blocks (cache rows) a layer
         self.latent_positions_valid = RateMeter()  # cached rows the served
         # ticks needed, summed over blocks: a tick at position p needs p
@@ -536,11 +548,13 @@ class ServeMetrics:
                 "moe_local_assignments": self.moe_local_assignments.count,
                 "moe_absent_assignments": self.moe_absent_assignments.count,
                 "experts_held": self.experts_held,
+                "groups": self.expert_groups,
                 "grouped_matmul": self.grouped_matmul,
                 "tick_form": self.tick_form,
                 "moe_grouped_rows": self.moe_grouped_rows.count,
                 "moe_grouped_tile_rows": self.moe_grouped_tile_rows.count,
             },
+            "linear_state": self.linear_state,
             "latent_pool": {
                 "attn_blocks": self.attn_blocks,
                 "latent_positions_valid": self.latent_positions_valid.count,
@@ -693,7 +707,7 @@ class ServeMetrics:
                 for name, value in s[section].items()
                 if name not in (
                     "moe_expert_load", "experts_held", "attn_blocks",
-                    "grouped_matmul", "tick_form",
+                    "grouped_matmul", "tick_form", "groups",
                     *self.kv_pool_static,
                 )
             ),
@@ -738,6 +752,19 @@ class ServeMetrics:
                 for r, v in s["distill"]["refreshes"].items()
             ] or 0),
         ])
+
+
+@jax.jit
+def _fold_record_ids(rng, ids):
+    """``StreamingGenerator._records_key_data``'s program: ``ids`` [3, N]
+    uint32 (the topic's checksum, the partition, the offset) → the raw
+    key data [N, W] of ``rng`` folded with each column in turn."""
+    def one(topic, partition, offset):
+        k = jax.random.fold_in(rng, topic)
+        k = jax.random.fold_in(jax.random.fold_in(k, partition), offset)
+        return jax.random.key_data(k)
+
+    return jax.vmap(one)(ids[0], ids[1], ids[2])
 
 
 def _layer_of(pool, l):
@@ -1559,7 +1586,12 @@ class StreamingGenerator:
         # machinery over ONE cache tensor, [L, B, M, rank + rope] in the
         # compute dtype; kvcache.resolve_kv_backend refuses every other
         # combination with its reason.
-        latent = cfg.is_mla
+        # Linear-attention layers beside latent ones (``linear_pattern``,
+        # models/linear_attn.py): slot memory by kind, a recurrent state
+        # [L_lin, B, H, E, E] in float32 and a conv tail [L_lin, B, taps -
+        # 1, 3 * H * E] the linear layers, the latent pool the others.
+        hybrid = bool(cfg.linear_pattern)
+        latent = cfg.is_mla and not hybrid
         # Kinds of layer (``window_pattern``): the same machinery over a
         # pool allocated by kind, ``KindKVCache``'s four tensors: the full
         # layers' K and V [Lf, B, M, K * Dh], the window layers' rings
@@ -1780,10 +1812,30 @@ class StreamingGenerator:
                         stats = _count_routing(stats, routing, act, cfg)
                     return (x, caches, stats), None
 
+                def hybrid_body(carry, layer, linear, row):
+                    # A linear or a latent layer: its row in its kind's
+                    # tensors; a slot that is not active keeps its state.
+                    x, caches, stats = carry
+                    x, caches, routing = linear_attn.slot_layer_step(
+                        x, layer, linear, row, caches, pos, act, cfg
+                    )
+                    if routing is not None:
+                        stats = _count_routing(stats, routing, act, cfg)
+                    return (x, caches, stats), None
+
                 # The layer index runs over the leading dense layers and
                 # then the expert layers (one group for every other config).
                 first = 0
-                for key, n, _expert_mlp in _layer_groups(cfg):
+                if hybrid:
+                    # Every kind's tensors are the period scan's carry.
+                    for key, pattern, lin0, lat0 in hybrid_groups(cfg):
+                        (x, caches, stats), _ = scan_hybrid(
+                            cfg, params[key], pattern, (x, caches, stats),
+                            hybrid_body, lin0, lat0,
+                        )
+                for key, n, _expert_mlp in (
+                    () if hybrid else _layer_groups(cfg)
+                ):
                     if kinds:
                         # Both pools are the period scan's carry.
                         (x, caches, stats), _ = scan_periods(
@@ -1907,7 +1959,7 @@ class StreamingGenerator:
             return out[:6]
 
         self._tick_fn = tick_fn
-        if kv_int8 or latent or kinds:
+        if kv_int8 or latent or kinds or hybrid:
             # int8 pools deliberately give up token-exactness, the one
             # contract warm resume exists to keep; hints are filtered out
             # in _take_hint, so no resume program is built. The latent
@@ -1918,7 +1970,23 @@ class StreamingGenerator:
         else:
             _resume = jax.jit(resume_admit, donate_argnums=(1,))
             self._resume_exec = lambda *a: _resume(self._params, *a)
-        if latent:
+        if hybrid:
+            n_lin, e = cfg.hybrid_layers(True), cfg.linear_head_dim
+            self._caches = (
+                jnp.zeros((n_lin, B, cfg.n_heads, e, e), jnp.float32),
+                jnp.zeros(
+                    (n_lin, B, cfg.linear_conv - 1, 3 * cfg.n_heads * e),
+                    cfg.dtype,
+                ),
+                jnp.zeros((cfg.cache_layers, B, M, cfg.latent_dim), cfg.dtype),
+            )
+            self.metrics.linear_state = {
+                "layers": n_lin, "bytes_state": self._caches[0].nbytes,
+                "bytes_conv": self._caches[1].nbytes,
+                "state_dtype": "float32", "step": linear_attn.step_form(),
+                "prefill": "chunked",
+            }
+        elif latent:
             # A row an attention block: [2L, ...] for the double layer.
             self._caches = (
                 jnp.zeros((cfg.cache_layers, B, M, cfg.latent_dim), cfg.dtype),
@@ -1963,6 +2031,9 @@ class StreamingGenerator:
                 (cfg.held_experts[1],), np.int64
             )
             self.metrics.experts_held = list(cfg.held_experts)
+            self.metrics.expert_groups = {
+                "n_group": cfg.n_group, "topk_group": cfg.topk_group,
+            }
         self._last_tok = jnp.zeros((B,), jnp.int32)
         self._pos = jnp.zeros((B,), jnp.int32)
         self._gen = jnp.zeros((B, self._max_new), jnp.int32)
@@ -2781,13 +2852,14 @@ class StreamingGenerator:
         # mid-loop, and an alias taken before the loop would clobber
         # them at the end.
         slot_iter = iter(phys_free)
+        key_data = self._records_key_data(queue)
         while True:
             nxt = self._next_decodable(queue)
             if nxt is None:
                 break
             rec, toks = nxt
             toks = np.asarray(toks, np.int32)
-            kd = self._record_key_data(rec)
+            kd = key_data[(rec.topic, rec.partition, rec.offset)]
             hint = self._take_hint(rec)
             hand = self._take_handoff(rec) if hint is None else None
             if hint is not None and hint.finished:
@@ -3471,18 +3543,33 @@ class StreamingGenerator:
                     break  # next record
         return None
 
-    def _record_key_data(self, rec: Record) -> np.ndarray:
-        """The record's sampling key: ``rng`` folded with the record's
-        identity — a pure function of (base key, topic, partition,
-        offset), so every replica/process derives the SAME key for the
-        same record (the fleet shares gen_kwargs). Raw key data, journal-
-        and device-friendly."""
-        k = jax.random.fold_in(
-            self._rng, zlib.crc32(rec.topic.encode()) & 0x7FFFFFFF
-        )
-        k = jax.random.fold_in(k, rec.partition & 0x7FFFFFFF)
-        k = jax.random.fold_in(k, rec.offset & 0x7FFFFFFF)
-        return np.asarray(jax.random.key_data(k), np.uint32)
+    def _records_key_data(self, records: list[Record]) -> dict:
+        """Every record's sampling key, by ``(topic, partition, offset)``:
+        ``rng`` folded with the record's identity — a pure function of
+        (base key, topic, partition, offset), so every replica/process
+        derives the SAME key for the same record (the fleet shares
+        gen_kwargs). Raw key data, journal- and device-friendly. An
+        admission's keys come from ONE dispatch and one fetch: a record
+        at a time each key is three eager folds and a blocking fetch, a
+        device round trip a record while the device waits for the
+        admission (a third of a second for 64 records, two for 384:
+        PERF.md §6, PR 41). The ids are padded to the slots (a whole
+        number of times), so the program has one shape and compiles with
+        the first admission (a warm-up's), never inside a serving window."""
+        if not records:
+            return {}
+        width = -(-len(records) // self._slots) * self._slots
+        ids = np.zeros((3, width), np.uint32)
+        for j, rec in enumerate(records):
+            ids[:, j] = (
+                zlib.crc32(rec.topic.encode()) & 0x7FFFFFFF,
+                rec.partition & 0x7FFFFFFF, rec.offset & 0x7FFFFFFF,
+            )
+        data = np.asarray(_fold_record_ids(self._rng, jnp.asarray(ids)))
+        return {
+            (rec.topic, rec.partition, rec.offset): data[j].astype(np.uint32)
+            for j, rec in enumerate(records)
+        }
 
     def add_resume_hints(self, entries: dict) -> None:
         """Install journal entries (``journal.DecodeJournal.load`` of a
@@ -3610,6 +3697,7 @@ class StreamingGenerator:
             keys_np = np.zeros((B, W), np.uint32)
             key_mask = np.zeros((B,), bool)
             queue = list(records)
+            key_data = self._records_key_data(queue)
             slot_iter = iter(free)
             resumed = 0
             journal_dirty = False
@@ -3618,7 +3706,7 @@ class StreamingGenerator:
                 if nxt is None:
                     break
                 rec, toks = nxt
-                kd = self._record_key_data(rec)
+                kd = key_data[(rec.topic, rec.partition, rec.offset)]
                 hint = self._take_hint(rec)
                 if hint is not None and hint.finished:
                     # The dead replica finished this completion but never

@@ -63,7 +63,7 @@ called ``token.commit``):
 The Pallas kernels carry fixed names too (``pl.pallas_call(name=...)`` in
 ``ops/``): ``tk_kvattn_dynlen``, ``tk_kvattn_paged``, ``tk_flash_fwd``,
 ``tk_flash_fwd_win``, ``tk_flash_bwd_dq``, ``tk_flash_bwd_dkv``,
-``tk_qmatmul``, ``tk_gmm_gate_up``, ``tk_gmm_down`` — the device trace
+``tk_qmatmul``, ``tk_gmm_gate_up``, ``tk_gmm_down``, ``tk_kda_step`` — the device trace
 names each kernel's operation after them.
 
 The device programs name their parts (``jit_admit``, ``jit_tick_block``,
@@ -95,12 +95,16 @@ that open it:
                        prefill's k and v, serve._slot_layer_step_latent
     tk_kv_write        quantisation and the row or ring write:
                        serve._quant_kv, _slot_layer_step*, admit's put,
-                       generate.ring_rows, prefill's pools
+                       generate.ring_rows, prefill's pools, a linear
+                       layer's conv tail (linear_attn.attend_step)
     tk_kv_read         scores, softmax and values over CACHED positions,
                        a pool of one kind: generate._read_cached
                        (_attend_cached's read), serve._slot_layer_step_q
                        (the Pallas call tk_kvattn_dynlen, the row write
-                       it holds too)
+                       it holds too); a linear-attention layer's pass
+                       over its recurrent state, which IS its cache
+                       (linear_attn.attend_step: the Pallas call
+                       tk_kda_step, or the jax.numpy step off the TPU)
     tk_kv_read_window  the same over a window layer's ring:
                        serve._slot_layer_step(kind=) names it to
                        generate._attend_merged
@@ -110,7 +114,9 @@ that open it:
                        mla._read_latent (attend_absorbed's read)
     tk_attn_flash      attention over a whole sequence, the flash kernels
                        and XLA's form: Transformer._attention,
-                       mla._attend_whole (attend_full's attention)
+                       mla._attend_whole (attend_full's attention), the
+                       linear layers' chunkwise form
+                       (linear_attn.attend_sequence: kda.kda_chunk)
     tk_ffn             the dense FFN and the shared experts:
                        transformer._dense_mlp, moe.routed_moe_mlp
     tk_moe_route       router scores, bias, top-k, renormalisation, the
