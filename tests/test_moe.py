@@ -341,7 +341,7 @@ class TestRoutedExpertLayer:
             honest = getattr(moe, name)
             monkeypatch.setattr(
                 moe, name,
-                lambda *a, _h=honest, _n=name: called.append(_n) or _h(*a),
+                lambda *a, _h=honest, _n=name, **kw: called.append(_n) or _h(*a, **kw),
             )
         layer = _routed_layer(rng)
         # 12 pairs over 8 experts, 1.5 an expert; 384 pairs, 48 an expert
@@ -667,11 +667,163 @@ class TestGroupedMatmulKernel:
         assert moe._gmm_rows(300) == (384, 128) and moe._gmm_rows(8) == (128, 128)
 
 
+# ------------------------------------------------ a held share, grouped
+# ``grouped_experts(share=True)`` against ``compacted_experts`` and the
+# plain sum over every pair (float64, a pair at a time): 4 held experts
+# of a router whose other outputs are absent (any idx outside [0, 4),
+# negative too: ``idx - first``). Each case: (the routing [24, 3] from
+# the seeded generator, the tokens that must read EXACTLY zero).
+
+
+def _share_routing(rng, case):
+    n, k, e = 24, 3, 4
+    spread = rng.integers(-6, 10, size=(n, k))  # a quarter of the pairs local
+    absent = np.where(rng.integers(0, 2, size=(n, k)) == 1, e + 3, -2)
+    local = (spread >= 0) & (spread < e)
+    if case == "no_local_pair":
+        return absent, np.arange(n)
+    if case == "every_pair_local":
+        return rng.integers(0, e, size=(n, k)), []
+    if case == "every_local_pair_to_one_expert":
+        return np.where(local, 2, absent), np.flatnonzero(~local.any(1))
+    if case == "a_token_with_every_choice_absent":
+        spread[5], spread[17] = [e, e + 1, -1], [-3, 2 * e, e]
+        return spread, [5, 17]
+    return spread, np.flatnonzero(~local.any(1))  # balanced; stacks at base
+
+
+_SHARE_CASES = [
+    "balanced", "no_local_pair", "every_pair_local",
+    "every_local_pair_to_one_expert", "a_token_with_every_choice_absent",
+    "base_into_stacks_of_two_layers",
+]
+
+
+@pytest.fixture
+def unvisited_rows_are_nan(monkeypatch):
+    """What the chip does and the interpreter does not: a row of the
+    kernels' output that no visit wrote holds anything. Here, NaN."""
+    from torchkafka_tpu.ops import moe
+
+    honest = moe._gmm
+
+    def poisoned(rows, mats, base, walk, tm, ts, name):
+        out = honest(rows, mats, base, walk, tm, ts, name)
+        row = jnp.arange(out.shape[0])[:, None]
+        return jnp.where(row < walk[0][-1], out, jnp.nan)
+
+    monkeypatch.setattr(moe, "_gmm", poisoned)
+
+
+class TestGroupedShare:
+    @pytest.mark.parametrize("case", _SHARE_CASES)
+    def test_the_share_s_grouped_form_equals_the_loop_and_the_plain_sum(
+        self, rng, case, unvisited_rows_are_nan
+    ):
+        """An absent pair adds exactly nothing, an all-absent token reads
+        exactly zero, no local pair is dropped at any load, and ``base``
+        reaches the layer's experts in stacks of two layers'."""
+        from torchkafka_tpu.ops import moe
+
+        e, d = 4, 32
+        routing, zero_tokens = _share_routing(rng, case)
+        layer = _routed_layer(rng)
+        mats = [layer[n][:e] for n in ("w_gate", "w_up", "w_down")]
+        base = 0
+        if case == "base_into_stacks_of_two_layers":
+            other = [layer[n][e:] for n in ("w_gate", "w_up", "w_down")]
+            stacks, base = [jnp.concatenate(p) for p in zip(other, mats)], e
+        else:
+            stacks = mats
+        x = jnp.asarray(rng.normal(size=(24, d)), jnp.float32)
+        w = jnp.asarray(rng.uniform(0.1, 1.0, size=(24, 3)), jnp.float32)
+        idx = jnp.asarray(routing, jnp.int32)
+        want = np.zeros((24, d))
+        for t, ks in enumerate(routing):
+            for j, ex in enumerate(ks):
+                if 0 <= ex < e:
+                    want[t] += float(w[t, j]) * _np_swiglu(
+                        np.asarray(x[t], np.float64), *(m[ex] for m in mats)
+                    )
+        got = np.asarray(
+            moe.grouped_experts(x, idx, w, *stacks, at=(base, e), share=True)
+        )
+        loop = np.asarray(
+            moe.compacted_experts(x, idx, w, *stacks, e=e, cap=16, base=base)
+        )
+        np.testing.assert_allclose(got, want, atol=1e-5)
+        np.testing.assert_allclose(loop, want, atol=1e-5)
+        assert (got[zero_tokens] == 0).all() and np.isfinite(got).all()
+        local = ((routing >= 0) & (routing < e)).sum()
+        if case == "no_local_pair":
+            assert local == 0 and (got == 0).all()
+        if case == "every_pair_local":
+            assert local == routing.size
+
+    def test_the_walk_visits_the_local_pairs_blocks_alone(self, rng):
+        """Sizes over the held experts alone: of 6 blocks of 16 sorted
+        rows the walk visits those that the 20 local pairs fill, two, and
+        an expert no pair chose makes no visit."""
+        from torchkafka_tpu.ops import moe
+
+        sizes = jnp.asarray([9, 0, 11, 0], jnp.int32)  # of 96 pairs
+        offsets, expert, tile, visits = moe._gmm_tiles(sizes, 6, 16)
+        assert int(visits) == 3 and int(offsets[-1]) == 20
+        assert expert[:3].tolist() == [0, 2, 2] and tile[:3].tolist() == [0, 0, 1]
+
+    def test_a_share_s_counts_are_the_local_pairs(self, rng):
+        from torchkafka_tpu.ops import moe
+
+        routing, _ = _share_routing(rng, "balanced")
+        first = 8
+        local = ((routing >= 0) & (routing < 4)).sum()
+        pairs, rows = np.asarray(moe.grouped_counts(
+            jnp.asarray(routing + first, jnp.int32)[None], 4, first
+        ))
+        assert pairs == local and rows >= pairs and rows % 128 == 0
+        assert np.asarray(moe.grouped_counts(
+            jnp.asarray(routing % 4, jnp.int32)[None], 4
+        ))[0] == routing.size
+
+    @pytest.mark.parametrize("floor,form", [(0, "grouped"), (10**9, "compacted")])
+    def test_the_layer_hands_a_share_to_either_form(
+        self, rng, monkeypatch, floor, form
+    ):
+        """``routed_moe_mlp`` with experts [2, 6) of 8 held: the same part
+        of the sum by the kernels and by the tile loop, the form by the
+        rule alone (its floors out of reach, or at zero)."""
+        from torchkafka_tpu.ops import moe
+
+        monkeypatch.setattr(moe, "_GROUPED_MIN_PAIRS_PER_EXPERT", floor)
+        monkeypatch.setattr(moe, "_GROUPED_MIN_PAIRS_OUT_OF_STACKS", floor)
+        cfg = dataclasses.replace(
+            ROUTED_CFG, experts_held=(2, 4), n_shared_experts=0
+        )
+        assert moe.expert_form(cfg, 18) == form
+        layer = _routed_layer(rng, bias_scale=0.3)
+        held = {
+            n: layer[n][2:6] if n in ("w_gate", "w_up", "w_down") else layer[n]
+            for n in layer
+        }
+        h = jnp.asarray(rng.normal(size=(2, 9, 32)), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda h: moe.routed_moe_mlp(h, held, cfg))(h)
+        names = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+        assert ("pallas_call" in names) == (form == "grouped")
+        out, idx = moe.routed_moe_mlp(h, held, cfg)
+        # The plain sum over all eight experts, the absent ones' outputs zero.
+        absent = dict(layer, w_down=layer["w_down"].at[:2].set(0).at[6:].set(0))
+        ref, chosen = _np_routed(h, absent, cfg, shared=False)
+        np.testing.assert_allclose(np.asarray(out), ref, atol=1e-5)
+        assert (np.sort(np.asarray(idx).reshape(-1, 3)) == np.sort(chosen)).all()
+
+
 # The benchmark's four serving configurations at their own sizes: (file
 # under chipbench/configs, program, the form its routed layer takes there).
 # Mellum2's tick averages 16 pairs an expert out of stacks, Kanana's 3 out
-# of the layer's own tensors; their admissions 512 and 144; LongCat holds a
-# share; Mistral has no routed layer.
+# of the layer's own tensors; their admissions 512 and 144; LongCat and
+# Ling3 hold a share (2 and 6 local pairs a held expert a tick, 48 an
+# admission's trip, whose sorted copy would be mostly absent pairs' rows);
+# Mistral has no routed layer.
 _CELL_FORMS = [
     ("mellum2-12b-a2.5b-8l", "tick", "grouped"),
     ("mellum2-12b-a2.5b-8l", "admit", "grouped"),
@@ -679,6 +831,8 @@ _CELL_FORMS = [
     ("kanana-2-30b-a3b-7l", "admit", "grouped"),
     ("longcat-flash-omni-4l-ep32", "tick", "compacted"),
     ("longcat-flash-omni-4l-ep32", "admit", "compacted"),
+    ("ling-3.0-flash-7l-ep8", "tick", "grouped"),
+    ("ling-3.0-flash-7l-ep8", "admit", "compacted"),
     ("mistral-7b-v0.3-w8", "tick", None),
     ("mistral-7b-v0.3-w8", "admit", None),
 ]
@@ -746,25 +900,64 @@ class TestTheFormACellTakes:
             )
 
     def test_fewer_slots_than_the_thresholds_keep_the_loop(self):
-        """The rule is the static shapes', not the model's: Mellum2 with a
-        pair an expert fewer than the threshold asks keeps the compacted
-        loop out of its stacks."""
+        """The rule is the static shapes', not the model's: out of stacks
+        the floor is the one read against the loop, and Mellum2 with a pair
+        an expert fewer than it asks keeps the compacted loop; so does
+        Ling3's share with fewer LOCAL pairs a held expert."""
         from torchkafka_tpu.ops import moe
 
+        floor = moe._GROUPED_MIN_PAIRS_OUT_OF_STACKS
+        assert floor <= moe._GROUPED_MIN_PAIRS_PER_EXPERT
         _server, cfg, _window = _cell_server("mellum2-12b-a2.5b-8l")
-        slots = (
-            moe._GROUPED_MIN_PAIRS_PER_EXPERT * cfg.n_experts
-            // cfg.expert_top_k
-        )
+        slots = floor * cfg.n_experts // cfg.expert_top_k
         assert moe.expert_form(cfg, slots) == "grouped"
         few, _cfg, _w = _cell_server("mellum2-12b-a2.5b-8l", slots - 1)
         assert few.metrics.summary()["expert_layer"]["tick_form"] == "compacted"
+        _server, ling, _window = _cell_server("ling-3.0-flash-7l-ep8")
+        slots = floor * ling.router_width // ling.expert_top_k
+        assert slots == 256 and moe.expert_form(ling, slots) == "grouped"
+        assert moe.expert_form(ling, slots - 1) == "compacted"
 
-    @pytest.mark.parametrize("rows,stacked,form", [
-        (1, False, "all_experts"), (1, True, "compacted"),
-        (64, False, "grouped"), (64, True, "grouped"),
+    @pytest.mark.parametrize("site,pairs,count,width,d,f,form", [
+        ("ling3_tick", 384 * 8, 64, 512, 2560, 768, "grouped"),
+        ("ling3_admission_trip", 6 * 512 * 8, 64, 512, 2560, 768, "compacted"),
+        ("longcat_tick", 128 * 12, 16, 768, 6144, 2048, "compacted"),
+        ("longcat_admission_trip", 6 * 512 * 12, 16, 768, 6144, 2048, "compacted"),
     ])
-    def test_the_form_named_is_the_form_traced(self, rng, rows, stacked, form):
+    def test_what_decides_each_held_share_site(
+        self, monkeypatch, site, pairs, count, width, d, f, form
+    ):
+        """The four held-share sites of the cells by the rule's three
+        conditions: the floor (local pairs a held expert), the kernels'
+        VMEM, the absent pairs' rows against the held experts' weight
+        rows. With the floors at zero only the VMEM still decides."""
+        from torchkafka_tpu.ops import moe
+
+        assert moe._form(pairs, count, width, True, d, f, 2) == form
+        tm, ts = moe._gmm_rows(pairs)
+        fits = moe._gmm_vmem(2, d, f, tm, ts, 2) <= moe._GMM_VMEM_BYTES
+        assert fits == site.startswith("ling3")
+        absent = pairs * (width - count) // width
+        assert (absent <= moe._GROUPED_MAX_ABSENT_ROWS * count * 3 * f) == (
+            site.endswith("tick")
+        )
+        monkeypatch.setattr(moe, "_GROUPED_MIN_PAIRS_PER_EXPERT", 0)
+        assert moe._form(pairs, count, width, True, d, f, 2) == (
+            "grouped" if fits else "compacted"
+        )
+
+    @pytest.mark.parametrize("rows,stacked,width,form", [
+        (1, False, None, "all_experts"), (1, True, None, "compacted"),
+        (64, False, None, "grouped"), (64, True, None, "grouped"),
+        # a share of 8 of a router's 9 outputs: 3 pairs (under the floor),
+        # 48 (5 a held expert, 5 absent rows), 192 (21 absent rows against
+        # a sixteenth of the held experts' 288 weight rows)
+        (1, True, 9, "compacted"), (16, True, 9, "grouped"),
+        (64, True, 9, "compacted"),
+    ])
+    def test_the_form_named_is_the_form_traced(
+        self, rng, rows, stacked, width, form
+    ):
         """The rule's answer against the program ``routed_experts`` builds
         for the same shapes (3 pairs over 8 experts, and 192): the
         kernels' calls, the tile loop, or neither."""
@@ -776,9 +969,9 @@ class TestTheFormACellTakes:
             mats = [jnp.concatenate([m, m]) for m in mats]
         idx = jnp.zeros((rows, 3), jnp.int32)
         jaxpr = jax.make_jaxpr(lambda x, w, *m: moe.routed_experts(
-            x, idx, w, *m, at=(8, 8) if stacked else None
+            x, idx, w, *m, at=(8, 8) if stacked else None, width=width
         ))(jnp.zeros((rows, 32)), jnp.zeros((rows, 3)), *mats)
         names = {e.primitive.name for e in jaxpr.jaxpr.eqns}
-        assert moe._form(rows * 3, 8, stacked) == form
+        assert moe._form(rows * 3, 8, width or 8, stacked, 32, 12, 4) == form
         assert ("pallas_call" in names) == (form == "grouped")
         assert ("while" in names) == (form == "compacted")

@@ -1537,7 +1537,7 @@ def test_a_tick_grouped_by_its_own_shapes_serves_the_reference_tokens():
     from torchkafka_tpu.ops import moe
 
     experts, top_k = 4, 2
-    slots = moe._GROUPED_MIN_PAIRS_PER_EXPERT * experts // top_k
+    slots = moe._GROUPED_MIN_PAIRS_OUT_OF_STACKS * experts // top_k
     cfg = TransformerConfig(
         vocab_size=VOCAB, d_model=32, n_layers=4, n_heads=4, n_kv_heads=2,
         d_ff=48, max_seq_len=P + MAX_NEW, dtype=jnp.float32,
@@ -1554,6 +1554,7 @@ def test_a_tick_grouped_by_its_own_shapes_serves_the_reference_tokens():
     prompts = _topic(broker, 10)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(moe, "_GROUPED_MIN_PAIRS_PER_EXPERT", 10**9)
+        mp.setattr(moe, "_GROUPED_MIN_PAIRS_OUT_OF_STACKS", 10**9)
         expected = _greedy_by_full_forward(cfg, params, prompts, MAX_NEW)
     consumer = tk.MemoryConsumer(broker, "p", group_id="g")
     server = StreamingGenerator(

@@ -30,9 +30,11 @@ is THIS chip's part of the sum. Nothing stands in for the absent chips.
 
 No token is dropped at any load, in any form:
 
-- ``grouped_experts`` (every expert held; an admission, a forward, and a
+- ``grouped_experts`` (every expert held: an admission, a forward, and a
   decode tick whose rows average ``_GROUPED_MIN_PAIRS_PER_EXPERT`` pairs
-  an expert: Mellum2's 128 slots, 16): the pairs are sorted by expert
+  an expert, Mellum2's 128 slots, 16; or a SHARE of them, where a held
+  expert can expect ``_GROUPED_MIN_PAIRS_OUT_OF_STACKS`` local pairs:
+  Ling3's tick of 384 slots, 6): the pairs are sorted by expert
   and each expert multiplies exactly the rows routed to it, in two Pallas
   kernels of this file (``tk_gmm_gate_up``: ``silu(x · w_gate) * (x ·
   w_up)`` formed in float32 and written once; ``tk_gmm_down``), so the
@@ -41,28 +43,52 @@ No token is dropped at any load, in any form:
   is visited once by each, which multiplies the pieces its run touches;
   an expert's matrices stay in VMEM over its consecutive blocks, the next
   expert's are fetched while this one multiplies, and the stacks ``[L *
-  E, ...]`` are taken whole (``_gmm``). Off the TPU the Pallas
+  E, ...]`` are taken whole (``_gmm``). Of a share the local pairs sort
+  first, by expert, and the absent ones behind them belong to no run: the
+  walk stops where the local pairs end, the kernels touch nothing past
+  it, and a select drops those rows from the sum. Off the TPU the Pallas
   interpreter runs them.
 - ``all_experts`` (every expert held, the layer's own ``[E, ...]``
   tensors): where the rows are fewer an expert than that (Kanana's decode
   tick, 3) every expert multiplies every row and the unrouted ones are
   weighted by zero: the weights are streamed whole either way.
-- ``compacted_experts`` (a share held, or zero experts behind the real
-  ones: prefill and decode alike; and the few rows an expert of a layer
-  whose experts are rows of stacks, where the einsum would copy the
-  stacks): of N·K pairs only ``count / (E + Z)`` meet a held expert, so
+- ``compacted_experts`` (the few rows an expert of a layer whose experts
+  are rows of stacks, where the einsum would copy the stacks; a share
+  where the grouped form is not taken: fewer local pairs a held expert
+  than the floor, experts too large for the kernels' VMEM, LongCat's, or
+  an admission's trip, most of whose sorted copy would be absent pairs'
+  rows): of N·K pairs only ``count / (E + Z)`` meet a held expert, so
   the local pairs are sorted to the front and multiplied in tiles of
   ``cap`` rows of one expert; the loop walks the tiles there are, a value
   of the routing and not a bound on it: all N rows to one expert are N /
   cap tiles, none is cut, and an expert no pair chose is not read. Its
   body is serial: a tile's weights are not fetched while the one before
-  multiplies.
+  multiplies, which experts of 75 MB hide (LongCat's tick reads 88% of
+  the HBM roofline) and experts of 11.8 MB do not (Ling3's read 42%).
 
 Which one runs is decided by the configuration and the static shapes
-alone (``_form``: ``_GROUPED_MIN_PAIRS_PER_EXPERT`` against the pairs an
-expert can expect, and whether the experts are rows of stacks), never by
-an option; ``expert_form`` names the choice for a configuration and a
-row count, and PERF.md holds the chip's readings.
+alone (``_form``: the floors against the pairs a held expert can expect,
+whether the experts are rows of stacks, whether the kernels fit, and how
+much of the sorted copy is absent pairs' rows), never by an option;
+``expert_form`` names the choice for a configuration and a row count, and
+PERF.md holds the chip's readings. The four held-share sites the
+benchmark's cells have (TPU v5e, PR 42, ``chipbench/tick_forms.py`` with
+the loop as built against the floors at 0; ms a tick, s an admission of
+every slot):
+
+    site                      pairs   local/expert  expert   form
+    Ling3 tick, 384 slots      3,072  6             11.8 MB  grouped   40.44 / 35.03
+      at 256 and 128 slots     2,048  4 and 2                          30.77 / 25.61, 21.20 / 15.28
+    Ling3 admission trip      24,576  48            11.8 MB  compacted 7.281 / 7.302 (384 rows; 4.899 / 4.904, 2.500 / 2.511)
+    LongCat tick, 128 slots    1,536  2             75 MB    compacted (the kernels would ask 123 of 128 MiB)
+    LongCat admission trip    36,864  48            75 MB    compacted
+
+An admission keeps the loop: its tiles hold 96 rows of one expert, the
+products and not the weights' stream bound it, and seven eighths of the
+24,576 rows the grouped form sorts and copies (126 MB a layer, a sixth of
+the held experts' stream, four passes) belong to absent pairs: the chip
+reads the two forms within 0.3% of each other, and the copies would add
+0.3 GiB to the admit program's footprint.
 """
 
 from __future__ import annotations
@@ -80,21 +106,50 @@ from torchkafka_tpu.ops.flash import _default_interpret, tpu_compiler_params
 from torchkafka_tpu.utils import tracing
 
 # Token-choice pairs an expert must average before the sorted, grouped
-# form is taken: below it the all-experts einsum streams the same weights
-# and skips the sort, the gather of the sorted rows and the inverse
-# permutation (out of stacks, the compacted loop walks its tiles). Read on
-# the v5e (PERF.md §6, PR 39; ms a decode tick, the fallback against the
-# grouped form): Mellum2, out of its stacks, at 16, 12, 8 and 4 pairs an
-# expert (128, 96, 64, 32 slots) 24.94 / 17.53, 23.28 / 15.60, 20.53 /
-# 13.38, 18.33 / 11.44: the grouped form wins at every point against the
-# loop; Kanana's tick, over the layer's own tensors, at 3: 18.09 / 40.10,
-# the einsum keeps it, and not by the pairs (the kernels are handed a copy
-# of the layer's experts out of the layer scan). The one constant governs
-# both fallbacks, so it stands at the highest point read at which a
-# serving cell sits, Mellum2's 16: the own-tensor kind has been read
-# nowhere between 3 and an admission's 144 (Kanana's; Mellum2's 512), and
-# what it would take to go to 4 is in PERF.md §7.
+# form is taken, against each fallback: two crossovers that were read on
+# the v5e, not a knob (PERF.md §6, PR 39 and PR 42; ms a decode tick, the
+# fallback against the grouped form).
+#
+# Against the all-experts EINSUM over the layer's own tensors, which
+# streams the same weights and skips the sort, the gather of the sorted
+# rows and the inverse permutation: Kanana's tick at 3 pairs an expert
+# 18.09 / 40.10, the einsum keeps it, and not by the pairs (the kernels
+# are handed a copy of the layer's experts out of the layer scan). That
+# kind has been read nowhere between 3 and an admission's 144 (Kanana's),
+# so its floor stands at the highest point read at which a serving cell
+# sits, Mellum2's 16; what it would take to go lower is in PERF.md §7.
 _GROUPED_MIN_PAIRS_PER_EXPERT = 16
+# Against the LOOP of one-expert tiles (experts that are rows of stacks,
+# every one held or a share): Mellum2 at 16, 12, 8 and 4 pairs an expert
+# (128, 96, 64, 32 slots) 24.94 / 17.53, 23.28 / 15.60, 20.53 / 13.38,
+# 18.33 / 11.44; Ling3's share of 64 of 512 at 6, 4 and 2 LOCAL pairs a
+# held expert (384, 256, 128 slots) 40.44 / 35.03, 30.77 / 25.61, 21.20 /
+# 15.28: the grouped form wins at every point against the loop, which
+# pays a tile's serial fetch whatever it is fed. The floor stands at 4,
+# the lowest point both kinds were read at; LongCat's share at 2 (experts
+# of 75 MB, whose fetch hides the loop's gaps: 88% of the roofline) has
+# not been read grouped and does not fit the kernels as built. The loop
+# gives way wherever the einsum does: the lower of the two floors counts
+# out of stacks, and ``chipbench/tick_forms.py`` sets the one above to 0
+# to read the grouped form at every row count.
+_GROUPED_MIN_PAIRS_OUT_OF_STACKS = 4
+# A share's sorted copy holds all N·K rows though ``count / width`` of
+# them are local: the rows gathered and copied for NOTHING, the absent
+# pairs', as a share of the held experts' weight rows (``count · 3 · F``;
+# a row is D numbers on both sides) up to which the grouped form is taken.
+# Ling3's tick 2,688 rows against 147,456, a fifty-fifth (15.7 MB a layer
+# beside a stream of 755): grouped, 40.44 -> 35.03 ms a tick; its
+# admission's trip 21,504, a seventh: the chip reads the two forms within
+# 0.3% (7.281 / 7.302 s for 384 rows) and the loop stays (module
+# docstring). The bound lies between the two readings.
+_GROUPED_MAX_ABSENT_ROWS = 1 / 16
+# What the kernels may ask of the v5e's 128 MiB of VMEM (``_gmm_vmem``:
+# an expert's gate and up matrices twice, so that the next expert's are
+# fetched while this one multiplies). Mellum2's experts ask 31 MiB, Ling3's
+# 31; LongCat's (6144 x 2048) 123, which the compiler takes for a described
+# v5e and which no chip run has read: three quarters of VMEM keeps the
+# rule on this side of what was measured.
+_GMM_VMEM_BYTES = 96 << 20
 
 
 @tracing.scope(tracing.SCOPE_MOE_ROUTE)
@@ -225,6 +280,14 @@ def _gmm_kernel(base_ref, offsets_ref, expert_ref, tile_ref, x_ref, *refs,
     lax.fori_loop(0, tm // ts, piece, None)
 
 
+def _gmm_vmem(mats: int, kdim: int, n: int, tm: int, ts: int, item: int) -> int:
+    """The VMEM ``_gmm`` asks for, in bytes: two buffers a block (an
+    expert's ``mats`` whole ``[kdim, n]`` matrices, the rows in and out),
+    a piece's float32 products, room for the rest."""
+    vmem = 2 * item * (mats * kdim * n + tm * (kdim + n))
+    return vmem + 4 * ts * n * (mats + 1) + (8 << 20)
+
+
 @tracing.scope(tracing.SCOPE_MOE_EXPERTS)
 def _gmm(rows, mats, base, walk, tm: int, ts: int, name: str):
     """rows [M, K] sorted by expert, M a multiple of the block ``tm``, a
@@ -238,10 +301,7 @@ def _gmm(rows, mats, base, walk, tm: int, ts: int, name: str):
     n = mats[0].shape[-1]
     offsets, expert, tile, visits = walk
     interpret = _default_interpret()
-    item = rows.dtype.itemsize
-    # Two buffers a block, a piece's float32 products, room for the rest.
-    vmem = 2 * item * (len(mats) * kdim * n + tm * (kdim + n))
-    vmem += 4 * ts * n * (len(mats) + 1) + (8 << 20)
+    vmem = _gmm_vmem(len(mats), kdim, n, tm, ts, rows.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, ts=ts),
         out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
@@ -269,7 +329,8 @@ def _gmm(rows, mats, base, walk, tm: int, ts: int, name: str):
       rows, *mats)
 
 
-def grouped_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
+def grouped_experts(h, idx, weights, w_gate, w_up, w_down, at=None,
+                    share=False):
     """Σ_k w_k · E_idx_k(h) by one grouped matmul kernel a projection pair.
 
     h [N, D]; idx, weights [N, K]; w_gate, w_up [E, D, F]; w_down
@@ -282,15 +343,28 @@ def grouped_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
     ``i`` of ``count`` is row ``base + i``. The kernels take the stacks
     whole and reach a row through their index maps: a layer's slice of a
     stack would be copied out first, three times the experts' bytes a
-    layer (PERF.md, PR 34)."""
+    layer (PERF.md, PR 34).
+
+    ``share``: the ``count`` experts are a share of the router's, and an
+    ``idx`` outside ``[0, count)`` is a pair this chip does not compute
+    (as ``compacted_experts`` reads it). Those pairs sort behind the last
+    held expert's run and no run holds them, so the walk visits only the
+    blocks the local pairs fill: the kernels neither read nor write the
+    other rows, which come back UNINITIALISED. A select on "the pair is
+    local" takes them out before the weighted sum; a zero weight would
+    not (0 × NaN)."""
     n, k = idx.shape
     base, count = at or (0, w_gate.shape[0])
     tm, ts = _gmm_rows(n * k)
     tiles_m = -(-n * k // tm)
     with tracing.scope(tracing.SCOPE_MOE_ROUTE):
         flat = idx.reshape(-1)
+        if share:
+            local = (flat >= 0) & (flat < count)
+            flat = jnp.where(local, flat, count)  # absent pairs last
         order = jnp.argsort(flat, stable=True)  # sorted pair -> pair
-        sizes = jnp.zeros((count,), jnp.int32).at[flat].add(1)
+        # An absent pair's key, ``count``, falls off the end and is dropped.
+        sizes = jnp.zeros((count,), jnp.int32).at[flat].add(1, mode="drop")
         walk = _gmm_tiles(sizes, tiles_m, tm)
     with tracing.scope(tracing.SCOPE_MOE_DISPATCH):
         # Rows past N·K fill the last tile: no expert's, never read back.
@@ -301,6 +375,8 @@ def grouped_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
     with tracing.scope(tracing.SCOPE_MOE_DISPATCH):
         inverse = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
         out = out[inverse].reshape(n, k, -1).astype(jnp.float32)
+        if share:
+            out = jnp.where(local.reshape(n, k, 1), out, 0.0)
         return jnp.einsum("nkd,nk->nd", out, weights).astype(h.dtype)
 
 
@@ -376,14 +452,35 @@ def all_experts(h, idx, weights, w_gate, w_up, w_down):
         ).astype(h.dtype)
 
 
-def _takes_grouped(pairs: int, count: int) -> bool:
-    return pairs >= _GROUPED_MIN_PAIRS_PER_EXPERT * count
+def _form(pairs: int, count: int, width: int, stacked: bool, d: int, f: int,
+          itemsize: int) -> str:
+    """The form of the sum of ``pairs`` token-choice pairs drawn over a
+    router of ``width`` outputs of which ``count`` meet an expert held
+    here (``width`` = ``count``: every expert is), an expert's matrices
+    ``[d, f]`` and ``[f, d]`` of ``itemsize`` bytes a number, rows of
+    stacks (always so for a share) or the layer's own tensors. The grouped
+    form is taken where all three hold, each read from the shapes:
 
-
-def _form(pairs: int, count: int, stacked: bool) -> str:
-    """The form of the sum where every expert is held: ``pairs`` over
-    ``count`` experts, their matrices rows of stacks or the layer's own."""
-    if _takes_grouped(pairs, count):
+    - a held expert can expect the floor's pairs, ``pairs / width``: the
+      floor against the einsum over own tensors, the lower of the two
+      floors against the loop out of stacks;
+    - the kernels fit: they hold an expert's gate and up matrices twice in
+      VMEM (``_gmm_vmem``);
+    - the rows sorted and copied for nothing, the ABSENT pairs', stay
+      under ``_GROUPED_MAX_ABSENT_ROWS`` of the held experts' weight rows
+      (none where every expert is held). A floor of 0 is
+      ``chipbench/tick_forms.py``'s way to time the grouped form at every
+      row count: it lifts this bound with the floor."""
+    floor = _GROUPED_MIN_PAIRS_PER_EXPERT
+    if stacked:
+        floor = min(floor, _GROUPED_MIN_PAIRS_OUT_OF_STACKS)
+    tm, ts = _gmm_rows(pairs)
+    absent = pairs * (width - count) // width
+    if (
+        pairs >= floor * width
+        and _gmm_vmem(2, d, f, tm, ts, itemsize) <= _GMM_VMEM_BYTES
+        and (floor == 0 or absent <= _GROUPED_MAX_ABSENT_ROWS * count * 3 * f)
+    ):
         return "grouped"
     return "compacted" if stacked else "all_experts"
 
@@ -393,15 +490,17 @@ def expert_form(cfg, rows: int) -> str | None:
     ``"grouped"``, ``"compacted"``, ``"all_experts"``; None: the config
     has no routed layer. The experts come out of stacks where the model
     hands them on so (``scan_periods``' ``experts_at`` under a
-    ``window_pattern``, the double layer's)."""
+    ``window_pattern``, the double layer's), and a share is reached as
+    stacks are."""
     if not cfg.routed_moe:
         return None
-    if cfg.moe_partial:
-        return "compacted"
     return _form(
-        rows * cfg.expert_top_k, cfg.n_experts,
-        stacked=bool(cfg.window_pattern or cfg.linear_pattern)
+        rows * cfg.expert_top_k, cfg.held_experts[1], cfg.router_width,
+        stacked=cfg.moe_partial
+        or bool(cfg.window_pattern or cfg.linear_pattern)
         or cfg.attn_blocks == 2,
+        d=cfg.d_model, f=cfg.moe_d_ff,
+        itemsize=jnp.dtype(cfg.dtype).itemsize,
     )
 
 
@@ -412,44 +511,60 @@ def grouped_form(cfg, rows: int) -> bool:
 
 
 @tracing.scope(tracing.SCOPE_MOE_ROUTE)
-def grouped_counts(routing, count: int):
+def grouped_counts(routing, count: int, first: int | None = None):
     """What ``grouped_experts`` multiplied for the routing [L, ..., K] of
     L layers' calls over ``count`` experts each: int32 (the pairs, the
     rows of the pieces its kernels multiplied: every piece an expert's
     run touches, whole), summed over the layers. Their quotient is the
     pieces' fill, what uneven routing costs the kernels
-    (``ServeMetrics.moe_grouped_rows``, ``_tile_rows``)."""
+    (``ServeMetrics.moe_grouped_rows``, ``_tile_rows``). ``first``: the
+    ``count`` experts are a share from that output of the router on, and
+    the pairs are the local ones."""
     flat = routing.reshape(routing.shape[0], -1)
     pairs = flat.shape[1]
     _tm, ts = _gmm_rows(pairs)
 
-    def pieces(layer):
-        sizes = jnp.sum(
+    def sizes(layer):
+        return jnp.sum(
             layer[:, None] == jnp.arange(count), axis=0, dtype=jnp.int32
         )
-        return _gmm_tiles(sizes, -(-pairs // ts), ts)[3]
 
-    return jnp.stack([
-        jnp.int32(flat.size), jax.vmap(pieces)(flat).sum() * ts
-    ])
+    def pieces(layer):
+        return _gmm_tiles(sizes(layer), -(-pairs // ts), ts)[3]
+
+    if first is None:
+        multiplied = jnp.int32(flat.size)
+    else:
+        flat = flat - first
+        multiplied = jax.vmap(sizes)(flat).sum()
+    return jnp.stack([multiplied, jax.vmap(pieces)(flat).sum() * ts])
 
 
-def routed_experts(h, idx, weights, w_gate, w_up, w_down, at=None):
-    """Every expert is here: the form the static shapes call for (module
-    docstring). ``at`` = ``(base, count)``: the matrices are stacks and
-    this layer's experts their rows ``[base, base + count)``."""
+def routed_experts(h, idx, weights, w_gate, w_up, w_down, at=None, width=None):
+    """The form the static shapes call for (module docstring). ``at`` =
+    ``(base, count)``: the matrices are stacks and this layer's experts
+    their rows ``[base, base + count)``. ``width``: the ``count`` experts
+    are a SHARE of a router's ``width`` outputs (``at`` is given), ``idx``
+    counts from the first held expert and a value outside ``[0, count)``
+    is a pair that adds nothing here; default every expert is here."""
     n, k = idx.shape
     count = w_gate.shape[0] if at is None else at[1]
-    form = _form(n * k, count, stacked=at is not None)
+    form = _form(
+        n * k, count, width or count, at is not None, *w_gate.shape[-2:],
+        w_gate.dtype.itemsize,
+    )
     if form == "grouped":
-        return grouped_experts(h, idx, weights, w_gate, w_up, w_down, at)
+        return grouped_experts(
+            h, idx, weights, w_gate, w_up, w_down, at, share=width is not None
+        )
     if form == "compacted":
         # Few rows an expert, out of stacks: the compacted form reaches an
         # expert by ONE dynamic index, which fuses into its products. The
         # all-experts einsum over a slice of the stacks has the compiler
         # re-lay the WHOLE stacks once a dispatch (PERF.md, PR 34: 4 GB
         # of temporaries at 64 experts of 2304 x 896 in 8 layers).
-        cap = -(-2 * n * k // count // 16) * 16  # as a held share's
+        # Twice the pairs a held expert can expect, in whole sublane groups.
+        cap = -(-2 * n * k // (width or count) // 16) * 16
         return compacted_experts(
             h, idx, weights, w_gate, w_up, w_down, e=count, cap=cap,
             base=at[0],
@@ -482,10 +597,9 @@ def routed_moe_mlp(h, layer, cfg, experts=None):
     mats = [load_weight(m, cfg.dtype) for m in mats]
     first, count = cfg.held_experts
     if cfg.moe_partial:
-        # Twice the pairs a held expert can expect, in whole sublane groups.
-        cap = -(-2 * idx.size // cfg.router_width // 16) * 16
-        out = compacted_experts(
-            x, idx - first, weights, *mats, e=count, cap=cap, base=base
+        out = routed_experts(
+            x, idx - first, weights, *mats, at=(base, count),
+            width=cfg.router_width,
         )
     else:
         out = routed_experts(

@@ -1666,6 +1666,8 @@ class StreamingGenerator:
         # (ops/moe.py decides by the static shapes): the admit program
         # then counts what its kernels multiplied, one more output.
         grouped = moe.grouped_form(cfg, R * P)
+        held = cfg.held_experts  # of a share, the local pairs are counted
+        counted = (held[1], held[0] if cfg.moe_partial else None)
         if grouped:
             self.metrics.grouped_matmul = "kernel"
         # A tick's B tokens, by the same rule.
@@ -1711,7 +1713,7 @@ class StreamingGenerator:
                 if grouped:  # the routing [L_moe, R, P, top_k]
                     counts = [
                         counts[0]
-                        + moe.grouped_counts(chosen[0], cfg.n_experts)
+                        + moe.grouped_counts(chosen[0], *counted)
                     ]
                 if latent:
                     rows = (fresh,)  # [L, R, P, C]
