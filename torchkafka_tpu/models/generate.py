@@ -45,6 +45,9 @@ from torchkafka_tpu.models.transformer import (
     _moe_mlp,
     _rms_norm,
     _rope,
+    embed_tokens,
+    head_product,
+    join_residual,
     param_specs,
     scan_periods,
     shardings_for_mesh,
@@ -375,7 +378,8 @@ def _attend_merged(x, q, slab_k, slab_v, valid, layer, cfg, scope):
 
 
 def _read_merged(q, slab_k, slab_v, valid, cfg):
-    """``_attend_merged``'s read → [B, 1, H, Dh]."""
+    """``_attend_merged``'s read → [B, 1, H, Dh]. The scores are over
+    ``sqrt(Dh)``, or times the config's ``attention_multiplier``."""
     b, _s, h, dh = q.shape
     n_kv = cfg.n_kv_heads
     own = jnp.arange(h)[:, None] // (h // n_kv) == jnp.arange(n_kv)[None, :]
@@ -385,7 +389,11 @@ def _read_merged(q, slab_k, slab_v, valid, cfg):
     scores = jnp.einsum(
         "bhc,bmc->bhm", q_wide, slab_k.astype(cfg.dtype),
         preferred_element_type=jnp.float32,
-    ) / jnp.sqrt(jnp.float32(dh))
+    )
+    if cfg.attention_multiplier:
+        scores = scores * jnp.float32(cfg.attention_multiplier)
+    else:
+        scores = scores / jnp.sqrt(jnp.float32(dh))
     probs = jax.nn.softmax(
         jnp.where(valid[:, None, :], scores, -1e30), axis=-1
     )
@@ -411,19 +419,19 @@ def _attn_tail_routing(x, attn, layer, cfg):
     """``_attn_tail`` and the expert choices it made: (x, routing [B, S,
     top_k] for a routed expert layer (ops/moe.py), else None)."""
     with tracing.scope(tracing.SCOPE_ATTN_PROJ):
-        x = x + jnp.einsum(
+        x = join_residual(x, jnp.einsum(
             "bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype)
-        )
-        h = _rms_norm(x, layer["ln2"])
+        ), cfg)
+        h = _rms_norm(x, layer["ln2"], cfg.norm_eps)
     if "router" not in layer:
-        return x + _dense_mlp(h, layer, cfg), None
+        return join_residual(x, _dense_mlp(h, layer, cfg), cfg), None
     if cfg.routed_moe:
         # The one routed expert layer, prefill's too (ops/moe.py): its
         # form follows the static row count, and no token is dropped.
         from torchkafka_tpu.ops.moe import routed_moe_mlp
 
         mlp_out, routing = routed_moe_mlp(h, layer, cfg)
-        return x + mlp_out, routing
+        return join_residual(x, mlp_out, cfg), routing
     # Decode always routes EXACTLY (dense dispatch) regardless of
     # cfg.moe_dispatch: capacity drops are a training
     # throughput/regularization tradeoff; at inference every token
@@ -437,7 +445,7 @@ def _attn_tail_routing(x, attn, layer, cfg):
 def _project_qkv(x, layer, cfg):
     """RMSNorm + q/k/v projections for decode queries. x: [B, S, D] —
     S=1 for a decode tick, S=k+1 for spec decode's multi-query verify."""
-    h = _rms_norm(x, layer["ln1"])
+    h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
     k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
     v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
@@ -448,11 +456,8 @@ def _project_qkv(x, layer, cfg):
 def head_logits(params, cfg, x, at: int):
     """The final norm and the head's product at position ``at`` of x
     [B, S, D] → float32 logits [B, V]."""
-    x = _rms_norm(x, params["ln_f"])
-    return jnp.einsum(
-        "bd,dv->bv", x[:, at], load_weight(params["lm_head"], cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+    return head_product(params, cfg, x[:, at])
 
 
 def _layer_step(x, layer, cache_k, cache_v, pos, cfg):
@@ -506,7 +511,7 @@ def prefill(
         from torchkafka_tpu.models.linear_attn import hybrid_forward
 
         with tracing.scope(tracing.SCOPE_EMBED):
-            x = embed_rows(params["embed"], tokens, cfg.dtype)
+            x = embed_tokens(params, cfg, tokens)
         x, kept, chosen = hybrid_forward(params, model, x)
         out = head_logits(params, cfg, x, -1), kept, chosen
         return out if routing else out[:2]

@@ -1,15 +1,23 @@
-"""Linear-attention (KDA) layers beside latent-attention layers: a hybrid
-model's layer of either kind, over a whole sequence and as one decode
-token a slot, and the forward that keeps what serving holds of it.
+"""The linear layers of a hybrid model beside its attention layers: a
+layer of either kind over a whole sequence and as one decode token a
+slot, and the forward that keeps what serving holds of it.
 
 ``TransformerConfig.linear_pattern`` says which layers of a period are
-linear (True) and which latent (False); the leading dense layers
-(``first_dense_layers``) are linear. A group's tensors are stacked BY
-KIND (``transformer.scan_hybrid``): the norms and the MLP's over every
-layer of the group, a linear layer's over the group's linear layers, a
-latent layer's (``models/mla.py``) over its latent layers.
+linear (True) and which are attention layers (False); the leading dense
+layers (``first_dense_layers``) are linear. Two properties of the config
+say what runs in them, each apart from the other: ``linear_kind`` the
+RECURRENCE of a linear layer ("kda", the delta rule with a decay a
+channel, ``ops/kda.py``; "ssd", the Mamba-2 mixer with a scalar decay a
+head, ``ops/ssd.py``), and ``kv_lora_rank`` the ATTENTION of the others
+(latent, ``models/mla.py``, over one pool of latent rows; or, at 0,
+grouped-query attention over pools of K and V rows, the layer the dense
+paths have: ``Transformer._gqa_qkv`` here, ``serve._slot_layer_step`` in
+a tick). A group's tensors are stacked BY KIND
+(``transformer.scan_hybrid``): the norms and the MLP's over every layer
+of the group, a linear layer's own over the group's linear layers, an
+attention layer's over its attention layers.
 
-A linear layer's tensors, with H heads of E = ``linear_head_dim``:
+A KDA layer's tensors, with H heads of E = ``linear_head_dim``:
 ``lqkv`` [D, 3 * H * E] (q, k and v before the convolution, side by
 side), ``lconv`` [taps, 3 * H * E] (a causal depthwise convolution over
 the last ``linear_conv`` tokens, its own taps a channel, then SiLU),
@@ -19,12 +27,22 @@ gate, ONE scalar a head), ``lnorm`` [E] (RMSNorm of a head's read-out) and
 ``lo`` [H, E, D]. A latent layer has ``wg`` [D, H] too: the same
 head-wise gate before ``wo`` (``attn_gate``).
 
+An SSD layer's, with H = ``ssd_heads`` heads of P = ``ssd_head_dim`` and a
+state of N = ``ssd_state_dim``: ``s_in`` [D, H * P + (H * P + 2 N)] (the
+gate z and, before the convolution, x beside the one group's B and C),
+``s_in_dt`` [D, H] (the step, with ``s_dt`` [H] under a softplus),
+``s_conv`` [taps, H * P + 2 N] with its bias ``s_conv_b``, ``s_alog`` [H]
+(the rate ``A = -exp(.)``), ``s_d`` [H] (the skip), ``s_norm`` [H * P]
+(RMSNorm of ``y * SiLU(z)`` over all the heads' channels) and ``s_out``
+[H, P, D].
+
 What a slot keeps of a linear layer is NOT indexed by position: the
-recurrent state ``[H, E, E]`` in float32 and the conv tail, the last
-``linear_conv - 1`` rows of ``lqkv``'s output (what the next token's
-convolution reads). The admission leaves both as they stand after the
-prompt window (the chunkwise form, ``ops/kda.py::kda_chunk``); a tick
-updates the state in place, one pass (``tk_kda_step``).
+recurrent state in float32 (``[H, E, E]``; ``[H, P, N]``) and the conv
+tail, the last ``linear_conv - 1`` rows of what the convolution reads
+(what the next token's reads). The admission leaves both as they stand
+after the prompt window (the chunked forms, ``kda_chunk``,
+``ssd_chunk``); a tick updates the state in place, one pass
+(``tk_kda_step``, ``tk_ssd_step``).
 """
 
 from __future__ import annotations
@@ -42,7 +60,7 @@ from torchkafka_tpu.models.transformer import (
     hybrid_groups,
     scan_hybrid,
 )
-from torchkafka_tpu.ops import kda
+from torchkafka_tpu.ops import kda, ssd
 from torchkafka_tpu.utils import tracing
 
 
@@ -50,6 +68,29 @@ def step_form() -> str:
     """How a tick passes over the state: the Pallas kernel on the TPU,
     ``jax.numpy`` elsewhere."""
     return "kernel" if jax.default_backend() == "tpu" else "xla"
+
+
+def slot_shapes(cfg: TransformerConfig):
+    """(a slot's state of one linear layer, its conv tail), by the
+    recurrence's kind. The Mamba-2 mixer's tail is its ``taps - 1`` rows
+    IN ONE ROW: as [taps - 1, C] the device pads the 3 rows to 4, and the
+    compiler, short of memory, then keeps the tails "compressed" between
+    their uses, a copy of every layer's tails there and back around each
+    layer of each tick (14 copies, 10 ms a tick as compiled for a
+    described v5e at 128 slots)."""
+    taps = cfg.linear_conv - 1
+    if cfg.linear_kind == "ssd":
+        return (
+            (cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_state_dim),
+            (taps * cfg.ssd_conv_dim,),
+        )
+    e = cfg.linear_head_dim
+    return (cfg.n_heads, e, e), (taps, 3 * cfg.n_heads * e)
+
+
+def prefill_chunk(cfg: TransformerConfig) -> int:
+    """Tokens a chunk of the admission's scan."""
+    return cfg.ssd_chunk if cfg.linear_kind == "ssd" else kda.CHUNK
 
 
 @tracing.scope(tracing.SCOPE_ATTN_PROJ)
@@ -100,10 +141,36 @@ def gate_heads(attn, h, layer, cfg: TransformerConfig):
 
 
 def attend_sequence(h, layer, cfg: TransformerConfig):
-    """A linear layer's attention over a whole sequence from an empty
-    state: normed h [B, S, D] → (the gated read-outs [B, S, H, E], the
-    state after the last token [B, H, E, E] float32, the conv tail [B,
-    taps - 1, 3 * H * E])."""
+    """A linear layer's mixer over a whole sequence from an empty state:
+    normed h [B, S, D] → (the read-outs [B, S, H, E] before the output
+    projection, ``out_projection``; the state after the last token,
+    float32; the conv tail as a slot keeps it, ``slot_shapes``)."""
+    if cfg.linear_kind == "ssd":
+        return _ssd_sequence(h, layer, cfg)
+    return _kda_sequence(h, layer, cfg)
+
+
+def out_projection(layer, cfg: TransformerConfig):
+    """A linear layer's output projection, [H, E, D]."""
+    return layer["s_out" if cfg.linear_kind == "ssd" else "lo"]
+
+
+def attend_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
+    """One decode token a slot: normed h [B, 1, D] against row ``row`` of
+    the stacked states [L, B, H, ., .] and conv tails (``slot_shapes``) →
+    (the read-out [B, 1, H, E], states, tails).
+    ``act`` [B] bool or None: a slot that is not active keeps its state
+    and its tail as they are (KDA: it decays nothing, g 0, and corrects
+    nothing, beta 0; SSD: it takes no step, dt 0; the kernel writes back
+    what it read)."""
+    if cfg.linear_kind == "ssd":
+        return _ssd_step(h, layer, cfg, states, tails, row, act)
+    return _kda_step(h, layer, cfg, states, tails, row, act)
+
+
+def _kda_sequence(h, layer, cfg: TransformerConfig):
+    """→ (the gated read-outs [B, S, H, E], the state after the last token
+    [B, H, E, E] float32, the conv tail [B, taps - 1, 3 * H * E])."""
     qkv, g, beta, out_gate = _project(h, layer, cfg)
     rows = jnp.pad(qkv, ((0, 0), (cfg.linear_conv - 1, 0), (0, 0)))
     q, k, v = _conv_qkv(rows, layer, cfg)
@@ -115,13 +182,7 @@ def attend_sequence(h, layer, cfg: TransformerConfig):
     )
 
 
-def attend_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
-    """One decode token a slot: normed h [B, 1, D] against row ``row`` of
-    the stacked states [L, B, H, E, E] and conv tails [L, B, taps - 1, 3
-    * H * E] → (the gated read-out [B, 1, H, E], states, tails). ``act``
-    [B] bool or None: a slot that is not active keeps its state and its
-    tail as they are (it decays nothing, g 0, and corrects nothing, beta
-    0: the kernel writes back what it read)."""
+def _kda_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
     qkv, g, beta, out_gate = _project(h, layer, cfg)
     tail = lax.dynamic_index_in_dim(tails, row, keepdims=False)
     rows = jnp.concatenate([tail, qkv.astype(tail.dtype)], axis=1)
@@ -139,18 +200,109 @@ def attend_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
     return _finish(o[:, None], out_gate, layer, cfg), states, tails
 
 
+# ------------------------------------------------------- the Mamba-2 mixer
+
+
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
+def _ssd_project(h, layer, cfg: TransformerConfig):
+    """Normed h [B, S, D] → (the gate z [B, S, H * P] and, before the
+    convolution, x beside B and C [B, S, H * P + 2 N], compute dtype; the
+    step dt [B, S, H] float32, after its softplus)."""
+    zx = jnp.einsum("bsd,dc->bsc", h, load_weight(layer["s_in"], cfg.dtype))
+    dt = jnp.einsum(
+        "bsd,dh->bsh", h, load_weight(layer["s_in_dt"], cfg.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    dt = jax.nn.softplus(dt + layer["s_dt"].astype(jnp.float32))
+    return zx[..., :cfg.ssd_inner], zx[..., cfg.ssd_inner:], dt
+
+
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
+def _ssd_conv(rows, layer, cfg: TransformerConfig):
+    """``rows`` [B, taps - 1 + S, H * P + 2 N], the tokens before the S in
+    front → x [B, S, H, P], B and C [B, S, N], float32."""
+    y = ssd.short_conv(rows, layer["s_conv"], layer["s_conv_b"])
+    return _ssd_split(y, cfg)
+
+
+def _ssd_split(y, cfg: TransformerConfig):
+    """The convolution's output [..., H * P + 2 N] → x [..., H, P], B and
+    C [..., N]."""
+    inner, n = cfg.ssd_inner, cfg.ssd_state_dim
+    x = y[..., :inner].reshape(*y.shape[:-1], cfg.ssd_heads, cfg.ssd_head_dim)
+    return x, y[..., inner:inner + n], y[..., inner + n:]
+
+
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
+def _ssd_finish(y, z, layer, cfg: TransformerConfig):
+    """The read-outs y [B, S, H, P] float32 times SiLU(z), RMS-normed over
+    ALL the heads' channels with the learned weight → compute dtype."""
+    y = y.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+    y = (y * layer["s_norm"].astype(jnp.float32)).astype(cfg.dtype)
+    return y.reshape(*y.shape[:2], cfg.ssd_heads, cfg.ssd_head_dim)
+
+
+def _ssd_rates(layer):
+    """(A [H] < 0, D [H]), float32."""
+    return (
+        -jnp.exp(layer["s_alog"].astype(jnp.float32)),
+        layer["s_d"].astype(jnp.float32),
+    )
+
+
+def _ssd_sequence(h, layer, cfg: TransformerConfig):
+    z, xbc, dt = _ssd_project(h, layer, cfg)
+    rows = jnp.pad(xbc, ((0, 0), (cfg.linear_conv - 1, 0), (0, 0)))
+    x, bm, cm = _ssd_conv(rows, layer, cfg)
+    a, d = _ssd_rates(layer)
+    with tracing.scope(tracing.SCOPE_ATTN_FLASH):
+        y, state = ssd.ssd_chunk(x, dt, a, bm, cm, d, chunk=cfg.ssd_chunk)
+    tail = rows[:, rows.shape[1] - (cfg.linear_conv - 1):]
+    return (
+        _ssd_finish(y, z, layer, cfg), state,
+        tail.reshape(tail.shape[0], -1),  # (``slot_shapes``)
+    )
+
+
+def _ssd_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
+    z, xbc, dt = _ssd_project(h, layer, cfg)
+    tail = lax.dynamic_index_in_dim(tails, row, keepdims=False)
+    with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+        y, fresh = ssd.conv_step(
+            tail, xbc[:, 0].astype(tail.dtype), layer["s_conv"],
+            layer["s_conv_b"],
+        )
+        x, bm, cm = _ssd_split(y, cfg)
+    dt = dt[:, 0]
+    if act is not None:
+        dt = jnp.where(act[:, None], dt, 0.0)
+        fresh = jnp.where(act[:, None], fresh, tail)
+    with tracing.scope(tracing.SCOPE_KV_WRITE):
+        tails = lax.dynamic_update_index_in_dim(tails, fresh, row, 0)
+    a, d = _ssd_rates(layer)
+    with tracing.scope(tracing.SCOPE_KV_READ):
+        step = ssd.ssd_step if step_form() == "kernel" else ssd.ssd_step_xla
+        y, states = step(states, row, x, dt, a, bm, cm, d)
+    return _ssd_finish(y[:, None], z, layer, cfg), states, tails
+
+
+# ------------------------------------------------------ a layer of either kind
+
+
 def layer_forward(model, x, layer, linear: bool):
     """One layer of either kind on a whole sequence [B, S, D] → (x, what
     a slot keeps of it, a tuple: ``(state, conv tail)`` of a linear
-    layer, ``(rows [B, S, rank + rope],)`` of a latent one; the routing
-    [B, S, top_k] or None)."""
+    layer, ``(rows [B, S, rank + rope],)`` of a latent one, ``(K rows, V
+    rows [B, S, K * Dh])`` of a grouped-query one; the routing [B, S,
+    top_k] or None)."""
     cfg = model.cfg
     with tracing.scope(tracing.SCOPE_ATTN_PROJ):
-        h = _rms_norm(x, layer["ln1"])
+        h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
     if linear:
         attn, state, tail = attend_sequence(h, layer, cfg)
-        kept, wo = (state, tail), layer["lo"]
-    else:
+        kept, wo = (state, tail), out_projection(layer, cfg)
+    elif cfg.is_mla:
         q_nope, q_rope, rows = mla.project(
             h, layer, cfg, model._seq_positions(x.shape[1])
         )
@@ -158,44 +310,57 @@ def layer_forward(model, x, layer, linear: bool):
             q_nope, q_rope, rows, layer, cfg, use_flash=model._use_flash
         )
         attn, kept, wo = gate_heads(attn, h, layer, cfg), (rows,), layer["wo"]
+    else:
+        # The grouped-query layer the dense paths run; a position's kv
+        # heads side by side in one row, as a pool by kind holds them.
+        q, k, v = model._gqa_qkv(
+            h, layer, model._seq_positions(x.shape[1]), cfg.rope_theta
+        )
+        attn, wo = model._gqa_attend(q, k, v), layer["wo"]
+        kept = tuple(a.reshape(*a.shape[:2], -1) for a in (k, v))
     x, routing = _attn_tail_routing(x, attn, {**layer, "wo": wo}, cfg)
     return x, kept, routing
 
 
 def slot_layer_step(x, layer, linear: bool, row, caches, pos_b, act, cfg):
-    """One decode token a slot through a layer of either kind. x [B, 1,
-    D]; ``caches`` = (states, conv tails, the latent pool [L, B, M, rank
-    + rope]), ``row`` the layer's row in its kind's tensors. Returns (x,
-    caches, routing [B, 1, top_k] | None)."""
-    states, tails, pool = caches
+    """One decode token a slot through a linear layer, or a latent one.
+    x [B, 1, D]; ``caches`` = (states, conv tails, the attention layers'
+    pools: the latent pool [L, B, M, rank + rope]), ``row`` the layer's
+    row in its kind's tensors. Returns (x, caches, routing [B, 1, top_k] |
+    None). A grouped-query layer's token goes through the dense path's
+    own step (``serve._slot_layer_step``)."""
+    states, tails, *pools = caches
     with tracing.scope(tracing.SCOPE_ATTN_PROJ):
-        h = _rms_norm(x, layer["ln1"])
+        h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
     if linear:
         attn, states, tails = attend_step(
             h, layer, cfg, states, tails, row, act
         )
-        wo = layer["lo"]
+        wo = out_projection(layer, cfg)
     else:
+        (pool,) = pools
         q_nope, q_rope, latent = mla.project(h, layer, cfg, pos_b[:, None])
         with tracing.scope(tracing.SCOPE_KV_WRITE):
             pool = pool.at[row, jnp.arange(pool.shape[1]), pos_b].set(
                 latent[:, 0].astype(pool.dtype)
             )
         attn = mla.attend_absorbed(q_nope, q_rope, pool, row, pos_b, layer, cfg)
-        attn, wo = gate_heads(attn, h, layer, cfg), layer["wo"]
+        attn, wo, pools = gate_heads(attn, h, layer, cfg), layer["wo"], (pool,)
     x, routing = _attn_tail_routing(x, attn, {**layer, "wo": wo}, cfg)
-    return x, (states, tails, pool), routing
+    return x, (states, tails, *pools), routing
 
 
 def hybrid_forward(params, model, x: jax.Array):
     """A hybrid config's layers over the embedded tokens x [B, S, D] →
     (the stream after the last layer, before the final norm; what a slot
-    keeps: the states [L_lin, B, H, E, E] float32, the conv tails [L_lin,
-    B, taps - 1, 3 * H * E] and the latent rows [L_lat, B, S, rank +
-    rope], each over its kind's layers in order; the expert layers'
-    routing [L_moe, B, S, top_k] or None)."""
+    keeps: the states [L_lin, B, H, ., .] float32, the conv tails
+    (``slot_shapes``) and the attention layers' rows, the latent rows
+    [L_att, B, S, rank + rope] or the K and the V rows [L_att, B, S, K *
+    Dh], each over its kind's layers in order; the expert layers' routing
+    [L_moe, B, S, top_k] or None)."""
     cfg = model.cfg
-    states, tails, latents, routing = [], [], [], []
+    kinds = {True: ([], []), False: ([],) if cfg.is_mla else ([], [])}
+    routing = []
     for key, pattern, _lin0, _lat0 in hybrid_groups(cfg):
         def step(x, layer, linear, _row):
             x, kept, chosen = layer_forward(model, x, layer, linear)
@@ -204,7 +369,7 @@ def hybrid_forward(params, model, x: jax.Array):
         x, ys = scan_hybrid(cfg, params[key], pattern, x, step)
         # A layer of the period: its kept tensors stacked over the
         # periods; the kind's layers in order are period-major.
-        for kind, into in ((True, (states, tails)), (False, (latents,))):
+        for kind, into in kinds.items():
             kept = [y[0] for y, lin in zip(ys, pattern) if lin == kind]
             for t, dest in enumerate(into if kept else ()):
                 stacked = jnp.stack([k[t] for k in kept], axis=1)
@@ -216,8 +381,8 @@ def hybrid_forward(params, model, x: jax.Array):
     def cat(parts):
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-    kept = (
-        cat(states), cat(tails).astype(cfg.dtype),
-        cat(latents).astype(cfg.dtype),
+    states, tails = kinds[True]
+    kept = (cat(states), cat(tails).astype(cfg.dtype)) + tuple(
+        cat(rows).astype(cfg.dtype) for rows in kinds[False]
     )
     return x, kept, cat(routing) if routing else None

@@ -254,6 +254,37 @@ class TransformerConfig:
     # A head-wise sigmoid gate on the attention's output before ``wo``
     # (one scalar a head, ``wg`` [D, H]); built beside ``linear_pattern``.
     attn_gate: bool = False
+    # WHICH recurrence a linear layer of a ``linear_pattern`` runs, apart
+    # from which attention the other layers run (latent where
+    # ``kv_lora_rank`` > 0, else grouped-query with K and V rows): "kda",
+    # the delta rule above, or "ssd", the Mamba-2 mixer (ops/ssd.py):
+    # ``ssd_heads`` heads of ``ssd_head_dim`` with a state of
+    # ``ssd_state_dim`` a head and ONE group of B and C, a scalar decay a
+    # head, a conv with a bias over ``linear_conv`` tokens on x, B and C, a
+    # gated RMSNorm over all the heads' channels, the admission's scan in
+    # chunks of ``ssd_chunk``.
+    linear_kind: str = "kda"
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_state_dim: int = 0
+    ssd_chunk: int = 256
+    # What a checkpoint of the ``linear_pattern`` families states beside
+    # its shapes (each at its default builds what was built before it):
+    # the embedding's rows times ``embedding_multiplier``; both branches of
+    # every layer times ``residual_multiplier`` before they join the
+    # stream; the attention's scores times ``attention_multiplier`` in
+    # place of ``1 / sqrt(head_dim)`` (0: the latter); the logits divided
+    # by ``logits_scaling``; ``use_rope`` False: no rotation in any layer
+    # (no positions at all: "nope"); ``tie_embeddings``: the head is the
+    # embedding's transpose and the tree has no ``lm_head``; ``norm_eps``
+    # under every RMSNorm's root.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
+    use_rope: bool = True
+    tie_embeddings: bool = False
+    norm_eps: float = 1e-6
     # Group-limited selection (DeepSeek-V3's): the router's outputs fall
     # into ``n_group`` groups of consecutive experts, a group scores the
     # sum of its two best biased scores, the ``topk_group`` best groups
@@ -351,6 +382,22 @@ class TransformerConfig:
     def moe_d_ff(self) -> int:
         return self.expert_d_ff or self.d_ff
 
+    @property
+    def ssd_inner(self) -> int:
+        """The Mamba-2 mixer's channels: every head's."""
+        return self.ssd_heads * self.ssd_head_dim
+
+    @property
+    def ssd_conv_dim(self) -> int:
+        """What the mixer's convolution runs over, and its tail keeps: x
+        beside the one group's B and C."""
+        return self.ssd_inner + 2 * self.ssd_state_dim
+
+    @property
+    def attn_scale(self) -> float:
+        """What multiplies a grouped-query layer's scores."""
+        return self.attention_multiplier or 1.0 / math.sqrt(self.head_dim)
+
     def __post_init__(self) -> None:
         if self.d_model % self.n_heads:
             raise ValueError("d_model must divide by n_heads")
@@ -385,12 +432,16 @@ class TransformerConfig:
         gqa_routed = (
             # The routed layer beside grouped-query attention, as built:
             # the renormalised softmax top-k, no selection bias, every
-            # layer an expert layer holding every expert.
+            # layer an expert layer; holding every expert and no shared
+            # one, or in a ``linear_pattern`` model (whose groups are
+            # stacked by ``_hybrid_shapes``) a share beside a shared one.
             self.routed_moe and not self.is_mla
             and self.router_score == "softmax" and self.norm_topk
-            and not self.first_dense_layers and not self.n_shared_experts
-            and not self.zero_experts and self.experts_held is None
+            and not self.first_dense_layers and not self.zero_experts
             and self.routed_scaling == 1.0
+            and (bool(self.linear_pattern) or (
+                not self.n_shared_experts and self.experts_held is None
+            ))
         )
         if self.is_moe and self.routed_moe != self.is_mla and not gqa_routed:
             raise ValueError(
@@ -401,8 +452,9 @@ class TransformerConfig:
                 "layers' experts are the routed ones (beside grouped-query "
                 "attention the routed layer is built for experts of a "
                 "stated width, expert_d_ff, under the renormalised softmax "
-                "top-k alone: norm_topk=True, routed_scaling 1, no shared, "
-                "zero or absent experts, no leading dense layer)"
+                "top-k alone: norm_topk=True, routed_scaling 1, no zero "
+                "experts, no leading dense layer; a shared expert and a "
+                "held share, experts_held, in a linear_pattern model alone)"
             )
         if self.is_mla and self.is_moe and (
             self.router_score == "softmax" and self.norm_topk
@@ -515,25 +567,89 @@ class TransformerConfig:
                 "a model of full layers alone)"
             )
         hybrid = self.linear_pattern
-        if hybrid and not (
-            self.is_mla and not self.q_lora_rank and self.attn_blocks == 1
-            and not pattern and any(hybrid) and not all(hybrid)
-            and (self.n_layers - self.first_dense_layers) % len(hybrid) == 0
-            and self.linear_head_dim > 0 and self.linear_conv >= 2
-            and self.linear_lower_bound < 0
+        if hybrid and self.linear_kind == "kda" and not (
+            self.is_mla and not self.q_lora_rank
+            and self.linear_head_dim > 0 and self.linear_lower_bound < 0
         ):
             raise ValueError(
-                f"linear_pattern={hybrid} is built beside latent attention "
-                "(kv_lora_rank > 0, no q_lora_rank, attn_blocks 1, no "
-                "window_pattern): a period of linear AND latent layers "
-                "that divides the layers after first_dense_layers, "
-                "linear_head_dim > 0, linear_conv >= 2 and a negative "
+                "linear_kind='kda' (the delta rule with a decay a channel) "
+                "is built beside latent attention (kv_lora_rank > 0, no "
+                "q_lora_rank) with linear_head_dim > 0 and a negative "
                 "linear_lower_bound"
+            )
+        if hybrid and self.linear_kind == "ssd" and not (
+            not self.is_mla and not self.attn_gate
+            and min(self.ssd_heads, self.ssd_head_dim, self.ssd_state_dim,
+                    self.ssd_chunk) > 0
+        ):
+            raise ValueError(
+                "linear_kind='ssd' (the Mamba-2 mixer) is built beside "
+                "grouped-query attention over K and V rows (no "
+                "kv_lora_rank, no attn_gate) with ssd_heads, ssd_head_dim, "
+                "ssd_state_dim and ssd_chunk > 0"
+            )
+        if hybrid and not (
+            self.attn_blocks == 1 and not pattern
+            and any(hybrid) and not all(hybrid)
+            and (self.n_layers - self.first_dense_layers) % len(hybrid) == 0
+            and self.linear_conv >= 2 and self.linear_kind in ("kda", "ssd")
+        ):
+            raise ValueError(
+                f"linear_pattern={hybrid} (linear_kind={self.linear_kind!r}) "
+                "is a period of linear AND attention layers that divides "
+                "the layers after first_dense_layers, linear_conv >= 2, no "
+                "attn_blocks 2 and no window_pattern; the linear layers "
+                "run linear_kind 'kda' (the delta rule) or 'ssd' (the "
+                "Mamba-2 mixer), the others latent attention where "
+                "kv_lora_rank > 0 and grouped-query attention over K and "
+                "V rows otherwise"
+            )
+        if not (hybrid and self.linear_kind == "ssd") and (
+            self.linear_kind != "kda" or self.ssd_heads
+            or self.ssd_head_dim or self.ssd_state_dim
+        ):
+            raise ValueError(
+                "linear_kind, ssd_heads, ssd_head_dim and ssd_state_dim "
+                "describe the linear layers of a linear_pattern: set one "
+                "with linear_kind='ssd'"
             )
         if self.attn_gate and not hybrid:
             raise ValueError(
                 "attn_gate (the head-wise output gate) is built for the "
                 "layers of a linear_pattern alone"
+            )
+        stated = {
+            "embedding_multiplier": self.embedding_multiplier != 1.0,
+            "residual_multiplier": self.residual_multiplier != 1.0,
+            "attention_multiplier": self.attention_multiplier != 0.0,
+            "logits_scaling": self.logits_scaling != 1.0,
+            "use_rope=False": not self.use_rope,
+            "tie_embeddings": self.tie_embeddings,
+            "norm_eps": self.norm_eps != 1e-6,
+        }
+        if any(stated.values()) and not hybrid:
+            raise ValueError(
+                f"{', '.join(n for n, on in stated.items() if on)}: the "
+                "multipliers, attention without positions, the tied head "
+                "and a stated norm_eps are built into the layers and the "
+                "head of a linear_pattern model alone (they serve on one "
+                "device through StreamingGenerator and run Transformer's "
+                "forward); the dense, windowed and latent paths, training, "
+                "generate(), pages, speculation and quantize_params have "
+                "not been taught them"
+            )
+        if self.is_mla and (not self.use_rope or self.attention_multiplier):
+            raise ValueError(
+                "use_rope=False (no positions) and attention_multiplier "
+                "are built for grouped-query layers: latent attention "
+                "caches a roped key beside its latent and scales by its "
+                "own head width"
+            )
+        if (self.attention_multiplier < 0 or self.logits_scaling <= 0
+                or self.norm_eps <= 0):
+            raise ValueError(
+                "attention_multiplier must be >= 0 (0: 1 / sqrt(head_dim)), "
+                "logits_scaling and norm_eps positive"
             )
         if (self.n_group, self.topk_group) != (1, 1) and not (
             self.routed_moe and not self.zero_experts
@@ -634,14 +750,21 @@ _EXPERT_TENSORS = ("we_gate", "we_up", "we_down")
 
 
 def _arch_shapes(cfg: TransformerConfig, expert_mlp: bool) -> dict:
-    """One layer's tensors of a latent-attention config: name -> (shape,
+    """One layer's tensors of a latent-attention config, or of the
+    grouped-query layer of a ``linear_pattern`` model: name -> (shape,
     fan_in or None for a norm's scale or the selection bias). In the
     double layer (``attn_blocks`` 2) the attention's and the dense
     SwiGLU's tensors lead with the block axis, and the held experts have
     names of their own (``we_*``)."""
     dm, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
     shapes = {"ln1": ((dm,), None), "ln2": ((dm,), None)}
-    if cfg.q_lora_rank:
+    if not cfg.is_mla:
+        k, dh = cfg.n_kv_heads, cfg.head_dim
+        shapes.update(
+            wq=((dm, h, dh), dm), wk=((dm, k, dh), dm), wv=((dm, k, dh), dm),
+            wo=((h, dh, dm), h * dh),
+        )
+    elif cfg.q_lora_rank:
         q = cfg.q_lora_rank
         shapes.update(
             wqa=((dm, q), dm), q_norm=((q,), None),
@@ -649,19 +772,22 @@ def _arch_shapes(cfg: TransformerConfig, expert_mlp: bool) -> dict:
         )
     else:
         shapes["wq"] = ((dm, h, cfg.qk_head_dim), dm)
-    shapes.update({
-        # One projection gives the latent and the shared roped key.
-        "wkva": ((dm, cfg.latent_dim), dm),
-        "kv_norm": ((r,), None),
-        # Up-projection of the latent: a head's k_nope beside its v.
-        "wkvb": ((r, h, cfg.qk_nope_dim + cfg.v_head_dim), r),
-        "wo": ((h, cfg.v_head_dim, dm), h * cfg.v_head_dim),
-    })
+    if cfg.is_mla:
+        shapes.update({
+            # One projection gives the latent and the shared roped key.
+            "wkva": ((dm, cfg.latent_dim), dm),
+            "kv_norm": ((r,), None),
+            # Up-projection of the latent: a head's k_nope beside its v.
+            "wkvb": ((r, h, cfg.qk_nope_dim + cfg.v_head_dim), r),
+            "wo": ((h, cfg.v_head_dim, dm), h * cfg.v_head_dim),
+        })
     double = cfg.attn_blocks == 2
     lead, f = (), cfg.d_ff
     if expert_mlp:
         e, w = cfg.held_experts[1], cfg.router_width
-        shapes.update(router=((dm, w), dm), router_bias=((w,), None))
+        shapes["router"] = ((dm, w), dm)
+        if cfg.is_mla:  # the latent families state a selection bias
+            shapes["router_bias"] = ((w,), None)
         fs = cfg.n_shared_experts * cfg.moe_d_ff
         if fs:  # the shared experts, one SwiGLU
             shapes.update(
@@ -689,18 +815,46 @@ def _arch_shapes(cfg: TransformerConfig, expert_mlp: bool) -> dict:
 
 
 # A hybrid model's tensors by kind (``linear_pattern``): what a linear
-# layer has of its own, what a latent layer has; the rest (the norms, the
-# MLP's) every layer has.
-_LINEAR_TENSORS = (
+# layer has of its own by its recurrence, what an attention layer has by
+# its attention; the rest (the norms, the MLP's) every layer has.
+_KDA_TENSORS = (
     "lqkv", "lconv", "lf", "l_alog", "l_dt", "lb", "lg", "lnorm", "lo",
 )
+_SSD_TENSORS = (
+    "s_in", "s_in_dt", "s_conv", "s_conv_b", "s_dt", "s_alog", "s_d",
+    "s_norm", "s_out",
+)
 _LATENT_TENSORS = ("wq", "wkva", "kv_norm", "wkvb", "wo", "wg")
+_GQA_TENSORS = ("wq", "wk", "wv", "wo")
+
+
+def hybrid_tensors(cfg: TransformerConfig) -> dict[bool, tuple[str, ...]]:
+    """``{True: a linear layer's own tensors, False: an attention
+    layer's}`` of a ``linear_pattern`` model."""
+    return {
+        True: _SSD_TENSORS if cfg.linear_kind == "ssd" else _KDA_TENSORS,
+        False: _LATENT_TENSORS if cfg.is_mla else _GQA_TENSORS,
+    }
 
 
 def _linear_shapes(cfg: TransformerConfig) -> dict:
     """One linear layer's own tensors (``models/linear_attn.py``): name
     -> (shape, fan_in or None)."""
-    dm, h, e = cfg.d_model, cfg.n_heads, cfg.linear_head_dim
+    dm = cfg.d_model
+    if cfg.linear_kind == "ssd":
+        h, inner, conv = cfg.ssd_heads, cfg.ssd_inner, cfg.ssd_conv_dim
+        return {
+            # [z | x B C] side by side; the step apart, read in float32.
+            "s_in": ((dm, inner + conv), dm), "s_in_dt": ((dm, h), dm),
+            "s_conv": ((cfg.linear_conv, conv), cfg.linear_conv),
+            "s_conv_b": ((conv,), None), "s_dt": ((h,), None),
+            "s_alog": ((h,), None), "s_d": ((h,), None),
+            "s_norm": ((inner,), None),
+            # (a head's rows apart, as ``lo`` and ``wo``: reshaped inside
+            # the program the matrix is copied every tick)
+            "s_out": ((h, cfg.ssd_head_dim, dm), inner),
+        }
+    h, e = cfg.n_heads, cfg.linear_head_dim
     return {
         "lqkv": ((dm, 3 * h * e), dm),
         "lconv": ((cfg.linear_conv, 3 * h * e), cfg.linear_conv),
@@ -716,17 +870,19 @@ def _hybrid_shapes(cfg: TransformerConfig, nl: int, expert_mlp: bool) -> dict:
     kind's own tensors with the group's layers of the kind (the leading
     dense layers are all linear)."""
     shapes = _arch_shapes(cfg, expert_mlp)
-    latent = {n: v for n, v in shapes.items() if n in _LATENT_TENSORS}
+    attn = {
+        n: v for n, v in shapes.items() if n in hybrid_tensors(cfg)[False]
+    }
     if cfg.attn_gate:
-        latent["wg"] = ((cfg.d_model, cfg.n_heads), cfg.d_model)
-    common = {n: v for n, v in shapes.items() if n not in latent}
+        attn["wg"] = ((cfg.d_model, cfg.n_heads), cfg.d_model)
+    common = {n: v for n, v in shapes.items() if n not in attn}
     lead = cfg.first_dense_layers and not expert_mlp
     pattern = (True,) if lead else cfg.linear_pattern
     n_lin = nl // len(pattern) * sum(pattern)
     return {
         n: ((count, *shape), fan)
         for count, part in (
-            (nl, common), (n_lin, _linear_shapes(cfg)), (nl - n_lin, latent),
+            (nl, common), (n_lin, _linear_shapes(cfg)), (nl - n_lin, attn),
         ) if count
         for n, (shape, fan) in part.items()
     }
@@ -754,7 +910,7 @@ def scan_hybrid(cfg, group, pattern, carry, step, lin0=0, lat0=0):
     p = len(pattern)
     per = {True: sum(pattern), False: p - sum(pattern)}
     experts, rest = _expert_stacks(cfg, group)
-    own = {True: _LINEAR_TENSORS, False: _LATENT_TENSORS}
+    own = hybrid_tensors(cfg)
 
     def period(carry, i):
         ys = []
@@ -808,11 +964,14 @@ def _arch_init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             name: draw(k, shape, fan_in)
             for k, (name, (shape, fan_in)) in zip(keys, shapes.items())
         }
-        for name in ("l_alog", "l_dt"):  # a rate of one, no shift
+        # A rate of one and no shift; the mixer's, and no conv bias.
+        for name in ("l_alog", "l_dt", "s_alog", "s_dt", "s_conv_b"):
             if name in out[key]:
                 out[key][name] = jnp.zeros_like(out[key][name])
         if "router_bias" in out[key]:
             out[key]["router_bias"] = jnp.zeros((nl, cfg.router_width), pd)
+    if cfg.tie_embeddings:  # the head is the embedding's transpose
+        del out["lm_head"]
     return out
 
 
@@ -838,7 +997,7 @@ def shardings_for_mesh(mesh: Mesh, specs: Any) -> Any:
 
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     """Scaled-normal init, stacked [L, ...] per layer tensor."""
-    if cfg.is_mla:
+    if cfg.is_mla or cfg.linear_pattern:
         return _arch_init_params(rng, cfg)
     keys = jax.random.split(rng, 10)
     dm, dff, nl = cfg.d_model, cfg.d_ff, cfg.n_layers
@@ -881,10 +1040,45 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
 # -------------------------------------------------------------------- forward
 
 
-def _rms_norm(x: jax.Array, scale: jax.Array) -> jax.Array:
+def _rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     xf = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
+    rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def embed_tokens(params, cfg: "TransformerConfig", tokens: jax.Array):
+    """The embedding's rows of ``tokens`` in the compute dtype, times the
+    config's ``embedding_multiplier``."""
+    x = embed_rows(params["embed"], tokens, cfg.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def join_residual(x, branch, cfg: "TransformerConfig"):
+    """``x`` plus a layer's branch, times ``residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
+    return x + branch
+
+
+def head_product(params, cfg: "TransformerConfig", x: jax.Array) -> jax.Array:
+    """Final-normed x [..., D] → float32 logits [..., V]: against
+    ``lm_head``, or the embedding's transpose where the head is tied,
+    over the config's ``logits_scaling``."""
+    if cfg.tie_embeddings:
+        logits = jnp.einsum(
+            "...d,vd->...v", x, load_weight(params["embed"], cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+    else:
+        logits = jnp.einsum(
+            "...d,dv->...v", x, load_weight(params["lm_head"], cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.float32(cfg.logits_scaling)
+    return logits
 
 
 def router_aux(stats: jax.Array, n_tokens: int | jax.Array) -> jax.Array:
@@ -1226,15 +1420,22 @@ def _arch_refusal(cfg: "TransformerConfig", what: str) -> str | None:
     kinds of layer or the routed layer beside grouped-query attention
     (None: it does, the config is neither)."""
     if cfg.linear_pattern:
+        layers = (
+            "state-space layers (linear_pattern, linear_kind='ssd': the "
+            "Mamba-2 mixer)" if cfg.linear_kind == "ssd"
+            else "linear-attention layers (linear_pattern)"
+        )
         return (
-            f"{what} is not built for a config with linear-attention "
-            "layers (linear_pattern): what a slot keeps of such a layer is "
-            "a recurrent state and a conv tail that no position indexes, "
-            "so nothing that rebuilds, shares, pages, quantises, shards "
-            "or differentiates a cache of rows by position can hold it. "
-            "These configs serve on one device through StreamingGenerator's "
-            "slot memory by kind (a float32 state and a conv tail a linear "
-            "layer, a compute-dtype latent pool a latent layer) and run "
+            f"{what} is not built for a config with {layers}: what a slot "
+            "keeps of such a layer is a recurrent state and a conv tail "
+            "that no position indexes, so nothing that rebuilds, shares, "
+            "pages, quantises, shards or differentiates a cache of rows by "
+            "position can hold it (nor has any of them been taught the "
+            "multipliers, the attention without positions or the tied head "
+            "such a config may state). These configs serve on one device "
+            "through StreamingGenerator's slot memory by kind (a float32 "
+            "state and a conv tail a linear layer; a compute-dtype pool "
+            "the attention layers, latent rows or K and V rows) and run "
             "Transformer's forward"
         )
     if cfg.window_pattern or (cfg.routed_moe and not cfg.is_mla):
@@ -1323,21 +1524,23 @@ class Transformer:
         return init_params(rng, self.cfg)
 
     @tracing.scope(tracing.SCOPE_ATTN_FLASH)
-    def _attention(self, q, k, v, window=None):
-        if window is not None:
-            # A sliding-window layer (never under a mesh or a sequence-
-            # parallel impl: the config and ``__init__`` refused). Forward
-            # only: the windowed kernel has no backward.
+    def _attention(self, q, k, v, window=None, scale=None):
+        if window is not None or scale is not None:
+            # A sliding-window layer, or scores under a stated multiplier
+            # (never under a mesh or a sequence-parallel impl: the config
+            # and ``__init__`` refused). Forward only: the kernel's
+            # backward takes no window and has its own scale.
             from torchkafka_tpu.ops.flash import _repeat_kv, flash_forward
 
             if self._use_flash:
                 out = flash_forward(
-                    q, k, v, scale=1.0 / math.sqrt(q.shape[-1]), window=window
+                    q, k, v, scale=scale or 1.0 / math.sqrt(q.shape[-1]),
+                    window=window,
                 )
                 if out is not None:
                     return out  # else S does not tile: the dense form
             k, v = _repeat_kv(q, k, v)
-            return mha(q, k, v, causal=True, window=window)
+            return mha(q, k, v, causal=True, window=window, scale=scale)
         if self._use_ulysses:
             return ulysses_attention(
                 q, k, v, mesh=self.mesh, axis_name="sp", causal=True,
@@ -1452,15 +1655,29 @@ class Transformer:
             x = x + mlp_out
         return x, stats, (latent, routing)
 
-    def _gqa(self, h, layer, positions, kind=None):
+    def _gqa_qkv(self, h, layer, positions, rope):
+        """The grouped-query projections of normed h [B, S, D]: q [B, S,
+        H, Dh], k and v [B, S, K, Dh], q and k rotated unless the config
+        has no positions."""
         cfg = self.cfg
-        window, rope = kind or (None, cfg.rope_theta)
         with tracing.scope(tracing.SCOPE_ATTN_PROJ):
             q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
             k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
             v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
-            q = _rope(q, positions, rope)
-            k = _rope(k, positions, rope)
+            if cfg.use_rope:
+                q = _rope(q, positions, rope)
+                k = _rope(k, positions, rope)
+        return q, k, v
+
+    def _gqa(self, h, layer, positions, kind=None):
+        window, rope = kind or (None, self.cfg.rope_theta)
+        q, k, v = self._gqa_qkv(h, layer, positions, rope)
+        return self._gqa_attend(q, k, v, window)
+
+    def _gqa_attend(self, q, k, v, window=None):
+        cfg = self.cfg
+        if cfg.attention_multiplier:
+            return self._attention(q, k, v, window, cfg.attn_scale)
         if window is not None:
             return self._attention(q, k, v, window)
         if cfg.n_kv_heads != cfg.n_heads and not (
@@ -1484,7 +1701,7 @@ class Transformer:
         blocked CE without ever materialising [B, S, V] logits."""
         cfg = self.cfg
         with tracing.scope(tracing.SCOPE_EMBED):
-            x = embed_rows(params["embed"], tokens, cfg.dtype)
+            x = embed_tokens(params, cfg, tokens)
         n_tokens = tokens.shape[0] * tokens.shape[1]
 
         if self.mesh is not None and self.mesh.shape.get("pp", 1) > 1:
@@ -1550,7 +1767,10 @@ class Transformer:
 
                 x, _kept, _routing = hybrid_forward(params, self, x)
                 with tracing.scope(tracing.SCOPE_HEAD):
-                    return _rms_norm(x, params["ln_f"]), jnp.float32(0.0)
+                    return (
+                        _rms_norm(x, params["ln_f"], cfg.norm_eps),
+                        jnp.float32(0.0),
+                    )
             for key, nl, _expert_mlp in _layer_groups(cfg):
                 if cfg.window_pattern:
                     # Kinds of layer: a scan over periods (forward only,
@@ -1585,10 +1805,7 @@ class Transformer:
         ``return_aux``, the mean per-layer router load-balance loss)."""
         x, aux = self.trunk(params, tokens)
         with tracing.scope(tracing.SCOPE_HEAD):
-            logits = jnp.einsum(
-                "bsd,dv->bsv", x, load_weight(params["lm_head"], self.cfg.dtype),
-                preferred_element_type=jnp.float32,
-            )
+            logits = head_product(params, self.cfg, x)
         if return_aux:
             return logits, aux
         return logits

@@ -53,6 +53,7 @@ def mha(
     q_offset: int | jax.Array = 0,
     k_offset: int | jax.Array = 0,
     window: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Dense multi-head attention.
 
@@ -62,12 +63,13 @@ def mha(
     each block — this is what lets the same kernel serve both the single-chip
     path (offsets 0) and one block step of ring attention (shard offsets).
     ``window`` (with ``causal``): a sliding-window layer, a query at i sees
-    the keys j with ``i - window < j <= i``.
+    the keys j with ``i - window < j <= i``. ``scale`` multiplies the
+    scores (None: ``1 / sqrt(D)``).
     """
     dim = q.shape[-1]
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * (1.0 / math.sqrt(dim))
+    ) * (1.0 / math.sqrt(dim) if scale is None else scale)
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = k_offset + jnp.arange(k.shape[1])

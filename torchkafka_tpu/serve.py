@@ -95,6 +95,7 @@ from torchkafka_tpu.models.transformer import (
     _layer_groups,
     _rms_norm,
     _rope,
+    embed_tokens,
     hybrid_groups,
     scan_hybrid,
     scan_periods,
@@ -345,11 +346,12 @@ class ServeMetrics:
         # Group-limited selection (``n_group``, ``topk_group``; 1 and 1
         # where the router has no groups).
         self.expert_groups: dict = {"n_group": 1, "topk_group": 1}
-        # The linear-attention layers' slot memory (a config with
-        # ``linear_pattern``; empty otherwise): the layers, the bytes of
-        # the recurrent state and of the conv tails, the state's dtype,
-        # what a tick passes over the state by ("kernel": tk_kda_step;
-        # "xla") and the admission's form ("chunked").
+        # The linear layers' slot memory (a config with
+        # ``linear_pattern``; empty otherwise): the recurrence's kind
+        # ("kda", "ssd"), the layers, the bytes of the recurrent state and
+        # of the conv tails, the state's dtype, what a tick passes over
+        # the state by ("kernel": tk_kda_step, tk_ssd_step; "xla"), the
+        # admission's form ("chunked") and its chunk's tokens.
         self.linear_state: dict = {}
         self.attn_blocks = 1  # attention blocks (cache rows) a layer
         self.latent_positions_valid = RateMeter()  # cached rows the served
@@ -794,8 +796,9 @@ def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg, kind=None):
     choices [B, 1, top_k] or None)."""
     window, rope = kind or (None, cfg.rope_theta)
     q, k, v = _project_qkv(x, layer, cfg)
-    q = _rope(q, pos_b[:, None], rope)
-    k = _rope(k, pos_b[:, None], rope)
+    if cfg.use_rope:  # (a config without positions rotates nothing)
+        q = _rope(q, pos_b[:, None], rope)
+        k = _rope(k, pos_b[:, None], rope)
     # Per-row cache write as a SCATTER (.at[l, rows, pos].set), not a masked
     # select: the select rewrites the whole pool every layer while the
     # scatter writes one row per slot. The scatter goes into the stacked
@@ -1586,10 +1589,13 @@ class StreamingGenerator:
         # machinery over ONE cache tensor, [L, B, M, rank + rope] in the
         # compute dtype; kvcache.resolve_kv_backend refuses every other
         # combination with its reason.
-        # Linear-attention layers beside latent ones (``linear_pattern``,
+        # Linear layers beside attention layers (``linear_pattern``,
         # models/linear_attn.py): slot memory by kind, a recurrent state
-        # [L_lin, B, H, E, E] in float32 and a conv tail [L_lin, B, taps -
-        # 1, 3 * H * E] the linear layers, the latent pool the others.
+        # in float32 ([L_lin, B, H, E, E] the delta rule's, [L_lin, B, H,
+        # P, N] the Mamba-2 mixer's) and a conv tail the linear layers
+        # (``linear_attn.slot_shapes``); the others the latent pool, or K and V
+        # rows a position [L_att, B, M, K * Dh] (a pool by kind's full
+        # layers, written and read by the dense path's own step).
         hybrid = bool(cfg.linear_pattern)
         latent = cfg.is_mla and not hybrid
         # Kinds of layer (``window_pattern``): the same machinery over a
@@ -1673,6 +1679,19 @@ class StreamingGenerator:
         # A tick's B tokens, by the same rule.
         self.metrics.tick_form = moe.expert_form(cfg, B)
 
+        # The state-space state [H, P, N] leaves the chunked scan's
+        # product laid out otherwise than the pool (P before H), and the
+        # compiler lays the POOL out again to take it: a copy of the whole
+        # state in and out of every admission (4.5 GiB at 128 slots: the
+        # program no longer fits the chip). Through a view with a slot's
+        # axes merged only one layout makes the view free, and the rows
+        # are laid out again instead. The K and V rows likewise (they
+        # leave the head-wise projections with the positions minor): each
+        # pool is still copied in and out of an admission, but through
+        # the view in a GiB less of temporaries (the figures compiled for
+        # a described v5e are in the benchmark's configuration file).
+        merged_put = hybrid and cfg.linear_kind == "ssd"
+
         def admit(params, caches, last_tok, pos, gen, prompts, admit_mask,
                   keys):
             """Prefill the admitted rows of the [B, P] prompt batch, R rows
@@ -1696,13 +1715,24 @@ class StreamingGenerator:
             @xprof.scope(xprof.SCOPE_KV_WRITE)
             def put(pool, rows, slots):
                 # rows [L, R, ...] over the window, in the pool's layout.
+                shape = pool.shape
+                if merged_put:
+                    # Through a view of the pool with a slot's axes merged
+                    # (a slot's window is the leading run of its numbers):
+                    # all of them, or for the state [H, P, N] all but the
+                    # minor one, whose tiles then lie where they lay (the
+                    # view is free; merged too, the view itself is a copy
+                    # of the state).
+                    keep = shape[-1:] if pool.ndim > 4 else ()
+                    pool = pool.reshape(*shape[:2], -1, *keep)
+                    rows = rows.reshape(*rows.shape[:2], -1, *keep)
                 tail = (0,) * (pool.ndim - 2)
                 for r in range(R):
                     pool = lax.dynamic_update_slice(
                         pool, rows[:, r:r + 1].astype(pool.dtype),
                         (0, slots[r], *tail),
                     )
-                return pool
+                return pool.reshape(shape)
 
             def chunk(i, state):
                 caches, last_tok, pos, gen, *counts = state
@@ -1767,7 +1797,7 @@ class StreamingGenerator:
                 caches, last_tok, pos, gen, done_latch, n_out, stats = carry
                 act = active_in & ~done_latch
                 with xprof.scope(xprof.SCOPE_EMBED):
-                    x = embed_rows(params["embed"], last_tok, cfg.dtype)[:, None, :]
+                    x = embed_tokens(params, cfg, last_tok)[:, None, :]
 
                 # The pool rides the layer loop as its CARRY, as it rides
                 # the tick loop: each layer writes its rows into the
@@ -1815,12 +1845,21 @@ class StreamingGenerator:
                     return (x, caches, stats), None
 
                 def hybrid_body(carry, layer, linear, row):
-                    # A linear or a latent layer: its row in its kind's
-                    # tensors; a slot that is not active keeps its state.
+                    # A linear or an attention layer: its row in its
+                    # kind's tensors; a slot that is not active keeps its
+                    # state. The grouped-query layer is the one the dense
+                    # path steps, over K and V rows as a pool by kind's.
                     x, caches, stats = carry
-                    x, caches, routing = linear_attn.slot_layer_step(
-                        x, layer, linear, row, caches, pos, act, cfg
-                    )
+                    if linear or cfg.is_mla:
+                        x, caches, routing = linear_attn.slot_layer_step(
+                            x, layer, linear, row, caches, pos, act, cfg
+                        )
+                    else:
+                        x, ck, cv, routing = _slot_layer_step(
+                            x, layer, *caches[2:], row, pos, cfg,
+                            (None, cfg.rope_theta),
+                        )
+                        caches = (*caches[:2], ck, cv)
                     if routing is not None:
                         stats = _count_routing(stats, routing, act, cfg)
                     return (x, caches, stats), None
@@ -1973,21 +2012,31 @@ class StreamingGenerator:
             _resume = jax.jit(resume_admit, donate_argnums=(1,))
             self._resume_exec = lambda *a: _resume(self._params, *a)
         if hybrid:
-            n_lin, e = cfg.hybrid_layers(True), cfg.linear_head_dim
+            n_lin, n_att = cfg.hybrid_layers(True), cfg.cache_layers
+            state, conv = linear_attn.slot_shapes(cfg)
+            pools = (
+                ((n_att, B, M, cfg.latent_dim),) if cfg.is_mla
+                else ((n_att, B, M, kh * dh),) * 2
+            )
             self._caches = (
-                jnp.zeros((n_lin, B, cfg.n_heads, e, e), jnp.float32),
-                jnp.zeros(
-                    (n_lin, B, cfg.linear_conv - 1, 3 * cfg.n_heads * e),
-                    cfg.dtype,
-                ),
-                jnp.zeros((cfg.cache_layers, B, M, cfg.latent_dim), cfg.dtype),
+                jnp.zeros((n_lin, B, *state), jnp.float32),
+                jnp.zeros((n_lin, B, *conv), cfg.dtype),
+                *(jnp.zeros(shape, cfg.dtype) for shape in pools),
             )
             self.metrics.linear_state = {
+                "kind": cfg.linear_kind,
                 "layers": n_lin, "bytes_state": self._caches[0].nbytes,
                 "bytes_conv": self._caches[1].nbytes,
                 "state_dtype": "float32", "step": linear_attn.step_form(),
                 "prefill": "chunked",
+                "chunk": linear_attn.prefill_chunk(cfg),
             }
+            if not cfg.is_mla:
+                self.metrics.kv_pool_static = {
+                    "full_layers": n_att,
+                    "bytes_full": sum(c.nbytes for c in self._caches[2:]),
+                    "read": "xla",
+                }
         elif latent:
             # A row an attention block: [2L, ...] for the double layer.
             self._caches = (
@@ -3625,7 +3674,9 @@ class StreamingGenerator:
         data axis (its [1, S] resume prefill has no batch to shard —
         tp/fsdp-only meshes are unaffected). Everything else falls back
         to cold replay, which is still correct."""
-        if self._kv_int8 or self._cfg.is_mla or self._cfg.window_pattern:
+        cfg = self._cfg
+        if (self._kv_int8 or cfg.is_mla or cfg.window_pattern
+                or cfg.linear_pattern):
             return False
         if self._mesh is None:
             return True
@@ -4093,6 +4144,14 @@ class StreamingGenerator:
             # The XLA read fetches the whole slab of every slot, every tick.
             self.metrics.latent_positions_read.add(
                 blocks * self._slots * self._ticks_per_sync * self._max_len
+            )
+        if self._cfg.linear_pattern and not self._cfg.is_mla:
+            # The attention layers' K and V rows, as a pool by kind's full
+            # layers: the XLA read fetches every slot's slab, every tick.
+            n_full = self._cfg.cache_layers
+            self.metrics.full_positions_valid.add(rows_needed * n_full)
+            self.metrics.full_positions_read.add(
+                n_full * self._slots * self._ticks_per_sync * self._max_len
             )
         if self._cfg.window_pattern:
             m, ticks = self.metrics, self._slots * self._ticks_per_sync

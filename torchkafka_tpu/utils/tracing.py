@@ -63,8 +63,9 @@ called ``token.commit``):
 The Pallas kernels carry fixed names too (``pl.pallas_call(name=...)`` in
 ``ops/``): ``tk_kvattn_dynlen``, ``tk_kvattn_paged``, ``tk_flash_fwd``,
 ``tk_flash_fwd_win``, ``tk_flash_bwd_dq``, ``tk_flash_bwd_dkv``,
-``tk_qmatmul``, ``tk_gmm_gate_up``, ``tk_gmm_down``, ``tk_kda_step`` — the device trace
-names each kernel's operation after them.
+``tk_qmatmul``, ``tk_gmm_gate_up``, ``tk_gmm_down``, ``tk_kda_step``,
+``tk_ssd_step`` — the device trace names each kernel's operation after
+them.
 
 The device programs name their parts (``jit_admit``, ``jit_tick_block``,
 ``jit__step`` and whatever else traces the shared model code): twelve
@@ -81,42 +82,55 @@ the innermost counts; a Pallas call's own name (below) sits inside the
 scope that holds it. The names, with what each holds and the functions
 that open it:
 
-    tk_embed           the token embedding: Transformer.trunk,
+    tk_embed           the token embedding and its multiplier
+                       (transformer.embed_tokens): Transformer.trunk,
                        generate.prefill / _prefill_kinds / latent_forward
                        / _decode_one, serve.py's tick_block
     tk_attn_proj       the norm before attention, q/k/v or latent
                        projections, rope (YaRN), the output projection,
                        its residual and the norm after it:
-                       transformer._rope, Transformer._gqa /
+                       transformer._rope, Transformer._gqa_qkv (_gqa's
+                       and a hybrid's grouped-query layer's) /
                        _layer_capture, _double_layer, mla.project /
                        attend_full (the latent's up-projection) /
                        attend_absorbed (the absorbed products),
                        generate._project_qkv / _attn_tail_routing /
-                       prefill's k and v, serve._slot_layer_step_latent
+                       prefill's k and v, serve._slot_layer_step_latent;
+                       a linear layer's in-projections, convolution,
+                       gates and gated norm (linear_attn._project /
+                       _conv_qkv / _finish, the delta rule's;
+                       _ssd_project / _ssd_conv / _ssd_finish, the
+                       Mamba-2 mixer's) and linear_attn.layer_forward /
+                       slot_layer_step's norm
     tk_kv_write        quantisation and the row or ring write:
                        serve._quant_kv, _slot_layer_step*, admit's put,
                        generate.ring_rows, prefill's pools, a linear
-                       layer's conv tail (linear_attn.attend_step)
+                       layer's conv tail (linear_attn._kda_step /
+                       _ssd_step)
     tk_kv_read         scores, softmax and values over CACHED positions,
                        a pool of one kind: generate._read_cached
                        (_attend_cached's read), serve._slot_layer_step_q
                        (the Pallas call tk_kvattn_dynlen, the row write
-                       it holds too); a linear-attention layer's pass
-                       over its recurrent state, which IS its cache
-                       (linear_attn.attend_step: the Pallas call
-                       tk_kda_step, or the jax.numpy step off the TPU)
+                       it holds too); a linear layer's pass over its
+                       recurrent state, which IS its cache
+                       (linear_attn._kda_step / _ssd_step: the Pallas
+                       calls tk_kda_step / tk_ssd_step, or the
+                       jax.numpy steps off the TPU)
     tk_kv_read_window  the same over a window layer's ring:
                        serve._slot_layer_step(kind=) names it to
                        generate._attend_merged
     tk_kv_read_full    the same over a full layer's slab of a pool by
-                       kind: likewise
+                       kind: likewise; and over the K and V rows of a
+                       hybrid's grouped-query layer (serve.py's
+                       hybrid_body hands it the same step)
     tk_kv_read_latent  the absorbed read of the latent pool:
                        mla._read_latent (attend_absorbed's read)
     tk_attn_flash      attention over a whole sequence, the flash kernels
                        and XLA's form: Transformer._attention,
                        mla._attend_whole (attend_full's attention), the
-                       linear layers' chunkwise form
-                       (linear_attn.attend_sequence: kda.kda_chunk)
+                       linear layers' chunked forms
+                       (linear_attn._kda_sequence: kda.kda_chunk;
+                       _ssd_sequence: ssd.ssd_chunk)
     tk_ffn             the dense FFN and the shared experts:
                        transformer._dense_mlp, moe.routed_moe_mlp
     tk_moe_route       router scores, bias, top-k, renormalisation, the
