@@ -3,8 +3,10 @@ chunked scan an admission runs against the token-serial recurrence (at
 lengths that are and are not multiples of the chunk, at one token, from a
 carried state, at the published chunk), the step kernel under the Pallas
 interpreter against the ``jax.numpy`` step (a slot that is not active
-keeps its state bit for bit, the other layers' slabs are untouched), and
-the convolution that feeds them."""
+keeps its state bit for bit, the other layers' slabs are untouched; at
+the cell's own block of 64 heads; its read-out held to float64 where one
+bfloat16 pass is not; a head's channels in the head's own row whatever
+the block), and the convolution that feeds them."""
 
 from __future__ import annotations
 
@@ -132,6 +134,88 @@ def test_the_step_kernel_takes_heads_that_no_block_divides():
     got = ssd.ssd_step(state, 0, x, dt, a, bm, cm, d, interpret=True)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+@pytest.fixture(scope="module")
+def real_block():
+    """A grid step's block as the cell has it, 64 heads of 64 x 128 a
+    slot, layer 1 of a stack of two, the second of two slots not active:
+    (the state, the step's arguments, the interpreted kernel's result)."""
+    slots, heads = 2, 64
+    ks = jax.random.split(jax.random.key(13), 6)
+    state = jax.random.normal(ks[0], (2, slots, heads, 64, 128))
+    x = jax.random.normal(ks[1], (slots, heads, 64))
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (slots, heads)) - 2.0)
+    dt = dt.at[1].set(0.0)
+    a = -jnp.exp(jax.random.uniform(ks[3], (heads,), maxval=2.7))
+    bm, cm = jax.random.normal(ks[4], (2, slots, 128))
+    d = jax.random.uniform(ks[5], (heads,), minval=0.5, maxval=1.5)
+    args = (x, dt, a, bm, cm, d)
+    return state, args, ssd.ssd_step(state, 1, *args, interpret=True)
+
+
+def test_the_step_kernel_at_a_real_block_is_the_jax_numpy_step(real_block):
+    """64 heads of 64 x 128 a grid step, eight read-out products of eight
+    heads' tiles each: the ``jax.numpy`` step's read-out and state; the
+    idle slot's state BIT FOR BIT, layer 0 untouched."""
+    state, args, (got_y, got_s) = real_block
+    want_y, want_s = ssd.ssd_step_xla(state, 1, *args)
+    _close(got_y, want_y, 2e-6)
+    np.testing.assert_allclose(got_s, want_s, atol=1e-6)
+    assert bool((got_s[1, 1] == state[1, 1]).all())
+    assert bool((got_s[0] == state[0]).all())
+
+
+# The read-out's error against float64, as a share of max|y|. The shipped
+# form (C against the tiles at ``Precision.HIGHEST``) reads 3e-7 here and
+# 2e-7 on the v5e (PERF.md §6, PR 46); ONE bfloat16 pass reads 2e-3.
+READ_OUT_BOUND = 2e-6
+
+
+@pytest.mark.parametrize("form", ["kernel", "one_bf16_pass"])
+def test_the_read_out_keeps_the_reference_s_precision(real_block, form):
+    """``y`` of the state the kernel wrote, against that state's read-out
+    in float64: within ``READ_OUT_BOUND`` of max|y|. A read-out of one
+    bfloat16 pass (a lower precision than the configuration's path
+    states) must fail the bound a hundred times over."""
+    _state, (x, _dt, _a, _bm, cm, d), (y, s) = real_block
+    if form == "one_bf16_pass":
+        y = jnp.einsum(
+            "bhpn,bn->bhp", s[1].astype(jnp.bfloat16),
+            cm.astype(jnp.bfloat16), preferred_element_type=jnp.float32,
+        ) + d[:, None] * x
+    want = np.einsum(
+        "bhpn,bn->bhp", np.asarray(s[1], np.float64),
+        np.asarray(cm, np.float64),
+    ) + np.asarray(d, np.float64)[:, None] * np.asarray(x, np.float64)
+    err = np.abs(np.asarray(y, np.float64) - want).max() / np.abs(want).max()
+    if form == "kernel":
+        assert err <= READ_OUT_BOUND
+    else:
+        assert err > 100 * READ_OUT_BOUND
+
+
+@pytest.mark.parametrize(
+    "slots,heads,p", [(3, 4, 64), (2, 6, 16), (1, 128, 64)]
+)
+def test_the_step_kernel_s_read_out_comes_back_a_head_a_row(slots, heads, p):
+    """``y`` [B, H, P] float32 whatever the block (4 heads: one group of
+    4; 6 heads of 16: gcd 2; 128 heads: two grid steps a slot), a head's
+    channels in the head's own row: every head is given a state that
+    reads out its own number."""
+    state = jnp.broadcast_to(
+        jnp.arange(1.0, heads * p + 1).reshape(heads, p, 1),
+        (1, slots, heads, p, 128),
+    )
+    cm = jnp.zeros((slots, 128)).at[:, 5].set(1.0)
+    zeros = jnp.zeros((slots, heads, p))
+    y, s = ssd.ssd_step(
+        state, 0, zeros, jnp.zeros((slots, heads)), -jnp.ones((heads,)),
+        cm, cm, jnp.ones((heads,)), interpret=True,
+    )
+    assert y.shape == (slots, heads, p) and y.dtype == jnp.float32
+    np.testing.assert_array_equal(y, state[0, ..., 5])
+    np.testing.assert_array_equal(s, state)
 
 
 def test_the_convolution_is_causal_and_has_a_bias():

@@ -13,14 +13,22 @@ its softplus), and ``B``, ``C`` [N], which every head of the layer SHARES
 so, unlike the delta rule (``ops/kda.py``), nothing is read back from the
 state before it is written: no rank-one correction.
 
-**The step** (``ssd_step``) passes over a layer's state ONCE: decay, outer
-product, read-out and skip of ``STEP_HEADS`` heads of a slot a grid step,
-the state ``[layers, slots, heads, P, N]`` aliased in place
-(``tk_ssd_step``). ``dt = 0`` is a slot that is not active: the decay is
-exactly 1 and the outer product exactly 0, so the kernel writes back what
-it read, bit for bit. Off the TPU the same arithmetic runs as
-``jax.numpy`` (``ssd_step_xla``; the tests run the kernel under the Pallas
-interpreter against it).
+**The step** (``ssd_step``) passes over a layer's state ONCE, the state
+``[layers, slots, heads, P, N]`` aliased in place (``tk_ssd_step``),
+``STEP_HEADS`` heads of a slot a grid step. The update is the vector
+unit's, in float32, operation for operation: a head's decay is a SCALAR
+(SMEM; no broadcast), ``dt x`` a column broadcast along the lanes, B a
+row. The read-out is the matrix unit's: C against the tiles of
+``STEP_GROUP`` heads as rows ``[group * P, N]``, both contracted on
+their lanes at ``Precision.HIGHEST``, so it leaves as a dense ROW of
+``y`` laid ``[B, H * P]``: no lane reduction to a column, no masked
+column store, no transpose of ``y`` after (PERF.md §6, PR 46, has what
+each of those cost). The skip ``D x`` is added outside, where it fuses
+with what reads ``y``. ``dt = 0`` is a slot that is not active: the
+decay is exactly 1 and the outer product exactly 0, so the kernel writes
+back what it read, bit for bit. Off the TPU the same arithmetic runs as
+``jax.numpy`` (``ssd_step_xla``; the tests run the kernel under the
+Pallas interpreter against it).
 
 **The chunked scan** (``ssd_chunk``) does not walk a prompt token by
 token. In a chunk of Q tokens from a state ``S0``, with ``G_r`` the running
@@ -51,11 +59,20 @@ from torchkafka_tpu.ops.flash import tpu_compiler_params
 CHUNK = 256
 # Heads of one slot a grid step of the step kernel takes: 64 tiles of
 # 32 KiB in and out, double-buffered, are 8 MiB of the default scoped
-# VMEM. Read on the v5e at 128 slots of 128 heads, us a call (PERF.md §6,
-# PR 45): 32 heads 2,014, 64 1,968, 128 1,959: the block's size is not
-# what holds the kernel at two thirds of the HBM peak.
+# VMEM. The kernel is held by its DMA and by nothing else: read on the v5e
+# at 128 slots of 128 heads (PERF.md §6, PR 46), 32 / 64 / 128 heads a
+# grid step are 1,690 / 1,671 / 1,661 us a call, where the same blocks
+# streamed through a bare multiply, no column and no read-out, take 1,684
+# (640 GB/s in and out in place: 78% of the HBM peak is what such a stream
+# gets). Before PR 46 a tile paid two column broadcasts, a lane reduction
+# and a masked column store: 1,985 us, and 165 us less for each cross-lane
+# operation a vreg taken out, down to that floor.
 STEP_HEADS = 64
+# Heads whose tiles one read-out product takes: 8 heads of 64 channels are
+# 512 rows of the state against C (2, 8 and 16 heads read the same time).
+STEP_GROUP = 8
 _HI = lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))  # both operands contracted on their lanes
 
 
 def short_conv(x, taps, bias):
@@ -103,63 +120,77 @@ def ssd_step_xla(state, layer, x, dt, a, bm, cm, d):
     return y, lax.dynamic_update_index_in_dim(state, s, layer, 0)
 
 
-def _step_kernel(base_ref, decay_ref, dtx_ref, dx_ref, b_ref, c_ref, s_ref,
-                 y_ref, s_out_ref, *, heads: int):
+def _step_kernel(base_ref, decay_ref, dtx_ref, b_ref, c_ref, s_ref, y_ref,
+                 s_out_ref, *, heads: int, group: int):
     """A slot's ``heads`` heads: each tile is read once, decayed, given
-    its outer product, read out and written once. What scales the tile's
-    ROWS (the decay, ``dt x``, ``D x``) comes channel-major, [P, heads]: a
-    head's is a column, broadcast along the lanes, and so is its read-out;
-    B and C are rows, the same for every head."""
+    its outer product and written once on the vector unit; the tiles of
+    ``group`` heads, as rows [group * P, N], are then read out by ONE
+    product with C that contracts the lanes and leaves as a dense row of
+    ``y``. A head's decay is a scalar (SMEM); ``dt x`` scales the tile's
+    ROWS and comes channel-major, [P, heads], a head's a column broadcast
+    along the lanes; B and C are rows, the same for every head."""
     del base_ref
-    b, c = b_ref[0], c_ref[0]  # [1, N]
-    for h in range(heads):
-        col = (slice(None), slice(h, h + 1))
-        s = s_ref[0, 0, h] * decay_ref[0, 0][col] + dtx_ref[0, 0][col] * b
-        s_out_ref[0, 0, h] = s
-        y_ref[0, 0, :, h:h + 1] = (
-            jnp.sum(s * c, axis=1, keepdims=True) + dx_ref[0, 0][col]
+    p, n = s_ref.shape[3:]
+    b = b_ref[0]  # [1, N]
+    c = jnp.broadcast_to(c_ref[0], (8, n))  # a product's least 8 rows
+    rows = group * p
+    for g in range(heads // group):
+        for h in range(g * group, (g + 1) * group):
+            s_out_ref[0, 0, h] = (
+                s_ref[0, 0, h] * decay_ref[0, 0, 0, h]
+                + dtx_ref[0, 0][:, h:h + 1] * b
+            )
+        tiles = s_out_ref[0, 0, g * group:(g + 1) * group].reshape(rows, n)
+        y = lax.dot_general(
+            c, tiles, _NT, precision=_HI, preferred_element_type=jnp.float32
         )
+        y_ref[0, 0, :, g * rows:(g + 1) * rows] = y[:1]
 
 
 def ssd_step(state, layer, x, dt, a, bm, cm, d, *, interpret: bool = False):
     """``ssd_step_xla`` as the Pallas kernel ``tk_ssd_step``: the state
     comes back aliased to the one passed in, the other layers' slabs
-    untouched."""
+    untouched. The kernel returns ``S C`` as rows [B, H * P]; the skip
+    ``D x`` is added here, where it fuses with what reads ``y``."""
     _nl, b, h, p, n = state.shape
     hb = math.gcd(h, STEP_HEADS)
-
-    def cols(v):  # [B, H, P] -> [B, H / hb, P, hb]
-        return v.reshape(b, h // hb, hb, p).swapaxes(2, 3)
-
-    decay = jnp.broadcast_to(jnp.exp(dt * a)[..., None], x.shape)
-    operands = (
-        cols(decay), cols(dt[..., None] * x), cols(d[:, None] * x),
-        bm[:, None, :], cm[:, None, :],
+    blocks = h // hb
+    dtx = (dt[..., None] * x).reshape(b, blocks, hb, p).swapaxes(2, 3)
+    decay_spec = pl.BlockSpec(
+        (1, 1, 1, hb), lambda i, j, base: (i, j, 0, 0),
+        memory_space=pltpu.SMEM,
     )
     col_spec = pl.BlockSpec((1, 1, p, hb), lambda i, j, base: (i, j, 0, 0))
     row_spec = pl.BlockSpec((1, 1, n), lambda i, j, base: (i, 0, 0))
+    y_spec = pl.BlockSpec((1, 1, 1, hb * p), lambda i, j, base: (i, j, 0, 0))
     tile_spec = pl.BlockSpec(
         (1, 1, hb, p, n), lambda i, j, base: (base[0], i, j, 0, 0)
     )
     kw = {} if interpret else tpu_compiler_params(("parallel", "parallel"))
     y, state = pl.pallas_call(
-        functools.partial(_step_kernel, heads=hb),
+        functools.partial(
+            _step_kernel, heads=hb, group=math.gcd(hb, STEP_GROUP)
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, h // hb),
-            in_specs=[col_spec] * 3 + [row_spec] * 2 + [tile_spec],
-            out_specs=[col_spec, tile_spec],
+            num_scalar_prefetch=1, grid=(b, blocks),
+            in_specs=[decay_spec, col_spec, row_spec, row_spec, tile_spec],
+            out_specs=[y_spec, tile_spec],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, h // hb, p, hb), jnp.float32),
+            jax.ShapeDtypeStruct((b, blocks, 1, hb * p), jnp.float32),
             jax.ShapeDtypeStruct(state.shape, jnp.float32),
         ],
         # Operand numbers count the scalar-prefetch argument.
-        input_output_aliases={6: 1},
+        input_output_aliases={5: 1},
         interpret=interpret,
         name="tk_ssd_step",
         **kw,
-    )(jnp.asarray(layer, jnp.int32).reshape(1), *operands, state)
-    return y.swapaxes(2, 3).reshape(b, h, p), state
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.exp(dt * a).reshape(b, blocks, 1, hb), dtx,
+        bm[:, None, :], cm[:, None, :], state,
+    )
+    return y.reshape(b, h, p) + d[:, None] * x, state
 
 
 # ------------------------------------------------------------ the chunk form
