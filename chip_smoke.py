@@ -292,9 +292,9 @@ def check_kernels(sz: Sizes) -> dict:
         return int8_decode_attention_dynlen(q, *pool, pos, layer=1), *pool
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def write_in_read(q, pool, fresh, pos):
+    def write_in_read(q, pool, fresh, pos, live=None):
         return int8_decode_attention_dynlen(
-            q, *pool, pos, layer=jnp.int32(1), rows=fresh
+            q, *pool, pos, layer=jnp.int32(1), rows=fresh, live=live
         )
 
     want = scatter_then_read(q, stacked, fresh, pos)
@@ -304,6 +304,27 @@ def check_kernels(sz: Sizes) -> dict:
             np.array_equal(np.asarray(g), np.asarray(w)),
             f"dynlen_write: {name} differs from scatter-then-read",
         )
+    # Under a live mask (the first slot dead, dead ones between live ones)
+    # the live slots' attention and rows are those above; a dead slot
+    # gives zeros and its pool is untouched.
+    live = np.arange(b) % 2 == 1
+    masked = write_in_read(
+        q, [jnp.copy(c) for c in stacked], fresh, pos, jnp.asarray(live)
+    )
+    for name, g, w, old in zip(
+        ("attn", "kq", "ks", "vq", "vs"), masked, want, [None, *stacked]
+    ):
+        g, w = np.asarray(g), np.asarray(w)
+        if old is None:
+            ok = np.array_equal(g[live], w[live]) and not g[~live].any()
+        else:
+            old = np.asarray(old)
+            ok = (
+                np.array_equal(g[1][live], w[1][live])
+                and np.array_equal(g[1][~live], old[1][~live])
+                and np.array_equal(g[0], old[0])
+            )
+        _require(ok, f"dynlen_live: {name} differs under a live mask")
 
     # The grouped expert matmul (ops/moe.py: the gated pair's kernel and
     # the down projection's) against ``lax.ragged_dot`` over the same

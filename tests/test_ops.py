@@ -594,6 +594,120 @@ class TestInt8DecodeAttentionKernel:
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
             assert g.sharding.is_equivalent_to(p.sharding, g.ndim)
 
+    # The LIVE MASK (``live=``): a slot that is not live fetches nothing,
+    # writes nothing and gives zeros; the chain of prefetches and staged
+    # row writes runs over the live slots alone.
+    _LIVE_MASKS = {
+        "first-dead": [0, 1, 1, 1, 1, 1],
+        "last-dead": [1, 1, 1, 1, 1, 0],
+        "a-run-of-dead-between-live": [1, 0, 0, 0, 1, 1],
+        "all-dead": [0, 0, 0, 0, 0, 0],
+        "all-live": [1, 1, 1, 1, 1, 1],
+    }
+
+    @pytest.mark.parametrize("block", [8, None], ids=["block-8", "block-default"])
+    @pytest.mark.parametrize("layered", [False, True], ids=["slab", "layer"])
+    @pytest.mark.parametrize("write", [False, True], ids=["read", "rows"])
+    @pytest.mark.parametrize("mask", list(_LIVE_MASKS))
+    def test_dynlen_live_mask(self, monkeypatch, mask, write, layered, block):
+        """Live slots' attention equals the scale-folded XLA read (of the
+        pool with the live rows scattered in, where the call writes); dead
+        slots' is zero; a live slot's pool row is bit for bit the
+        scatter's and a dead slot's pool rows are untouched, whatever its
+        frozen watermark says (one lies past the pool)."""
+        from types import SimpleNamespace
+
+        from torchkafka_tpu.models import generate
+        from torchkafka_tpu.ops.kvattn import (
+            dynlen_block, int8_decode_attention_dynlen,
+        )
+
+        B, M, K, Dh, layer = 6, 64, 2, 16, 1
+        assert dynlen_block(M) == 64
+        q, pool, _ = self._stacked_pool(B=B, M=M)
+        rows = self._fresh_rows(B=B)
+        live = np.asarray(self._LIVE_MASKS[mask], bool)
+        pos = jnp.asarray([17, 3, 63, 8, 70, 40], jnp.int32)
+        inside = jnp.minimum(pos, M - 1)
+        if not layered:
+            pool = tuple(c[layer] for c in pool)
+        got = jax.jit(
+            lambda l, alive: int8_decode_attention_dynlen(
+                q, *pool, pos, layer=l if layered else None,
+                rows=rows if write else None, live=alive, block=block,
+                interpret=True,
+            )
+        )(jnp.int32(layer), jnp.asarray(live))
+        slab = pool if not layered else tuple(c[layer] for c in pool)
+        want_slab = slab
+        if write:
+            got, *got_pool = got
+            at = (np.nonzero(live)[0][:, None], jnp.arange(K)[None, :],
+                  inside[live][:, None])
+            want_slab = tuple(
+                c.at[at].set(r[live]) for c, r in zip(slab, rows)
+            )
+            want_pool = want_slab if not layered else tuple(
+                c.at[layer].set(w) for c, w in zip(pool, want_slab)
+            )
+            for name, g, w in zip(("kq", "ks", "vq", "vs"), got_pool, want_pool):
+                np.testing.assert_array_equal(
+                    np.asarray(g), np.asarray(w), err_msg=name
+                )
+        monkeypatch.setattr(
+            generate, "_attn_tail", lambda x, attn, layer, cfg: attn
+        )
+        kq, ks, vq, vs = want_slab  # K-major: [B, K, M, ·]
+        ref = generate._attend_cached(
+            None, q, jnp.swapaxes(kq, 1, 2), jnp.swapaxes(vq, 1, 2),
+            jnp.arange(M)[None, :] <= inside[:, None], None,
+            SimpleNamespace(dtype=jnp.float32, head_dim=Dh),
+            k_scale=jnp.swapaxes(ks, 1, 2), v_scale=jnp.swapaxes(vs, 1, 2),
+        )
+        got = np.asarray(got)
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got[~live], 0)
+        np.testing.assert_allclose(
+            got[live], np.asarray(ref)[live], atol=2e-5, rtol=2e-5
+        )
+
+    @pytest.mark.parametrize("axes", [{"data": 2, "tp": 2, "fsdp": 2}])
+    def test_dynlen_sharded_live_mask(self, axes):
+        """Under a mesh ``live`` splits over ``data`` with the slots: each
+        shard orders its own, and the result is the unsharded call's."""
+        from torchkafka_tpu.models.generate import (
+            kv_kmajor_scale_sharding, kv_kmajor_sharding,
+        )
+        from torchkafka_tpu.ops.kvattn import (
+            int8_decode_attention_dynlen,
+            int8_decode_attention_dynlen_sharded,
+        )
+
+        mesh = make_mesh(axes)
+        q, pool, pos = self._stacked_pool()
+        rows = self._fresh_rows()
+        live = jnp.asarray([False, True, False, False])  # a whole shard dead
+        placed = tuple(
+            jax.device_put(
+                c, kv_kmajor_sharding(mesh) if c.ndim == 5
+                else kv_kmajor_scale_sharding(mesh)
+            )
+            for c in pool
+        )
+        want, *want_pool = int8_decode_attention_dynlen(
+            q, *pool, pos, layer=2, rows=rows, live=live, block=8,
+            interpret=True,
+        )
+        got, *got_pool = jax.jit(
+            lambda l: int8_decode_attention_dynlen_sharded(
+                q, *placed, pos, mesh, layer=l, rows=rows, live=live, block=8,
+                interpret=True,
+            )
+        )(jnp.int32(2))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for g, w in zip(got_pool, want_pool):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
     def test_paged_kernel_matches_gathered_read(self):
         """The block-table read (the dyn-len kernel's watermark-DMA
         structure through per-slot block tables) against the XLA gathered
@@ -657,6 +771,27 @@ class TestInt8DecodeAttentionKernel:
             np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5
         )
 
+    @pytest.mark.parametrize("pool, block", [
+        (4096, 512), (2048, 512),
+        (1024, 256),  # four blocks a pool: half of it is no block (PR 47)
+        (512, 256), (768, 256), (384, 128),
+        (1032, 8),    # tiles, but tiny → TPU-gated
+        (1030, 0),    # does not tile at all
+    ])
+    def test_dynlen_block_of_a_pool(self, pool, block):
+        """The rows the dynamic-length read fetches at a time, and what the
+        serving probe makes of them on the TPU (a block under 256 there is
+        refused: ``_kernel_probe_dense``)."""
+        from types import SimpleNamespace
+
+        from torchkafka_tpu.kvcache.backend import _kernel_probe_dense
+        from torchkafka_tpu.ops.kvattn import dynlen_block
+
+        assert dynlen_block(pool) == block
+        cfg = SimpleNamespace(head_dim=128)
+        refused = _kernel_probe_dense(cfg, pool, on_tpu=True) is not None
+        assert refused == (block < 256)
+
     def test_kernel_gates(self):
         """The dyn-len kernel's scratch is block-sized, so LONG pools are
         supported; pools that only tile at tiny blocks are refused on TPU
@@ -667,13 +802,8 @@ class TestInt8DecodeAttentionKernel:
         from torchkafka_tpu.models.transformer import (
             TransformerConfig, init_params,
         )
-        from torchkafka_tpu.ops.kvattn import dynlen_block
         from torchkafka_tpu.serve import StreamingGenerator
 
-        assert dynlen_block(2048) == 512
-        assert dynlen_block(4096) == 512
-        assert dynlen_block(1032) == 8     # tiles, but tiny → TPU-gated
-        assert dynlen_block(1030) == 0     # does not tile at all
         # M=4096 is accepted with the explicit kernel (off-TPU it honors
         # via interpret — ctor only, no decode executed here).
         cfg = TransformerConfig(

@@ -6,6 +6,8 @@ slot recycling across admission waves, and per-completion offset accounting
 (commit covers exactly the finished prompts; unfinished ones re-deliver).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1166,7 +1168,10 @@ def test_kernel_tick_writes_its_rows_in_the_read(variant, scatters,
 def test_carried_tick_equals_xs_ys_reference(variant):
     """Identity: one block of ticks from a ragged state (slots at different
     positions, one of them idle) gives bit-identical gen, pos, done, counts
-    and pool to the xs/ys formulation above."""
+    and pool to the xs/ys formulation above. The idle slot's pool: the
+    reference writes a stale row at its frozen position every tick; the
+    dense int8 kernel, which owns its row write, leaves a slot that is not
+    live as it was (PR 47)."""
     srv, consumer = _tick_server(variant)
     B = 4
     rng = np.random.default_rng(11)
@@ -1183,9 +1188,16 @@ def test_carried_tick_equals_xs_ys_reference(variant):
     )[:4]
     active = jnp.asarray([True, True, False, True])
     got = tick(srv._params, *state, active, srv._slot_keys)
+    before = jax.tree.map(np.asarray, state[0])  # the tick donates it
     ref = jax.jit(
         lambda *a: _ref_tick_block(srv, *a)
     )(srv._params, *state, active)
+    if srv._kv_kernel:
+        idle = jnp.asarray(~np.asarray(active))
+        ref = (tuple(
+            jnp.where(idle.reshape(1, B, *[1] * (c.ndim - 2)), old, c)
+            for c, old in zip(ref[0], before)
+        ), *ref[1:])
     assert sorted(np.asarray(state[2]).tolist()) != [P] * B  # ragged indeed
     for name, a, b in zip(
         ("caches", "last_tok", "pos", "gen", "done", "n_out"), got, ref
@@ -1616,3 +1628,150 @@ def test_latent_model_crash_before_commit_redelivers_unfinished():
         (p, o) for p in (0, 1) for o in range(committed[p], 4)
     }
     again.close()
+
+
+# ----------------------------------------------------------------------
+# The answer budget on the device (PR 47): a slot latches done at its
+# request's budget inside the tick block, as at EOS or a full buffer, so no
+# tick decodes a token the host would cut, and the dense int8 kernel
+# fetches nothing for the slot from then on. The served tokens do not
+# depend on it: the same tokens at any sync cadence, cut at the same budget.
+
+_BUDGET_NEW, _BUDGET_EOS, _BUDGET_RECORDS = 16, 5, 14
+_BUDGET_VARIANTS = {
+    # name: (kv_dtype, kv_kernel, paged)
+    "bf16": (None, "auto", False),
+    "int8": ("int8", False, False),
+    "int8-kernel": ("int8", True, False),
+    "paged": (None, "auto", True),  # _build_paged's programs
+}
+
+
+def _heavy_tailed_budgets(cap=None):
+    """Lognormal answer budgets in 1.._BUDGET_NEW (most short, a few the
+    whole buffer), one a record; ``cap`` shortens every one."""
+    draws = np.random.default_rng(47).lognormal(np.log(4), 0.8, _BUDGET_RECORDS)
+    budgets = np.clip(np.rint(draws), 1, _BUDGET_NEW).astype(int)
+    budgets[:2] = (1, _BUDGET_NEW)  # the two ends, whatever the draw
+    return np.minimum(budgets, cap) if cap else budgets
+
+
+@functools.lru_cache(maxsize=None)
+def _budget_run(variant, ticks, cap=None):
+    """Serve the records under their budgets; what was published, what
+    the device handed the host at every sync, and the meters."""
+    from torchkafka_tpu.workload import header_max_new
+
+    kv_dtype, kv_kernel, paged = _BUDGET_VARIANTS[variant]
+    m = P + _BUDGET_NEW
+    cfg = TransformerConfig(
+        vocab_size=VOCAB, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        d_ff=64, max_seq_len=m, dtype=jnp.float32,
+    )
+    params = init_params(jax.random.key(0), cfg)
+    broker = tk.InMemoryBroker()
+    broker.create_topic("b", partitions=1)
+    rng = np.random.default_rng(3)
+    budgets = _heavy_tailed_budgets(cap)
+    for budget in budgets:
+        broker.produce(
+            "b", rng.integers(0, VOCAB, P, dtype=np.int32).tobytes(),
+            headers=(("max_new", str(budget).encode()),),
+        )
+    consumer = tk.MemoryConsumer(broker, "b", group_id=f"g-{variant}-{ticks}")
+    kw = {"kv_pages": {"block_size": 4, "num_blocks": 4 * -(-m // 4) + 16}}
+    srv = StreamingGenerator(
+        consumer, params, cfg, slots=4, prompt_len=P, max_new=_BUDGET_NEW,
+        ticks_per_sync=ticks, eos_id=_BUDGET_EOS, kv_dtype=kv_dtype,
+        kv_kernel=kv_kernel, max_new_of=header_max_new, commit_every=4,
+        **(kw if paged else {}),
+    )
+    assert (srv._kv_pages is not None) is paged
+    handed = []  # (tokens by the device's count, budget) a slot a sync
+    retire = srv._retire_block
+
+    def spy(done_h, n_out_h, gen_h, pos_h, *rest):
+        for i in np.nonzero(srv._active)[0]:
+            count = int(n_out_h[i] if done_h[i] else pos_h[i] - P + 1)
+            handed.append((count, bool(done_h[i]), int(srv._slot_budget[i])))
+        return retire(done_h, n_out_h, gen_h, pos_h, *rest)
+
+    srv._retire_block = spy
+    out = {
+        rec.offset: np.asarray(toks)
+        for rec, toks in srv.run(max_records=_BUDGET_RECORDS)
+    }
+    summary = srv.metrics.summary()
+    srv.close()
+    consumer.close()
+    return {
+        "out": out, "budgets": budgets, "handed": handed, "summary": summary,
+    }
+
+
+@pytest.mark.parametrize("variant", list(_BUDGET_VARIANTS))
+def test_budget_same_tokens_at_any_sync_cadence(variant):
+    """Blocks of 8 ticks (a slot may finish seven ticks before its sync)
+    and of 1 publish the same tokens for every record, each within its
+    budget and cut at it unless an EOS came first."""
+    wide, narrow = _budget_run(variant, 8), _budget_run(variant, 1)
+    assert sorted(wide["out"]) == list(range(_BUDGET_RECORDS))
+    for off, toks in wide["out"].items():
+        np.testing.assert_array_equal(toks, narrow["out"][off], err_msg=str(off))
+        budget = wide["budgets"][off]
+        assert 1 <= len(toks) <= budget
+        assert len(toks) == budget or toks[-1] == _BUDGET_EOS
+
+
+@pytest.mark.parametrize("ticks", [8, 1])
+@pytest.mark.parametrize("variant", list(_BUDGET_VARIANTS))
+def test_budget_is_latched_on_the_device(variant, ticks):
+    """``done`` and ``n_out`` come back at the budget: at no sync does the
+    device hand the host more tokens than the slot's budget (the host's
+    clamp finds nothing to cut), and a slot that reached it is done."""
+    run = _budget_run(variant, ticks)
+    assert run["handed"]
+    for count, done, budget in run["handed"]:
+        assert count <= budget
+        assert done or count < budget
+    # Capped: finished by a budget under the buffer, and not by an EOS.
+    capped = sum(
+        len(toks) == run["budgets"][off] < _BUDGET_NEW
+        and toks[-1] != _BUDGET_EOS
+        for off, toks in run["out"].items()
+    )
+    assert capped > 0
+    assert run["summary"]["output_capped"] == capped
+    served = run["summary"]["scheduler"]["slot_ticks_served"]
+    assert served == sum(len(t) - 1 for t in run["out"].values())
+
+
+@pytest.mark.parametrize("variant", ["int8", "int8-kernel"])
+def test_dense_int8_pool_counts_rows_needed_and_fetched(variant):
+    """``full_positions_valid`` / ``_read`` of the dense int8 pool: the rows
+    the served ticks needed and the rows the read fetched for them, a
+    layer. Needed never passes fetched, and both fall with the budgets.
+    The kernel fetches whole blocks (of 8 here: ``dynlen_block(24)``) for
+    live slot-ticks alone; XLA's read the slab of every slot, every tick."""
+    long, short = (_budget_run(variant, 8, cap)["summary"] for cap in (None, 3))
+    for s in (long, short):
+        pool = s["kv_pool"]
+        assert pool["read"] == ("kernel" if variant == "int8-kernel" else "xla")
+        assert pool["full_layers"] == 2
+        assert 0 < pool["full_positions_valid"] <= pool["full_positions_read"]
+    read = [s["kv_pool"]["full_positions_read"] for s in (short, long)]
+    # As many blocks of 8 ticks either way: XLA's read cannot fall here.
+    assert read[0] < read[1] if variant == "int8-kernel" else read[0] == read[1]
+    assert short["kv_pool"]["full_positions_valid"] < long["kv_pool"]["full_positions_valid"]
+    if variant == "int8-kernel":
+        # Every fetched row a whole block's, of a tick that served a token
+        # (or latched a budget of one): by the published lengths.
+        run = _budget_run(variant, 8)
+        block = run["summary"]["kv_pool"]["block"]
+        assert block == 8
+        rows = sum(
+            -(-(P + j) // block) * block
+            for toks in run["out"].values()
+            for j in range(1, max(len(toks), 2))
+        )
+        assert run["summary"]["kv_pool"]["full_positions_read"] == 2 * rows

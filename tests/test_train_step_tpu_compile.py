@@ -12,6 +12,10 @@ that does (Mellum2's, PR 39), about two seconds each: what Mosaic refuses
 (a block over the scoped VMEM, a contraction it does not take) shows here
 and not on the chip.
 
+And the dense int8 read under its live mask (``ops/kvattn.py``, PR 47)
+at Mistral's widths, at each block the chip read: the order's indexed
+blocks and the [K, block] scale slices are Mosaic's to refuse.
+
 A file of its own because ``tests/chipbench/`` belongs to the accepted
 benchmark and is not edited: the topology is described inside a fixture,
 never at import, and where this worker cannot load the TPU's library
@@ -216,3 +220,45 @@ def test_the_grouped_matmul_kernels_compile_at_the_admissions_widths(
     assert "tk_gmm_down" in calls[0] + calls[1]
     stack = rf"bf16\[{layers * e},({d},{f}|{f},{d})\]"
     assert opcodes_of(text, stack) <= {"parameter"}
+
+
+@pytest.mark.parametrize("block", [None, 512, 128], ids=["default", "512", "128"])
+def test_the_dynlen_read_compiles_under_a_live_mask(topo, block):
+    """``tk_kvattn_dynlen`` as Mistral's tick calls it (48 slots, 8 kv
+    heads of 128, a pool of 1,024, the stacked pool and this tick's rows,
+    the tick's ``act``): ONE Mosaic call under its name, and the pool goes
+    through it where it lies, no pool-shaped result beside the call's own
+    aliased outputs."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from torchkafka_tpu.ops.kvattn import (
+        dynlen_block, int8_decode_attention_dynlen,
+    )
+
+    layers, slots, heads, kv, m, dh = 32, 48, 32, 8, 1024, 128
+    assert dynlen_block(m) == 256
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def fn(q, kq, ks, vq, vs, pos, live, layer, *rows):
+        return int8_decode_attention_dynlen(
+            q, kq, ks, vq, vs, pos, layer=layer, rows=rows, live=live,
+            block=block, interpret=False,
+        )
+
+    payload = sds((layers, slots, kv, m, dh), jnp.int8)
+    scales = sds((layers, slots, kv, m), jnp.float32)
+    row, row_scale = sds((slots, kv, dh), jnp.int8), sds((slots, kv), jnp.float32)
+    text = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+        sds((slots, 1, heads, dh), jnp.bfloat16), payload, scales, payload,
+        scales, sds((slots,), jnp.int32), sds((slots,), jnp.bool_),
+        sds((), jnp.int32), row, row_scale, row, row_scale,
+    ).compile().as_text()
+    calls = [ln for ln in text.split("\n") if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "tk_kvattn_dynlen" in calls[0]
+    pool = rf"s8\[({layers},{slots}|{layers * slots}),{kv},{m},{dh}\]"
+    assert opcodes_of(text, pool) <= {"parameter", "bitcast", "get-tuple-element"}

@@ -32,13 +32,25 @@ Why the layout and the structure:
   fill, which no XLA spelling can do (static shapes make every read
   pool-shaped).
 - **Global buffer parity.** Which of the two VMEM buffers block (slot, j)
-  uses is ``(blocks of all earlier slots + j) % 2``, derived by each
-  program from the prefetched watermarks' prefix sum, not restarted per
-  program, so that
+  uses is ``(blocks of all earlier slots + j) % 2``, the watermarks'
+  prefix sum (the dense kernel is handed it with the watermarks, the
+  paged one sums it a program), not restarted per program, so that
 - **the predecessor prefetches.** The grid is sequential ("arbitrary")
   and scratch persists across programs, so program i starts the DMA of
   program i+1's first block during its own last block's compute; without
   it every slot opens with a DMA stall.
+- **A slot that is not live costs the dense kernel nothing** (``live=``;
+  PR 47). A decode tick runs every slot, whether it serves a request or
+  not: idle ones, and ones the tick latched done earlier in its block (by
+  EOS, or at the request's answer budget: a quarter of the slot-ticks of
+  a heavy-tailed deck). The grid walks one more scalar-prefetch operand,
+  an ORDER with the live slots first (the query, fresh-row and output
+  blocks are indexed through it), so the first ``n_live`` programs are the
+  kernel above over a permutation, with the chain of prefetches, buffer
+  parities and staged row writes among them alone, and every program
+  past them starts no DMA, waits on none, writes no row and stores zeros
+  (0 / 0 must not reach the residual). "Earlier slots" above reads
+  "earlier programs".
 
 - **The dense kernel owns the tick's row write** (``rows=``; PR 30). A
   decode tick puts one new position into each slot: 8 heads x 132 bytes.
@@ -59,7 +71,9 @@ Why the layout and the structure:
   prefetch at once. Every other byte of the group is what was fetched, so
   the pool is bit for bit the scatters'. A DMA has no bounds check where
   a scatter drops: ``pos <= M - 1`` is the caller's (the tick's latch
-  holds it) and the kernel clamps.
+  holds it) and the call clamps. A slot that is not live writes
+  nothing, where the scatters wrote a stale row at its frozen position
+  that nothing read: its pool stays as it was.
 
 Numbers (time a call, roofline share): PERF.md §5.
 """
@@ -243,7 +257,7 @@ def block_table_attention(
 
 
 def _kvattn_dynlen_kernel(
-    pos_ref, base_ref, q_ref, *refs, mb: int, max_len: int,
+    pos_ref, base_ref, order_ref, par_ref, q_ref, *refs, mb: int,
     inv_sqrt_dh: float, rg: int, lg: int,
 ):
     # ``rg``/``lg`` > 0: the WRITING form, whose refs carry this tick's rows
@@ -256,39 +270,43 @@ def _kvattn_dynlen_kernel(
          gk, gks, gv, gvs, wsems) = refs
     else:
         kq_hbm, ks_hbm, vq_hbm, vs_hbm, o_ref, kt, st, vt, wt, sems = refs
-    b = pl.program_id(0)
-    nb = pl.num_programs(0)
+    i = pl.program_id(0)
+    # THE GRID WALKS AN ORDER, LIVE SLOTS FIRST: program t serves slot
+    # ``order[t]`` (the query, fresh-row and output blocks are indexed
+    # through it), and the first ``n_live`` programs are the live slots in
+    # slot order. Those are the whole chain of fetches, prefetches and row
+    # writes below; a program past them starts no DMA, waits on none,
+    # writes no row and stores zeros.
+    n_live = base_ref[1]
+    live = i < n_live
+    b = order_ref[i]
     # Slot b's rows lie at ``base + b`` of the pool operands: 0 for one
     # layer's slab, ``layer * B`` for the stacked pool taken whole.
     base = base_ref[0]
 
-    # A DMA is unchecked where XLA's gather and scatter clamp or drop: the
-    # watermark is held inside the pool here, for the read and the write.
-    def pos_of(t):
-        return jnp.minimum(pos_ref[t], max_len - 1)
-
-    pos = pos_of(b)
-    n_blocks = (pos + mb) // mb  # ceil((pos + 1) / mb), pos >= 0
+    # The watermark of this program's slot, held inside the pool by the
+    # caller (a DMA is unchecked where XLA's gather and scatter clamp or
+    # drop), for the read and the write.
+    pos = pos_ref[i]
+    # ceil((pos + 1) / mb), pos >= 0; none for a slot that is not live.
+    n_blocks = jnp.where(live, (pos + mb) // mb, 0)
     q = q_ref[0]  # [K, rep, Dh] compute dtype
     n_kv, rep, dh = q.shape
 
     # CROSS-PROGRAM PREFETCH. Grid programs run sequentially (semantics
-    # "arbitrary") and scratch persists across them, so each program's
-    # FIRST block is DMA'd by its predecessor during that predecessor's
-    # last-block compute — without this, every slot begins with a DMA
-    # stall.
+    # "arbitrary") and scratch persists across them, so each live
+    # program's FIRST block is DMA'd by its predecessor during that
+    # predecessor's last-block compute — without this, every slot begins
+    # with a DMA stall.
     # Buffer parity must therefore be GLOBAL over the whole run, not
-    # per-program: block (slot, j) uses parity (prefix_blocks(slot) + j)
-    # % 2, computable by any program from the prefetched watermarks.
-    def blocks_of(t):
-        return (pos_of(t) + mb) // mb
+    # per-program: block (program, j) uses parity
+    # (blocks of all earlier programs + j) % 2. The prefix sums come with
+    # the watermarks (a program summing them itself walks every earlier
+    # program's, on the scalar core, ahead of its first DMA).
+    parity0 = par_ref[i]
 
-    parity0 = jax.lax.fori_loop(
-        0, b, lambda t, acc: acc + blocks_of(t), jnp.int32(0)
-    ) % 2
-
-    def dmas(slot, i, j):  # block j of slot i into buffer ``slot``
-        row = base + i
+    def dmas(slot, t, j):  # block j of program t's slot into buffer ``slot``
+        row = base + order_ref[t]
         return (
             pltpu.make_async_copy(
                 kq_hbm.at[row, :, pl.ds(j * mb, mb), :], kt.at[slot],
@@ -334,13 +352,13 @@ def _kvattn_dynlen_kernel(
         writes. The group (``rg`` payload rows: one packed int8 tile; ``lg``
         scale lanes) goes out from a staging scratch of its own, so the
         tile buffer is free for the next prefetch at once; the staging
-        scratch is waited where it is next filled, one program later (the
-        last program waits its own at the end)."""
+        scratch is waited where it is next filled, one live program later
+        (the last live program waits its own at the end)."""
         off = pos - (n_blocks - 1) * mb
         r0 = pl.multiple_of(off // rg * rg, rg)
         c0 = pl.multiple_of(off // lg * lg, lg)
 
-        @pl.when(b > 0)
+        @pl.when(i > 0)
         def _():
             for d in row_writes(0, 0):
                 d.wait()
@@ -360,9 +378,9 @@ def _kvattn_dynlen_kernel(
         for d in row_writes((n_blocks - 1) * mb + r0, (n_blocks - 1) * mb + c0):
             d.start()
 
-    @pl.when(b == 0)
+    @pl.when((i == 0) & live)
     def _():  # no predecessor: start our own first block
-        for d in dmas(parity0 % 2, b, 0):
+        for d in dmas(parity0 % 2, i, 0):
             d.start()
 
     m0 = jnp.full((n_kv, rep), _NEG_INF, jnp.float32)
@@ -375,15 +393,15 @@ def _kvattn_dynlen_kernel(
 
         @pl.when(j + 1 < n_blocks)
         def _():
-            for d in dmas((parity0 + j + 1) % 2, b, j + 1):
+            for d in dmas((parity0 + j + 1) % 2, i, j + 1):
                 d.start()
 
-        @pl.when((j + 1 == n_blocks) & (b + 1 < nb))
-        def _():  # prefetch the NEXT program's first block
-            for d in dmas((parity0 + n_blocks) % 2, b + 1, 0):
+        @pl.when((j + 1 == n_blocks) & (i + 1 < n_live))
+        def _():  # prefetch the NEXT LIVE program's first block
+            for d in dmas((parity0 + n_blocks) % 2, i + 1, 0):
                 d.start()
 
-        for d in dmas(slot, b, j):
+        for d in dmas(slot, i, j):
             d.wait()
         if write:
             pl.when(j + 1 == n_blocks)(lambda: write_row(slot))
@@ -408,20 +426,45 @@ def _kvattn_dynlen_kernel(
         return m_new, l, acc
 
     m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
+    # A slot that is not live read nothing: its sum is 0 over 0, and what
+    # reaches the residual is zeros.
+    l = jnp.where(live, l, 1.0)
     o_ref[0] = (acc / l[..., None]).astype(o_ref.dtype)
     if write:
-        @pl.when(b + 1 == nb)
+        @pl.when(i + 1 == n_live)
         def _():
             for d in row_writes(0, 0):
                 d.wait()
 
 
+def _live_first(live: jax.Array | None, b: int):
+    """(order [b] int32, n_live): the live slots in slot order, then the
+    others. A rank by prefix sums and a one-hot sum, not a sort: the tick
+    asks once a layer."""
+    slots = jnp.arange(b, dtype=jnp.int32)
+    if live is None:
+        return slots, jnp.int32(b)
+    live = live.astype(bool)
+    ahead = jnp.cumsum(live, dtype=jnp.int32)  # live slots up to and with b
+    n_live = ahead[-1]
+    rank = jnp.where(live, ahead - 1, n_live + slots - ahead)
+    order = jnp.sum(
+        jnp.where(rank[:, None] == slots[None, :], slots[:, None], 0), axis=0
+    )
+    return order.astype(jnp.int32), n_live
+
+
 def dynlen_block(max_len: int) -> int:
-    """Largest of (512, 256, 128, 64, 8) dividing the pool length — the
-    M-block granularity of the dynamic-length read (skipping works at
-    block granularity; smaller blocks skip more but issue more DMAs)."""
+    """Largest of (512, 256, 128, 64, 8) dividing the pool length, and a
+    512 only where the pool holds four of them — the M-block granularity
+    of the dynamic-length read (skipping works at block granularity;
+    smaller blocks skip more but issue more DMAs). A pool of 1,024 reads
+    by 256: behind a prompt window of 512 a block of 512 is half the pool
+    and every slot fetches all of it from its first tick. Read on the
+    chip at that pool, a call: 113.9 us by 256, 120.1 by 512, 137.2 by
+    128 (PERF.md §5, PR 47); longer pools were not read and keep 512."""
     for mb in (512, 256, 128, 64, 8):
-        if max_len % mb == 0:
+        if max_len % mb == 0 and (mb <= 256 or 4 * mb <= max_len):
             return mb
     return 0  # no tiling → caller must fall back
 
@@ -436,6 +479,7 @@ def int8_decode_attention_dynlen(
     *,
     layer: jax.Array | int | None = None,
     rows: tuple[jax.Array, jax.Array, jax.Array, jax.Array] | None = None,
+    live: jax.Array | None = None,
     block: int | None = None,
     interpret: bool | None = None,
 ):
@@ -459,8 +503,14 @@ def int8_decode_attention_dynlen(
     and returns (attn, ck_q, ck_s, cv_q, cv_s), the pools aliased to the
     ones passed in — bit for bit what
     ``c.at[layer, b, :, pos[b]].set(row)`` on each, then the read, gives.
-    ``pos`` must lie inside the pool (the kernel clamps it to M - 1: a
-    DMA has no bounds check, where a scatter drops what is out of range).
+    ``pos`` must lie inside the pool (the call clamps it to M - 1: a DMA
+    has no bounds check, where a scatter drops what is out of range).
+
+    With ``live`` ([B] bool; all live without it) a slot that is not live
+    costs no HBM traffic: nothing of its pool is fetched, its row (with
+    ``rows``) is not written, so that its pool stays as it was, and its
+    ``attn`` is zeros. A live slot's result and pool row are what they
+    are without the mask.
 
     Exact w.r.t. the scale-folded read restricted to valid positions
     (flash-style online softmax; differential-tested against
@@ -472,14 +522,14 @@ def int8_decode_attention_dynlen(
     pool = (ck_q, ck_s.astype(jnp.float32), cv_q, cv_s.astype(jnp.float32))
     shapes = [c.shape for c in pool]
     if layer is None:
-        base = jnp.zeros((1,), jnp.int32)
+        base = jnp.int32(0)
     else:
         if ck_q.ndim != 5 or ck_q.shape[1] != b:
             raise ValueError(
                 f"layer= takes the stacked pool [L, {b}, K, M, Dh], got "
                 f"{ck_q.shape}"
             )
-        base = (jnp.asarray(layer, jnp.int32) * b).reshape(1)
+        base = jnp.asarray(layer, jnp.int32) * b
         pool = tuple(c.reshape(-1, *c.shape[2:]) for c in pool)
     n_kv, m = pool[0].shape[1:3]
     rep = h // n_kv
@@ -489,12 +539,22 @@ def int8_decode_attention_dynlen(
     if interpret is None:
         interpret = _default_interpret()
     qg = q[:, 0].reshape(b, n_kv, rep, dh)
+    # What the programs read from SMEM, in program order: the slot each
+    # serves, its watermark (inside the pool) and its first block's buffer.
+    order, n_live = _live_first(live, b)
+    pos = jnp.clip(pos.astype(jnp.int32), 0, m - 1)[order]
+    blocks = jnp.where(jnp.arange(b) < n_live, (pos + mb) // mb, 0)
+    parity = (jnp.cumsum(blocks) - blocks) % 2
     # SEQUENTIAL grid ("arbitrary"): the cross-program prefetch scheme
     # relies on program i+1's first block being DMA'd by program i, so
     # the order must be the textual one.
     kw = {} if interpret else tpu_compiler_params(("arbitrary",))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    q_spec = pl.BlockSpec((1, n_kv, rep, dh), lambda i, pos, base: (i, 0, 0, 0))
+    # Program i serves slot ``order[i]``: every per-slot block goes through
+    # the order.
+    q_spec = pl.BlockSpec(
+        (1, n_kv, rep, dh), lambda i, pos, base, order, par: (order[i], 0, 0, 0)
+    )
     in_specs = [q_spec]
     operands = [qg]
     out_specs = q_spec
@@ -516,8 +576,12 @@ def int8_decode_attention_dynlen(
         rg = 32 if mb % 32 == 0 else mb
         lg = 128 if mb % 128 == 0 else mb
         in_specs += [
-            pl.BlockSpec((1, n_kv, 1, dh), lambda i, pos, base: (i, 0, 0, 0)),
-            pl.BlockSpec((1, n_kv, 1), lambda i, pos, base: (i, 0, 0)),
+            pl.BlockSpec(
+                (1, n_kv, 1, dh), lambda i, pos, base, order, par: (order[i], 0, 0, 0)
+            ),
+            pl.BlockSpec(
+                (1, n_kv, 1), lambda i, pos, base, order, par: (order[i], 0, 0)
+            ),
         ] * 2
         operands += [
             kq.astype(jnp.int8)[:, :, None, :], ks.astype(jnp.float32)[..., None],
@@ -534,11 +598,11 @@ def int8_decode_attention_dynlen(
             pltpu.VMEM((n_kv, lg), jnp.float32),       # staged v scales
             pltpu.SemaphoreType.DMA((4,)),
         ]
-        # Operand numbers count the two scalar-prefetch arguments: the
-        # pool is operands 7..10 and outputs 1..4.
-        kw["input_output_aliases"] = {7 + i: 1 + i for i in range(4)}
+        # Operand numbers count the four scalar-prefetch arguments: the
+        # pool is operands 9..12 and outputs 1..4.
+        kw["input_output_aliases"] = {9 + i: 1 + i for i in range(4)}
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=4,
         grid=(b,),
         in_specs=in_specs + [any_spec] * 4,
         out_specs=out_specs,
@@ -546,7 +610,7 @@ def int8_decode_attention_dynlen(
     )
     out = pl.pallas_call(
         functools.partial(
-            _kvattn_dynlen_kernel, mb=mb, max_len=m,
+            _kvattn_dynlen_kernel, mb=mb,
             inv_sqrt_dh=float(1.0 / np.sqrt(dh)), rg=rg, lg=lg,
         ),
         grid_spec=grid_spec,
@@ -554,7 +618,8 @@ def int8_decode_attention_dynlen(
         interpret=interpret,
         name="tk_kvattn_dynlen",
         **kw,
-    )(pos.astype(jnp.int32), base, *operands, *pool)
+    )(pos, jnp.stack([base, n_live]), order, parity.astype(jnp.int32),
+      *operands, *pool)
     if rows is None:
         return out.reshape(b, 1, h, dh)
     attn, *pool = out
@@ -582,6 +647,7 @@ def int8_decode_attention_dynlen_sharded(
     *,
     layer: jax.Array | int,
     rows: tuple[jax.Array, jax.Array, jax.Array, jax.Array] | None = None,
+    live: jax.Array | None = None,
     block: int | None = None,
     interpret: bool | None = None,
 ):
@@ -598,7 +664,8 @@ def int8_decode_attention_dynlen_sharded(
     L merges with the SHARD's slots inside it: an unsharded L cannot
     merge with a ``data``-sharded B outside. With ``rows`` each shard
     writes its own slots' and heads' rows, and the pools come back under
-    the specs they came in with."""
+    the specs they came in with. ``live`` splits over ``data`` like
+    ``pos``: each shard orders its own slots."""
     from jax.sharding import PartitionSpec as P
 
     bspec = "data" if "data" in mesh.shape else None
@@ -607,8 +674,11 @@ def int8_decode_attention_dynlen_sharded(
     cspec = P(None, bspec, tp, None, None)   # [L, B, K, M, Dh] payloads
     sspec = P(None, bspec, tp, None)         # [L, B, K, M] scales
     pool_specs = (cspec, sspec, cspec, sspec)
-    in_specs = (qspec, *pool_specs, P(bspec), P())
-    args = (q, ck_q, ck_s, cv_q, cv_s, pos, jnp.asarray(layer, jnp.int32))
+    if live is None:
+        live = jnp.ones(pos.shape, bool)
+    in_specs = (qspec, *pool_specs, P(bspec), P(bspec), P())
+    args = (q, ck_q, ck_s, cv_q, cv_s, pos, live,
+            jnp.asarray(layer, jnp.int32))
     out_specs = qspec
     if rows is not None:
         rspec = P(bspec, tp, None)           # [B, K, Dh] fresh payloads
@@ -617,10 +687,10 @@ def int8_decode_attention_dynlen_sharded(
         args += tuple(rows)
         out_specs = (qspec, *pool_specs)
 
-    def read(q, ck_q, ck_s, cv_q, cv_s, pos, layer, *rows):
+    def read(q, ck_q, ck_s, cv_q, cv_s, pos, live, layer, *rows):
         return int8_decode_attention_dynlen(
             q, ck_q, ck_s, cv_q, cv_s, pos, layer=layer, rows=rows or None,
-            block=block, interpret=interpret,
+            live=live, block=block, interpret=interpret,
         )
 
     fn = jax.shard_map(

@@ -186,7 +186,7 @@ def _quant_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 def _slot_layer_step_q(
     x, layer, ck_q, ck_s, cv_q, cv_s, l, pos_b, cfg, use_kernel=False,
-    mesh=None,
+    mesh=None, act=None,
 ):
     """int8-KV variant of ``_slot_layer_step``, over the same STACKED pool
     at layer index ``l`` (written in place, read at ``l``): the pool stores
@@ -215,10 +215,12 @@ def _slot_layer_step_q(
         # [l, b, :, pos_b[b]]: merged into the tile the kernel fetched,
         # whose aligned group it writes back. As four XLA scatters, one
         # update after another, that write cost more than the read
-        # (PERF.md, PR 30). Every slot writes, active or not
-        # (``tick_block``'s note on stale rows). A DMA has no bounds check
-        # where a scatter drops: the tick's latch holds
-        # pos_b <= P + max_new - 2 < M, and the kernel clamps. Under a
+        # (PERF.md, PR 30). A slot that is not live (``act``: idle, or
+        # latched done inside this block, by EOS or at its answer budget)
+        # costs the kernel no HBM traffic: nothing fetched, no row written,
+        # zeros out (``tick_block``'s note on such slots). A DMA has no
+        # bounds check where a scatter drops: the tick's latch holds
+        # pos_b <= P + max_new - 2 < M, and the call clamps. Under a
         # mesh the call runs per (data, tp) shard inside shard_map, each
         # shard over its own slots and kv heads (the capability probe
         # gated the divisibilities).
@@ -231,11 +233,13 @@ def _slot_layer_step_q(
         with xprof.scope(xprof.SCOPE_KV_READ):
             if mesh is not None:
                 attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen_sharded(
-                    q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh, layer=l, rows=fresh
+                    q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh, layer=l,
+                    rows=fresh, live=act,
                 )
             else:
                 attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen(
-                    q, ck_q, ck_s, cv_q, cv_s, pos_b, layer=l, rows=fresh
+                    q, ck_q, ck_s, cv_q, cv_s, pos_b, layer=l, rows=fresh,
+                    live=act,
                 )
         x = _attn_tail(x, attn, layer, cfg)
     else:
@@ -368,8 +372,10 @@ class ServeMetrics:
         self.window_positions_read = RateMeter()
         self.full_positions_valid = RateMeter()
         self.full_positions_read = RateMeter()
-        self.output_capped = RateMeter()  # slots force-finished by a
-        # per-record output budget (max_new_of) at sync granularity
+        self.output_capped = RateMeter()  # slots finished by a per-record
+        # output budget (max_new_of) below max_new, and not by EOS: latched
+        # by the tick on the device, or cut by the host's clamp at the sync
+        # where a tick program does not take the budget
         # Paged prefix cache (kv_pages=, torchkafka_tpu/kvcache): all zero
         # on the dense path.
         self.prefix_hits = RateMeter()  # admissions that reused cached blocks
@@ -1541,13 +1547,26 @@ class StreamingGenerator:
             journal.set_model_version(self._model_version)
         # Per-record output budget: ``max_new_of(record) -> n`` bounds
         # that record's generation to n tokens (clamped to [1, max_new]).
-        # Enforced host-side at sync granularity: when a slot's emitted
-        # count reaches its budget it is force-finished exactly like a
-        # device ``done`` (output truncated to the budget, slot freed,
-        # journal finished) — the static tick program never changes, so
-        # heavy-tailed per-record output lengths (workload generation,
-        # user-requested max_tokens) cost nothing when None.
+        # ``_slot_budget`` holds it a slot (``max_new`` where there is
+        # none), set where a record is attached to its slot
+        # (``_attach_record``), and the tick takes it as an operand: a slot
+        # latches done at its budget ON THE DEVICE, as at EOS or a full
+        # buffer, so no tick decodes a token the host would cut (and the
+        # dense int8 kernel fetches nothing for the slot from then on).
+        # The host's clamp at the sync (``_retire_block``) stays as the
+        # guard for tick programs that do not take the operand (the
+        # speculative server's): there a block may overshoot by up to
+        # ticks_per_sync - 1 tokens and the overshoot is truncated.
         self._max_new_of = max_new_of
+        # The rows the dense int8 pool's read fetches at a time, for the
+        # ``full_positions_*`` meters (_build; 0: no such pool).
+        self._kv_read_block = 0
+        self._slot_budget = np.full((slots,), max_new, np.int32)
+        self._slot_budget_dev = None  # the device's copy; None: stale
+        # Whether ``_tick_fn`` / ``_tick_chunk_fn`` take the budget as a
+        # trailing operand: _build and _build_paged say so, a subclass
+        # that installs tick programs of its own does not.
+        self._tick_takes_budget = False
         self._resume_hints: dict[tuple[str, int, int], JournalEntry] = {}
         self._journal_ready: list[tuple[Record, np.ndarray]] = []
         self._slot_emitted = np.zeros((slots,), np.int64)
@@ -1781,7 +1800,8 @@ class StreamingGenerator:
 
         K = self._ticks_per_sync
 
-        def tick_block(params, caches, last_tok, pos, gen, active_in, skey):
+        def tick_block(params, caches, last_tok, pos, gen, active_in, skey,
+                       budget=None):
             """K chained decode ticks in ONE dispatch (static K), with a
             LATCHED done mask: a slot that completes at inner tick j is
             masked out of ticks j+1..K, so its output cannot be clobbered.
@@ -1790,8 +1810,13 @@ class StreamingGenerator:
             uint32 per-slot RECORD keys; tick t of slot b draws at fold
             index ``pos_b - P + 1`` (token 0 was the admit draw), so the
             sampled stream is a pure function of (record, index) — the
-            warm-failover exactness contract."""
+            warm-failover exactness contract. ``budget``: [B] int32, the
+            tokens each slot's answer may have (1..max_new; ``max_new``
+            for every slot where none is passed): a slot completes at it
+            as at a full buffer."""
             caches, last_tok, pos, gen = pin_state(caches, last_tok, pos, gen)
+            if budget is None:
+                budget = jnp.full((B,), self._max_new, jnp.int32)
 
             def one(carry, _):
                 caches, last_tok, pos, gen, done_latch, n_out, stats = carry
@@ -1820,7 +1845,7 @@ class StreamingGenerator:
                     elif kv_int8:
                         x, *caches = _slot_layer_step_q(
                             x, layer, *caches, l, pos, cfg,
-                            use_kernel=kv_kernel, mesh=mesh,
+                            use_kernel=kv_kernel, mesh=mesh, act=act,
                         )
                     else:
                         x, *caches, routing = _slot_layer_step(
@@ -1894,14 +1919,19 @@ class StreamingGenerator:
                     first += n
                 logits = head_logits(params, cfg, x, 0)
                 tok = pick_rows(logits, skey, pos - P + 1)
-                # Inactive slots write stale kv at their frozen position —
-                # safe: re-admission overwrites [0, P) via prefill and every
+                # A slot that is not live (idle, or latched done: by EOS,
+                # at its budget or at a full buffer) still runs the static
+                # tick; what it computes is never read. Through the XLA
+                # reads it writes stale kv at its frozen position — safe:
+                # re-admission overwrites [0, P) via prefill and every
                 # later position is rewritten by the tick that reaches it
                 # BEFORE the attention that could read it. Freezing the
                 # caches with a jnp.where would copy the pool every token,
                 # which nothing in this program does: every write is a
-                # scatter, or the read kernel's own row write, into the
-                # carried pool.
+                # scatter into the carried pool. The dense int8 kernel,
+                # which owns its row write, takes ``act`` and does nothing
+                # for such a slot: no fetch, no write (its pool stays as it
+                # was, the same promise), zeros into the residual.
                 t = pos - P  # decode ticks completed before this one
                 idx = jnp.minimum(t + 1, self._max_new - 1)
                 # One-hot select over the tiny [B, max_new] buffer
@@ -1914,13 +1944,12 @@ class StreamingGenerator:
                     else jnp.zeros_like(act)
                 )
                 # Tokens after this tick = t + 2 (prefill's token 0 plus
-                # t+1 decode outputs); complete on EOS or a full buffer.
-                done_now = act & (hit_eos | (t + 2 >= self._max_new))
+                # t+1 decode outputs); complete on EOS or at the budget (a
+                # full buffer where the request carries none).
+                done_now = act & (hit_eos | (t + 2 >= budget))
                 pos = jnp.where(act & ~done_now, pos + 1, pos)
                 last_tok = jnp.where(act, tok, last_tok)
-                n_out = jnp.where(
-                    done_now, jnp.minimum(t + 2, self._max_new), n_out
-                )
+                n_out = jnp.where(done_now, jnp.minimum(t + 2, budget), n_out)
                 done_latch = done_latch | done_now
                 return (caches, last_tok, pos, gen, done_latch, n_out, stats), None
 
@@ -1984,6 +2013,7 @@ class StreamingGenerator:
         # program instead of referencing the resident device buffers.
         _admit = jax.jit(admit, donate_argnums=(1,))
         _tick = jax.jit(tick_block, donate_argnums=(1,))
+        self._tick_takes_budget = True
         # Raw (un-jitted) body for decode_roofline's fori-chained windows.
         self._tick_block_raw = tick_block
 
@@ -2057,21 +2087,24 @@ class StreamingGenerator:
                 "bytes_window": sum(c.nbytes for c in self._caches[2:]),
                 "bytes_full": sum(c.nbytes for c in self._caches[:2]),
             }
-        elif kv_int8 and kv_kernel:
-            # K-major pool for the Pallas read (see _slot_layer_step_q).
-            self._caches = (
-                jnp.zeros((nl, B, kh, M, dh), jnp.int8),
-                jnp.zeros((nl, B, kh, M), jnp.float32),
-                jnp.zeros((nl, B, kh, M, dh), jnp.int8),
-                jnp.zeros((nl, B, kh, M), jnp.float32),
-            )
         elif kv_int8:
-            self._caches = (
-                jnp.zeros((nl, B, M, kh, dh), jnp.int8),
-                jnp.zeros((nl, B, M, kh), jnp.float32),
-                jnp.zeros((nl, B, M, kh, dh), jnp.int8),
-                jnp.zeros((nl, B, M, kh), jnp.float32),
+            # K-major for the Pallas read (see _slot_layer_step_q), which
+            # fetches a live slot's rows by blocks; position-major for the
+            # XLA read, whose block is the slab.
+            from torchkafka_tpu.ops.kvattn import dynlen_block
+
+            rows = (kh, M) if kv_kernel else (M, kh)
+            self._caches = tuple(
+                jnp.zeros((nl, B, *rows, *tail), dtype)
+                for _ in "kv" for tail, dtype in (((dh,), jnp.int8), ((), jnp.float32))
             )
+            self._kv_read_block = dynlen_block(M) if kv_kernel else M
+            self.metrics.kv_pool_static = {
+                "full_layers": nl,
+                "bytes_full": sum(c.nbytes for c in self._caches),
+                "read": "kernel" if kv_kernel else "xla",
+                "block": self._kv_read_block,
+            }
         else:
             self._caches = (
                 jnp.zeros((nl, B, M, kh, dh), cfg.dtype),
@@ -2316,10 +2349,11 @@ class StreamingGenerator:
         ti = self._paged_table_idx
 
         def decode_bookkeep(logits, skey, act, last_tok, pos, gen,
-                            done_latch, n_out):
+                            done_latch, n_out, budget):
             """The decode tick's sampling/EOS/position bookkeeping over
             per-slot logits — identical to the dense tick body's tail
-            (see the dense ``tick_block`` on the one-hot gen write)."""
+            (see the dense ``tick_block`` on the one-hot gen write and on
+            ``budget``)."""
             tok = pick_rows(logits, skey, pos - P + 1)
             t = pos - P  # decode ticks completed before this one
             idx = jnp.minimum(t + 1, self._max_new - 1)
@@ -2329,12 +2363,10 @@ class StreamingGenerator:
                 (tok == self._eos_id) if self._eos_id is not None
                 else jnp.zeros_like(act)
             )
-            done_now = act & (hit_eos | (t + 2 >= self._max_new))
+            done_now = act & (hit_eos | (t + 2 >= budget))
             pos = jnp.where(act & ~done_now, pos + 1, pos)
             last_tok = jnp.where(act, tok, last_tok)
-            n_out = jnp.where(
-                done_now, jnp.minimum(t + 2, self._max_new), n_out
-            )
+            n_out = jnp.where(done_now, jnp.minimum(t + 2, budget), n_out)
             done_latch = done_latch | done_now
             return last_tok, pos, gen, done_latch, n_out
 
@@ -2347,7 +2379,8 @@ class StreamingGenerator:
             _device_table), so the write can never corrupt a block
             another slot holds (pinned by the stale-tail regression in
             tests/test_kvcache.py)."""
-            last_tok, pos, gen, done_latch, n_out, active_in, skey = carry
+            (last_tok, pos, gen, done_latch, n_out, active_in, skey,
+             budget) = carry
             act = active_in & ~done_latch
             x = embed_rows(params["embed"], last_tok, cfg.dtype)[:, None, :]
             x, pools = layer_pass(
@@ -2357,21 +2390,26 @@ class StreamingGenerator:
             x = _rms_norm(x, params["ln_f"])
             logits = logits_head(params, x[:, 0])
             last_tok, pos, gen, done_latch, n_out = decode_bookkeep(
-                logits, skey, act, last_tok, pos, gen, done_latch, n_out
+                logits, skey, act, last_tok, pos, gen, done_latch, n_out,
+                budget,
             )
             return pools, (
                 last_tok, pos, gen, done_latch, n_out, active_in, skey,
+                budget,
             )
 
-        def tick_block(params, caches, last_tok, pos, gen, active_in, skey):
+        def tick_block(params, caches, last_tok, pos, gen, active_in, skey,
+                       budget=None):
             """K decode-only ticks in ONE dispatch — the dense
             tick_block's K-chained latched-done structure over the paged
-            pool. The table passes through the donated state
-            unchanged."""
+            pool, ``budget`` included. The table passes through the
+            donated state unchanged."""
             pools, table = caches[:ti], caches[ti]
             pools, last_tok, pos, gen = pin_paged(pools, last_tok, pos, gen)
             done0 = jnp.zeros((B,), bool)
             n0 = jnp.zeros((B,), jnp.int32)
+            if budget is None:
+                budget = jnp.full((B,), self._max_new, jnp.int32)
 
             def one(carry, _):
                 pools, rest = carry
@@ -2381,7 +2419,7 @@ class StreamingGenerator:
             (pools, rest), _ = lax.scan(
                 one,
                 (tuple(pools), (last_tok, pos, gen, done0, n0, active_in,
-                                skey)),
+                                skey, budget)),
                 None, length=K,
             )
             last_tok, pos, gen, done, n_out = rest[:5]
@@ -2393,7 +2431,8 @@ class StreamingGenerator:
         C = self._prefill_chunk
 
         def tick_chunk_block(params, caches, last_tok, pos, gen, active_in,
-                             skey, ctok, ctable, cpos, fin_mask, fin_row):
+                             skey, ctok, ctable, cpos, fin_mask, fin_row,
+                             budget=None):
             """THE fused tick: one static program carrying a bounded
             prefill chunk alongside all decode slots. The first inner
             tick concatenates the B decode rows with the C chunk rows
@@ -2426,6 +2465,8 @@ class StreamingGenerator:
             pools, last_tok, pos, gen = pin_paged(pools, last_tok, pos, gen)
             done0 = jnp.zeros((B,), bool)
             n0 = jnp.zeros((B,), jnp.int32)
+            if budget is None:
+                budget = jnp.full((B,), self._max_new, jnp.int32)
             act = active_in
             toks_all = jnp.concatenate([pull_replicated(last_tok), ctok])
             x = embed_rows(params["embed"], toks_all, cfg.dtype)[:, None, :]
@@ -2440,7 +2481,8 @@ class StreamingGenerator:
             logits_all = logits_head(params, x[:, 0])  # [B + C, V]
             chunk_logits = logits_all[B:]
             last_tok, pos, gen, done, n_out = decode_bookkeep(
-                logits_all[:B], skey, act, last_tok, pos, gen, done0, n0
+                logits_all[:B], skey, act, last_tok, pos, gen, done0, n0,
+                budget,
             )
 
             def one(carry, _):
@@ -2451,7 +2493,7 @@ class StreamingGenerator:
             (pools, rest), _ = lax.scan(
                 one,
                 (tuple(pools), (last_tok, pos, gen, done, n_out, active_in,
-                                skey)),
+                                skey, budget)),
                 None, length=K - 1,
             )
             last_tok, pos, gen, done, n_out = rest[:5]
@@ -2468,6 +2510,7 @@ class StreamingGenerator:
             )
 
         _tick = jax.jit(tick_block, donate_argnums=(1,))
+        self._tick_takes_budget = True
         self._tick_jit = _tick
         self._tick_block_raw = tick_block
         self._tick_fn = lambda *a: _tick(self._params, *a)
@@ -2993,7 +3036,7 @@ class StreamingGenerator:
                 crash_hook("decode_adopt_pre_activate")
                 cacheable = RadixCache.matchable_blocks(len(toks), bs)
                 self._kv_radix.insert(toks, row[:cacheable])
-                self._slot_rec[i] = rec
+                self._attach_record(i, rec)
                 key_np = (
                     np.asarray(hand.key_data, np.uint32)
                     if hand.key_data else kd
@@ -3029,7 +3072,7 @@ class StreamingGenerator:
             else:
                 self.metrics.prefix_misses.add(1)
                 self.metrics.tenant_prefix_misses(tenant).add(1)
-            self._slot_rec[i] = rec
+            self._attach_record(i, rec)
             key_np = (
                 np.asarray(hint.key_data, np.uint32)
                 if hint is not None and hint.key_data is not None else kd
@@ -3344,12 +3387,13 @@ class StreamingGenerator:
                 none, key, jnp.zeros((C,), jnp.int32),
                 jnp.full((C, nblk), SINK_BLOCK, jnp.int32),
                 jnp.zeros((C,), jnp.int32), none,
-                jnp.zeros((B,), jnp.int32),
+                jnp.zeros((B,), jnp.int32), *self._tick_budget(),
             )
             self._caches, self._last_tok, self._pos, self._gen = out[:4]
             jax.device_get(out[4])
             out = self._tick_fn(
-                self._caches, self._last_tok, self._pos, self._gen, none, key
+                self._caches, self._last_tok, self._pos, self._gen, none, key,
+                *self._tick_budget(),
             )
             self._caches, self._last_tok, self._pos, self._gen = out[:4]
             jax.device_get(out[4])
@@ -3359,7 +3403,8 @@ class StreamingGenerator:
             jnp.zeros((B, self._prompt_len), jnp.int32), none, key,
         )
         out = self._tick_fn(
-            self._caches, self._last_tok, self._pos, self._gen, none, key
+            self._caches, self._last_tok, self._pos, self._gen, none, key,
+            *self._tick_budget(),
         )
         self._caches, self._last_tok, self._pos, self._gen = out[:4]
         jax.device_get(out[4])
@@ -3691,6 +3736,30 @@ class StreamingGenerator:
             top_p=self._top_p, model_version=self._model_version,
         )
 
+    def _attach_record(self, i: int, rec: Record) -> None:
+        """Slot ``i`` serves ``rec`` from here on: the record and its
+        answer budget (``max_new_of``, held to [1, max_new]; ``max_new``
+        where there is none), which the next tick block takes."""
+        self._slot_rec[i] = rec
+        budget = self._max_new_of(rec) if self._max_new_of is not None else None
+        budget = (
+            self._max_new if budget is None
+            else max(1, min(int(budget), self._max_new))
+        )
+        if budget != self._slot_budget[i]:
+            self._slot_budget[i] = budget
+            self._slot_budget_dev = None
+
+    def _tick_budget(self) -> tuple:
+        """The tick programs' trailing operand: the slots' budgets on the
+        device, sent again only after an admission changed one (no
+        transfer a tick); nothing for tick programs that take none."""
+        if not self._tick_takes_budget:
+            return ()
+        if self._slot_budget_dev is None:
+            self._slot_budget_dev = jnp.asarray(self._slot_budget.copy())
+        return (self._slot_budget_dev,)
+
     def _resume_into_slot(self, i: int, rec: Record, prompt_toks,
                           hint: JournalEntry, key_np: np.ndarray) -> None:
         """Dense warm resume: one prefill dispatch of prompt + journaled
@@ -3707,7 +3776,7 @@ class StreamingGenerator:
             jnp.asarray(seq), jnp.int32(i), jnp.asarray(row), jnp.int32(g),
         )
         self._caches, self._last_tok, self._pos, self._gen = out
-        self._slot_rec[i] = rec
+        self._attach_record(i, rec)
         self._active[i] = True
         self._slot_emitted[i] = g
         self._slot_journaled[i] = g
@@ -3795,7 +3864,7 @@ class StreamingGenerator:
                     journal_dirty = journal_dirty or self._journal is not None
                     continue
                 prompts[i] = toks
-                self._slot_rec[i] = rec
+                self._attach_record(i, rec)
                 admit_mask[i] = True
                 self._active[i] = True
                 self._slot_emitted[i] = 0
@@ -4004,6 +4073,7 @@ class StreamingGenerator:
                             self._slot_keys, jnp.asarray(ctok),
                             jnp.asarray(ctable), jnp.asarray(cpos),
                             jnp.asarray(fin_mask), jnp.asarray(fin_row),
+                            *self._tick_budget(),
                         )
                     )
                 self.metrics.chunk_ticks.add(1)
@@ -4017,6 +4087,7 @@ class StreamingGenerator:
                     caches, last_tok, pos, gen, done, n_out = self._tick_fn(
                         self._caches, self._last_tok, self._pos, self._gen,
                         jnp.asarray(self._active.copy()), self._slot_keys,
+                        *self._tick_budget(),
                     )
             self._caches, self._last_tok, self._pos, self._gen = (
                 caches, last_tok, pos, gen
@@ -4082,27 +4153,35 @@ class StreamingGenerator:
         decoded = 0
         first_tokens = 0  # slots surfacing their admission's own token
         rows_needed = 0  # cached rows the served ticks read, a layer
+        rows_fetched = 0  # and those the dense int8 read fetched for them
         ring_needed = 0  # and a window layer, of its ring
         ring = self._cfg.sliding_window
+        kv_block = self._kv_read_block  # 0: no dense int8 pool
         for i in np.nonzero(self._active)[0]:
             cnt = int(
                 n_out_h[i] if done_h[i]
                 else pos_h[i] - self._prompt_len + 1
             )
-            if self._max_new_of is not None:
-                budget = self._max_new_of(self._slot_rec[i])
-                if budget is not None:
-                    budget = max(1, min(int(budget), self._max_new))
-                    if cnt >= budget:
-                        # Budget reached (tick blocks may overshoot
-                        # by up to ticks_per_sync - 1 tokens; the
-                        # overshoot is truncated): force-finish this
-                        # slot exactly like a device done.
-                        cnt = budget
-                        if not done_h[i]:
-                            self.metrics.output_capped.add(1)
-                        done_h[i] = True
-                        n_out_h[i] = budget
+            ran = cnt  # tokens of the ticks the device held the slot live
+            budget = int(self._slot_budget[i])
+            if done_h[i] and cnt == budget:
+                # Latched at its budget by the tick: capped unless the
+                # budget is the buffer or the last token is the EOS.
+                if budget < self._max_new and not (
+                    self._eos_id is not None
+                    and gen_h[i, cnt - 1] == self._eos_id
+                ):
+                    self.metrics.output_capped.add(1)
+            elif cnt >= budget:
+                # The guard for a tick program that does not take the
+                # budget: its blocks may overshoot by up to
+                # ticks_per_sync - 1 tokens; the overshoot is truncated
+                # and the slot force-finished exactly like a device done.
+                cnt = budget
+                if not done_h[i]:
+                    self.metrics.output_capped.add(1)
+                done_h[i] = True
+                n_out_h[i] = budget
             new_toks = cnt - int(self._slot_emitted[i])
             decoded += new_toks
             first_tokens += int(self._slot_emitted[i] == 0)
@@ -4115,6 +4194,12 @@ class StreamingGenerator:
                 ring_needed += int(np.minimum(
                     self._prompt_len + np.arange(j0, cnt), ring
                 ).sum())
+            if kv_block:
+                # A live tick fetches whole blocks up to its row (a slot
+                # whose budget is 1 is live for the one tick that latches
+                # it); a slot that is not live fetches nothing.
+                j = self._prompt_len + np.arange(j0, max(ran, j0 + (cnt == 1)))
+                rows_fetched += int((-(-j // kv_block) * kv_block).sum())
             if self._tracer is not None and new_toks > 0:
                 self._tracer.tokens(
                     self._slot_rec[i], new_toks,
@@ -4145,6 +4230,13 @@ class StreamingGenerator:
             self.metrics.latent_positions_read.add(
                 blocks * self._slots * self._ticks_per_sync * self._max_len
             )
+        if kv_block:
+            n_full = self._cfg.n_layers
+            if not self._kv_kernel:
+                # The XLA read fetches every slot's slab, every tick.
+                rows_fetched = self._slots * self._ticks_per_sync * kv_block
+            self.metrics.full_positions_valid.add(rows_needed * n_full)
+            self.metrics.full_positions_read.add(rows_fetched * n_full)
         if self._cfg.linear_pattern and not self._cfg.is_mla:
             # The attention layers' K and V rows, as a pool by kind's full
             # layers: the XLA read fetches every slot's slab, every tick.
