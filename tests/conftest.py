@@ -155,77 +155,3 @@ def _stale_chipbench_modules_see_the_per_layer_of_their_pr(request, monkeypatch)
             return (bench, *rest)
 
         monkeypatch.setattr(runner, "load_cell", load_cell)
-
-
-def pytest_collection_modifyitems(config, items):
-    """Budget-aware ordering: the tier-1 wall-clock budget (ROADMAP's
-    870 s `timeout`) is nearly saturated by the long-standing suites, so
-    the NEWEST differential suites (PR 14: tiered cache + disaggregated
-    prefill) and the newest harness scenario are scheduled LAST — a
-    budget overrun on a slow box truncates the newest coverage first,
-    never the seed regression surface. Within the tail, cheap host-only
-    property tests run before jit-compiling differentials so the most
-    coverage survives whatever slack the box leaves. The full suites run
-    unconditionally outside the tier-1 timeout (plain `pytest tests/`,
-    `-m chaos`, CI without `-m 'not slow'`)."""
-    tail_modules = ("test_tier.py", "test_disagg.py")
-    tail_tests = ("test_scenario_21_disaggregated_prefill_kill_storm",)
-    # ISSUE-15 coverage is the newest: its jit-heavy pieces run after
-    # even scenario 21, so a budget overrun truncates them first. The
-    # pure-python controller/race units are sub-second and ride the
-    # cheap rank.
-    newest_tests = ("test_scenario_22_autoscaled_step_storm",)
-    newest_module = "test_autoscale.py"
-    # ISSUE-17 coverage is newer still: the quorum failover storm runs
-    # near-last so a budget overrun truncates it before anything older.
-    quorum_tests = ("test_scenario_23_quorum_leader_failover",)
-    # ISSUE-18 coverage: the rollout differential suite and the
-    # hot-swap canary scenario.
-    rollout_module = "test_rollout.py"
-    rollout_tests = ("test_scenario_24_rolling_hot_swap",)
-    # ISSUE-19 coverage is the newest of all: the online-distillation
-    # differential suite and the closed-loop scenario run dead last.
-    distill_module = "test_distill.py"
-    distill_tests = ("test_scenario_25_online_draft_distillation",)
-
-    def tail_rank(item):
-        path = str(getattr(item, "fspath", ""))
-        if item.name in distill_tests:
-            return 10
-        if path.endswith(distill_module):
-            # Wire/controller/policy units are host-only (no jit) —
-            # cheap; the trainer/fleet differentials compile — rank 9.
-            cheap = (
-                "TestDistillWire" in item.nodeid
-                or "TestDistillController" in item.nodeid
-            )
-            return 1 if cheap else 9
-        if item.name in rollout_tests:
-            return 8
-        if path.endswith(rollout_module):
-            return 7
-        if item.name in quorum_tests:
-            return 6
-        if item.name in newest_tests:
-            return 5
-        if path.endswith(newest_module):
-            # Controller/race units are host-only (no jit) — cheap; the
-            # in-process scale_to differential compiles — last.
-            return 1 if "TestServingFleetScaleTo" not in item.nodeid else 4
-        if item.name in tail_tests:
-            return 3
-        if path.endswith(tail_modules):
-            # Host-only property/plumbing tests first (sub-second),
-            # jit-heavy serving differentials after.
-            cheap = (
-                "TestHostTier" in item.nodeid
-                or "TestTieredRadixProperty" in item.nodeid
-                or "test_wire_roundtrip" in item.nodeid
-                or "test_admission_queue_routes" in item.nodeid
-                or "test_prefill_role_validation" in item.nodeid
-                or "test_config_validation" in item.nodeid
-            )
-            return 1 if cheap else 2
-        return 0
-
-    items.sort(key=tail_rank)

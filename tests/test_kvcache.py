@@ -968,7 +968,7 @@ class TestBackendCapabilityErrors:
     def test_row_write_follows_the_dense_kernel(self, kv_dtype, kv_kernel,
                                                 row_write):
         """Who writes a tick's new rows: the dense int8 pool's Pallas read
-        where it engages (``serve._slot_layer_step_q``'s ``use_kernel``
+        where it engages (``slot_pool._slot_layer_step_q``'s ``use_kernel``
         branch is this same flag), XLA's scatters everywhere else."""
         from torchkafka_tpu.kvcache import resolve_kv_backend
         from torchkafka_tpu.models import TransformerConfig

@@ -266,9 +266,6 @@ REFUSALS = {
     "quantize_params": lambda c, p: __import__(
         "torchkafka_tpu.models.quant", fromlist=["x"]
     ).quantize_params(p, c),
-    "decode_roofline": lambda c, p: _build(c, p).decode_roofline(
-        peak_hbm_gbs=819.0
-    ),
 }
 STATE = "linear-attention layers"
 REASONS = {

@@ -29,7 +29,8 @@ from torchkafka_tpu.models.transformer import (
     make_train_step,
 )
 from torchkafka_tpu.ops import moe
-from torchkafka_tpu.serve import StreamingGenerator, _slot_layer_step_latent
+from torchkafka_tpu.kvcache.slot_pool import _slot_layer_step_latent
+from torchkafka_tpu.serve import StreamingGenerator
 
 P, NEW, VOCAB = 8, 8, 64
 TOL = 2e-5
@@ -549,9 +550,6 @@ REFUSALS = {
     "make_train_step": (
         lambda c, p: make_train_step(c, _mesh2(), None),
         "make_train_step is not built"),
-    "decode_roofline": (
-        lambda c, p: _server(c, p)[0].decode_roofline(peak_hbm_gbs=819.0),
-        "K/V pool bytes"),
     "kv_kernel=True": (
         lambda c, p: _server(c, p, kv_kernel=True), "no Pallas read is built"),
 }

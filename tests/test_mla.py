@@ -23,7 +23,8 @@ from torchkafka_tpu.models.generate import generate, latent_forward, prefill
 from torchkafka_tpu.models.transformer import (
     Transformer, TransformerConfig, _rope, init_params, make_train_step,
 )
-from torchkafka_tpu.serve import StreamingGenerator, _slot_layer_step_latent
+from torchkafka_tpu.kvcache.slot_pool import _slot_layer_step_latent
+from torchkafka_tpu.serve import StreamingGenerator
 
 P, NEW, VOCAB = 8, 8, 64
 TOL = 2e-5
@@ -222,9 +223,6 @@ REFUSALS = {
     ).SpecStreamingGenerator(None, p, c, slots=2, prompt_len=P, max_new=NEW),
     "generate": lambda c, p: generate(p, c, jnp.zeros((1, P), jnp.int32), 4),
     "make_train_step": lambda c, p: make_train_step(c, _mesh2(), None),
-    "decode_roofline": lambda c, p: _server(c, p)[0].decode_roofline(
-        peak_hbm_gbs=819.0
-    ),
     "kv_kernel=True": lambda c, p: _server(c, p, kv_kernel=True),
     "no roped key": lambda c, p: latent_cfg(qk_rope_dim=0),
     "interleave without latent attention": lambda c, p: TransformerConfig(
@@ -250,7 +248,6 @@ REASONS = {
     "speculative": "speculative serving is not built",
     "generate": "lockstep decode is not built",
     "make_train_step": "make_train_step is not built",
-    "decode_roofline": "K/V pool bytes",
     "kv_kernel=True": "no Pallas read is built",
     "no roped key": "even qk_rope_dim",
     "interleave without latent attention": "latent attention alone",

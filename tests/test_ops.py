@@ -336,7 +336,7 @@ class TestInt8DecodeAttentionKernel:
 
         from torchkafka_tpu.models import generate
         from torchkafka_tpu.ops.kvattn import int8_decode_attention_dynlen
-        from torchkafka_tpu.serve import _quant_kv
+        from torchkafka_tpu.kvcache.slot_pool import _quant_kv
 
         rng = np.random.default_rng(2)
         B, M, K, Dh = 4, 32, 2, 16
@@ -374,7 +374,7 @@ class TestInt8DecodeAttentionKernel:
         several-block slots, so that the kernel's buffer parity and its
         prefetch of the NEXT slot's first block (which crosses from one
         slot's rows into the next's) both matter."""
-        from torchkafka_tpu.serve import _quant_kv
+        from torchkafka_tpu.kvcache.slot_pool import _quant_kv
 
         rng = np.random.default_rng(seed)
         q = jnp.asarray(rng.normal(size=(B, 1, K * rep, Dh)), jnp.float32)
@@ -475,7 +475,7 @@ class TestInt8DecodeAttentionKernel:
 
     @staticmethod
     def _fresh_rows(B=4, K=2, Dh=16, seed=9):
-        from torchkafka_tpu.serve import _quant_kv
+        from torchkafka_tpu.kvcache.slot_pool import _quant_kv
 
         rng = np.random.default_rng(seed)
         k, v = jnp.asarray(rng.normal(size=(2, B, K, Dh)) * 3, jnp.float32)

@@ -438,44 +438,6 @@ class TestStreamingGenerator:
                 float(line.rsplit(" ", 1)[1])
         consumer.close()
 
-    def test_decode_roofline_accounting(self, model):
-        """decode_roofline must measure the real tick program (chained
-        dispatches) and report self-consistent byte/bandwidth accounting;
-        the server must stay usable afterwards (donated pool rebound)."""
-        cfg, params = model
-        broker = tk.InMemoryBroker()
-        _topic(broker, 4)
-        consumer = tk.MemoryConsumer(broker, "p", group_id="g")
-        server = StreamingGenerator(
-            consumer, params, cfg, slots=2, prompt_len=P, max_new=MAX_NEW,
-        )
-        server.warmup()
-        # Off-chip the peak is the caller's to give: the device-kind
-        # lookup knows chips only, and this device is the CPU.
-        with pytest.raises(ValueError, match="no published peaks"):
-            server.decode_roofline(iters=2, windows=2)
-        r = server.decode_roofline(iters=2, windows=2, peak_hbm_gbs=819.0)
-        # The slope between the two windows can be ~0/negative for a toy
-        # model on CPU (both windows are dispatch noise); a degenerate
-        # slope must be FLAGGED (numeric fields None), never published as
-        # floored values.
-        if r["slope_ok"]:
-            assert r["device_tick_ms"] >= 0
-            if r["device_tick_ms"] > 1e-3:
-                assert r["device_tok_s"] == pytest.approx(
-                    2 / (r["device_tick_ms"] / 1e3), rel=0.01
-                )
-        else:
-            assert r["device_tick_ms"] is None
-            assert r["hbm_roofline_pct"] is None
-        total = r["weight_bytes"] + r["kv_pool_bytes"]
-        assert r["roofline_tok_s"] == pytest.approx(
-            2 * r["peak_hbm_gbs"] * 1e9 / total, rel=0.01
-        )
-        # Still serves after the measurement.
-        got = list(server.run(max_records=4))
-        assert len(got) == 4
-
     def test_rejects_bad_config(self, model):
         cfg, params = model
         consumer = object()
@@ -498,25 +460,6 @@ class TestStreamingGenerator:
                 object(), params, cfg, prompt_len=P, max_new=MAX_NEW,
                 kv_dtype="int8", kv_kernel=bad,
             )
-
-    def test_decode_roofline_restores_pos(self, model):
-        """ADVICE r5 #2: the 'mid' fill probe overwrote self._pos for
-        every slot and never put it back, corrupting in-flight
-        generations — the probe must restore the entry positions."""
-        cfg, params = model
-        broker = tk.InMemoryBroker()
-        _topic(broker, 4)
-        consumer = tk.MemoryConsumer(broker, "p", group_id="grp")
-        server = StreamingGenerator(
-            consumer, params, cfg, slots=2, prompt_len=P, max_new=MAX_NEW,
-        )
-        server.warmup()
-        before = np.asarray(server._pos).copy()
-        server.decode_roofline(iters=1, windows=1, peak_hbm_gbs=819.0)
-        np.testing.assert_array_equal(np.asarray(server._pos), before)
-        # And still serves correctly afterwards.
-        assert len(list(server.run(max_records=4))) == 4
-        consumer.close()
 
 
 class TestOutputTopic:
@@ -737,7 +680,7 @@ class TestInt8KV:
     bf16 path is deliberately given up (documented)."""
 
     def test_quant_roundtrip_error_bound(self):
-        from torchkafka_tpu.serve import _quant_kv
+        from torchkafka_tpu.kvcache.slot_pool import _quant_kv
 
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(4, 32, 2, 16)) * 3.0, jnp.float32)
@@ -921,7 +864,7 @@ def _ref_layer_step_q(x, layer, ckq, cks, cvq, cvs, pos_b, cfg, kernel, mesh):
     )
     from torchkafka_tpu.models.transformer import _rope
     from torchkafka_tpu.ops.kvattn import int8_decode_attention_dynlen
-    from torchkafka_tpu.serve import _quant_kv
+    from torchkafka_tpu.kvcache.slot_pool import _quant_kv
 
     q, k, v = _project_qkv(x, layer, cfg)
     q = _rope(q, pos_b[:, None], cfg.rope_theta)
@@ -1226,7 +1169,8 @@ def _ref_admit(srv, params, caches, last_tok, pos, gen, prompts, admit_mask,
     from jax import lax
 
     from torchkafka_tpu.models.generate import prefill
-    from torchkafka_tpu.serve import _pick_slots, _quant_kv
+    from torchkafka_tpu.kvcache.slot_pool import _quant_kv
+    from torchkafka_tpu.serve import _pick_slots
 
     cfg, P_, B = srv._cfg, srv._prompt_len, srv._slots
     logits, fresh = prefill(params, cfg, prompts, srv._max_len, srv._mesh)

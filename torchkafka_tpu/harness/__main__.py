@@ -17,7 +17,7 @@ def main() -> None:
     ap.add_argument("--size", choices=("tiny", "full"), default="tiny")
     ap.add_argument("--model-scale", choices=("45m", "1b", "8b"), default=None,
                     help="serving scenarios (5/7) only: serve the zoo model "
-                    "at this scale (8b = int8) with HBM roofline accounting")
+                    "at this scale (8b = int8)")
     ap.add_argument("--serve-eos", action="store_true",
                     help="scenario 7 at a model scale: EOS ON with 8-tick "
                     "blocks — the continuous-batching row (slots readmit "

@@ -606,9 +606,9 @@ def scenario_5(
     """Prompt topic → KV-cache generation → commit offsets only after the
     whole generation retires (BASELINE config 5; no reference analog).
     ``model_scale`` (45m | 1b | 8b) serves the zoo models at true HBM
-    footprint and adds device-side decode timing with an HBM roofline %
-    (prefill measured separately — it is compute-bound, decode is
-    bandwidth-bound; folding them together hides which one you are)."""
+    footprint and adds device-side decode timing (prefill measured
+    separately — it is compute-bound, decode is bandwidth-bound; folding
+    them together hides which one you are)."""
     import time as _time
 
     import jax
@@ -662,9 +662,9 @@ def scenario_5(
     }
     if model_scale is not None and jax.default_backend() == "tpu":
         # Device-side split: prefill alone, then whole-generate, both as
-        # median-of-3 strict-fetch timings; decode tok/s and its roofline
-        # come from the difference. Large models run long enough per call
-        # that dispatch jitter is noise here.
+        # median-of-3 strict-fetch timings; decode tok/s comes from the
+        # difference. Large models run long enough per call that dispatch
+        # jitter is noise here.
         toks_dev = jnp.asarray(
             rng.integers(0, cfg.vocab_size, (batch, prompt_len)), jnp.int32
         )
@@ -681,19 +681,9 @@ def scenario_5(
             int(jax.device_get(out[0, 0]))
             gen_times.append(_time.perf_counter() - t0)
         pf_s, gen_s = float(np.median(pf_times)), float(np.median(gen_times))
-        from torchkafka_tpu.serve import decode_tick_bytes
-        from torchkafka_tpu.utils.devices import device_peaks
-
-        w_bytes, kv_bytes = decode_tick_bytes(
-            params, cfg, batch, prompt_len + max_new
-        )
-        roofline_tok_s = (
-            batch * device_peaks().hbm_bytes_s / (w_bytes + kv_bytes)
-        )
         extra.update({
             "device_prefill_ms": round(pf_s * 1e3, 1),
             "device_generate_ms": round(gen_s * 1e3, 1),
-            "roofline_tok_s": round(roofline_tok_s, 1),
         })
         decode_s = gen_s - pf_s
         if decode_s <= 0.25 * gen_s:
@@ -703,21 +693,13 @@ def scenario_5(
             # under the fixed cost (the 45M scale: both walls read about
             # the fixed cost and the delta is jitter). Flag unless decode
             # dominates the generate wall, like two_point_slope's
-            # slope_ok — scenario 7's fori-chained decode_roofline is the
-            # robust decode number at every scale.
-            extra.update({
-                "split_ok": False,
-                "device_decode_tok_s": None,
-                "hbm_roofline_pct": None,
-            })
+            # slope_ok (the benchmark's traced ``tick_ms.tput`` is the
+            # robust decode number at every scale).
+            extra.update({"split_ok": False, "device_decode_tok_s": None})
         else:
-            decode_tok_s = batch * max_new / decode_s
             extra.update({
                 "split_ok": True,
-                "device_decode_tok_s": round(decode_tok_s, 1),
-                "hbm_roofline_pct": round(
-                    100 * decode_tok_s / roofline_tok_s, 1
-                ),
+                "device_decode_tok_s": round(batch * max_new / decode_s, 1),
             })
     return _result("5:generate", rows, elapsed, stream, extra)
 
@@ -738,11 +720,11 @@ def scenario_7(
     completion through the interval ledger. (No reference analog.)
 
     ``model_scale`` (45m | 1b | 8b): serve the zoo models at true HBM
-    footprint, adding ``decode_roofline`` — pure device decode tok/s
-    against the HBM-bandwidth bound, the serving analog of MFU. EOS is
-    off at scale BY DEFAULT (every slot runs full max_new, one dispatch
-    per generation — the throughput ceiling, directly comparable to the
-    roofline); ``serve_eos=True`` (--serve-eos) turns it ON at scale with
+    footprint (the decode tick against the HBM-bandwidth bound is the
+    benchmark's to read: ``kvattn.roofline_pct``, ``tick_ms.tput``). EOS
+    is off at scale BY DEFAULT (every slot runs full max_new, one dispatch
+    per generation — the throughput ceiling); ``serve_eos=True``
+    (--serve-eos) turns it ON at scale with
     ``ticks_per_sync=8``, so completed slots readmit MID-generation-block
     — the continuous-batching row (VERDICT r4 weak #4), with
     ``readmissions`` counting slots refilled while others were in
@@ -846,18 +828,6 @@ def scenario_7(
             f"{_wt.perf_counter() - _t0:.1f}s",
             file=sys.stderr, flush=True,
         )
-    # No roofline probe on the spec server: it runs LIVE speculative
-    # rounds, which would pollute the measured acceptance counters (and
-    # its byte accounting is target-only — see serve_spec._build).
-    roofline = (
-        server.decode_roofline()
-        if model_scale is not None and not spec
-        and jax.default_backend() == "tpu"
-        else {}
-    )
-    if roofline:
-        print(f"[scale {model_scale}] roofline: {roofline}",
-              file=sys.stderr, flush=True)
     toks = 0
     done = 0
     truncated = 0
@@ -895,7 +865,6 @@ def scenario_7(
         "commit_failures": server.metrics.commit_failures.count,
         "dropped": server.metrics.dropped.count,
         "commit": server.metrics.commit_latency.summary(),
-        **roofline,
     }
 
 

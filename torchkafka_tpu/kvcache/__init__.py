@@ -9,7 +9,8 @@ miss just re-prefills, token-exactness never depends on the cache);
 ``PagedKVConfig`` — the ``StreamingGenerator(kv_pages=...)`` knob;
 ``resolve_kv_backend`` — the single capability probe deciding how the
 four cache axes (dense/paged × compute/int8 × gather/kernel ×
-single-device/mesh) compose for one server (kvcache/backend.py).
+single-device/mesh) compose for one server (kvcache/backend.py); what the
+layout it names means for the dense server is kvcache/slot_pool.py's.
 """
 
 from torchkafka_tpu.kvcache.backend import (

@@ -38,6 +38,10 @@ wrapped in ``shard_map`` (``ops.kvattn.*_sharded`` — the
 ``flash_attention_sharded`` precedent: batch/head-parallel attention
 needs no collectives, so each (data, tp) shard runs the kernel over
 its own slots and heads).
+
+What a layout of the dense server MEANS (its tensors and shardings, an
+admission's rows, a tick's layer walk and step, its meters) is one class
+of ``kvcache/slot_pool.py``, which ``KVBackend.layout`` selects.
 """
 
 from __future__ import annotations
@@ -49,7 +53,7 @@ __all__ = ["KVBackend", "resolve_kv_backend"]
 # Pool length at/above which kv_kernel="auto" engages the Pallas reads:
 # the kernels' advantage grows with pool bytes while their fixed
 # in-tick cost does not. The threshold dates from a machine that is
-# gone and has no benchmark cell on its short side (ROADMAP C5).
+# gone and has no benchmark cell on its short side (ROADMAP C4).
 KV_KERNEL_AUTO_MIN_POOL = 1024
 
 
@@ -83,7 +87,9 @@ class KVBackend:
     who writes a decode tick's new rows into the pool — "kernel" where
     the dense int8 pool's Pallas read does it itself
     (``ops.kvattn.int8_decode_attention_dynlen`` with ``rows=``),
-    "scatter" for XLA's scatters on every other path."""
+    "scatter" for XLA's scatters on every other path. ``partner``
+    (layout "state"): the attention layers' pool, "latent" or "kv" rows.
+    ``resumable`` (derived): can a PARTIAL journal hint warm-resume."""
 
     layout: str
     int8: bool
@@ -92,6 +98,7 @@ class KVBackend:
     chunked: bool
     data: int
     tp: int
+    partner: str | None = None
 
     @property
     def paged(self) -> bool:
@@ -103,9 +110,20 @@ class KVBackend:
 
     @property
     def row_write(self) -> str:
-        # The flag serve.py's dense int8 tick branches on (``use_kernel``).
+        # The flag slot_pool.Int8Pool's step branches on (``use_kernel``).
         wrote_in_read = self.layout == "dense" and self.int8 and self.kernel
         return "kernel" if wrote_in_read else "scatter"
+
+    @property
+    def resumable(self) -> bool:
+        # int8 pools never (exactness, the one contract warm resume keeps,
+        # was traded away); the latent pool, the pool by kind and the state
+        # have no spelling of the K/V resume prefill yet. Compute-dtype
+        # K/V: the paged path always (prompt + emitted tokens ride the
+        # chunk queue), the dense path unless the mesh has a data axis (its
+        # [1, S] resume prefill has no batch to shard). Else: cold replay.
+        exact_kv = self.layout in ("dense", "paged") and not self.int8
+        return exact_kv and (self.paged or self.data == 1)
 
     def describe(self) -> dict:
         """The ``ServeMetrics`` ``kv_backend`` info payload."""
@@ -231,6 +249,7 @@ def _resolve_state(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
     return KVBackend(
         layout="state", int8=False, kernel=False,
         kernel_disabled_reason=None, chunked=False, data=1, tp=1,
+        partner="latent" if getattr(cfg, "is_mla", False) else "kv",
     )
 
 

@@ -408,9 +408,9 @@ def _read_merged(q, slab_k, slab_v, valid, cfg):
 
 def _attn_tail(x, attn, layer, cfg):
     """Post-attention residual: output projection + the MLP block. Shared
-    by the bf16 cache read (``_attend_cached``), the int8-KV read
-    (serve._attend_cached_q8) and the latent read
-    (serve._slot_layer_step_latent), so the layer math has one
+    by the bf16 cache read (``_attend_cached``), the dense int8 kernel's
+    read (slot_pool._slot_layer_step_q) and the latent read
+    (slot_pool._slot_layer_step_latent), so the layer math has one
     definition."""
     return _attn_tail_routing(x, attn, layer, cfg)[0]
 

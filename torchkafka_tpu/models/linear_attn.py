@@ -11,8 +11,8 @@ channel, ``ops/kda.py``; "ssd", the Mamba-2 mixer with a scalar decay a
 head, ``ops/ssd.py``), and ``kv_lora_rank`` the ATTENTION of the others
 (latent, ``models/mla.py``, over one pool of latent rows; or, at 0,
 grouped-query attention over pools of K and V rows, the layer the dense
-paths have: ``Transformer._gqa_qkv`` here, ``serve._slot_layer_step`` in
-a tick). A group's tensors are stacked BY KIND
+paths have: ``Transformer._gqa_qkv`` here, ``slot_pool._slot_layer_step``
+in a tick). A group's tensors are stacked BY KIND
 (``transformer.scan_hybrid``): the norms and the MLP's over every layer
 of the group, a linear layer's own over the group's linear layers, an
 attention layer's over its attention layers.
@@ -328,7 +328,7 @@ def slot_layer_step(x, layer, linear: bool, row, caches, pos_b, act, cfg):
     pools: the latent pool [L, B, M, rank + rope]), ``row`` the layer's
     row in its kind's tensors. Returns (x, caches, routing [B, 1, top_k] |
     None). A grouped-query layer's token goes through the dense path's
-    own step (``serve._slot_layer_step``)."""
+    own step (``kvcache/slot_pool.py::_slot_layer_step``)."""
     states, tails, *pools = caches
     with tracing.scope(tracing.SCOPE_ATTN_PROJ):
         h = _rms_norm(x, layer["ln1"], cfg.norm_eps)

@@ -66,7 +66,7 @@ def quant_kv_groups(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     [..., Dh] → (int8 [..., Dh], f32 scale [...]) — one scale per
     (position, head) group, the KV-cache analog of ``quantize``'s
     per-output-channel weight scheme. Shared by the dense int8 slot
-    pool (serve._slot_layer_step_q) and the int8 PAGED pool (the block
+    pool (slot_pool._slot_layer_step_q) and the int8 PAGED pool (the block
     pools quantize each written position through the same groups, so
     int8-paged serving is token-exact vs int8-dense serving — the
     groups, not just the scheme, are identical)."""

@@ -103,7 +103,7 @@ def _multi_step(params, cfg, cache: KVCache, tokens, pos_b):
 
     Sibling implementations (update in step if the write/mask discipline
     changes): generate._layer_step (scalar-pos lockstep) and
-    serve._slot_layer_step (per-row S=1, the measured serving tick —
+    slot_pool._slot_layer_step (per-row S=1, the measured serving tick —
     kept separate so spec-decode changes can never shift its published
     numbers)."""
     b, s = tokens.shape
@@ -117,7 +117,7 @@ def _multi_step(params, cfg, cache: KVCache, tokens, pos_b):
         q, k, v = _project_qkv(x, layer, cfg)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
-        # Scatter writes (serve._slot_layer_step's r5 note: the vmapped
+        # Scatter writes (slot_pool._slot_layer_step's note: the vmapped
         # dynamic_update_slice lowering rewrites the whole pool per
         # layer; the scatter writes S rows per slot — measured +41%
         # tok/s on the 1B serving tick).

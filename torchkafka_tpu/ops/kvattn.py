@@ -142,7 +142,7 @@ def paged_scatter(
     point every entry at the sink block (kvcache.SINK_BLOCK), which no
     live table references — their unconditional frozen-position writes
     land there harmlessly (masking the write would cost a pool-sized
-    select per layer; serve._slot_layer_step's lesson)."""
+    select per layer; slot_pool._slot_layer_step's lesson)."""
     bs = pool.shape[1]
     blk = jnp.take_along_axis(table, positions // bs, axis=1)  # [B, S]
     off = positions % bs

@@ -63,20 +63,14 @@ from torchkafka_tpu.kvcache import (
     TierConfig,
     resolve_kv_backend,
 )
+from torchkafka_tpu.kvcache.slot_pool import make_slot_pool
 from torchkafka_tpu.resilience.crashpoint import crash_hook
 from torchkafka_tpu.models.generate import (
-    _attend_cached,
-    _attend_merged,
     _attn_tail,
-    _attn_tail_routing,
     _project_qkv,
     check_sampling_params,
     check_serving_mesh,
     head_logits,
-    kv_kmajor_scale_sharding,
-    kv_kmajor_sharding,
-    kv_scale_sharding,
-    kv_sharding,
     paged_pool_kmajor_sharding,
     paged_pool_sharding,
     paged_scale_kmajor_sharding,
@@ -85,20 +79,13 @@ from torchkafka_tpu.models.generate import (
     serving_shardings,
     slot_sharding,
 )
-from torchkafka_tpu.models import linear_attn
 from torchkafka_tpu.models.quant import embed_rows, load_weight
 from torchkafka_tpu.models.transformer import (
     TransformerConfig,
     _arch_refusal,
-    _double_layer,
-    _double_scan,
-    _layer_groups,
     _rms_norm,
     _rope,
     embed_tokens,
-    hybrid_groups,
-    scan_hybrid,
-    scan_periods,
 )
 from torchkafka_tpu.ops import moe
 from torchkafka_tpu.source.records import Record, TopicPartition
@@ -106,11 +93,6 @@ from torchkafka_tpu.utils import tracing as xprof
 from torchkafka_tpu.utils.metrics import Gauge, LatencyHistogram, RateMeter
 
 _logger = logging.getLogger(__name__)
-
-# The kv_kernel="auto" engagement threshold and every other which-
-# backend decision live in ONE place now: kvcache/backend.py
-# ``resolve_kv_backend`` — the capability probe _build/_build_paged
-# consume (and ServeMetrics surfaces as kv_backend info).
 
 # Prompt tokens one trip of the dense admission prefills (``_build::admit``
 # walks the admitted slots in chunks of ``_ADMIT_CHUNK_TOKENS // window``
@@ -120,31 +102,6 @@ _logger = logging.getLogger(__name__)
 # v5e's readings of 2,048, 3,072, 4,096 and 8,192 are in PERF.md, PR 28).
 # The program's shapes decide, never an option.
 _ADMIT_CHUNK_TOKENS = 3072
-
-
-def decode_tick_bytes(params, cfg: TransformerConfig, batch: int,
-                      max_len: int, kv_int8: bool = False) -> tuple[int, int]:
-    """(weight_bytes, kv_bytes) streamed from HBM per decode tick.
-
-    Weights: every layer tensor and the lm_head are read in full (the
-    logits matmul contracts the whole [D, V] head), but the EMBEDDING
-    table is a gather of one row per slot — counting the full [V, D]
-    table would overstate bytes/tick ~5-7% at zoo scales. KV: both cache
-    halves across all layers at the STATIC pool length (attention reads
-    the whole buffer; masking discards, it does not skip); ``kv_int8``
-    counts the quantized pool (1 byte/element + one f32 scale per
-    (layer, slot, position, head) group)."""
-    from torchkafka_tpu.models.quant import quantized_nbytes
-
-    total = quantized_nbytes(params)
-    embed = quantized_nbytes(params["embed"])
-    embed_rows_read = batch * (embed // max(cfg.vocab_size, 1))
-    groups = 2 * cfg.n_layers * batch * max_len * cfg.n_kv_heads
-    if kv_int8:
-        kv = groups * (cfg.head_dim + 4)  # int8 payload + f32 scale
-    else:
-        kv = groups * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
-    return total - embed + embed_rows_read, kv
 
 
 @xprof.scope(xprof.SCOPE_HEAD)
@@ -170,94 +127,6 @@ def _pick_slots(logits, key_data, idx, *, temperature, top_k, top_p):
             row, k, temperature=temperature, top_k=top_k, top_p=top_p
         )
     )(logits, keys)
-
-
-@xprof.scope(xprof.SCOPE_KV_WRITE)
-def _quant_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Symmetric absmax int8 over the last (head_dim) axis:
-    [..., Dh] → (int8 [..., Dh], f32 scale [...]). The shared
-    ``models.quant.quant_kv_groups`` scheme — the int8 PAGED pool
-    quantizes through the same (position, head) groups, which is what
-    keeps int8-paged serving token-exact vs int8-dense serving."""
-    from torchkafka_tpu.models.quant import quant_kv_groups
-
-    return quant_kv_groups(x)
-
-
-def _slot_layer_step_q(
-    x, layer, ck_q, ck_s, cv_q, cv_s, l, pos_b, cfg, use_kernel=False,
-    mesh=None, act=None,
-):
-    """int8-KV variant of ``_slot_layer_step``, over the same STACKED pool
-    at layer index ``l`` (written in place, read at ``l``): the pool stores
-    int8 payloads + per-(position, head) f32 absmax scales over Dh —
-    (Dh+4)/(2·Dh) ≈ 52% of bf16 pool bytes at Dh=128 — read through
-    ``_attend_cached``'s scale-folded mode (scales land on the small
-    score/prob tensors; the big operands carry only a cast). A capacity
-    lever: ~2× the slot/context headroom. Quantization error is bounded by
-    absmax/127 per group; OPT-IN because token-exactness vs the bf16 path is
-    deliberately given up."""
-    q, k, v = _project_qkv(x, layer, cfg)
-    q = _rope(q, pos_b[:, None], cfg.rope_theta)
-    k = _rope(k, pos_b[:, None], cfg.rope_theta)
-    kq, ks = _quant_kv(k[:, 0])  # [B, K, Dh] int8, [B, K]
-    vq, vs = _quant_kv(v[:, 0])
-    if use_kernel:
-        # Pallas DYNAMIC-LENGTH int8 decode attention (ops/kvattn.py; its
-        # docstring has the why of each point), which is this layer's
-        # WRITE as well as its read. The pool is K-MAJOR ([L, B, K, M, Dh]
-        # / [L, B, K, M]) so that the kernel batches its dots over (slot,
-        # head); per-slot watermarks are scalar-prefetched and the kernel
-        # DMAs M-blocks itself, so HBM traffic follows each slot's ACTUAL
-        # fill. It takes the pool WHOLE with the layer's index (``pool[l]``
-        # outside an opaque call would materialise the slab every layer)
-        # and returns it ALIASED to what came in, with this tick's rows at
-        # [l, b, :, pos_b[b]]: merged into the tile the kernel fetched,
-        # whose aligned group it writes back. As four XLA scatters, one
-        # update after another, that write cost more than the read
-        # (PERF.md, PR 30). A slot that is not live (``act``: idle, or
-        # latched done inside this block, by EOS or at its answer budget)
-        # costs the kernel no HBM traffic: nothing fetched, no row written,
-        # zeros out (``tick_block``'s note on such slots). A DMA has no
-        # bounds check where a scatter drops: the tick's latch holds
-        # pos_b <= P + max_new - 2 < M, and the call clamps. Under a
-        # mesh the call runs per (data, tp) shard inside shard_map, each
-        # shard over its own slots and kv heads (the capability probe
-        # gated the divisibilities).
-        from torchkafka_tpu.ops.kvattn import (
-            int8_decode_attention_dynlen,
-            int8_decode_attention_dynlen_sharded,
-        )
-
-        fresh = (kq, ks, vq, vs)
-        with xprof.scope(xprof.SCOPE_KV_READ):
-            if mesh is not None:
-                attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen_sharded(
-                    q, ck_q, ck_s, cv_q, cv_s, pos_b, mesh, layer=l,
-                    rows=fresh, live=act,
-                )
-            else:
-                attn, ck_q, ck_s, cv_q, cv_s = int8_decode_attention_dynlen(
-                    q, ck_q, ck_s, cv_q, cv_s, pos_b, layer=l, rows=fresh,
-                    live=act,
-                )
-        x = _attn_tail(x, attn, layer, cfg)
-    else:
-        # The XLA read has nothing to write inside: scatters, like the
-        # bf16 path (see _slot_layer_step's note), into the position-major
-        # pool, payload [L, B, M, K, Dh] and scale [L, B, M, K] alike.
-        rows = jnp.arange(ck_q.shape[1])
-        with xprof.scope(xprof.SCOPE_KV_WRITE):
-            ck_q, ck_s, cv_q, cv_s = (
-                c.at[l, rows, pos_b].set(row)
-                for c, row in ((ck_q, kq), (ck_s, ks), (cv_q, vq), (cv_s, vs))
-            )
-        valid = jnp.arange(ck_q.shape[2])[None, :] <= pos_b[:, None]  # [B, M]
-        x = _attend_cached(
-            x, q, _layer_of(ck_q, l), _layer_of(cv_q, l), valid, layer, cfg,
-            k_scale=_layer_of(ck_s, l), v_scale=_layer_of(cv_s, l),
-        )
-    return x, ck_q, ck_s, cv_q, cv_s
 
 
 class ServeMetrics:
@@ -775,128 +644,6 @@ def _fold_record_ids(rng, ids):
     return jax.vmap(one)(ids[0], ids[1], ids[2])
 
 
-def _layer_of(pool, l):
-    """Layer ``l``'s slab of a stacked pool, for a read XLA can see into:
-    the dynamic slice fuses into the read's first operation."""
-    return lax.dynamic_index_in_dim(pool, l, keepdims=False)
-
-
-def _slot_layer_step(x, layer, cache_k, cache_v, l, pos_b, cfg, kind=None):
-    """One decode token through layer ``l`` with a DIFFERENT position per
-    slot. x: [B, 1, D]; caches: the STACKED pool [L, B, M, K, Dh], which
-    the caller carries through its layer loop — written in place here, one
-    row per slot, and read at index ``l``; pos_b: [B]. Only the rope and
-    the cache write differ from the lockstep ``generate._layer_step``; the
-    attention/MLP tail is the shared ``_attend_cached``. (Sibling:
-    spec_decode._multi_step generalizes this to S queries per row —
-    update in step if the write/mask discipline changes.)
-
-    ``kind`` (``cfg.layer_kind(j)``, a config with kinds of layer): the
-    layer's ``(window or None, rope)``, the caches its KIND's pool, a
-    position's kv heads in one row [L, B, M, K * Dh]
-    (``generate.KindKVCache``), and ``l`` its row there. A window layer's
-    pool is a ring [Lw, B, W, K * Dh]: the row goes to ``pos mod W`` and
-    the read takes the rows ``< min(pos + 1, W)``, which hold the last W
-    positions in some order (keys are cached roped, so the order does not
-    matter). Returns (x, cache_k, cache_v, the routed expert layer's
-    choices [B, 1, top_k] or None)."""
-    window, rope = kind or (None, cfg.rope_theta)
-    q, k, v = _project_qkv(x, layer, cfg)
-    if cfg.use_rope:  # (a config without positions rotates nothing)
-        q = _rope(q, pos_b[:, None], rope)
-        k = _rope(k, pos_b[:, None], rope)
-    # Per-row cache write as a SCATTER (.at[l, rows, pos].set), not a masked
-    # select: the select rewrites the whole pool every layer while the
-    # scatter writes one row per slot. The scatter goes into the stacked
-    # pool and not into a layer's slab: a pool that is a scan's input and
-    # output is sliced, written back and copied whole every tick (PERF.md,
-    # PR 25); a carry is written in place.
-    rows = jnp.arange(cache_k.shape[1])
-    at, last = pos_b, pos_b
-    if window is not None:
-        at, last = pos_b % window, jnp.minimum(pos_b, window - 1)
-    with xprof.scope(xprof.SCOPE_KV_WRITE):
-        if kind is not None:  # a position's kv heads side by side in one row
-            k, v = (a.reshape(*a.shape[:2], -1) for a in (k, v))
-        cache_k = cache_k.at[l, rows, at].set(k[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[l, rows, at].set(v[:, 0].astype(cache_v.dtype))
-    valid = jnp.arange(cache_k.shape[2])[None, :] <= last[:, None]  # [B, M]
-    slabs = _layer_of(cache_k, l), _layer_of(cache_v, l)
-    if kind is None:
-        x, routing = _attend_cached(x, q, *slabs, valid, layer, cfg, routing=True)
-    else:
-        x, routing = _attend_merged(
-            x, q, *slabs, valid, layer, cfg,
-            xprof.SCOPE_KV_READ_FULL if window is None
-            else xprof.SCOPE_KV_READ_WINDOW,
-        )
-    return x, cache_k, cache_v, routing
-
-
-@xprof.scope(xprof.SCOPE_MOE_ROUTE)
-def _count_routing(stats, routing, act, cfg):
-    """(experts touched, pairs by expert[, pairs by fate]) with one expert
-    layer's routing [B, 1, top_k] of a tick added: a pair counts where
-    the device holds the slot active, an expert is touched where it got at
-    least one. Where the layer has zero-compute experts or holds a share
-    (``stats`` then has a third member), the load is over the HELD
-    experts and the fates are (zero, local, absent)."""
-    touched, load, *fates = stats
-    flat = routing.reshape(-1)
-    live = jnp.repeat(act, routing.shape[-1]).astype(load.dtype)
-    if not fates:
-        pairs = jnp.zeros_like(load).at[flat].add(live)
-        return touched + jnp.sum(pairs > 0), load + pairs
-    first, count = cfg.held_experts
-    zero = flat >= cfg.n_experts
-    local = (flat >= first) & (flat < first + count)
-    pairs = jnp.zeros_like(load).at[
-        jnp.where(local, flat - first, count)
-    ].add(live, mode="drop")
-    fate = jnp.stack([
-        jnp.sum(live * zero), jnp.sum(live * local),
-        jnp.sum(live * ~(zero | local)),
-    ])
-    return touched + jnp.sum(pairs > 0), load + pairs, fates[0] + fate
-
-
-def _slot_layer_step_latent(x, layer, pool, l, pos_b, cfg):
-    """``_slot_layer_step`` for a latent-attention config: the stacked pool
-    is ONE tensor [L, B, M, rank + rope]. The slot's row (the normed
-    latent beside the roped shared key, models/mla.py) is scattered into
-    layer ``l`` in place, and the read is ABSORBED: the heads' queries meet
-    the cached rows themselves, nothing is up-projected for the pool's
-    positions. The double layer (``attn_blocks`` 2) does so twice, block
-    ``i`` against pool row ``2l + i``, the pool ``[2L, ...]``. Returns (x,
-    pool, routing [B, 1, top_k] | None)."""
-    from torchkafka_tpu.models import mla
-
-    rows = jnp.arange(pool.shape[1])
-    if cfg.attn_blocks == 2:
-        held = [pool]
-
-        def attend(i, h, blk):
-            q_nope, q_rope, latent = mla.project(h, blk, cfg, pos_b[:, None])
-            with xprof.scope(xprof.SCOPE_KV_WRITE):
-                held[0] = held[0].at[2 * l + i, rows, pos_b].set(
-                    latent[:, 0].astype(pool.dtype)
-                )
-            return mla.attend_absorbed(
-                q_nope, q_rope, held[0], 2 * l + i, pos_b, blk, cfg
-            )
-
-        x, routing = _double_layer(x, layer, cfg, attend)
-        return x, held[0], routing
-    with xprof.scope(xprof.SCOPE_ATTN_PROJ):
-        h = _rms_norm(x, layer["ln1"])
-    q_nope, q_rope, latent = mla.project(h, layer, cfg, pos_b[:, None])
-    with xprof.scope(xprof.SCOPE_KV_WRITE):
-        pool = pool.at[l, rows, pos_b].set(latent[:, 0].astype(pool.dtype))
-    attn = mla.attend_absorbed(q_nope, q_rope, pool, l, pos_b, layer, cfg)
-    x, routing = _attn_tail_routing(x, attn, layer, cfg)
-    return x, pool, routing
-
-
 class _PendingPrefill:
     """One admission's queued chunk-prefill work (paged mode).
 
@@ -1195,7 +942,7 @@ class StreamingGenerator:
         is stored K-major ([L, B, K, M, Dh]) so every head's tile is a
         contiguous slice and the kernel's dots batch over heads with no
         relayout (ops/kvattn.py docstring); note the tick time is then
-        FILL-DEPENDENT (see ``decode_roofline``'s ``fill``).
+        FILL-DEPENDENT (the benchmark's ``kvattn.roofline_pct`` reads it).
 
         ``max_send_failure_streak``: a SYNCHRONOUS send failure leaves its
         record uncommitted (the watermark stalls there, it re-delivers on
@@ -1510,6 +1257,7 @@ class StreamingGenerator:
         self._prefill_queue: list[_PendingPrefill] = []
         self._tick_counter = 0
         self._paged_table_idx = 2  # the table's slot in the state tuple
+        self._kv_dtype = kv_dtype
         self._kv_int8 = kv_dtype == "int8"
         self._kv_kernel_opt = kv_kernel
         self._max_send_failure_streak = max_send_failure_streak
@@ -1558,9 +1306,6 @@ class StreamingGenerator:
         # speculative server's): there a block may overshoot by up to
         # ticks_per_sync - 1 tokens and the overshoot is truncated.
         self._max_new_of = max_new_of
-        # The rows the dense int8 pool's read fetches at a time, for the
-        # ``full_positions_*`` meters (_build; 0: no such pool).
-        self._kv_read_block = 0
         self._slot_budget = np.full((slots,), max_new, np.int32)
         self._slot_budget_dev = None  # the device's copy; None: stale
         # Whether ``_tick_fn`` / ``_tick_chunk_fn`` take the budget as a
@@ -1584,6 +1329,9 @@ class StreamingGenerator:
         # resolved KVBackend this server actually serves with — a paged
         # pool too small for one slot re-resolves as dense here.
         self._kv_backend: KVBackend | None = None
+        # The dense build's pool object (kvcache/slot_pool.py); None for the
+        # paged build and for a subclass that allocates its own state.
+        self._pool = None
         # What the last tick block's routed expert layers counted on the
         # device (_build); None for a config without one.
         self._tick_stats = None
@@ -1603,93 +1351,47 @@ class StreamingGenerator:
                 "prefill_role cannot fall back to dense serving — size "
                 "kv_pages to hold at least one slot"
             )
-        cfg = self._cfg
-        # A latent-attention config (models/mla.py) runs the same slot
-        # machinery over ONE cache tensor, [L, B, M, rank + rope] in the
-        # compute dtype; kvcache.resolve_kv_backend refuses every other
-        # combination with its reason.
-        # Linear layers beside attention layers (``linear_pattern``,
-        # models/linear_attn.py): slot memory by kind, a recurrent state
-        # in float32 ([L_lin, B, H, E, E] the delta rule's, [L_lin, B, H,
-        # P, N] the Mamba-2 mixer's) and a conv tail the linear layers
-        # (``linear_attn.slot_shapes``); the others the latent pool, or K and V
-        # rows a position [L_att, B, M, K * Dh] (a pool by kind's full
-        # layers, written and read by the dense path's own step).
-        hybrid = bool(cfg.linear_pattern)
-        latent = cfg.is_mla and not hybrid
-        # Kinds of layer (``window_pattern``): the same machinery over a
-        # pool allocated by kind, ``KindKVCache``'s four tensors: the full
-        # layers' K and V [Lf, B, M, K * Dh], the window layers' rings
-        # [Lw, B, W, K * Dh].
-        kinds = bool(cfg.window_pattern)
+        cfg, mesh = self._cfg, self._mesh
         B, P, M = self._slots, self._prompt_len, self._max_len
-        nl, kh, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        temp = self._temperature
-        mesh = self._mesh
-
-        kv_int8 = self._kv_int8
-        # The Pallas dyn-len read (ops/kvattn.py). The engagement
-        # decision (incl. the "auto" >= 1024-pool threshold — a Pallas
-        # call carries a flat cost, the K-major layout handling and a
-        # fusion break, that a short pool's read does not win back — and
-        # the per-mesh divisibilities the shard_map wrapping needs) is
-        # the capability probe's —
-        # kvcache.resolve_kv_backend — so dense and paged builds, and
-        # the metrics that surface the decision, share one rule. Under
-        # a mesh the kernel runs per (data, tp) shard inside shard_map
-        # (ops.kvattn.int8_decode_attention_dynlen_sharded, the
-        # flash_attention_sharded precedent); kv_kernel=True raised at
-        # construction if the combination cannot be honored.
-        self._kv_backend = resolve_kv_backend(
-            cfg, mesh=mesh, kv_dtype="int8" if kv_int8 else None,
+        # Which pool serves, and whether the Pallas dyn-len read engages, is
+        # the capability probe's decision (one rule for dense and paged
+        # builds and the metrics); what the layout it names MEANS is the
+        # pool object's (kvcache/slot_pool.py): nothing below branches on it.
+        self._kv_backend = backend = resolve_kv_backend(
+            cfg, mesh=mesh, kv_dtype=self._kv_dtype,
             kv_kernel=self._kv_kernel_opt, kv_pages=None, max_len=M,
             slots=B, backend=jax.default_backend(),
         )
-        kv_kernel = self._kv_backend.kernel
-        self._kv_kernel = kv_kernel
-
-        def pin_state(caches, last_tok, pos, gen):
-            """Pin the slot state's layouts inside the jitted programs so
-            the donate-and-rebind round trip keeps kv heads on tp and
-            slots on data, instead of whatever GSPMD first guesses. int8
-            pools carry 4D scale tensors [L, B, M, K] between the 5D
-            payloads — same axes minus head_dim; kernel mode stores both
-            K-MAJOR ([L, B, K, M, ·]), same axes transposed with the
-            layout."""
-            if mesh is None:
-                return caches, last_tok, pos, gen
-            if kv_kernel:
-                kv = kv_kmajor_sharding(mesh)
-                kvs = kv_kmajor_scale_sharding(mesh)
-            else:
-                kv = kv_sharding(mesh)
-                kvs = kv_scale_sharding(mesh)
-            row = slot_sharding(mesh)
-            return (
-                tuple(
-                    lax.with_sharding_constraint(c, kv if c.ndim == 5 else kvs)
-                    for c in caches
-                ),
-                lax.with_sharding_constraint(last_tok, row),
-                lax.with_sharding_constraint(pos, row),
-                lax.with_sharding_constraint(gen, slot_sharding(mesh, 2)),
-            )
-
-        pick_rows = functools.partial(
-            _pick_slots, temperature=temp, top_k=self._top_k,
-            top_p=self._top_p,
+        self._kv_kernel = backend.kernel
+        self._pool = pool = make_slot_pool(
+            cfg, backend, slots=B, max_len=M, mesh=mesh
+        )
+        # The slot state's layouts under a mesh, asked once: pinned inside
+        # the jitted programs (the donate-and-rebind round trip keeps kv
+        # heads on tp and slots on data, not whatever GSPMD first guesses)
+        # and where the initial state is placed.
+        placed = None if mesh is None else (
+            pool.shardings(), slot_sharding(mesh), slot_sharding(mesh),
+            slot_sharding(mesh, 2),
         )
 
-        # Rows a chunk of the admission prefills: what static shapes give
+        def pin_state(*state):
+            if placed is None:
+                return state
+            return jax.tree.map(lax.with_sharding_constraint, state, placed)
+
+        pick_rows = functools.partial(
+            _pick_slots, temperature=self._temperature, top_k=self._top_k,
+            top_p=self._top_p,
+        )
+        # Rows a chunk of the admission prefills, from the static shapes
         # (_ADMIT_CHUNK_TOKENS over the prompt window, the pool's slots at
-        # most). The prefill constrains its batch over ``data``, so under a
-        # mesh a chunk is a whole number of rows a data shard.
+        # most); under a mesh a whole number of rows a ``data`` shard.
         data = 1 if mesh is None else mesh.shape.get("data", 1)
         R = min(B, -(-max(1, _ADMIT_CHUNK_TOKENS // P) // data) * data)
         self._admit_chunk_rows = R
-        # A trip's R * P tokens go through the grouped expert matmul
-        # (ops/moe.py decides by the static shapes): the admit program
-        # then counts what its kernels multiplied, one more output.
+        # Where a trip's R * P tokens go through the grouped expert matmul
+        # (ops/moe.py decides), admit counts what its kernels multiplied.
         grouped = moe.grouped_form(cfg, R * P)
         held = cfg.held_experts  # of a share, the local pairs are counted
         counted = (held[1], held[0] if cfg.moe_partial else None)
@@ -1698,60 +1400,40 @@ class StreamingGenerator:
         # A tick's B tokens, by the same rule.
         self.metrics.tick_form = moe.expert_form(cfg, B)
 
-        # The state-space state [H, P, N] leaves the chunked scan's
-        # product laid out otherwise than the pool (P before H), and the
-        # compiler lays the POOL out again to take it: a copy of the whole
-        # state in and out of every admission (4.5 GiB at 128 slots: the
-        # program no longer fits the chip). Through a view with a slot's
-        # axes merged only one layout makes the view free, and the rows
-        # are laid out again instead. The K and V rows likewise (they
-        # leave the head-wise projections with the positions minor): each
-        # pool is still copied in and out of an admission, but through
-        # the view in a GiB less of temporaries (the figures compiled for
-        # a described v5e are in the benchmark's configuration file).
-        merged_put = hybrid and cfg.linear_kind == "ssd"
-
         def admit(params, caches, last_tok, pos, gen, prompts, admit_mask,
                   keys):
             """Prefill the admitted rows of the [B, P] prompt batch, R rows
-            a trip, and write each trip's rows into the pool where they
-            belong. prompts: [B, P] int32; admit_mask: [B] bool; keys:
-            [B, W] uint32 per-record key data (token 0 draws at index 0).
-
-            The admitted slots come first in ``order`` and the loop makes
-            ceil(admitted / R) trips, a value of the mask: a slot that is
-            mid-generation costs nothing. The pool is the loop's CARRY and
-            every write a dynamic-update-slice of one slot's prompt window
-            [0, P) (a later position is rewritten by the tick that reaches
-            it before the attention that could read it, see ``tick_block``);
-            nothing here selects over the pool. The last trip's pad rows
-            repeat its last real row: the same slot gets the same values
-            twice."""
+            a trip, and write each trip's rows into the pool. prompts:
+            [B, P] int32; admit_mask: [B] bool; keys: [B, W] uint32
+            per-record key data (token 0 draws at index 0). The admitted
+            slots come first in ``order`` and the loop makes ceil(admitted
+            / R) trips: a slot that is mid-generation costs nothing. The
+            pool is the loop's CARRY and every write a dynamic-update-slice
+            of one slot's prompt window [0, P); nothing here selects over
+            the pool. The last trip's pad rows repeat its last real row."""
             caches, last_tok, pos, gen = pin_state(caches, last_tok, pos, gen)
             order = jnp.argsort(~admit_mask, stable=True)
             count = admit_mask.sum(dtype=jnp.int32)
 
             @xprof.scope(xprof.SCOPE_KV_WRITE)
-            def put(pool, rows, slots):
+            def put(pool_t, rows, slots):
                 # rows [L, R, ...] over the window, in the pool's layout.
-                shape = pool.shape
-                if merged_put:
-                    # Through a view of the pool with a slot's axes merged
-                    # (a slot's window is the leading run of its numbers):
-                    # all of them, or for the state [H, P, N] all but the
-                    # minor one, whose tiles then lie where they lay (the
-                    # view is free; merged too, the view itself is a copy
-                    # of the state).
-                    keep = shape[-1:] if pool.ndim > 4 else ()
-                    pool = pool.reshape(*shape[:2], -1, *keep)
+                shape = pool_t.shape
+                if pool.merged_put:
+                    # Through a view with a slot's axes merged (its window
+                    # is the leading run of its numbers): all of them, or
+                    # for the state [H, P, N] all but the minor one, whose
+                    # tiles then lie where they lay (the view is free).
+                    keep = shape[-1:] if pool_t.ndim > 4 else ()
+                    pool_t = pool_t.reshape(*shape[:2], -1, *keep)
                     rows = rows.reshape(*rows.shape[:2], -1, *keep)
-                tail = (0,) * (pool.ndim - 2)
+                tail = (0,) * (pool_t.ndim - 2)
                 for r in range(R):
-                    pool = lax.dynamic_update_slice(
-                        pool, rows[:, r:r + 1].astype(pool.dtype),
+                    pool_t = lax.dynamic_update_slice(
+                        pool_t, rows[:, r:r + 1].astype(pool_t.dtype),
                         (0, slots[r], *tail),
                     )
-                return pool.reshape(shape)
+                return pool_t.reshape(shape)
 
             def chunk(i, state):
                 caches, last_tok, pos, gen, *counts = state
@@ -1761,25 +1443,11 @@ class StreamingGenerator:
                 )
                 if grouped:  # the routing [L_moe, R, P, top_k]
                     counts = [
-                        counts[0]
-                        + moe.grouped_counts(chosen[0], *counted)
+                        counts[0] + moe.grouped_counts(chosen[0], *counted)
                     ]
-                if latent:
-                    rows = (fresh,)  # [L, R, P, C]
-                elif kv_int8:
-                    rows = (*_quant_kv(fresh.k), *_quant_kv(fresh.v))
-                    if kv_kernel:
-                        # Kernel mode stores the pool K-major: transpose
-                        # the chunk's freshly-quantized [L, R, P, K, ·]
-                        # rows (the per-tick read this layout accelerates
-                        # runs max_new times an admission).
-                        with xprof.scope(xprof.SCOPE_KV_WRITE):
-                            rows = tuple(jnp.swapaxes(a, 2, 3) for a in rows)
-                else:
-                    # (k, v); by kind the full layers' window [0, P) and
-                    # the rings as P positions leave them.
-                    rows = tuple(fresh)
-                caches = tuple(put(c, a, slots) for c, a in zip(caches, rows))
+                caches = tuple(
+                    put(c, a, slots) for c, a in zip(caches, pool.rows(fresh))
+                )
                 tok0 = pick_rows(
                     logits, keys[slots], jnp.zeros((R,), jnp.int32)
                 )
@@ -1798,22 +1466,18 @@ class StreamingGenerator:
                 (caches, last_tok, pos, gen, *counts0),
             )
 
-        K = self._ticks_per_sync
-
         def tick_block(params, caches, last_tok, pos, gen, active_in, skey,
                        budget=None):
-            """K chained decode ticks in ONE dispatch (static K), with a
-            LATCHED done mask: a slot that completes at inner tick j is
-            masked out of ticks j+1..K, so its output cannot be clobbered.
-            One host sync per K tokens — per-token syncing costs a full
-            host↔device round trip per generated token. ``skey``: [B, W]
-            uint32 per-slot RECORD keys; tick t of slot b draws at fold
-            index ``pos_b - P + 1`` (token 0 was the admit draw), so the
-            sampled stream is a pure function of (record, index) — the
-            warm-failover exactness contract. ``budget``: [B] int32, the
-            tokens each slot's answer may have (1..max_new; ``max_new``
-            for every slot where none is passed): a slot completes at it
-            as at a full buffer."""
+            """K chained decode ticks in ONE dispatch (static K, one host
+            sync per K tokens), with a LATCHED done mask: a slot that
+            completes at inner tick j is masked out of ticks j+1..K.
+            ``skey``: [B, W] uint32 per-slot RECORD keys; tick t of slot b
+            draws at fold index ``pos_b - P + 1`` (token 0 was the admit
+            draw), so the sampled stream is a pure function of (record,
+            index), the warm-failover exactness contract. ``budget``: [B]
+            int32, the tokens each slot's answer may have (``max_new``
+            where none is passed): a slot completes at it as at a full
+            buffer."""
             caches, last_tok, pos, gen = pin_state(caches, last_tok, pos, gen)
             if budget is None:
                 budget = jnp.full((B,), self._max_new, jnp.int32)
@@ -1823,120 +1487,23 @@ class StreamingGenerator:
                 act = active_in & ~done_latch
                 with xprof.scope(xprof.SCOPE_EMBED):
                     x = embed_tokens(params, cfg, last_tok)[:, None, :]
-
-                # The pool rides the layer loop as its CARRY, as it rides
-                # the tick loop: each layer writes its rows into the
-                # stacked pool in place (a scatter, or the dense int8
-                # kernel's aliased write) and reads at its own index. A
-                # scan's xs and ys are two buffers: as those, every
-                # layer's slab is sliced out of one and written back into
-                # the other, and the pool is copied whole every tick to
-                # become the tick loop's carry again (PERF.md, PR 25).
-                def body(carry, inputs):
-                    x, caches, stats = carry
-                    layer, l = inputs
-                    if latent:
-                        x, pool, routing = _slot_layer_step_latent(
-                            x, layer, *caches, l, pos, cfg
-                        )
-                        caches = (pool,)
-                        if routing is not None:
-                            stats = _count_routing(stats, routing, act, cfg)
-                    elif kv_int8:
-                        x, *caches = _slot_layer_step_q(
-                            x, layer, *caches, l, pos, cfg,
-                            use_kernel=kv_kernel, mesh=mesh, act=act,
-                        )
-                    else:
-                        x, *caches, routing = _slot_layer_step(
-                            x, layer, *caches, l, pos, cfg
-                        )
-                        if routing is not None:
-                            stats = _count_routing(stats, routing, act, cfg)
-                    return (x, tuple(caches), stats), None
-
-                def kind_body(carry, layer, j, i):
-                    # Layer j of period i: its kind's pool, its row there.
-                    x, caches, stats = carry
-                    rank, count = cfg.kind_rank(j)
-                    at = 2 if cfg.window_pattern[j] else 0
-                    x, ck, cv, routing = _slot_layer_step(
-                        x, layer, caches[at], caches[at + 1],
-                        i * count + rank, pos, cfg, cfg.layer_kind(j),
-                    )
-                    caches = caches[:at] + (ck, cv) + caches[at + 2:]
-                    if routing is not None:
-                        stats = _count_routing(stats, routing, act, cfg)
-                    return (x, caches, stats), None
-
-                def hybrid_body(carry, layer, linear, row):
-                    # A linear or an attention layer: its row in its
-                    # kind's tensors; a slot that is not active keeps its
-                    # state. The grouped-query layer is the one the dense
-                    # path steps, over K and V rows as a pool by kind's.
-                    x, caches, stats = carry
-                    if linear or cfg.is_mla:
-                        x, caches, routing = linear_attn.slot_layer_step(
-                            x, layer, linear, row, caches, pos, act, cfg
-                        )
-                    else:
-                        x, ck, cv, routing = _slot_layer_step(
-                            x, layer, *caches[2:], row, pos, cfg,
-                            (None, cfg.rope_theta),
-                        )
-                        caches = (*caches[:2], ck, cv)
-                    if routing is not None:
-                        stats = _count_routing(stats, routing, act, cfg)
-                    return (x, caches, stats), None
-
-                # The layer index runs over the leading dense layers and
-                # then the expert layers (one group for every other config).
-                first = 0
-                if hybrid:
-                    # Every kind's tensors are the period scan's carry.
-                    for key, pattern, lin0, lat0 in hybrid_groups(cfg):
-                        (x, caches, stats), _ = scan_hybrid(
-                            cfg, params[key], pattern, (x, caches, stats),
-                            hybrid_body, lin0, lat0,
-                        )
-                for key, n, _expert_mlp in (
-                    () if hybrid else _layer_groups(cfg)
-                ):
-                    if kinds:
-                        # Both pools are the period scan's carry.
-                        (x, caches, stats), _ = scan_periods(
-                            cfg, params[key], (x, caches, stats), kind_body
-                        )
-                        continue
-                    xs, step = (params[key], jnp.arange(first, first + n)), body
-                    if cfg.attn_blocks == 2:
-                        # The blocks' tensors stay stacked: _double_scan.
-                        xs, layer_of = _double_scan(params[key], first)
-                        step = lambda c, s, f=layer_of: body(c, (f(s), s[1]))  # noqa: E731
-                    (x, caches, stats), _ = lax.scan(
-                        step, (x, caches, stats), xs
-                    )
-                    first += n
+                x, caches, stats = pool.tick_layers(
+                    params, x, caches, stats, pos, act
+                )
                 logits = head_logits(params, cfg, x, 0)
                 tok = pick_rows(logits, skey, pos - P + 1)
-                # A slot that is not live (idle, or latched done: by EOS,
-                # at its budget or at a full buffer) still runs the static
-                # tick; what it computes is never read. Through the XLA
-                # reads it writes stale kv at its frozen position — safe:
-                # re-admission overwrites [0, P) via prefill and every
-                # later position is rewritten by the tick that reaches it
-                # BEFORE the attention that could read it. Freezing the
-                # caches with a jnp.where would copy the pool every token,
-                # which nothing in this program does: every write is a
-                # scatter into the carried pool. The dense int8 kernel,
-                # which owns its row write, takes ``act`` and does nothing
-                # for such a slot: no fetch, no write (its pool stays as it
-                # was, the same promise), zeros into the residual.
+                # A slot that is not live (idle, or latched done) still runs
+                # the static tick; what it computes is never read. Through
+                # the XLA reads it writes stale kv at its frozen position:
+                # safe, re-admission overwrites [0, P) and every later
+                # position is rewritten by the tick that reaches it BEFORE
+                # the attention that could read it (a jnp.where freezing the
+                # caches would copy the pool every token). The dense int8
+                # kernel takes ``act`` and neither fetches nor writes for it.
                 t = pos - P  # decode ticks completed before this one
                 idx = jnp.minimum(t + 1, self._max_new - 1)
-                # One-hot select over the tiny [B, max_new] buffer
-                # (unlike the POOL writes, where only a scatter avoids
-                # rewriting the pool: _slot_layer_step).
+                # One-hot select over the tiny [B, max_new] buffer (the
+                # POOL is only ever scattered into: slot_pool.py).
                 onehot = jnp.arange(self._max_new)[None, :] == idx[:, None]
                 gen = jnp.where(onehot & act[:, None], tok[:, None], gen)
                 hit_eos = (
@@ -1944,8 +1511,7 @@ class StreamingGenerator:
                     else jnp.zeros_like(act)
                 )
                 # Tokens after this tick = t + 2 (prefill's token 0 plus
-                # t+1 decode outputs); complete on EOS or at the budget (a
-                # full buffer where the request carries none).
+                # t+1 decode outputs); complete on EOS or at the budget.
                 done_now = act & (hit_eos | (t + 2 >= budget))
                 pos = jnp.where(act & ~done_now, pos + 1, pos)
                 last_tok = jnp.where(act, tok, last_tok)
@@ -1955,10 +1521,8 @@ class StreamingGenerator:
 
             done0 = jnp.zeros((B,), bool)
             n0 = jnp.zeros((B,), jnp.int32)
-            # What the routed expert layer did (ServeMetrics.moe_*): the
-            # experts touched and the pairs by expert, counted on the
-            # device and fetched with the sync's other arrays. Nothing for
-            # a config without one.
+            # What the routed expert layer did (ServeMetrics.moe_*): experts
+            # touched, pairs by expert; fetched with the sync.
             stats0 = (
                 jnp.zeros((), jnp.int32),
                 jnp.zeros((cfg.held_experts[1],), jnp.int32),
@@ -1968,7 +1532,7 @@ class StreamingGenerator:
                 stats0 += (jnp.zeros((3,), jnp.int32),)
             (caches, last_tok, pos, gen, done, n_out, stats), _ = lax.scan(
                 one, (caches, last_tok, pos, gen, done0, n0, stats0), None,
-                length=K,
+                length=self._ticks_per_sync,
             )
             return (caches, last_tok, pos, gen, done, n_out) + (
                 (stats,) if stats else ()
@@ -1977,44 +1541,28 @@ class StreamingGenerator:
         def resume_admit(params, caches, last_tok, pos, gen, seq, slot,
                          emitted_row, g):
             """Warm-resume ONE slot from a journal hint: prefill ``seq``
-            (= prompt + the g journaled tokens minus the last — position
-            P+g-1 is rewritten by the next tick's own write-before-attend
-            anyway) into the slot's cache row in one dispatch, and restore
-            the position/last-token/gen-buffer state the no-kill run would
-            hold. seq: [1, S] with S = P + g - 1; slot/g: scalars;
-            emitted_row: [max_new] (journaled tokens, zero-padded — zeros
-            beyond g match a fresh admit's cleared buffer)."""
+            [1, P + g - 1] (prompt + the g journaled tokens minus the last:
+            position P+g-1 is rewritten by the next tick anyway) into the
+            slot's cache row, and restore the state the no-kill run would
+            hold. emitted_row: [max_new], the journaled tokens zero-padded
+            as a fresh admit's cleared buffer."""
             caches, last_tok, pos, gen = pin_state(caches, last_tok, pos, gen)
             _logits, fresh = prefill(params, cfg, seq, M, mesh)
-            caches = (
-                lax.dynamic_update_slice(
-                    caches[0], fresh.k.astype(caches[0].dtype),
-                    (0, slot, 0, 0, 0),
-                ),
-                lax.dynamic_update_slice(
-                    caches[1], fresh.v.astype(caches[1].dtype),
-                    (0, slot, 0, 0, 0),
-                ),
-            )
+            caches = pool.resume_put(caches, fresh, slot)
             last_tok = last_tok.at[slot].set(emitted_row[g - 1])
             pos = pos.at[slot].set(P + g - 1)
             gen = lax.dynamic_update_slice(gen, emitted_row[None, :], (slot, 0))
             return caches, last_tok, pos, gen
 
-        # Donate the cache pool: the tick writes it in place (the pool is
-        # the carry of its tick and layer loops) and so does admit (the
-        # carry of its chunk loop), so the output can be the caller's own
-        # buffer; without donation each dispatch first copies the full
-        # [L, B, M, K, Dh] pair. The run loop rebinds the returned buffers
-        # immediately.
-        # Params travel as an ARGUMENT, not a closure: a closed-over param
-        # tree lowers as jaxpr constants, and at zoo scale (2.5-8 GB) that
-        # bloats lowering/compile memory and ships the weights inside the
-        # program instead of referencing the resident device buffers.
+        # Donate the pool: the tick and admit write it in place (the carry
+        # of their loops), so the output can be the caller's own buffer;
+        # the run loop rebinds the returned buffers immediately. Params
+        # travel as an ARGUMENT: a closed-over tree lowers as constants,
+        # bloats compile memory and ships the weights inside the program.
         _admit = jax.jit(admit, donate_argnums=(1,))
         _tick = jax.jit(tick_block, donate_argnums=(1,))
         self._tick_takes_budget = True
-        # Raw (un-jitted) body for decode_roofline's fori-chained windows.
+        # The un-jitted tick, for tests that read its jaxpr.
         self._tick_block_raw = tick_block
 
         def admit_fn(*a):
@@ -2022,122 +1570,33 @@ class StreamingGenerator:
             self._admit_stats.extend(out[4:])
             return out[:4]
 
-        self._admit_fn = admit_fn
-
         def tick_fn(*a):
             out = _tick(self._params, *a)
             self._tick_stats = out[6] if len(out) > 6 else None
             return out[:6]
 
-        self._tick_fn = tick_fn
-        if kv_int8 or latent or kinds or hybrid:
-            # int8 pools deliberately give up token-exactness, the one
-            # contract warm resume exists to keep; hints are filtered out
-            # in _take_hint, so no resume program is built. The latent
-            # pool and the pool by kind have no spelling of the K/V resume
-            # prefill yet (_resume_supported): hints fall back to cold
-            # replay.
-            self._resume_exec = None
-        else:
+        self._admit_fn, self._tick_fn = admit_fn, tick_fn
+        # Hints a pool cannot resume (KVBackend.resumable) are filtered out
+        # in _take_hint and replay cold: no resume program is built.
+        self._resume_exec = None
+        if backend.resumable:
             _resume = jax.jit(resume_admit, donate_argnums=(1,))
             self._resume_exec = lambda *a: _resume(self._params, *a)
-        if hybrid:
-            n_lin, n_att = cfg.hybrid_layers(True), cfg.cache_layers
-            state, conv = linear_attn.slot_shapes(cfg)
-            pools = (
-                ((n_att, B, M, cfg.latent_dim),) if cfg.is_mla
-                else ((n_att, B, M, kh * dh),) * 2
-            )
-            self._caches = (
-                jnp.zeros((n_lin, B, *state), jnp.float32),
-                jnp.zeros((n_lin, B, *conv), cfg.dtype),
-                *(jnp.zeros(shape, cfg.dtype) for shape in pools),
-            )
-            self.metrics.linear_state = {
-                "kind": cfg.linear_kind,
-                "layers": n_lin, "bytes_state": self._caches[0].nbytes,
-                "bytes_conv": self._caches[1].nbytes,
-                "state_dtype": "float32", "step": linear_attn.step_form(),
-                "prefill": "chunked",
-                "chunk": linear_attn.prefill_chunk(cfg),
-            }
-            if not cfg.is_mla:
-                self.metrics.kv_pool_static = {
-                    "full_layers": n_att,
-                    "bytes_full": sum(c.nbytes for c in self._caches[2:]),
-                    "read": "xla",
-                }
-        elif latent:
-            # A row an attention block: [2L, ...] for the double layer.
-            self._caches = (
-                jnp.zeros((cfg.cache_layers, B, M, cfg.latent_dim), cfg.dtype),
-            )
-            self.metrics.attn_blocks = cfg.attn_blocks
-        elif kinds:
-            w = cfg.sliding_window
-            self._caches = tuple(
-                jnp.zeros(
-                    (cfg.kind_layers(window), B, rows, kh * dh), cfg.dtype
-                )
-                for window, rows in ((False, M), (False, M), (True, w), (True, w))
-            )
-            self.metrics.kv_pool_static = {
-                "window": w, "window_layers": cfg.kind_layers(True),
-                "full_layers": cfg.kind_layers(False),
-                "bytes_window": sum(c.nbytes for c in self._caches[2:]),
-                "bytes_full": sum(c.nbytes for c in self._caches[:2]),
-            }
-        elif kv_int8:
-            # K-major for the Pallas read (see _slot_layer_step_q), which
-            # fetches a live slot's rows by blocks; position-major for the
-            # XLA read, whose block is the slab.
-            from torchkafka_tpu.ops.kvattn import dynlen_block
-
-            rows = (kh, M) if kv_kernel else (M, kh)
-            self._caches = tuple(
-                jnp.zeros((nl, B, *rows, *tail), dtype)
-                for _ in "kv" for tail, dtype in (((dh,), jnp.int8), ((), jnp.float32))
-            )
-            self._kv_read_block = dynlen_block(M) if kv_kernel else M
-            self.metrics.kv_pool_static = {
-                "full_layers": nl,
-                "bytes_full": sum(c.nbytes for c in self._caches),
-                "read": "kernel" if kv_kernel else "xla",
-                "block": self._kv_read_block,
-            }
-        else:
-            self._caches = (
-                jnp.zeros((nl, B, M, kh, dh), cfg.dtype),
-                jnp.zeros((nl, B, M, kh, dh), cfg.dtype),
-            )
+        for name, payload in pool.static().items():
+            setattr(self.metrics, name, payload)
         if cfg.routed_moe:
-            self.metrics.moe_expert_load = np.zeros(
-                (cfg.held_experts[1],), np.int64
-            )
-            self.metrics.experts_held = list(cfg.held_experts)
+            self.metrics.moe_expert_load = np.zeros((held[1],), np.int64)
+            self.metrics.experts_held = list(held)
             self.metrics.expert_groups = {
                 "n_group": cfg.n_group, "topk_group": cfg.topk_group,
             }
-        self._last_tok = jnp.zeros((B,), jnp.int32)
-        self._pos = jnp.zeros((B,), jnp.int32)
-        self._gen = jnp.zeros((B, self._max_new), jnp.int32)
-        if mesh is not None:
-            # Place the initial pool in its serving layout so the first
-            # dispatch doesn't start from replicated buffers.
-            if kv_kernel:
-                kv = kv_kmajor_sharding(mesh)
-                kvs = kv_kmajor_scale_sharding(mesh)
-            else:
-                kv = kv_sharding(mesh)
-                kvs = kv_scale_sharding(mesh)
-            row = slot_sharding(mesh)
-            self._caches = tuple(
-                jax.device_put(c, kv if c.ndim == 5 else kvs)
-                for c in self._caches
-            )
-            self._last_tok = jax.device_put(self._last_tok, row)
-            self._pos = jax.device_put(self._pos, row)
-            self._gen = jax.device_put(self._gen, slot_sharding(mesh, 2))
+        state = (
+            pool.zeros(), jnp.zeros((B,), jnp.int32),
+            jnp.zeros((B,), jnp.int32), jnp.zeros((B, self._max_new), jnp.int32),
+        )
+        if placed is not None:
+            state = jax.device_put(state, placed)
+        self._caches, self._last_tok, self._pos, self._gen = state
 
     # ------------------------------------------------------ paged slot pool
     #
@@ -2148,7 +1607,7 @@ class StreamingGenerator:
     # logical position — lives host-side in the allocator/radix pair. The
     # table rides INSIDE the donated state tuple (returned unchanged by the
     # tick) so every dispatch signature matches the dense path and
-    # decode_roofline/warmup/step need no special plumbing.
+    # warmup/step need no special plumbing.
 
     def _paged_setup(self) -> bool:
         """Host-side paging state; False = pool too small for even ONE
@@ -3173,197 +2632,6 @@ class StreamingGenerator:
             self._journal.flush()
         return filled
 
-    def decode_roofline(
-        self, *, iters: int = 8, windows: int = 3,
-        peak_hbm_gbs: float | None = None, fill: str = "mid",
-    ) -> dict:
-        """Pure DEVICE decode speed with HBM-bandwidth roofline accounting.
-
-        Decode is weight/KV-streaming bound: every tick reads the full
-        parameter set plus the slot KV pool for one token per slot. This
-        measures the decode tick program alone, as the SLOPE between two
-        window lengths (``iters`` and 3×``iters`` tick blocks chained
-        INSIDE one jitted ``fori_loop``, fenced by one scalar fetch): ONE
-        dispatch per window — which the slope then cancels exactly. A
-        Python loop of jitted calls here would only amortise the
-        per-dispatch host cost (~overhead/K per tick), so in host-bound
-        regimes (small models) it reports the host dispatch rate while
-        slope_ok stays True — the exact failure mode
-        ``device_step_seconds``' fori-chaining exists to avoid
-        (ADVICE r4). Reports achieved bytes/s against the chip's peak HBM
-        bandwidth, the serving analog of training's MFU:
-        ``peak_hbm_gbs`` defaults to the published peak of the device
-        this process holds (``utils.devices.device_peaks`` — a device
-        that is not in its table raises; off-chip callers pass a
-        number). The gap between the run loop's end-to-end tokens/s and
-        this number is host/admission overhead; the gap between this and
-        100% roofline is the program's own inefficiency.
-
-        Slot positions are saved and RESTORED around the probe (the
-        'mid' fill pins them, and the probe ticks advance them either
-        way); the probe still writes probe kv/tokens through the real
-        tick program, so call it while no generations are in flight for
-        full state safety. With the dynamic-length kernel engaged, the
-        per-tick KV bytes are scaled by the measured fill fraction
-        (``kv_read_bytes``) — the kernel only reads live positions, and
-        pool-shaped accounting could report >100% of physical peak."""
-        cfg = self._cfg
-        if peak_hbm_gbs is None:
-            from torchkafka_tpu.utils.devices import device_peaks
-
-            peak_hbm_gbs = device_peaks().hbm_bytes_s / 1e9
-        B, K = self._slots, self._ticks_per_sync
-        active = jnp.ones((B,), bool)
-        key = self._slot_keys  # per-slot record-key data, [B, W] uint32
-        tick_block = self._tick_block_raw
-        # ``fill``: the slot positions the measurement starts from. With
-        # the dynamic-length kernel the tick reads only [0, pos] per
-        # slot, so tick time is FILL-DEPENDENT and measuring from empty
-        # pools (pos=0) would overstate throughput. "mid" (default)
-        # pins every slot to the steady-state midpoint (prompt +
-        # max_new/2); "live" keeps whatever state the server is in
-        # (the pre-v3 behavior — fill-independent paths measure the
-        # same either way, within noise).
-        if fill not in ("mid", "live"):
-            raise ValueError(f"fill must be 'mid' or 'live', got {fill!r}")
-        why = _arch_refusal(self._cfg, "decode_roofline (it counts K/V pool bytes)")
-        if why:
-            raise ValueError(why)
-        # The probe ticks advance (and 'mid' first overwrites) self._pos;
-        # without restoring it, a probe taken mid-serving would leave every
-        # in-flight slot at a fabricated position and corrupt its remaining
-        # generation (ADVICE r5 #2). Restored in the finally below. NOTE
-        # the probe still runs real ticks: it writes probe kv/tokens into
-        # the pool and gen buffer, so for full safety call it while no
-        # generations are in flight (scenario 7 probes after warmup,
-        # before serving) — the pos restore makes the IDLE case exact and
-        # bounds the damage in the in-flight case.
-        pos_saved = self._pos
-        if fill == "mid":
-            target = min(
-                self._prompt_len + self._max_new // 2, self._max_len - 1
-            )
-            self._pos = jnp.full((B,), target, jnp.int32)
-        # The fill the window ACTUALLY measures: positions advance one
-        # per tick inside a K-tick block (re-pinned only between blocks)
-        # until the done latch freezes them at prompt + max_new - 2, so
-        # with a large ticks_per_sync the block's mean fill sits above
-        # the pinned start. Report the analytic per-tick mean, not the
-        # start value.
-        cap = self._prompt_len + self._max_new - 2
-        start = np.asarray(self._pos)
-        per_tick = np.minimum(start[None, :] + np.arange(K)[:, None], cap)
-        measured_fill = float((per_tick + 1).mean()) / self._max_len
-
-        # n is a TRACED loop bound: one compile serves both window lengths.
-        # The cache pool is DONATED like the serving tick's dispatch: at
-        # the 8B-class scales this path exists for, an un-donated window
-        # would hold input + output pools at once (multiple GB) and could
-        # OOM mid-benchmark.
-        pin_fill = fill == "mid"
-        pos0 = self._pos
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
-        def run(n, params, caches, last_tok, pos, gen):
-            def body(_, carry):
-                caches, last_tok, pos, gen = carry
-                caches, last_tok, pos, gen, _done, _n_out = tick_block(
-                    params, caches, last_tok, pos, gen, active, key
-                )
-                if pin_fill:
-                    # Constant-fill measurement: ticks advance (and then
-                    # done-latch-freeze) positions, which would drift the
-                    # fill toward pool-full across a long window; re-pin
-                    # between tick blocks so a fill-dependent read (the
-                    # dynamic-length kernel) is measured AT the stated
-                    # fill (drift within one K-tick block only).
-                    pos = pos0
-                return (caches, last_tok, pos, gen)
-
-            out = lax.fori_loop(0, n, body, (caches, last_tok, pos, gen))
-            # Scalar fence transitively dependent on every iteration.
-            return out, out[1].ravel()[0]
-
-        # Rebind self state after EVERY window: an exception mid-
-        # measurement must not leave the server holding donated (deleted)
-        # buffers.
-        def window(n_dispatches: int) -> float:
-            t0 = time.perf_counter()
-            out, fence = run(
-                n_dispatches, self._params, self._caches, self._last_tok,
-                self._pos, self._gen,
-            )
-            self._caches, self._last_tok, self._pos, self._gen = out
-            int(np.asarray(jax.device_get(fence)))  # completion proof
-            return time.perf_counter() - t0
-
-        from torchkafka_tpu.utils.timing import two_point_slope
-
-        try:
-            window(1)  # warm (compile + route)
-            # INTERLEAVED short/long windows: grouping all shorts before all
-            # longs lets drifting host conditions flip the slope's sign.
-            shorts, longs = [], []
-            for _ in range(windows):
-                shorts.append(window(iters))
-                longs.append(window(3 * iters))
-        finally:
-            # Probe over (or died mid-window): put the real per-slot
-            # positions back — pos is never donated, so the saved handle
-            # is still alive.
-            self._pos = pos_saved
-        t_short, t_long = float(np.median(shorts)), float(np.median(longs))
-        tick_s, overhead_s, slope_ok = two_point_slope(
-            t_short, t_long, iters * K, 3 * iters * K
-        )
-        overhead_ms = overhead_s * 1e3
-        w_bytes, kv_bytes = decode_tick_bytes(
-            self._params, cfg, B, self._max_len, kv_int8=self._kv_int8
-        )
-        # The v3 dynamic-length kernel DMAs only [0, pos] per slot, so the
-        # KV bytes a tick actually READS scale with the measured fill —
-        # counting the full pool there would let achieved GB/s (and the
-        # roofline %) exceed physical peak at partial fills (ADVICE r5
-        # #1). The XLA read is pool-shaped either way, so kv_read ==
-        # kv_pool without the kernel.
-        kv_read = (
-            int(round(kv_bytes * measured_fill)) if self._kv_kernel
-            else kv_bytes
-        )
-        bytes_per_tick = w_bytes + kv_read
-        roofline_tok_s = B * peak_hbm_gbs * 1e9 / bytes_per_tick
-        out = {
-            "slope_ok": slope_ok,
-            "fill": fill,
-            "measured_fill_frac": round(measured_fill, 3),
-            "dispatch_overhead_ms": round(overhead_ms, 1),
-            "weight_bytes": w_bytes,
-            "kv_pool_bytes": kv_bytes,
-            "kv_read_bytes": kv_read,
-            "weight_bytes_g": round(w_bytes / 1e9, 3),
-            "kv_pool_bytes_g": round(kv_bytes / 1e9, 3),
-            "peak_hbm_gbs": peak_hbm_gbs,
-            "roofline_tok_s": round(roofline_tok_s, 1),
-        }
-        if not slope_ok:
-            # The windows' fixed costs drifted by more than the device
-            # work separating them — publishing the floored values would
-            # fabricate numbers like 1e10 tok/s. Flag and return.
-            out.update({
-                "device_tick_ms": None, "device_tok_s": None,
-                "achieved_hbm_gbs": None, "hbm_roofline_pct": None,
-            })
-            return out
-        achieved_gbs = bytes_per_tick / tick_s / 1e9
-        out.update({
-            # 6 decimals: a toy model's tick is microseconds.
-            "device_tick_ms": round(tick_s * 1e3, 6),
-            "device_tok_s": round(B / tick_s, 1),
-            "achieved_hbm_gbs": round(achieved_gbs, 1),
-            "hbm_roofline_pct": round(100 * achieved_gbs / peak_hbm_gbs, 1),
-        })
-        return out
-
     def warmup(self) -> None:
         """Compile the admit and decode programs (no-op inputs) so the
         first real generation doesn't pay XLA compilation (minutes at the
@@ -3696,10 +2964,9 @@ class StreamingGenerator:
             and 1 <= g <= self._max_new
             and (hint.finished or g < self._max_new)
             # Partial-generation resume prefills through this server's
-            # cache — possible exactly when the pool keeps the
-            # exactness contract and the prefill has a spelling here
-            # (_resume_supported). Finished hints need no prefill at
-            # all.
+            # cache: possible exactly when the pool keeps the exactness
+            # contract and the prefill has a spelling (_resume_supported).
+            # Finished hints need no prefill at all.
             and (hint.finished or self._resume_supported())
         )
         if not ok:
@@ -3709,25 +2976,9 @@ class StreamingGenerator:
         return hint
 
     def _resume_supported(self) -> bool:
-        """Can a PARTIAL journal hint warm-resume on this backend?
-
-        int8 pools never (exactness was traded away — the one contract
-        warm resume exists to keep). Compute-dtype pools: always on one
-        device; under a mesh, the paged path resumes fine (the
-        prompt + emitted tokens ride the chunk queue and state restores
-        host-side), and the dense path resumes when the mesh carries no
-        data axis (its [1, S] resume prefill has no batch to shard —
-        tp/fsdp-only meshes are unaffected). Everything else falls back
-        to cold replay, which is still correct."""
-        cfg = self._cfg
-        if (self._kv_int8 or cfg.is_mla or cfg.window_pattern
-                or cfg.linear_pattern):
-            return False
-        if self._mesh is None:
-            return True
-        if self._kv_pages is not None:
-            return True
-        return self._mesh.shape.get("data", 1) == 1
+        """Can a PARTIAL journal hint warm-resume here (``KVBackend.
+        resumable``)? Everything else replays cold, which is still correct."""
+        return self._kv_backend.resumable
 
     def _journal_record(self, rec, key_data, tokens, finished) -> None:
         self._journal.record(
@@ -3898,8 +3149,7 @@ class StreamingGenerator:
             # Rebind self state after every dispatch: admit/tick DONATE
             # the pool, so the old self._caches handles are dead buffers —
             # without this, anything reading server state afterwards (a
-            # second run, decode_roofline, spec_stats) holds deleted
-            # arrays.
+            # second run, spec_stats) holds deleted arrays.
             self._caches, self._last_tok, self._pos, self._gen = out
             if self._tracer is not None:
                 for i in np.nonzero(admit_mask)[0]:
@@ -4152,11 +3402,7 @@ class StreamingGenerator:
         journal_dirty = False
         decoded = 0
         first_tokens = 0  # slots surfacing their admission's own token
-        rows_needed = 0  # cached rows the served ticks read, a layer
-        rows_fetched = 0  # and those the dense int8 read fetched for them
-        ring_needed = 0  # and a window layer, of its ring
-        ring = self._cfg.sliding_window
-        kv_block = self._kv_read_block  # 0: no dense int8 pool
+        spans = []  # what the served ticks produced, for the pool's meters
         for i in np.nonzero(self._active)[0]:
             cnt = int(
                 n_out_h[i] if done_h[i]
@@ -4185,21 +3431,7 @@ class StreamingGenerator:
             new_toks = cnt - int(self._slot_emitted[i])
             decoded += new_toks
             first_tokens += int(self._slot_emitted[i] == 0)
-            # The tick that produced token j >= 1 read prompt_len + j rows.
-            j0 = max(int(self._slot_emitted[i]), 1)
-            rows_needed += (cnt - j0) * self._prompt_len + (
-                (cnt - j0) * (j0 + cnt - 1) // 2 if cnt > j0 else 0
-            )
-            if ring:
-                ring_needed += int(np.minimum(
-                    self._prompt_len + np.arange(j0, cnt), ring
-                ).sum())
-            if kv_block:
-                # A live tick fetches whole blocks up to its row (a slot
-                # whose budget is 1 is live for the one tick that latches
-                # it); a slot that is not live fetches nothing.
-                j = self._prompt_len + np.arange(j0, max(ran, j0 + (cnt == 1)))
-                rows_fetched += int((-(-j // kv_block) * kv_block).sum())
+            spans.append((max(int(self._slot_emitted[i]), 1), cnt, ran))
             if self._tracer is not None and new_toks > 0:
                 self._tracer.tokens(
                     self._slot_rec[i], new_toks,
@@ -4223,37 +3455,11 @@ class StreamingGenerator:
         self.metrics.tokens_per_tick.set(float(decoded))
         self.metrics.slot_ticks_run.add(self._slots * self._ticks_per_sync)
         self.metrics.slot_ticks_served.add(decoded - first_tokens)
-        if self._cfg.is_mla:
-            blocks = self._cfg.cache_layers
-            self.metrics.latent_positions_valid.add(rows_needed * blocks)
-            # The XLA read fetches the whole slab of every slot, every tick.
-            self.metrics.latent_positions_read.add(
-                blocks * self._slots * self._ticks_per_sync * self._max_len
+        if self._pool is not None:
+            self._pool.count_reads(
+                self.metrics, spans, self._prompt_len,
+                self._slots * self._ticks_per_sync,
             )
-        if kv_block:
-            n_full = self._cfg.n_layers
-            if not self._kv_kernel:
-                # The XLA read fetches every slot's slab, every tick.
-                rows_fetched = self._slots * self._ticks_per_sync * kv_block
-            self.metrics.full_positions_valid.add(rows_needed * n_full)
-            self.metrics.full_positions_read.add(rows_fetched * n_full)
-        if self._cfg.linear_pattern and not self._cfg.is_mla:
-            # The attention layers' K and V rows, as a pool by kind's full
-            # layers: the XLA read fetches every slot's slab, every tick.
-            n_full = self._cfg.cache_layers
-            self.metrics.full_positions_valid.add(rows_needed * n_full)
-            self.metrics.full_positions_read.add(
-                n_full * self._slots * self._ticks_per_sync * self._max_len
-            )
-        if self._cfg.window_pattern:
-            m, ticks = self.metrics, self._slots * self._ticks_per_sync
-            n_win = self._cfg.kind_layers(True)
-            n_full = self._cfg.kind_layers(False)
-            m.window_positions_valid.add(ring_needed * n_win)
-            m.full_positions_valid.add(rows_needed * n_full)
-            # The XLA reads fetch every slot's ring and slab, every tick.
-            m.window_positions_read.add(n_win * ticks * ring)
-            m.full_positions_read.add(n_full * ticks * self._max_len)
         if journal_dirty:
             # Synchronous at the cadence point: the whole point is
             # that a SIGKILL one instruction later finds these tokens

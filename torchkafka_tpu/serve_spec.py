@@ -374,11 +374,8 @@ class SpecStreamingGenerator(StreamingGenerator):
         self._resume_exec = lambda *a: _resume(
             (self._params, self._draft_params), *a
         )
-        # decode_roofline's raw hook passes only the target tree; close
-        # over the draft (a 45M-class self-draft — small enough that the
-        # constant-lowering cost the base avoids for 8B trees is fine).
-        # NOTE its byte accounting stays target-only: the reported
-        # roofline % under-counts the draft's extra reads.
+        # The un-jitted tick, for tests that read its jaxpr (the base's
+        # hook): it takes only the target tree, so close over the draft.
         self._tick_block_raw = (
             lambda params, *a: tick_block((params, self._draft_params), *a)
         )
@@ -675,10 +672,7 @@ class SpecStreamingGenerator(StreamingGenerator):
         """Measured speculation counters since construction (one device
         fetch). ``acceptance`` is the realized α — the workload-dependent
         number the i.i.d. speedup curve must be evaluated at. Warmup's
-        all-inactive rounds don't count (no active slot → no proposals);
-        a ``decode_roofline`` probe DOES run live rounds, so measure α
-        from a server that hasn't probed (the harness probes a separate
-        instance)."""
+        all-inactive rounds don't count (no active slot → no proposals)."""
         # Counters are the state tuple's TAIL in both layouts (dense:
         # pools + 3 counters; paged: pools + table + 3 counters).
         acc, prop, rounds = (

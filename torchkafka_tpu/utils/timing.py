@@ -4,8 +4,7 @@ Any timing of the form "run K device iterations, fetch, divide by K"
 carries the constant dispatch+fetch cost in every estimate — overhead/K per
 iteration, which buries a quantity of a few milliseconds at small K. Timing
 TWO chain lengths and taking the slope cancels the constant term exactly,
-whatever its size. One implementation, shared by serve.decode_roofline and
-the harness scenarios.
+whatever its size. One implementation, shared by the harness scenarios.
 """
 
 from __future__ import annotations

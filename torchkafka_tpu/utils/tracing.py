@@ -95,7 +95,7 @@ that open it:
                        attend_full (the latent's up-projection) /
                        attend_absorbed (the absorbed products),
                        generate._project_qkv / _attn_tail_routing /
-                       prefill's k and v, serve._slot_layer_step_latent;
+                       prefill's k and v, slot_pool._slot_layer_step_latent;
                        a linear layer's in-projections, convolution,
                        gates and gated norm (linear_attn._project /
                        _conv_qkv / _finish, the delta rule's;
@@ -103,13 +103,14 @@ that open it:
                        Mamba-2 mixer's) and linear_attn.layer_forward /
                        slot_layer_step's norm
     tk_kv_write        quantisation and the row or ring write:
-                       serve._quant_kv, _slot_layer_step*, admit's put,
-                       generate.ring_rows, prefill's pools, a linear
+                       slot_pool._quant_kv, _slot_layer_step*, admit's
+                       put, generate.ring_rows, prefill's pools, a linear
                        layer's conv tail (linear_attn._kda_step /
                        _ssd_step)
     tk_kv_read         scores, softmax and values over CACHED positions,
                        a pool of one kind: generate._read_cached
-                       (_attend_cached's read), serve._slot_layer_step_q
+                       (_attend_cached's read),
+                       slot_pool._slot_layer_step_q
                        (the Pallas call tk_kvattn_dynlen, the row write
                        it holds too); a linear layer's pass over its
                        recurrent state, which IS its cache
@@ -117,12 +118,12 @@ that open it:
                        calls tk_kda_step / tk_ssd_step, or the
                        jax.numpy steps off the TPU)
     tk_kv_read_window  the same over a window layer's ring:
-                       serve._slot_layer_step(kind=) names it to
+                       slot_pool._slot_layer_step(kind=) names it to
                        generate._attend_merged
     tk_kv_read_full    the same over a full layer's slab of a pool by
                        kind: likewise; and over the K and V rows of a
-                       hybrid's grouped-query layer (serve.py's
-                       hybrid_body hands it the same step)
+                       hybrid's grouped-query layer
+                       (slot_pool.StatePool hands it the same step)
     tk_kv_read_latent  the absorbed read of the latent pool:
                        mla._read_latent (attend_absorbed's read)
     tk_attn_flash      attention over a whole sequence, the flash kernels
@@ -137,7 +138,7 @@ that open it:
                        sort, group sizes and the counts of what was
                        routed: moe.route, grouped_experts,
                        compacted_experts, grouped_counts,
-                       serve._count_routing
+                       slot_pool._count_routing
     tk_moe_dispatch    rows gathered into expert order, a tile's gather,
                        the inverse gather, the weighted sum or
                        scatter-add: moe.grouped_experts,
