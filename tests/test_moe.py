@@ -835,6 +835,11 @@ _CELL_FORMS = [
     ("ling-3.0-flash-7l-ep8", "admit", "compacted"),
     ("mistral-7b-v0.3-w8", "tick", None),
     ("mistral-7b-v0.3-w8", "admit", None),
+    # A held share of 16 of 128 beside GQA, 48 slots: 3 local pairs a held
+    # expert a tick, under the floor of 4; an admission's trip of 8,192
+    # tokens would sort seven eighths of absent pairs' rows.
+    ("keye-vl-2.0-30b-a3b-8l-ep8", "tick", "compacted"),
+    ("keye-vl-2.0-30b-a3b-8l-ep8", "admit", "compacted"),
 ]
 
 

@@ -50,6 +50,15 @@ def kinds_cfg() -> TransformerConfig:
     )
 
 
+def indexed_cfg() -> TransformerConfig:
+    return dense_cfg(
+        d_model=32, n_layers=2, n_heads=4, d_ff=48, stated_head_dim=16,
+        window_pattern=(False,), n_experts=4, expert_top_k=2, expert_d_ff=24,
+        router_score="softmax", norm_topk=True, experts_held=(2, 2),
+        index_heads=2, index_head_dim=8, index_topk=4,
+    )
+
+
 F32, I8 = jnp.dtype("float32"), jnp.dtype("int8")
 KV, KV8, KV8_S = (2, SLOTS, M, 2, 128), (2, SLOTS, 2, M, 128), (2, SLOTS, 2, M)
 # name: (config, kv_dtype, kv_kernel, the pool's class, layout, tensors,
@@ -89,6 +98,15 @@ LAYOUTS = {
         {"kv_pool": {"window": 4, "window_layers": 3, "full_layers": 1,
                      "bytes_window": 2 * 3 * SLOTS * 4 * 32 * 4,
                      "bytes_full": 2 * SLOTS * M * 32 * 4}},
+        False,
+    ),
+    "indexed": (
+        indexed_cfg, None, "auto", "IndexedPool", "indexed",
+        [((2, SLOTS, M, 1, 64), F32), ((2, SLOTS, 8, M), F32)],
+        {"kv_pool": {"full_layers": 2, "read": "kernel", "index_layers": 2,
+                     "topk": 4, "bytes_full": 2 * SLOTS * M * 64 * 4,
+                     "bytes_index": 2 * SLOTS * 8 * M * 4,
+                     "index_positions_valid": 0, "sparse_positions_read": 0}},
         False,
     ),
     "state-kda-latent": (

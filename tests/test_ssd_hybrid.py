@@ -200,9 +200,10 @@ def test_latent_attention_keeps_its_positions_and_its_scale(kw):
     assert hybrid_cfg(residual_multiplier=0.5).is_mla  # (the others are built)
 
 
-def test_a_shared_expert_or_a_share_beside_gqa_needs_the_hybrid():
-    """What PR 34 built beside grouped-query attention stays what it was:
-    a shared expert or a held share there is the hybrid's alone."""
+def test_a_shared_expert_beside_gqa_needs_the_hybrid_and_a_share_does_not():
+    """Beside grouped-query attention outside a hybrid a shared expert is
+    still the hybrid's alone; a held share is taken (PR 49: one chip's
+    share of a grouped-query expert model)."""
     plain = {k: v for k, v in dataclasses.asdict(ssd_cfg()).items()}
     plain.update(
         linear_pattern=(), linear_kind="kda", ssd_heads=0, ssd_head_dim=0,
@@ -212,7 +213,13 @@ def test_a_shared_expert_or_a_share_beside_gqa_needs_the_hybrid():
     )
     with pytest.raises(ValueError, match="linear_pattern model alone"):
         TransformerConfig(**plain)
-    plain.update(n_shared_experts=0, experts_held=None)
+    plain.update(n_shared_experts=0)
+    share = TransformerConfig(**plain)
+    assert share.routed_moe and share.moe_partial
+    assert share.held_experts == tuple(plain["experts_held"])
+    with pytest.raises(ValueError, match="linear_pattern model alone"):
+        TransformerConfig(**{**plain, "n_shared_experts": 1})
+    plain.update(experts_held=None)
     assert TransformerConfig(**plain).routed_moe
 
 

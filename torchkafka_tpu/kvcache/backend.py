@@ -77,8 +77,13 @@ class KVBackend:
     in one; beside the
     attention layers' pool by THEIR kind: the latent rows [L_att, B, M,
     rank + rope], or K and V rows [L_att, B, M, K * Dh] twice, as a pool
-    by kind's full layers). ``int8``: quantized payloads + group-wise
-    scales.
+    by kind's full layers) or "indexed" (learned sparse attention,
+    ``index_topk``: a position's K row beside its V row in ONE row of
+    32-bit words, a tile of its own [L, B, M, W / 128, 128], two bfloat16
+    a word, and its index key
+    [L, B, Di, M], positions along the lanes: ``ops/dsa.py`` scores the
+    keys and fetches the selected rows by index). ``int8``: quantized
+    payloads + group-wise scales.
     ``kernel``: the Pallas fill-bounded read engages on decode ticks.
     ``kernel_disabled_reason``: why it does NOT engage (None when it
     does, or when int8 was never requested — there is no kernel
@@ -117,8 +122,8 @@ class KVBackend:
     @property
     def resumable(self) -> bool:
         # int8 pools never (exactness, the one contract warm resume keeps,
-        # was traded away); the latent pool, the pool by kind and the state
-        # have no spelling of the K/V resume prefill yet. Compute-dtype
+        # was traded away); the latent pool, the pool by kind, the state
+        # and the indexed pool have no spelling of the resume prefill yet. Compute-dtype
         # K/V: the paged path always (prompt + emitted tokens ride the
         # chunk queue), the dense path unless the mesh has a data axis (its
         # [1, S] resume prefill has no batch to shard). Else: cold replay.
@@ -289,6 +294,42 @@ def _resolve_by_kind(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
     )
 
 
+def _resolve_indexed(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
+    """The pool of learned sparse attention: what is built, and a reasoned
+    refusal of every combination that is not."""
+    what = "the indexed slot pool (learned sparse attention, index_topk)"
+    if kv_dtype == "int8":
+        raise ValueError(
+            f"{what} is compute-dtype only: kv_dtype='int8' quantises "
+            "(position, head) groups of K and V for the dyn-len read, and "
+            "neither the selected read (a row a DMA, whole words) nor the "
+            "index keys have a scale scheme"
+        )
+    if kv_pages is not None:
+        raise ValueError(
+            f"{what} is a dense per-slot pool: kv_pages (block tables, the "
+            "radix prefix cache, its host tier and the prefill hand-off cut "
+            "from them) address blocks of K and V rows, and a position "
+            "here holds an index key besides, which no block carries"
+        )
+    if mesh is not None and mesh.size > 1:
+        raise ValueError(
+            f"{what} serves on one device: no sharded layout has been "
+            "taught the index keys, and the routed expert layer's held "
+            "share has no exchange across chips behind it"
+        )
+    if kv_kernel is True:
+        raise ValueError(
+            f"{what} is read by its own kernels (tk_dsa_index, "
+            "tk_dsa_attend): kv_kernel=True asks for the int8 pool's Pallas "
+            "read, and never falls back silently"
+        )
+    return KVBackend(
+        layout="indexed", int8=False, kernel=False,
+        kernel_disabled_reason=None, chunked=False, data=1, tp=1,
+    )
+
+
 def _mesh_kernel_reason(cfg, mesh, slots: int) -> str | None:
     """None = the shard_map wrapping works on this mesh; else why not.
 
@@ -344,6 +385,11 @@ def resolve_kv_backend(
         )
     if getattr(cfg, "is_mla", False):
         return _resolve_latent(
+            cfg, mesh=mesh, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
+            kv_pages=kv_pages,
+        )
+    if getattr(cfg, "is_sparse", False):
+        return _resolve_indexed(
             cfg, mesh=mesh, kv_dtype=kv_dtype, kv_kernel=kv_kernel,
             kv_pages=kv_pages,
         )

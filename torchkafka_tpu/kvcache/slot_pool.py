@@ -40,9 +40,11 @@ from torchkafka_tpu.models.transformer import (
     _rms_norm,
     _rope,
     hybrid_groups,
+    index_project,
     scan_hybrid,
     scan_periods,
 )
+from torchkafka_tpu.ops import dsa
 from torchkafka_tpu.ops.kvattn import (
     dynlen_block,
     int8_decode_attention_dynlen,
@@ -220,6 +222,42 @@ def _slot_layer_step_latent(x, layer, pool, l, pos_b, cfg):
     attn = mla.attend_absorbed(q_nope, q_rope, pool, l, pos_b, layer, cfg)
     x, routing = _attn_tail_routing(x, attn, layer, cfg)
     return x, pool, routing
+
+
+def _slot_layer_step_indexed(x, layer, rows, keys, l, pos_b, act, cfg, rope):
+    """``_slot_layer_step`` under learned sparse attention (ops/dsa.py),
+    over the indexed pool: ``rows`` [L, B, M, W / 128, 128] (a position's K
+    row beside its V row, ``dsa.pack_rows``, a tile) and the index keys ``keys`` [L, B, Di,
+    M]. The token's row and index key are scattered into layer ``l`` in
+    place; ``tk_dsa_index`` scores the slot's ``pos + 1`` valid keys, the
+    ``index_topk`` best are selected (``lax.top_k``: exact, ties to the
+    lower position), and ``tk_dsa_attend`` fetches those rows alone. A
+    slot that is not live (``act``) has no valid position: nothing of it is
+    fetched, what it computes is never read. Returns (x, rows, keys,
+    routing)."""
+    q, k, v = _project_qkv(x, layer, cfg)
+    q = _rope(q, pos_b[:, None], rope)
+    k = _rope(k, pos_b[:, None], rope)
+    with xprof.scope(xprof.SCOPE_ATTN_PROJ):
+        h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
+    qi, ki, w = index_project(h, layer, cfg, pos_b[:, None], rope)
+    slots = jnp.arange(rows.shape[1])
+    with xprof.scope(xprof.SCOPE_KV_WRITE):
+        rows = rows.at[l, slots, pos_b].set(
+            dsa.pack_rows(k[:, 0], v[:, 0]).reshape(-1, *rows.shape[3:])
+        )
+        keys = keys.at[l, slots, :, pos_b].set(ki[:, 0].astype(keys.dtype))
+    with xprof.scope(xprof.SCOPE_KV_READ_FULL):
+        n = jnp.where(act, pos_b + 1, 0)
+        scores = dsa.index_scores(qi[:, 0], w[:, 0], keys, l, n)
+        topk = min(cfg.index_topk, scores.shape[-1])
+        _best, chosen = lax.top_k(scores, topk)
+        attn = dsa.attend_selected(
+            q[:, 0], rows, l, chosen, jnp.minimum(n, topk),
+            n_kv=cfg.n_kv_heads, scale=cfg.attn_scale,
+        )
+    x, routing = _attn_tail_routing(x, attn[:, None], layer, cfg)
+    return x, rows, keys, routing
 
 
 @xprof.scope(xprof.SCOPE_MOE_ROUTE)
@@ -497,6 +535,78 @@ class ByKindPool(SlotPool):
         _count_slabs(metrics, "full", cfg.kind_layers(False), spans, window, read)
 
 
+class IndexedPool(SlotPool):
+    """Layout ``indexed`` (learned sparse attention, ``index_topk``): a
+    position's K row beside its V row in ONE row of 32-bit words, a tile
+    of its own [L, B, M, W / 128, 128], and its index key [L, B, Di, M]
+    (ops/dsa.py has why each lies so),
+    both the layer scan's carry; a tick scores a live slot's valid index
+    keys and fetches the selected rows alone."""
+
+    def shapes(self):
+        cfg = self.cfg
+        tile, words = dsa.row_spec(cfg.n_kv_heads, cfg.head_dim, cfg.dtype)
+        return (
+            ((cfg.n_layers, self.slots, self.max_len, *tile), words),
+            ((cfg.n_layers, self.slots, cfg.index_head_dim, self.max_len),
+             cfg.dtype),
+        )
+
+    @property
+    def topk(self) -> int:  # what a query selects, at most
+        return min(self.cfg.index_topk, self.max_len)
+
+    def rows(self, fresh):
+        rows, keys = fresh  # [L, R, P, W] as a tile a position
+        return rows.reshape(*rows.shape[:3], *self.shapes()[0][0][3:]), keys
+
+    def static(self):
+        cfg = self.cfg
+        return {"kv_pool_static": {
+            "full_layers": cfg.n_layers, "bytes_full": self.nbytes(slice(1)),
+            "read": "kernel", "index_layers": cfg.n_layers,
+            "bytes_index": self.nbytes(slice(1, 2)), "topk": self.topk,
+        }}
+
+    def tick_layers(self, params, x, caches, stats, pos, act):
+        cfg = self.cfg
+
+        def body(carry, layer, j, i):
+            x, (rows, keys), stats = carry
+            x, rows, keys, routing = _slot_layer_step_indexed(
+                x, layer, rows, keys, i, pos, act, cfg, cfg.layer_kind(j)[1]
+            )
+            return (x, (rows, keys), _count_routing(stats, routing, act, cfg)), None
+
+        for key, _n, _expert_mlp in _layer_groups(cfg):
+            (x, caches, stats), _ = scan_periods(
+                cfg, params[key], (x, caches, stats), body
+            )
+        return x, caches, stats
+
+    def count_reads(self, metrics, spans, window, ticks):
+        # A served tick at position p holds p + 1 = window + j rows and
+        # selects min(that, topk) of them; the kernels fetch, for a tick
+        # the device held the slot live, the index keys by whole blocks up
+        # to the row and the selected rows by whole chunks.
+        layers, topk = self.cfg.n_layers, self.topk
+        blk = dsa.index_block(self.max_len)
+        chunk = min(dsa.ATTEND_CHUNK, topk)
+        up = lambda a, b: -(-a // b) * b  # noqa: E731
+        for j0, cnt, ran in spans:
+            held = window + np.arange(j0, cnt)
+            live = window + np.arange(j0, max(ran, j0 + (cnt == 1)))
+            metrics.index_positions_valid.add(layers * int(held.sum()))
+            metrics.index_positions_read.add(layers * int(up(live, blk).sum()))
+            metrics.sparse_positions_valid.add(layers * int(held.sum()))
+            metrics.sparse_positions_selected.add(
+                layers * int(np.minimum(held, topk).sum())
+            )
+            metrics.sparse_positions_read.add(
+                layers * int(up(np.minimum(live, topk), chunk).sum())
+            )
+
+
 class StatePool(SlotPool):
     """Layout ``state`` (``linear_pattern``, models/linear_attn.py): a
     linear layer's recurrent state in float32 ([L_lin, B, H, E, E] the
@@ -586,6 +696,6 @@ def make_slot_pool(cfg, backend, *, slots: int, max_len: int, mesh=None) -> Slot
     """The pool of the layout that ``backend`` (a resolved ``KVBackend``) names."""
     kind = {
         "dense": Int8Pool if backend.int8 else SlotPool, "latent": LatentPool,
-        "by_kind": ByKindPool, "state": StatePool,
+        "by_kind": ByKindPool, "state": StatePool, "indexed": IndexedPool,
     }[backend.layout]
     return kind(cfg, backend, slots, max_len, mesh)

@@ -518,6 +518,9 @@ def prefill(
     if cfg.is_mla:
         out = latent_forward(params, model, tokens)
         return out if routing else out[:2]
+    if cfg.is_sparse:
+        out = _prefill_indexed(params, model, tokens)
+        return out if routing else out[:2]
     if cfg.window_pattern:
         out = _prefill_kinds(params, model, tokens, max_len)
         return out if routing else out[:2]
@@ -598,6 +601,39 @@ def _prefill_kinds(params, model: Transformer, tokens: jax.Array, max_len: int):
             ring_rows(pool(True, 1), cfg.sliding_window),
         )
     return logits, cache, chosen
+
+
+def _prefill_indexed(params, model: Transformer, tokens: jax.Array):
+    """``prefill`` for learned sparse attention: (last-position logits [B,
+    V]; what the indexed pool keeps of the S positions, ``(rows [L, B, S,
+    W], index keys [L, B, Di, S])``: a position's K row beside its V row
+    as ``ops.dsa.pack_rows`` lays them, its index key transposed; the
+    routed expert layers' choices [L, B, S, top_k] or None). S long, not a
+    pool: the caller writes them where its pool keeps them."""
+    from torchkafka_tpu.models.transformer import index_key
+    from torchkafka_tpu.ops.dsa import pack_rows
+
+    cfg = model.cfg
+    with tracing.scope(tracing.SCOPE_EMBED):
+        x = embed_rows(params["embed"], tokens, cfg.dtype)
+    positions = jnp.arange(tokens.shape[1])
+
+    def capture(x, layer, j, _i):
+        # Transformer._layer's k, v and index key once more, beside it.
+        kind = cfg.layer_kind(j)
+        with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+            h = _rms_norm(x, layer["ln1"])
+            k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
+            v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
+            k = _rope(k, positions, kind[1])
+        ki = index_key(h, layer, cfg, positions, kind[1])
+        x, _stats, (_latent, chosen) = model._layer_capture(x, layer, kind)
+        with tracing.scope(tracing.SCOPE_KV_WRITE):
+            kept = pack_rows(k, v), ki.swapaxes(1, 2).astype(cfg.dtype)
+        return x, (*kept, chosen)
+
+    x, ((rows, keys, chosen),) = scan_periods(cfg, params["layers"], x, capture)
+    return head_logits(params, cfg, x, -1), (rows, keys), chosen
 
 
 def latent_forward(params, model: Transformer, tokens: jax.Array):

@@ -291,6 +291,19 @@ class TransformerConfig:
     # stay and the top-k is taken among them. (1, 1): no groups.
     n_group: int = 1
     topk_group: int = 1
+    # Learned sparse attention (DeepSeek-Sparse-Attention's indexer beside
+    # grouped-query attention, ops/dsa.py): every full layer of a
+    # ``window_pattern`` of full layers alone has an indexer of
+    # ``index_heads`` heads of ``index_head_dim`` (``wiq``), ONE index key a
+    # position (``wik``) and a weight a head (``wiw``), all from the
+    # layer's normed input, queries and key roped with the layer's theta;
+    # a query attends to the ``index_topk`` earlier positions of largest
+    # ``sum_j w_j relu(qI_j . kI)`` alone. The serving pool keeps the index
+    # key beside the K and V rows (``kvcache/backend.py``, layout
+    # "indexed"). 0: none.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -325,6 +338,11 @@ class TransformerConfig:
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def is_sparse(self) -> bool:
+        """Learned sparse attention: an indexer selects what a query reads."""
+        return self.index_topk > 0
 
     @property
     def routed_moe(self) -> bool:
@@ -432,16 +450,14 @@ class TransformerConfig:
         gqa_routed = (
             # The routed layer beside grouped-query attention, as built:
             # the renormalised softmax top-k, no selection bias, every
-            # layer an expert layer; holding every expert and no shared
-            # one, or in a ``linear_pattern`` model (whose groups are
-            # stacked by ``_hybrid_shapes``) a share beside a shared one.
+            # layer an expert layer, every expert held or a share
+            # (``experts_held``); a shared expert in a ``linear_pattern``
+            # model alone (whose groups are stacked by ``_hybrid_shapes``).
             self.routed_moe and not self.is_mla
             and self.router_score == "softmax" and self.norm_topk
             and not self.first_dense_layers and not self.zero_experts
             and self.routed_scaling == 1.0
-            and (bool(self.linear_pattern) or (
-                not self.n_shared_experts and self.experts_held is None
-            ))
+            and (bool(self.linear_pattern) or not self.n_shared_experts)
         )
         if self.is_moe and self.routed_moe != self.is_mla and not gqa_routed:
             raise ValueError(
@@ -453,8 +469,9 @@ class TransformerConfig:
                 "attention the routed layer is built for experts of a "
                 "stated width, expert_d_ff, under the renormalised softmax "
                 "top-k alone: norm_topk=True, routed_scaling 1, no zero "
-                "experts, no leading dense layer; a shared expert and a "
-                "held share, experts_held, in a linear_pattern model alone)"
+                "experts, no leading dense layer, every expert held or a "
+                "share, experts_held; a shared expert, n_shared_experts, in "
+                "a linear_pattern model alone)"
             )
         if self.is_mla and self.is_moe and (
             self.router_score == "softmax" and self.norm_topk
@@ -674,6 +691,20 @@ class TransformerConfig:
                 "window_pattern is built for grouped-query layers in one "
                 "stacked group on one device: not beside latent attention, "
                 "first_dense_layers or a sequence-parallel attn_impl"
+            )
+        indexer = (self.index_heads, self.index_head_dim, self.index_topk)
+        if any(indexer) and not (
+            min(indexer) > 0 and self.index_head_dim % 2 == 0
+            and pattern == (False,) and not hybrid
+            and not self.attention_multiplier
+        ):
+            raise ValueError(
+                f"index_heads={self.index_heads}, index_head_dim="
+                f"{self.index_head_dim}, index_topk={self.index_topk} "
+                "describe learned sparse attention (ops/dsa.py): all three "
+                "positive, an even index_head_dim, beside the grouped-query "
+                "full layers of window_pattern=(False,) (no window layer, "
+                "no latent attention, no linear_pattern)"
             )
 
 
@@ -1008,9 +1039,10 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
         return (jax.random.normal(key, shape, pd) / math.sqrt(fan_in)).astype(pd)
 
     if cfg.is_moe:
-        ne, eff = cfg.n_experts, cfg.moe_d_ff
+        # (a share's layer holds ``experts_held`` of the router's outputs)
+        ne, eff = cfg.held_experts[1], cfg.moe_d_ff
         mlp = {
-            "router": norm(keys[8], (nl, dm, ne), dm),
+            "router": norm(keys[8], (nl, dm, cfg.router_width), dm),
             "w_gate": norm(keys[5], (nl, ne, dm, eff), dm),
             "w_up": norm(keys[6], (nl, ne, dm, eff), dm),
             "w_down": norm(keys[7], (nl, ne, eff, dm), eff),
@@ -1031,9 +1063,26 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
             "wv": norm(keys[3], (nl, dm, k, dh), dm),
             "wo": norm(keys[4], (nl, h, dh, dm), h * dh),
             **mlp,
+            **_index_params(rng, cfg, norm),
         },
         "ln_f": jnp.ones((dm,), pd),
         "lm_head": norm(keys[9], (dm, v), dm),
+    }
+
+
+def _index_params(rng: jax.Array, cfg: TransformerConfig, norm) -> dict:
+    """The indexer's tensors of a learned-sparse-attention config (none
+    otherwise), from keys of their own: the other tensors are drawn as
+    they were before it existed."""
+    if not cfg.is_sparse:
+        return {}
+    nl, dm = cfg.n_layers, cfg.d_model
+    hi, di = cfg.index_heads, cfg.index_head_dim
+    kq, kk, kw = (jax.random.fold_in(rng, 100 + i) for i in range(3))
+    return {
+        "wiq": norm(kq, (nl, dm, hi, di), dm),
+        "wik": norm(kk, (nl, dm, di), dm),
+        "wiw": norm(kw, (nl, dm, hi), dm),
     }
 
 
@@ -1278,6 +1327,28 @@ def _rope(
     return out.astype(x.dtype)
 
 
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
+def index_project(h, layer, cfg: "TransformerConfig", positions, rope):
+    """The indexer's projections of normed h [B, S, D] (learned sparse
+    attention): index queries qI [B, S, Hi, Di] and the ONE index key a
+    position kI [B, S, Di], both roped over all their columns with the
+    layer's ``rope``, in the compute dtype; the heads' weights w [B, S,
+    Hi] in float32."""
+    qi = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wiq"], cfg.dtype))
+    w = jnp.einsum(
+        "bsd,dh->bsh", h, load_weight(layer["wiw"], cfg.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    return _rope(qi, positions, rope), index_key(h, layer, cfg, positions, rope), w
+
+
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
+def index_key(h, layer, cfg: "TransformerConfig", positions, rope):
+    """``index_project``'s kI alone [B, S, Di] (what a cache keeps)."""
+    ki = jnp.einsum("bsd,de->bse", h, load_weight(layer["wik"], cfg.dtype))
+    return _rope(ki[:, :, None, :], positions, rope)[:, :, 0]
+
+
 @tracing.scope(tracing.SCOPE_FFN)
 def _dense_mlp(h: jax.Array, layer: Mapping[str, jax.Array], cfg) -> jax.Array:
     """SwiGLU on normed activations h [B, S, D]."""
@@ -1406,7 +1477,7 @@ def scan_periods(cfg: "TransformerConfig", stacks, carry, step, first=0):
                 for n, w in rest.items()
             }
             if experts:
-                layer["experts_at"] = (*experts, at * cfg.n_experts)
+                layer["experts_at"] = (*experts, at * cfg.held_experts[1])
             carry, y = step(carry, layer, j, i)
             ys.append(y)
         return carry, tuple(ys)
@@ -1437,6 +1508,19 @@ def _arch_refusal(cfg: "TransformerConfig", what: str) -> str | None:
             "state and a conv tail a linear layer; a compute-dtype pool "
             "the attention layers, latent rows or K and V rows) and run "
             "Transformer's forward"
+        )
+    if cfg.is_sparse:
+        return (
+            f"{what} is not built for learned sparse attention (index_heads, "
+            "index_head_dim, index_topk: an indexer scores every cached "
+            "position and a query reads the rows it selected): the cache "
+            "holds an index key a position beside the K and V rows, which "
+            "nothing that pages, shares, quantises, shards, speculates on "
+            "or rebuilds a cache of K and V rows knows of, and training the "
+            "indexer needs its own alignment loss. These configs serve on "
+            "one device through StreamingGenerator's indexed slot pool "
+            "(compute-dtype rows, read by tk_dsa_index and tk_dsa_attend) "
+            "and run Transformer's forward"
         )
     if cfg.window_pattern or (cfg.routed_moe and not cfg.is_mla):
         return (
@@ -1672,6 +1756,16 @@ class Transformer:
     def _gqa(self, h, layer, positions, kind=None):
         window, rope = kind or (None, self.cfg.rope_theta)
         q, k, v = self._gqa_qkv(h, layer, positions, rope)
+        if self.cfg.is_sparse:
+            # Learned sparse attention: causal and selected (ops/dsa.py).
+            from torchkafka_tpu.ops.dsa import sparse_prefill_attention
+
+            qi, ki, w = index_project(h, layer, self.cfg, positions, rope)
+            with tracing.scope(tracing.SCOPE_ATTN_FLASH):
+                return sparse_prefill_attention(
+                    q, k, v, qi, ki, w, topk=self.cfg.index_topk,
+                    scale=self.cfg.attn_scale, use_kernel=self._use_flash,
+                )
         return self._gqa_attend(q, k, v, window)
 
     def _gqa_attend(self, q, k, v, window=None):

@@ -290,6 +290,89 @@ def _flash_fwd_window(q, k, v, qoff, koff, kv, *, scale, block_q, block_k,
     )(q, k, v, qoff, koff)
 
 
+def _flash_sel_kernel(
+    q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref, l_ref,
+    *, scale: float, block_q: int, block_k: int,
+):
+    """``_flash_kernel`` (causal, offsets 0, no log-sum-exp) under a mask
+    a (query, key) pair that the caller made: a row may keep no key of a
+    block, so a masked probability is set to 0 and not left to exp()."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(ki * block_k <= qi * block_q + block_q - 1)
+    def _block():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        keep = mask_ref[0] != 0  # [block_q, block_k]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale
+        s = jnp.where(keep, s, _NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:, :1] = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[:, :1] = m_new
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finish():
+        l = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def flash_forward_selected(
+    q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array, *,
+    scale: float, interpret: bool | None = None,
+    block_q: int | None = None, block_k: int | None = None,
+) -> jax.Array | None:
+    """Forward-only flash under a SELECTION (learned sparse attention's
+    admission, ``ops/dsa.py``): q [B, S, H, D]; k, v [B, S, K, D]; mask [B,
+    S, S] int8, 1 where query i attends to key j, which the caller holds
+    inside the causal triangle (a block above the diagonal is skipped and
+    its mask not read) -> [B, S, H, D], or None where S does not tile. A
+    scattered selection empties no block under the diagonal, so the call,
+    named ``tk_flash_fwd_sel``, visits the causal call's blocks; the
+    causal call's own program is untouched."""
+    b, s, h, d = q.shape
+    block_q, block_k, interpret = _resolve(s, block_q, block_k, interpret)
+    if not _supported(s, block_q, block_k):
+        return None
+    kv = _kv_index(h, k.shape[2])
+    vmem = {"memory_space": pltpu.VMEM}
+    out = pl.pallas_call(
+        functools.partial(
+            _flash_sel_kernel, scale=scale, block_q=block_q, block_k=block_k
+        ),
+        out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        grid=(b * h, s // block_q, s // block_k),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), lambda g, i, j: (g, i, 0), **vmem),
+            pl.BlockSpec((1, block_k, d), lambda g, i, j: (kv(g), j, 0), **vmem),
+            pl.BlockSpec((1, block_k, d), lambda g, i, j: (kv(g), j, 0), **vmem),
+            pl.BlockSpec(
+                (1, block_q, block_k), lambda g, i, j: (g // h, i, j), **vmem
+            ),
+        ],
+        out_specs=pl.BlockSpec(
+            (1, block_q, d), lambda g, i, j: (g, i, 0), **vmem
+        ),
+        scratch_shapes=_scratch([(block_q, d), (block_q, 128), (block_q, 128)]),
+        interpret=interpret,
+        name="tk_flash_fwd_sel",
+    )(_to_bhsd(q), _to_bhsd(k), _to_bhsd(v), mask)
+    return _from_bhsd(out, b, h)
+
+
 # ----------------------------------------------------------------- backward
 
 

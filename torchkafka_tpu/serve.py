@@ -241,6 +241,16 @@ class ServeMetrics:
         self.window_positions_read = RateMeter()
         self.full_positions_valid = RateMeter()
         self.full_positions_read = RateMeter()
+        # The indexed pool (learned sparse attention, ``index_topk``; in
+        # no other pool's summary), summed over the layers: index keys the served ticks
+        # needed against those ``tk_dsa_index`` fetched; rows the slots
+        # held, rows the model selects of them (``min(held, topk)``) and
+        # rows ``tk_dsa_attend`` fetched.
+        self.index_positions_valid = RateMeter()
+        self.index_positions_read = RateMeter()
+        self.sparse_positions_valid = RateMeter()
+        self.sparse_positions_selected = RateMeter()
+        self.sparse_positions_read = RateMeter()
         self.output_capped = RateMeter()  # slots finished by a per-record
         # output budget (max_new_of) below max_new, and not by EOS: latched
         # by the tick on the device, or cut by the host's clamp at the sync
@@ -443,6 +453,13 @@ class ServeMetrics:
                 "window_positions_read": self.window_positions_read.count,
                 "full_positions_valid": self.full_positions_valid.count,
                 "full_positions_read": self.full_positions_read.count,
+                **({
+                    name: getattr(self, name).count for name in (
+                        "index_positions_valid", "index_positions_read",
+                        "sparse_positions_valid", "sparse_positions_selected",
+                        "sparse_positions_read",
+                    )
+                } if "index_layers" in self.kv_pool_static else {}),
             },
             "output_capped": self.output_capped.count,
             "prefix_cache": self.cache_summary(),

@@ -90,7 +90,9 @@ that open it:
                        projections, rope (YaRN), the output projection,
                        its residual and the norm after it:
                        transformer._rope, Transformer._gqa_qkv (_gqa's
-                       and a hybrid's grouped-query layer's) /
+                       and a hybrid's grouped-query layer's),
+                       transformer.index_project / index_key (learned
+                       sparse attention's indexer) /
                        _layer_capture, _double_layer, mla.project /
                        attend_full (the latent's up-projection) /
                        attend_absorbed (the absorbed products),
@@ -123,7 +125,12 @@ that open it:
     tk_kv_read_full    the same over a full layer's slab of a pool by
                        kind: likewise; and over the K and V rows of a
                        hybrid's grouped-query layer
-                       (slot_pool.StatePool hands it the same step)
+                       (slot_pool.StatePool hands it the same step);
+                       under learned sparse attention the index
+                       scoring, the selection and the selected read
+                       (slot_pool._slot_layer_step_indexed: the Pallas
+                       calls tk_dsa_index and tk_dsa_attend around a
+                       top-k)
     tk_kv_read_latent  the absorbed read of the latent pool:
                        mla._read_latent (attend_absorbed's read)
     tk_attn_flash      attention over a whole sequence, the flash kernels
@@ -131,7 +138,10 @@ that open it:
                        mla._attend_whole (attend_full's attention), the
                        linear layers' chunked forms
                        (linear_attn._kda_sequence: kda.kda_chunk;
-                       _ssd_sequence: ssd.ssd_chunk)
+                       _ssd_sequence: ssd.ssd_chunk); learned sparse
+                       attention's index scores, selection and selected
+                       flash forward (Transformer._gqa:
+                       dsa.sparse_prefill_attention, tk_flash_fwd_sel)
     tk_ffn             the dense FFN and the shared experts:
                        transformer._dense_mlp, moe.routed_moe_mlp
     tk_moe_route       router scores, bias, top-k, renormalisation, the
