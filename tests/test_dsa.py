@@ -104,6 +104,178 @@ def test_the_decode_kernels_against_their_plain_forms(dtype):
         )
 
 
+CHUNK = dsa.ATTEND_CHUNK
+# Selected rows on both sides of every edge of three chunks: no chunk, one
+# row, a chunk's tail, a whole chunk, a second chunk's first row (the
+# buffer's other half), an even count, the last chunk's tail, the whole list.
+COUNTS = (0, 1, CHUNK - 1, CHUNK, CHUNK + 1, 2 * CHUNK, 3 * CHUNK - 1, 3 * CHUNK)
+
+
+def chunked_read(dtype, n, head_dim=128):
+    """A call over three chunks of selected rows (rows of whole lanes at
+    ``head_dim`` 128: two sublanes a row in bfloat16, four in float32):
+    slots of ``n``, none and ``3 * CHUNK - n`` rows, the entries past a
+    slot's count out of range. -> (the counts, the kernel's, the
+    reference's over the entries that count)."""
+    k = 3 * CHUNK
+    _k, _v, rows, _keys = pool_of(
+        jax.random.key(9), dtype, L=2, B=3, M=k + 40, K=2, Dh=head_dim
+    )
+    ks = jax.random.split(jax.random.key(10), 4)
+    q = jax.random.normal(ks[0], (3, 4, head_dim), dtype)
+    idx = jnp.stack([jax.random.permutation(kk, k + 40)[:k] for kk in ks[1:]])
+    counts = jnp.asarray([n, 0, k - n], jnp.int32)
+    past = jnp.arange(k)[None, :] >= counts[:, None]
+    wild = jnp.where(jnp.arange(k)[None, :] % 2 == 0, 2**30, -7)
+    got = dsa.attend_selected(
+        q, rows, 1, jnp.where(past, wild, idx).astype(jnp.int32), counts,
+        n_kv=2, scale=head_dim ** -0.5,
+    )
+    want = dsa.attend_selected_reference(
+        q, rows, 1, idx.astype(jnp.int32), counts, n_kv=2,
+        scale=head_dim ** -0.5,
+    )
+    return counts, got, want
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n", COUNTS)
+def test_the_selected_read_over_several_chunks(dtype, n):
+    """Every chunk's rows arrive before they are multiplied, in either half
+    of the buffer; a chunk's tail is masked, an entry past the count is
+    neither fetched (it is out of range) nor counted, and the slot that is
+    not live between two that are reads zeros."""
+    tol = dict(rtol=3e-2, atol=3e-2) if dtype == jnp.bfloat16 else dict(
+        rtol=1e-5, atol=1e-5
+    )
+    counts, got, want = chunked_read(dtype, n)
+    live = np.asarray(counts > 0)
+    assert got.dtype == dtype and (np.asarray(got, np.float32)[~live] == 0).all()
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
+        **tol,
+    )
+
+
+def test_a_chunk_s_one_wait_takes_what_its_starts_gave(monkeypatch):
+    """The DMA semaphores are left at zero after an even and an odd count
+    of chunks (slots of two, none and one): the kernel run with its
+    semaphores read at the end, and the call made twice. Narrow rows: the
+    interpreter's semaphore is 16 bits wide, and a chunk must fit it. So
+    the byte count of the full-size wait (512 KB a chunk, rows of whole
+    lanes) is held by Mosaic's check of the semaphores at the kernel's
+    exit, on the chip alone, and by no test here; nor can the wide-row
+    cases above tell one wait from a wait a row, the interpreter's DMAs
+    being done when they start."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    left = []
+
+    def probed(meta, idx, q, pool, o, sems, buf, sem, *scratch, **kw):
+        dsa._attend_kernel(meta, idx, q, pool, o, buf, sem, *scratch, **kw)
+        for s in range(2):
+            sems[s] = pltpu.semaphore_read(sem.at[s])
+
+    honest = pl.pallas_call
+
+    def with_probe(kernel, *, out_shape, grid_spec, **kw):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=grid_spec.num_scalar_prefetch,
+            grid=grid_spec.grid, in_specs=grid_spec.in_specs,
+            out_specs=[
+                grid_spec.out_specs,
+                pl.BlockSpec((None, 2), lambda s, *_: (s, 0),
+                             memory_space=pltpu.SMEM),
+            ],
+            scratch_shapes=grid_spec.scratch_shapes,
+        )
+        b = out_shape.shape[0]
+        call = honest(
+            lambda *refs: probed(*refs, **kernel.keywords),
+            out_shape=[out_shape, jax.ShapeDtypeStruct((b, 2), jnp.int32)],
+            grid_spec=grid_spec, **kw,
+        )
+
+        def run(*args):
+            out, sems = call(*args)
+            left.append(sems)
+            return out
+
+        return run
+
+    first = chunked_read(jnp.float32, 2 * CHUNK, 16)[1]
+    with monkeypatch.context() as patch:
+        patch.setattr(dsa.pl, "pallas_call", with_probe)
+        probed_out = chunked_read(jnp.float32, 2 * CHUNK, 16)[1]
+    assert len(left) == 1 and (np.asarray(left[0]) == 0).all()
+    again = chunked_read(jnp.float32, 2 * CHUNK, 16)[1]
+    assert (np.asarray(first) == np.asarray(again)).all()
+    assert (np.asarray(first) == np.asarray(probed_out)).all()
+
+
+@pytest.mark.parametrize("wrong", ["queries", "list", "counts", "pool"])
+def test_the_unchecked_read_refuses_shapes_that_do_not_fit(wrong):
+    """The kernel is compiled without Mosaic's bounds checks, so what
+    holds a program's slot under the pool's is the wrapper, at trace time:
+    more queries than the pool has slots, a list or counts of another
+    batch, or a pool that is not ``[L, B, M, tile, lanes]`` never reach
+    the kernel."""
+    _k, _v, rows, _keys = pool_of(jax.random.key(11), jnp.float32)  # B = 4
+    b = 5 if wrong == "queries" else 4
+    q = jnp.zeros((b, 4, 16))
+    idx = jnp.zeros((3 if wrong == "list" else b, 8), jnp.int32)
+    n = jnp.zeros((3 if wrong == "counts" else b,), jnp.int32)
+    if wrong == "pool":
+        rows = rows.reshape(*rows.shape[:3], -1)
+    with pytest.raises(AssertionError):
+        dsa.attend_selected(q, rows, 0, idx, n, n_kv=2, scale=1.0)
+
+
+def test_the_read_compiles_for_the_chip_without_mosaic_s_checks():
+    """The chip's compiler, no chip attached (every other case here runs
+    the interpreter, which has no such flag): at the cell's row (bfloat16,
+    a ``(4, 128)`` tile) and three chunks, ``tk_dsa_attend`` carries
+    ``disable_bounds_checks`` and compiles; ``tk_dsa_index``, whose
+    blocks the pipeline addresses, keeps Mosaic's checks."""
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    b, m, k = 4, 4096, 3 * CHUNK
+    read = jax.jit(lambda q, rows, idx, n: dsa.attend_selected(
+        q, rows, 1, idx, n, n_kv=4, scale=1.0, interpret=False,
+    )).lower(
+        of((b, 32, 128), jnp.bfloat16), of((2, b, m, 4, 128), jnp.int32),
+        of((b, k), jnp.int32), of((b,), jnp.int32),
+    )
+    text = read.as_text()
+    assert "tk_dsa_attend" in text and "disable_bounds_checks" in text
+    assert "disable_bounds_checks\\22: true" in text
+    read.compile()
+    scores = jax.jit(lambda qi, w, keys, n: dsa.index_scores(
+        qi, w, keys, 1, n, interpret=False,
+    )).lower(
+        of((b, 16, 64), jnp.bfloat16), of((b, 16), jnp.float32),
+        of((2, b, 64, m), jnp.bfloat16), of((b,), jnp.int32),
+    ).as_text()
+    assert "tk_dsa_index" in scores
+    assert "disable_bounds_checks\\22: true" not in scores
+
+
 def test_a_slot_that_is_not_live_names_the_block_before_it():
     """No fetch for it: the order of slots changes no score."""
     _k, _v, _rows, keys = pool_of(jax.random.key(3), jnp.float32, M=256)

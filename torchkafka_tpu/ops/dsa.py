@@ -36,9 +36,22 @@ A decode tick, a layer (the program, ``IndexedPool.step``):
   selected rows by index, one DMA a row out of the pool where it lies,
   ``ATTEND_CHUNK`` rows in flight while the chunk before them is
   multiplied, online softmax over the chunks; nothing else of the slot is
-  read. The queries are laid block-diagonal (head ``h`` in its kv head's
-  columns, ``generate._read_merged``'s form), so a chunk's scores and
-  values are plain products over the row as it lies.
+  read. A row costs the instruction stream its START and nothing else:
+  the list comes in clipped, its entries past the slot's count naming the
+  last counted row, so a start reads one index out of SMEM (no clamp, no
+  compare), and a chunk is waited for ONCE, by a descriptor whose
+  destination is the chunk's whole buffer (a DMA semaphore counts bytes:
+  the one wait takes what the chunk's starts gave), and the start is
+  compiled without Mosaic's own bounds check of its two addresses, which
+  cost more than the start itself: the wrapper has clipped the list and
+  the layer and holds the query batch to the pool's slots, everything
+  else is a loop counter. The queries are laid block-diagonal (head ``h``
+  in its kv head's columns, ``generate._read_merged``'s form), so a
+  chunk's scores and values are plain products over the chunk's rows
+  flattened to ``[chunk, W]``, the K words then the V words. (The
+  flattening re-lays a row's sublanes as lane groups; it read 1-2% of the
+  kernel on the chip, PERF.md PR 50, and is not worth a second way of
+  reading the buffer.)
 
 An admission (``sparse_prefill_attention``): the index scores of a block
 of queries against every key, the threshold of each query's row by
@@ -53,6 +66,7 @@ no scores at all.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -74,9 +88,12 @@ __all__ = [
 ]
 
 # Rows of a slot the selected read has in flight at a time (a chunk is
-# multiplied while the next one's rows arrive), and the queries whose index
+# multiplied while the next one's rows arrive), the most rows whose DMAs
+# one trip of its fetch loop starts (unrolled: 32 read 566 us a call at the
+# cell's sizes, 8 629, 64 558; PERF.md, PR 50), and the queries whose index
 # scores an admission holds at a time (the configuration's 512).
 ATTEND_CHUNK = 256
+_ROWS_A_TRIP = 32
 SELECT_BLOCK = 512
 _INDEX_BLOCK_MAX = 2048
 
@@ -288,38 +305,47 @@ def index_scores_dense(qi, ki, w):
 def _attend_kernel(meta_ref, idx_ref, q_ref, pool_ref, o_ref, buf, sem,
                    m_ref, l_ref, acc_ref, *, chunk, scale, dtype):
     b = pl.program_id(0)
-    layer, n = meta_ref[0], meta_ref[1 + b]
+    n = meta_ref[1 + b]
     n_chunks = (n + chunk - 1) // chunk
     width = buf.shape[-2] * buf.shape[-1]
     half = width // 2
+    slab = pool_ref.at[meta_ref[0], b]  # the slot's rows of this layer
     m_ref[...] = jnp.full(m_ref.shape, -1e30, m_ref.dtype)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def copy(c, i, slot):
-        # Row i of chunk c: past the slot's last selected row the last one
-        # again (a chunk's rows are fetched whole; the softmax masks them).
-        row = idx_ref[0, jnp.minimum(c * chunk + i, n - 1)]
-        return pltpu.make_async_copy(
-            pool_ref.at[layer, b, row], buf.at[slot, i], sem.at[slot]
-        )
+    # (Mosaic's loops unroll wholly or not at all: ``group`` rows a trip,
+    # the largest power of two up to ``_ROWS_A_TRIP`` that divides a chunk,
+    # as an inner loop unrolled whole: the same code as a Python loop over
+    # the rows, traced once and not ``group`` times.)
+    group = math.gcd(chunk, _ROWS_A_TRIP)
 
-    # (Mosaic's loops unroll wholly or not at all: eight rows a trip.)
-    group = 8 if chunk % 8 == 0 else 1
-
-    def each_row(c, slot, do):
+    def fetch(c, slot):
+        # A row is its index and nothing else: ``attend_selected`` has
+        # clipped the list and written the last counted entry over the
+        # entries past ``n`` (a chunk's rows are fetched whole; the softmax
+        # masks them). The trip's first index and its destinations are
+        # taken once, so that a row adds a constant to each.
         def trip(g, _):
-            for i in range(group):
-                do(copy(c, g * group + i, slot))
-            return _
+            at = pl.multiple_of(g * group, group)
+            first = c * chunk + at
+            dst = buf.at[slot, pl.ds(at, group)]
+
+            def row(i, _):
+                pltpu.make_async_copy(
+                    slab.at[idx_ref[0, first + i]], dst.at[i], sem.at[slot]
+                ).start()
+                return _
+
+            return lax.fori_loop(0, group, row, None, unroll=True)
 
         lax.fori_loop(0, chunk // group, trip, None)
 
-    def fetch(c, slot):
-        each_row(c, slot, lambda dma: dma.start())
-
-    def wait(c, slot):
-        each_row(c, slot, lambda dma: dma.wait())
+    def wait(slot):
+        # ONE wait for the chunk: a DMA semaphore counts bytes, and this
+        # descriptor's destination is the chunk's bytes, what its ``chunk``
+        # starts gave between them (only the destination's size is read).
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
 
     @pl.when(n_chunks > 0)
     def _first():
@@ -332,7 +358,7 @@ def _attend_kernel(meta_ref, idx_ref, q_ref, pool_ref, o_ref, buf, sem,
         def _next():
             fetch(c + 1, 1 - slot)
 
-        wait(c, slot)
+        wait(slot)
         rows = buf[slot].reshape(chunk, width)  # words
         q = q_ref[...]  # [H, parts * half]
         ks = _expand(rows[:, :half], dtype)
@@ -371,7 +397,8 @@ def attend_selected(q, pool, layer, idx, n, *, n_kv: int, scale: float,
     many of them count (the leading ones; 0: the slot is not live) ->
     attention [B, H, Dh] over those rows alone, zeros where n is 0. The
     kernel fetches ``ceil(n / chunk) * chunk`` rows of a slot, each by its
-    own DMA, and nothing else of the pool."""
+    own DMA, a chunk behind ONE wait, and nothing else of the pool; an
+    entry past ``n`` is never fetched, whatever it holds."""
     if interpret is None:
         interpret = _default_interpret()
     b, h, dh = q.shape
@@ -379,11 +406,24 @@ def attend_selected(q, pool, layer, idx, n, *, n_kv: int, scale: float,
     m = pool.shape[2]
     dtype = q.dtype
     chunk = min(ATTEND_CHUNK, k)
+    # Every address the kernel forms is in range by what is done HERE (a
+    # program's slot is under the pool's by the shapes held below; the
+    # list and the layer are clipped where XLA's gather would clamp; the
+    # buffer's half and the row of a chunk are loop counters), so the
+    # kernel is compiled without Mosaic's own check of every DMA's two
+    # addresses, which was 13 of a start's 18 instruction bundles. The
+    # entries past ``n`` name the slot's last counted row, so that the row
+    # loop reads an index and neither clamps nor compares (a slot of none
+    # fetches no chunk: what its entries hold is never read).
+    assert pool.ndim == 5 and pool.shape[1] >= b, (pool.shape, q.shape)
+    assert idx.shape == (b, k) and n.shape == (b,), (idx.shape, n.shape)
     n = jnp.clip(n.astype(jnp.int32), 0, k)
-    # A DMA is unchecked where XLA's gather clamps.
     idx = jnp.clip(idx.astype(jnp.int32), 0, m - 1)
+    last = jnp.take_along_axis(idx, jnp.maximum(n - 1, 0)[:, None], axis=1)
+    idx = jnp.where(jnp.arange(k)[None, :] < n[:, None], idx, last)
+    layer = jnp.clip(jnp.asarray(layer, jnp.int32), 0, pool.shape[0] - 1)
     wide = _wide_queries(q, n_kv)
-    meta = jnp.concatenate([jnp.asarray(layer, jnp.int32).reshape(1), n])
+    meta = jnp.concatenate([layer.reshape(1), n])
     vmem = pltpu.VMEM
     out = pl.pallas_call(
         functools.partial(
@@ -411,7 +451,9 @@ def attend_selected(q, pool, layer, idx, n, *, n_kv: int, scale: float,
         ),
         interpret=interpret,
         name="tk_dsa_attend",
-        **({} if interpret else tpu_compiler_params(("arbitrary",))),
+        **({} if interpret else tpu_compiler_params(
+            ("arbitrary",), disable_bounds_checks=True
+        )),
     )(meta, idx[:, None, :], wide, pool)
     return _narrow_values(out, n_kv, dtype)
 

@@ -591,16 +591,21 @@ def _default_interpret() -> bool:
 
 
 def tpu_compiler_params(
-    dimension_semantics: tuple, vmem_limit_bytes: int | None = None
+    dimension_semantics: tuple, vmem_limit_bytes: int | None = None,
+    disable_bounds_checks: bool = False,
 ) -> dict:
     """``{"compiler_params": ...}`` kwargs for a compiled-Mosaic
-    pallas_call — shared by the qmatmul, kvattn and grouped-matmul
-    kernels. ``vmem_limit_bytes``: the scoped VMEM a kernel whose blocks
-    outgrow the compiler's default asks for (None: the default)."""
+    pallas_call — shared by the qmatmul, kvattn, grouped-matmul and
+    sparse-attention kernels. ``vmem_limit_bytes``: the scoped VMEM a
+    kernel whose blocks outgrow the compiler's default asks for (None: the
+    default). ``disable_bounds_checks``: Mosaic's own check of every DMA's
+    addresses left out, for a kernel whose caller holds every address in
+    range itself (``ops/dsa.py::attend_selected``)."""
     return {
         "compiler_params": pltpu.CompilerParams(
             dimension_semantics=dimension_semantics,
             vmem_limit_bytes=vmem_limit_bytes,
+            disable_bounds_checks=disable_bounds_checks,
         )
     }
 
