@@ -1,4 +1,5 @@
-"""What ``cfg.remat`` keeps: the flash kernel's output and log-sum-exp.
+"""What ``cfg.remat`` keeps: the flash kernel's output and log-sum-exp,
+and under a ``tp`` axis the residual stream after the attention block.
 
 The layer's ``jax.checkpoint`` saves the two residuals that
 ``ops.flash._flash_fwd`` names (``REMAT_SAVED``), so the differentiated
@@ -8,9 +9,20 @@ gradient (where each Pallas call sits), by the numbers (the kept tensors
 are the ones a recompute would have produced: every gradient leaf is that
 of the bare ``jax.checkpoint``, bit for bit), and by what must not move:
 layers that run no flash kernel, and every forward-only caller.
+
+Under a mesh with a ``tp`` axis the layer also names the residual stream
+after its attention block, the output projection summed over ``tp`` and
+added (``transformer.REMAT_SAVED_TP``), and the policy keeps it: the
+backward's recompute runs neither the ``wo`` product nor its all-reduce
+again. Held by the compiled gradient's text (no all-reduce under
+``rematted_computation``), by where the names sit in the gradient's jaxpr
+(a kept name is outside every ``remat2``), and by the numbers as above.
+With no ``tp`` axis nothing more is named: the kept names are
+``REMAT_SAVED`` alone.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +32,7 @@ import pytest
 from torchkafka_tpu.models import Transformer, TransformerConfig
 from torchkafka_tpu.models import transformer as tfm
 from torchkafka_tpu.ops import flash
+from torchkafka_tpu.models.transformer import param_specs, shardings_for_mesh
 from torchkafka_tpu.parallel import make_mesh
 
 CFG = TransformerConfig(
@@ -54,14 +67,15 @@ def _sub_jaxprs(eqn):
                 yield inner
 
 
-def pallas_calls(jaxpr, path: tuple = ()):
+def pallas_calls(jaxpr, path: tuple = (), primitive: str = "pallas_call"):
     """(names of the enclosing primitives, kernel name) of every Pallas
-    call under ``jaxpr``."""
+    call under ``jaxpr``; with ``primitive="name"``, the same of every
+    ``checkpoint_name`` and the name it gives."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
+        if eqn.primitive.name == primitive:
             yield path, eqn.params["name"]
         for sub in _sub_jaxprs(eqn):
-            yield from pallas_calls(sub, path + (eqn.primitive.name,))
+            yield from pallas_calls(sub, path + (eqn.primitive.name,), primitive)
 
 
 def primitives(jaxpr) -> set[str]:
@@ -216,3 +230,118 @@ def test_the_residuals_are_named_in_the_kernel_s_layout():
     # floating residual that the forward pass reads through a
     # ``reduce_precision``, a pass over the tensor that changes nothing.
     assert [str(a.dtype) for a in named.values()] == ["uint32", "float32"]
+
+
+# --- under a tp axis: the stream after the attention block is kept --------
+
+WITH_TP = flash.REMAT_SAVED + (tfm.REMAT_SAVED_TP,)
+KEPT = {
+    "no_mesh": (None, flash.REMAT_SAVED),
+    "gpipe_data2_pp2": ({"data": 2, "pp": 2}, flash.REMAT_SAVED),
+    "data4": ({"data": 4}, flash.REMAT_SAVED),
+    "data2_fsdp2": ({"data": 2, "fsdp": 2}, flash.REMAT_SAVED),
+    "data2_tp2": ({"data": 2, "tp": 2}, WITH_TP),
+    "gpipe_pp2_tp2": ({"pp": 2, "tp": 2}, WITH_TP),
+}
+
+
+def names_in(jaxpr):
+    """(names of the enclosing primitives, name) of every
+    ``checkpoint_name`` under ``jaxpr``."""
+    return pallas_calls(jaxpr, primitive="name")
+
+
+def saved_under_remat_saved(fn):
+    """The policy of before this file's second half: ``REMAT_SAVED``
+    alone, whatever the layer names."""
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(
+            *flash.REMAT_SAVED
+        ),
+    )
+
+
+def reductions(model: Transformer, toks: jax.Array) -> list[str]:
+    """The ``op_name`` of every all-reduce in the compiled gradient, the
+    parameters laid out by ``param_specs`` as a train step's are."""
+    params = jax.device_put(
+        model.init(jax.random.key(0)),
+        shardings_for_mesh(model.mesh, param_specs(model.cfg)),
+    )
+    text = jax.jit(
+        jax.grad(lambda p: model.loss(p, toks, toks))
+    ).lower(params).compile().as_text()
+    return [
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        for line in text.split("\n")
+        if re.search(r" all-reduce(-start)?\(", line)
+    ]
+
+
+@pytest.mark.parametrize("where", list(KEPT))
+def test_what_is_kept_is_what_the_mesh_calls_for(where):
+    """In the gradient's jaxpr a kept value is named in the forward pass,
+    outside every ``remat2``; a value named and NOT kept would be named
+    again inside the recompute."""
+    axes, kept = KEPT[where]
+    named = list(names_in(grad_jaxpr(model_of(CFG, axes), tokens())))
+    assert all("remat2" not in path for path, _ in named), named
+    assert tuple(name for _, name in named) == kept
+
+
+def test_the_backward_repeats_no_reduction():
+    """data 2 x tp 2, compiled: the forward's reduction of the output
+    projection once, in the forward loop, and no all-reduce inside the
+    backward's recompute."""
+    found = reductions(model_of(CFG, MESHES["data2_tp2"]), tokens())
+    assert not [op for op in found if "rematted_computation" in op], found
+    proj = [op for op in found if "bshe,hed->bsd" in op]
+    assert len(proj) == 1 and "transpose(jvp" not in proj[0], found
+
+
+def test_keeping_flash_s_names_alone_repeats_one(monkeypatch):
+    """The probe sees what it is meant to: with the policy of before, the
+    recompute holds the output projection's all-reduce a second time (one
+    all-reduce more in all), and the layer's name sits inside the
+    ``remat2``."""
+    kept = reductions(model_of(CFG, MESHES["data2_tp2"]), tokens())
+    monkeypatch.setattr(tfm, "_remat_layer", saved_under_remat_saved)
+    model = model_of(CFG, MESHES["data2_tp2"])
+    found = reductions(model, tokens())
+    again = [op for op in found if "rematted_computation" in op]
+    assert len(again) == 1 and "bshe,hed->bsd" in again[0], found
+    assert len(found) == len(kept) + 1
+    named = list(names_in(grad_jaxpr(model, tokens())))
+    assert [
+        name for path, name in named if "remat2" in path
+    ] == [tfm.REMAT_SAVED_TP]
+
+
+def test_gpipe_s_layer_under_tp_has_the_bare_checkpoint_s_numbers(monkeypatch):
+    """The second call site: gpipe's ``layer_fn`` under pp 2 x tp 2 keeps
+    the reduced stream too, and what it keeps is what the recompute would
+    have produced."""
+    toks, axes = tokens(), KEPT["gpipe_pp2_tp2"][0]
+    loss, grads = loss_and_grads(model_of(CFG, axes), toks)
+    monkeypatch.setattr(tfm, "_remat_layer", jax.checkpoint)
+    loss_b, grads_b = loss_and_grads(model_of(CFG, axes), toks)
+    assert float(loss) == float(loss_b)
+    for (path, a), (_, b) in zip(
+        jax.tree_util.tree_leaves_with_path(grads),
+        jax.tree_util.tree_leaves_with_path(grads_b),
+    ):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), str(path))
+
+
+@pytest.mark.parametrize("call", ["forward", "gradient"])
+def test_without_remat_a_tp_mesh_names_nothing_more(call):
+    """Serving and a step without ``cfg.remat`` keep nothing, so name
+    nothing: under tp their programs are the programs of before."""
+    plain = dataclasses.replace(CFG, remat=False)
+    model = model_of(plain, MESHES["data2_tp2"])
+    if call == "forward":
+        jaxpr = jax.make_jaxpr(model)(model.init(jax.random.key(0)), tokens())
+        assert "name" not in primitives(jaxpr.jaxpr)
+    else:
+        named = [n for _, n in names_in(grad_jaxpr(model, tokens()))]
+        assert tuple(named) == flash.REMAT_SAVED

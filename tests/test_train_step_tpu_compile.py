@@ -4,6 +4,11 @@ held to what ``cfg.remat`` keeps since PR 32: ONE flash forward kernel a
 layer (three Pallas calls in the step: forward, dq, dkv; the recompute's
 second forward is gone), the kept output and log-sum-exp stacked by an
 in-place write of one layer's slice, and a program that fits its chip.
+Since PR 51 the 2x2 step also keeps, under its ``tp`` axis, the residual
+stream after the attention block, reduction done: no all-reduce inside
+the backward's recompute, one fewer than under ``REMAT_SAVED`` alone, one
+more ``[L, B, S, d_model]`` stack, and still inside the chip; the one-chip
+step keeps what it kept.
 Nothing runs, so no number here is a measurement.
 
 Beside them the grouped expert matmul's two kernels (``ops/moe.py``, PR
@@ -59,9 +64,8 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.fixture(scope="module", params=list(CELLS))
-def step(request, topo):
-    """(cell, the step program compiled for the described chips)."""
+def compile_step(topo, cell: str):
+    """``cell``'s step program compiled for the described chips."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -75,7 +79,7 @@ def step(request, topo):
         shardings_for_mesh,
     )
 
-    name, rows, _, _ = CELLS[request.param]
+    name, rows, _, _ = CELLS[cell]
     conf = json.loads((REPO / "chipbench/configs" / f"{name}.json").read_text())
     honest = jax.default_backend
     jax.default_backend = lambda: "tpu"  # flash compiles, not interprets
@@ -109,9 +113,34 @@ def step(request, topo):
             jax.tree.map(sds, p_shapes, p_sh),
             jax.tree.map(sds, o_shapes, o_sh), tokens, tokens,
         ).compile()
-        return request.param, compiled
+        return compiled
     finally:
         jax.default_backend = honest
+
+
+_COMPILED: dict = {}
+
+
+def compiled_once(topo, cell: str):
+    """``cell``'s step as the tree compiles it, once a worker."""
+    if cell not in _COMPILED:
+        _COMPILED[cell] = compile_step(topo, cell)
+    return _COMPILED[cell]
+
+
+@pytest.fixture(scope="module", params=list(CELLS))
+def step(request, topo):
+    """(cell, the step program compiled for the described chips)."""
+    return request.param, compiled_once(topo, request.param)
+
+
+def all_reduces(text: str) -> list[str]:
+    """The ``op_name`` of every all-reduce of a compiled program."""
+    return [
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        for line in text.split("\n")
+        if re.search(r" all-reduce(-start)?\(", line)
+    ]
 
 
 def opcodes_of(text: str, shape: str) -> set[str]:
@@ -150,6 +179,48 @@ def test_the_kept_stacks_are_written_in_place(step):
             "dynamic-update-slice", "fusion", "get-tuple-element",
             "parameter", "bitcast",
         }, ops
+
+
+def test_the_recompute_reduces_nothing(step):
+    """Under ``tp`` the remat keeps the residual stream after the attention
+    block, its output projection reduced and added: the forward's
+    reduction of that projection stays, once, and the backward loop's
+    recompute holds no all-reduce. One more stack of the layer input's
+    shape says where it is kept; the one-chip step has the layer inputs'
+    stack alone."""
+    cell, compiled = step
+    text = compiled.as_text()
+    found = all_reduces(text)
+    assert not [op for op in found if "rematted_computation" in op], found
+    layers, rows = (24, 2) if cell == "2x2" else (20, 2)
+    stacks = [
+        line for line in text.split("\n")
+        if re.search(rf" = bf16\[{layers},{rows},4096,2048\]\S* custom-call\(", line)
+        and "AllocateBuffer" in line
+    ]
+    if cell == "2x2":
+        assert len([op for op in found if "bshe,hed->bsd" in op]) == 1, found
+        assert len(stacks) == 2, stacks
+    else:
+        assert found == [] and len(stacks) == 1, stacks
+
+
+def test_keeping_flash_s_names_alone_reduces_once_more(topo, monkeypatch):
+    """The 2x2 step under the policy of before PR 51: the output
+    projection's all-reduce a second time, inside the recompute."""
+    import jax
+
+    from torchkafka_tpu.models import transformer as tfm
+    from torchkafka_tpu.ops.flash import REMAT_SAVED
+
+    kept = all_reduces(compiled_once(topo, "2x2").as_text())
+    monkeypatch.setattr(tfm, "_remat_layer", lambda fn: jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED)
+    ))
+    before = all_reduces(compile_step(topo, "2x2").as_text())
+    again = [op for op in before if "rematted_computation" in op]
+    assert len(again) == 1 and "bshe,hed->bsd" in again[0], before
+    assert len(before) == len(kept) + 1
 
 
 def test_the_step_fits_its_chips(step, capsys):

@@ -33,7 +33,21 @@ Design choices, all for the TPU/XLA compilation model:
   runs once a layer and not again in the recompute. That costs
   ``2·B·S·H·D + 4·B·S·H`` bytes a layer in bf16, as much again as the layer
   input remat already keeps where ``H·D = d_model``. A layer that runs no
-  flash kernel (dense fallback, ring/ulysses) names nothing: nothing is kept.
+  flash kernel (dense fallback, ring/ulysses) names nothing of these.
+  Under a mesh whose ``tp`` axis is larger than 1 the layer also names, and
+  the policy keeps, the residual stream AFTER the attention block
+  (``REMAT_SAVED_TP``: ``x + attn·wo``, the product already summed over
+  ``tp``; whatever the attention kernel). It is what ``ln2`` and the FFN
+  read, so a recompute from the layer input would run the ``wo`` product
+  and its all-reduce a second time, one of five reductions a layer where a
+  tensor-parallel layer needs four. That costs ``2·B·S·d_model`` bytes a
+  layer a chip in bf16, the layer input's size again, written by the
+  fusion that makes the sum. With no ``tp`` axis (one chip, data, fsdp, sp
+  or pp alone) there is no reduction to save, the product alone is about a
+  hundredth of a step, and the one-chip program is cut to the memory it
+  has: nothing is named, and the program is the one of before. The rule
+  reads the mesh (``Transformer._keeps_attn_residual``); no option chooses
+  it.
 """
 
 from __future__ import annotations
@@ -46,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from torchkafka_tpu.models.quant import QTensor, embed_rows, load_weight
@@ -1357,13 +1372,24 @@ def _dense_mlp(h: jax.Array, layer: Mapping[str, jax.Array], cfg) -> jax.Array:
     return jnp.einsum("bsf,fd->bsd", gate * up, load_weight(layer["w_down"], cfg.dtype))
 
 
+# The residual stream after the attention block (the output projection
+# summed over ``tp`` and added): named, and so kept by ``_remat_layer``, only
+# where a remat layer runs under a ``tp`` axis
+# (``Transformer._keeps_attn_residual``).
+REMAT_SAVED_TP = "tk_attn_residual"
+
+
 def _remat_layer(fn: Callable) -> Callable:
     """``cfg.remat``'s ``jax.checkpoint`` of one layer (module docstring,
-    "Remat."): everything is recomputed but what ``ops/flash.py`` names."""
+    "Remat."): everything is recomputed but what ``ops/flash.py`` names
+    and, where the layer names it, ``REMAT_SAVED_TP``."""
     from torchkafka_tpu.ops.flash import REMAT_SAVED
 
     return jax.checkpoint(
-        fn, policy=jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED)
+        fn,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *REMAT_SAVED, REMAT_SAVED_TP
+        ),
     )
 
 
@@ -1603,6 +1629,12 @@ class Transformer:
                 self._use_flash = False
             else:
                 self._flash_shard_mesh = mesh
+        # A remat layer under a ``tp`` axis keeps the residual stream after
+        # its attention block, reduction done (module docstring, "Remat.");
+        # with no ``tp`` axis there is no reduction to save: nothing is named.
+        self._keeps_attn_residual = (
+            cfg.remat and mesh is not None and mesh.shape.get("tp", 1) > 1
+        )
 
     def init(self, rng: jax.Array) -> dict:
         return init_params(rng, self.cfg)
@@ -1725,6 +1757,8 @@ class Transformer:
             x = x + jnp.einsum(
                 "bshe,hed->bsd", attn, load_weight(layer["wo"], cfg.dtype)
             )
+            if self._keeps_attn_residual:
+                x = checkpoint_name(x, REMAT_SAVED_TP)
             h = _rms_norm(x, layer["ln2"])
         stats, routing = jnp.zeros((2, 1), jnp.float32), None
         if "router" not in layer:  # a dense layer (all of a dense config's)

@@ -167,8 +167,9 @@ that open it:
     tk_optimizer       the optimizer's update and its addition to the
                        parameters: make_train_step::_step
 
-``tk_flash_out`` and ``tk_flash_lse`` are the remat policy's names
-(``ops/flash.py::REMAT_SAVED``), not tracing.
+``tk_flash_out``, ``tk_flash_lse`` and ``tk_attn_residual`` are the remat
+policy's names (``ops/flash.py::REMAT_SAVED``,
+``models/transformer.py::REMAT_SAVED_TP``), not tracing.
 
 Importing this module puts metadata into the persistent compilation
 cache's key (``jax_compilation_cache_include_metadata_in_key``; the note
