@@ -70,20 +70,31 @@ _LAST_CELL_BEFORE_PR_41 = "mellum2.repo-context-drain"
 # the LAST of every list it was appended to and its two ``kda.*`` metrics
 # the last of ``per_layer`` (lines 101 and 107): stale with PR 45, which
 # appends the cell ``granite4h.multi-session-drain`` and two ``ssd.*``
-# metrics. By the same precedent that module is shown the benchmark as its
-# PR left it: the lists cut after its own configuration, cell and metrics.
-_LAST_OF_PR_41 = {
-    "config": "ling-3.0-flash-7l-ep8", "cell": "ling3.long-decode-drain",
-    "per_layer": "kda.step_roofline_pct",
+# metrics. ``tests/chipbench/test_chipbench_keye.py`` (PR 49) likewise
+# (``BENCH["configs"][-1]``, ``listed[-2:]``, ``names[-6:]``, nine of
+# each): stale with PR 52, which appends the tenth configuration, the cell
+# ``lfm2.record-enrichment-drain`` and two ``gconv.*`` metrics. By the same
+# precedent each module is shown the benchmark as its PR left it: the
+# lists cut after its own configuration, cell and metrics.
+_LAST_OF_ITS_PR = {
+    "test_chipbench_ling": {
+        "config": "ling-3.0-flash-7l-ep8", "cell": "ling3.long-decode-drain",
+        "per_layer": "kda.step_roofline_pct",
+    },
+    "test_chipbench_keye": {
+        "config": "keye-vl-2.0-30b-a3b-8l-ep8",
+        "cell": "keye2.long-document-drain",
+        "per_layer": "flash.sel_roofline_pct",
+    },
 }
 
 
-def as_pr_41_left_it(bench: dict) -> dict:
+def as_its_pr_left_it(bench: dict, last: dict) -> dict:
     def upto(items, name, key=lambda x: x):
         names = [key(i) for i in items]
         return items[: names.index(name) + 1]
 
-    cell = _LAST_OF_PR_41["cell"]
+    cell = last["cell"]
 
     def known(metric):
         if "workloads" not in metric:
@@ -93,14 +104,10 @@ def as_pr_41_left_it(bench: dict) -> dict:
             upto(cells, cell) if cell in cells else cells
         )}
 
-    per_layer = upto(
-        bench["per_layer"], _LAST_OF_PR_41["per_layer"], lambda m: m["name"]
-    )
+    per_layer = upto(bench["per_layer"], last["per_layer"], lambda m: m["name"])
     return {
         **bench,
-        "configs": upto(
-            bench["configs"], _LAST_OF_PR_41["config"], lambda c: c["name"]
-        ),
+        "configs": upto(bench["configs"], last["config"], lambda c: c["name"]),
         "workloads": upto(bench["workloads"], cell, lambda w: w["name"]),
         "end_to_end": [known(m) for m in bench["end_to_end"]],
         "per_layer": [known(m) for m in per_layer],
@@ -130,15 +137,18 @@ def _stale_chipbench_modules_see_the_per_layer_of_their_pr(request, monkeypatch)
     if module.__name__.rsplit(".", 1)[-1] == "test_chipbench_scopes":
         monkeypatch.setattr(module, "BENCH", as_pr_38_left_it(module.BENCH))
         return
-    if module.__name__.rsplit(".", 1)[-1] == "test_chipbench_ling":
-        monkeypatch.setattr(module, "BENCH", as_pr_41_left_it(module.BENCH))
+    last = _LAST_OF_ITS_PR.get(module.__name__.rsplit(".", 1)[-1])
+    if last is not None:
+        monkeypatch.setattr(module, "BENCH", as_its_pr_left_it(module.BENCH, last))
         real, repo = module.runner.load_cell, module.REPO
 
-        def load_cell_of_pr_41(root, name):
+        def load_cell_of_its_pr(root, name):
             bench, *rest = real(root, name)
-            return (as_pr_41_left_it(bench) if root == repo else bench, *rest)
+            return (
+                as_its_pr_left_it(bench, last) if root == repo else bench, *rest
+            )
 
-        monkeypatch.setattr(module.runner, "load_cell", load_cell_of_pr_41)
+        monkeypatch.setattr(module.runner, "load_cell", load_cell_of_its_pr)
         return
     if module.__name__.rsplit(".", 1)[-1] not in _HOLD_THE_OLD_PER_LAYER:
         return

@@ -74,7 +74,7 @@ class KVBackend:
     position indexes, by the recurrence's kind, ``linear_kind``: "kda"
     [L_lin, B, H, E, E] and [L_lin, B, taps - 1, 3 * H * E]; "ssd" [L_lin,
     B, H, P, N] and [L_lin, B, (taps - 1) * (H * P + 2 N)], a slot's rows
-    in one; beside the
+    in one; "conv" NO state and [L_lin, B, (taps - 1) * D] alone; beside the
     attention layers' pool by THEIR kind: the latent rows [L_att, B, M,
     rank + rope], or K and V rows [L_att, B, M, K * Dh] twice, as a pool
     by kind's full layers) or "indexed" (learned sparse attention,
@@ -218,12 +218,21 @@ def _resolve_state(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
     """The slot memory of a config with linear layers (either
     recurrence, beside either attention's pool): what is built, and a
     reasoned refusal of every combination that is not."""
+    kind = getattr(cfg, "linear_kind", "kda")
     what = (
         "the slot memory of linear layers (linear_pattern, linear_kind="
-        f"{getattr(cfg, 'linear_kind', 'kda')!r})"
+        f"{kind!r})"
     )
+    # The gated convolution keeps a conv tail and no state: what the other
+    # kinds cannot have, it has not been BUILT with.
+    stateless = kind == "conv"
     if kv_dtype == "int8":
         raise ValueError(
+            f"{what} keeps compute-dtype conv tails beside the attention "
+            "layers' K and V rows, and the int8 rows (scales a position "
+            "beside the tails, the quantising write of an admission and a "
+            "tick in this layout) are not built for it"
+            if stateless else
             f"{what} keeps a float32 recurrent state: kv_dtype='int8' "
             "quantises rows of K and V a position, and a state that every "
             "token rewrites has no scale scheme that holds over a thousand "
@@ -231,6 +240,12 @@ def _resolve_state(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
         )
     if kv_pages is not None:
         raise ValueError(
+            f"{what} keeps a conv tail a slot beside rows a position: the "
+            "tail after a prefix is a function of the prefix's last rows, "
+            "so blocks of positions could carry it, but kv_pages (block "
+            "tables, the radix prefix cache, its host tier and the prefill "
+            "hand-off cut from them) are not built to keep a tail a block"
+            if stateless else
             f"{what} is a state a slot, not rows a position: kv_pages "
             "(block tables, the radix prefix cache, its host tier and the "
             "prefill hand-off cut from them) share and rebuild a cache by "
@@ -240,13 +255,18 @@ def _resolve_state(cfg, *, mesh, kv_dtype, kv_kernel, kv_pages) -> KVBackend:
     if mesh is not None and mesh.size > 1:
         raise ValueError(
             f"{what} serves on one device: no sharded layout has been "
-            "taught the state, and the routed expert layer's held share "
+            f"taught the {'tails' if stateless else 'state'}, and the "
+            "routed expert layer's held share "
             "has no exchange across chips behind it"
         )
     if kv_kernel is True:
+        own = (
+            "is rolled by XLA beside the projection it follows "
+            "(tk_gconv_step is a fusion, not a kernel)" if stateless else
+            "is passed over by its own kernel (tk_kda_step, tk_ssd_step)"
+        )
         raise ValueError(
-            f"{what} is passed over by its own kernel (tk_kda_step, "
-            "tk_ssd_step) and the attention layers' compute-dtype pool "
+            f"{what} {own} and the attention layers' compute-dtype pool "
             "(latent rows, or K and V rows) is read by XLA: kv_kernel=True "
             "asks for the int8 pool's Pallas read, and never falls back "
             "silently"

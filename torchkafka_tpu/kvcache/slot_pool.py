@@ -608,15 +608,18 @@ class IndexedPool(SlotPool):
 
 
 class StatePool(SlotPool):
-    """Layout ``state`` (``linear_pattern``, models/linear_attn.py): a
-    linear layer's recurrent state in float32 ([L_lin, B, H, E, E] the
-    delta rule's, [L_lin, B, H, P, N] the Mamba-2 mixer's) and its conv
-    tail (``linear_attn.slot_shapes``), then the attention layers' pool by
+    """Layout ``state`` (``linear_pattern``, models/linear_attn.py): what a
+    slot keeps of a linear layer, its recurrent state in float32 ([L_lin,
+    B, H, E, E] the delta rule's, [L_lin, B, H, P, N] the Mamba-2 mixer's,
+    NONE the gated convolution's) and its conv tail
+    (``linear_attn.slot_shapes``), then the attention layers' pool by
     ``partner``: the latent rows, or K and V rows [L_att, B, M, K * Dh]."""
 
     def __init__(self, *args):
         super().__init__(*args)
         self.latent = self.backend.partner == "latent"
+        # The tensors of the linear layers lead: (states, tails) or (tails,).
+        self.kept = linear_attn.kept_tensors(self.cfg)
         # The state-space state [H, P, N] leaves the chunked scan's
         # product laid out otherwise than the pool (P before H), and the
         # compiler lays the POOL out again to take it: a copy of the whole
@@ -636,29 +639,33 @@ class StatePool(SlotPool):
             ((n_att, B, self.max_len, cfg.latent_dim),) if self.latent
             else ((n_att, B, self.max_len, cfg.n_kv_heads * cfg.head_dim),) * 2
         )
+        states = () if state is None else (((n_lin, B, *state), jnp.float32),)
         return (
-            ((n_lin, B, *state), jnp.float32), ((n_lin, B, *conv), cfg.dtype),
+            *states, ((n_lin, B, *conv), cfg.dtype),
             *((shape, cfg.dtype) for shape in pools),
         )
 
     def static(self):
-        cfg = self.cfg
+        cfg, kept = self.cfg, self.kept
+        chunk = linear_attn.prefill_chunk(cfg)
         payload = {"linear_state": {
             "kind": cfg.linear_kind, "layers": cfg.hybrid_layers(True),
-            "bytes_state": self.nbytes(slice(1)),
-            "bytes_conv": self.nbytes(slice(1, 2)),
-            "state_dtype": "float32", "step": linear_attn.step_form(),
-            "prefill": "chunked", "chunk": linear_attn.prefill_chunk(cfg),
+            "bytes_state": self.nbytes(slice(kept - 1)),
+            "bytes_conv": self.nbytes(slice(kept - 1, kept)),
+            "state_dtype": "float32" if kept == 2 else None,
+            "step": linear_attn.step_form() if kept == 2 else "xla",
+            **({"prefill": "chunked", "chunk": chunk} if chunk
+               else {"prefill": "shifted_sum"}),
         }}
         if not self.latent:
             payload["kv_pool_static"] = {
                 "full_layers": cfg.cache_layers,
-                "bytes_full": self.nbytes(slice(2, None)), "read": "xla",
+                "bytes_full": self.nbytes(slice(kept, None)), "read": "xla",
             }
         return payload
 
     def tick_layers(self, params, x, caches, stats, pos, act):
-        cfg = self.cfg
+        cfg, kept = self.cfg, self.kept
 
         def body(carry, layer, linear, row):
             # Its row in its kind's tensors; a slot that is not active
@@ -671,9 +678,10 @@ class StatePool(SlotPool):
                 )
             else:
                 x, ck, cv, routing = _slot_layer_step(
-                    x, layer, *caches[2:], row, pos, cfg, (None, cfg.rope_theta)
+                    x, layer, *caches[kept:], row, pos, cfg,
+                    (None, cfg.rope_theta),
                 )
-                caches = (*caches[:2], ck, cv)
+                caches = (*caches[:kept], ck, cv)
             return (x, caches, _count_routing(stats, routing, act, cfg)), None
 
         # Every kind's tensors are the period scan's carry.

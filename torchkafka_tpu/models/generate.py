@@ -49,6 +49,7 @@ from torchkafka_tpu.models.transformer import (
     head_product,
     join_residual,
     param_specs,
+    qk_head_norm,
     scan_periods,
     shardings_for_mesh,
 )
@@ -449,7 +450,7 @@ def _project_qkv(x, layer, cfg):
     q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
     k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
     v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
-    return q, k, v
+    return (*qk_head_norm(q, k, layer, cfg), v)
 
 
 @tracing.scope(tracing.SCOPE_HEAD)

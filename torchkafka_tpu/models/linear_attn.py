@@ -8,7 +8,9 @@ layers (``first_dense_layers``) are linear. Two properties of the config
 say what runs in them, each apart from the other: ``linear_kind`` the
 RECURRENCE of a linear layer ("kda", the delta rule with a decay a
 channel, ``ops/kda.py``; "ssd", the Mamba-2 mixer with a scalar decay a
-head, ``ops/ssd.py``), and ``kv_lora_rank`` the ATTENTION of the others
+head, ``ops/ssd.py``; "conv", the gated short convolution, which recurs
+over nothing, ``ops/gconv.py``), and ``kv_lora_rank`` the ATTENTION of the
+others
 (latent, ``models/mla.py``, over one pool of latent rows; or, at 0,
 grouped-query attention over pools of K and V rows, the layer the dense
 paths have: ``Transformer._gqa_qkv`` here, ``slot_pool._slot_layer_step``
@@ -36,13 +38,21 @@ gate z and, before the convolution, x beside the one group's B and C),
 (RMSNorm of ``y * SiLU(z)`` over all the heads' channels) and ``s_out``
 [H, P, D].
 
+A gated convolution's: ``g_in`` [D, 3 * D] (the gates b and c and the
+value x, side by side), ``g_conv`` [taps, D] (no bias, no activation) and
+``g_out`` [1, D, D].
+
 What a slot keeps of a linear layer is NOT indexed by position: the
-recurrent state in float32 (``[H, E, E]``; ``[H, P, N]``) and the conv
-tail, the last ``linear_conv - 1`` rows of what the convolution reads
-(what the next token's reads). The admission leaves both as they stand
-after the prompt window (the chunked forms, ``kda_chunk``,
-``ssd_chunk``); a tick updates the state in place, one pass
-(``tk_kda_step``, ``tk_ssd_step``).
+recurrent state in float32 (``[H, E, E]``; ``[H, P, N]``; the gated
+convolution has none) and the conv tail, the last ``linear_conv - 1``
+rows of what the convolution reads (what the next token's reads). These
+are the layer's ``kept`` tensors, in this order, ``(state, tail)`` or
+``(tail,)``: what a sequence leaves, what a step takes and returns, and
+the head of the slot pool's tensors (``kept_tensors`` says how many). The
+admission leaves them as they stand after the prompt window (the chunked
+forms, ``kda_chunk``, ``ssd_chunk``; the convolution's shifted sum); a
+tick updates the state in place, one pass (``tk_kda_step``,
+``tk_ssd_step``), and rolls the tail.
 """
 
 from __future__ import annotations
@@ -60,7 +70,7 @@ from torchkafka_tpu.models.transformer import (
     hybrid_groups,
     scan_hybrid,
 )
-from torchkafka_tpu.ops import kda, ssd
+from torchkafka_tpu.ops import gconv, kda, ssd
 from torchkafka_tpu.utils import tracing
 
 
@@ -77,8 +87,11 @@ def slot_shapes(cfg: TransformerConfig):
     compiler, short of memory, then keeps the tails "compressed" between
     their uses, a copy of every layer's tails there and back around each
     layer of each tick (14 copies, 10 ms a tick as compiled for a
-    described v5e at 128 slots)."""
+    described v5e at 128 slots). The gated convolution keeps its tail the
+    same way and has NO state: None."""
     taps = cfg.linear_conv - 1
+    if cfg.linear_kind == "conv":
+        return None, (taps * cfg.d_model,)
     if cfg.linear_kind == "ssd":
         return (
             (cfg.ssd_heads, cfg.ssd_head_dim, cfg.ssd_state_dim),
@@ -88,8 +101,16 @@ def slot_shapes(cfg: TransformerConfig):
     return (cfg.n_heads, e, e), (taps, 3 * cfg.n_heads * e)
 
 
-def prefill_chunk(cfg: TransformerConfig) -> int:
-    """Tokens a chunk of the admission's scan."""
+def kept_tensors(cfg: TransformerConfig) -> int:
+    """How many tensors a slot keeps of a linear layer (module docstring)."""
+    return sum(shape is not None for shape in slot_shapes(cfg))
+
+
+def prefill_chunk(cfg: TransformerConfig) -> int | None:
+    """Tokens a chunk of the admission's scan; None where it runs none
+    (the convolution over a sequence is a sum of shifted copies)."""
+    if cfg.linear_kind == "conv":
+        return None
     return cfg.ssd_chunk if cfg.linear_kind == "ssd" else kda.CHUNK
 
 
@@ -144,28 +165,26 @@ def attend_sequence(h, layer, cfg: TransformerConfig):
     """A linear layer's mixer over a whole sequence from an empty state:
     normed h [B, S, D] → (the read-outs [B, S, H, E] before the output
     projection, ``out_projection``; the state after the last token,
-    float32; the conv tail as a slot keeps it, ``slot_shapes``)."""
-    if cfg.linear_kind == "ssd":
-        return _ssd_sequence(h, layer, cfg)
-    return _kda_sequence(h, layer, cfg)
+    float32, or None where the kind has none; the conv tail as a slot
+    keeps it, ``slot_shapes``)."""
+    return _SEQUENCE[cfg.linear_kind](h, layer, cfg)
 
 
 def out_projection(layer, cfg: TransformerConfig):
     """A linear layer's output projection, [H, E, D]."""
-    return layer["s_out" if cfg.linear_kind == "ssd" else "lo"]
+    return layer[_OUT[cfg.linear_kind]]
 
 
-def attend_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
+def attend_step(h, layer, cfg: TransformerConfig, kept, row, act):
     """One decode token a slot: normed h [B, 1, D] against row ``row`` of
-    the stacked states [L, B, H, ., .] and conv tails (``slot_shapes``) →
-    (the read-out [B, 1, H, E], states, tails).
+    the stacked ``kept`` tensors (states [L, B, H, ., .] where the kind
+    has one, conv tails, ``slot_shapes``) → (the read-out [B, 1, H, E],
+    kept).
     ``act`` [B] bool or None: a slot that is not active keeps its state
     and its tail as they are (KDA: it decays nothing, g 0, and corrects
     nothing, beta 0; SSD: it takes no step, dt 0; the kernel writes back
-    what it read)."""
-    if cfg.linear_kind == "ssd":
-        return _ssd_step(h, layer, cfg, states, tails, row, act)
-    return _kda_step(h, layer, cfg, states, tails, row, act)
+    what it read; the convolution: its tail is not rolled)."""
+    return _STEP[cfg.linear_kind](h, layer, cfg, *kept, row, act)
 
 
 def _kda_sequence(h, layer, cfg: TransformerConfig):
@@ -197,7 +216,7 @@ def _kda_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
     with tracing.scope(tracing.SCOPE_KV_READ):
         step = kda.kda_step if step_form() == "kernel" else kda.kda_step_xla
         o, states = step(states, row, q[:, 0], k[:, 0], v[:, 0], g, beta)
-    return _finish(o[:, None], out_gate, layer, cfg), states, tails
+    return _finish(o[:, None], out_gate, layer, cfg), (states, tails)
 
 
 # ------------------------------------------------------- the Mamba-2 mixer
@@ -284,7 +303,42 @@ def _ssd_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
     with tracing.scope(tracing.SCOPE_KV_READ):
         step = ssd.ssd_step if step_form() == "kernel" else ssd.ssd_step_xla
         y, states = step(states, row, x, dt, a, bm, cm, d)
-    return _ssd_finish(y[:, None], z, layer, cfg), states, tails
+    return _ssd_finish(y[:, None], z, layer, cfg), (states, tails)
+
+
+# ------------------------------------------------- the gated short convolution
+
+
+@tracing.scope(tracing.SCOPE_ATTN_PROJ)
+def _conv_project(h, layer, cfg: TransformerConfig):
+    """Normed h [B, S, D] → the gates b and c and the value x [B, S, D]."""
+    bcx = jnp.einsum("bsd,dc->bsc", h, load_weight(layer["g_in"], cfg.dtype))
+    return jnp.split(bcx, 3, axis=-1)
+
+
+def _conv_sequence(h, layer, cfg: TransformerConfig):
+    """→ (y [B, S, 1, D], no state, the tail [B, (taps - 1) * D])."""
+    b, c, x = _conv_project(h, layer, cfg)
+    with tracing.scope(tracing.SCOPE_ATTN_FLASH):
+        y, tail = gconv.gconv_seq(b, c, x, layer["g_conv"])
+    return y[:, :, None], None, tail
+
+
+def _conv_step(h, layer, cfg: TransformerConfig, tails, row, act):
+    b, c, x = _conv_project(h, layer, cfg)
+    tail = lax.dynamic_index_in_dim(tails, row, keepdims=False)
+    with tracing.scope(tracing.SCOPE_ATTN_PROJ):
+        y, fresh = gconv.gconv_step(
+            tail, b[:, 0], c[:, 0], x[:, 0], layer["g_conv"], act
+        )
+    with tracing.scope(tracing.SCOPE_KV_WRITE):
+        tails = lax.dynamic_update_index_in_dim(tails, fresh, row, 0)
+    return y[:, None, None], (tails,)
+
+
+_OUT = {"kda": "lo", "ssd": "s_out", "conv": "g_out"}
+_SEQUENCE = {"kda": _kda_sequence, "ssd": _ssd_sequence, "conv": _conv_sequence}
+_STEP = {"kda": _kda_step, "ssd": _ssd_step, "conv": _conv_step}
 
 
 # ------------------------------------------------------ a layer of either kind
@@ -292,7 +346,8 @@ def _ssd_step(h, layer, cfg: TransformerConfig, states, tails, row, act):
 
 def layer_forward(model, x, layer, linear: bool):
     """One layer of either kind on a whole sequence [B, S, D] → (x, what
-    a slot keeps of it, a tuple: ``(state, conv tail)`` of a linear
+    a slot keeps of it, a tuple: ``(state, conv tail)`` or ``(conv
+    tail,)`` of a linear
     layer, ``(rows [B, S, rank + rope],)`` of a latent one, ``(K rows, V
     rows [B, S, K * Dh])`` of a grouped-query one; the routing [B, S,
     top_k] or None)."""
@@ -301,7 +356,8 @@ def layer_forward(model, x, layer, linear: bool):
         h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
     if linear:
         attn, state, tail = attend_sequence(h, layer, cfg)
-        kept, wo = (state, tail), out_projection(layer, cfg)
+        kept = (tail,) if state is None else (state, tail)
+        wo = out_projection(layer, cfg)
     elif cfg.is_mla:
         q_nope, q_rope, rows = mla.project(
             h, layer, cfg, model._seq_positions(x.shape[1])
@@ -324,18 +380,18 @@ def layer_forward(model, x, layer, linear: bool):
 
 def slot_layer_step(x, layer, linear: bool, row, caches, pos_b, act, cfg):
     """One decode token a slot through a linear layer, or a latent one.
-    x [B, 1, D]; ``caches`` = (states, conv tails, the attention layers'
+    x [B, 1, D]; ``caches`` = (the linear layers' kept tensors: states
+    where the kind has one, conv tails; the attention layers'
     pools: the latent pool [L, B, M, rank + rope]), ``row`` the layer's
     row in its kind's tensors. Returns (x, caches, routing [B, 1, top_k] |
     None). A grouped-query layer's token goes through the dense path's
     own step (``kvcache/slot_pool.py::_slot_layer_step``)."""
-    states, tails, *pools = caches
+    n = kept_tensors(cfg)
+    kept, pools = caches[:n], caches[n:]
     with tracing.scope(tracing.SCOPE_ATTN_PROJ):
         h = _rms_norm(x, layer["ln1"], cfg.norm_eps)
     if linear:
-        attn, states, tails = attend_step(
-            h, layer, cfg, states, tails, row, act
-        )
+        attn, kept = attend_step(h, layer, cfg, kept, row, act)
         wo = out_projection(layer, cfg)
     else:
         (pool,) = pools
@@ -347,19 +403,23 @@ def slot_layer_step(x, layer, linear: bool, row, caches, pos_b, act, cfg):
         attn = mla.attend_absorbed(q_nope, q_rope, pool, row, pos_b, layer, cfg)
         attn, wo, pools = gate_heads(attn, h, layer, cfg), layer["wo"], (pool,)
     x, routing = _attn_tail_routing(x, attn, {**layer, "wo": wo}, cfg)
-    return x, (states, tails, *pools), routing
+    return x, (*kept, *pools), routing
 
 
 def hybrid_forward(params, model, x: jax.Array):
     """A hybrid config's layers over the embedded tokens x [B, S, D] →
     (the stream after the last layer, before the final norm; what a slot
-    keeps: the states [L_lin, B, H, ., .] float32, the conv tails
+    keeps: the states [L_lin, B, H, ., .] float32 (where the kind has
+    one), the conv tails
     (``slot_shapes``) and the attention layers' rows, the latent rows
     [L_att, B, S, rank + rope] or the K and the V rows [L_att, B, S, K *
     Dh], each over its kind's layers in order; the expert layers' routing
     [L_moe, B, S, top_k] or None)."""
     cfg = model.cfg
-    kinds = {True: ([], []), False: ([],) if cfg.is_mla else ([], [])}
+    kinds = {
+        True: tuple([] for _ in range(kept_tensors(cfg))),
+        False: ([],) if cfg.is_mla else ([], []),
+    }
     routing = []
     for key, pattern, _lin0, _lat0 in hybrid_groups(cfg):
         def step(x, layer, linear, _row):
@@ -381,8 +441,8 @@ def hybrid_forward(params, model, x: jax.Array):
     def cat(parts):
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-    states, tails = kinds[True]
-    kept = (cat(states), cat(tails).astype(cfg.dtype)) + tuple(
-        cat(rows).astype(cfg.dtype) for rows in kinds[False]
+    *states, tails = kinds[True]  # (a state stays float32)
+    kept = tuple(cat(s) for s in states) + tuple(
+        cat(rows).astype(cfg.dtype) for rows in (tails, *kinds[False])
     )
     return x, kept, cat(routing) if routing else None

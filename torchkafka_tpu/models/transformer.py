@@ -277,7 +277,10 @@ class TransformerConfig:
     # ``ssd_state_dim`` a head and ONE group of B and C, a scalar decay a
     # head, a conv with a bias over ``linear_conv`` tokens on x, B and C, a
     # gated RMSNorm over all the heads' channels, the admission's scan in
-    # chunks of ``ssd_chunk``.
+    # chunks of ``ssd_chunk``; or "conv", the gated short convolution
+    # (ops/gconv.py): ``d_model`` channels, ``linear_conv`` taps, no bias,
+    # no activation, gated on both sides, and NO state: a slot keeps the
+    # conv tail alone.
     linear_kind: str = "kda"
     ssd_heads: int = 0
     ssd_head_dim: int = 0
@@ -300,6 +303,10 @@ class TransformerConfig:
     use_rope: bool = True
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
+    # A grouped-query layer's q and k are RMS-normed over a HEAD's width
+    # with a learned weight (``q_head_norm``, ``k_head_norm`` [Dh]) before
+    # the rotation: the cache's K rows are normed and rotated.
+    qk_norm: bool = False
     # Group-limited selection (DeepSeek-V3's): the router's outputs fall
     # into ``n_group`` groups of consecutive experts, a group scores the
     # sum of its two best biased scores, the ``topk_group`` best groups
@@ -464,15 +471,23 @@ class TransformerConfig:
             )
         gqa_routed = (
             # The routed layer beside grouped-query attention, as built:
+            # every expert held or a share (``experts_held``), no zero
+            # experts; a shared expert in a ``linear_pattern`` model alone
+            # (whose groups are stacked by ``_hybrid_shapes``); and either
             # the renormalised softmax top-k, no selection bias, every
-            # layer an expert layer, every expert held or a share
-            # (``experts_held``); a shared expert in a ``linear_pattern``
-            # model alone (whose groups are stacked by ``_hybrid_shapes``).
-            self.routed_moe and not self.is_mla
-            and self.router_score == "softmax" and self.norm_topk
-            and not self.first_dense_layers and not self.zero_experts
-            and self.routed_scaling == 1.0
+            # layer an expert layer, or, beside the gated convolution
+            # (``linear_kind`` "conv"), sigmoid scores with a selection
+            # bias after leading dense layers.
+            self.routed_moe and not self.is_mla and not self.zero_experts
             and (bool(self.linear_pattern) or not self.n_shared_experts)
+            and (
+                (self.router_score == "softmax" and self.norm_topk
+                 and not self.first_dense_layers
+                 and self.routed_scaling == 1.0)
+                or (self.router_score == "sigmoid"
+                    and bool(self.linear_pattern)
+                    and self.linear_kind == "conv")
+            )
         )
         if self.is_moe and self.routed_moe != self.is_mla and not gqa_routed:
             raise ValueError(
@@ -486,7 +501,9 @@ class TransformerConfig:
                 "top-k alone: norm_topk=True, routed_scaling 1, no zero "
                 "experts, no leading dense layer, every expert held or a "
                 "share, experts_held; a shared expert, n_shared_experts, in "
-                "a linear_pattern model alone)"
+                "a linear_pattern model alone; sigmoid scores with a "
+                "selection bias and leading dense layers in a "
+                "linear_pattern of linear_kind='conv' alone)"
             )
         if self.is_mla and self.is_moe and (
             self.router_score == "softmax" and self.norm_topk
@@ -620,30 +637,47 @@ class TransformerConfig:
                 "kv_lora_rank, no attn_gate) with ssd_heads, ssd_head_dim, "
                 "ssd_state_dim and ssd_chunk > 0"
             )
+        if hybrid and self.linear_kind == "conv" and (
+            self.is_mla or self.attn_gate
+        ):
+            raise ValueError(
+                "linear_kind='conv' (the gated short convolution) is built "
+                "beside grouped-query attention over K and V rows (no "
+                "kv_lora_rank, no attn_gate)"
+            )
         if hybrid and not (
             self.attn_blocks == 1 and not pattern
             and any(hybrid) and not all(hybrid)
             and (self.n_layers - self.first_dense_layers) % len(hybrid) == 0
-            and self.linear_conv >= 2 and self.linear_kind in ("kda", "ssd")
+            and self.linear_conv >= 2
+            and self.linear_kind in ("kda", "ssd", "conv")
         ):
             raise ValueError(
                 f"linear_pattern={hybrid} (linear_kind={self.linear_kind!r}) "
                 "is a period of linear AND attention layers that divides "
                 "the layers after first_dense_layers, linear_conv >= 2, no "
                 "attn_blocks 2 and no window_pattern; the linear layers "
-                "run linear_kind 'kda' (the delta rule) or 'ssd' (the "
-                "Mamba-2 mixer), the others latent attention where "
+                "run linear_kind 'kda' (the delta rule), 'ssd' (the "
+                "Mamba-2 mixer) or 'conv' (the gated short convolution), "
+                "the others latent attention where "
                 "kv_lora_rank > 0 and grouped-query attention over K and "
                 "V rows otherwise"
             )
         if not (hybrid and self.linear_kind == "ssd") and (
-            self.linear_kind != "kda" or self.ssd_heads
-            or self.ssd_head_dim or self.ssd_state_dim
+            self.linear_kind not in (("kda", "conv") if hybrid else ("kda",))
+            or self.ssd_heads or self.ssd_head_dim or self.ssd_state_dim
         ):
             raise ValueError(
                 "linear_kind, ssd_heads, ssd_head_dim and ssd_state_dim "
                 "describe the linear layers of a linear_pattern: set one "
-                "with linear_kind='ssd'"
+                "with linear_kind='ssd' (the sizes) or 'conv' (none)"
+            )
+        if self.qk_norm and (not hybrid or self.is_mla):
+            raise ValueError(
+                "qk_norm (an RMSNorm a head on q and k before the rotation) "
+                "is built into the grouped-query layers of a linear_pattern "
+                "model alone: no other path draws, shards or quantises "
+                "q_head_norm and k_head_norm"
             )
         if self.attn_gate and not hybrid:
             raise ValueError(
@@ -810,6 +844,8 @@ def _arch_shapes(cfg: TransformerConfig, expert_mlp: bool) -> dict:
             wq=((dm, h, dh), dm), wk=((dm, k, dh), dm), wv=((dm, k, dh), dm),
             wo=((h, dh, dm), h * dh),
         )
+        if cfg.qk_norm:
+            shapes.update(q_head_norm=((dh,), None), k_head_norm=((dh,), None))
     elif cfg.q_lora_rank:
         q = cfg.q_lora_rank
         shapes.update(
@@ -832,7 +868,9 @@ def _arch_shapes(cfg: TransformerConfig, expert_mlp: bool) -> dict:
     if expert_mlp:
         e, w = cfg.held_experts[1], cfg.router_width
         shapes["router"] = ((dm, w), dm)
-        if cfg.is_mla:  # the latent families state a selection bias
+        # The latent families state a selection bias, and the sigmoid
+        # router beside grouped-query attention.
+        if cfg.is_mla or cfg.router_score == "sigmoid":
             shapes["router_bias"] = ((w,), None)
         fs = cfg.n_shared_experts * cfg.moe_d_ff
         if fs:  # the shared experts, one SwiGLU
@@ -870,16 +908,24 @@ _SSD_TENSORS = (
     "s_in", "s_in_dt", "s_conv", "s_conv_b", "s_dt", "s_alog", "s_d",
     "s_norm", "s_out",
 )
+# (the output projection [1, D, D]: one "head" of every channel, as ``lo``
+# and ``s_out`` are [H, E, D], so the layers' tail is one einsum)
+_CONV_TENSORS = ("g_in", "g_conv", "g_out")
+_LINEAR_TENSORS = {
+    "kda": _KDA_TENSORS, "ssd": _SSD_TENSORS, "conv": _CONV_TENSORS,
+}
 _LATENT_TENSORS = ("wq", "wkva", "kv_norm", "wkvb", "wo", "wg")
 _GQA_TENSORS = ("wq", "wk", "wv", "wo")
+_QK_NORM_TENSORS = ("q_head_norm", "k_head_norm")  # (``qk_norm``)
 
 
 def hybrid_tensors(cfg: TransformerConfig) -> dict[bool, tuple[str, ...]]:
     """``{True: a linear layer's own tensors, False: an attention
     layer's}`` of a ``linear_pattern`` model."""
     return {
-        True: _SSD_TENSORS if cfg.linear_kind == "ssd" else _KDA_TENSORS,
-        False: _LATENT_TENSORS if cfg.is_mla else _GQA_TENSORS,
+        True: _LINEAR_TENSORS[cfg.linear_kind],
+        False: _LATENT_TENSORS if cfg.is_mla
+        else _GQA_TENSORS + (_QK_NORM_TENSORS if cfg.qk_norm else ()),
     }
 
 
@@ -887,6 +933,13 @@ def _linear_shapes(cfg: TransformerConfig) -> dict:
     """One linear layer's own tensors (``models/linear_attn.py``): name
     -> (shape, fan_in or None)."""
     dm = cfg.d_model
+    if cfg.linear_kind == "conv":
+        return {
+            # [b | c | x] side by side; no bias anywhere.
+            "g_in": ((dm, 3 * dm), dm),
+            "g_conv": ((cfg.linear_conv, dm), cfg.linear_conv),
+            "g_out": ((1, dm, dm), dm),
+        }
     if cfg.linear_kind == "ssd":
         h, inner, conv = cfg.ssd_heads, cfg.ssd_inner, cfg.ssd_conv_dim
         return {
@@ -1108,6 +1161,18 @@ def _rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     xf = x.astype(jnp.float32)
     rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * rms).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def qk_head_norm(q, k, layer, cfg: "TransformerConfig"):
+    """``cfg.qk_norm``: q [B, S, H, Dh] and k [B, S, K, Dh] RMS-normed over
+    a head's width with the layer's learned weights, before any rotation;
+    else as they came."""
+    if not cfg.qk_norm:
+        return q, k
+    return (
+        _rms_norm(q, layer["q_head_norm"], cfg.norm_eps),
+        _rms_norm(k, layer["k_head_norm"], cfg.norm_eps),
+    )
 
 
 def embed_tokens(params, cfg: "TransformerConfig", tokens: jax.Array):
@@ -1512,26 +1577,37 @@ def scan_periods(cfg: "TransformerConfig", stacks, carry, step, first=0):
     return lax.scan(period, carry, jnp.arange(count // p))
 
 
+_STATEFUL = (
+    "a recurrent state and a conv tail", "a float32 state and a conv tail",
+)
+
+
 def _arch_refusal(cfg: "TransformerConfig", what: str) -> str | None:
     """Why ``what`` does not take a latent-attention config, or one with
     kinds of layer or the routed layer beside grouped-query attention
     (None: it does, the config is neither)."""
     if cfg.linear_pattern:
-        layers = (
-            "state-space layers (linear_pattern, linear_kind='ssd': the "
-            "Mamba-2 mixer)" if cfg.linear_kind == "ssd"
-            else "linear-attention layers (linear_pattern)"
-        )
+        layers, keeps, memory = {
+            "kda": ("linear-attention layers (linear_pattern)",) + _STATEFUL,
+            "ssd": (
+                "state-space layers (linear_pattern, linear_kind='ssd': the "
+                "Mamba-2 mixer)",
+            ) + _STATEFUL,
+            "conv": (
+                "gated short convolutions (linear_pattern, linear_kind="
+                "'conv')", "a conv tail and no state,", "a conv tail",
+            ),
+        }[cfg.linear_kind]
         return (
             f"{what} is not built for a config with {layers}: what a slot "
-            "keeps of such a layer is a recurrent state and a conv tail "
+            f"keeps of such a layer is {keeps} "
             "that no position indexes, so nothing that rebuilds, shares, "
             "pages, quantises, shards or differentiates a cache of rows by "
             "position can hold it (nor has any of them been taught the "
             "multipliers, the attention without positions or the tied head "
             "such a config may state). These configs serve on one device "
-            "through StreamingGenerator's slot memory by kind (a float32 "
-            "state and a conv tail a linear layer; a compute-dtype pool "
+            f"through StreamingGenerator's slot memory by kind ({memory} a "
+            "linear layer; a compute-dtype pool "
             "the attention layers, latent rows or K and V rows) and run "
             "Transformer's forward"
         )
@@ -1782,6 +1858,7 @@ class Transformer:
             q = jnp.einsum("bsd,dhe->bshe", h, load_weight(layer["wq"], cfg.dtype))
             k = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wk"], cfg.dtype))
             v = jnp.einsum("bsd,dke->bske", h, load_weight(layer["wv"], cfg.dtype))
+            q, k = qk_head_norm(q, k, layer, cfg)
             if cfg.use_rope:
                 q = _rope(q, positions, rope)
                 k = _rope(k, positions, rope)

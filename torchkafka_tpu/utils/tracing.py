@@ -65,7 +65,13 @@ The Pallas kernels carry fixed names too (``pl.pallas_call(name=...)`` in
 ``tk_flash_fwd_win``, ``tk_flash_bwd_dq``, ``tk_flash_bwd_dkv``,
 ``tk_qmatmul``, ``tk_gmm_gate_up``, ``tk_gmm_down``, ``tk_kda_step``,
 ``tk_ssd_step`` — the device trace names each kernel's operation after
-them.
+them. Two names of the same style are NOT kernels: ``tk_gconv_step`` and
+``tk_gconv_seq`` (``ops/gconv.py``) are ``jax.named_scope`` path elements
+around the gated short convolution's own part (gates, taps, tail) of a
+tick and of an admission, inside ``tk_attn_proj`` and ``tk_attn_flash``:
+XLA fuses that part as it sees fit, and the benchmark finds its
+operations by the name in their ``op_name``
+(``chipbench/layer_metrics/_gconv.py``).
 
 The device programs name their parts (``jit_admit``, ``jit_tick_block``,
 ``jit__step`` and whatever else traces the shared model code): twelve
